@@ -1237,17 +1237,6 @@ inline std::vector<NDArray> max_axis(
   return op_.Invoke();
 }
 
-// maximum(x, y)
-inline std::vector<NDArray> maximum(
-    const NDArray &x, const NDArray &y,
-    const std::vector<std::pair<std::string, std::string>> &attrs = {}) {
-  Operator op_("maximum");
-  op_.AddInput(x);
-  op_.AddInput(y);
-  for (const auto &kv : attrs) op_.SetParam(kv.first, kv.second);
-  return op_.Invoke();
-}
-
 // mean(data; attrs: axis, keepdims, exclude)
 inline std::vector<NDArray> mean(
     const NDArray &data,
@@ -1264,17 +1253,6 @@ inline std::vector<NDArray> min_axis(
     const std::vector<std::pair<std::string, std::string>> &attrs = {}) {
   Operator op_("min_axis");
   op_.AddInput(data);
-  for (const auto &kv : attrs) op_.SetParam(kv.first, kv.second);
-  return op_.Invoke();
-}
-
-// minimum(x, y)
-inline std::vector<NDArray> minimum(
-    const NDArray &x, const NDArray &y,
-    const std::vector<std::pair<std::string, std::string>> &attrs = {}) {
-  Operator op_("minimum");
-  op_.AddInput(x);
-  op_.AddInput(y);
   for (const auto &kv : attrs) op_.SetParam(kv.first, kv.second);
   return op_.Invoke();
 }
