@@ -1,0 +1,568 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the main path runs on the chip.
+
+    python3 chip_smoke.py
+
+One process, seeded synthetic data, no network.  It drives what a user
+would: a Gluon ``gpt2_small`` (12 layers, 768 wide, vocab 50,257,
+T = 1,024, bf16, Pallas flash attention) placed on ``mx.tpu(0)``, trained
+by ``Trainer.train_step`` (the captured whole-step program), then served
+by ``ServingEngine`` + ``ContinuousBatcher``; with four chips, the same
+step under ``shard_model`` fsdp and tp.  Phases, in order: device, sync,
+kernel, train, serve, sharded.  The first failed check raises and the
+process exits non-zero; the last line of stdout is the JSON result only
+when every phase passed.
+
+``__main__`` always demands platform ``tpu``.  The phase functions take a
+`Size` and the required platform so that tests/test_chip_smoke.py can
+drive them tiny on the CPU.  Timings printed here are compile and
+wall-clock seconds of a smoke run — set-up facts, not performance
+figures.
+"""
+
+import dataclasses
+import gc
+import json
+import math
+import sys
+import threading
+import time
+
+import numpy as np
+
+
+class SmokeError(RuntimeError):
+    """A phase's check did not hold."""
+
+
+def require(cond, msg):
+    if not cond:
+        raise SmokeError(msg)
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+@dataclasses.dataclass(frozen=True)
+class Size:
+    model: str              # factory in gluon.model_zoo.gpt
+    batch: int
+    steps: int
+    sharded_steps: int
+    kernel_shapes: tuple    # ((B, H, T, D, causal), ...)
+    sync: tuple             # (n, matmuls per call, calls)
+    batch_buckets: tuple
+    prefill_floor: int
+    prompt_lens: tuple      # one request each, mixed prefill buckets
+    new_tokens: int
+    lr: float = 3e-4
+
+
+FULL = Size(
+    model="gpt2_small", batch=8, steps=5, sharded_steps=3,
+    # BERT-base's recorded shape (b32 x 12 heads, T512), then this
+    # model's own training shape
+    kernel_shapes=((32, 12, 512, 64, False), (8, 12, 1024, 64, True)),
+    sync=(4096, 16, 8),
+    batch_buckets=(1, 4), prefill_floor=128,
+    prompt_lens=(5, 17, 60, 128, 200, 33), new_tokens=8)
+
+# the two timings of the sync phase may differ by this factor
+SYNC_FACTOR = 2.0
+# max |kernel - dense| / max |dense| on bf16 operands (both sides round
+# probabilities to bf16 for the value matmul)
+KERNEL_TOL_FWD = 2e-2
+KERNEL_TOL_BWD = 4e-2
+# |first loss - ln(vocab)| at Xavier init
+INIT_LOSS_TOL = 0.5
+# sharded vs single-chip step-0 loss, bf16 activations reduced in another
+# order
+SHARDED_LOSS_TOL = 5e-2
+
+
+def on_platform(arr, platform):
+    return all(d.platform == platform for d in arr.devices())
+
+
+# seconds of every XLA backend compile (or persistent-cache load) this
+# process has made, appended by a jax.monitoring listener
+COMPILES = []
+_watching = False
+
+
+def watch_compiles():
+    """Start recording into COMPILES (once: jax.monitoring listeners
+    cannot be removed)."""
+    global _watching
+    import jax
+
+    def on_event(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            COMPILES.append(secs)
+
+    if not _watching:
+        _watching = True
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+
+
+# -- device --------------------------------------------------------------------
+
+def phase_device(platform):
+    from importlib import metadata
+
+    import jax
+    import jaxlib
+
+    devs = jax.devices()
+    info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    say(f"[device] platform={info['platform']} kind={info['kind']!r} "
+        f"count={info['count']} local={jax.local_device_count()} "
+        f"jax={jax.__version__} jaxlib={jaxlib.__version__} "
+        f"libtpu={libtpu} python={sys.version.split()[0]}")
+    require(info["platform"] == platform,
+            f"device: JAX runs on {info['platform']!r}, "
+            f"{platform!r} required")
+    return info
+
+
+# -- sync ----------------------------------------------------------------------
+
+def phase_sync(size, platform):
+    """Does `block_until_ready` alone wait for the device?  The same
+    chained bf16 matmul is timed ended by block_until_ready and ended by
+    a host readback; the block_until_ready timing runs FIRST, before
+    this process has read anything back."""
+    import jax
+    import jax.numpy as jnp
+
+    n, chain, calls = size.sync
+
+    @jax.jit
+    def f(a, b):
+        for _ in range(chain):
+            a = (a @ b) * (1.0 / n)     # keeps bf16 finite down the chain
+        return jnp.sum(a.astype(jnp.float32))
+
+    ka, kb = jax.random.split(jax.random.key(0))
+    a = jax.random.normal(ka, (n, n), jnp.bfloat16)
+    b = jax.random.normal(kb, (n, n), jnp.bfloat16)
+    require(on_platform(a, platform), "sync: operands off the device")
+    f(a, b).block_until_ready()         # compile
+
+    def timed(finish):
+        best = math.inf
+        for _ in range(3):
+            t0 = time.perf_counter()
+            out = None
+            for _ in range(calls):
+                out = f(a, b)
+            finish(out)
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    t_block = timed(lambda out: out.block_until_ready())
+    t_read = timed(np.asarray)
+    ratio = max(t_block, t_read) / max(min(t_block, t_read), 1e-9)
+    say(f"[sync] {calls} calls x {chain} matmuls n={n}: "
+        f"block_until_ready {t_block:.4f}s, readback {t_read:.4f}s, "
+        f"ratio {ratio:.2f} (limit {SYNC_FACTOR})")
+    require(ratio <= SYNC_FACTOR,
+            f"sync: block_until_ready ({t_block:.4f}s) and readback "
+            f"({t_read:.4f}s) timings disagree by {ratio:.2f}x")
+    return {"block_until_ready_s": t_block, "readback_s": t_read}
+
+
+# -- kernel --------------------------------------------------------------------
+
+def phase_kernel(size, platform):
+    """flash_attention forward and backward against the dense oracle,
+    compiled (never interpreted) when the platform is tpu."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    require(pa._use_interpret() == (platform == "cpu"),
+            f"kernel: interpret mode is {pa._use_interpret()} on "
+            f"{platform}")
+    out = []
+    for B, H, T, D, causal in size.kernel_shapes:
+        keys = jax.random.split(jax.random.key(T), 4)
+        q, k, v, w = (jax.random.normal(kk, (B, H, T, D), jnp.bfloat16)
+                      for kk in keys)
+        scale = D ** -0.5
+
+        def flash(q, k, v):
+            return pa.flash_attention(q, k, v, causal=causal)
+
+        def dense(q, k, v):
+            return pa._dense_ref(q, k, v, causal, scale)
+
+        def grads_of(fn):
+            def loss(q, k, v):
+                return jnp.sum(fn(q, k, v).astype(jnp.float32)
+                               * w.astype(jnp.float32))
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+        t0 = time.perf_counter()
+        fwd = jax.jit(flash).lower(q, k, v)
+        if platform == "tpu":
+            require("tpu_custom_call" in fwd.as_text(),
+                    "kernel: no Mosaic custom call in the lowered "
+                    "forward")
+        o = fwd.compile()(q, k, v)
+        g = grads_of(flash)(q, k, v)
+        jax.block_until_ready((o, g))
+        secs = time.perf_counter() - t0
+        o_ref = jax.jit(dense)(q, k, v)
+        g_ref = grads_of(dense)(q, k, v)
+
+        def err(a, b):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            require(np.isfinite(a).all(), "kernel: non-finite output")
+            return float(np.abs(a - b).max() / np.abs(b).max())
+
+        e_fwd = err(o, o_ref)
+        e_bwd = [err(a, b) for a, b in zip(g, g_ref)]
+        say(f"[kernel] ({B}x{H}, T{T}, D{D}) causal={causal}: compile+run "
+            f"{secs:.1f}s, fwd err {e_fwd:.4f} (tol {KERNEL_TOL_FWD}), "
+            f"dq/dk/dv err {e_bwd[0]:.4f}/{e_bwd[1]:.4f}/{e_bwd[2]:.4f} "
+            f"(tol {KERNEL_TOL_BWD})")
+        require(on_platform(o, platform), "kernel: output off the device")
+        require(e_fwd <= KERNEL_TOL_FWD,
+                f"kernel: forward off the dense oracle by {e_fwd}")
+        require(max(e_bwd) <= KERNEL_TOL_BWD,
+                f"kernel: backward off the dense oracle by {e_bwd}")
+        out.append({"shape": [B, H, T, D], "causal": causal,
+                    "compile_run_s": secs, "fwd_err": e_fwd,
+                    "bwd_err": e_bwd})
+    return out
+
+
+# -- train ---------------------------------------------------------------------
+
+def _ctx_for(platform):
+    import mxnet_tpu as mx
+
+    return mx.cpu(0) if platform == "cpu" else mx.tpu(0)
+
+
+def _build(size, platform, seed=0):
+    """Seeded net + trainer + one fixed batch of the learnable corpus of
+    examples/gpt_pretrain_sharded.py (tok[t+1] = perm[tok[t]])."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon.model_zoo import gpt
+
+    ctx = _ctx_for(platform)
+    mx.random.seed(seed)
+    np.random.seed(seed)
+    net = getattr(gpt, size.model)(scan_layers=True,
+                                   attention_impl="flash", dropout=0.0)
+    net.initialize(init=mx.init.Xavier(), ctx=ctx)
+    net.cast("bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "adamw",
+                            {"learning_rate": size.lr})
+    vocab, seq = net._vocab, net._max_length
+    rng = np.random.RandomState(seed)
+    perm = rng.permutation(vocab)
+    seqs = [rng.randint(0, vocab, (size.batch,))]
+    for _ in range(seq - 1):
+        seqs.append(perm[seqs[-1]])
+    ids = np.stack(seqs, axis=1).astype(np.float32)
+    return net, trainer, mx.nd.array(ids, ctx=ctx)
+
+
+def _state_arrays(trainer):
+    """Every optimizer-state array the trainer holds."""
+    from mxnet_tpu.ndarray.ndarray import NDArray
+
+    def walk(s):
+        if isinstance(s, NDArray):
+            yield s._data
+        elif isinstance(s, (list, tuple)):
+            for x in s:
+                yield from walk(x)
+
+    for upd in trainer._updaters:
+        for st in upd.states.values():
+            yield from walk(st)
+
+
+def _run_steps(tag, net, trainer, ids, steps):
+    """`steps` x trainer.train_step on one batch; asserts the captured
+    path took every step with ONE capture and ONE trace."""
+    from mxnet_tpu.gluon import captured
+    from mxnet_tpu.gluon.model_zoo import gpt
+    from mxnet_tpu.optimizer import grouped
+
+    require(steps >= 2, f"{tag}: two steps at least, to see that the "
+                        "second compiles nothing")
+    loss_fn = gpt.GPTLMLoss()
+    watch_compiles()
+    captured.reset_counters()
+    grouped.reset_dispatch_count()
+    losses, secs = [], []
+    last = None
+    for step in range(steps):
+        if step == 1:
+            compiles_after_step0 = len(COMPILES)
+        t0 = time.perf_counter()
+        last = trainer.train_step(net, loss_fn, ids, ids)
+        losses.append(float(np.asarray(last._data, np.float32)))
+        secs.append(time.perf_counter() - t0)
+    late = len(COMPILES) - compiles_after_step0
+    stats = captured.cache_stats()
+    say(f"[{tag}] losses {' '.join(f'{v:.4f}' for v in losses)}; first "
+        f"step (trace+compile+run) {secs[0]:.1f}s, later steps "
+        f"{' '.join(f'{s:.2f}' for s in secs[1:])}s; captures "
+        f"{stats['misses']}, traces {captured.trace_count()}, XLA "
+        f"compiles after step 0: {late}")
+    require(late == 0,
+            f"{tag}: {late} XLA compile(s) after step 0 — the step "
+            "program must compile once")
+    require(captured.get_step(trainer, net, loss_fn, ids, ids, 1)
+            is not None, f"{tag}: the step is not capturable")
+    require(captured.dispatch_count() == steps
+            and grouped.dispatch_count() == 0,
+            f"{tag}: {captured.dispatch_count()} captured and "
+            f"{grouped.dispatch_count()} eager dispatches in {steps} "
+            "steps")
+    require(stats["misses"] == 1 and captured.trace_count() == 1,
+            f"{tag}: {stats['misses']} captures, "
+            f"{captured.trace_count()} traces (1 each expected)")
+    require(all(math.isfinite(v) for v in losses),
+            f"{tag}: non-finite loss in {losses}")
+    return losses, secs, last
+
+
+def phase_train(size, platform):
+    net, trainer, ids = _build(size, platform)
+    losses, secs, last = _run_steps("train", net, trainer, ids, size.steps)
+    uniform = math.log(net._vocab)
+    require(abs(losses[0] - uniform) <= INIT_LOSS_TOL,
+            f"train: first loss {losses[0]:.4f} is not within "
+            f"{INIT_LOSS_TOL} of ln(vocab) = {uniform:.4f}")
+    require(losses[-1] < losses[0],
+            f"train: loss did not fall ({losses[0]} -> {losses[-1]})")
+    params = [p.data()._data for p in net.collect_params().values()]
+    states = list(_state_arrays(trainer))
+    require(states, "train: the trainer holds no optimizer state")
+    for what, arrs in (("parameter", params), ("optimizer state", states),
+                       ("loss", [last._data])):
+        require(all(on_platform(a, platform) for a in arrs),
+                f"train: a {what} is not on a {platform} device")
+    dev = _ctx_for(platform).jax_device
+    stats = dev.memory_stats()
+    require(stats is not None or platform == "cpu",
+            "train: the device reports no memory stats")
+    peak = stats["peak_bytes_in_use"] if stats else None
+    say(f"[train] {len(params)} parameters + {len(states)} optimizer "
+        f"states + loss on {platform}; peak bytes in use {peak}")
+    return {"net": net, "losses": losses, "first_step_s": secs[0],
+            "peak_bytes": peak}
+
+
+# -- serve ---------------------------------------------------------------------
+
+def phase_serve(size, platform, net):
+    import jax.numpy as jnp
+
+    from mxnet_tpu import serving
+
+    vocab = net._vocab
+    t0 = time.perf_counter()
+    engine = serving.ServingEngine(
+        net, batch_buckets=size.batch_buckets,
+        prefill_floor=size.prefill_floor, dtype=jnp.bfloat16)
+    engine.warmup()
+    warm = time.perf_counter() - t0
+    pinned = serving.trace_count()
+    say(f"[serve] warmup: {engine.program_count()} AOT programs in "
+        f"{warm:.1f}s (batch {engine.batch_buckets} x prefill "
+        f"{engine.prefill_buckets} + decode)")
+    ck, cv = engine.init_cache(1)
+    require(all(on_platform(a, platform)
+                for a in engine._weights + (ck, cv)),
+            f"serve: weights or cache not on a {platform} device")
+    del ck, cv
+
+    rng = np.random.RandomState(1)
+    prompts = [rng.randint(0, vocab, n).tolist()
+               for n in size.prompt_lens]
+    n_clients = 2
+    batcher = serving.ContinuousBatcher(
+        engine, max_delay_ms=20.0, max_batch=max(size.batch_buckets))
+    results, errors = [None] * len(prompts), []
+
+    def client(idx):
+        try:
+            for j in range(idx, len(prompts), n_clients):
+                results[j] = batcher.submit(
+                    prompts[j], size.new_tokens).result(timeout=300)
+        except BaseException as exc:    # re-raised on the main thread
+            errors.append(exc)
+
+    t0 = time.perf_counter()
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(n_clients)]
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=600)
+    finally:
+        batcher.close()
+    require(not any(th.is_alive() for th in threads),
+            "serve: a client thread did not finish")
+    if errors:
+        raise errors[0]
+    for j, rec in enumerate(results):
+        toks = None if rec is None else rec["tokens"]
+        require(toks is not None and len(toks) == size.new_tokens
+                and all(0 <= int(t) < vocab for t in toks),
+                f"serve: request {j} resolved to {toks}")
+    say(f"[serve] {len(prompts)} requests (prompt lengths "
+        f"{size.prompt_lens}) from {n_clients} clients in "
+        f"{time.perf_counter() - t0:.2f}s, "
+        f"{batcher.groups_served} groups, buckets "
+        f"{sorted({tuple(r['bucket']) for r in results})}")
+
+    # a coalesced group == the same prompts one by one through the SAME
+    # (batch, prefill) bucket, bitwise; a lone prompt would otherwise
+    # pick the smallest batch bucket, a different program
+    big = max(size.batch_buckets)
+    group = [p for p in prompts
+             if len(p) <= engine.prefill_buckets[0]][:big]
+    require(len(group) >= 2, "serve: size gives no group to coalesce")
+    together, timing = engine.serve_group(group, size.new_tokens)
+    engine.batch_buckets = (big,)
+    try:
+        alone = [engine.serve_group([p], size.new_tokens)
+                 for p in group]
+    finally:
+        engine.batch_buckets = tuple(sorted(size.batch_buckets))
+    for j, (a, (b, tm)) in enumerate(zip(together, alone)):
+        require(tm["bucket"] == timing["bucket"],
+                f"serve: buckets differ {tm['bucket']} {timing['bucket']}")
+        require(np.array_equal(a, b[0]),
+                f"serve: prompt {j} coalesced {a} != alone {b[0]}")
+    again = engine.serve_group([prompts[0]], size.new_tokens)[0][0]
+    once = engine.serve_group([prompts[0]], size.new_tokens)[0][0]
+    require(np.array_equal(again, once),
+            f"serve: a repeated request differs: {again} {once}")
+    require(serving.trace_count() == pinned,
+            f"serve: {serving.trace_count() - pinned} retraces after "
+            "warmup")
+    say(f"[serve] coalesced == one-by-one through bucket "
+        f"{timing['bucket']} for {len(group)} prompts, repeat "
+        f"identical, 0 retraces after warmup")
+    return {"warmup_s": warm, "programs": engine.program_count()}
+
+
+# -- sharded -------------------------------------------------------------------
+
+SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
+
+
+def phase_sharded(size, platform, single_loss0, single_peak):
+    """The train path again under shard_model, on four devices: same
+    seed, so step 0 must reproduce the single-chip loss."""
+    import jax
+
+    from mxnet_tpu import parallel
+
+    devs = jax.devices()[:4]
+    out = []
+    try:
+        for axes, mode in SHARDED_LAYOUTS:
+            tag = f"sharded {mode} {axes}"
+            net, trainer, ids = _build(size, platform)
+            mesh = parallel.make_mesh(axes=axes, devices=devs)
+            parallel.shard_model(net, mesh, mode=mode)
+            losses, secs, _ = _run_steps(tag, net, trainer, ids,
+                                         size.sharded_steps)
+            for name, p in net.collect_params().items():
+                n = len(p.data()._data.sharding.device_set)
+                require(n == 4, f"{tag}: {name} spans {n} device(s)")
+            spec = ids._data.sharding.spec
+            require(len(spec) > 0 and spec[0] == "dp",
+                    f"{tag}: the batch is laid out {spec}, not split on "
+                    "dp")
+            require(abs(losses[0] - single_loss0) <= SHARDED_LOSS_TOL,
+                    f"{tag}: step-0 loss {losses[0]} vs single-chip "
+                    f"{single_loss0}")
+            out.append({"mode": mode, "axes": axes, "losses": losses,
+                        "first_step_s": secs[0]})
+            del net, trainer, ids
+            gc.collect()
+    finally:
+        parallel.set_default_mesh(None)
+    # peak_bytes_in_use is a high-water mark since process start: device
+    # 0 still carries the single-chip phases, so the other three speak
+    # for the sharded runs
+    stats = [d.memory_stats() for d in devs]
+    require(all(s is not None for s in stats) or platform == "cpu",
+            "sharded: a device reports no memory stats")
+    if all(s is not None for s in stats):
+        peaks = [s["peak_bytes_in_use"] for s in stats]
+        say(f"[sharded] peak bytes in use per device {peaks}; "
+            f"single-chip peak {single_peak}")
+        rest = peaks[1:]
+        require(max(rest) <= 2 * min(rest),
+                f"sharded: peaks differ in order across chips: {peaks}")
+        require(max(rest) < single_peak,
+                f"sharded: per-chip peak {max(rest)} is not below the "
+                f"single-chip peak {single_peak}")
+    return out
+
+
+# -- main ----------------------------------------------------------------------
+
+def main():
+    platform = "tpu"    # not configurable: this script proves the chip
+    t_start = time.perf_counter()
+    timings = {}
+
+    def run(name, fn, *args):
+        t0, c0 = time.perf_counter(), len(COMPILES)
+        res = fn(*args)
+        timings[name] = {
+            "seconds": round(time.perf_counter() - t0, 1),
+            "compiles": len(COMPILES) - c0,
+            "compile_seconds": round(sum(COMPILES[c0:]), 1)}
+        say(f"[{name}] ok: {json.dumps(timings[name])}")
+        return res
+
+    device = run("device", phase_device, platform)
+    watch_compiles()
+    from mxnet_tpu import engine
+
+    say(f"[device] compile cache: {engine.ensure_compile_cache()}")
+    run("sync", phase_sync, FULL, platform)
+    run("kernel", phase_kernel, FULL, platform)
+    train = run("train", phase_train, FULL, platform)
+    run("serve", phase_serve, FULL, platform, train.pop("net"))
+    gc.collect()
+    import jax
+
+    if jax.local_device_count() >= 4:
+        run("sharded", phase_sharded, FULL, platform,
+            train["losses"][0], train["peak_bytes"])
+    else:
+        say(f"[sharded] skipped: {jax.local_device_count()} local "
+            "device(s), four needed")
+    say(json.dumps({"phases": timings, "total_seconds": round(
+        time.perf_counter() - t_start, 1)}))
+    say(json.dumps({"ok": True, "device": device}))
+
+
+if __name__ == "__main__":
+    main()

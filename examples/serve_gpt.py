@@ -37,12 +37,13 @@ def corpus():
     return codecs.decode(this_mod.s, "rot13")
 
 
-def train(net, loss_fn, trainer, data, steps, rs, seq_len=16, batch=16):
+def train(net, loss_fn, trainer, data, steps, rs, ctx, seq_len=16,
+          batch=16):
     last = None
     for _ in range(steps):
         starts = rs.randint(0, len(data) - seq_len - 1, batch)
         ids = nd.array(np.stack([data[s:s + seq_len] for s in starts])
-                       .astype(np.float32))
+                       .astype(np.float32), ctx=ctx)
         with autograd.record():
             loss = loss_fn(net(ids), ids)
         loss.backward()
@@ -64,12 +65,18 @@ def main(steps=30, requests=8, new_tokens=8, seed=0):
     # layout and the serving checkpoint convention (docs/serving.md)
     net = gpt.gpt_tiny(vocab_size=len(vocab), max_length=16,
                        scan_layers=True)
-    net.initialize(init=mx.init.Xavier())
+    # a model lives where its ctx says: the TPU when the process has
+    # one, and training batches and the serving engine follow it there
+    ctx = mx.tpu(0) if mx.num_tpus() > 0 else mx.cpu(0)
+    dev = ctx.jax_device
+    print(f"training and serving on {ctx}: {dev.device_kind} "
+          f"({dev.platform})")
+    net.initialize(init=mx.init.Xavier(), ctx=ctx)
     loss_fn = gpt.GPTLMLoss()
     trainer = gluon.Trainer(net.collect_params(), "adam",
                             {"learning_rate": 3e-3})
 
-    loss0 = train(net, loss_fn, trainer, data, steps, rs)
+    loss0 = train(net, loss_fn, trainer, data, steps, rs, ctx)
     print(f"trained {steps} steps, loss {loss0:.3f}")
 
     ckdir = tempfile.mkdtemp(prefix="serve_gpt_")
@@ -107,7 +114,7 @@ def main(steps=30, requests=8, new_tokens=8, seed=0):
     serve_round("serve v1")
 
     # keep training; commit; the replica hot-swaps between batches
-    loss1 = train(net, loss_fn, trainer, data, steps, rs)
+    loss1 = train(net, loss_fn, trainer, data, steps, rs, ctx)
     ck.save(2, serving.state_for_serving(net))
     ck.wait()
     ck.close()

@@ -8,11 +8,8 @@ treat ANY malformed line — torn tail from a crash mid-write, bit-rot,
 stale schema — as absent-with-a-logged-event (``tune_db_fallback``),
 never as a crash.  Stale-version entries are GC'd on the next write.
 
-Location: ``MXTPU_TUNE_DB`` when set, else ``tune_db.jsonl`` next to
-the persistent XLA compile cache (``MXTPU_COMPILE_CACHE_DIR``) — the
-two caches answer the same question ("have I seen this program
-before?") and travel together across restarts.  Neither set → no
-persistence (search still runs, winners just aren't replayable).
+Location: ``MXTPU_TUNE_DB``.  Unset → no persistence (search still
+runs, winners just aren't replayable).
 
 Entries are keyed by (capture signature, device kind, mesh shape): a
 config tuned on the CPU test mesh never replays on a TPU slice, and a
@@ -30,13 +27,7 @@ DB_VERSION = 1
 
 def tune_db_path():
     """The database file path, or None when persistence is off."""
-    p = os.environ.get("MXTPU_TUNE_DB")
-    if p:
-        return p
-    cache = os.environ.get("MXTPU_COMPILE_CACHE_DIR")
-    if cache:
-        return os.path.join(cache, "tune_db.jsonl")
-    return None
+    return os.environ.get("MXTPU_TUNE_DB") or None
 
 
 def entry_key(signature, device_kind, mesh_shape):

@@ -33,56 +33,51 @@ def set_engine_type(name: str) -> None:
     _NAIVE = name.lower() == "naiveengine"
 
 
-_COMPILE_CACHE_DIR = None
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+_CACHE_CONFIGURED = False
 
 
-def ensure_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at
-    ``MXTPU_COMPILE_CACHE_DIR`` (idempotent; returns the directory, or
-    None when the env var is unset).
+def ensure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache — the one place this
+    repository configures it (idempotent; returns the directory).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is set here.  Otherwise the directory is
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part
+    of the cache key and a directory that moves never hits.  There the
+    CPU backend persists nothing: its compiles are short, and XLA:CPU
+    (jaxlib 0.9.0) logs a machine-feature error on every cache load.
+    Set the variable to cache CPU programs too.
 
     The whole-step capture (`gluon.captured`) compiles ONE large XLA
-    program per training configuration; on a restart after preemption
-    the retrace is unavoidable but the XLA compile — the expensive half
-    — need not be.  With the cache dir set, a restarted worker's
-    first-step latency drops to trace + cache-deserialize (bench.py's
-    ``restart_first_step_ms`` measures exactly this).  Thresholds are
-    zeroed so even small programs (the eager oracle's per-group
-    updates) persist.
+    program per training configuration; a restarted process re-traces
+    it but deserializes the executable instead of recompiling.
+    Thresholds are zeroed so small programs (parameter init, the eager
+    oracle's per-group updates) persist too.
     """
-    global _COMPILE_CACHE_DIR
-    cache_dir = os.environ.get("MXTPU_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return None
-    if _COMPILE_CACHE_DIR == cache_dir:
-        return cache_dir
+    global _CACHE_CONFIGURED
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (AttributeError, ValueError):
-        pass  # older jax: defaults still persist the big programs
-    try:
-        # enable for all backends (by default jax only persists for
-        # TPU/GPU; the CPU-fallback bench path wants it too)
-        jax.config.update("jax_persistent_cache_enable_xla_caches",
-                          "all")
-    except (AttributeError, ValueError):
-        pass
-    try:
-        # the cache module latches its enabled/dir decision at the FIRST
-        # compile; anything already compiled (e.g. parameter init ops
-        # before the Trainer existed) froze it — reset so the next
-        # compile re-reads the config and starts persisting
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:
-        pass  # cache is best-effort; compilation still works without
-    _COMPILE_CACHE_DIR = cache_dir
-    return cache_dir
+    if _CACHE_CONFIGURED:
+        return jax.config.jax_compilation_cache_dir
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          _CHECKOUT_CACHE_DIR)
+        if jax.default_backend() == "cpu":
+            jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the cache module latches its enabled/dir decision at the FIRST
+    # compile; anything already compiled (parameter init ops before the
+    # Trainer existed) froze it — reset so the next compile re-reads
+    # the config and starts persisting
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+    _CACHE_CONFIGURED = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def maybe_sync(arr):

@@ -195,22 +195,6 @@ def _raw(x):
     return getattr(x, "_data", x)
 
 
-def _arg_specs_of(args):
-    """Abstract (shape, dtype) skeleton of one dispatch's arguments —
-    enough to re-lower the program for cost analysis after the real
-    buffers were donated.  Returns None when any leaf lacks an aval."""
-    import jax
-    import numpy as _np
-
-    try:
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(
-                _np.shape(a), getattr(a, "dtype", _np.asarray(a).dtype)),
-            args)
-    except Exception:
-        return None
-
-
 def ineligible_reason(trainer, block, loss_fn, data, grad_accum):
     """Why this (trainer, block, loss) combination cannot be captured,
     or None when it can.  Cheap checks only — group planning happens in
@@ -273,7 +257,13 @@ def _mesh_sharding_of(trainer):
         raw = getattr(getattr(p, "_data", None), "_data", None)
         sh = getattr(raw, "sharding", None)
         if isinstance(sh, NamedSharding):
-            fp.append((i, str(sh.spec)))
+            # the program hands a donated (None, 'dp', None) param back
+            # as (None, 'dp'): the same placement, so trailing Nones stay
+            # out of the key or step 1 would capture the step again
+            spec = tuple(sh.spec)
+            while spec and spec[-1] is None:
+                spec = spec[:-1]
+            fp.append((i, str(spec)))
     return mesh, (tuple(sorted(mesh.shape.items())), tuple(fp))
 
 
@@ -529,12 +519,16 @@ class CapturedStep:
         self._others = [(name, p) for name, p in pairs
                         if id(p) not in trained_ids]
         self._pos = {i: j for j, (i, _p) in enumerate(trained)}
-        # MFU accounting (mxnet_tpu/telemetry.py): arg avals captured on
-        # the first dispatch, cost analysis lowered lazily ONCE per
-        # capture signature — never on the per-step path
-        self._arg_specs = None
+        # the step's executables, compiled ahead of time from the FIRST
+        # dispatch's arguments and called directly afterwards: one
+        # capture is one XLA compile (jit's own cache would compile the
+        # same trace again when freshly created optimizer state comes
+        # back committed after step 0).  Keyed by the static ``attest``
+        # flag — None when the program has no fingerprint output.
+        self._executables = {}
+        # MFU accounting (mxnet_tpu/telemetry.py) reads the dispatch
+        # executable's own analyses, lazily, ONCE per capture signature
         self._flops = _SENTINEL_UNSET
-        self._compiled = _SENTINEL_UNSET
         self._collective_bytes = _SENTINEL_UNSET
         self._peak_bytes = _SENTINEL_UNSET
         from .. import integrity as _integrity
@@ -920,29 +914,19 @@ class CapturedStep:
         scale = _np.float32(scaler.loss_scale if scaler else 1.0)
         train_raws = [p.data()._data for _i, p in self._trained]
         other_raws = [p.data()._data for _n, p in self._others]
-        if self._arg_specs is None:
-            from .. import telemetry
-
-            if telemetry.enabled():
-                self._arg_specs = _arg_specs_of(
-                    (train_raws, other_raws, state_vals, dyn_list,
-                     xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv))
+        args = (train_raws, other_raws, state_vals, dyn_list,
+                xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv)
         fp = None
         with profiler.annotate("captured_step"):
             if self._want_fp:
                 attest = bool(trainer._integrity_due())
                 (new_train, new_others, new_states, losses, health,
-                 fp) = self._fn(
-                    train_raws, other_raws, state_vals, dyn_list,
-                    xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv,
-                    attest)
+                 fp) = self._executable(args, attest)(*args)
                 if not attest:
                     fp = None
             else:
                 new_train, new_others, new_states, losses, health = \
-                    self._fn(
-                        train_raws, other_raws, state_vals, dyn_list,
-                        xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv)
+                    self._executable(args)(*args)
         _DISPATCH_COUNT += 1
         for (_i, p), nw in zip(self._trained, new_train):
             p.data()._set_data(nw)
@@ -979,32 +963,25 @@ class CapturedStep:
                 _integrity.combine(_np.asarray(fp)))
         return _from_jax(losses)
 
+    def _executable(self, args, attest=None):
+        """The compiled program for this dispatch: lowered and compiled
+        from the first dispatch's real arguments (their placements
+        included), then reused.  Static args are baked in at `lower`
+        and left out of the call."""
+        exe = self._executables.get(attest)
+        if exe is None:
+            lowered = self._fn.lower(*args) if attest is None \
+                else self._fn.lower(*args, attest)
+            exe = self._executables[attest] = lowered.compile()
+        return exe
+
     # -- program accounting (mxnet_tpu/telemetry.py) ----------------------------
 
     def _compiled_for_stats(self):
-        """The compiled step program re-lowered against the recorded
-        arg avals — at most once per capture signature, with no device
-        dispatch and no readback.  The retrace this lowering performs is
-        excluded from `trace_count` (that counter pins RUNTIME
-        retraces).  None when avals are unknown or lowering fails."""
-        global _TRACE_COUNT
-        if self._compiled is _SENTINEL_UNSET:
-            self._compiled = None
-            if self._arg_specs is not None:
-                saved = _TRACE_COUNT
-                try:
-                    # the integrity program carries a trailing static
-                    # attest flag: lower the non-attest specialization
-                    # (the one every steady-state step runs)
-                    args = tuple(self._arg_specs) + (False,) \
-                        if self._want_fp else self._arg_specs
-                    self._compiled = \
-                        self._fn.lower(*args).compile()
-                except Exception:
-                    self._compiled = None
-                finally:
-                    _TRACE_COUNT = saved
-        return self._compiled
+        """The executable every steady-state step runs (under the
+        integrity plane: the non-attest specialization), or None before
+        the first dispatch."""
+        return self._executables.get(False if self._want_fp else None)
 
     def cost_flops(self):
         """Total FLOPs of the compiled step program via XLA cost
@@ -1013,8 +990,9 @@ class CapturedStep:
             from .. import telemetry
 
             compiled = self._compiled_for_stats()
-            self._flops = None if compiled is None \
-                else telemetry.flops_of_compiled(compiled)
+            if compiled is None:
+                return None
+            self._flops = telemetry.flops_of_compiled(compiled)
         return self._flops
 
     def memory_high_water(self):
@@ -1022,19 +1000,18 @@ class CapturedStep:
         (arguments + outputs + XLA temp allocations, donation aliases
         counted once), or None when the compiler doesn't expose it."""
         if self._peak_bytes is _SENTINEL_UNSET:
-            self._peak_bytes = None
             compiled = self._compiled_for_stats()
-            if compiled is not None:
-                try:
-                    ma = compiled.memory_analysis()
-                    total = (int(ma.temp_size_in_bytes)
-                             + int(ma.argument_size_in_bytes)
-                             + int(ma.output_size_in_bytes)
-                             - int(getattr(ma, "alias_size_in_bytes",
-                                           0)))
-                    self._peak_bytes = max(total, 0)
-                except Exception:
-                    self._peak_bytes = None
+            if compiled is None:
+                return None
+            try:
+                ma = compiled.memory_analysis()
+                total = (int(ma.temp_size_in_bytes)
+                         + int(ma.argument_size_in_bytes)
+                         + int(ma.output_size_in_bytes)
+                         - int(getattr(ma, "alias_size_in_bytes", 0)))
+                self._peak_bytes = max(total, 0)
+            except Exception:
+                self._peak_bytes = None
         return self._peak_bytes
 
     def pipeline_stats(self):
@@ -1069,14 +1046,14 @@ class CapturedStep:
         """{axis: bytes-moved-per-device} over the step program's
         collectives (telemetry.collective_bytes_by_axis), or None on a
         single-device capture / when HLO is unavailable."""
+        if self._mesh is None:
+            return None
         if self._collective_bytes is _SENTINEL_UNSET:
-            self._collective_bytes = None
-            if self._mesh is not None:
-                from .. import telemetry
+            from .. import telemetry
 
-                compiled = self._compiled_for_stats()
-                if compiled is not None:
-                    self._collective_bytes = \
-                        telemetry.collective_bytes_by_axis(
-                            compiled, self._mesh)
+            compiled = self._compiled_for_stats()
+            if compiled is None:
+                return None
+            self._collective_bytes = \
+                telemetry.collective_bytes_by_axis(compiled, self._mesh)
         return self._collective_bytes

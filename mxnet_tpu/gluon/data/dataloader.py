@@ -319,14 +319,27 @@ class _MultiWorkerIter:
         # pickled reference to the default fn would drag the whole
         # package (and jax) into every spawned child
         fn = loader._batchify_fn if loader._custom_batchify else None
-        for _ in range(loader._num_workers):
-            p = ctx.Process(
-                target=_shm_worker.worker_loop,
-                args=(loader._dataset, fn, self._slots, self._task_q,
-                      self._result_q),
-                daemon=True)
-            p.start()
-            self._procs.append(p)
+        # a spawned child takes os.environ as it stands at start(): hold
+        # the workers to the host CPU, so that a dataset which builds an
+        # NDArray in a worker cannot claim the chip from under the
+        # trainer (one process per chip).  This process's own JAX read
+        # the variable at import and is not affected.
+        saved = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            for _ in range(loader._num_workers):
+                p = ctx.Process(
+                    target=_shm_worker.worker_loop,
+                    args=(loader._dataset, fn, self._slots, self._task_q,
+                          self._result_q),
+                    daemon=True)
+                p.start()
+                self._procs.append(p)
+        finally:
+            if saved is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = saved
 
     def _push_next(self):
         if self._closed:

@@ -182,8 +182,22 @@ class Parameter:
             import jax.numpy as jnp
 
             data = _from_jax(jnp.asarray(data, dtype=np_dtype(self.dtype)))
+        # a parameter lives where its ctx says; what the initializer
+        # produced sits on JAX's default device.  An accelerator
+        # placement is always committed, so that mixing it with host
+        # arrays raises instead of quietly computing on the host.  On
+        # the host CPU an array already there stays uncommitted:
+        # replicated-by-default flows (a mesh-sharded table next to
+        # plain parameters) lean on that.
+        import jax
+
+        ctx = ctx or current_context()
+        dev = ctx.jax_device
+        if dev.platform != "cpu" or data._data.devices() != {dev}:
+            data._data = jax.device_put(data._data, dev)
+        data._ctx = ctx
         self._data = data
-        self._ctx_list = [ctx or current_context()]
+        self._ctx_list = [ctx]
         if self._grad_req != "null":
             self._init_grad()
 

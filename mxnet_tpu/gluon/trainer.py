@@ -31,7 +31,7 @@ class Trainer:
                  kvstore="device", compression_params=None,
                  update_on_kvstore=None, clip_global_norm=None):
         from .. import engine, obs
-        engine.ensure_compile_cache()  # MXTPU_COMPILE_CACHE_DIR, if set
+        engine.ensure_compile_cache()
         obs.ensure_from_env()          # MXTPU_METRICS_PORT, if set
         if isinstance(params, (dict, ParameterDict)):
             params = list(params.values())

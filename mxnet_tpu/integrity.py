@@ -122,9 +122,9 @@ def peer_timeout(default=5.0) -> float:
 
 def ledger_path():
     """Ledger location: MXTPU_INTEGRITY_LEDGER when set, else
-    ``integrity_ledger.jsonl`` next to the autotune tuning DB (the
-    MXTPU_TUNE_DB dir / MXTPU_COMPILE_CACHE_DIR), else None (ledger
-    off — attestation still works, provenance stamping degrades)."""
+    ``integrity_ledger.jsonl`` next to the autotune tuning DB
+    (MXTPU_TUNE_DB's directory), else None (ledger off — attestation
+    still works, provenance stamping degrades)."""
     p = os.environ.get("MXTPU_INTEGRITY_LEDGER")
     if p:
         return p
@@ -132,9 +132,6 @@ def ledger_path():
     if db:
         return os.path.join(os.path.dirname(db) or ".",
                             "integrity_ledger.jsonl")
-    cache = os.environ.get("MXTPU_COMPILE_CACHE_DIR")
-    if cache:
-        return os.path.join(cache, "integrity_ledger.jsonl")
     return None
 
 

@@ -27,9 +27,10 @@ def scaled_dot_product_attention(query, key, value, mask=None,
                                  _is_training=True):
     """q,k,v: (B, H, T, D).  mask: broadcastable to (B, H, Tq, Tk), 1=keep.
 
-    impl: 'dense' | 'ring' | 'ulysses' | 'flash' (flash falls back to dense
-    off-TPU).  mask/dropout are dense-path features; the sharded/fused
-    impls reject them loudly instead of silently ignoring them.
+    impl: 'dense' | 'ring' | 'ulysses' | 'flash' (the Pallas kernel:
+    compiled on TPU, interpreted on the CPU).  mask/dropout are
+    dense-path features; the sharded/fused impls reject them loudly
+    instead of silently ignoring them.
     """
     if scale is None:
         scale = query.shape[-1] ** -0.5
@@ -48,10 +49,7 @@ def scaled_dot_product_attention(query, key, value, mask=None,
         return ulysses_attention(query, key, value, causal=causal,
                                  scale=scale)
     if impl == "flash":
-        from .pallas_attention import flash_attention
-
-        return flash_attention(query, key, value, causal=causal,
-                               scale=scale)
+        return _flash_on_mesh(query, key, value, causal, scale)
     s = jnp.einsum("bhqd,bhkd->bhqk", query.astype(jnp.float32),
                    key.astype(jnp.float32)) * scale
     if causal:
@@ -66,6 +64,48 @@ def scaled_dot_product_attention(query, key, value, mask=None,
         p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       value.astype(jnp.float32)).astype(query.dtype)
+
+
+def _flash_on_mesh(q, k, v, causal, scale):
+    """The Pallas kernel on each device's own shard.
+
+    A compiled Pallas call is opaque to the SPMD partitioner, and JAX
+    refuses one under a sharded jit on TPU ("Mosaic kernels cannot be
+    automatically partitioned").  Attention is independent per (batch,
+    head), so under
+    the process default mesh (`parallel.shard_model` sets it) the call
+    runs in a `shard_map` with batch over ``dp`` and heads over ``tp``
+    — the layout the Megatron rules give q/k/v anyway — and needs no
+    collective.  An axis that does not divide its dim stays out of the
+    spec (replicated), like `parallel.sharding.constrain`.
+    """
+    from jax.sharding import PartitionSpec
+
+    from ..parallel.mesh import DP, TP, default_mesh
+    from .pallas_attention import _use_interpret, flash_attention
+
+    mesh = default_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal, scale=scale)
+
+    def axis_for(name, dim):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+
+    spec = PartitionSpec(axis_for(DP, q.shape[0]),
+                         axis_for(TP, q.shape[1]), None, None)
+    # interpret-mode pallas_call trips the vma check inside a manual
+    # region (parallel/ring.py has the same switch); on TPU the kernel's
+    # out_shapes carry the vma and the check stays on
+    check = not _use_interpret()
+
+    def local(q, k, v):
+        vma = tuple(jax.typeof(q).vma) if check else ()
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               vma=vma)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=check)(q, k, v)
 
 
 def _split_heads(x, num_heads):

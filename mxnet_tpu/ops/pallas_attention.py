@@ -14,18 +14,21 @@ matrix nor the whole K/V sequence is ever resident:
 - m/l are kept lane-replicated (block_q, 128) in VMEM so the
   online-softmax update is pure elementwise VPU work — the same layout
   trick the production TPU kernels use; the logsumexp persisted to HBM
-  for the backward is narrowed to (B·H, T, 8) (the minimum Mosaic-legal
-  lane tile) and re-broadcast from lane 0 inside the bwd kernels;
+  for the backward is stored TRANSPOSED, (B·H, 8, T): the sequence on
+  the lane axis, 8 sublane copies.  HBM tiles are (8, 128), so this
+  costs 8·T floats per head, where a (T, 8) layout is padded to
+  (T, 128) — 16× (measured on the v5e: 1.05 GB of padding for BERT-base
+  at b32/T512, which alone pushed that step past 16 GB);
 - causal q/kv block pairs above the diagonal skip all compute (pl.when);
 - backward is the FlashAttention-2 recipe: recompute p = exp(s − L) per
   tile; dq accumulates over the kv grid, dk/dv over the q grid; D_i =
   rowsum(dO ∘ O) is computed in-kernel from the O/dO tiles (never
   materialized in HBM).
 
-Off-TPU (tests, CPU mesh) the kernels run in interpret mode, keeping one
-code path.  On TPU, sequence lengths not divisible by 128 fall back to a
-dense XLA path (flash only matters at lengths where T % 128 == 0 is
-free to arrange).
+On the CPU (tests, the virtual mesh) the kernels run in interpret mode,
+keeping one code path.  On TPU they compile through Mosaic, which needs
+128-aligned tiles: a sequence length not divisible by 128 raises, and
+nothing is substituted for the kernel the caller named.
 """
 
 from __future__ import annotations
@@ -40,7 +43,14 @@ _LANE = 128
 
 
 def _use_interpret():
-    return jax.default_backend() != "tpu"
+    """True on the CPU (Pallas interpreter), False on TPU (Mosaic).  Any
+    other backend is an error, not a silent interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            "flash attention has a TPU kernel and a CPU interpreter "
+            f"mode; jax default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _block_sizes(T):
@@ -52,13 +62,25 @@ def _block_sizes(T):
         # 256)
         bq = 256 if T % 256 == 0 else _LANE
         return min(bq, T), _LANE
-    # interpret-mode small/odd shapes; real TPU dispatches dense instead
+    # interpret-mode small/odd shapes; flash_attention refuses them on TPU
     return T, T
 
 
-# lanes of logsumexp/delta actually persisted to HBM between fwd and bwd
-# (sublane-legal minimum; ×8 instead of the kernels' working ×128)
-_LSE_LANES = 8
+# sublane copies of the logsumexp row persisted to HBM between fwd and
+# bwd: (B·H, 8, T), one f32 tile row — the minimum that is not padded
+_LSE_ROWS = 8
+
+
+def _lse_to_rows(lse):
+    """kernel working layout (bq, 128), lane-replicated → the stored
+    (8, bq) block with the sequence on lanes."""
+    return lse.T[:_LSE_ROWS]
+
+
+def _lse_from_rows(rows, n):
+    """stored (8, bq) block → (bq, n), lane-replicated."""
+    bq = rows.shape[1]
+    return _bcast_lanes(jnp.broadcast_to(rows[:1], (_LANE, bq)).T, n)
 
 
 def _bcast_lanes(x, n):
@@ -128,7 +150,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         D = acc_scr.shape[1]
         o_ref[0] = (acc_scr[...] / _bcast_lanes(lsafe, D)).astype(
             o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(lsafe))[:, :_LSE_LANES]
+        lse_ref[0] = _lse_to_rows(m_scr[...] + jnp.log(lsafe))
 
 
 def _sds(shape, dtype, vma):
@@ -165,14 +187,12 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, vma=None):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LSE_LANES),
-                         lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, _LSE_ROWS, block_q),
+                         lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             _sds((B * H, T, D), q.dtype, vma),
-            # logsumexp, ×8 sublane-replicated (narrowest Mosaic-legal
-            # lane tile — ×128 would cost 16× the HBM for no information)
-            _sds((B * H, T, _LSE_LANES), jnp.float32, vma),
+            _sds((B * H, _LSE_ROWS, T), jnp.float32, vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),
@@ -216,7 +236,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref,
                 jnp.int32, s.shape, 1)
             s = jnp.where(qpos >= kpos, s, _NEG)
         bk = s.shape[1]
-        p = jnp.exp(s - _bcast_lanes(lse_ref[0][:, :1], bk))
+        p = jnp.exp(s - _lse_from_rows(lse_ref[0], bk))
         p = jnp.where(s <= _NEG / 2, 0.0, p)
         v = v_ref[0].astype(jnp.float32)
         dp = jax.lax.dot_general(                      # dO · Vᵀ
@@ -266,7 +286,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dk_ref,
                 jnp.int32, s.shape, 1)
             s = jnp.where(qpos >= kpos, s, _NEG)
         bk = s.shape[1]
-        p = jnp.exp(s - _bcast_lanes(lse_ref[0][:, :1], bk))
+        p = jnp.exp(s - _lse_from_rows(lse_ref[0], bk))
         p = jnp.where(s <= _NEG / 2, 0.0, p)
         delta = jnp.sum(g * o, axis=1)[:, None]        # (bq, 1)
         v = v_ref[0].astype(jnp.float32)
@@ -313,8 +333,8 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, scale, block_q,
 
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    lspec = pl.BlockSpec((1, block_q, _LSE_LANES),
-                         lambda b, i, j: (b, i, 0))
+    lspec = pl.BlockSpec((1, _LSE_ROWS, block_q),
+                         lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nk=nk),
@@ -333,8 +353,8 @@ def _flash_bwd_call(q, k, v, out, lse, g, causal, scale, block_q,
     # dkv grid: kv block is the revisited (outer) axis, q streams inner
     qspec2 = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
     kspec2 = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
-    lspec2 = pl.BlockSpec((1, block_q, _LSE_LANES),
-                          lambda b, i, j: (b, j, 0))
+    lspec2 = pl.BlockSpec((1, _LSE_ROWS, block_q),
+                          lambda b, i, j: (b, 0, j))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq),
@@ -367,8 +387,8 @@ def _flash_core(q, k, v, causal, scale, block_q, block_k, vma=()):
 
 
 def _dense_ref(q, k, v, causal, scale):
-    """Dense oracle for tests, and the TPU path for T % 128 != 0 (and
-    the doc of what the kernel computes)."""
+    """Dense oracle for tests (and the doc of what the kernel
+    computes)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -407,9 +427,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if T % _LANE != 0 and not _use_interpret():
-        # TPU lowering needs 128-aligned tiles; short/odd sequences are
-        # exactly where dense XLA attention is fine anyway
-        return _dense_ref(q, k, v, bool(causal), float(scale))
+        raise ValueError(
+            f"flash_attention: sequence length {T} is not "
+            f"{_LANE}-aligned, which the TPU kernel's tiles need; pad "
+            "the sequence or use impl='dense'")
     dbq, dbk = _block_sizes(T)
     bq, bk = int(block_q or dbq), int(block_k or dbk)
     if T % bq or T % bk:

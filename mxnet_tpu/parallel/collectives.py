@@ -20,18 +20,32 @@ from jax.lax import (all_gather, all_to_all, axis_index,  # noqa: F401
                      ppermute, psum, psum_scatter)
 
 
+def pvary(x, axes):
+    """Inside shard_map: mark ``x`` as varying over those mesh ``axes``
+    (one name or several) it does not vary over yet — needed for
+    scan/fori carries whose body mixes in device-dependent values;
+    `lax.pcast` refuses an axis that already varies."""
+    import jax
+
+    if isinstance(axes, str):
+        axes = (axes,)
+    vma = jax.typeof(x).vma
+    missing = tuple(a for a in axes if a not in vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
 @functools.lru_cache(maxsize=None)
 def _allreduce_fn(mesh, axes):
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec
-    from ._compat import shard_map
+    from jax.sharding import PartitionSpec
 
     spec = PartitionSpec(axes)
 
     def inner(x):
         return jax.lax.psum(x, axes)
 
-    smapped = shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec)
+    smapped = jax.shard_map(inner, mesh=mesh, in_specs=spec,
+                            out_specs=spec)
     return jax.jit(smapped)
 
 

@@ -41,7 +41,7 @@ def _pipeline_outs(stage_fn, n_stages, n_micro, axis, params, xs,
     import jax.numpy as jnp
     from jax import lax
 
-    from ._compat import pvary
+    from .collectives import pvary
 
     my_params = jax.tree_util.tree_map(lambda p: p[0], params)
     stage = lax.axis_index(axis)
@@ -173,7 +173,7 @@ def _pipeline_1f1b_grads(stage_apply, epi_loss, n_stages, n_micro, axis,
     import jax.numpy as jnp
     from jax import lax
 
-    from ._compat import pvary
+    from .collectives import pvary
 
     S, M = n_stages, n_micro
     table_f, table_b = tables
@@ -316,8 +316,6 @@ def pipeline_apply(stage_fn, params_stacked, x_micro, mesh=None, axis=PP):
     import jax
     from jax.sharding import PartitionSpec
 
-    from ._compat import shard_map
-
     mesh = mesh or default_mesh()
     if mesh is None:
         raise MXNetError("pipeline_apply needs a mesh")
@@ -336,8 +334,8 @@ def pipeline_apply(stage_fn, params_stacked, x_micro, mesh=None, axis=PP):
         return _pipeline_outs(stage_fn, n_stages, n_micro, axis, params,
                               xs)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
-                   out_specs=xspec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
+                       out_specs=xspec)
     return fn(params_stacked, x_micro)
 
 
@@ -576,8 +574,6 @@ class PipelineTrainer:
 
         from jax.sharding import PartitionSpec
 
-        from ._compat import shard_map
-
         n_trunk = self._n_trunk
         prologue, epilogue = self.prologue, self.epilogue
         pro_ids = list(self._edge_ids["prologue"])
@@ -621,16 +617,16 @@ class PipelineTrainer:
                 local = lambda params, aux_, xs_: _pipeline_outs(
                     stage_fn, n_stages, n_micro, axis, params, xs_,
                     aux=aux_)
-                fn = shard_map(local, mesh=mesh,
-                               in_specs=(pspec_tree, aspec_tree,
-                                         PartitionSpec()),
-                               out_specs=(PartitionSpec(), aspec_tree))
+                fn = jax.shard_map(
+                    local, mesh=mesh,
+                    in_specs=(pspec_tree, aspec_tree, PartitionSpec()),
+                    out_specs=(PartitionSpec(), aspec_tree))
                 return fn(trunk_vals, trunk_aux, xs)
             local = lambda params, xs_: _pipeline_outs(
                 stage_fn, n_stages, n_micro, axis, params, xs_)
-            fn = shard_map(local, mesh=mesh,
-                           in_specs=(pspec_tree, PartitionSpec()),
-                           out_specs=PartitionSpec())
+            fn = jax.shard_map(local, mesh=mesh,
+                               in_specs=(pspec_tree, PartitionSpec()),
+                               out_specs=PartitionSpec())
             return fn(trunk_vals, xs), []
 
         def pure_step(param_vals, opt_state, trunk_aux, pro_aux, epi_aux,
@@ -728,7 +724,7 @@ class PipelineTrainer:
                         (table_f, table_b), params, aux_, epi_, hs_,
                         ys_)
 
-                fn = shard_map(
+                fn = jax.shard_map(
                     local, mesh=mesh,
                     in_specs=(pspec_tree, aspec_tree,
                               [PartitionSpec()] * len(epi_ids),

@@ -23,27 +23,16 @@ import functools
 import jax
 
 from ..base import MXNetError
+from .collectives import pvary as _pvary
 from .mesh import SP, default_mesh
 
 _NEG_INF = -1e30
 
 
-def _pvary(x, axis):
-    """Mark an array as varying over `axis` inside shard_map (needed for
-    scan/fori carries whose body mixes in device-dependent values)."""
-    from ._compat import pvary
-
-    axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    return pvary(x, axes)
-
-
 def _vma_of(x):
-    """The set of mesh axes `x` varies over inside shard_map (empty
-    tuple on pre-vma jax or outside a manual region)."""
-    try:
-        return tuple(jax.typeof(x).vma)
-    except Exception:
-        return ()
+    """The mesh axes `x` varies over inside shard_map (empty outside a
+    manual region)."""
+    return tuple(jax.typeof(x).vma)
 
 
 def _place(mesh, spec, *arrays):
@@ -151,7 +140,7 @@ def _ring_block_fwd(q, k, v, j, i, causal, scale, bq, bk):
     def _call(causal_flag):
         out, lse8 = _flash_call(q, k, v, causal_flag, scale, bq, bk,
                                 vma=vma)
-        return out, lse8[:, :, 0].reshape(B, H, Tq)
+        return out, lse8[:, 0, :].reshape(B, H, Tq)
 
     if not causal:
         return _call(False)
@@ -245,12 +234,12 @@ def _ring_flash_vjp_bwd(axis, p, causal, scale, bq, bk, res, g):
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas_attention import _LSE_LANES
+    from ..ops.pallas_attention import _LSE_ROWS
 
     q, k, v, out, lse = res
     i = lax.axis_index(axis)
     B, H, Tq, D = q.shape
-    lse8 = jnp.tile(lse.reshape(B * H, Tq, 1), (1, 1, _LSE_LANES))
+    lse8 = jnp.tile(lse.reshape(B * H, 1, Tq), (1, _LSE_ROWS, 1))
     vma = _vma_of(q) or axis
     dq = _pvary(jnp.zeros(q.shape, jnp.float32), vma)
     dk_acc = _pvary(jnp.zeros(k.shape, jnp.float32), vma)
@@ -296,7 +285,6 @@ def ring_attention(q, k, v, mesh=None, axis=SP, causal=False, scale=None,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from ._compat import shard_map
     from jax.sharding import NamedSharding, PartitionSpec
 
     from ..ops.pallas_attention import _LANE, _block_sizes, _use_interpret
@@ -367,9 +355,10 @@ def ring_attention(q, k, v, mesh=None, axis=SP, causal=False, scale=None,
     # dynamic_slice vma mismatch (the error message itself prescribes
     # check_vma=False).  On real TPU the Mosaic lowering takes the vma
     # plumbed through _flash_call's out_shapes, so the check stays on.
-    fn = shard_map(local_flash if use_flash else local_dense, mesh=mesh,
-                   in_specs=(spec, spec, spec), out_specs=spec,
-                   check_vma=not (use_flash and _use_interpret()))
+    fn = jax.shard_map(local_flash if use_flash else local_dense,
+                       mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec,
+                       check_vma=not (use_flash and _use_interpret()))
     return _uncommit(fn(q, k, v), eager)
 
 
@@ -382,7 +371,6 @@ def ulysses_attention(q, k, v, mesh=None, axis=SP, causal=False,
     """
     import jax.numpy as jnp
     from jax import lax
-    from ._compat import shard_map
     from jax.sharding import PartitionSpec
 
     import jax
@@ -440,7 +428,7 @@ def ulysses_attention(q, k, v, mesh=None, axis=SP, causal=False,
     # check_vma off only for interpret-mode flash (same jax-internal
     # limitation as the ring path); on TPU the vma plumbs through
     # flash_attention's out_shapes and the check stays on
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec,
-                   check_vma=not (use_flash and _use_interpret()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec,
+                       check_vma=not (use_flash and _use_interpret()))
     return _uncommit(fn(q, k, v), eager)

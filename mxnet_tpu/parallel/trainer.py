@@ -191,7 +191,7 @@ class ShardedTrainer:
         import jax
 
         from .. import engine
-        engine.ensure_compile_cache()  # MXTPU_COMPILE_CACHE_DIR, if set
+        engine.ensure_compile_cache()
         self.block = block
         self.loss_fn = loss_fn
         self.mesh = mesh if mesh is not None else data_parallel_mesh()
@@ -407,7 +407,7 @@ class ShardedTrainer:
         lr = self.optimizer.lr_at(t)
         key = _random.next_key()
         # MXTPU_STEP_TIMEOUT arms a watchdog around the dispatch: a step
-        # wedged inside the runtime (dead tunnel, stuck collective) dumps
+        # wedged inside the runtime (lost device, stuck collective) dumps
         # thread stacks and errors out instead of hanging the driver
         from .. import resilience
 

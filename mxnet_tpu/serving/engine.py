@@ -7,7 +7,7 @@ request path.  Three properties, all pinned by tests/test_serving.py:
 - **Zero retraces after warmup.**  Every (batch bucket × seq bucket)
   pair gets ONE ahead-of-time program via the same
   ``jit(...).lower(*avals).compile()`` path ``CapturedStep`` uses for
-  its cost analysis; requests are padded to the nearest bucket and run
+  the train step; requests are padded to the nearest bucket and run
   through the pre-compiled executable directly — the jit tracing
   machinery is never re-entered on the request path.  A module-level
   trace counter (incremented as a Python side effect inside the traced
@@ -274,7 +274,14 @@ class ServingEngine:
                 f"serving reload: weight mismatch — qkv stack {got} vs "
                 f"compiled {want}; a mismatched swap would force a "
                 f"retrace on the request path")
-        new_w = self._prepare_weights(stacks, lnf, tok, pos)
+        import jax
+
+        # a checkpoint state arrives as host arrays: the swapped-in
+        # weights go where the compiled programs' weights live
+        new_w = tuple(
+            jax.device_put(new, old.sharding) for new, old in
+            zip(self._prepare_weights(stacks, lnf, tok, pos),
+                self._weights))
         for old, new in zip(self._weights, new_w):
             if tuple(old.shape) != tuple(new.shape) \
                     or old.dtype != new.dtype:
@@ -299,18 +306,17 @@ class ServingEngine:
     def init_cache(self, B):
         """Fresh zeroed (ck, cv) for batch bucket B: stage-major
         (L, B, H, W, Dh), serving dtype, head-sharded under tp."""
-        import jax
         import jax.numpy as jnp
 
         tok = self._weights[0]
         shape = (self._L, B, self._H, self._W, self._C // self._H)
-        ck = jnp.zeros(shape, tok.dtype)
-        cv = jnp.zeros(shape, tok.dtype)
-        if self._mesh is not None:
-            ns = self._cache_sharding()
-            ck = jax.device_put(ck, ns)
-            cv = jax.device_put(cv, ns)
-        return ck, cv
+        # committed next to the weights: the engine serves from the
+        # device(s) the model was placed on, never from the process
+        # default
+        where = tok.sharding if self._mesh is None \
+            else self._cache_sharding()
+        return (jnp.zeros(shape, tok.dtype, device=where),
+                jnp.zeros(shape, tok.dtype, device=where))
 
     # -- the traced block step -------------------------------------------------
 
@@ -394,26 +400,29 @@ class ServingEngine:
     def _aval(self, arr):
         import jax
 
-        if self._mesh is None:
-            return jax.ShapeDtypeStruct(tuple(arr.shape), arr.dtype)
         return jax.ShapeDtypeStruct(tuple(arr.shape), arr.dtype,
                                     sharding=arr.sharding)
+
+    def _input_sharding(self):
+        """Where the per-call int inputs go: the weights' own device,
+        or replicated over the mesh."""
+        if self._mesh is None:
+            return self._weights[0].sharding
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(self._mesh, P())
 
     def _int_aval(self, shape):
         import jax
         import numpy as np
 
-        if self._mesh is None:
-            return jax.ShapeDtypeStruct(shape, np.int32)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        return jax.ShapeDtypeStruct(
-            shape, np.int32, sharding=NamedSharding(self._mesh, P()))
+        return jax.ShapeDtypeStruct(shape, np.int32,
+                                    sharding=self._input_sharding())
 
     def _compile(self, B, S):
-        """One donated program for bucket (B, S) via the captured-step
-        AOT path (``lower(*avals).compile()`` — gluon/captured.py's
-        ``_compiled_for_stats`` discipline applied to the request path)."""
+        """One donated program for bucket (B, S), compiled ahead of time
+        (``lower(*avals).compile()``, as gluon/captured.py compiles the
+        train step)."""
         global _COMPILE_COUNT
         import jax
 
@@ -450,19 +459,14 @@ class ServingEngine:
     def _call(self, B, S, ck, cv, pos, toks):
         global _DISPATCH_COUNT
         import jax
-        import jax.numpy as jnp
+        import numpy as np
 
         compiled = self._programs.get((B, S))
         if compiled is None:
             compiled = self._compile(B, S)
-        pos = jnp.asarray(pos, jnp.int32)
-        toks = jnp.asarray(toks, jnp.int32)
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            rep = NamedSharding(self._mesh, P())
-            pos = jax.device_put(pos, rep)
-            toks = jax.device_put(toks, rep)
+        where = self._input_sharding()
+        pos = jax.device_put(np.asarray(pos, np.int32), where)
+        toks = jax.device_put(np.asarray(toks, np.int32), where)
         with _LOCK:
             _DISPATCH_COUNT += 1
         with self._reload_lock:

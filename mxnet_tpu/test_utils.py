@@ -35,6 +35,27 @@ def default_dtype():
     return np.float32
 
 
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def cpu_child_env(devices=1, **extra):
+    """Environment for a child process that runs on the host CPU whatever
+    the parent runs on.  One process holds a chip at a time, so a child
+    that does not need the chip must not initialise the accelerator
+    backend: ``JAX_PLATFORMS=cpu``, with ``devices`` virtual CPU devices.
+
+    The child imports this checkout (PYTHONPATH) and inherits no
+    ``MXTPU_*`` switch from the parent; pass what it needs in ``extra``.
+    """
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("MXTPU_")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = _REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    env.update(extra)
+    return env
+
+
 def _as_np(x):
     if isinstance(x, NDArray):
         return x.asnumpy()

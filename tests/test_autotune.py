@@ -34,7 +34,6 @@ _TRACE_REPORT = os.path.join(_REPO, "tools", "trace_report.py")
 _TUNE_ENVS = [k.env for k in space.KNOBS.values()] + [
     "MXTPU_AUTOTUNE", "MXTPU_TUNE_DB", "MXTPU_TUNE_BUDGET",
     "MXTPU_TUNE_STEPS", "MXTPU_TUNE_SEMANTICS", "MXTPU_FAULT_INJECT",
-    "MXTPU_COMPILE_CACHE_DIR",
 ]
 
 
@@ -193,10 +192,11 @@ def test_db_roundtrip_and_key(tmp_path, monkeypatch):
     assert telemetry.event_counts().get("tune_db_write") == 2
 
 
-def test_db_lives_next_to_compile_cache(tmp_path, monkeypatch):
+def test_db_path_is_its_own_variable(tmp_path, monkeypatch):
+    """The tuning DB is placed by MXTPU_TUNE_DB alone: the compile
+    cache's location (JAX_COMPILATION_CACHE_DIR) does not move it."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert db.tune_db_path() is None            # no persistence configured
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path))
-    assert db.tune_db_path() == str(tmp_path / "tune_db.jsonl")
     monkeypatch.setenv("MXTPU_TUNE_DB", str(tmp_path / "elsewhere.jsonl"))
     assert db.tune_db_path() == str(tmp_path / "elsewhere.jsonl")
 

@@ -1,0 +1,68 @@
+"""chip_smoke.py's phase functions, driven tiny on the CPU.
+
+The script itself always demands a TPU (`main`); what tier-1 can hold
+still is the control flow of each phase and every check in it, at
+`gpt_tiny` size with the Pallas kernels interpreted.
+"""
+
+import json
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+TINY = chip_smoke.Size(
+    model="gpt_tiny", batch=4, steps=5, sharded_steps=2,
+    kernel_shapes=((2, 2, 64, 16, False), (1, 2, 128, 16, True)),
+    sync=(256, 8, 4),
+    batch_buckets=(1, 2), prefill_floor=32,
+    prompt_lens=(3, 9, 20, 40, 33, 5), new_tokens=4)
+
+
+def test_main_refuses_a_cpu(capsys):
+    """No flag or variable relaxes it: without a TPU the run fails in
+    the device phase and prints no result line."""
+    with pytest.raises(chip_smoke.SmokeError, match="'tpu' required"):
+        chip_smoke.main()
+    out = capsys.readouterr().out
+    assert "[device] platform=cpu" in out
+    assert '"ok"' not in out
+
+
+def test_device_phase_reports_what_jax_reports():
+    import jax
+
+    info = chip_smoke.phase_device("cpu")
+    assert info == {"platform": "cpu",
+                    "kind": jax.devices()[0].device_kind,
+                    "count": len(jax.devices())}
+    json.dumps(info)
+
+
+def test_sync_and_kernel_phases():
+    t = chip_smoke.phase_sync(TINY, "cpu")
+    assert t["block_until_ready_s"] > 0 and t["readback_s"] > 0
+    res = chip_smoke.phase_kernel(TINY, "cpu")
+    assert [r["shape"][2] for r in res] == [64, 128]
+    # a phase told to expect the chip refuses the interpreter
+    with pytest.raises(chip_smoke.SmokeError, match="interpret"):
+        chip_smoke.phase_kernel(TINY, "tpu")
+
+
+def test_train_serve_sharded_phases(mesh8):
+    """One model through all three: the captured step (one capture, one
+    trace, loss falling from ln(vocab)), the served tokens (coalesced ==
+    one by one, zero retraces), and the four-device layouts reproducing
+    the single-device step-0 loss."""
+    train = chip_smoke.phase_train(TINY, "cpu")
+    assert len(train["losses"]) == TINY.steps
+    serve = chip_smoke.phase_serve(TINY, "cpu", train.pop("net"))
+    assert serve["programs"] == 2 * 3       # (32, 64, decode) x (1, 2)
+    sharded = chip_smoke.phase_sharded(TINY, "cpu", train["losses"][0],
+                                       train["peak_bytes"])
+    assert [s["mode"] for s in sharded] == ["fsdp", "tp"]

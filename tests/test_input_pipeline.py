@@ -39,6 +39,19 @@ class FailingDataset:
         return np.full(3, i, np.float32)
 
 
+class PlatformProbeDataset:
+    """Module-level (picklable for spawn): each sample says whether the
+    worker that built it was held to the host CPU."""
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        import os
+
+        return np.float32(os.environ.get("JAX_PLATFORMS") == "cpu")
+
+
 def sum_batchify(samples):
     """Module-level custom batchify (picklable for spawn workers)."""
     return np.asarray([float(np.sum(s[0])) for s in samples], np.float32)
@@ -146,6 +159,22 @@ def test_loader_shm_workers_parity():
     _assert_batches_equal(got, want)
     assert not [p for p in multiprocessing.active_children()
                 if p.is_alive()]
+
+
+def test_shm_workers_are_held_to_the_cpu(monkeypatch):
+    """One process per chip: whatever platform the parent runs on, a
+    spawned worker sees JAX_PLATFORMS=cpu, so a dataset that builds an
+    NDArray there cannot claim the accelerator; the parent's own
+    setting comes back once the workers are started."""
+    import os
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    loader = DataLoader(PlatformProbeDataset(), batch_size=4,
+                        num_workers=1, thread_pool=False)
+    with iter(loader) as it:
+        (batch,) = list(it)
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"
+    np.testing.assert_array_equal(batch.asnumpy(), np.ones(4, np.float32))
 
 
 def test_loader_shm_oversize_batch_pickle_fallback(monkeypatch):

@@ -173,6 +173,44 @@ def test_over_budget_transformer_trains_sharded(mesh8, mode, axes):
     assert all(np.isfinite(l).all() for l in losses)
 
 
+# -- the Pallas kernel under a mesh ---------------------------------------------
+
+def test_flash_attention_runs_on_local_shards_under_a_mesh(mesh8):
+    """A compiled Pallas call cannot be partitioned by GSPMD (on TPU it
+    is refused outright): under the default mesh the impl="flash" entry
+    wraps it in a shard_map, batch over dp and heads over tp, so q, k
+    and v reach the kernel still sharded.  Interpret mode on the CPU
+    mesh would partition without it, so the jaxpr is what is checked."""
+    from jax.sharding import PartitionSpec as P
+
+    from mxnet_tpu.ops.attention import scaled_dot_product_attention
+    from mxnet_tpu.ops.pallas_attention import _dense_ref
+
+    parallel.set_default_mesh(mesh8(dp=2, tp=2))
+    rng = np.random.RandomState(0)
+    q, k, v = (jax.numpy.asarray(rng.standard_normal((4, 2, 32, 8)),
+                                 "float32") for _ in range(3))
+
+    def flash(q, k, v):
+        return scaled_dot_product_attention(q, k, v, causal=True,
+                                            impl="flash")
+
+    (eqn,) = [e for e in jax.make_jaxpr(flash)(q, k, v).eqns
+              if e.primitive.name == "shard_map"]
+    spec = P("dp", "tp", None, None)
+    assert tuple(eqn.params["in_specs"]) == (spec,) * 3
+    assert tuple(eqn.params["out_specs"]) == (spec,)
+    out = jax.jit(flash)(q, k, v)
+    assert len(out.sharding.device_set) == 4
+    np.testing.assert_allclose(
+        out, _dense_ref(q, k, v, True, 8 ** -0.5), atol=2e-6)
+    # an axis that does not divide its dim stays out of the spec
+    (eqn,) = [e for e in jax.make_jaxpr(flash)(q[:3], k[:3], v[:3]).eqns
+              if e.primitive.name == "shard_map"]
+    assert tuple(eqn.params["in_specs"]) == \
+        (P(None, "tp", None, None),) * 3
+
+
 # -- captured-path regression discipline at tp>1 -------------------------------
 
 def test_one_dispatch_one_readback_per_step_tp(mesh8, monkeypatch):
@@ -190,9 +228,14 @@ def test_one_dispatch_one_readback_per_step_tp(mesh8, monkeypatch):
                               .astype(np.float32)),
                   mx.nd.array(rng.randint(0, 32, size=(8, 6))
                               .astype(np.float32)))
+    captured.reset_counters()
     for _ in range(2):  # warmup: trace + compile
         x, y = mk()
         tr.train_step(net, loss_fn, x, y)
+    # ONE capture: the placement fingerprint must not change when the
+    # first update hands ('tp', None) params back as ('tp',)
+    assert captured.cache_stats() == {"hits": 1, "misses": 1}
+    assert captured.trace_count() == 1
     captured.reset_counters()
     grouped.reset_dispatch_count()
     numerics.reset_readback_count()

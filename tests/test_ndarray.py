@@ -170,6 +170,23 @@ def test_context_roundtrip():
     assert b is a
 
 
+def test_context_never_names_another_device():
+    """`mx.tpu(i)` is a TPU or an error: no CPU stands in for a missing
+    accelerator, and no id wraps around to an existing device."""
+    import jax
+
+    from mxnet_tpu.base import MXNetError
+
+    for ctx in (mx.tpu(0), mx.gpu(0)):
+        with pytest.raises(MXNetError, match="no accelerator"):
+            ctx.jax_device
+    with pytest.raises(MXNetError, match="out of range"):
+        mx.cpu(len(jax.devices("cpu"))).jax_device
+    with pytest.raises(MXNetError):
+        nd.ones((2, 2), ctx=mx.tpu(0))
+    assert mx.num_tpus() == 0
+
+
 def test_copyto():
     a = nd.ones((2, 2))
     b = nd.zeros((2, 2))

@@ -30,15 +30,22 @@ def main():
     import mxnet_tpu as mx
     print(f"Version      : {mx.__version__}")
     print(f"Import time  : {time.time() - t0:.2f}s")
+    from importlib import metadata
+
     import jax
+    import jaxlib
     print(f"jax          : {jax.__version__}")
+    print(f"jaxlib       : {jaxlib.__version__}")
     try:
-        devs = jax.devices()
-        print(f"Devices      : {[str(d) for d in devs]}")
-        print(f"Backend      : {devs[0].platform}")
-    except Exception as e:
-        print(f"Devices      : unavailable ({type(e).__name__}: {e})")
+        print(f"libtpu       : {metadata.version('libtpu')}")
+    except metadata.PackageNotFoundError:
+        print("libtpu       : not installed")
+    devs = jax.devices()
+    print(f"Platform     : {devs[0].platform}")
+    print(f"Device kind  : {devs[0].device_kind}")
+    print(f"Devices      : {[str(d) for d in devs]}")
     print(f"num_tpus     : {mx.num_tpus()}")
+    print(f"Compile cache: {mx.engine.ensure_compile_cache()}")
 
     print("----------Features----------")
     for feat in mx.runtime.Features().values():

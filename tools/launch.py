@@ -10,6 +10,12 @@ TPU-native redesign (SURVEY.md §2.6): there is no parameter server; a
 dmlc tracker).  Supported launchers: ``local`` (N processes on this host —
 the analog of the reference's fake-multi-node nightly tests) and ``ssh``.
 
+One process holds a TPU host's chips at a time, and every ``local`` rank
+is handed this launcher's full environment.  ``--launcher local`` is
+therefore for CPU gangs (``JAX_PLATFORMS=cpu``, as the tests run it);
+on TPUs use one rank per host (``ssh``).  The launcher itself never
+imports jax, so it holds no chip.
+
 Two supervision modes for ``local``:
 
 - default (gang fate-sharing): one nonzero worker exit tears down the
@@ -34,7 +40,9 @@ import time
 
 
 def _spawn_worker(cmd, rank, num_workers, port, extra_env=None):
-    """Spawn ONE worker with the gang env contract."""
+    """Spawn ONE worker with the gang env contract (plus the launcher's
+    whole environment: on a TPU host N local ranks would contend for
+    the chips — see the module docstring)."""
     env = dict(os.environ)
     env.update({
         "MXTPU_COORDINATOR": f"127.0.0.1:{port}",

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Profile the compiled ResNet-50 / BERT training step on the attached chip.
 
-The instrument behind BASELINE.md's MFU notes (VERDICT r2 item #2): times the
-whole-step program honestly (host-readback terminated — block_until_ready does
-not synchronize on this backend until a readback happens), then dissects the
+The instrument behind BASELINE.md's MFU notes: times the whole-step program
+host-readback terminated (on the installed runtime block_until_ready alone
+waits too — chip_smoke.py's sync phase, docs/perf.md), then dissects the
 optimized HLO: op-category histogram from XLA's cost analysis, transpose/copy
 counts (layout pressure), conv shapes, and the biggest fusions.
 
@@ -100,24 +100,16 @@ def main():
 
     dev = jax.devices()[0]
     print(f"device: {dev.device_kind} ({dev.platform})", file=sys.stderr)
-    if dev.platform != "cpu":
-        cache_dir = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache")
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass
+    from mxnet_tpu import engine
+
+    engine.ensure_compile_cache()
 
     if args.model.startswith("bert"):
         trainer, (x, y), flops = build_bert(args)
     else:
         trainer, (x, y), flops = build_resnet(args)
 
-    # compile + drain (readback = the only real sync on this backend)
+    # compile + drain
     t0 = time.perf_counter()
     np.asarray(trainer.step(x, y)._data)
     print(f"first step (compile): {time.perf_counter() - t0:.1f}s",

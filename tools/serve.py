@@ -2,8 +2,9 @@
 """Serve N requests through the low-latency serving tier, from the CLI.
 
 The smallest end-to-end exercise of mxnet_tpu/serving/: build a model
-zoo decoder, AOT-warm the bucketed programs, optionally hot-load the
-newest committed AsyncCheckpointer manifest, push N random requests
+zoo decoder on the TPU when the process has one (else on the host CPU,
+and it says which), AOT-warm the bucketed programs, optionally hot-load
+the newest committed AsyncCheckpointer manifest, push N random requests
 through the continuous batcher from C concurrent clients, and print a
 latency summary (p50/p99 per stage, tokens/sec, bucket usage).
 
@@ -11,7 +12,8 @@ Stdlib argparse only — the jax-facing imports happen after parsing, so
 ``--help`` works anywhere.
 
 Usage:
-    python tools/serve.py [--ckpt DIR] [--requests 16] [--clients 4]
+    python tools/serve.py [--model gpt_tiny] [--ckpt DIR]
+                          [--requests 16] [--clients 4]
                           [--new-tokens 8] [--buckets 1,2,4]
                           [--max-delay-ms 2.0] [--seed 0]
 """
@@ -26,6 +28,9 @@ import time
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Serve N requests through mxnet_tpu/serving/")
+    ap.add_argument("--model", default="gpt_tiny",
+                    choices=["gpt_tiny", "gpt2_small", "gpt2_medium"],
+                    help="model zoo decoder to serve")
     ap.add_argument("--ckpt", default=None,
                     help="AsyncCheckpointer directory; the newest "
                          "committed manifest is hot-loaded before "
@@ -50,10 +55,16 @@ def main(argv=None):
 
     np.random.seed(args.seed)
     mx.random.seed(args.seed)
-    net = gpt.gpt_tiny(scan_layers=True)
-    net.initialize(init=mx.init.Xavier())
-    net(mx.nd.array(np.random.randint(0, 128, (1, 8))
-                    .astype(np.float32)))
+    # a model lives where its ctx says; the engine serves from there
+    ctx = mx.tpu(0) if mx.num_tpus() > 0 else mx.cpu(0)
+    net = getattr(gpt, args.model)(scan_layers=True, dropout=0.0)
+    net.initialize(init=mx.init.Xavier(), ctx=ctx)
+    vocab = net._vocab
+    net(mx.nd.array(np.random.randint(0, vocab, (1, 8))
+                    .astype(np.float32), ctx=ctx))
+    dev = ctx.jax_device
+    print(f"serving {args.model} from {ctx}: {dev.device_kind} "
+          f"({dev.platform})")
 
     buckets = tuple(sorted({int(b) for b in args.buckets.split(",")}))
     engine = serving.ServingEngine(net, batch_buckets=buckets)
@@ -79,7 +90,7 @@ def main(argv=None):
     rng = np.random.RandomState(args.seed + 1)
     window = engine.prefill_buckets[-1]
     max_prompt = max(2, min(16, window - args.new_tokens))
-    prompts = [rng.randint(0, 128, rng.randint(2, max_prompt + 1))
+    prompts = [rng.randint(0, vocab, rng.randint(2, max_prompt + 1))
                .tolist() for _ in range(args.requests)]
 
     batcher = serving.ContinuousBatcher(
