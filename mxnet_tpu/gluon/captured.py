@@ -127,7 +127,7 @@ def resolve_pp_schedule(mesh, grad_accum, batch):
 # -- accounting (regression-tested) --------------------------------------------
 #
 # dispatch: exactly ONE per captured step.  trace: increments only when
-# jit actually re-traces pure_step (a python side effect in the traced
+# jit actually re-traces train_step (a python side effect in the traced
 # body) — the retrace-regression tests pin this at one per signature.
 # hits/misses: Trainer-level capture-cache stats, reported by bench.py.
 
@@ -658,10 +658,41 @@ class CapturedStep:
                           for n, ov in zip(other_names, others)]
             return loss, gs, new_others
 
-        def pure_step(train_vals, other_vals, state_vals, dyn_list,
-                      xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv):
+        def train_step(train_vals, other_vals, state_vals, dyn_list,
+                       xs, ys, keys_b, keys_l, scale, sp_uniq, sp_inv):
+            # the function's name is the program's: ``jit_train_step``
+            # in the HLO and in every device trace; the three
+            # ``train.*`` scopes below name its parts there (metadata
+            # only)
             global _TRACE_COUNT
             _TRACE_COUNT += 1  # python side effect: fires at trace only
+            with jax.named_scope("train.forward_backward"):
+                losses, grads, new_others = forward_backward(
+                    train_vals, other_vals, xs, ys, keys_b, keys_l,
+                    scale, sp_uniq, sp_inv)
+            if want_guard:
+                with jax.named_scope("train.guard"):
+                    health = guard(train_vals, grads, sp_uniq)
+            else:
+                health = None
+            with jax.named_scope("train.optimizer"):
+                new_train, new_states = optimizer(
+                    train_vals, state_vals, dyn_list, grads, sp_uniq,
+                    health)
+            if train_shs is not None:
+                # pin param/aux outputs to their INPUT shardings: the
+                # donated buffers must round-trip layout-stable or the
+                # next dispatch sees new input shardings and retraces
+                # (sits at the program tail, outside every cut/cond —
+                # no fusion decision changes upstream of it)
+                new_train = [jax.lax.with_sharding_constraint(v, s)
+                             for v, s in zip(new_train, train_shs)]
+                new_others = [jax.lax.with_sharding_constraint(v, s)
+                              for v, s in zip(new_others, other_shs)]
+            return new_train, new_others, new_states, losses, health
+
+        def forward_backward(train_vals, other_vals, xs, ys, keys_b,
+                             keys_l, scale, sp_uniq, sp_inv):
             # sparse tables enter the forward as their PRE-GATHERED
             # unique rows (the out-of-range sentinel id clamps to the
             # last row under mode='clip' — deterministic filler no
@@ -736,21 +767,24 @@ class CapturedStep:
                 # cooldown drain: the last microbatch's grads are still
                 # in flight when the scan ends
                 grads = [cut(a + p) for a, p in zip(acc, pending)]
-            if want_guard:
-                hg = grads
-                if sparse_pos:
-                    # the eager guard reads the DENSE gradient view
-                    # (RowSparseNDArray._data = zeros.at[ids].add(vals),
-                    # its own dispatch): same formula here, with the
-                    # out-of-bounds sentinel rows dropped by the scatter
-                    hg = list(grads)
-                    for j, p in enumerate(sparse_pos):
-                        hg[p] = cut(jnp.zeros(
-                            train_vals[p].shape,
-                            grads[p].dtype).at[sp_uniq[j]].add(grads[p]))
-                health = cut(numerics.health_of(hg))
-            else:
-                health = None
+            return losses, grads, new_others
+
+        def guard(train_vals, grads, sp_uniq):
+            hg = grads
+            if sparse_pos:
+                # the eager guard reads the DENSE gradient view
+                # (RowSparseNDArray._data = zeros.at[ids].add(vals),
+                # its own dispatch): same formula here, with the
+                # out-of-bounds sentinel rows dropped by the scatter
+                hg = list(grads)
+                for j, p in enumerate(sparse_pos):
+                    hg[p] = cut(jnp.zeros(
+                        train_vals[p].shape,
+                        grads[p].dtype).at[sp_uniq[j]].add(grads[p]))
+            return cut(numerics.health_of(hg))
+
+        def optimizer(train_vals, state_vals, dyn_list, grads, sp_uniq,
+                      health):
             new_train = list(train_vals)
             new_states = []
             for (gfn, pos), states, dyn in zip(group_meta, state_vals,
@@ -772,26 +806,16 @@ class CapturedStep:
                                a, train_shs[p]) for a in item_states]
                           for p, item_states in zip(pos, ns)]
                 new_states.append(ns)
-            if train_shs is not None:
-                # pin param/aux outputs to their INPUT shardings: the
-                # donated buffers must round-trip layout-stable or the
-                # next dispatch sees new input shardings and retraces
-                # (sits at the program tail, outside every cut/cond —
-                # no fusion decision changes upstream of it)
-                new_train = [jax.lax.with_sharding_constraint(v, s)
-                             for v, s in zip(new_train, train_shs)]
-                new_others = [jax.lax.with_sharding_constraint(v, s)
-                              for v, s in zip(new_others, other_shs)]
-            return new_train, new_others, new_states, losses, health
+            return new_train, new_states
 
         if not self._want_fp:
-            return jax.jit(pure_step, donate_argnums=(0, 1, 2))
+            return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
         from .. import integrity as _integrity
 
-        def pure_step_fp(train_vals, other_vals, state_vals, dyn_list,
-                         xs, ys, keys_b, keys_l, scale, sp_uniq,
-                         sp_inv, attest):
+        def train_step_fp(train_vals, other_vals, state_vals, dyn_list,
+                          xs, ys, keys_b, keys_l, scale, sp_uniq,
+                          sp_inv, attest):
             # ``attest`` is STATIC: jit specializes into exactly two
             # executables (one trace + compile each, cached by jit).
             # The non-attest executable is the plain step plus a
@@ -801,9 +825,9 @@ class CapturedStep:
             # every param+state array becomes a conditional operand,
             # which blocks fusion/aliasing on EVERY step.)
             new_train, new_others, new_states, losses, health = \
-                pure_step(train_vals, other_vals, state_vals, dyn_list,
-                          xs, ys, keys_b, keys_l, scale, sp_uniq,
-                          sp_inv)
+                train_step(train_vals, other_vals, state_vals,
+                           dyn_list, xs, ys, keys_b, keys_l, scale,
+                           sp_uniq, sp_inv)
             if attest:
                 flat_states = [a for group in new_states
                                for item in group for a in item]
@@ -814,7 +838,8 @@ class CapturedStep:
             return (new_train, new_others, new_states, losses, health,
                     fp)
 
-        return jax.jit(pure_step_fp, donate_argnums=(0, 1, 2),
+        train_step_fp.__name__ = train_step_fp.__qualname__ = "train_step"
+        return jax.jit(train_step_fp, donate_argnums=(0, 1, 2),
                        static_argnums=(11,))
 
     # -- per-step host driver ---------------------------------------------------
@@ -850,10 +875,12 @@ class CapturedStep:
             # key-draw count of the eager oracle at grad_accum=n_micro
             k = self._n_micro
             kbs, kls = [], []
-            for _ in range(k):
-                kbs.append(_random.next_key())
-                if self._loss_keyed:
-                    kls.append(_random.next_key())
+            # the key split is a program of its own on the device
+            with profiler.annotate("captured_keys"):
+                for _ in range(k):
+                    kbs.append(_random.next_key())
+                    if self._loss_keyed:
+                        kls.append(_random.next_key())
         with profiler.annotate("captured_data"):
             if k == 1:
                 keys_b = kbs[0]
@@ -928,15 +955,16 @@ class CapturedStep:
                 new_train, new_others, new_states, losses, health = \
                     self._executable(args)(*args)
         _DISPATCH_COUNT += 1
-        for (_i, p), nw in zip(self._trained, new_train):
-            p.data()._set_data(nw)
-        for (_n, p), nv in zip(self._others, new_others):
-            p.data()._set_data(nv)
-        for (_gkey, items), ns_group in \
-                zip(self._groups.items(), new_states):
-            for (_i, _w, _g, st, _d), ns in zip(items, ns_group):
-                for s_nd, s_new in zip(st, ns):
-                    s_nd._set_data(s_new)
+        with profiler.annotate("captured_commit"):
+            for (_i, p), nw in zip(self._trained, new_train):
+                p.data()._set_data(nw)
+            for (_n, p), nv in zip(self._others, new_others):
+                p.data()._set_data(nv)
+            for (_gkey, items), ns_group in \
+                    zip(self._groups.items(), new_states):
+                for (_i, _w, _g, st, _d), ns in zip(items, ns_group):
+                    for s_nd, s_new in zip(st, ns):
+                        s_nd._set_data(s_new)
         from .. import resilience as _resilience
 
         if _resilience.fault_armed("bit_flip_param"):

@@ -185,7 +185,21 @@ class Trainer:
         ``ignore_stale_grad`` only applies to the eager fallback, and
         manual ``backward()`` + ``step()`` flows should not be
         interleaved with ``train_step`` on the same trainer step.
+
+        The whole call runs under the ``train_step`` span (the parent
+        of ``captured_host_prep`` ... ``guard_readback``), so that the
+        capture lookup before them and the step's bookkeeping after
+        them are the program's in a device trace too.
         """
+        from .. import profiler
+
+        with profiler.scope("train_step"):
+            return self._train_step(block, loss_fn, data, label,
+                                    batch_size, grad_accum,
+                                    ignore_stale_grad)
+
+    def _train_step(self, block, loss_fn, data, label, batch_size,
+                    grad_accum, ignore_stale_grad):
         from .. import resilience
         from .. import telemetry
         from . import captured as _captured

@@ -5,7 +5,10 @@ NEW, fleet-observability plane (ISSUE 14).  A request entering
 rides the existing submit → batcher → engine call chain (and the
 shed-retry hop to the next replica), collecting host-side spans —
 frontdoor, queue (coalescing wait), prefill, decode — with wall-clock
-t0s and microsecond durations.  The closed tree is embedded in the
+t0s and microsecond durations.  ``prefill`` and ``decode`` are the
+engine's `profiler.scope` spans of the request's group
+(``serve.prefill.*``, ``serve.decode.*``): their clock reads, moved to
+the wall clock by `wall`, not a second set of readings.  The closed tree is embedded in the
 request's telemetry record (``trace_id`` + ``spans`` fields, schema
 v3), so rendering a request's latency waterfall costs ZERO extra
 device dispatches and zero extra log records: the span tree travels
@@ -32,6 +35,17 @@ from __future__ import annotations
 import os
 import threading
 import time
+
+
+# perf_counter -> epoch seconds, fixed at import: a `profiler.scope`
+# reads perf_counter, a request tree orders spans of several processes
+# on the wall clock
+_EPOCH = time.time() - time.perf_counter()
+
+
+def wall(t_perf) -> float:
+    """Epoch seconds of a ``time.perf_counter()`` reading."""
+    return t_perf + _EPOCH
 
 
 def new_id() -> str:
@@ -95,6 +109,13 @@ class Trace:
         with self._lock:
             self._spans.append(sp)
         return sp
+
+    def add(self, name, parent, t0, dur_us, **attrs) -> Span:
+        """A span that already ran, from its real start (epoch seconds)
+        and duration: how the engine's `profiler.scope` spans of a
+        group enter each of its requests' trees."""
+        return self.begin(name, parent=parent, t0=t0,
+                          **attrs).close(dur_us=dur_us)
 
     def spans(self):
         with self._lock:

@@ -125,34 +125,39 @@ def multi_head_attention(query, key, value, qkv_weight=None, qkv_bias=None,
     """Full fused MHA on (B, T, C) inputs with packed qkv projection
     (reference: the contrib/transformer interleaved kernels fused exactly
     this to avoid three GEMMs — one packed MXU matmul here)."""
-    if qkv_weight is not None:
-        if query is key and key is value:
-            qkv = jnp.einsum("btc,gc->btg", query, qkv_weight)
-            if qkv_bias is not None:
-                qkv = qkv + qkv_bias
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+    # the scopes name the block's parts in the compiled program and in
+    # every device trace (metadata only)
+    with jax.named_scope("attn_qkv"):
+        if qkv_weight is not None:
+            if query is key and key is value:
+                qkv = jnp.einsum("btc,gc->btg", query, qkv_weight)
+                if qkv_bias is not None:
+                    qkv = qkv + qkv_bias
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+            else:
+                wq, wk, wv = jnp.split(qkv_weight, 3, axis=0)
+                bq = bk = bv = None
+                if qkv_bias is not None:
+                    bq, bk, bv = jnp.split(qkv_bias, 3, axis=0)
+                q = jnp.einsum("btc,gc->btg", query, wq)
+                k = jnp.einsum("btc,gc->btg", key, wk)
+                v = jnp.einsum("btc,gc->btg", value, wv)
+                if bq is not None:
+                    q, k, v = q + bq, k + bk, v + bv
         else:
-            wq, wk, wv = jnp.split(qkv_weight, 3, axis=0)
-            bq = bk = bv = None
-            if qkv_bias is not None:
-                bq, bk, bv = jnp.split(qkv_bias, 3, axis=0)
-            q = jnp.einsum("btc,gc->btg", query, wq)
-            k = jnp.einsum("btc,gc->btg", key, wk)
-            v = jnp.einsum("btc,gc->btg", value, wv)
-            if bq is not None:
-                q, k, v = q + bq, k + bk, v + bv
-    else:
-        q, k, v = query, key, value
-    qh = _split_heads(q, num_heads)
-    kh = _split_heads(k, num_heads)
-    vh = _split_heads(v, num_heads)
-    out = scaled_dot_product_attention(qh, kh, vh, mask=mask,
-                                       causal=causal, impl=impl)
-    out = _merge_heads(out)
-    if proj_weight is not None:
-        out = jnp.einsum("btg,cg->btc", out, proj_weight)
-        if proj_bias is not None:
-            out = out + proj_bias
+            q, k, v = query, key, value
+        qh = _split_heads(q, num_heads)
+        kh = _split_heads(k, num_heads)
+        vh = _split_heads(v, num_heads)
+    with jax.named_scope("flash" if impl == "flash" else "attn"):
+        out = scaled_dot_product_attention(qh, kh, vh, mask=mask,
+                                           causal=causal, impl=impl)
+    with jax.named_scope("attn_out"):
+        out = _merge_heads(out)
+        if proj_weight is not None:
+            out = jnp.einsum("btg,cg->btc", out, proj_weight)
+            if proj_bias is not None:
+                out = out + proj_bias
     return out
 
 
@@ -244,15 +249,16 @@ def scan_transformer_encoder(data, qkv_w, qkv_b, proj_w, proj_b,
                 jax.random.bernoulli(k1, keep, attn.shape),
                 attn / keep, 0.0).astype(attn.dtype)
         x = x + attn
-        h = layer_norm(x, g2, b2)
-        h = jnp.einsum("btc,hc->bth", h, f1w,
-                       preferred_element_type=jnp.float32) \
-            .astype(x.dtype) + f1b
-        h = jax.nn.gelu(h) if activation == "gelu" \
-            else jnp.maximum(h, 0)
-        h = (jnp.einsum("bth,ch->btc", h, f2w,
-                        preferred_element_type=jnp.float32)
-             .astype(x.dtype) + f2b)
+        with jax.named_scope("mlp"):
+            h = layer_norm(x, g2, b2)
+            h = jnp.einsum("btc,hc->bth", h, f1w,
+                           preferred_element_type=jnp.float32) \
+                .astype(x.dtype) + f1b
+            h = jax.nn.gelu(h) if activation == "gelu" \
+                else jnp.maximum(h, 0)
+            h = (jnp.einsum("bth,ch->btc", h, f2w,
+                            preferred_element_type=jnp.float32)
+                 .astype(x.dtype) + f2b)
         if use_drop:
             h = jnp.where(jax.random.bernoulli(k2, keep, h.shape),
                           h / keep, 0.0).astype(h.dtype)

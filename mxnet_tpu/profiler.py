@@ -9,7 +9,9 @@ TPU-first: the host-side tracer records per-op dispatch spans from the
 NDArray invoke layer (the analog of ThreadedEngine::ExecuteOprBlock hooks);
 device-side time belongs to XLA's own profiler — ``start_xla_trace`` /
 ``stop_xla_trace`` wrap ``jax.profiler`` so one call captures an xplane
-trace alongside the chrome dump (open either in Perfetto).  With
+trace alongside the chrome dump (open either in Perfetto).  `scope` is
+the one span primitive: its spans show in every ``jax.profiler``
+session, these wrappers' or anyone else's.  With
 ``profile_sync=True`` every traced op blocks on completion, so spans are
 true op latencies (NaiveEngine-style measurement).
 """
@@ -21,6 +23,8 @@ import os
 import threading
 import time
 from collections import defaultdict
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 from . import telemetry as _telemetry
 
@@ -140,20 +144,6 @@ def get_summary(reset=False):
     return "\n".join(lines)
 
 
-def aggregates(reset=False):
-    """Structured counterpart of `get_summary`: ``{name: {count,
-    total_ms, min_ms, max_ms}}`` — bench.py derives its step-time
-    breakdown (data stall / host prep / dispatch / collective /
-    readback shares) from the named `annotate` scopes collected here."""
-    with _LOCK:
-        out = {name: {"count": count, "total_ms": total,
-                      "min_ms": mn, "max_ms": mx}
-               for name, (count, total, mn, mx) in _S.aggregate.items()}
-        if reset:
-            _S.aggregate.clear()
-    return out
-
-
 dump_profile = dump
 profiler_set_config = set_config
 profiler_set_state = set_state
@@ -178,50 +168,53 @@ def stop_xla_trace():
     return out
 
 
-def annotate(name):
+def annotate(name, **attrs):
     """Named phase marker for hot-path stages ("allreduce",
-    "optimizer_update", "bucket_pack", ...): a `jax.profiler.
-    TraceAnnotation` so the stage shows up named in xplane traces, plus a
-    host span when the host profiler is running."""
-    return scope(name)
+    "optimizer_update", "bucket_pack", ...): see `scope`."""
+    return scope(name, **attrs)
 
 
 class scope:
-    """Annotation scope appearing in both host + XLA traces (reference:
-    profiler scopes / NVTX ranges).
+    """The one span primitive: a named interval of host work, on the
+    device trace's clock (reference: profiler scopes / NVTX ranges).
 
-    The `jax.profiler.TraceAnnotation` is constructed ONLY while a trace
-    can actually see it — the host profiler running, or an XLA trace
-    opened via `start_xla_trace` — so hot-path `annotate` calls with
-    profiling off pay two `perf_counter` reads, not a context-manager
-    round-trip into jax.  The host duration is always measured and
-    forwarded to the telemetry step assembler (mxnet_tpu/telemetry.py),
-    which is how StepStats gets its breakdown without the profiler on.
+    Every use enters a `jax.profiler.TraceAnnotation` (a ``TraceMe``),
+    so the span shows in ANY live profiler session, whoever started it
+    (`start_xla_trace`, a bare ``jax.profiler.start_trace``, the
+    benchmark's `trace.record`, obs/collector.py's on-demand capture),
+    beside the device's operations.  With no session live a ``TraceMe``
+    is a no-op; the whole scope then costs about a microsecond
+    (tests/test_tracing_spans.py holds it under 5).  ``attrs`` are flat
+    scalars shown with the span (``scope("serve.decode.readback",
+    step=j)``).  The host duration is always measured with
+    ``perf_counter`` and forwarded to the telemetry step assembler
+    (mxnet_tpu/telemetry.py), which is how StepStats gets its breakdown
+    without a profiler on; ``t0`` and ``t1`` keep the two clock reads
+    for a caller that sums them (serving's ``timings``).
     """
 
-    __slots__ = ("name", "_jax", "_t0")
+    __slots__ = ("name", "t0", "t1", "_jax")
 
-    def __init__(self, name):
+    def __init__(self, name, **attrs):
         self.name = name
+        self._jax = _TraceMe(name, **attrs)
+
+    def set(self, **attrs):
+        """Attributes known only once the span is under way (a group's
+        size); call before the span closes."""
+        self._jax.set_metadata(**attrs)
 
     def __enter__(self):
-        if _S.running or _S.xla_dir is not None:
-            import jax
-
-            self._jax = jax.profiler.TraceAnnotation(self.name)
-            self._jax.__enter__()
-        else:
-            self._jax = None
-        self._t0 = time.perf_counter()
+        self._jax.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        if self._jax is not None:
-            self._jax.__exit__(*exc)
+        self.t1 = t1 = time.perf_counter()
+        self._jax.__exit__(*exc)
         if _S.running:
-            record_span(self.name, "scope", self._t0, t1)
-        _telemetry.on_scope(self.name, t1 - self._t0)
+            record_span(self.name, "scope", self.t0, t1)
+        _telemetry.on_scope(self.name, t1 - self.t0)
 
 
 if os.environ.get("MXNET_PROFILER_AUTOSTART", "0") == "1":

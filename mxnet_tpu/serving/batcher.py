@@ -28,6 +28,7 @@ from concurrent.futures import Future
 from .. import telemetry
 from ..base import MXNetError, getenv_int
 from ..obs.spans import Trace
+from ..profiler import scope
 
 
 class ServerOverloaded(MXNetError):
@@ -143,33 +144,39 @@ class ContinuousBatcher:
         """Block for the first request, then coalesce until the deadline
         or the largest bucket fills.  Blocking (not polling): an idle
         replica costs zero CPU; close() wakes the block with a
-        sentinel — _collect returns None and the loop exits to drain."""
+        sentinel — _collect returns no group and the loop exits to
+        drain.  Returns (group, microseconds under ``serve.collect``)."""
         first = self._q.get()
         if first is _SHUTDOWN:
-            return None
+            return None, 0.0
         group = [first]
         deadline = first.t_enqueue + self.max_delay_ms / 1e3
-        while len(group) < self.max_batch:
-            wait = deadline - time.perf_counter()
-            if wait <= 0:
-                # deadline hit — grab whatever is already queued, no wait
+        # the span runs from the first request dequeued to the group
+        # closed: the device idles here while clients (re)submit
+        with scope("serve.collect") as sp:
+            while len(group) < self.max_batch:
+                wait = deadline - time.perf_counter()
+                if wait <= 0:
+                    # deadline hit — grab whatever is already queued,
+                    # no wait
+                    try:
+                        while len(group) < self.max_batch:
+                            item = self._q.get_nowait()
+                            if item is _SHUTDOWN:
+                                break   # _loop re-checks _stop next
+                            group.append(item)
+                    except queue.Empty:
+                        pass
+                    break
                 try:
-                    while len(group) < self.max_batch:
-                        item = self._q.get_nowait()
-                        if item is _SHUTDOWN:
-                            break       # _loop re-checks _stop next
-                        group.append(item)
+                    item = self._q.get(timeout=wait)
                 except queue.Empty:
-                    pass
-                break
-            try:
-                item = self._q.get(timeout=wait)
-            except queue.Empty:
-                break
-            if item is _SHUTDOWN:
-                break
-            group.append(item)
-        return group
+                    break
+                if item is _SHUTDOWN:
+                    break
+                group.append(item)
+            sp.set(n=len(group))
+        return group, (sp.t1 - sp.t0) * 1e6
 
     def _expire(self, group, now):
         """Resolve requests whose deadline passed during queueing with
@@ -198,47 +205,59 @@ class ContinuousBatcher:
                     f"{(now - r.t_enqueue) * 1e3:.1f} ms in queue"))
         return live
 
-    def _serve(self, group):
-        t_batch = time.perf_counter()
-        group = self._expire(group, t_batch)
-        if not group:
-            return
-        try:
-            if self.before_batch is not None:
-                self.before_batch()
-            outs, timings = self.engine.serve_group(
-                [r.prompt for r in group],
-                [r.max_new_tokens for r in group],
-                temperature=self._temperature, rng=self._rng)
-        except BaseException as exc:  # resolve ALL futures, never hang
-            for r in group:
-                if not r.future.cancelled():
-                    r.future.set_exception(exc)
-            return
-        self.groups_served += 1
-        self.requests_served += len(group)
+    def _serve(self, group, collect_us=0.0):
+        """One group, under the ``serve.group`` span: expire, serve
+        (the engine's ``serve.prefill.*`` / ``serve.decode.*`` spans),
+        then ``serve.finish``: records, telemetry, futures."""
+        with scope("serve.group") as span:
+            t_batch = span.t0
+            group = self._expire(group, t_batch)
+            if not group:
+                return
+            try:
+                if self.before_batch is not None:
+                    self.before_batch()
+                outs, timings = self.engine.serve_group(
+                    [r.prompt for r in group],
+                    [r.max_new_tokens for r in group],
+                    temperature=self._temperature, rng=self._rng)
+            except BaseException as exc:  # resolve ALL futures, never hang
+                for r in group:
+                    if not r.future.cancelled():
+                        r.future.set_exception(exc)
+                return
+            span.set(B=timings["bucket"][0], S=timings["bucket"][1],
+                     steps=max(len(o) for o in outs),
+                     generation=timings["generation"])
+            self.groups_served += 1
+            self.requests_served += len(group)
+            with scope("serve.finish") as fin:
+                self._finish(group, outs, dict(timings,
+                                               collect_us=collect_us),
+                             t_batch, fin.t0)
+
+    def _finish(self, group, outs, timings, t_batch, t_finish):
         t_done = time.time()
         for r, toks in zip(group, outs):
             queue_us = (t_batch - r.t_enqueue) * 1e6
             rec = dict(timings)
             rec["queue_us"] = queue_us
             rec["tokens"] = toks
-            # close the request's span tree from the group's stage
-            # clocks — no extra timing work, the engine already took
-            # these readings (obs/spans.py)
+            # the request's tree takes prefill and decode from the
+            # engine's real spans, their start and duration; an engine
+            # that reports none gets none rebuilt here (obs/spans.py)
             r.qspan.close(dur_us=queue_us)
-            r.trace.begin("prefill", parent=r.span,
-                          t0=timings.get("t_prefill0"),
-                          bucket=f"{timings['bucket'][0]}x"
-                                 f"{timings['bucket'][1]}",
-                          generation=timings["generation"]) \
-                .close(dur_us=timings["prefill_us"])
-            r.trace.begin("decode", parent=r.span,
-                          t0=timings.get("t_decode0"),
-                          new_tokens=len(toks)) \
-                .close(dur_us=timings.get(
-                    "decode_us",
-                    timings["decode_us_per_token"] * len(toks)))
+            if "t_prefill0" in timings:
+                r.trace.add("prefill", parent=r.span,
+                            t0=timings["t_prefill0"],
+                            dur_us=timings["prefill_us"],
+                            bucket=f"{timings['bucket'][0]}x"
+                                   f"{timings['bucket'][1]}",
+                            generation=timings["generation"])
+                r.trace.add("decode", parent=r.span,
+                            t0=timings["t_decode0"],
+                            dur_us=timings["decode_us"],
+                            new_tokens=len(toks))
             r.trace.close_open(t_end=t_done)
             telemetry.request_record(
                 queue_us=queue_us,
@@ -249,17 +268,23 @@ class ContinuousBatcher:
                 new_tokens=len(toks),
                 generation=timings["generation"],
                 deadline_exceeded=False, replica_id=self.replica_id,
+                collect_us=timings["collect_us"],
+                decode_host_us_per_step=timings.get(
+                    "decode_host_us_per_step"),
                 **r.trace.to_fields())
+            # from the group's last token to this request's answer
+            # handed over: one clock read a request, none a step
+            rec["finish_us"] = (time.perf_counter() - t_finish) * 1e6
             if not r.future.cancelled():
                 r.future.set_result(rec)
 
     def _loop(self):
         while not self._stop.is_set():
-            group = self._collect()
+            group, collect_us = self._collect()
             if group is None:
                 break
             if group:
-                self._serve(group)
+                self._serve(group, collect_us)
         # drain: resolve what is left rather than abandoning futures
         while True:
             try:

@@ -48,6 +48,8 @@ import threading
 import time
 
 from ..base import MXNetError
+from ..obs.spans import wall
+from ..profiler import scope
 from ..gluon.model_zoo.gpt import (STACK_NAMES, _sample,
                                    extract_decoder_stacks)
 
@@ -341,51 +343,60 @@ class ServingEngine:
             (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
              g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
             S = toks.shape[1]
-            positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
-            x = (jnp.take(tok_e, toks, axis=0) +
-                 jnp.take(pos_e, positions, axis=0)
-                 ).astype(jnp.float32)                         # (B, S, C)
+            with jax.named_scope("serve.embed"):
+                positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
+                x = (jnp.take(tok_e, toks, axis=0) +
+                     jnp.take(pos_e, positions, axis=0)
+                     ).astype(jnp.float32)                     # (B, S, C)
 
             def layer(x, per):
                 (qw, qb, pw, pb_l, f1w_l, f1b_l, f2w_l, f2b_l,
                  g1, b1, g2, b2, ck_l, cv_l) = per
-                h = layer_norm(x, g1, b1)
-                qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
-                qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
-                kh = qkv[:, :, 1].swapaxes(1, 2)
-                vh = qkv[:, :, 2].swapaxes(1, 2)
+                with jax.named_scope("serve.attn_qkv"):
+                    h = layer_norm(x, g1, b1)
+                    qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
+                    qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
+                    kh = qkv[:, :, 1].swapaxes(1, 2)
+                    vh = qkv[:, :, 2].swapaxes(1, 2)
 
                 def write(c, k, p):
                     # per-row cache write at that row's own offset
                     return lax.dynamic_update_slice(c, k, (0, p, 0))
 
-                ck_l = jax.vmap(write)(ck_l, kh.astype(ck_l.dtype), pos)
-                cv_l = jax.vmap(write)(cv_l, vh.astype(cv_l.dtype), pos)
-                scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
-                    * (Dh ** -0.5)
-                # per-row causal mask: row b at block offset s may see
-                # cache slots <= pos[b] + s (stale pad garbage beyond is
-                # invisible — the overwrite-before-attend invariant)
-                mask = jnp.arange(W)[None, None, :] <= \
-                    (pos[:, None, None] +
-                     jnp.arange(S)[None, :, None])             # (B, S, W)
-                scores = jnp.where(mask[:, None], scores, -1e30)
-                p = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
-                attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
-                x = x + attn
-                h = layer_norm(x, g2, b2)
-                h = h @ f1w_l.T + f1b_l
-                h = jax.nn.gelu(h) if act == "gelu" \
-                    else jnp.maximum(h, 0)
-                x = x + (h @ f2w_l.T + f2b_l)
+                with jax.named_scope("serve.cache_write"):
+                    ck_l = jax.vmap(write)(ck_l, kh.astype(ck_l.dtype),
+                                           pos)
+                    cv_l = jax.vmap(write)(cv_l, vh.astype(cv_l.dtype),
+                                           pos)
+                with jax.named_scope("serve.attn"):
+                    scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
+                        * (Dh ** -0.5)
+                    # per-row causal mask: row b at block offset s may
+                    # see cache slots <= pos[b] + s (stale pad garbage
+                    # beyond is invisible — the overwrite-before-attend
+                    # invariant)
+                    mask = jnp.arange(W)[None, None, :] <= \
+                        (pos[:, None, None] +
+                         jnp.arange(S)[None, :, None])         # (B, S, W)
+                    scores = jnp.where(mask[:, None], scores, -1e30)
+                    p = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
+                    attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
+                    x = x + attn
+                with jax.named_scope("serve.mlp"):
+                    h = layer_norm(x, g2, b2)
+                    h = h @ f1w_l.T + f1b_l
+                    h = jax.nn.gelu(h) if act == "gelu" \
+                        else jnp.maximum(h, 0)
+                    x = x + (h @ f2w_l.T + f2b_l)
                 return x, (ck_l, cv_l)
 
             per_layer = (qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
                          g1s, b1s, g2s, b2s, ck, cv)
             x, (ck2, cv2) = lax.scan(layer, x, per_layer)
-            h = layer_norm(x, lnf_g, lnf_b)
-            logits = h @ tok_e.T
+            with jax.named_scope("serve.head"):
+                h = layer_norm(x, lnf_g, lnf_b)
+                logits = h @ tok_e.T
             if cache_ns is not None:
                 # pin the donated buffers' output layout to the input
                 # layout, so the next AOT call sees identical shardings
@@ -393,7 +404,17 @@ class ServingEngine:
                 cv2 = lax.with_sharding_constraint(cv2, cache_ns)
             return ck2, cv2, logits
 
-        return step
+        def named(name):
+            # one traced function under two names: a program is called
+            # ``jit_<__name__>`` in its HLO and in every device trace
+            def fn(w, ck, cv, pos, toks):
+                return step(w, ck, cv, pos, toks)
+
+            fn.__name__ = fn.__qualname__ = name
+            return fn
+
+        return {"prefill": named("serve_prefill"),
+                "decode": named("serve_decode")}
 
     # -- AOT compilation -------------------------------------------------------
 
@@ -428,7 +449,8 @@ class ServingEngine:
 
         w_avals = tuple(self._aval(x) for x in self._weights)
         ck, cv = self.init_cache(B)
-        jfn = jax.jit(self._step, donate_argnums=(1, 2))
+        jfn = jax.jit(self._step["decode" if S == 1 else "prefill"],
+                      donate_argnums=(1, 2))
         compiled = jfn.lower(w_avals, self._aval(ck), self._aval(cv),
                              self._int_aval((B,)),
                              self._int_aval((B, S))).compile()
@@ -490,7 +512,12 @@ class ServingEngine:
         per-request list.  Returns ``(outputs, timings)`` where
         outputs[i] is the i-th request's generated tokens (np.int32)
         and timings carries the per-request record fields
-        (prefill_us, decode_us_per_token, bucket, padded_fraction)."""
+        (prefill_us, decode_us_per_token, bucket, padded_fraction, the
+        per-step split of decode and ``token_t_us``;
+        docs/observability.md has the table).  Each phase runs under a
+        `profiler.scope` (``serve.prefill.dispatch`` ...
+        ``serve.decode.readback``), and the timings are sums of those
+        spans' own clock reads."""
         import numpy as np
 
         n = len(prompts)
@@ -517,35 +544,53 @@ class ServingEngine:
         toks = np.zeros((B, S), np.int32)
         for i, p in enumerate(prompts):
             toks[i, :lens[i]] = np.asarray(p, np.int32)
-        t0 = time.perf_counter()
-        t_prefill0 = time.time()
-        ck, cv = self.init_cache(B)
-        ck, cv, logits = self._call(B, S, ck, cv,
-                                    np.zeros(B, np.int32), toks)
-        last = np.asarray(logits)[np.arange(B), lens - 1]
-        prefill_us = (time.perf_counter() - t0) * 1e6
-        t1 = time.perf_counter()
-        t_decode0 = time.time()
+        with scope("serve.prefill.dispatch") as sp_dispatch:
+            ck, cv = self.init_cache(B)
+            ck, cv, logits = self._call(B, S, ck, cv,
+                                        np.zeros(B, np.int32), toks)
+        with scope("serve.prefill.readback") as sp_readback:
+            last = np.asarray(logits)[np.arange(B), lens - 1]
+        t0, t1 = sp_dispatch.t0, sp_readback.t1
+        prefill_us = (t1 - t0) * 1e6
         out = np.zeros((B, steps), np.int32)
+        sample_s = dispatch_s = readback_s = 0.0
+        token_t_us = []
         for j in range(steps):
-            nxt = _sample(last, temperature, rng)
-            out[:, j] = nxt
+            with scope("serve.decode.sample", step=j) as sp:
+                nxt = _sample(last, temperature, rng)
+                out[:, j] = nxt
+            sample_s += sp.t1 - sp.t0
+            token_t_us.append((sp.t1 - t1) * 1e6)
             if j < steps - 1:      # the last token needs no cache step
-                ck, cv, logits = self._call(B, 1, ck, cv, lens + j,
-                                            nxt[:, None])
-                last = np.asarray(logits)[:, 0]
-        decode_us = (time.perf_counter() - t1) * 1e6
+                with scope("serve.decode.dispatch", step=j) as sp:
+                    ck, cv, logits = self._call(B, 1, ck, cv, lens + j,
+                                                nxt[:, None])
+                dispatch_s += sp.t1 - sp.t0
+                with scope("serve.decode.readback", step=j) as sp:
+                    last = np.asarray(logits)[:, 0]
+                readback_s += sp.t1 - sp.t0
+        decode_us = (sp.t1 - t1) * 1e6
+        per_step = 1e6 / steps        # seconds summed -> us a step
         timings = {
             "prefill_us": prefill_us,
-            "decode_us_per_token": decode_us / max(1, steps),
+            "decode_us_per_token": decode_us / steps,
             "bucket": [int(B), int(S)],
             "padded_fraction": round(
                 1.0 - float(lens[:n].sum()) / float(B * S), 4),
             "generation": self.generation,
-            # wall-clock stage starts + total decode time: span
-            # material for obs/spans.py (host clock reads only)
-            "t_prefill0": t_prefill0,
-            "t_decode0": t_decode0,
+            # wall-clock stage starts + total decode time: what groups
+            # a group's records and places its request spans
+            # (obs/spans.py); every duration here comes from the spans'
+            # own clock reads
+            "t_prefill0": wall(t0),
+            "t_decode0": wall(t1),
             "decode_us": decode_us,
+            "decode_sample_us_per_step": sample_s * per_step,
+            "decode_dispatch_us_per_step": dispatch_s * per_step,
+            "decode_readback_us_per_step": readback_s * per_step,
+            "decode_host_us_per_step": (sample_s + dispatch_s) * per_step,
+            # when each token was emitted, from t_decode0 (token 0 is
+            # the prefill's): the gaps are what a streaming caller sees
+            "token_t_us": token_t_us,
         }
         return [out[i, :per_req[i]].copy() for i in range(n)], timings

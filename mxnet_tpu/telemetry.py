@@ -10,7 +10,7 @@ bottleneck (PyGraph / XLA-fusion papers, PAPERS.md).  This module keeps
 that attribution, always, at <1% of step time:
 
 - `MetricsRegistry` — process-wide counters / gauges / time-and-byte
-  histograms.  Components increment (`count`, `gauge_set`, `observe`);
+  histograms.  Components increment (`count`, `gauge_set`);
   the per-step assembler reads deltas.  No device work, ever.
 - `StepStats` — ONE record per training step, assembled from the
   existing single host readback plus the `profiler.annotate` scope
@@ -136,6 +136,9 @@ _BREAKDOWN_KEYS = ("data", "host_prep", "dispatch", "readback",
 _SCOPE_BUCKET = {
     "captured_data": "data",
     "captured_host_prep": "host_prep",
+    # captured_keys is a child of captured_host_prep, whose duration
+    # holds it already: it is named in the trace and summed once here
+    "captured_commit": "host_prep",
     "captured_step": "dispatch",
     "optimizer_update": "dispatch",
     "guard_readback": "readback",
@@ -270,11 +273,6 @@ def count(name, n=1):
 def gauge_set(name, v):
     if enabled():
         REGISTRY.gauge(name).set(v)
-
-
-def observe(name, v):
-    if enabled():
-        REGISTRY.histogram(name).observe(v)
 
 
 # -- run identity and the JSONL sink -------------------------------------------

@@ -301,9 +301,14 @@ def report_requests(requests, out):
     out.write("  serving requests:\n")
     out.write(f"    {'stage':<22}{'p50 us':>12}{'p99 us':>12}\n")
     for key, label in (("queue_us", "queue"),
+                       ("collect_us", "collect (group)"),
                        ("prefill_us", "prefill"),
-                       ("decode_us_per_token", "decode/token")):
+                       ("decode_us_per_token", "decode/token"),
+                       ("decode_host_us_per_step", "  host part/step")):
         vals = [r.get(key) for r in requests]
+        if key in ("collect_us", "decode_host_us_per_step") \
+                and all(v is None for v in vals):
+            continue        # a log from before these fields
         out.write(f"    {label:<22}{_fmt(_pctl(vals, 50)):>12}"
                   f"{_fmt(_pctl(vals, 99)):>12}\n")
     pf = _mean([r.get("padded_fraction") for r in requests])
