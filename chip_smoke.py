@@ -392,6 +392,17 @@ def phase_serve(size, platform, net):
     require(all(on_platform(a, platform)
                 for a in engine._weights + (ck, cv)),
             f"serve: weights or cache not on a {platform} device")
+    # the decode step carries the cache: nothing in its compiled
+    # program copies or slices out a layer of it (B x H x W x Dh)
+    big = max(size.batch_buckets)
+    layer = big * (ck.nbytes // ck.shape[0])
+    moved = serving.whole_layer_ops(
+        engine._programs[(big, 1)].as_text(), layer)
+    say(f"[serve] decode program, batch {big}: {len(moved)} copy / "
+        f"dynamic-slice / dynamic-update-slice of a cache layer "
+        f"({layer} bytes) or more")
+    require(not moved,
+            f"serve: the decode program moves whole cache layers: {moved}")
     del ck, cv
 
     rng = np.random.RandomState(1)

@@ -6,7 +6,7 @@ from .batcher import (ContinuousBatcher, DeadlineExceeded,
                       max_queue_from_env)
 from .engine import (ServingEngine, batch_buckets_from_env, compile_count,
                      dispatch_count, prefill_buckets_for, reset_counters,
-                     state_for_serving, trace_count)
+                     state_for_serving, trace_count, whole_layer_ops)
 from .replica import FleetWatcher, FrontDoor, ReplicaServer
 
 __all__ = [
@@ -14,5 +14,5 @@ __all__ = [
     "FleetWatcher", "ServerOverloaded", "DeadlineExceeded",
     "state_for_serving", "batch_buckets_from_env", "prefill_buckets_for",
     "max_delay_ms_from_env", "max_queue_from_env", "trace_count",
-    "compile_count", "dispatch_count", "reset_counters",
+    "compile_count", "dispatch_count", "reset_counters", "whole_layer_ops",
 ]

@@ -16,8 +16,16 @@ request path.  Three properties, all pinned by tests/test_serving.py:
 - **KV-cache decode.**  The per-layer key/value cache is laid out
   stage-major — ``(L, B, H, W, Dh)`` with L the scanned-trunk layer
   axis, matching the ``*_stack_*`` weight stacks
-  (parallel/sharding.py TRANSFORMER_TP_RULES) — and donated between
-  steps, so decode re-uses the prefill buffers in place.  Prefill
+  (parallel/sharding.py TRANSFORMER_TP_RULES).  The pair is donated
+  and the layer loop *carries* it whole: a layer writes its new
+  ``(B, H, S, Dh)`` rows into the stack and attends over its own slice
+  of it, so a step's outputs are its inputs' buffers with ``B x S``
+  slots a layer changed, and only the two attention contractions read
+  a whole layer (tests pin the aliasing and, with ``whole_layer_ops``,
+  that the compiled decode program moves no layer-sized buffer).
+  Before PR 25 the cache was a scanned input and output of the loop:
+  donation handed the buffers back, but each layer of each step was
+  sliced out, re-laid, written and copied into a new stack.  Prefill
   (S = seq bucket) and decode (S = 1) are separate bucketed programs
   of the SAME traced function.
 - **Hot reload without recompile.**  Weights are *arguments* to the
@@ -29,7 +37,7 @@ request path.  Three properties, all pinned by tests/test_serving.py:
 Unlike ``gpt.CachedDecoder`` (one uniform-length batch, scalar write
 position), the step here takes a **per-row position vector**, so a
 coalesced batch can mix prompt lengths: each row's cache writes land at
-its own offset (vmapped dynamic_update_slice) and its own causal mask.
+its own offset (one dynamic_update_slice a row) and its own causal mask.
 Every op is row-independent (per-row LN / softmax / einsum rows), which
 is what makes a coalesced batch bitwise equal to the same requests
 served one-by-one through the same batch bucket — pad rows can never
@@ -44,6 +52,7 @@ axis (parallel/sharding.serving_cache_sharding).
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 
@@ -143,6 +152,65 @@ def _stacks_from_state(state):
     stacks = {nm: get1(nm) for nm in STACK_NAMES}
     return (stacks, (get1("lnf_gamma"), get1("lnf_beta")),
             get1("tok_embed_weight"), get1("pos_embed_weight"))
+
+
+# -- reading a compiled program: what moves a layer of the cache ---------------
+
+_HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8,
+                 "u64": 8, "f64": 8}
+_HLO_INSTR = re.compile(
+    r"^\s+(ROOT\s+)?(%?[\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
+
+
+def whole_layer_ops(hlo_text, layer_bytes):
+    """Names of the instructions of a compiled program's text
+    (``compiled.as_text()``) that materialise ``layer_bytes`` or more by
+    moving data: a ``copy`` or ``dynamic-slice`` with so large a result,
+    a ``dynamic-update-slice`` with so large an *update* (its result is
+    the whole buffer by definition, also when it writes in place), or a
+    fusion whose root is one of those.  A slice read inside the fusion
+    that consumes it materialises nothing and does not count."""
+    # computation -> {instruction: (bytes, op, operands, called fusion)}
+    comps, roots, fused, comp = {}, {}, set(), None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace():
+            # "%name (params) -> shape {" opens a computation
+            comp = line.split("(")[0].replace("ENTRY", "").strip(" %") \
+                if line.rstrip().endswith("{") else None
+            if comp is not None:
+                comps[comp] = {}
+            continue
+        m = _HLO_INSTR.match(line)
+        if m is None or comp is None:
+            continue
+        root, instr, dtype, dims, op, rest = m.groups()
+        instr = instr.lstrip("%")
+        size = _HLO_ITEMSIZE.get(dtype, 4)
+        for d in dims.split(","):
+            size *= int(d) if d else 1
+        called = re.search(r"calls=%?([\w.\-]+)", rest) \
+            if op == "fusion" else None
+        if called:
+            fused.add(called.group(1))
+        comps[comp][instr] = (
+            size, op, re.findall(r"%([\w.\-]+)", rest.split("), ")[0]),
+            called and called.group(1))
+        if root:
+            roots[comp] = instr
+
+    def moved(comp, instr):
+        size, op, operands, called = comps[comp][instr]
+        if called in roots:
+            return moved(called, roots[called])
+        if op in ("copy", "dynamic-slice"):
+            return size
+        if op == "dynamic-update-slice" and operands[1] in comps[comp]:
+            return comps[comp][operands[1]][0]
+        return 0
+
+    return [instr for comp, instrs in comps.items() if comp not in fused
+            for instr in instrs if moved(comp, instr) >= layer_bytes]
 
 
 class ServingEngine:
@@ -327,6 +395,8 @@ class ServingEngine:
         import jax.numpy as jnp
         from jax import lax
 
+        from jax.experimental.layout import with_layout_constraint
+
         from ..ops.nn import layer_norm
 
         H, W = self._H, self._W
@@ -334,6 +404,20 @@ class ServingEngine:
         act = self._act
         mesh = self._mesh
         cache_ns = self._cache_sharding() if mesh is not None else None
+        # how this platform lays a cache out on the device (a v5e puts W
+        # minor-most where Dh is under 128): read off one, not assumed
+        cache_layout = self.init_cache(1)[0].format.layout
+
+        def keep_layout(c):
+            return with_layout_constraint(c, cache_layout)
+
+        if mesh is not None:
+            # the constraint has no partitioning rule (the partitioner
+            # would gather the cache to apply it), so each shard pins
+            # its own
+            keep_layout = jax.shard_map(
+                keep_layout, mesh=mesh, in_specs=cache_ns.spec,
+                out_specs=cache_ns.spec)
 
         def step(w, ck, cv, pos, toks):
             """ck/cv (L, B, H, W, Dh) donated; pos (B,) per-row write
@@ -342,33 +426,45 @@ class ServingEngine:
             _mark_trace()
             (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
              g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
-            S = toks.shape[1]
+            B, S = toks.shape
             with jax.named_scope("serve.embed"):
                 positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
                 x = (jnp.take(tok_e, toks, axis=0) +
                      jnp.take(pos_e, positions, axis=0)
                      ).astype(jnp.float32)                     # (B, S, C)
 
-            def layer(x, per):
+            def write(c, new, l):
+                """Row b's new (H, S, Dh) block into the carried stack
+                at [l, b, :, pos[b]:pos[b] + S, :], and nothing else: one
+                dynamic_update_slice a row, each at that row's own
+                offset (a start that would run past W is clamped)."""
+                new = new.astype(c.dtype)
+                zero = jnp.int32(0)
+                for b in range(B):
+                    c = lax.dynamic_update_slice(
+                        c, new[b][None, None],
+                        (l, jnp.int32(b), zero, pos[b], zero))
+                # keep the stack in the layout the donated buffer came
+                # in: left to itself the TPU compiler re-lays the whole
+                # cache around the loop to make these writes cheaper
+                return keep_layout(c)
+
+            def layer(carry, per):
+                x, ck, cv = carry
                 (qw, qb, pw, pb_l, f1w_l, f1b_l, f2w_l, f2b_l,
-                 g1, b1, g2, b2, ck_l, cv_l) = per
+                 g1, b1, g2, b2, l) = per
                 with jax.named_scope("serve.attn_qkv"):
                     h = layer_norm(x, g1, b1)
                     qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
                     qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
                     kh = qkv[:, :, 1].swapaxes(1, 2)
                     vh = qkv[:, :, 2].swapaxes(1, 2)
-
-                def write(c, k, p):
-                    # per-row cache write at that row's own offset
-                    return lax.dynamic_update_slice(c, k, (0, p, 0))
-
                 with jax.named_scope("serve.cache_write"):
-                    ck_l = jax.vmap(write)(ck_l, kh.astype(ck_l.dtype),
-                                           pos)
-                    cv_l = jax.vmap(write)(cv_l, vh.astype(cv_l.dtype),
-                                           pos)
+                    ck = write(ck, kh, l)
+                    cv = write(cv, vh, l)
                 with jax.named_scope("serve.attn"):
+                    ck_l = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
+                    cv_l = lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
                     scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
                         * (Dh ** -0.5)
                     # per-row causal mask: row b at block offset s may
@@ -389,11 +485,16 @@ class ServingEngine:
                     h = jax.nn.gelu(h) if act == "gelu" \
                         else jnp.maximum(h, 0)
                     x = x + (h @ f2w_l.T + f2b_l)
-                return x, (ck_l, cv_l)
+                return (x, ck, cv), None
 
+            # the cache is carried, not scanned: a scanned input is
+            # sliced a layer at a time and a scanned output is a new
+            # stacked buffer, which cost a copy of every layer's keys
+            # and values each way
             per_layer = (qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
-                         g1s, b1s, g2s, b2s, ck, cv)
-            x, (ck2, cv2) = lax.scan(layer, x, per_layer)
+                         g1s, b1s, g2s, b2s,
+                         jnp.arange(ck.shape[0], dtype=jnp.int32))
+            (x, ck2, cv2), _ = lax.scan(layer, (x, ck, cv), per_layer)
             with jax.named_scope("serve.head"):
                 h = layer_norm(x, lnf_g, lnf_b)
                 logits = h @ tok_e.T

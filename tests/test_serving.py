@@ -667,3 +667,241 @@ def test_reload_skips_stale_epoch_manifest(tmp_path, monkeypatch):
                 if e.get("event") == "serving_reload_rejected"]
     assert rejected and all(
         e["reason"].startswith("stale_epoch") for e in rejected)
+
+
+# -- the carried KV cache ------------------------------------------------------
+
+def _np_step(w, act, ck, cv, pos, toks):
+    """The serving step in NumPy, one slot at a time: row b's block is
+    written at ``min(pos[b], W - S)`` (dynamic_update_slice's clamp),
+    and slot w is visible to (b, s) when ``w <= pos[b] + s``.  Mutates
+    ck/cv (L, B, H, W, Dh) and returns the logits."""
+    (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
+     g1s, b1s, g2s, b2s, lnf_g, lnf_b) = [np.asarray(a, np.float64)
+                                          for a in w]
+    L, B, H, W, Dh = ck.shape
+    S = toks.shape[1]
+
+    def ln(x, g, b):
+        mu = x.mean(-1, keepdims=True)
+        return (x - mu) / np.sqrt(x.var(-1, keepdims=True) + 1e-5) * g + b
+
+    def gelu(h):
+        return 0.5 * h * (1 + np.tanh(np.sqrt(2 / np.pi) *
+                                      (h + 0.044715 * h ** 3)))
+
+    logits = np.zeros((B, S, tok_e.shape[0]))
+    for b in range(B):
+        at = pos[b] + np.arange(S)
+        # a position past the table reads NaN, as jnp.take fills it
+        x = tok_e[toks[b]] + np.where((at < W)[:, None],
+                                      pos_e[np.minimum(at, W - 1)], np.nan)
+        start = min(int(pos[b]), W - S)
+        for l in range(L):
+            h = ln(x, g1s[l], b1s[l])
+            qkv = np.einsum("sc,thdc->sthd", h, qkvw[l]) + qkvb[l]
+            for s in range(S):
+                ck[l, b, :, start + s, :] = qkv[s, 1]
+                cv[l, b, :, start + s, :] = qkv[s, 2]
+            attn = np.zeros((S, H, Dh))
+            for s in range(S):
+                seen = np.arange(W) <= pos[b] + s
+                sc = np.einsum("hd,hwd->hw", qkv[s, 0],
+                               ck[l, b].astype(np.float64)) * Dh ** -0.5
+                sc = np.where(seen[None], sc, -1e30)
+                p = np.exp(sc - sc.max(-1, keepdims=True))
+                p /= p.sum(-1, keepdims=True)
+                attn[s] = np.einsum("hw,hwd->hd", p,
+                                    cv[l, b].astype(np.float64))
+            x = x + np.einsum("shd,chd->sc", attn, pwh[l]) + pb[l]
+            h = ln(x, g2s[l], b2s[l]) @ f1w[l].T + f1b[l]
+            h = gelu(h) if act == "gelu" else np.maximum(h, 0)
+            x = x + h @ f2w[l].T + f2b[l]
+        logits[b] = ln(x, lnf_g, lnf_b) @ tok_e.T
+    return logits
+
+
+def _cache_walk(eng, lens, S, decode_pos):
+    """One prefill then one decode step per entry of ``decode_pos``
+    through the engine's programs and through ``_np_step``; returns
+    (engine ck, cv, logits), (NumPy ck, cv, logits)."""
+    B = len(lens)
+    rng = np.random.RandomState(17)
+    toks = np.zeros((B, S), np.int32)
+    for b, n in enumerate(lens):
+        toks[b, :n] = rng.randint(1, 128, n)
+    w = [np.asarray(a) for a in eng._weights]
+    ck, cv = eng.init_cache(B)
+    shape = tuple(ck.shape)
+    nk, nv = np.zeros(shape), np.zeros(shape)
+    calls = [(np.zeros(B, np.int32), toks)]
+    calls += [(np.asarray(p, np.int32),
+               rng.randint(1, 128, (B, 1)).astype(np.int32))
+              for p in decode_pos]
+    for pos, t in calls:
+        ck, cv, lg = eng._call(B, t.shape[1], ck, cv, pos, t)
+        want = _np_step(w, eng._act, nk, nv, pos, t)
+    assert tuple(ck.shape) == shape and ck.dtype == cv.dtype
+    return (np.asarray(ck), np.asarray(cv), np.asarray(lg)), (nk, nv, want)
+
+
+def _loops(jaxpr):
+    """Every scan/while equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            yield eqn
+        for sub in eqn.params.values():
+            inner = getattr(sub, "jaxpr", None)
+            if inner is not None:
+                yield from _loops(inner)
+
+
+@pytest.mark.parametrize("kind,S", [("prefill", 8), ("decode", 1)])
+def test_step_carries_the_cache_and_aliases_it(kind, S):
+    """The layer loop takes the stacked cache in and hands it out as a
+    carry: nothing the loop scans over or stacks up is the cache or a
+    layer of it (a scanned input is sliced a layer at a time, a scanned
+    output is a new buffer), and the compiled program writes outputs
+    0, 1 into the donated arguments 1, 2."""
+    import jax
+
+    eng = serving.ServingEngine(_model(num_layers=3), batch_buckets=(4,))
+    B = 4
+    ck, cv = eng.init_cache(B)
+    stack, layer = tuple(ck.shape), tuple(ck.shape[1:])
+    jaxpr = jax.make_jaxpr(eng._step[kind])(
+        eng._weights, ck, cv, np.zeros(B, np.int32),
+        np.zeros((B, S), np.int32))
+    loops = list(_loops(jaxpr.jaxpr))
+    assert [e.primitive.name for e in loops] == ["scan"]
+    scan = loops[0]
+    n_fixed = scan.params["num_consts"] + scan.params["num_carry"]
+    carried = [tuple(v.aval.shape) for v in
+               scan.invars[scan.params["num_consts"]:n_fixed]]
+    assert carried.count(stack) == 2, carried
+    for v in scan.invars[n_fixed:] + \
+            scan.outvars[scan.params["num_carry"]:]:
+        assert tuple(v.aval.shape) != stack, v.aval
+    body = scan.params["jaxpr"].jaxpr
+    for v in body.outvars[scan.params["num_carry"]:]:
+        assert tuple(v.aval.shape) != layer, v.aval
+
+    text = eng._compile(B, S).as_text()
+    n_w = len(eng._weights)
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    assert f"{{0}}: ({n_w}, {{}}" in alias, alias
+    assert f"{{1}}: ({n_w + 1}, {{}}" in alias, alias
+    if kind == "decode":
+        assert serving.whole_layer_ops(text, ck.nbytes // ck.shape[0]) == []
+
+
+@pytest.mark.parametrize("case", ["walk", "clamped"])
+def test_cache_holds_exactly_the_rows_written(case):
+    """One prefill and three decode steps of a 3-layer engine whose
+    rows sit at different positions (a pad row among them): the
+    returned cache equals one built slot by slot in NumPy, and every
+    slot no step wrote is still zero.  ``clamped``: a write whose start
+    would run past the window lands where dynamic_update_slice puts
+    it, at ``W - S`` (what it holds there is NaN: the position table has
+    no such row)."""
+    eng = serving.ServingEngine(_model(num_layers=3), batch_buckets=(4,))
+    lens = [3, 7, 5, 1]                # row 3 is a pad row
+    steps = [[n + j for n in lens] for j in range(3)]
+    if case == "clamped":
+        steps.append([16, 20, 15, 99])     # W = 16: rows 0, 1, 3 clamp
+    got, want = _cache_walk(eng, lens, 8, steps)
+    for g, n, name in zip(got, want, ("ck", "cv", "logits")):
+        np.testing.assert_allclose(g, n, rtol=1e-4, atol=1e-5,
+                                   err_msg=name)
+    ck, cv = got[:2]
+    for b, n in enumerate(lens):
+        written = np.zeros(16, bool)
+        written[:8] = True                         # the prefill block
+        written[[min(s[b], 15) for s in steps]] = True
+        assert not ck[:, b, :, ~written].any()
+        assert not cv[:, b, :, ~written].any()
+        assert (ck[:, b, :, written] != 0).all()
+
+
+def test_tp_cache_holds_exactly_the_rows_written(mesh8):
+    """The same walk with the cache sharded on its head axis."""
+    eng = serving.ServingEngine(_model(num_layers=3), batch_buckets=(4,),
+                                mesh=mesh8(tp=2, dp=4))
+    lens = [3, 7, 5, 1]
+    steps = [[n + j for n in lens] for j in range(3)]
+    got, want = _cache_walk(eng, lens, 8, steps)
+    for g, n, name in zip(got, want, ("ck", "cv", "logits")):
+        np.testing.assert_allclose(g, n, rtol=2e-4, atol=1e-5,
+                                   err_msg=name)
+    assert not got[0][:, 0, :, 8:].any()       # row 0 wrote slots 0..7
+    assert not got[1][:, 1, :, 10:].any()      # row 1 wrote up to 9
+
+
+# a decode program's text cut down to the shapes of what the TPU
+# compiler made of the scanned cache (copy.48, the slice and update
+# fusions) and of the carried one (the in-place row write, the slice
+# read inside the fusion that contracts it); a cache layer is 2048 bytes
+_HLO = """HloModule jit_serve_decode, is_scheduled=true
+
+%fused_slice (param_0.1: bf16[3,4,2,16,8], param_1.1: s32[]) -> bf16[1,4,2,16,8] {
+  %param_0.1 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.1 = s32[]{:T(128)} parameter(1)
+  ROOT %dynamic_slice.87 = bf16[1,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.1, %param_1.1, %param_1.1), dynamic_slice_sizes={1,4,2,16,8}
+}
+
+%fused_update (param_0.2: bf16[3,4,2,16,8], param_1.2: bf16[1,4,2,16,8], param_2.2: s32[]) -> bf16[3,4,2,16,8] {
+  %param_0.2 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = bf16[1,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(1)
+  %param_2.2 = s32[]{:T(128)} parameter(2)
+  ROOT %dynamic_update_slice.7 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.2, %param_1.2, %param_2.2, %param_2.2)
+}
+
+%fused_row_write (param_0.3: bf16[3,4,2,16,8], param_1.3: bf16[1,1,2,1,8], param_2.3: s32[]) -> bf16[3,4,2,16,8] {
+  %param_0.3 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.3 = bf16[1,1,2,1,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(1)
+  %param_2.3 = s32[]{:T(128)} parameter(2)
+  ROOT %dynamic_update_slice.9 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.3, %param_1.3, %param_2.3, %param_2.3)
+}
+
+%fused_scores (param_0.4: bf16[3,4,2,16,8], param_1.4: s32[]) -> f32[4,2,16] {
+  %param_0.4 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.4 = s32[]{:T(128)} parameter(1)
+  %dynamic_slice.114 = bf16[1,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.4, %param_1.4, %param_1.4), dynamic_slice_sizes={1,4,2,16,8}
+  %convert.5 = f32[1,4,2,16,8]{3,4,2,1,0:T(8,128)} convert(%dynamic_slice.114)
+  ROOT %reduce.1 = f32[4,2,16]{2,1,0:T(8,128)} reduce(%convert.5, %param_1.4), dimensions={0,4}
+}
+
+%body (arg: (bf16[3,4,2,16,8], s32[])) -> (bf16[3,4,2,16,8], s32[]) {
+  %arg = (bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)}, s32[]{:T(128)}) parameter(0)
+  %gte.0 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=0
+  %gte.1 = s32[]{:T(128)} get-tuple-element(%arg), index=1
+  %slice_fusion.23 = bf16[1,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} fusion(%gte.0, %gte.1), kind=kLoop, calls=%fused_slice
+  %copy.48 = bf16[1,4,2,16,8]{4,3,2,1,0:T(8,128)(2,1)} copy(%slice_fusion.23), metadata={op_name="jit(serve_decode)/while/body/dynamic_slice"}
+  %update_fusion.4 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} fusion(%gte.0, %copy.48, %gte.1), kind=kLoop, calls=%fused_update
+  %row.1 = bf16[1,1,2,1,8]{3,4,2,1,0:T(8,128)(2,1)} copy(%bitcast.3)
+  %row_write.1 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} fusion(%update_fusion.4, %row.1, %gte.1), kind=kLoop, calls=%fused_row_write
+  %dynamic-update-slice.16 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%row_write.1, %row.1, %gte.1, %gte.1)
+  %scores.1 = f32[4,2,16]{2,1,0:T(8,128)} fusion(%dynamic-update-slice.16, %gte.1), kind=kLoop, calls=%fused_scores
+  ROOT %tuple.1 = (bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)}, s32[]{:T(128)}) tuple(%dynamic-update-slice.16, %gte.1)
+}
+
+ENTRY %main.1 (ck.1: bf16[3,4,2,16,8]) -> bf16[3,4,2,16,8] {
+  %ck.1 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} parameter(0)
+  %copy.88 = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} copy(%ck.1)
+  ROOT %done = bf16[3,4,2,16,8]{3,4,2,1,0:T(8,128)(2,1)} bitcast(%copy.88)
+}
+"""
+
+
+@pytest.mark.parametrize("layer_bytes,want", [
+    (2048, ["slice_fusion.23", "copy.48", "update_fusion.4", "copy.88"]),
+    (4096, ["copy.88"]),
+    (32, ["slice_fusion.23", "copy.48", "update_fusion.4", "row.1",
+          "row_write.1", "dynamic-update-slice.16", "copy.88"]),
+])
+def test_whole_layer_ops_reads_a_recorded_program(layer_bytes, want):
+    """What counts is what an instruction materialises: a copy's or a
+    slice's result, an update's *update* (in place or not, its result
+    is the whole buffer), a fusion's root; a slice read inside the
+    fusion that contracts it is no buffer at all."""
+    assert serving.whole_layer_ops(_HLO, layer_bytes) == want
