@@ -7,9 +7,12 @@ One process, seeded synthetic data, no network.  It drives what a user
 would: a Gluon ``gpt2_small`` (12 layers, 768 wide, vocab 50,257,
 T = 1,024, bf16, Pallas flash attention) placed on ``mx.tpu(0)``, trained
 by ``Trainer.train_step`` (the captured whole-step program), then served
-by ``ServingEngine`` + ``ContinuousBatcher``; with four chips, the same
-step under ``shard_model`` fsdp and tp.  Phases, in order: device, sync,
-kernel, train, serve, sharded.  The first failed check raises and the
+by ``ServingEngine`` + ``ContinuousBatcher``; then a second family
+through the same engine, ``MiMoV2Model`` at its published widths (the
+cut of ``benchmark/configs/mimo-v2.5-ep16.json``: window and full
+layers, two kinds of cache, a chip's share of the routed experts); with
+four chips, the GPT step under ``shard_model`` fsdp and tp.  Phases, in
+order: device, sync, kernel, train, serve, serve_mimo, sharded.  The first failed check raises and the
 process exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
@@ -21,9 +24,11 @@ figures.
 """
 
 import dataclasses
+import functools
 import gc
 import json
 import math
+import os
 import sys
 import threading
 import time
@@ -67,6 +72,29 @@ FULL = Size(
     sync=(4096, 16, 8),
     batch_buckets=(1, 4), prefill_floor=128,
     prompt_lens=(5, 17, 60, 128, 200, 33), new_tokens=8)
+
+
+
+@dataclasses.dataclass(frozen=True)
+class MimoSize:
+    kwargs: dict            # MiMoV2Model's
+    batch: int              # the one batch bucket
+    prefill_floor: int
+    prompt_lens: tuple      # one group: under, at and past the window
+    new_tokens: int         # enough for every ring to wrap twice
+
+
+def mimo_full():
+    """The benchmark configuration's own constructor arguments: the
+    published widths, 7 layers, 16 of 256 experts a layer."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    return MimoSize(kwargs=kwargs, batch=8, prefill_floor=512,
+                    prompt_lens=(40, 128, 300, 500, 77, 129, 16, 260),
+                    new_tokens=2 * kwargs["window"] + 5)
+
 
 # the two timings of the sync phase may differ by this factor
 SYNC_FACTOR = 2.0
@@ -478,6 +506,119 @@ def phase_serve(size, platform, net):
     return {"warmup_s": warm, "programs": engine.program_count()}
 
 
+# -- serve, a second family ----------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _normal_maker(shape, dtype, std, sharding):
+    """One compiled draw per distinct leaf: most layers share theirs."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(
+        lambda key: (std * jax.random.normal(key, shape, jnp.float32)
+                     ).astype(dtype),
+        out_shardings=sharding)
+
+
+def _seed_mimo(net, seed=0):
+    """Seeded values made on the parameters' own device, one leaf at a
+    time (a host draw of 3.4B values would take minutes): matrices
+    normal(0.02), sink logits normal(1), correction biases normal(0.1),
+    gains as `initialize` left them."""
+    import jax
+
+    key = jax.random.key(seed)
+    for i, (name, p) in enumerate(net.collect_params().items()):
+        if name.endswith("gamma"):
+            continue
+        std = 1.0 if name.endswith("sink_bias") else \
+            0.1 if name.endswith("router_bias") else 0.02
+        old = p.data()._data
+        draw = _normal_maker(tuple(old.shape), old.dtype, std, old.sharding)
+        p.set_data(draw(jax.random.fold_in(key, i)))
+
+
+def phase_serve_mimo(size, platform):
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import serving
+    from mxnet_tpu.gluon.model_zoo import mimo_v2
+
+    t0 = time.perf_counter()
+    net = mimo_v2.MiMoV2Model(**size.kwargs)
+    net.initialize(init=mx.init.One(), ctx=_ctx_for(platform))
+    _seed_mimo(net)
+    n_params = sum(int(np.prod(p.shape))
+                   for p in net.collect_params().values())
+    dtype = jnp.dtype(size.kwargs.get("dtype", "float32"))
+    engine = serving.ServingEngine(net, batch_buckets=(size.batch,),
+                                   prefill_floor=size.prefill_floor,
+                                   dtype=dtype)
+    say(f"[serve_mimo] {n_params / 1e9:.2f}B parameters placed and seeded "
+        f"in {time.perf_counter() - t0:.1f}s")
+    own = {id(p.data()._data) for p in net.collect_params().values()}
+    require(all(id(a) in own for a in engine._weights),
+            "serve_mimo: the engine holds a second copy of a parameter")
+    cache = engine.init_cache(1)
+    require(all(on_platform(a, platform)
+                for a in engine._weights + cache),
+            f"serve_mimo: weights or cache not on a {platform} device")
+    vocab = net._vocab
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, vocab, n).tolist() for n in size.prompt_lens]
+    t0 = time.perf_counter()
+    together, timing = engine.serve_group(prompts, size.new_tokens)
+    first = time.perf_counter() - t0
+    pinned = serving.trace_count()
+    B, S = timing["bucket"]
+    # the decode program moves no layer-sized piece of any of the four
+    # stacks (two kinds of cache, keys and values)
+    text = engine._programs[(B, 1)].as_text()
+    big = engine.init_cache(B)
+    for c in big[:4]:
+        moved = serving.whole_layer_ops(text, c.nbytes // c.shape[0])
+        require(not moved, f"serve_mimo: the decode program moves whole "
+                           f"layers of a {tuple(c.shape)} stack: {moved}")
+    del big
+    for j, toks in enumerate(together):
+        require(len(toks) == size.new_tokens
+                and all(0 <= int(t) < vocab for t in toks),
+                f"serve_mimo: request {j} resolved to {toks}")
+    real = sum(size.prompt_lens)
+    layers = sum(1 for m in size.kwargs["moe_layers"] if m)
+    require(0 < timing["moe_pairs_prefill"]
+            <= real * layers * size.kwargs["experts_per_token"],
+            f"serve_mimo: {timing['moe_pairs_prefill']} prefill "
+            f"assignments for {real} tokens")
+    require(timing["moe_rows_computed_decode"]
+            >= timing["moe_pairs_decode"] > 0,
+            f"serve_mimo: decode counters {timing}")
+    # a coalesced group == each request alone through the same bucket
+    for j in (0, len(prompts) - 1):
+        alone, tm = engine.serve_group([prompts[j]], size.new_tokens)
+        require(tm["bucket"][0] == B, "serve_mimo: another batch bucket")
+        if tm["bucket"] == timing["bucket"]:
+            require(np.array_equal(alone[0], together[j]),
+                    f"serve_mimo: prompt {j} coalesced {together[j]} != "
+                    f"alone {alone[0]}")
+    again, _ = engine.serve_group(prompts, size.new_tokens)
+    require(all(np.array_equal(a, b) for a, b in zip(again, together)),
+            "serve_mimo: a repeated group differs")
+    dev = _ctx_for(platform).jax_device
+    stats = dev.memory_stats()
+    peak = stats["peak_bytes_in_use"] if stats else None
+    say(f"[serve_mimo] group of {len(prompts)} (prompts "
+        f"{size.prompt_lens}) x {size.new_tokens} tokens through bucket "
+        f"{timing['bucket']}: first call {first:.1f}s, then "
+        f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
+        f"counters { {k: v for k, v in timing.items() if k.startswith('moe')} }; "
+        f"peak bytes in use {peak}")
+    return {"params": n_params, "programs": engine.program_count(),
+            "retraces": serving.trace_count() - pinned,
+            "peak_bytes": peak}
+
+
 # -- sharded -------------------------------------------------------------------
 
 SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
@@ -561,6 +702,8 @@ def main():
     run("kernel", phase_kernel, FULL, platform)
     train = run("train", phase_train, FULL, platform)
     run("serve", phase_serve, FULL, platform, train.pop("net"))
+    gc.collect()
+    run("serve_mimo", phase_serve_mimo, mimo_full(), platform)
     gc.collect()
     import jax
 

@@ -1283,6 +1283,20 @@ inline std::vector<NDArray> moe_ffn(
   return op_.Invoke();
 }
 
+// moe_share_ffn(data, router_weight, router_bias, w13, w2; attrs: k, experts_lo, pass_rows, output_stats)
+inline std::vector<NDArray> moe_share_ffn(
+    const NDArray &data, const NDArray &router_weight, const NDArray &router_bias, const NDArray &w13, const NDArray &w2,
+    const std::vector<std::pair<std::string, std::string>> &attrs = {}) {
+  Operator op_("moe_share_ffn");
+  op_.AddInput(data);
+  op_.AddInput(router_weight);
+  op_.AddInput(router_bias);
+  op_.AddInput(w13);
+  op_.AddInput(w2);
+  for (const auto &kv : attrs) op_.SetParam(kv.first, kv.second);
+  return op_.Invoke();
+}
+
 // multi_head_attention(query, key, value; attrs: qkv_weight, qkv_bias, proj_weight, proj_bias, num_heads, mask, causal, impl)
 inline std::vector<NDArray> multi_head_attention(
     const NDArray &query, const NDArray &key, const NDArray &value,

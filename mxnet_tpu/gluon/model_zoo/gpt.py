@@ -72,6 +72,11 @@ class GPTModel(HybridBlock):
         logits = F.dot(flat, tok_embed_weight, transpose_b=True)
         return F.reshape(logits, shape=(-1, T, self._vocab))
 
+    def decoder_program(self, dtype=None, mesh=None, tp_axis="tp"):
+        """What `serving.ServingEngine` serves this family through."""
+        return GPTDecoderProgram(self, dtype=dtype, mesh=mesh,
+                                 tp_axis=tp_axis)
+
 
 def _lm_loss_pure(logits, labels):
     """Shifted next-token cross-entropy; labels < 0 are ignored —
@@ -245,6 +250,272 @@ def extract_decoder_stacks(model):
 
     return (stacks, (lnf_g, lnf_b), get1("tok_embed_weight"),
             get1("pos_embed_weight"), num_heads, act)
+
+
+def stacks_from_state(state):
+    """Rebuild (stacks, lnf, tok, pos) from a flat name→array state dict
+    (scanned-trunk convention: scan_layers=True param names)."""
+    import jax.numpy as jnp
+
+    from ...base import MXNetError
+
+    def get1(suffix):
+        ks = [k for k in state if k.endswith(suffix)]
+        if len(ks) != 1:
+            raise MXNetError(
+                f"serving reload: expected exactly one param ending "
+                f"{suffix!r} in the checkpoint state, found {ks}")
+        return jnp.asarray(state[ks[0]])
+
+    if not any(k.endswith("qkv_stack_weight") for k in state):
+        raise MXNetError(
+            "serving reload: checkpoint state lacks the scanned-trunk "
+            "(*_stack_*) parameter convention; save the model with "
+            "scan_layers=True (serving.state_for_serving) or reload "
+            "from a live model via reload_from_model")
+    stacks = {nm: get1(nm) for nm in STACK_NAMES}
+    return (stacks, (get1("lnf_gamma"), get1("lnf_beta")),
+            get1("tok_embed_weight"), get1("pos_embed_weight"))
+
+
+class GPTDecoderProgram:
+    """GPT's decoder program for `serving.ServingEngine`
+    (docs/serving.md): ``weights()``, ``init_cache(B)``,
+    ``step(w, cache, pos, last, toks)``.
+
+    The cache is one ``(L, B, H, W, Dh)`` pair, stage-major like the
+    ``*_stack_*`` weights; the layer loop *carries* it whole: a layer
+    writes its new ``(B, H, S, Dh)`` rows into the stack and attends
+    over its own slice of it.  Under a ``mesh`` the weight stacks follow
+    the Megatron column/row split of TRANSFORMER_TP_RULES and the cache
+    shards on its head axis (parallel/sharding.serving_cache_sharding).
+    """
+
+    def __init__(self, model, dtype=None, mesh=None, tp_axis="tp"):
+        from ...base import MXNetError
+
+        self._mesh, self._tp_axis, self._dtype = mesh, tp_axis, dtype
+        self.window = model._max_length
+        (stacks, lnf, tok, pos, self._H,
+         self._act) = extract_decoder_stacks(model)
+        self._C = int(tok.shape[1])
+        self._L = int(stacks["qkv_stack_weight"].shape[0])
+        self.vocab = int(tok.shape[0])
+        # what a reloaded model must share beyond its shapes
+        self.signature = (self._H, self._act)
+        if mesh is not None:
+            n_tp = mesh.shape[tp_axis]
+            F = int(stacks["ffn1_stack_weight"].shape[1])
+            if self._H % n_tp or F % n_tp:
+                raise MXNetError(
+                    f"ServingEngine: tp axis size {n_tp} must divide "
+                    f"num_heads={self._H} and ffn hidden={F}")
+        self._w = self._prepare(stacks, lnf, tok, pos)
+        # how this platform lays a cache out on the device (a v5e puts W
+        # minor-most where Dh is under 128): read off one, not assumed
+        self._cache_layout = self.init_cache(1)[0].format.layout
+
+    # -- weights ---------------------------------------------------------------
+
+    def _shard(self, arr, spec):
+        if self._mesh is None:
+            return arr
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return jax.device_put(arr,
+                              NamedSharding(self._mesh, P(*spec)))
+
+    def _prepare(self, stacks, lnf, tok, pos):
+        """Head-/hidden-major restructure + serving dtype + tp placement
+        (the same Megatron column/row layout CachedDecoder._build
+        derives, but produced as a flat argument tuple so the compiled
+        programs take weights as inputs — the hot-reload contract)."""
+        s = dict(stacks)
+        if self._dtype is not None:
+            for nm in ("qkv_stack_weight", "proj_stack_weight",
+                       "ffn1_stack_weight", "ffn2_stack_weight"):
+                s[nm] = s[nm].astype(self._dtype)
+            tok = tok.astype(self._dtype)
+            pos = pos.astype(self._dtype)
+        L, H, C = self._L, self._H, self._C
+        Dh = C // H
+        tp = self._tp_axis
+        qkvw = self._shard(s["qkv_stack_weight"].reshape(L, 3, H, Dh, C),
+                           (None, None, tp))
+        qkvb = self._shard(s["qkv_stack_bias"].reshape(L, 3, H, Dh),
+                           (None, None, tp))
+        pwh = self._shard(s["proj_stack_weight"].reshape(L, C, H, Dh),
+                          (None, None, tp))
+        f1w = self._shard(s["ffn1_stack_weight"], (None, tp))
+        f1b = self._shard(s["ffn1_stack_bias"], (None, tp))
+        f2w = self._shard(s["ffn2_stack_weight"], (None, None, tp))
+        rep = ()
+        return (self._shard(tok, rep), self._shard(pos, rep),
+                qkvw, qkvb, pwh, self._shard(s["proj_stack_bias"], rep),
+                f1w, f1b, f2w, self._shard(s["ffn2_stack_bias"], rep),
+                self._shard(s["ln1_stack_gamma"], rep),
+                self._shard(s["ln1_stack_beta"], rep),
+                self._shard(s["ln2_stack_gamma"], rep),
+                self._shard(s["ln2_stack_beta"], rep),
+                self._shard(lnf[0], rep), self._shard(lnf[1], rep))
+
+    def weights(self):
+        return self._w
+
+    def weights_from_state(self, state):
+        """The weight tuple of an AsyncCheckpointer state dict
+        (``serving.state_for_serving`` convention)."""
+        from ...base import MXNetError
+
+        stacks, lnf, tok, pos = stacks_from_state(state)
+        got = tuple(stacks["qkv_stack_weight"].shape)
+        want = (self._L, 3 * self._C, self._C)
+        if got != want:
+            raise MXNetError(
+                f"serving reload: weight mismatch — qkv stack {got} vs "
+                f"compiled {want}; a mismatched swap would force a "
+                f"retrace on the request path")
+        return self._prepare(stacks, lnf, tok, pos)
+
+    # -- cache -----------------------------------------------------------------
+
+    def _cache_sharding(self):
+        from ...parallel.sharding import serving_cache_sharding
+
+        return serving_cache_sharding(self._mesh, tp_axis=self._tp_axis)
+
+    def init_cache(self, B):
+        """Fresh zeroed (ck, cv) for batch bucket B: stage-major
+        (L, B, H, W, Dh), serving dtype, head-sharded under tp."""
+        import jax.numpy as jnp
+
+        tok = self._w[0]
+        shape = (self._L, B, self._H, self.window, self._C // self._H)
+        # committed next to the weights: the engine serves from the
+        # device(s) the model was placed on, never from the process
+        # default
+        where = tok.sharding if self._mesh is None \
+            else self._cache_sharding()
+        return (jnp.zeros(shape, tok.dtype, device=where),
+                jnp.zeros(shape, tok.dtype, device=where))
+
+    # -- the traced block step -------------------------------------------------
+
+    def step(self, w, cache, pos, last, toks):
+        """cache = (ck, cv), each (L, B, H, W, Dh), donated; pos (B,)
+        per-row write offsets; last (B,) the index in the block of each
+        row's last real token; toks (B, S) int32.  Returns ((ck', cv'),
+        logits (B, vocab)) at ``last``.  S = seq bucket for prefill, 1
+        for decode."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from jax.experimental.layout import with_layout_constraint
+
+        from ...ops.nn import layer_norm
+
+        H, W = self._H, self.window
+        Dh = self._C // H
+        act = self._act
+        mesh = self._mesh
+        cache_ns = self._cache_sharding() if mesh is not None else None
+        cache_layout = self._cache_layout
+
+        def keep_layout(c):
+            return with_layout_constraint(c, cache_layout)
+
+        if mesh is not None:
+            # the constraint has no partitioning rule (the partitioner
+            # would gather the cache to apply it), so each shard pins
+            # its own
+            keep_layout = jax.shard_map(
+                keep_layout, mesh=mesh, in_specs=cache_ns.spec,
+                out_specs=cache_ns.spec)
+
+        ck, cv = cache
+        (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
+         g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
+        B, S = toks.shape
+        with jax.named_scope("serve.embed"):
+            positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
+            x = (jnp.take(tok_e, toks, axis=0) +
+                 jnp.take(pos_e, positions, axis=0)
+                 ).astype(jnp.float32)                     # (B, S, C)
+
+        def write(c, new, l):
+            """Row b's new (H, S, Dh) block into the carried stack
+            at [l, b, :, pos[b]:pos[b] + S, :], and nothing else: one
+            dynamic_update_slice a row, each at that row's own
+            offset (a start that would run past W is clamped)."""
+            new = new.astype(c.dtype)
+            zero = jnp.int32(0)
+            for b in range(B):
+                c = lax.dynamic_update_slice(
+                    c, new[b][None, None],
+                    (l, jnp.int32(b), zero, pos[b], zero))
+            # keep the stack in the layout the donated buffer came
+            # in: left to itself the TPU compiler re-lays the whole
+            # cache around the loop to make these writes cheaper
+            return keep_layout(c)
+
+        def layer(carry, per):
+            x, ck, cv = carry
+            (qw, qb, pw, pb_l, f1w_l, f1b_l, f2w_l, f2b_l,
+             g1, b1, g2, b2, l) = per
+            with jax.named_scope("serve.attn_qkv"):
+                h = layer_norm(x, g1, b1)
+                qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
+                qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
+                kh = qkv[:, :, 1].swapaxes(1, 2)
+                vh = qkv[:, :, 2].swapaxes(1, 2)
+            with jax.named_scope("serve.cache_write"):
+                ck = write(ck, kh, l)
+                cv = write(cv, vh, l)
+            with jax.named_scope("serve.attn"):
+                ck_l = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
+                cv_l = lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
+                scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
+                    * (Dh ** -0.5)
+                # per-row causal mask: row b at block offset s may
+                # see cache slots <= pos[b] + s (stale pad garbage
+                # beyond is invisible — the overwrite-before-attend
+                # invariant)
+                mask = jnp.arange(W)[None, None, :] <= \
+                    (pos[:, None, None] +
+                     jnp.arange(S)[None, :, None])         # (B, S, W)
+                scores = jnp.where(mask[:, None], scores, -1e30)
+                p = jax.nn.softmax(scores, axis=-1)
+                attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
+                attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
+                x = x + attn
+            with jax.named_scope("serve.mlp"):
+                h = layer_norm(x, g2, b2)
+                h = h @ f1w_l.T + f1b_l
+                h = jax.nn.gelu(h) if act == "gelu" \
+                    else jnp.maximum(h, 0)
+                x = x + (h @ f2w_l.T + f2b_l)
+            return (x, ck, cv), None
+
+        # the cache is carried, not scanned: a scanned input is
+        # sliced a layer at a time and a scanned output is a new
+        # stacked buffer, which cost a copy of every layer's keys
+        # and values each way
+        per_layer = (qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
+                     g1s, b1s, g2s, b2s,
+                     jnp.arange(ck.shape[0], dtype=jnp.int32))
+        (x, ck2, cv2), _ = lax.scan(layer, (x, ck, cv), per_layer)
+        with jax.named_scope("serve.head"):
+            # the head reads one position a row: the last real token's
+            h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            logits = layer_norm(h, lnf_g, lnf_b) @ tok_e.T
+        if cache_ns is not None:
+            # pin the donated buffers' output layout to the input
+            # layout, so the next AOT call sees identical shardings
+            ck2 = lax.with_sharding_constraint(ck2, cache_ns)
+            cv2 = lax.with_sharding_constraint(cv2, cache_ns)
+        return (ck2, cv2), logits
 
 
 class CachedDecoder:

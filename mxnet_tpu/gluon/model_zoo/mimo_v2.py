@@ -1,0 +1,674 @@
+"""MiMo-V2 (``model_type`` ``mimo_v2``) language-model family.
+
+A decoder whose layers are of two kinds, listed by ``layer_types``:
+
+- **full** layers attend over every earlier position, with few
+  key/value heads; **window** layers attend over the last ``window``
+  positions, with their own number of key/value heads, their own rotary
+  base and one learnable *sink* logit per query head in the softmax's
+  denominator;
+- query and key heads are ``qk_dim`` wide with rotary positions on the
+  first ``rotary_dim`` (dimension d paired with d + rotary_dim/2), value
+  heads ``v_dim`` wide and scaled by ``value_scale``; query head h reads
+  key/value head ``h // (heads / kv heads)``;
+- RMSNorm, no bias, an unscaled embedding, an untied head;
+- the feed-forward is a dense SwiGLU, or (``moe_layers``) sigmoid-routed
+  experts of which this block holds ``experts_held = (lo, n)``: a chip's
+  share of an expert-parallel deployment (`ops/moe.py::moe_share_ffn`).
+  The router scores all ``router_experts``; what the absent experts
+  would add is left out, and nothing stands in for their exchange.
+
+``hybrid_forward`` is the uncached full-sequence forward.
+``decoder_program`` hands `serving.ServingEngine` the family's cached
+step (docs/serving.md, "The decoder program"): two kinds of cache, four
+stacks, each carried, donated and written in place:
+
+- full layers: keys ``(Lf, B, Hkv, W, qk_dim)`` and values
+  ``(.., v_dim)``; row b's block lands at ``pos[b] .. pos[b] + S``;
+- window layers: a ring of ``window`` slots, ``(Lw, B, Hkv', window,
+  ..)``: position p lives in slot ``p mod window``.  Prefill attends
+  inside its own block (the engine always prefills from position 0) and
+  then writes each row's last ``min(length, window)`` positions; decode
+  writes one slot and attends over the ring, masking slots not yet
+  written (a slot's position is the latest one congruent to it, so it
+  is never older than the window);
+- a small int32 array of expert-layer counters rides in the same
+  donated carry and is read back once a group (``counters``).
+
+Prefill attention runs in blocks of ``attn_block`` keys with a running
+maximum and sum (the sink enters the sum once), window layers visiting
+only the blocks their window reaches; no ``(B, H, S, W)`` array exists
+for S > 1.  Rows are worked off ``prefill_chunk_tokens`` tokens at a
+time through attention and the dense feed-forward, so that a bucket of
+64 x 1,024 tokens fits beside the weights.
+"""
+
+from __future__ import annotations
+
+
+from ...base import MXNetError
+from ..block import HybridBlock
+
+_MASKED = -1e30
+
+
+# -- pieces shared by the forward pass and the cached step ---------------------
+
+def _mm(spec, a, w):
+    """The one mixed-precision product: the activation in the weight's
+    type, the result float32."""
+    import jax.numpy as jnp
+
+    return jnp.einsum(spec, a.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def _rms_norm(x, g, eps):
+    import jax.numpy as jnp
+    from jax import lax
+
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                         + eps) * g.astype(jnp.float32)
+
+
+def _rope(x, pos, theta, rot):
+    """x (B, .., S, D) float32 rotated on its first ``rot`` dimensions at
+    positions ``pos`` (B, S)."""
+    import jax.numpy as jnp
+
+    half = rot // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    ang = pos.astype(jnp.float32)[..., None] * freq          # (B, S, half)
+    shape = (pos.shape[0],) + (1,) * (x.ndim - 3) + (pos.shape[1], half)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    a, b = x[..., :half], x[..., half:rot]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x[..., rot:]], axis=-1)
+
+
+def _attend_blocks(q, k, v, sink, window, blk):
+    """Causal attention of a block of S positions over itself, in key
+    blocks of ``blk`` with a running maximum and sum.
+
+    q (B, K, G, S, D) scaled; k (B, K, S, D); v (B, K, S, Dv); ``sink``
+    (K, G) float32 or None; ``window`` positions or None for all.
+    Offset o pairs query block n with key block n - o, so a window layer
+    makes ``1 + ceil((window - 1) / blk)`` passes and a full layer one
+    per block.  Returns (B, K, G, S, Dv) float32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    B, K, G, S, D = q.shape
+    Dv = v.shape[-1]
+    nb = S // blk
+    n_off = nb if window is None else min(nb, 1 + -(-(window - 1) // blk))
+    qb = q.reshape(B, K, G, nb, blk, D)
+    front = (n_off - 1) * blk
+    kp = jnp.pad(k, ((0, 0), (0, 0), (front, 0), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (0, 0), (front, 0), (0, 0)))
+    at = jnp.arange(blk)
+    q_at = jnp.arange(nb)[:, None, None] * blk + at[None, :, None]
+
+    def one_offset(o, carry):
+        m, l, acc = carry
+        start = (n_off - 1 - o) * blk
+        ko = lax.dynamic_slice_in_dim(kp, start, S, axis=2
+                                      ).reshape(B, K, nb, blk, D)
+        vo = lax.dynamic_slice_in_dim(vp, start, S, axis=2
+                                      ).reshape(B, K, nb, blk, Dv)
+        s = jnp.einsum("bkgnqd,bknsd->bkgnqs", qb, ko,
+                       preferred_element_type=jnp.float32)
+        k_at = (jnp.arange(nb)[:, None, None] - o) * blk + at[None, None, :]
+        seen = (k_at >= 0) & (k_at <= q_at)
+        if window is not None:
+            seen = seen & (k_at > q_at - window)
+        s = jnp.where(seen, s, _MASKED)
+        m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m2[..., None])
+        scale = jnp.exp(m - m2)
+        acc = acc * scale[..., None] + jnp.einsum(
+            "bkgnqs,bknsd->bkgnqd", p.astype(vo.dtype), vo,
+            preferred_element_type=jnp.float32)
+        return m2, l * scale + jnp.sum(p, axis=-1), acc
+
+    stat = (B, K, G, nb, blk)
+    if sink is None:
+        m0, l0 = jnp.full(stat, _MASKED, jnp.float32), jnp.zeros(stat)
+    else:
+        # the sink is one more key, of no value: it opens the running sum
+        m0 = jnp.broadcast_to(sink[None, :, :, None, None], stat)
+        l0 = jnp.ones(stat)
+    _, l, acc = lax.fori_loop(
+        0, n_off, one_offset,
+        (m0.astype(jnp.float32), l0.astype(jnp.float32),
+         jnp.zeros(stat + (Dv,), jnp.float32)))
+    return (acc / l[..., None]).reshape(B, K, G, S, Dv)
+
+
+def _attend_cache(q, ck, cv, seen, sink):
+    """One query position a row over a cache layer: q (B, K, G, D)
+    scaled; ck (B, K, W, D); cv (B, K, W, Dv); ``seen`` (B, W) bool;
+    ``sink`` (K, G) or None.  Returns (B, K, G, Dv) float32."""
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bkgd,bkwd->bkgw", q, ck,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(seen[:, None, None, :], s, _MASKED)
+    m = jnp.max(s, axis=-1)
+    if sink is not None:
+        m = jnp.maximum(m, sink[None])
+    p = jnp.exp(s - m[..., None])
+    denom = jnp.sum(p, axis=-1)
+    if sink is not None:
+        denom = denom + jnp.exp(sink[None] - m)
+    a = jnp.einsum("bkgw,bkwd->bkgd", p.astype(cv.dtype), cv,
+                   preferred_element_type=jnp.float32)
+    return a / denom[..., None]
+
+
+class _Sizes:
+    """The family's sizes, as the constructor got them."""
+
+    def __init__(self, **kw):
+        self.__dict__.update(kw)
+        self.moe_at = [i for i, m in enumerate(self.moe_layers) if m]
+        self.of_kind = {t: [i for i, x in enumerate(self.layer_types)
+                            if x == t] for t in ("full", "window")}
+
+    def layer_names(self, i):
+        names = ["ln1_gamma", "q_weight", "k_weight", "v_weight",
+                 "o_weight"]
+        if self.layer_types[i] == "window":
+            names.append("sink_bias")
+        names.append("ln2_gamma")
+        names += (["router_weight", "router_bias", "experts_gate_up_weight",
+                   "experts_down_weight"] if self.moe_layers[i]
+                  else ["gate_weight", "up_weight", "down_weight"])
+        return names
+
+    def leaf_names(self):
+        return (["embed_weight"]
+                + [f"l{i}_{n}" for i in range(len(self.layer_types))
+                   for n in self.layer_names(i)]
+                + ["lnf_gamma", "head_weight"])
+
+    def shape_of(self, i, name):
+        z = self
+        hkv = z.kv_heads[z.layer_types[i]]
+        n = z.experts_held[1]
+        return {"ln1_gamma": (z.units,), "ln2_gamma": (z.units,),
+                "q_weight": (z.num_heads * z.qk_dim, z.units),
+                "k_weight": (hkv * z.qk_dim, z.units),
+                "v_weight": (hkv * z.v_dim, z.units),
+                "o_weight": (z.units, z.num_heads * z.v_dim),
+                "sink_bias": (z.num_heads,),
+                "gate_weight": (z.hidden_size, z.units),
+                "up_weight": (z.hidden_size, z.units),
+                "down_weight": (z.units, z.hidden_size),
+                "router_weight": (z.router_experts, z.units),
+                "router_bias": (z.router_experts,),
+                "experts_gate_up_weight": (n, z.units, 2 * z.expert_hidden),
+                "experts_down_weight": (n, z.expert_hidden, z.units)}[name]
+
+
+def _qkv(z, kind, p, x, pos):
+    """x (B, S, C) float32 → q (B, K, G, S, D) scaled and rotated,
+    k (B, K, S, D) rotated, v (B, K, S, Dv) scaled; all in the weights'
+    type.  The products are plain ``x Wᵀ``: a weight is read as it lies,
+    and only activations are re-laid."""
+    import jax
+
+    K = z.kv_heads[kind]
+    G, D, Dv = z.num_heads // K, z.qk_dim, z.v_dim
+    B, S, _ = x.shape
+    dt = p["q_weight"].dtype
+    with jax.named_scope("serve.attn_qkv"):
+        u = _rms_norm(x, p["ln1_gamma"], z.eps).astype(dt)
+
+        def heads(w, n, d):
+            return _mm("bsc,gc->bsg", u, w).reshape(B, S, n, d
+                                                    ).transpose(0, 2, 1, 3)
+
+        theta = z.rope_theta[kind]
+        q = _rope(heads(p["q_weight"], K * G, D), pos, theta,
+                  z.rotary_dim) * (D ** -0.5)
+        k = _rope(heads(p["k_weight"], K, D), pos, theta, z.rotary_dim)
+        v = heads(p["v_weight"], K, Dv) * z.value_scale
+        return (q.astype(dt).reshape(B, K, G, S, D), k.astype(dt),
+                v.astype(dt))
+
+
+def _sink(z, kind, p):
+    import jax.numpy as jnp
+
+    if kind != "window":
+        return None
+    K = z.kv_heads[kind]
+    return p["sink_bias"].astype(jnp.float32).reshape(K, z.num_heads // K)
+
+
+def _attn_out(z, p, x, a):
+    """x + a Wo for a (B, K, G, S, Dv): heads side by side, then the
+    plain product."""
+    B, K, G, S, Dv = a.shape
+    a = a.transpose(0, 3, 1, 2, 4).reshape(B, S, K * G * Dv)
+    return x + _mm("bsg,cg->bsc", a, p["o_weight"])
+
+
+def _dense(z, p, x):
+    import jax
+
+    with jax.named_scope("serve.mlp"):
+        u = _rms_norm(x, p["ln2_gamma"], z.eps)
+        h = jax.nn.silu(_mm("bsc,fc->bsf", u, p["gate_weight"])) \
+            * _mm("bsc,fc->bsf", u, p["up_weight"])
+        return x + _mm("bsf,cf->bsc", h, p["down_weight"])
+
+
+def _route(z, p, x):
+    """Layer's second norm and its router on x (B, S, C): (u in the
+    experts' type, chosen (B, S, k), weights (B, S, k))."""
+    import jax
+
+    from ...ops import moe
+
+    B, S, C = x.shape
+    with jax.named_scope("serve.moe.route"):
+        u = _rms_norm(x, p["ln2_gamma"], z.eps)
+        chosen, weights = moe.sigmoid_topk_route(
+            u.reshape(B * S, C), p["router_weight"], p["router_bias"],
+            z.experts_per_token)
+        k = z.experts_per_token
+        return (u.astype(p["experts_down_weight"].dtype),
+                chosen.reshape(B, S, k), weights.reshape(B, S, k))
+
+
+def _experts(z, p, x, route, valid):
+    """x + the held experts' part for the routed tokens; also
+    `held_experts_ffn`'s counts."""
+    import jax
+
+    from ...ops import moe
+
+    B, S, C = x.shape
+    u, chosen, weights = route
+    k = z.experts_per_token
+    with jax.named_scope("serve.moe.experts"):
+        y, stats = moe.held_experts_ffn(
+            u.reshape(B * S, C), chosen.reshape(B * S, k),
+            weights.reshape(B * S, k), p["experts_gate_up_weight"],
+            p["experts_down_weight"], experts_lo=z.experts_held[0],
+            valid=None if valid is None else valid.reshape(B * S),
+            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C))
+        return y.reshape(B, S, C), stats
+
+
+def _feed_forward_front(z, i, p, x):
+    """What of layer i's feed-forward a token needs no other token for:
+    the whole dense SwiGLU, or the norm and the router.  Returns
+    (x, route or ())."""
+    if z.moe_layers[i]:
+        return x, _route(z, p, x)
+    return _dense(z, p, x), ()
+
+
+def _by_rows(fn, rows, x, pos):
+    """``fn(x, pos) -> (x, extras)`` over the rows of a block, ``rows``
+    at a time and one chunk after another, so that only one chunk's
+    temporaries are alive: the residual stream is updated where it
+    lies, and the extras (keys, values, the router's choice) fill
+    buffers of their own.  Whole when one chunk holds every row."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    B = x.shape[0]
+    if rows >= B:
+        return fn(x, pos)
+
+    def chunk(a, c):
+        return lax.dynamic_slice_in_dim(a, c * rows, rows, axis=0)
+
+    _, shapes = jax.eval_shape(fn, chunk(x, 0), chunk(pos, 0))
+    extras = jax.tree_util.tree_map(
+        lambda s: jnp.zeros((B,) + s.shape[1:], s.dtype), shapes)
+
+    def one(c, carry):
+        x, extras = carry
+        xc, ex = fn(chunk(x, c), chunk(pos, c))
+        put = lambda whole, part: lax.dynamic_update_slice_in_dim(
+            whole, part, c * rows, axis=0)
+        return put(x, xc), jax.tree_util.tree_map(put, extras, ex)
+
+    return lax.fori_loop(0, B // rows, one, (x, extras))
+
+
+def _chunk_rows(z, B, S):
+    """Rows a chunk: about ``prefill_chunk_tokens`` tokens, a divisor
+    of B."""
+    rows = max(1, min(B, z.prefill_chunk_tokens // S))
+    while B % rows:
+        rows -= 1
+    return rows
+
+
+def _block_layer(z, i, p, x, pos):
+    """Layer i on a block (B, S, C) that attends inside itself, as far
+    as a row needs no other row: attention, then the dense feed-forward
+    or the router.  Returns (x, (k, v, route))."""
+    import jax
+
+    kind = z.layer_types[i]
+    blk = min(x.shape[1], z.attn_block)
+    q, k, v = _qkv(z, kind, p, x, pos)
+    with jax.named_scope(f"serve.attn_{kind}"):
+        a = _attend_blocks(q, k, v, _sink(z, kind, p),
+                           z.window if kind == "window" else None, blk)
+        x = _attn_out(z, p, x, a)
+    x, route = _feed_forward_front(z, i, p, x)
+    return x, (k, v, route)
+
+
+def _forward(z, names, ids, *weights):
+    """(B, T) ids → (B, T, vocab) float32 logits, no cache."""
+    import jax.numpy as jnp
+
+    w = dict(zip(names, weights))
+    ids = ids.astype(jnp.int32)
+    B, T = ids.shape
+    blk = min(T, z.attn_block)
+    pad = -T % blk
+    ids = jnp.pad(ids, ((0, 0), (0, pad)))
+    S = T + pad
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    valid = pos < T
+    x = jnp.take(w["embed_weight"], ids, axis=0).astype(jnp.float32)
+    rows = _chunk_rows(z, B, S)
+    for i in range(len(z.layer_types)):
+        p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
+        x, (_, _, route) = _by_rows(
+            lambda x, pos, i=i, p=p: _block_layer(z, i, p, x, pos),
+            rows, x, pos)
+        if route:
+            x, _ = _experts(z, p, x, route, valid)
+    h = _rms_norm(x[:, :T], w["lnf_gamma"], z.eps)
+    return _mm("btc,vc->btv", h, w["head_weight"])
+
+
+class MiMoV2Model(HybridBlock):
+    """Embedding → ``layer_types`` layers → RMSNorm → untied head.
+    Input (B, T) token ids, output (B, T, vocab) float32 logits.
+
+    Parameters are created in ``dtype`` (a float32 copy of a large
+    share does not fit a chip).  ``grad_req="null"`` keeps a serving
+    copy from allocating gradients."""
+
+    def __init__(self, vocab_size, units, layer_types, moe_layers,
+                 num_heads, kv_heads, swa_kv_heads, qk_dim, v_dim,
+                 rotary_dim, window, rope_theta, swa_rope_theta,
+                 hidden_size, expert_hidden, router_experts,
+                 experts_per_token, experts_held=None, value_scale=1.0,
+                 eps=1e-5, max_length=2048, dtype="float32",
+                 grad_req="write", attn_block=128,
+                 prefill_chunk_tokens=4096, moe_pass_rows=None, **kwargs):
+        super().__init__(**kwargs)
+        layer_types, moe_layers = list(layer_types), list(moe_layers)
+        if len(layer_types) != len(moe_layers) or \
+                set(layer_types) - {"full", "window"}:
+            raise MXNetError(
+                "MiMoV2Model: layer_types lists 'full' / 'window', and "
+                "moe_layers has one entry a layer")
+        held = tuple(experts_held or (0, router_experts))
+        if held[0] < 0 or held[0] + held[1] > router_experts:
+            raise MXNetError(f"MiMoV2Model: experts_held {held} lies "
+                             f"outside the router's {router_experts}")
+        self._max_length = max_length
+        self._vocab = vocab_size
+        self._sizes = z = _Sizes(
+            vocab=vocab_size, units=units, layer_types=layer_types,
+            moe_layers=[bool(m) for m in moe_layers], num_heads=num_heads,
+            kv_heads={"full": kv_heads, "window": swa_kv_heads},
+            qk_dim=qk_dim, v_dim=v_dim, rotary_dim=rotary_dim,
+            window=window,
+            rope_theta={"full": float(rope_theta),
+                        "window": float(swa_rope_theta)},
+            hidden_size=hidden_size, expert_hidden=expert_hidden,
+            router_experts=router_experts,
+            experts_per_token=experts_per_token, experts_held=held,
+            value_scale=float(value_scale), eps=float(eps),
+            max_length=max_length, attn_block=attn_block,
+            prefill_chunk_tokens=prefill_chunk_tokens,
+            moe_pass_rows=moe_pass_rows)
+        self._names = z.leaf_names()
+
+        def add(name, shape):
+            setattr(self, name, self.params.get(
+                name, shape=shape, dtype=dtype, grad_req=grad_req))
+
+        with self.name_scope():
+            add("embed_weight", (vocab_size, units))
+            for i in range(len(layer_types)):
+                for n in z.layer_names(i):
+                    add(f"l{i}_{n}", z.shape_of(i, n))
+            add("lnf_gamma", (units,))
+            add("head_weight", (vocab_size, units))
+
+    def hybrid_forward(self, F, ids, **params):
+        import functools
+
+        from ...ndarray.register import invoke_simple
+
+        fn = functools.partial(_forward, self._sizes, tuple(self._names))
+        fn.__name__ = "mimo_v2_forward"
+        return invoke_simple(fn, (ids,) + tuple(params[n]
+                                                 for n in self._names))
+
+    def decoder_program(self, dtype=None, mesh=None, tp_axis="tp"):
+        """What `serving.ServingEngine` serves this family through."""
+        if mesh is not None:
+            raise MXNetError(
+                "MiMoV2Model serves from one chip: its experts are a "
+                "share of a deployment whose exchange this repo does not "
+                "have (mesh= is not supported for this family)")
+        return MiMoV2Program(self, dtype)
+
+
+class MiMoV2Program:
+    """The family's decoder program (docs/serving.md): ``weights()``,
+    ``init_cache(B)``, ``step(w, cache, pos, last, toks)``."""
+
+    def __init__(self, model, dtype=None):
+        self._model = model
+        self._z = model._sizes
+        self._dtype = dtype
+        self.window = model._max_length
+        self.vocab = model._vocab
+        self._pins = None
+        z = self._z
+        # what a reloaded model must share beyond its shapes
+        self.signature = (tuple(z.layer_types), tuple(z.moe_layers),
+                          z.experts_held, z.window, z.rotary_dim,
+                          tuple(z.rope_theta.items()), z.value_scale)
+
+    def weights(self):
+        """The parameters' own buffers, in the order of their names: no
+        second copy, unless ``dtype`` asks for another type than a
+        parameter has."""
+        out = []
+        for n in self._model._names:
+            a = getattr(self._model, n).data()._data
+            if self._dtype is not None and a.dtype != self._dtype:
+                a = a.astype(self._dtype)
+            out.append(a)
+        return tuple(out)
+
+    def _counter_shape(self):
+        z = self._z
+        return (max(1, len(z.moe_at)), 2, z.experts_held[1] + 3)
+
+    def init_cache(self, B):
+        """(full keys, full values, window keys, window values,
+        counters), zeroed, beside the embedding."""
+        import jax.numpy as jnp
+
+        z = self._z
+        emb = self._model.embed_weight.data()._data
+        Lf = max(1, len(z.of_kind["full"]))
+        Lw = max(1, len(z.of_kind["window"]))
+        Kf, Kw = z.kv_heads["full"], z.kv_heads["window"]
+
+        kv_dtype = self._dtype or emb.dtype
+
+        def zeros(shape, dtype=kv_dtype):
+            return jnp.zeros(shape, dtype, device=emb.sharding)
+
+        cache = (zeros((Lf, B, Kf, self.window, z.qk_dim)),
+                 zeros((Lf, B, Kf, self.window, z.v_dim)),
+                 zeros((Lw, B, Kw, z.window, z.qk_dim)),
+                 zeros((Lw, B, Kw, z.window, z.v_dim)),
+                 zeros(self._counter_shape(), jnp.int32))
+        if self._pins is None:
+            # each stack stays in the layout its donated buffer came in:
+            # read off an allocated cache, as GPT's program does
+            self._pins = [c.format.layout for c in cache[:4]]
+        return cache
+
+    def counters(self, cache):
+        """The expert layers' counters of one served group, read back
+        once (docs/observability.md has the table)."""
+        import numpy as np
+
+        z = self._z
+        if not z.moe_at:
+            return {}
+        c = np.asarray(cache[4]).astype(np.int64)
+        n = z.experts_held[1]
+        load = c[:, :, :n]
+        total = load.sum(axis=1)                        # (Lm, n)
+        decode_calls = int(c[0, 1, n + 2])
+        return {
+            "moe_pairs_prefill": int(load[:, 0].sum()),
+            "moe_pairs_decode": int(load[:, 1].sum()),
+            "moe_rows_computed_prefill": int(c[:, 0, n].sum()),
+            "moe_rows_computed_decode": int(c[:, 1, n].sum()),
+            "moe_experts_hit_per_step":
+                float(c[:, 1, n + 1].sum()) / (decode_calls * len(c))
+                if decode_calls else 0.0,
+            "moe_load_max_over_mean": float(np.mean(
+                total.max(axis=1) / np.maximum(total.mean(axis=1), 1e-9))),
+        }
+
+    # -- the traced step -------------------------------------------------------
+
+    def step(self, w, cache, pos, last, toks):
+        """cache donated; pos (B,) each row's first position; last (B,)
+        the index in the block of each row's last real token; toks
+        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
+        S > 1 is a prefill from an empty cache: it attends inside the
+        block.  S = 1 attends over the caches."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from jax.experimental.layout import with_layout_constraint
+
+        z = self._z
+        w = dict(zip(self._model._names, w))
+        fk, fv, wk, wv, counts = cache
+        pins = self._pins      # `init_cache` read them off a real cache
+        B, S = toks.shape
+        decode = S == 1
+        R = z.window
+        zero = jnp.int32(0)
+        with jax.named_scope("serve.embed"):
+            x = jnp.take(w["embed_weight"], toks, axis=0
+                         ).astype(jnp.float32)
+            at = pos[:, None] + jnp.arange(S)[None, :]            # (B, S)
+            valid = jnp.arange(S)[None, :] <= last[:, None]
+
+        def write_rows(c, new, l, starts, pin):
+            """Row b's (K, S', D) block into stack ``c`` at
+            [l, b, :, starts[b]:, :]: one dynamic_update_slice a row."""
+            new = new.astype(c.dtype)
+            for b in range(B):
+                c = lax.dynamic_update_slice(
+                    c, new[b][None, None],
+                    (jnp.int32(l), jnp.int32(b), zero, starts[b], zero))
+            return c if pin is None else with_layout_constraint(c, pin)
+
+        def ring_of(k):
+            """The ring a prefilled row leaves: slot s holds the latest
+            position congruent to s that is not past the row's last."""
+            slot = jnp.arange(R)[None, :]
+            p_s = last[:, None] - (last[:, None] - slot) % R      # (B, R)
+            got = jnp.take_along_axis(
+                k, jnp.clip(p_s, 0, S - 1)[:, None, :, None], axis=2)
+            return jnp.where((p_s >= 0)[:, None, :, None], got, 0)
+
+        rows = _chunk_rows(z, B, S)
+        for i, kind in enumerate(z.layer_types):
+            p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
+            l = z.of_kind[kind].index(i)
+            if decode:
+                q, k, v = _qkv(z, kind, p, x, at)
+            else:
+                x, (k, v, route) = _by_rows(
+                    lambda x, at, i=i, p=p: _block_layer(z, i, p, x, at),
+                    rows, x, at)
+            with jax.named_scope("serve.cache_write"):
+                if kind == "full":
+                    fk = write_rows(fk, k, l, pos, pins[0])
+                    fv = write_rows(fv, v, l, pos, pins[1])
+                elif decode:
+                    wk = write_rows(wk, k, l, pos % R, pins[2])
+                    wv = write_rows(wv, v, l, pos % R, pins[3])
+                else:
+                    at_layer = (jnp.int32(l), zero, zero, zero, zero)
+                    wk = lax.dynamic_update_slice(
+                        wk, ring_of(k).astype(wk.dtype)[None], at_layer)
+                    wv = lax.dynamic_update_slice(
+                        wv, ring_of(v).astype(wv.dtype)[None], at_layer)
+            if decode:
+                with jax.named_scope(f"serve.attn_{kind}"):
+                    if kind == "full":
+                        seen = jnp.arange(self.window)[None, :] \
+                            <= pos[:, None]
+                        ck, cv = fk[l], fv[l]
+                    else:
+                        # slot s holds position pos - (pos - s) mod R,
+                        # if that position exists
+                        slot = jnp.arange(R)[None, :]
+                        seen = pos[:, None] - (pos[:, None] - slot) % R >= 0
+                        ck, cv = wk[l], wv[l]
+                    a = _attend_cache(q[:, :, :, 0], ck, cv, seen,
+                                      _sink(z, kind, p))
+                    x = _attn_out(z, p, x, a[:, :, :, None])
+                x, route = _feed_forward_front(z, i, p, x)
+            if route:
+                # padding is routed nowhere: only real tokens cost
+                x, stats = _experts(z, p, x, route,
+                                    None if decode else valid)
+                n = z.experts_held[1]
+                row = jnp.concatenate([
+                    stats, jnp.sum(stats[:n] > 0, dtype=jnp.int32)[None],
+                    jnp.ones((1,), jnp.int32)])
+                counts = counts.at[z.moe_at.index(i), int(decode)].add(row)
+        with jax.named_scope("serve.head"):
+            h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            logits = _mm("bc,vc->bv", _rms_norm(h, w["lnf_gamma"], z.eps),
+                         w["head_weight"])
+        return (fk, fv, wk, wv, counts), logits
+
+
+def mimo_v2_tiny(**kwargs):
+    """A test-sized member of the family: both kinds of layer, one dense
+    feed-forward and then experts."""
+    cfg = dict(vocab_size=96, units=64,
+               layer_types=["full"] + ["window"] * 5 + ["full"],
+               moe_layers=[0, 1, 1, 1, 1, 1, 1], num_heads=4, kv_heads=1,
+               swa_kv_heads=2, qk_dim=24, v_dim=16, rotary_dim=8, window=4,
+               rope_theta=1e7, swa_rope_theta=1e4, hidden_size=96,
+               expert_hidden=32, router_experts=8, experts_per_token=2,
+               value_scale=0.707, max_length=32, attn_block=4)
+    cfg.update(kwargs)
+    return MiMoV2Model(**cfg)

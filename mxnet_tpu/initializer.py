@@ -188,7 +188,11 @@ class Zero(Initializer):
         super().__init__()
 
     def _init_weight(self, _, arr):
-        self._set(arr, _np.zeros(arr.shape))
+        # filled where it lies: a host array of a large model's zeros
+        # (float64, 8 bytes a parameter) is drawn and shipped for nothing
+        import jax.numpy as jnp
+
+        arr._set_data(jnp.zeros(arr.shape, arr._data.dtype))
 
 
 @register
@@ -197,7 +201,9 @@ class One(Initializer):
         super().__init__()
 
     def _init_weight(self, _, arr):
-        self._set(arr, _np.ones(arr.shape))
+        import jax.numpy as jnp
+
+        arr._set_data(jnp.ones(arr.shape, arr._data.dtype))
 
 
 # reference alias names (mx.init registry: @register(alias=...))

@@ -118,3 +118,112 @@ def moe_ffn(data, gate_weight, w1, b1, w2, b2, num_experts=None, k=1,
     p = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(f * p)
     return y, aux.astype(data.dtype)
+
+
+# -- a chip's share of a routed expert layer, dropless -------------------------
+#
+# Expert parallelism gives each chip some of a layer's experts.  The
+# router still scores all of them; the chip computes what its own
+# experts add for the tokens routed to them, and nothing stands in for
+# the rest (their chips add their parts; on one chip they are left out).
+# Unlike `moe_ffn` above there is no capacity: every assignment that
+# falls to a held expert is computed.  Shapes stay static by sorting the
+# (token, expert) pairs by expert and running a grouped product over a
+# buffer of ``pass_rows`` rows, as many passes as the pairs need: the
+# cost follows the assignments, not tokens x experts held.
+
+def share_pass_rows(tokens, k, held):
+    """Default rows of the grouped product's buffer: a quarter row a
+    token (uniform routing over many experts sends a token to far fewer
+    than ``k`` held ones; what does not fit takes a further pass), at
+    least 256, and never more than the ``tokens * min(k, held)`` pairs
+    the routing can make."""
+    return min(tokens * min(k, held), max(256, tokens // 4))
+
+
+def sigmoid_topk_route(x, router_weight, router_bias, k):
+    """x (T, M) → (chosen (T, k) int32, weights (T, k) float32).
+
+    Scores are ``sigmoid(x Wrᵀ)`` in float32; the chosen experts are the
+    top ``k`` of score + ``router_bias`` (the aux-loss-free correction
+    bias: it moves the choice, not the weight); weights are the chosen
+    scores, normalised to sum to one."""
+    logits = jnp.einsum("tm,em->te", x.astype(jnp.float32),
+                        router_weight.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    score = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(score + router_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(score, chosen, axis=-1)
+    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
+                     pass_rows=None, add_to=None):
+    """Σ over the held experts e of weight · W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x).
+
+    x (T, M); chosen/weights (T, k) from the router, expert ids global;
+    w13 (n, M, 2F), gate beside up; w2 (n, F, M): experts
+    ``experts_lo .. experts_lo + n``.  ``valid`` (T,) bool masks tokens
+    that are padding; ``add_to`` (T, M) float32 is what the sum is added
+    to (the residual stream; zeros if None).  Returns (y (T, M) float32,
+    stats (n + 1,) int32:
+    assignments per held expert, then the rows the grouped product was
+    given, padding included).  No assignment is dropped: the pairs are
+    worked off in passes of ``pass_rows`` rows until none is left."""
+    T, _ = x.shape
+    k = chosen.shape[1]
+    n, _, F2 = w13.shape
+    F = F2 // 2
+    P = int(pass_rows or share_pass_rows(T, k, n))
+    local = chosen - experts_lo
+    here = (local >= 0) & (local < n)
+    if valid is not None:
+        here = here & valid[:, None]
+    key = jnp.where(here, local, n).reshape(-1)              # (T k,)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held first
+    counts = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                     dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts, total = ends - counts, ends[-1]
+    order = jnp.pad(order, (0, -(T * k) % P))
+    flat_w = weights.reshape(-1)
+    xs_all = x.astype(w13.dtype)
+
+    def one_pass(p, y):
+        base = p * P
+        idx = jax.lax.dynamic_slice(order, (base,), (P,))
+        live = base + jnp.arange(P, dtype=jnp.int32) < total
+        tok = idx // k
+        sizes = jnp.clip(ends - base, 0, P) - jnp.clip(starts - base, 0, P)
+        h = jax.lax.ragged_dot(jnp.take(xs_all, tok, axis=0), w13, sizes,
+                               preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(w2.dtype)
+        o = jax.lax.ragged_dot(h, w2, sizes,
+                               preferred_element_type=jnp.float32)
+        # rows past the pairs hold whatever the product left there
+        o = jnp.where(live[:, None], o * flat_w[idx][:, None], 0.0)
+        return y.at[jnp.where(live, tok, T)].add(o, mode="drop")
+
+    passes = (total + P - 1) // P
+    y = jax.lax.fori_loop(
+        0, passes, one_pass,
+        jnp.zeros(x.shape, jnp.float32) if add_to is None else add_to)
+    return y, jnp.concatenate([counts, (passes * P)[None]])
+
+
+@register("moe_share_ffn")
+def moe_share_ffn(data, router_weight, router_bias, w13, w2, k=8,
+                  experts_lo=0, pass_rows=None, output_stats=False):
+    """A chip's share of a sigmoid-routed, dropless expert layer.
+
+    data (..., M); router_weight (E, M) over ALL E experts;
+    router_bias (E,); w13 (n, M, 2F) and w2 (n, F, M): the n experts
+    from ``experts_lo`` that this chip holds.  Returns what those
+    experts add (float32, data's shape); with ``output_stats`` also
+    `held_experts_ffn`'s counts."""
+    x = data.reshape(-1, data.shape[-1])
+    chosen, weights = sigmoid_topk_route(x, router_weight, router_bias, k)
+    y, stats = held_experts_ffn(x, chosen, weights, w13, w2,
+                                experts_lo=experts_lo, pass_rows=pass_rows)
+    y = y.reshape(data.shape)
+    return (y, stats) if output_stats else y

@@ -13,40 +13,35 @@ request path.  Three properties, all pinned by tests/test_serving.py:
   trace counter (incremented as a Python side effect inside the traced
   function, so it ticks exactly once per compile) makes the pin
   checkable: ``trace_count()`` must not move after ``warmup()``.
-- **KV-cache decode.**  The per-layer key/value cache is laid out
-  stage-major — ``(L, B, H, W, Dh)`` with L the scanned-trunk layer
-  axis, matching the ``*_stack_*`` weight stacks
-  (parallel/sharding.py TRANSFORMER_TP_RULES).  The pair is donated
-  and the layer loop *carries* it whole: a layer writes its new
-  ``(B, H, S, Dh)`` rows into the stack and attends over its own slice
-  of it, so a step's outputs are its inputs' buffers with ``B x S``
-  slots a layer changed, and only the two attention contractions read
-  a whole layer (tests pin the aliasing and, with ``whole_layer_ops``,
-  that the compiled decode program moves no layer-sized buffer).
-  Before PR 25 the cache was a scanned input and output of the loop:
-  donation handed the buffers back, but each layer of each step was
-  sliced out, re-laid, written and copied into a new stack.  Prefill
-  (S = seq bucket) and decode (S = 1) are separate bucketed programs
-  of the SAME traced function.
+- **A cached step the model provides.**  The engine holds no model's
+  layer body: ``model.decoder_program()`` hands it the family's weights
+  (a flat tuple), its cache (a flat tuple, every array donated to each
+  step and handed back by it) and one traced step (`ServingEngine`'s
+  docstring has the contract; `gluon/model_zoo/gpt.py` and
+  `mimo_v2.py` each provide one).  A family carries its cache whole
+  through its layers and writes only the new rows, so a step's outputs
+  are its inputs' buffers (tests pin the aliasing and, with
+  ``whole_layer_ops``, that the compiled decode program moves no
+  layer-sized buffer).  Prefill (S = seq bucket) and decode (S = 1)
+  are separate bucketed programs of the SAME traced function, and the
+  step returns the logits of one position a row: the host never reads
+  ``(B, S, vocab)``.
 - **Hot reload without recompile.**  Weights are *arguments* to the
   compiled programs, not closed-over constants: swapping in new
   weights (from a live model or an AsyncCheckpointer state dict) is an
   array replacement under a lock — no retrace, no dropped requests
   (serving/replica.py swaps between batches).
 
-Unlike ``gpt.CachedDecoder`` (one uniform-length batch, scalar write
-position), the step here takes a **per-row position vector**, so a
-coalesced batch can mix prompt lengths: each row's cache writes land at
-its own offset (one dynamic_update_slice a row) and its own causal mask.
-Every op is row-independent (per-row LN / softmax / einsum rows), which
-is what makes a coalesced batch bitwise equal to the same requests
-served one-by-one through the same batch bucket — pad rows can never
-leak into real rows.
+The step takes a **per-row position vector**, so a coalesced batch can
+mix prompt lengths: each row's cache writes land at its own offset and
+under its own causal mask.  Every op of a family's step is
+row-independent, which is what makes a coalesced batch bitwise equal to
+the same requests served one-by-one through the same batch bucket — pad
+rows can never leak into real rows.
 
-Tensor-parallel serving (``mesh=``): weight stacks are head-/hidden-
-reshaped and placed with NamedShardings following the Megatron
-column/row split of TRANSFORMER_TP_RULES; the cache shards on its head
-axis (parallel/sharding.serving_cache_sharding).
+Tensor-parallel serving (``mesh=``) is the family's to place: GPT
+shards its stacks and its cache over ``tp_axis``; a family that serves
+from one chip refuses a mesh.
 """
 
 from __future__ import annotations
@@ -59,8 +54,7 @@ import time
 from ..base import MXNetError
 from ..obs.spans import wall
 from ..profiler import scope
-from ..gluon.model_zoo.gpt import (STACK_NAMES, _sample,
-                                   extract_decoder_stacks)
+from ..gluon.model_zoo.gpt import _sample
 
 # -- counters (the retrace-free pin) -------------------------------------------
 
@@ -130,30 +124,6 @@ def state_for_serving(model):
             for name, p in model.collect_params().items()}
 
 
-def _stacks_from_state(state):
-    """Rebuild (stacks, lnf, tok, pos) from a flat name→array state dict
-    (scanned-trunk convention: scan_layers=True param names)."""
-    import jax.numpy as jnp
-
-    def get1(suffix):
-        ks = [k for k in state if k.endswith(suffix)]
-        if len(ks) != 1:
-            raise MXNetError(
-                f"serving reload: expected exactly one param ending "
-                f"{suffix!r} in the checkpoint state, found {ks}")
-        return jnp.asarray(state[ks[0]])
-
-    if not any(k.endswith("qkv_stack_weight") for k in state):
-        raise MXNetError(
-            "serving reload: checkpoint state lacks the scanned-trunk "
-            "(*_stack_*) parameter convention; save the model with "
-            "scan_layers=True (serving.state_for_serving) or reload "
-            "from a live model via reload_from_model")
-    stacks = {nm: get1(nm) for nm in STACK_NAMES}
-    return (stacks, (get1("lnf_gamma"), get1("lnf_beta")),
-            get1("tok_embed_weight"), get1("pos_embed_weight"))
-
-
 # -- reading a compiled program: what moves a layer of the cache ---------------
 
 _HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
@@ -214,7 +184,26 @@ def whole_layer_ops(hlo_text, layer_bytes):
 
 
 class ServingEngine:
-    """Bucketed AOT prefill/decode over a GPTModel's weight stacks.
+    """Bucketed AOT prefill/decode over a model's decoder program.
+
+    The engine owns buckets, ahead-of-time compilation, donation, hot
+    reload, the request path with its spans and counters.  The model
+    owns its layers: ``model.decoder_program(dtype=, mesh=, tp_axis=)``
+    returns the family's program (docs/serving.md, "The decoder
+    program"):
+
+    - ``weights()`` → a flat tuple of arrays, the compiled programs'
+      first argument (swapped on reload, never closed over);
+    - ``init_cache(B)`` → a flat tuple of arrays, every one donated to
+      each step and handed back by it;
+    - ``step(w, cache, pos, last, toks)`` → ``(cache, logits (B,
+      vocab))``: ``pos`` (B,) each row's first position, ``last`` (B,)
+      the index in the block of each row's last real token, ``toks``
+      (B, S) with S a prefill bucket or 1;
+    - ``window`` and ``vocab``; optionally ``weights_from_state(state)``
+      (a checkpoint convention), ``counters(cache)`` (a dict read back
+      once a group, merged into the timings) and ``signature`` (what a
+      reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
     pad to the nearest (batch, seq) bucket, one prefill dispatch, one
@@ -224,92 +213,52 @@ class ServingEngine:
 
     def __init__(self, model, batch_buckets=None, prefill_floor=8,
                  mesh=None, tp_axis="tp", dtype=None):
-        self._W = model._max_length
         self._mesh = mesh
         self._tp_axis = tp_axis
         self._dtype = dtype
+        self._program = self._program_of(model)
+        self._W = self._program.window
         self.batch_buckets = tuple(sorted(
             batch_buckets if batch_buckets is not None
             else batch_buckets_from_env()))
         self.prefill_buckets = prefill_buckets_for(self._W,
                                                    floor=prefill_floor)
-        (stacks, lnf, tok, pos, num_heads,
-         act) = extract_decoder_stacks(model)
-        self._H = num_heads
-        self._act = act
-        self._C = int(tok.shape[1])
-        self._L = int(stacks["qkv_stack_weight"].shape[0])
-        self._vocab = int(tok.shape[0])
-        if mesh is not None:
-            n_tp = mesh.shape[tp_axis]
-            F = int(stacks["ffn1_stack_weight"].shape[1])
-            if num_heads % n_tp or F % n_tp:
-                raise MXNetError(
-                    f"ServingEngine: tp axis size {n_tp} must divide "
-                    f"num_heads={num_heads} and ffn hidden={F}")
         self._reload_lock = threading.Lock()
         self.generation = 0
-        self._weights = self._prepare_weights(stacks, lnf, tok, pos)
+        self._weights = tuple(self._program.weights())
         self._programs = {}
         self._step = self._make_step()
 
+    def _program_of(self, model):
+        make = getattr(model, "decoder_program", None)
+        if make is None:
+            raise MXNetError(
+                f"ServingEngine: {type(model).__name__} provides no "
+                "decoder program (a model_zoo family serves through "
+                "its decoder_program(); docs/serving.md)")
+        return make(dtype=self._dtype, mesh=self._mesh,
+                    tp_axis=self._tp_axis)
+
     # -- weight plumbing -------------------------------------------------------
 
-    def _shard(self, arr, spec):
-        if self._mesh is None:
-            return arr
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        return jax.device_put(arr,
-                              NamedSharding(self._mesh, P(*spec)))
-
-    def _prepare_weights(self, stacks, lnf, tok, pos):
-        """Head-/hidden-major restructure + serving dtype + tp placement
-        (the same Megatron column/row layout CachedDecoder._build
-        derives, but produced as a flat argument tuple so the compiled
-        programs take weights as inputs — the hot-reload contract)."""
-        s = dict(stacks)
-        if self._dtype is not None:
-            for nm in ("qkv_stack_weight", "proj_stack_weight",
-                       "ffn1_stack_weight", "ffn2_stack_weight"):
-                s[nm] = s[nm].astype(self._dtype)
-            tok = tok.astype(self._dtype)
-            pos = pos.astype(self._dtype)
-        L, H, C = self._L, self._H, self._C
-        Dh = C // H
-        tp = self._tp_axis
-        qkvw = self._shard(s["qkv_stack_weight"].reshape(L, 3, H, Dh, C),
-                           (None, None, tp))
-        qkvb = self._shard(s["qkv_stack_bias"].reshape(L, 3, H, Dh),
-                           (None, None, tp))
-        pwh = self._shard(s["proj_stack_weight"].reshape(L, C, H, Dh),
-                          (None, None, tp))
-        f1w = self._shard(s["ffn1_stack_weight"], (None, tp))
-        f1b = self._shard(s["ffn1_stack_bias"], (None, tp))
-        f2w = self._shard(s["ffn2_stack_weight"], (None, None, tp))
-        rep = ()
-        return (self._shard(tok, rep), self._shard(pos, rep),
-                qkvw, qkvb, pwh, self._shard(s["proj_stack_bias"], rep),
-                f1w, f1b, f2w, self._shard(s["ffn2_stack_bias"], rep),
-                self._shard(s["ln1_stack_gamma"], rep),
-                self._shard(s["ln1_stack_beta"], rep),
-                self._shard(s["ln2_stack_gamma"], rep),
-                self._shard(s["ln2_stack_beta"], rep),
-                self._shard(lnf[0], rep), self._shard(lnf[1], rep))
-
     def reload_from_model(self, model, step=None):
-        """Swap in a live model's weights (shapes must match)."""
-        stacks, lnf, tok, pos, H, act = extract_decoder_stacks(model)
-        if H != self._H or act != self._act:
+        """Swap in a live model's weights (shapes must match), through
+        its family's ``weights()``."""
+        program = self._program_of(model)
+        want = getattr(self._program, "signature", None)
+        got = getattr(program, "signature", None)
+        if type(program) is not type(self._program) or got != want:
             raise MXNetError(
                 f"serving reload: incompatible model "
-                f"(heads {H} vs {self._H}, act {act!r} vs {self._act!r})")
-        self._swap(stacks, lnf, tok, pos, step=step)
+                f"({type(program).__name__} {got} vs compiled "
+                f"{type(self._program).__name__} {want})")
+        self._swap(program.weights(), step=step)
 
     def reload_from_state(self, state, step=None, expect_fp=None):
         """Swap in weights from an AsyncCheckpointer state dict
-        (``state_for_serving`` convention).
+        (``state_for_serving`` convention).  Served for the families
+        whose program reads one (``weights_from_state``): GPT's
+        scanned-trunk names; the others reload from a live model.
 
         ``expect_fp``: optional integrity fingerprint (u64, the
         training side's attested `integrity.fingerprint_host` of this
@@ -331,27 +280,28 @@ class ServingEngine:
                     "serving reload: restored state fingerprint does "
                     "not match the attested fingerprint — refusing to "
                     "serve corrupt weights")
-        stacks, lnf, tok, pos = _stacks_from_state(state)
-        self._swap(stacks, lnf, tok, pos, step=step)
+        from_state = getattr(self._program, "weights_from_state", None)
+        if from_state is None:
+            raise MXNetError(
+                f"serving reload: {type(self._program).__name__} has no "
+                "checkpoint-state convention; reload from a live model "
+                "via reload_from_model")
+        self._swap(from_state(state), step=step)
 
-    def _swap(self, stacks, lnf, tok, pos, step=None):
+    def _swap(self, weights, step=None):
         from .. import telemetry
 
-        got = tuple(stacks["qkv_stack_weight"].shape)
-        want = (self._L, 3 * self._C, self._C)
-        if got != want:
-            raise MXNetError(
-                f"serving reload: weight mismatch — qkv stack {got} vs "
-                f"compiled {want}; a mismatched swap would force a "
-                f"retrace on the request path")
         import jax
 
+        weights = tuple(weights)
+        if len(weights) != len(self._weights):
+            raise MXNetError(
+                f"serving reload: weight mismatch — {len(weights)} arrays "
+                f"vs compiled {len(self._weights)}")
         # a checkpoint state arrives as host arrays: the swapped-in
         # weights go where the compiled programs' weights live
-        new_w = tuple(
-            jax.device_put(new, old.sharding) for new, old in
-            zip(self._prepare_weights(stacks, lnf, tok, pos),
-                self._weights))
+        new_w = tuple(jax.device_put(new, old.sharding)
+                      for new, old in zip(weights, self._weights))
         for old, new in zip(self._weights, new_w):
             if tuple(old.shape) != tuple(new.shape) \
                     or old.dtype != new.dtype:
@@ -368,148 +318,24 @@ class ServingEngine:
 
     # -- cache -----------------------------------------------------------------
 
-    def _cache_sharding(self):
-        from ..parallel.sharding import serving_cache_sharding
-
-        return serving_cache_sharding(self._mesh, tp_axis=self._tp_axis)
-
     def init_cache(self, B):
-        """Fresh zeroed (ck, cv) for batch bucket B: stage-major
-        (L, B, H, W, Dh), serving dtype, head-sharded under tp."""
-        import jax.numpy as jnp
-
-        tok = self._weights[0]
-        shape = (self._L, B, self._H, self._W, self._C // self._H)
-        # committed next to the weights: the engine serves from the
-        # device(s) the model was placed on, never from the process
-        # default
-        where = tok.sharding if self._mesh is None \
-            else self._cache_sharding()
-        return (jnp.zeros(shape, tok.dtype, device=where),
-                jnp.zeros(shape, tok.dtype, device=where))
+        """A fresh cache of the family's for batch bucket B: a flat
+        tuple of arrays beside the weights, every one donated to each
+        step."""
+        return tuple(self._program.init_cache(B))
 
     # -- the traced block step -------------------------------------------------
 
     def _make_step(self):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        from jax.experimental.layout import with_layout_constraint
-
-        from ..ops.nn import layer_norm
-
-        H, W = self._H, self._W
-        Dh = self._C // H
-        act = self._act
-        mesh = self._mesh
-        cache_ns = self._cache_sharding() if mesh is not None else None
-        # how this platform lays a cache out on the device (a v5e puts W
-        # minor-most where Dh is under 128): read off one, not assumed
-        cache_layout = self.init_cache(1)[0].format.layout
-
-        def keep_layout(c):
-            return with_layout_constraint(c, cache_layout)
-
-        if mesh is not None:
-            # the constraint has no partitioning rule (the partitioner
-            # would gather the cache to apply it), so each shard pins
-            # its own
-            keep_layout = jax.shard_map(
-                keep_layout, mesh=mesh, in_specs=cache_ns.spec,
-                out_specs=cache_ns.spec)
-
-        def step(w, ck, cv, pos, toks):
-            """ck/cv (L, B, H, W, Dh) donated; pos (B,) per-row write
-            offsets; toks (B, S) int32.  Returns (ck', cv', logits
-            (B, S, vocab)).  S = seq bucket for prefill, 1 for decode."""
-            _mark_trace()
-            (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
-             g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
-            B, S = toks.shape
-            with jax.named_scope("serve.embed"):
-                positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
-                x = (jnp.take(tok_e, toks, axis=0) +
-                     jnp.take(pos_e, positions, axis=0)
-                     ).astype(jnp.float32)                     # (B, S, C)
-
-            def write(c, new, l):
-                """Row b's new (H, S, Dh) block into the carried stack
-                at [l, b, :, pos[b]:pos[b] + S, :], and nothing else: one
-                dynamic_update_slice a row, each at that row's own
-                offset (a start that would run past W is clamped)."""
-                new = new.astype(c.dtype)
-                zero = jnp.int32(0)
-                for b in range(B):
-                    c = lax.dynamic_update_slice(
-                        c, new[b][None, None],
-                        (l, jnp.int32(b), zero, pos[b], zero))
-                # keep the stack in the layout the donated buffer came
-                # in: left to itself the TPU compiler re-lays the whole
-                # cache around the loop to make these writes cheaper
-                return keep_layout(c)
-
-            def layer(carry, per):
-                x, ck, cv = carry
-                (qw, qb, pw, pb_l, f1w_l, f1b_l, f2w_l, f2b_l,
-                 g1, b1, g2, b2, l) = per
-                with jax.named_scope("serve.attn_qkv"):
-                    h = layer_norm(x, g1, b1)
-                    qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
-                    qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
-                    kh = qkv[:, :, 1].swapaxes(1, 2)
-                    vh = qkv[:, :, 2].swapaxes(1, 2)
-                with jax.named_scope("serve.cache_write"):
-                    ck = write(ck, kh, l)
-                    cv = write(cv, vh, l)
-                with jax.named_scope("serve.attn"):
-                    ck_l = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
-                    cv_l = lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
-                    scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
-                        * (Dh ** -0.5)
-                    # per-row causal mask: row b at block offset s may
-                    # see cache slots <= pos[b] + s (stale pad garbage
-                    # beyond is invisible — the overwrite-before-attend
-                    # invariant)
-                    mask = jnp.arange(W)[None, None, :] <= \
-                        (pos[:, None, None] +
-                         jnp.arange(S)[None, :, None])         # (B, S, W)
-                    scores = jnp.where(mask[:, None], scores, -1e30)
-                    p = jax.nn.softmax(scores, axis=-1)
-                    attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
-                    attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
-                    x = x + attn
-                with jax.named_scope("serve.mlp"):
-                    h = layer_norm(x, g2, b2)
-                    h = h @ f1w_l.T + f1b_l
-                    h = jax.nn.gelu(h) if act == "gelu" \
-                        else jnp.maximum(h, 0)
-                    x = x + (h @ f2w_l.T + f2b_l)
-                return (x, ck, cv), None
-
-            # the cache is carried, not scanned: a scanned input is
-            # sliced a layer at a time and a scanned output is a new
-            # stacked buffer, which cost a copy of every layer's keys
-            # and values each way
-            per_layer = (qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
-                         g1s, b1s, g2s, b2s,
-                         jnp.arange(ck.shape[0], dtype=jnp.int32))
-            (x, ck2, cv2), _ = lax.scan(layer, (x, ck, cv), per_layer)
-            with jax.named_scope("serve.head"):
-                h = layer_norm(x, lnf_g, lnf_b)
-                logits = h @ tok_e.T
-            if cache_ns is not None:
-                # pin the donated buffers' output layout to the input
-                # layout, so the next AOT call sees identical shardings
-                ck2 = lax.with_sharding_constraint(ck2, cache_ns)
-                cv2 = lax.with_sharding_constraint(cv2, cache_ns)
-            return ck2, cv2, logits
+        program = self._program
 
         def named(name):
             # one traced function under two names: a program is called
             # ``jit_<__name__>`` in its HLO and in every device trace
-            def fn(w, ck, cv, pos, toks):
-                return step(w, ck, cv, pos, toks)
+            def fn(w, cache, pos, last, toks):
+                _mark_trace()
+                cache, logits = program.step(w, cache, pos, last, toks)
+                return tuple(cache), logits
 
             fn.__name__ = fn.__qualname__ = name
             return fn
@@ -549,10 +375,10 @@ class ServingEngine:
         import jax
 
         w_avals = tuple(self._aval(x) for x in self._weights)
-        ck, cv = self.init_cache(B)
+        c_avals = tuple(self._aval(c) for c in self.init_cache(B))
         jfn = jax.jit(self._step["decode" if S == 1 else "prefill"],
-                      donate_argnums=(1, 2))
-        compiled = jfn.lower(w_avals, self._aval(ck), self._aval(cv),
+                      donate_argnums=(1,))
+        compiled = jfn.lower(w_avals, c_avals, self._int_aval((B,)),
                              self._int_aval((B,)),
                              self._int_aval((B, S))).compile()
         with _LOCK:
@@ -579,7 +405,9 @@ class ServingEngine:
     def program_count(self):
         return len(self._programs)
 
-    def _call(self, B, S, ck, cv, pos, toks):
+    def _call(self, B, S, cache, pos, last, toks):
+        """One dispatch of bucket (B, S): returns (cache, logits (B,
+        vocab)); ``cache`` is donated."""
         global _DISPATCH_COUNT
         import jax
         import numpy as np
@@ -589,12 +417,13 @@ class ServingEngine:
             compiled = self._compile(B, S)
         where = self._input_sharding()
         pos = jax.device_put(np.asarray(pos, np.int32), where)
+        last = jax.device_put(np.asarray(last, np.int32), where)
         toks = jax.device_put(np.asarray(toks, np.int32), where)
         with _LOCK:
             _DISPATCH_COUNT += 1
         with self._reload_lock:
             w = self._weights
-        return compiled(w, ck, cv, pos, toks)
+        return compiled(w, tuple(cache), pos, last, toks)
 
     # -- request path ----------------------------------------------------------
 
@@ -646,14 +475,15 @@ class ServingEngine:
         for i, p in enumerate(prompts):
             toks[i, :lens[i]] = np.asarray(p, np.int32)
         with scope("serve.prefill.dispatch") as sp_dispatch:
-            ck, cv = self.init_cache(B)
-            ck, cv, logits = self._call(B, S, ck, cv,
-                                        np.zeros(B, np.int32), toks)
+            cache, logits = self._call(B, S, self.init_cache(B),
+                                       np.zeros(B, np.int32), lens - 1,
+                                       toks)
         with scope("serve.prefill.readback") as sp_readback:
-            last = np.asarray(logits)[np.arange(B), lens - 1]
+            last = np.asarray(logits)
         t0, t1 = sp_dispatch.t0, sp_readback.t1
         prefill_us = (t1 - t0) * 1e6
         out = np.zeros((B, steps), np.int32)
+        step_last = np.zeros(B, np.int32)   # a decode block is one token
         sample_s = dispatch_s = readback_s = 0.0
         token_t_us = []
         for j in range(steps):
@@ -664,11 +494,11 @@ class ServingEngine:
             token_t_us.append((sp.t1 - t1) * 1e6)
             if j < steps - 1:      # the last token needs no cache step
                 with scope("serve.decode.dispatch", step=j) as sp:
-                    ck, cv, logits = self._call(B, 1, ck, cv, lens + j,
-                                                nxt[:, None])
+                    cache, logits = self._call(B, 1, cache, lens + j,
+                                               step_last, nxt[:, None])
                 dispatch_s += sp.t1 - sp.t0
                 with scope("serve.decode.readback", step=j) as sp:
-                    last = np.asarray(logits)[:, 0]
+                    last = np.asarray(logits)
                 readback_s += sp.t1 - sp.t0
         decode_us = (sp.t1 - t1) * 1e6
         per_step = 1e6 / steps        # seconds summed -> us a step
@@ -694,4 +524,9 @@ class ServingEngine:
             # the prefill's): the gaps are what a streaming caller sees
             "token_t_us": token_t_us,
         }
+        counters = getattr(self._program, "counters", None)
+        if counters is not None:
+            # what the family counted in its donated carry: one small
+            # readback a group, after the last step's logits are in
+            timings.update(counters(cache))
         return [out[i, :per_req[i]].copy() for i in range(n)], timings

@@ -66,3 +66,29 @@ def test_train_serve_sharded_phases(mesh8):
     sharded = chip_smoke.phase_sharded(TINY, "cpu", train["losses"][0],
                                        train["peak_bytes"])
     assert [s["mode"] for s in sharded] == ["fsdp", "tp"]
+
+
+def test_serve_mimo_phase():
+    """The second family's phase at a tiny size: the engine's tuple is
+    the parameters' own buffers, the four stacks stay where they are,
+    coalesced == alone, a repeat is identical."""
+    from mxnet_tpu.gluon.model_zoo import mimo_v2
+
+    full = chip_smoke.mimo_full()
+    assert full.kwargs["units"] == 4096 and full.kwargs["window"] == 128
+    assert full.new_tokens > 2 * full.kwargs["window"]
+    kwargs = dict(vocab_size=96, units=64,
+                  layer_types=["full"] + ["window"] * 5 + ["full"],
+                  moe_layers=[0, 1, 1, 1, 1, 1, 1], num_heads=4, kv_heads=1,
+                  swa_kv_heads=2, qk_dim=24, v_dim=16, rotary_dim=8,
+                  window=4, rope_theta=1e7, swa_rope_theta=1e4,
+                  hidden_size=96, expert_hidden=32, router_experts=8,
+                  experts_per_token=2, experts_held=[0, 4],
+                  value_scale=0.707, max_length=64, attn_block=4,
+                  grad_req="null")
+    assert set(kwargs) - {"attn_block"} <= set(full.kwargs)
+    size = chip_smoke.MimoSize(kwargs=kwargs, batch=4, prefill_floor=16,
+                               prompt_lens=(3, 4, 13, 16), new_tokens=13)
+    out = chip_smoke.phase_serve_mimo(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
+    assert isinstance(mimo_v2.mimo_v2_tiny(), mimo_v2.MiMoV2Model)

@@ -509,6 +509,9 @@ SKIP = {
     "Embedding_like": "alias surface",
     "MoEFFN_op": "MoE dispatch/combine covered vs oracle + ep-sharded "
                  "step in tests/test_parallel.py (moe suite)",
+    "moe_share_ffn": "the dropless share op is tested against the plain "
+                     "reference (shares adding up, forced imbalance, "
+                     "overflow passes) in tests/test_mimo_v2.py",
     "scan_transformer_encoder":
         "lax.scan trunk equivalence-tested (fwd+grads) vs the "
         "unstacked TransformerEncoder in tests/test_model_zoo.py",

@@ -419,8 +419,9 @@ def test_tp_serving_matches_unsharded(mesh8):
     for i, p in enumerate(prompts):
         toks[i, :len(p)] = p
     zero = np.zeros(2, np.int32)
-    _, _, ref_lg = plain._call(2, 8, *plain.init_cache(2), zero, toks)
-    _, _, tp_lg = tp._call(2, 8, *tp.init_cache(2), zero, toks)
+    last = np.asarray([len(p) - 1 for p in prompts], np.int32)
+    _, ref_lg = plain._call(2, 8, plain.init_cache(2), zero, last, toks)
+    _, tp_lg = tp._call(2, 8, tp.init_cache(2), zero, last, toks)
     np.testing.assert_allclose(np.asarray(tp_lg), np.asarray(ref_lg),
                                rtol=2e-4, atol=1e-5)
 
@@ -738,9 +739,13 @@ def _cache_walk(eng, lens, S, decode_pos):
     calls += [(np.asarray(p, np.int32),
                rng.randint(1, 128, (B, 1)).astype(np.int32))
               for p in decode_pos]
+    last = np.asarray(lens, np.int32) - 1
     for pos, t in calls:
-        ck, cv, lg = eng._call(B, t.shape[1], ck, cv, pos, t)
-        want = _np_step(w, eng._act, nk, nv, pos, t)
+        # the step hands back one position a row: the last real token's
+        (ck, cv), lg = eng._call(B, t.shape[1], (ck, cv), pos, last, t)
+        want = _np_step(w, eng._program._act, nk, nv, pos, t)[
+            np.arange(B), last]
+        last = np.zeros(B, np.int32)
     assert tuple(ck.shape) == shape and ck.dtype == cv.dtype
     return (np.asarray(ck), np.asarray(cv), np.asarray(lg)), (nk, nv, want)
 
@@ -770,8 +775,8 @@ def test_step_carries_the_cache_and_aliases_it(kind, S):
     ck, cv = eng.init_cache(B)
     stack, layer = tuple(ck.shape), tuple(ck.shape[1:])
     jaxpr = jax.make_jaxpr(eng._step[kind])(
-        eng._weights, ck, cv, np.zeros(B, np.int32),
-        np.zeros((B, S), np.int32))
+        eng._weights, (ck, cv), np.zeros(B, np.int32),
+        np.zeros(B, np.int32), np.zeros((B, S), np.int32))
     loops = list(_loops(jaxpr.jaxpr))
     assert [e.primitive.name for e in loops] == ["scan"]
     scan = loops[0]
