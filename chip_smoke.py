@@ -400,6 +400,31 @@ def phase_train(size, platform):
 
 # -- serve ---------------------------------------------------------------------
 
+def require_fed_on_device(tag, engine, prompts, steps, served, timing):
+    """A greedy group ran with no host round trip between its steps:
+    every decode step took the step before's ids on the device, the
+    host read 4 bytes a row of each program, and the tokens are those
+    of the same prompts with the host in every step (it reads each
+    program's logits, takes the argmax, puts ids and positions back)."""
+    from mxnet_tpu.test_utils import serving_host_walk
+
+    B = timing["bucket"][0]
+    require(timing["decode_steps_fed_on_device"] == steps - 1
+            and timing["decode_readback_bytes_per_step"] == 4 * B,
+            f"{tag}: {timing['decode_steps_fed_on_device']} of "
+            f"{steps - 1} decode steps fed on the device, "
+            f"{timing['decode_readback_bytes_per_step']} bytes read a "
+            f"step (bucket {B})")
+    host, _ = serving_host_walk(engine, prompts, steps)
+    for i, got in enumerate(served):
+        require(np.array_equal(got, host[i, :len(got)]),
+                f"{tag}: prompt {i} fed on the device {got} != picked "
+                f"on the host {host[i]}")
+    say(f"[{tag}] greedy group of {len(prompts)} x {steps} tokens: "
+        f"{steps - 1} decode steps fed on the device, {4 * B} bytes read "
+        f"a step, tokens equal the host-picked run")
+
+
 def phase_serve(size, platform, net):
     import jax.numpy as jnp
 
@@ -482,6 +507,8 @@ def phase_serve(size, platform, net):
              if len(p) <= engine.prefill_buckets[0]][:big]
     require(len(group) >= 2, "serve: size gives no group to coalesce")
     together, timing = engine.serve_group(group, size.new_tokens)
+    require_fed_on_device("serve", engine, group, size.new_tokens,
+                          together, timing)
     engine.batch_buckets = (big,)
     try:
         alone = [engine.serve_group([p], size.new_tokens)
@@ -602,9 +629,11 @@ def phase_serve_mimo(size, platform):
             require(np.array_equal(alone[0], together[j]),
                     f"serve_mimo: prompt {j} coalesced {together[j]} != "
                     f"alone {alone[0]}")
-    again, _ = engine.serve_group(prompts, size.new_tokens)
+    again, timing = engine.serve_group(prompts, size.new_tokens)
     require(all(np.array_equal(a, b) for a, b in zip(again, together)),
             "serve_mimo: a repeated group differs")
+    require_fed_on_device("serve_mimo", engine, prompts, size.new_tokens,
+                          again, timing)
     dev = _ctx_for(platform).jax_device
     stats = dev.memory_stats()
     peak = stats["peak_bytes_in_use"] if stats else None

@@ -114,7 +114,10 @@ def _windowed_last_logits(model, flat, nd_mod, np_mod):
 
 def _sample(last, temperature, rng):
     """Pick next tokens from (B, vocab) logits: greedy, or softmax
-    sampling at the given temperature (one home for both decode paths)."""
+    sampling at the given temperature (one home for both decode paths).
+    The greedy branch uses array methods only, so it also traces: the
+    serving programs pick their token with it on the device
+    (serving/engine.py::_make_step)."""
     import numpy as np
 
     if temperature:

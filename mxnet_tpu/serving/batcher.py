@@ -271,6 +271,10 @@ class ContinuousBatcher:
                 collect_us=timings["collect_us"],
                 decode_host_us_per_step=timings.get(
                     "decode_host_us_per_step"),
+                decode_steps_fed_on_device=timings.get(
+                    "decode_steps_fed_on_device"),
+                decode_readback_bytes_per_step=timings.get(
+                    "decode_readback_bytes_per_step"),
                 **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step

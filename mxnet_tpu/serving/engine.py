@@ -2,7 +2,7 @@
 
 The training side captures the whole step as ONE donated jit program
 (gluon/captured.py); this module applies the same discipline to the
-request path.  Three properties, all pinned by tests/test_serving.py:
+request path.  Four properties, all pinned by tests/test_serving.py:
 
 - **Zero retraces after warmup.**  Every (batch bucket × seq bucket)
   pair gets ONE ahead-of-time program via the same
@@ -26,6 +26,15 @@ request path.  Three properties, all pinned by tests/test_serving.py:
   are separate bucketed programs of the SAME traced function, and the
   step returns the logits of one position a row: the host never reads
   ``(B, S, vocab)``.
+- **A decode loop that does not wait for the host.**  Around the
+  family's step each compiled program also picks the greedy token
+  (float32 ``argmax``, lowest index on ties) and the next position, so
+  every input of decode step j+1 is an output of step j or a constant
+  of the group that is already on the device.  ``serve_group``
+  dispatches ``_STEPS_IN_FLIGHT`` steps ahead and reads the ``(B, 1)``
+  ids of a step while the next one runs; the ``(B, vocab)`` logits are
+  fetched only for a request with a ``temperature``, for which the
+  host draws the token.
 - **Hot reload without recompile.**  Weights are *arguments* to the
   compiled programs, not closed-over constants: swapping in new
   weights (from a live model or an AsyncCheckpointer state dict) is an
@@ -46,6 +55,7 @@ from one chip refuses a mesh.
 
 from __future__ import annotations
 
+import collections
 import os
 import re
 import threading
@@ -62,6 +72,14 @@ _LOCK = threading.Lock()
 _TRACE_COUNT = 0      # ticks inside the traced fn: once per (re)trace
 _COMPILE_COUNT = 0    # lower().compile() calls
 _DISPATCH_COUNT = 0   # compiled-program invocations
+
+# Decode steps of a greedy group dispatched and not yet read.  With two,
+# the device finds step j+1 queued when step j ends, while the host
+# reads the ids of step j: a dispatch costs well under a millisecond of
+# a step of several.  A constant, not an option: a deeper queue buys
+# nothing once the device never waits, and hands a streaming caller its
+# tokens later.
+_STEPS_IN_FLIGHT = 2
 
 
 def _mark_trace():
@@ -199,7 +217,9 @@ class ServingEngine:
     - ``step(w, cache, pos, last, toks)`` → ``(cache, logits (B,
       vocab))``: ``pos`` (B,) each row's first position, ``last`` (B,)
       the index in the block of each row's last real token, ``toks``
-      (B, S) with S a prefill bucket or 1;
+      (B, S) with S a prefill bucket or 1.  The engine compiles it
+      with two more outputs, the next step's own inputs: the greedy
+      ids ``(B, 1)`` and the next positions ``pos + last + 1``;
     - ``window`` and ``vocab``; optionally ``weights_from_state(state)``
       (a checkpoint convention), ``counters(cache)`` (a dict read back
       once a group, merged into the timings) and ``signature`` (what a
@@ -207,8 +227,12 @@ class ServingEngine:
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
     pad to the nearest (batch, seq) bucket, one prefill dispatch, one
-    decode dispatch per generated token, greedy (or temperature)
-    sampling on host — every dispatch hits a pre-compiled program.
+    decode dispatch per further token, every one a pre-compiled program.
+    Greedy tokens are picked inside the programs: a step is fed the
+    step before's ids and positions on the device, the host dispatches
+    ``_STEPS_IN_FLIGHT`` steps ahead and reads ``(B, 1)`` ids behind
+    them.  With a ``temperature`` the host reads each step's logits and
+    draws the token itself, so that step is not run ahead.
     """
 
     def __init__(self, model, batch_buckets=None, prefill_floor=8,
@@ -327,7 +351,13 @@ class ServingEngine:
     # -- the traced block step -------------------------------------------------
 
     def _make_step(self):
+        import jax
+        import jax.numpy as jnp
+
         program = self._program
+        # under a mesh the two small outputs come back as the programs
+        # take them in, on every chip
+        replicated = None if self._mesh is None else self._input_sharding()
 
         def named(name):
             # one traced function under two names: a program is called
@@ -335,7 +365,18 @@ class ServingEngine:
             def fn(w, cache, pos, last, toks):
                 _mark_trace()
                 cache, logits = program.step(w, cache, pos, last, toks)
-                return tuple(cache), logits
+                # what the next decode step takes, made where it is
+                # used: the greedy token by the rule the host samples
+                # by (`_sample`: float32 argmax, first index on ties,
+                # as NumPy's) and each row's next position
+                with jax.named_scope("serve.sample"):
+                    ids = _sample(logits.astype(jnp.float32), None,
+                                  None)[:, None]
+                    nxt = pos + last + 1
+                if replicated is not None:
+                    ids, nxt = jax.lax.with_sharding_constraint(
+                        (ids, nxt), replicated)
+                return tuple(cache), logits, ids, nxt
 
             fn.__name__ = fn.__qualname__ = name
             return fn
@@ -405,20 +446,30 @@ class ServingEngine:
     def program_count(self):
         return len(self._programs)
 
-    def _call(self, B, S, cache, pos, last, toks):
-        """One dispatch of bucket (B, S): returns (cache, logits (B,
-        vocab)); ``cache`` is donated."""
-        global _DISPATCH_COUNT
+    def _place(self, ints):
+        """A per-call int input where the programs take it.  A device
+        array is a step's own output (or was placed before): it goes
+        in as it is."""
         import jax
         import numpy as np
+
+        if isinstance(ints, jax.Array):
+            return ints
+        return jax.device_put(np.asarray(ints, np.int32),
+                              self._input_sharding())
+
+    def _call(self, B, S, cache, pos, last, toks):
+        """One dispatch of bucket (B, S): returns ``(cache, logits (B,
+        vocab), ids (B, 1), next pos (B,))``, all on the device; the
+        last two are the greedy tokens and the positions a decode step
+        that follows takes as ``toks`` and ``pos``.  ``cache`` is
+        donated."""
+        global _DISPATCH_COUNT
 
         compiled = self._programs.get((B, S))
         if compiled is None:
             compiled = self._compile(B, S)
-        where = self._input_sharding()
-        pos = jax.device_put(np.asarray(pos, np.int32), where)
-        last = jax.device_put(np.asarray(last, np.int32), where)
-        toks = jax.device_put(np.asarray(toks, np.int32), where)
+        pos, last, toks = (self._place(x) for x in (pos, last, toks))
         with _LOCK:
             _DISPATCH_COUNT += 1
         with self._reload_lock:
@@ -447,7 +498,14 @@ class ServingEngine:
         docs/observability.md has the table).  Each phase runs under a
         `profiler.scope` (``serve.prefill.dispatch`` ...
         ``serve.decode.readback``), and the timings are sums of those
-        spans' own clock reads."""
+        spans' own clock reads.
+
+        Token j is read from program j, the prefill or decode step
+        j - 1.  Greedy, a step takes the ids and positions the program
+        before it left on the device, the host keeps
+        ``_STEPS_IN_FLIGHT`` steps dispatched and reads the ids of the
+        oldest; with a ``temperature`` it reads that program's logits,
+        draws with ``rng`` and dispatches one step."""
         import numpy as np
 
         n = len(prompts)
@@ -474,32 +532,54 @@ class ServingEngine:
         toks = np.zeros((B, S), np.int32)
         for i, p in enumerate(prompts):
             toks[i, :lens[i]] = np.asarray(p, np.int32)
+        host_picks = bool(temperature)
+        ahead = 1 if host_picks else _STEPS_IN_FLIGHT
+
+        flight = collections.deque()    # dispatched, not yet read
+
+        def dispatch(width, cache, pos, last, block):
+            cache, logits, ids, pos = self._call(B, width, cache, pos,
+                                                 last, block)
+            # what the host will read of this program sets out for the
+            # host as soon as the program has made it
+            flight.append(logits if host_picks else ids)
+            flight[-1].copy_to_host_async()
+            return cache, pos, ids
+
         with scope("serve.prefill.dispatch") as sp_dispatch:
-            cache, logits = self._call(B, S, self.init_cache(B),
+            cache, pos, ids = dispatch(S, self.init_cache(B),
                                        np.zeros(B, np.int32), lens - 1,
                                        toks)
+            # a decode block is one token: placed once a group
+            step_last = self._place(np.zeros(B, np.int32))
         with scope("serve.prefill.readback") as sp_readback:
-            last = np.asarray(logits)
+            read = np.asarray(flight.popleft())
         t0, t1 = sp_dispatch.t0, sp_readback.t1
         prefill_us = (t1 - t0) * 1e6
         out = np.zeros((B, steps), np.int32)
-        step_last = np.zeros(B, np.int32)   # a decode block is one token
         sample_s = dispatch_s = readback_s = 0.0
         token_t_us = []
+        dispatched = 0
         for j in range(steps):
             with scope("serve.decode.sample", step=j) as sp:
-                nxt = _sample(last, temperature, rng)
+                nxt = _sample(read, temperature, rng) if host_picks \
+                    else read[:, 0]
                 out[:, j] = nxt
             sample_s += sp.t1 - sp.t0
             token_t_us.append((sp.t1 - t1) * 1e6)
-            if j < steps - 1:      # the last token needs no cache step
-                with scope("serve.decode.dispatch", step=j) as sp:
-                    cache, logits = self._call(B, 1, cache, lens + j,
-                                               step_last, nxt[:, None])
-                dispatch_s += sp.t1 - sp.t0
-                with scope("serve.decode.readback", step=j) as sp:
-                    last = np.asarray(logits)
-                readback_s += sp.t1 - sp.t0
+            if j == steps - 1:     # the last token needs no cache step
+                break
+            with scope("serve.decode.dispatch", step=j) as sp:
+                if host_picks:
+                    ids = self._place(nxt[:, None])
+                while dispatched < steps - 1 and len(flight) < ahead:
+                    cache, pos, ids = dispatch(1, cache, pos, step_last,
+                                               ids)
+                    dispatched += 1
+            dispatch_s += sp.t1 - sp.t0
+            with scope("serve.decode.readback", step=j) as sp:
+                read = np.asarray(flight.popleft())
+            readback_s += sp.t1 - sp.t0
         decode_us = (sp.t1 - t1) * 1e6
         per_step = 1e6 / steps        # seconds summed -> us a step
         timings = {
@@ -520,7 +600,11 @@ class ServingEngine:
             "decode_dispatch_us_per_step": dispatch_s * per_step,
             "decode_readback_us_per_step": readback_s * per_step,
             "decode_host_us_per_step": (sample_s + dispatch_s) * per_step,
-            # when each token was emitted, from t_decode0 (token 0 is
+            # decode steps whose token and position never left the
+            # device, and what the host read of each program
+            "decode_steps_fed_on_device": 0 if host_picks else dispatched,
+            "decode_readback_bytes_per_step": int(read.nbytes),
+            # when the host held each token, from t_decode0 (token 0 is
             # the prefill's): the gaps are what a streaming caller sees
             "token_t_us": token_t_us,
         }

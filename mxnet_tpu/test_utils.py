@@ -56,6 +56,45 @@ def cpu_child_env(devices=1, **extra):
     return env
 
 
+def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
+    """One group through a `ServingEngine`'s compiled programs with the
+    host in every step, the reference for `serve_group`'s device-fed
+    loop: read each program's logits, pick on the host (`gpt._sample`:
+    greedy, or drawn with ``rng``), put ids and positions back.  Holds
+    each program's own greedy ids and next positions to what the host
+    computes from the same step (AssertionError otherwise).  The group
+    runs in the buckets `serve_group` would pick.  Returns
+    ``(tokens (n, steps), logits (n, steps, vocab))``."""
+    from .gluon.model_zoo.gpt import _sample
+
+    n = len(prompts)
+    B = engine._pick_bucket(engine.batch_buckets, n, "group size")
+    lens = np.ones(B, np.int32)         # pad rows hold one dummy token
+    lens[:n] = [len(p) for p in prompts]
+    S = engine._pick_bucket(engine.prefill_buckets, int(lens.max()),
+                            "prompt length")
+    toks = np.zeros((B, S), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    pos, last = np.zeros(B, np.int32), lens - 1
+    cache, out, logits = engine.init_cache(B), [], []
+    for j in range(steps):
+        cache, lg, ids, nxt_pos = engine._call(B, toks.shape[1], cache,
+                                               pos, last, toks)
+        lg = np.asarray(lg)
+        assert lg.dtype == np.float32 and lg.ndim == 2, (lg.dtype, lg.shape)
+        assert ids.dtype == np.int32 and ids.shape == (B, 1), ids
+        np.testing.assert_array_equal(
+            np.asarray(ids)[:, 0], lg.argmax(-1),
+            err_msg=f"program {j}: ids are not the argmax of its logits")
+        np.testing.assert_array_equal(np.asarray(nxt_pos), lens + j)
+        nxt = _sample(lg, temperature, rng)
+        out.append(nxt[:n])
+        logits.append(lg[:n])
+        pos, last, toks = lens + j, np.zeros(B, np.int32), nxt[:, None]
+    return np.stack(out, 1), np.stack(logits, 1)
+
+
 def _as_np(x):
     if isinstance(x, NDArray):
         return x.asnumpy()

@@ -19,6 +19,7 @@ from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import mimo_v2               # noqa: E402
 from mxnet_tpu.ops import moe                               # noqa: E402
+from mxnet_tpu.test_utils import serving_host_walk as _walk  # noqa: E402
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import mimo_v2 as ref             # noqa: E402
@@ -88,32 +89,6 @@ def test_forward_equals_the_reference(T, chunk):
 
 
 # -- 2. prefill, then decode through both caches -------------------------------
-
-def _walk(eng, prompts, steps):
-    """Prefill and ``steps - 1`` greedy decode steps through the
-    engine's compiled programs: (tokens (n, steps), logits (n, steps,
-    vocab))."""
-    B = eng.batch_buckets[-1]
-    n = len(prompts)
-    lens = np.ones(B, np.int32)
-    lens[:n] = [len(p) for p in prompts]
-    S = next(s for s in eng.prefill_buckets if s >= lens.max())
-    toks = np.zeros((B, S), np.int32)
-    for i, p in enumerate(prompts):
-        toks[i, :len(p)] = p
-    cache, lg = eng._call(B, S, eng.init_cache(B), np.zeros(B, np.int32),
-                          lens - 1, toks)
-    out, logits = [], []
-    for j in range(steps):
-        lg = np.asarray(lg)
-        nxt = lg.argmax(-1).astype(np.int32)
-        out.append(nxt[:n])
-        logits.append(lg[:n])
-        if j < steps - 1:
-            cache, lg = eng._call(B, 1, cache, lens + j,
-                                  np.zeros(B, np.int32), nxt[:, None])
-    return np.stack(out, 1), np.stack(logits, 1)
-
 
 @pytest.mark.parametrize("chunk", [4096, 16])
 def test_serving_equals_the_reference_at_every_served_position(chunk):
@@ -283,6 +258,49 @@ def test_a_coalesced_group_is_bitwise_the_requests_served_alone(served):
         t1, l1 = _walk(eng, [p], 10)
         np.testing.assert_array_equal(t1[0], toks[i])
         np.testing.assert_array_equal(l1[0], logits[i])
+
+
+@pytest.mark.parametrize("steps", [1, 2, 9])
+def test_a_greedy_group_is_fed_on_the_device(served, steps):
+    """The second family through the same wrapper: a greedy group's
+    tokens are those of the path with the host in every step (rings
+    wrapped twice at 9 steps), it dispatches its prefill and
+    ``steps - 1`` decode programs and no third one, every decode step
+    takes the step before's ids, and the host reads 4 bytes a row."""
+    _, _, _, eng = served
+    rng = np.random.RandomState(8)
+    prompts = [rng.randint(0, 96, n).tolist() for n in (2, WINDOW, 13)]
+    want, _ = _walk(eng, prompts, steps)
+    pinned = (serving.trace_count(), serving.compile_count())
+    d0 = serving.dispatch_count()
+    outs, timings = eng.serve_group(prompts, steps)
+    assert serving.dispatch_count() - d0 == 1 + (steps - 1)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    np.testing.assert_array_equal(np.stack(outs), want)
+    assert timings["decode_steps_fed_on_device"] == steps - 1
+    assert timings["decode_readback_bytes_per_step"] == 4 * 4
+    ts = timings["token_t_us"]
+    assert len(ts) == steps and all(a < b for a, b in zip(ts, ts[1:]))
+    # the family's counters are still read once, after the last step
+    assert timings["moe_pairs_decode"] == 4 * 2 * 6 * (steps - 1)
+
+
+def test_a_sampled_group_draws_on_the_host(served):
+    """With a temperature and a seeded generator the tokens are those
+    of the path with the host in every step, draw for draw; the host
+    reads each program's logits and no step is fed on the device."""
+    _, _, _, eng = served
+    rng = np.random.RandomState(9)
+    prompts = [rng.randint(0, 96, n).tolist() for n in (5, 2, 11)]
+    want, _ = _walk(eng, prompts, 7, temperature=1.3,
+                    rng=np.random.default_rng(12))
+    d0 = serving.dispatch_count()
+    outs, timings = eng.serve_group(prompts, 7, temperature=1.3,
+                                    rng=np.random.default_rng(12))
+    np.testing.assert_array_equal(np.stack(outs), want)
+    assert serving.dispatch_count() - d0 == 7
+    assert timings["decode_steps_fed_on_device"] == 0
+    assert timings["decode_readback_bytes_per_step"] == 4 * 4 * 96
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

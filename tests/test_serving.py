@@ -21,7 +21,7 @@ from mxnet_tpu import checkpoint, serving, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import gpt
 from mxnet_tpu.serving.replica import FrontDoor, ReplicaServer
-from mxnet_tpu.test_utils import cpu_child_env
+from mxnet_tpu.test_utils import cpu_child_env, serving_host_walk
 
 
 def _model(seed=7, **kwargs):
@@ -95,6 +95,123 @@ def test_zero_retraces_after_warmup_across_all_buckets():
     assert serving.dispatch_count() > d0
 
 
+# -- the decode loop stays on the device ---------------------------------------
+
+def test_each_program_returns_the_greedy_ids_and_next_positions():
+    """Every served id is `np.argmax` of the logits the same program
+    returned, and a greedy group's tokens are those of the path with the
+    host in every step."""
+    eng = serving.ServingEngine(_model(), batch_buckets=(4,))
+    prompts = _prompts(3, np.random.RandomState(21))
+    want, _ = serving_host_walk(eng, prompts, 6)
+    outs, _ = eng.serve_group(prompts, 6)
+    np.testing.assert_array_equal(np.stack(outs), want)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 7])
+def test_greedy_group_is_fed_on_the_device(steps):
+    """No host round trip between steps: after warm-up a group
+    dispatches its prefill and `steps - 1` decode programs and nothing
+    else (no third program a step), every decode step takes the step
+    before's ids and positions, and the host reads 4 bytes a row."""
+    eng = serving.ServingEngine(_model(), batch_buckets=(4,))
+    prompts = _prompts(3, np.random.RandomState(4))
+    eng.serve_group(prompts, steps)                 # compiles
+    pinned = (serving.trace_count(), serving.compile_count())
+    d0 = serving.dispatch_count()
+    outs, timings = eng.serve_group(prompts, steps)
+    assert serving.dispatch_count() - d0 == 1 + (steps - 1)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    assert timings["decode_steps_fed_on_device"] == steps - 1
+    assert timings["decode_readback_bytes_per_step"] == 4 * 4
+    assert [len(o) for o in outs] == [steps] * 3
+    ts = timings["token_t_us"]
+    assert len(ts) == steps and all(a < b for a, b in zip(ts, ts[1:]))
+    assert ts[0] > 0
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_sampled_group_draws_on_the_host_from_the_programs_logits(seed):
+    """A request with a temperature: the same loop reads each program's
+    logits and draws with the caller's generator, token for token the
+    stream of the path with the host in every step; no step is fed on
+    the device."""
+    eng = serving.ServingEngine(_model(), batch_buckets=(4,))
+    prompts = _prompts(3, np.random.RandomState(8))
+    want, _ = serving_host_walk(eng, prompts, 6, temperature=0.7,
+                                rng=np.random.default_rng(seed))
+    d0 = serving.dispatch_count()
+    outs, timings = eng.serve_group(prompts, 6, temperature=0.7,
+                                    rng=np.random.default_rng(seed))
+    np.testing.assert_array_equal(np.stack(outs), want)
+    assert serving.dispatch_count() - d0 == 6
+    assert timings["decode_steps_fed_on_device"] == 0
+    assert timings["decode_readback_bytes_per_step"] == 4 * 4 * 128
+    greedy, _ = eng.serve_group(prompts, 6)
+    assert not np.array_equal(np.stack(greedy), want)
+
+
+class _TableProgram:
+    """A family of one table and no layers: a row's logits are the
+    table's row of its last token, the cache counts the programs run."""
+
+    window, vocab = 64, 6
+
+    def __init__(self, table):
+        import jax.numpy as jnp
+
+        self._table = jnp.asarray(table, jnp.float32)
+
+    def weights(self):
+        return (self._table,)
+
+    def init_cache(self, B):
+        import jax.numpy as jnp
+
+        return (jnp.zeros((B,), jnp.int32),)
+
+    def step(self, w, cache, pos, last, toks):
+        import jax.numpy as jnp
+
+        tok = jnp.take_along_axis(toks, last[:, None], axis=1)[:, 0]
+        return (cache[0] + 1,), w[0][tok]
+
+    def counters(self, cache):
+        return {"programs_run": int(np.asarray(cache[0])[0])}
+
+
+class _TableModel:
+    def __init__(self, table):
+        self._table = table
+
+    def decoder_program(self, dtype=None, mesh=None, tp_axis="tp"):
+        return _TableProgram(self._table)
+
+
+def test_ties_go_to_the_lowest_index_as_numpys_argmax():
+    """What the engine adds around a family's step, on a family whose
+    logits are written down: equal logits give the first index, a NaN
+    counts as the largest, and the chain of tokens is NumPy's."""
+    table = np.array([[1, 3, 3, 0, 3, 2],
+                      [0, 0, 0, 0, 0, 0],
+                      [5, 1, 5, 5, 0, 0],
+                      [0, 1, 2, 4, 4, 4],
+                      [2, np.nan, 9, np.nan, 0, 0],
+                      [7, 7, 7, 7, 7, 8]], np.float32)
+    eng = serving.ServingEngine(_TableModel(table), batch_buckets=(4,))
+    prompts = [[5, 0], [2], [1, 1, 3], [4]]
+    steps = 5
+    outs, timings = eng.serve_group(prompts, steps)
+    for p, got in zip(prompts, outs):
+        tok, want = p[-1], []
+        for _ in range(steps):
+            tok = int(np.argmax(table[tok]))
+            want.append(tok)
+        assert got.tolist() == want
+    # the family's counters are read once, after the last step
+    assert timings["programs_run"] == steps
+
+
 # -- continuous batcher --------------------------------------------------------
 
 def test_batcher_coalesces_and_emits_request_records():
@@ -121,6 +238,10 @@ def test_batcher_coalesces_and_emits_request_records():
     for r in requests:
         telemetry.validate_record(r)
         assert r["generation"] == 0
+        # greedy: both decode steps took the step before's ids on the
+        # device, and the host read (4, 1) int32 of each program
+        assert r["decode_steps_fed_on_device"] == 2
+        assert r["decode_readback_bytes_per_step"] == 4 * 4
 
 
 def test_batcher_propagates_engine_errors():
@@ -420,10 +541,19 @@ def test_tp_serving_matches_unsharded(mesh8):
         toks[i, :len(p)] = p
     zero = np.zeros(2, np.int32)
     last = np.asarray([len(p) - 1 for p in prompts], np.int32)
-    _, ref_lg = plain._call(2, 8, plain.init_cache(2), zero, last, toks)
-    _, tp_lg = tp._call(2, 8, tp.init_cache(2), zero, last, toks)
+    _, ref_lg, _, _ = plain._call(2, 8, plain.init_cache(2), zero, last,
+                                  toks)
+    _, tp_lg, tp_ids, tp_pos = tp._call(2, 8, tp.init_cache(2), zero, last,
+                                        toks)
     np.testing.assert_allclose(np.asarray(tp_lg), np.asarray(ref_lg),
                                rtol=2e-4, atol=1e-5)
+    # the next step's inputs come back as the programs take them in:
+    # on every chip of the mesh, so a step can be fed the step before's
+    for fed in (tp_ids, tp_pos):
+        assert fed.sharding.is_equivalent_to(tp._input_sharding(), fed.ndim)
+    np.testing.assert_array_equal(
+        np.asarray(tp_ids)[:, 0], np.asarray(tp_lg).argmax(-1))
+    np.testing.assert_array_equal(np.asarray(tp_pos), last + 1)
 
     # the full request path runs end-to-end on the mesh, retrace-free
     outs, timings = tp.serve_group(prompts, 4)
@@ -742,7 +872,8 @@ def _cache_walk(eng, lens, S, decode_pos):
     last = np.asarray(lens, np.int32) - 1
     for pos, t in calls:
         # the step hands back one position a row: the last real token's
-        (ck, cv), lg = eng._call(B, t.shape[1], (ck, cv), pos, last, t)
+        (ck, cv), lg, _, _ = eng._call(B, t.shape[1], (ck, cv), pos, last,
+                                       t)
         want = _np_step(w, eng._program._act, nk, nv, pos, t)[
             np.arange(B), last]
         last = np.zeros(B, np.int32)

@@ -130,6 +130,8 @@ def test_timings_carry_the_split_of_a_decode_step(traced):
                       "decode_dispatch_us_per_step",
                       "decode_readback_us_per_step",
                       "decode_host_us_per_step", "token_t_us",
+                      "decode_steps_fed_on_device",
+                      "decode_readback_bytes_per_step",
                       "prefill_us", "decode_us_per_token", "decode_us",
                       "t_prefill0", "t_decode0", "queue_us"):
             assert field in rec, field
@@ -154,10 +156,11 @@ def test_timings_carry_the_split_of_a_decode_step(traced):
 
 
 @pytest.mark.parametrize("bucket,module,scopes", [
-    ((4, 16), "jit_serve_prefill", ["serve.embed", "serve.head"]),
+    ((4, 16), "jit_serve_prefill",
+     ["serve.embed", "serve.head", "serve.sample"]),
     ((4, 1), "jit_serve_decode",
      ["serve.embed", "serve.attn_qkv", "serve.cache_write", "serve.attn",
-      "serve.mlp", "serve.head"]),
+      "serve.mlp", "serve.head", "serve.sample"]),
 ])
 def test_serving_programs_carry_their_names_and_scopes(traced, bucket,
                                                        module, scopes):
