@@ -1,8 +1,8 @@
 """Whole-step graph capture for the imperative Gluon Trainer.
 
 `ShardedTrainer` already compiles its entire step into one XLA program;
-the imperative path — the one the tests, examples and the trainer bench
-exercise — paid 4+ dispatches per step: the CachedOp forward, the
+the imperative path — the one the tests, examples and the training
+cell exercise — paid 4+ dispatches per step: the CachedOp forward, the
 tape backward, the health reduction, and one GroupedUpdater program per
 param group (plus per-microbatch grad-accumulate dispatches).  This
 module is the CachedOp idea applied to the *whole step*: given a
@@ -129,7 +129,8 @@ def resolve_pp_schedule(mesh, grad_accum, batch):
 # dispatch: exactly ONE per captured step.  trace: increments only when
 # jit actually re-traces train_step (a python side effect in the traced
 # body) — the retrace-regression tests pin this at one per signature.
-# hits/misses: Trainer-level capture-cache stats, reported by bench.py.
+# hits/misses: Trainer-level capture-cache stats (`cache_stats()`:
+# chip_smoke.py's train phase and tests/test_captured_step.py read them).
 
 _DISPATCH_COUNT = 0
 _TRACE_COUNT = 0

@@ -33,9 +33,8 @@ the set and skips them loudly (one ``batch_quarantined`` telemetry
 event per skip, emitted by the consuming iterator) instead of
 re-triggering the divergence.
 
-This module is deliberately numpy+stdlib only — it loads standalone
-(``bench.py``'s orchestrator keeps its driver jax-free) and in spawned
-loader workers.
+This module is deliberately numpy+stdlib only — it loads in spawned
+loader workers, which stay off JAX.
 """
 
 from __future__ import annotations

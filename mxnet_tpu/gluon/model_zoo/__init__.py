@@ -9,6 +9,6 @@ from .bert import (BERTModel, BERTPretrainLoss, TransformerEncoder,
                    TransformerEncoderLayer, bert_base, bert_large,
                    bert_tiny)
 from .gpt import (GPTModel, GPTLMLoss, gpt2_small, gpt2_medium,
-                  gpt_tiny, CachedDecoder, speculative_decode)
+                  gpt_tiny, CachedDecoder)
 from .model_store import get_model_file, purge
 from . import transformer

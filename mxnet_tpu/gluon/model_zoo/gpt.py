@@ -201,9 +201,9 @@ def extract_decoder_stacks(model):
     """Pull a GPTModel's trunk parameters into (L, ...) stacks, for scan
     and unstacked trunks alike.  Returns
     ``(stacks, (lnf_gamma, lnf_beta), tok_embed, pos_embed, num_heads,
-    activation)`` — the single weight-extraction home shared by
-    CachedDecoder and the serving tier (mxnet_tpu/serving/engine.py),
-    so both consume the exact same layout."""
+    activation)`` — the single weight-extraction home: what
+    GPTDecoderProgram, and through it CachedDecoder and the serving
+    tier (mxnet_tpu/serving/engine.py), build their layout from."""
     params = dict(model.collect_params())
 
     def get1(suffix):
@@ -331,9 +331,9 @@ class GPTDecoderProgram:
 
     def _prepare(self, stacks, lnf, tok, pos):
         """Head-/hidden-major restructure + serving dtype + tp placement
-        (the same Megatron column/row layout CachedDecoder._build
-        derives, but produced as a flat argument tuple so the compiled
-        programs take weights as inputs — the hot-reload contract)."""
+        (Megatron column-parallel qkv/ffn1, row-parallel proj/ffn2),
+        produced as a flat argument tuple so the compiled programs take
+        weights as inputs — the hot-reload contract."""
         s = dict(stacks)
         if self._dtype is not None:
             for nm in ("qkv_stack_weight", "proj_stack_weight",
@@ -522,158 +522,28 @@ class GPTDecoderProgram:
 
 
 class CachedDecoder:
-    """Wraps a GPTModel into jitted prefill/step functions.
+    """The model zoo's NDArray-in, NDArray-out cached decode: one
+    uniform-length batch walked through the family's own serving step
+    (``GPTDecoderProgram.step``, jitted once with the cache donated),
+    the host picking every token with ``_sample``.  ``decode`` mirrors
+    ``generate``'s sampling surface and gives its tokens.
 
-    Works for scan and unstacked trunks alike: parameters are pulled
-    into (L, ...) stacks once at construction.  ``decode`` mirrors
-    ``generate``'s sampling surface but runs the cached path.
+    Scan and unstacked trunks alike; ``dtype=`` and ``mesh=`` (with a
+    ``tp_axis`` mesh axis) are the program's: bf16 weight stacks, embed
+    tables and cache with f32 accumulation, and the Megatron head/FFN
+    split with the cache sharded on its head axis.
 
-    Pass ``mesh=`` (with a ``tp_axis`` mesh axis) for tensor-parallel
-    serving: heads, the KV cache, and the FFN hidden dim shard over the
-    axis (Megatron column/row rules) and GSPMD inserts the two
-    per-layer all-reduces — multi-chip decode with no code change.
+    No bucket table, batching, counters or spans: a server wants
+    `serving.ServingEngine` (docs/serving.md), which compiles the same
+    step ahead of time and feeds it on the device.
     """
 
     def __init__(self, model, mesh=None, tp_axis="tp", dtype=None):
-        self._W = model._max_length
-        self._mesh = mesh
-        self._tp_axis = tp_axis
-        self._dtype = dtype
-        (stacks, (lnf_g, lnf_b), tok, pos,
-         num_heads, act) = extract_decoder_stacks(model)
-        self._stacks = stacks
-        self._lnf = (lnf_g, lnf_b)
-        self._tok = tok
-        self._pos = pos
-        if dtype is not None:
-            # Serving precision: the BIG tensors (weight stacks, embed
-            # tables, and — via self._tok.dtype — the KV cache) go
-            # bf16 in HBM; LN/bias params and all accumulations stay
-            # f32 (jnp promotion), so this halves the HBM traffic the
-            # bandwidth-bound decode step is limited by without
-            # touching the numerics-sensitive reductions.
-            for nm in ("qkv_stack_weight", "proj_stack_weight",
-                       "ffn1_stack_weight", "ffn2_stack_weight"):
-                self._stacks[nm] = self._stacks[nm].astype(dtype)
-            self._tok = self._tok.astype(dtype)
-            self._pos = self._pos.astype(dtype)
-        self._H = num_heads
-        self._act = act
-        self._step_fn = None
-
-    def _shard(self, arr, spec):
-        """Place with a NamedSharding when a tp mesh is set (GSPMD then
-        propagates the layout and inserts the collectives); no-op on the
-        single-device path."""
-        if self._mesh is None:
-            return arr
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        return jax.device_put(arr, NamedSharding(self._mesh, P(*spec)))
-
-    def _init_cache(self, B):
-        """Fresh zeroed (ck, cv) for batch B, with the serving dtype
-        and (when a tp mesh is set) the head-sharded layout."""
-        import jax.numpy as jnp
-
-        L = self._stacks["qkv_stack_weight"].shape[0]
-        Dh = self._tok.shape[1] // self._H
-        spec = (None, None, self._tp_axis, None, None)
-        shape = (L, B, self._H, self._W, Dh)
-        return (self._shard(jnp.zeros(shape, self._tok.dtype), spec),
-                self._shard(jnp.zeros(shape, self._tok.dtype), spec))
-
-    def _build(self):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        from ...ops.nn import layer_norm
-
-        H, W = self._H, self._W
-        tok_e, pos_e = self._tok, self._pos
-        lnf_g, lnf_b = self._lnf
-        s = self._stacks
-        C = tok_e.shape[1]
-        Dh = C // H
-        act = self._act
-        L = s["qkv_stack_weight"].shape[0]
-        F = s["ffn1_stack_weight"].shape[1]
-        tp = self._tp_axis
-        if self._mesh is not None:
-            n_tp = self._mesh.shape[tp]
-            if H % n_tp or F % n_tp:
-                raise ValueError(
-                    f"CachedDecoder: tp axis size {n_tp} must divide "
-                    f"both num_heads={H} and ffn hidden={F}")
-
-        # Head-/hidden-major restructuring so a tp mesh shards the H and
-        # F dims (Megatron rules: column-parallel qkv/ffn1, row-parallel
-        # proj/ffn2 — the contraction over a sharded dim becomes XLA's
-        # all-reduce).  Single-device runs the same code unsharded.
-        qkvw = self._shard(
-            s["qkv_stack_weight"].reshape(L, 3, H, Dh, C),
-            (None, None, tp))
-        qkvb = self._shard(s["qkv_stack_bias"].reshape(L, 3, H, Dh),
-                           (None, None, tp))
-        pwh = self._shard(s["proj_stack_weight"].reshape(L, C, H, Dh),
-                          (None, None, tp))
-        f1w = self._shard(s["ffn1_stack_weight"], (None, tp))
-        f1b = self._shard(s["ffn1_stack_bias"], (None, tp))
-        f2w = self._shard(s["ffn2_stack_weight"], (None, None, tp))
-        pb, f2b = s["proj_stack_bias"], s["ffn2_stack_bias"]
-
-        def step(ck, cv, pos, toks):
-            """Block step: ck/cv (L, B, H, W, Dh); pos scalar (write
-            offset); toks (B, S) int32 — S tokens processed in one
-            causal pass (S=1 is the classic per-token step; S=T0 is
-            chunked prefill; S=k verifies a speculative draft block).
-            Returns (new_ck, new_cv, logits (B, S, vocab))."""
-            S = toks.shape[1]
-            # residual stream in f32 regardless of the serving dtype
-            x = (jnp.take(tok_e, toks, axis=0) +
-                 lax.dynamic_slice(pos_e, (pos, 0), (S, C))[None]
-                 ).astype(jnp.float32)                        # (B, S, C)
-
-            def layer(x, per):
-                (qw, qb, pw, pb, f1w, f1b, f2w, f2b, g1, b1, g2, b2,
-                 ck_l, cv_l) = per
-                h = layer_norm(x, g1, b1)
-                qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
-                qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
-                kh = qkv[:, :, 1].swapaxes(1, 2)
-                vh = qkv[:, :, 2].swapaxes(1, 2)
-                ck_l = lax.dynamic_update_slice(
-                    ck_l, kh.astype(ck_l.dtype), (0, 0, pos, 0))
-                cv_l = lax.dynamic_update_slice(
-                    cv_l, vh.astype(cv_l.dtype), (0, 0, pos, 0))
-                scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
-                    * (Dh ** -0.5)
-                mask = jnp.arange(W)[None, :] <= \
-                    pos + jnp.arange(S)[:, None]              # (S, W)
-                scores = jnp.where(mask[None, None], scores, -1e30)
-                p = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
-                attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb
-                x = x + attn
-                h = layer_norm(x, g2, b2)
-                h = h @ f1w.T + f1b
-                h = jax.nn.gelu(h) if act == "gelu" \
-                    else jnp.maximum(h, 0)
-                x = x + (h @ f2w.T + f2b)
-                return x, (ck_l, cv_l)
-
-            per_layer = (qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
-                         s["ln1_stack_gamma"], s["ln1_stack_beta"],
-                         s["ln2_stack_gamma"], s["ln2_stack_beta"],
-                         ck, cv)
-            x, (ck2, cv2) = lax.scan(layer, x, per_layer)
-            h = layer_norm(x, lnf_g, lnf_b)
-            logits = h @ tok_e.T   # bf16 table promotes to f32 in-op
-            return ck2, cv2, logits
-
-        self._step_fn = jax.jit(step, donate_argnums=(0, 1))
+        self._program = GPTDecoderProgram(model, dtype=dtype, mesh=mesh,
+                                          tp_axis=tp_axis)
+        self._step_fn = jax.jit(self._program.step, donate_argnums=(1,))
 
     def decode(self, ids, max_new_tokens=16, temperature=None,
                rng=None, return_logits=False):
@@ -686,172 +556,43 @@ class CachedDecoder:
         pre-sampling logits stack (scoring / equivalence checks)."""
         import numpy as np
 
-        import jax.numpy as jnp
-
         from ... import ndarray as nd
 
-        if self._step_fn is None:
-            self._build()
+        program = self._program
         out = ids.asnumpy().astype(np.int32)
         B, T0 = out.shape
-        if T0 + max_new_tokens > self._W:
+        if T0 + max_new_tokens > program.window:
             raise ValueError(
                 f"decode: {T0} seed + {max_new_tokens} new tokens "
-                f"exceed the cache window max_length={self._W}; use "
-                "generate() for sliding-window decoding")
-        ck, cv = self._init_cache(B)
-        # Chunked prefill: the whole seed in ONE block-step call.  The
-        # seed is right-padded to a power-of-two bucket so a serving
-        # loop with varied prompt lengths compiles log2(W) prefill
-        # programs, not one per distinct T0.  Pad garbage written at
-        # cache positions >= T0 is harmless: position q only becomes
-        # attendable at the step whose pos == q, and that same step
-        # overwrites q before attending.
+                f"exceed the cache window max_length={program.window}; "
+                "use generate() for sliding-window decoding")
+        # The whole seed in one block step, right-padded to a
+        # power-of-two bucket (log2(W) prefill programs, not one per
+        # T0).  Pad garbage written at cache positions >= T0 is
+        # harmless: position q only becomes attendable at the step
+        # whose pos == q, and that same step overwrites q first.
         T0p = 8
         while T0p < T0:
             T0p *= 2
-        T0p = min(T0p, self._W)
-        padded = np.zeros((B, T0p), np.int32)
-        padded[:, :T0] = out
-        ck, cv, logits = self._step_fn(
-            ck, cv, jnp.asarray(0), jnp.asarray(padded))
-        logits = logits[:, T0 - 1]
+        toks = np.zeros((B, min(T0p, program.window)), np.int32)
+        toks[:, :T0] = out
+        w, cache = program.weights(), program.init_cache(B)
+        pos = np.zeros(B, np.int32)
+        last = np.full(B, T0 - 1, np.int32)
         lg = []
         for n in range(max_new_tokens):
-            cur = np.asarray(logits)
-            lg.append(cur)
-            nxt = _sample(cur, temperature, rng)
+            cache, logits = self._step_fn(w, cache, pos, last, toks)
+            lg.append(np.asarray(logits))
+            nxt = _sample(lg[-1], temperature, rng)
             out = np.concatenate([out, nxt[:, None]], axis=1)
-            if n < max_new_tokens - 1:   # last token needs no step
-                ck, cv, logits = self._step_fn(
-                    ck, cv, jnp.asarray(T0 + n), jnp.asarray(nxt[:, None]))
-                logits = logits[:, -1]
-        toks = nd.array(out.astype(np.float32))
+            pos = np.full(B, T0 + n, np.int32)
+            last, toks = np.zeros(B, np.int32), nxt[:, None]
+        result = nd.array(out.astype(np.float32))
         if return_logits:
-            vocab = self._tok.shape[0]
             stacked = np.stack(lg) if lg else \
-                np.zeros((0, B, vocab), np.float32)
-            return toks, stacked
-        return toks
-
-
-def speculative_decode(target, draft, ids, max_new_tokens=16, k=4,
-                       return_stats=False):
-    """Greedy speculative decoding (LOSSLESS: emits exactly the tokens
-    ``CachedDecoder(target).decode`` would emit greedily).
-
-    The cheap ``draft`` model proposes ``k`` tokens with k O(1)-context
-    steps; the ``target`` verifies the whole block in ONE block-step
-    (the same MXU-friendly shape as chunked prefill), accepting the
-    longest prefix where the target's own greedy choice agrees, plus
-    the target's replacement token at the first disagreement.  Batched:
-    rows advance in lockstep at the minimum per-row acceptance (greedy
-    determinism makes re-proposal of the tail exact, so uniform
-    progress stays lossless).
-
-    target/draft: GPTModel or CachedDecoder (tp/bf16 decoders work).
-    Returns (B, T0+N) tokens; with ``return_stats=True`` also a dict
-    with rounds / accepted-token counts.
-
-    Caveat: "exactly" is up to float32 rounding ties — the S=k+1
-    verify step may reduce in a different order than decode()'s S=1
-    step, so an argmax sitting inside rounding noise can flip (the
-    same class of tie the tp all-reduce path documents).
-    """
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    from ... import ndarray as nd
-
-    tgt = target if isinstance(target, CachedDecoder) \
-        else CachedDecoder(target)
-    drf = draft if isinstance(draft, CachedDecoder) \
-        else CachedDecoder(draft)
-    for dec in (tgt, drf):
-        if dec._step_fn is None:
-            dec._build()
-
-    out = ids.asnumpy().astype(np.int32)
-    B, T0 = out.shape
-    total = T0 + max_new_tokens
-    if total + k > min(tgt._W, drf._W):
-        raise ValueError(
-            f"speculative_decode: {total} tokens + {k} draft overshoot "
-            f"exceed cache window (target {tgt._W}, draft {drf._W})")
-
-    t_ck, t_cv = tgt._init_cache(B)
-    d_ck, d_cv = drf._init_cache(B)
-    # prefill BOTH through the seed minus its last token: the invariant
-    # is "cache holds positions < P-1; the last committed token is the
-    # next thing fed", so the seed's last token heads the first block.
-    # Right-padded to a power-of-two bucket (same compile-count and
-    # pad-garbage-overwrite argument as decode()'s chunked prefill).
-    if T0 > 1:
-        Tp = 8
-        while Tp < T0 - 1:
-            Tp *= 2
-        Tp = min(Tp, min(tgt._W, drf._W))
-        padded = np.zeros((B, Tp), np.int32)
-        padded[:, :T0 - 1] = out[:, :-1]
-        t_ck, t_cv, _ = tgt._step_fn(
-            t_ck, t_cv, jnp.asarray(0), jnp.asarray(padded))
-        d_ck, d_cv, _ = drf._step_fn(
-            d_ck, d_cv, jnp.asarray(0), jnp.asarray(padded))
-
-    P = T0
-    dp = T0 - 1  # next draft-cache position to write
-    rounds = accepted_total = 0
-    while P < total:
-        # 0. draft cache catch-up: after a full-accept round the bonus
-        # token advanced P past what the proposal loop wrote (it writes
-        # through P+k-2, the bonus needs P+k-1) — feed the missing
-        # committed token(s) so the draft never attends a stale slot
-        while dp < P - 1:
-            d_ck, d_cv, _ = drf._step_fn(
-                d_ck, d_cv, jnp.asarray(dp),
-                jnp.asarray(out[:, dp][:, None]))
-            dp += 1
-        # 1. draft proposes k tokens, one cheap step each
-        props = np.zeros((B, k), np.int32)
-        last = out[:, P - 1]
-        for j in range(k):
-            d_ck, d_cv, d_lg = drf._step_fn(
-                d_ck, d_cv, jnp.asarray(P - 1 + j),
-                jnp.asarray(last[:, None]))
-            last = np.argmax(np.asarray(d_lg[:, -1]), axis=-1) \
-                .astype(np.int32)
-            props[:, j] = last
-        dp = P - 1 + k
-        # 2. target verifies in ONE (k+1)-block step: inputs are the
-        # last committed token + all k proposals at positions P-1..;
-        # choice[:, j] is the target's greedy pick for position P+j —
-        # including the FREE bonus token choice[:, k] on full accept
-        block = np.concatenate([out[:, P - 1:P], props], axis=1)
-        t_ck, t_cv, t_lg = tgt._step_fn(
-            t_ck, t_cv, jnp.asarray(P - 1), jnp.asarray(block))
-        choice = np.argmax(np.asarray(t_lg), axis=-1) \
-            .astype(np.int32)                            # (B, k+1)
-        # 3. longest agreeing prefix, uniform across the batch
-        agree = (props == choice[:, :k])
-        full = agree.all(axis=1)
-        first_bad = np.where(full, k, np.argmin(agree, axis=1))
-        m = int(first_bad.min())
-        # commit m accepted proposals + the target's own next token
-        # (replacement at the first disagreement, bonus on full accept)
-        commit = np.concatenate(
-            [props[:, :m], choice[:, m:m + 1]], axis=1)
-        commit = commit[:, :total - P]
-        out = np.concatenate([out, commit], axis=1)
-        P += commit.shape[1]
-        rounds += 1
-        accepted_total += min(m, commit.shape[1])
-    toks = nd.array(out.astype(np.float32))
-    if return_stats:
-        return toks, {"rounds": rounds, "proposed_per_round": k,
-                      "accepted_draft_tokens": accepted_total,
-                      "new_tokens": max_new_tokens}
-    return toks
+                np.zeros((0, B, program.vocab), np.float32)
+            return result, stacked
+        return result
 
 
 # -- pipeline-parallel parts ---------------------------------------------------

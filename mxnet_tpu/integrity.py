@@ -76,7 +76,7 @@ import time
 
 try:
     from .base import MXNetError
-except ImportError:     # standalone load (tools, bench orchestrator)
+except ImportError:     # standalone load (tools)
     MXNetError = RuntimeError
 
 _SALT_LO = 0x9E3779B1   # odd golden-ratio constants: per-leaf salts

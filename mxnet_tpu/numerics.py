@@ -185,8 +185,8 @@ class StepGuard:
             from . import profiler
 
             # the step's ONE host sync: in a pipelined loop this span is
-            # where the host waits out the device (bench.py reads it for
-            # the readback share of the step-time breakdown).  The
+            # where the host waits out the device (benchmark/spans.py
+            # reads it as the readback share of a step's idle time).  The
             # integrity fingerprint (when the step computed one) rides
             # the same transfer — attestation adds no extra sync.
             with profiler.annotate("guard_readback"):

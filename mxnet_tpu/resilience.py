@@ -13,8 +13,8 @@ Four primitives, composed by the rest of the stack:
   primitive behind rendezvous (``distributed.py``) and file opens
   (``recordio.py`` / ``io/io.py``).
 - :class:`Watchdog` — a heartbeat thread armed around blocking device
-  work (step dispatch, cross-process all-reduce, ``distributed.barrier``,
-  the bench backend probe).  On expiry it dumps every Python thread's
+  work (step dispatch, cross-process all-reduce,
+  ``distributed.barrier``).  On expiry it dumps every Python thread's
   stack and then interrupts or aborts instead of hanging forever.
 - :func:`run_resilient` — a supervised training driver composing
   ``checkpoint.PreemptionHandler`` + auto-resume-from-latest-checkpoint
@@ -114,8 +114,8 @@ import zlib
 
 try:
     from .base import MXNetError
-except ImportError:  # loaded standalone (bench.py orchestrator never
-    MXNetError = RuntimeError  # imports the package, let alone jax)
+except ImportError:  # loaded standalone, by path, with no package and
+    MXNetError = RuntimeError  # no jax (tests/test_resilience.py does)
 
 
 class InjectedFault(MXNetError):
@@ -132,8 +132,8 @@ class CheckpointCorrupt(MXNetError):
 
 def _tel_event(kind, /, **fields):
     """Structured telemetry event, guarded: this module also loads
-    standalone (bench.py orchestrator keeps its driver jax-free), where
-    the relative import has no package to resolve against."""
+    standalone (tests/test_resilience.py loads it by path), where the
+    relative import has no package to resolve against."""
     try:
         from . import telemetry
     except ImportError:

@@ -122,8 +122,7 @@ def batch_buckets_from_env(default=(1, 2, 4, 8)):
 
 def prefill_buckets_for(window, floor=8):
     """Power-of-two prefill sequence buckets up to the cache window —
-    log2(W) programs cover every prompt length (the same policy
-    CachedDecoder.decode uses for its chunked prefill)."""
+    log2(W) programs cover every prompt length."""
     buckets, s = [], max(1, floor)
     while s < window:
         buckets.append(s)

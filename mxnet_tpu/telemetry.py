@@ -29,7 +29,8 @@ that attribution, always, at <1% of step time:
   Writes are line-buffered and flushed per record; a crash mid-append
   leaves every earlier line parseable (readers skip a truncated tail —
   `tools/trace_report.py`).  Without a path, records land in a bounded
-  in-memory ring (`recent_steps()`), which is how bench.py reads them.
+  in-memory ring (`recent_steps()`), which is how
+  `benchmark/readers/step_span.py` reads them (``path="captured"``).
 
 Controlled by ``MXTPU_TELEMETRY`` (default on).  Zero extra device
 dispatches or host readbacks: everything here is host timers and dict
@@ -78,7 +79,7 @@ _ACCEPTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8)
 # autotune trial marking (mxnet_tpu/autotune/runner.py): while a trial
 # config is being timed every step record is stamped
 # ``tuning_trial: true`` so steady-state consumers (recent_steps
-# default, trace_report aggregates, bench) exclude it; outside trials
+# default, trace_report aggregates) exclude it; outside trials
 # an applied tuned config still stamps its fingerprint.
 _TRIAL_FP = None
 _CONFIG_FP = None
@@ -280,7 +281,7 @@ def gauge_set(name, v):
 _RUN_ID = f"{os.getpid():x}-{int(time.time() * 1000) & 0xffffffff:08x}"
 _SINK = None          # (path, file object)
 _SINK_SIZE = 0        # bytes written to the current sink file
-_RECENT = []          # bounded ring of step records (bench.py reads it)
+_RECENT = []          # bounded ring of step records (recent_steps())
 _RECENT_MAX = 256
 _EVENT_COUNTS = {}    # event kind -> count (cheap test/report surface)
 

@@ -95,6 +95,17 @@ def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
     return np.stack(out, 1), np.stack(logits, 1)
 
 
+def jaxpr_loops(jaxpr):
+    """Every scan/while equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            yield eqn
+        for sub in eqn.params.values():
+            inner = getattr(sub, "jaxpr", None)
+            if inner is not None:
+                yield from jaxpr_loops(inner)
+
+
 def _as_np(x):
     if isinstance(x, NDArray):
         return x.asnumpy()

@@ -1,9 +1,6 @@
 """IO tests (reference: tests/python/unittest/test_io.py,
 test_recordio.py, test_gluon_data.py)."""
 
-import os
-import tempfile
-
 import numpy as np
 import pytest
 
@@ -591,32 +588,6 @@ def test_det_parse_label_rejects_malformed():
         ImageDetIter._parse_label(
             np.array([2, 5, 1.0, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7],
                      np.float32)[: -1])  # 7-value body, ow=5
-
-
-def test_native_decode_beats_pil():
-    """IO-throughput guard (BASELINE.md round-4 table): the native
-    libjpeg decode+augment path must not regress below the PIL path —
-    a cheap in-CI version of tools/bench_io.py (small batch, one
-    thread; the recorded numbers come from the tool)."""
-    import importlib.util
-    import time as _t
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_io", os.path.join(os.path.dirname(__file__), "..",
-                                 "tools", "bench_io.py"))
-    bench_io = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_io)
-    from mxnet_tpu import _native
-    if not _native.has_jpeg():
-        pytest.skip("native decode lib not built")
-    with tempfile.TemporaryDirectory() as tmp:
-        rec = os.path.join(tmp, "bench.rec")
-        bench_io.synth_rec(rec, n=48, size=(240, 320))
-        native = bench_io.run(rec, n=48, batch_size=16)
-        pil = bench_io.run(rec, n=48, batch_size=16,
-                           force_python=True)
-    assert native >= 0.9 * pil, \
-        f"native decode ({native:.0f}/s) slower than PIL ({pil:.0f}/s)"
 
 
 # -- corruption hardening (mxnet_tpu/resilience.py integration) ----------------

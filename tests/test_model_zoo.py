@@ -1,7 +1,7 @@
 """Model zoo tests (reference: tests/python/unittest/test_gluon_model_zoo.py).
 
-Full-resolution ImageNet forwards are exercised on TPU by bench.py; here we
-keep CPU-mesh costs sane: construct every family, forward the cheap ones.
+Here we keep CPU-mesh costs sane: construct every family, forward the
+cheap ones.
 """
 
 import numpy as np
@@ -80,7 +80,7 @@ def test_resnet_nhwc_matches_nchw(tmp_path):
 
 
 def test_resnet50_nhwc_structure():
-    """The bench NHWC config (resnet50_v1 layout='NHWC') builds, forwards
+    """The NHWC config (resnet50_v1 layout='NHWC') builds, forwards
     and keeps the NCHW parameter count."""
     net = vision.resnet50_v1(classes=1000, layout="NHWC")
     net.initialize(init=mx.init.Xavier())
@@ -406,23 +406,69 @@ def test_gpt_scan_matches_unstacked():
                                rtol=2e-4, atol=2e-5)
 
 
-def test_gpt_cached_decoder_matches_recompute():
-    """KV-cache incremental decoding (static cache +
-    dynamic_update_slice, ONE jitted step) produces byte-identical
-    tokens to the full-recompute generate() — both trunk variants."""
+@pytest.mark.parametrize("scan", [False, True])
+def test_gpt_cached_decoder_matches_recompute(scan):
+    """KV-cache incremental decoding (the family's serving step, one
+    jitted program walked by the host) produces byte-identical tokens
+    to the full-recompute generate() — both trunk variants."""
     from mxnet_tpu import nd
     from mxnet_tpu.gluon.model_zoo import gpt
 
-    for scan in (False, True):
-        net = gpt.gpt_tiny(scan_layers=scan)
-        net.initialize(init=mx.init.Xavier())
-        ids = nd.array(np.random.RandomState(0)
-                       .randint(0, 128, (2, 6)).astype(np.float32))
-        net(ids)
-        ref = gpt.generate(net, ids, max_new_tokens=5).asnumpy()
-        dec = gpt.CachedDecoder(net).decode(
-            ids, max_new_tokens=5).asnumpy()
-        np.testing.assert_array_equal(ref, dec, err_msg=f"scan={scan}")
+    net = gpt.gpt_tiny(scan_layers=scan)
+    net.initialize(init=mx.init.Xavier())
+    ids = nd.array(np.random.RandomState(0)
+                   .randint(0, 128, (2, 6)).astype(np.float32))
+    net(ids)
+    ref = gpt.generate(net, ids, max_new_tokens=5).asnumpy()
+    dec = gpt.CachedDecoder(net).decode(ids, max_new_tokens=5).asnumpy()
+    np.testing.assert_array_equal(ref, dec)
+
+
+def test_gpt_cached_decoder_runs_the_serving_program():
+    """CachedDecoder has no layer body of its own: its jitted step is
+    GPTDecoderProgram.step, whose one scan carries the (L, B, H, W, Dh)
+    cache pair and scans nothing of that shape (a body that scans the
+    cache in and stacks it out copies every layer each way), and its
+    walk gives what a ServingEngine's compiled programs give for the
+    same model, group and prefill bucket."""
+    import jax
+
+    from mxnet_tpu import nd, serving
+    from mxnet_tpu.gluon.model_zoo import gpt
+    from mxnet_tpu.test_utils import jaxpr_loops, serving_host_walk
+
+    net = gpt.gpt_tiny(scan_layers=True, num_layers=3)
+    net.initialize(init=mx.init.Xavier())
+    B, T0, N = 2, 6, 5
+    seed = np.random.RandomState(4).randint(0, 128, (B, T0))
+    ids = nd.array(seed.astype(np.float32))
+    net(ids)
+    dec = gpt.CachedDecoder(net)
+
+    program = dec._program
+    ck, cv = program.init_cache(B)
+    stack = tuple(ck.shape)
+    assert stack[:2] == (3, B) and len(stack) == 5
+    ints = np.zeros(B, np.int32)
+    jaxpr = jax.make_jaxpr(dec._step_fn)(
+        program.weights(), (ck, cv), ints, ints, np.zeros((B, 1), np.int32))
+
+    found = list(jaxpr_loops(jaxpr.jaxpr))
+    assert [e.primitive.name for e in found] == ["scan"]
+    scan = found[0]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    carried = [tuple(v.aval.shape)
+               for v in scan.invars[n_consts:n_consts + n_carry]]
+    assert carried.count(stack) == 2, carried
+    for v in scan.invars[n_consts + n_carry:] + scan.outvars[n_carry:]:
+        assert tuple(v.aval.shape) != stack, v.aval
+
+    toks, lg = dec.decode(ids, max_new_tokens=N, return_logits=True)
+    eng = serving.ServingEngine(net, batch_buckets=(B,))
+    want_t, want_lg = serving_host_walk(eng, seed.tolist(), N)
+    _assert_decode_equiv(
+        np.concatenate([seed, want_t], axis=1), want_lg.swapaxes(0, 1),
+        toks.asnumpy(), lg, T0=T0)
 
 
 def test_gpt_cached_decoder_tensor_parallel():
@@ -478,53 +524,7 @@ def test_gpt_cached_decoder_bf16_serving():
         ids, max_new_tokens=3, return_logits=True)
     np.testing.assert_allclose(lg_tp[0], ref_lg[0], atol=0.05 * scale)
     # the cache really is bf16 (the HBM claim)
-    dec._build()
-    assert dec._tok.dtype == jnp.bfloat16
-
-
-def test_gpt_speculative_decode_lossless():
-    """Speculative decoding emits EXACTLY the target's greedy tokens —
-    with a self-draft (all-accept fast path), an independent weaker
-    draft (mixed accept/reject), and batch > 1 (uniform-min progress)."""
-    from mxnet_tpu import nd
-    from mxnet_tpu.gluon.model_zoo import gpt
-
-    tgt = gpt.gpt_tiny(scan_layers=True)
-    tgt.initialize(init=mx.init.Xavier())
-    ids = nd.array(np.random.RandomState(5)
-                   .randint(0, 128, (3, 7)).astype(np.float32))
-    tgt(ids)
-    ref_nd, ref_lg = gpt.CachedDecoder(tgt).decode(
-        ids, max_new_tokens=9, return_logits=True)
-    ref = ref_nd.asnumpy()
-
-    def assert_lossless(spec_np):
-        """Token-exact, except a divergence whose reference top-2
-        margin is inside rounding noise (S=1 vs S=k+1 reduction-order
-        ties — see the speculative_decode docstring)."""
-        if np.array_equal(spec_np, ref):
-            return
-        j = int(np.argwhere((spec_np != ref).any(axis=0))[0, 0]) \
-            - ids.shape[1]
-        top2 = np.sort(ref_lg[j], axis=-1)[:, -2:]
-        margin = float((top2[:, 1] - top2[:, 0]).min())
-        assert margin < 1e-3 * np.abs(ref_lg[j]).max(), \
-            f"diverged at step {j} with a decisive margin {margin}"
-
-    # self-draft: every (untrimmed) proposal must be accepted
-    spec, st = gpt.speculative_decode(tgt, tgt, ids, max_new_tokens=9,
-                                      k=3, return_stats=True)
-    assert_lossless(spec.asnumpy())
-    assert st["accepted_draft_tokens"] >= 6  # all-accept up to trim
-
-    # independent draft: still lossless, some rejections expected
-    drf = gpt.gpt_tiny(scan_layers=True)
-    drf.initialize(init=mx.init.Xavier())
-    drf(ids)
-    spec2, st2 = gpt.speculative_decode(tgt, drf, ids, max_new_tokens=9,
-                                        k=3, return_stats=True)
-    assert_lossless(spec2.asnumpy())
-    assert st2["rounds"] >= st["rounds"]
+    assert dec._program.init_cache(1)[0].dtype == jnp.bfloat16
 
 
 def _assert_decode_equiv(ref_t, ref_lg, tp_t, tp_lg, T0):

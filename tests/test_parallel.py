@@ -568,7 +568,7 @@ def test_bert_ring_attention_model():
 def test_sharded_trainer_bf16_multi_step():
     """bf16 training: params must STAY bf16 across steps (the f32 lr
     scalar used to promote the update math, retracing the step and then
-    failing in the conv transpose — the round-1 bench crash class)."""
+    failing in the conv transpose — a round-1 crash class)."""
     import jax.numpy as jnp
 
     from mxnet_tpu.gluon.model_zoo import vision

@@ -21,7 +21,8 @@ from mxnet_tpu import checkpoint, serving, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import gpt
 from mxnet_tpu.serving.replica import FrontDoor, ReplicaServer
-from mxnet_tpu.test_utils import cpu_child_env, serving_host_walk
+from mxnet_tpu.test_utils import (cpu_child_env, jaxpr_loops,
+                                  serving_host_walk)
 
 
 def _model(seed=7, **kwargs):
@@ -55,11 +56,11 @@ def test_coalesced_batch_bitwise_equals_one_by_one():
     assert timings["bucket"] == [4, 8]
     assert 0 <= timings["padded_fraction"] < 1
 
-    # ground truth: the engine agrees with CachedDecoder's greedy path
-    dec = gpt.CachedDecoder(net)
+    # ground truth: full recompute, the body that shares nothing with
+    # the program
     for p, got in zip(prompts, grouped):
         seed = mx.nd.array(np.asarray([p], np.float32))
-        ref = dec.decode(seed, max_new_tokens=5).asnumpy()[0, len(p):]
+        ref = gpt.generate(net, seed, max_new_tokens=5).asnumpy()[0, len(p):]
         np.testing.assert_array_equal(ref.astype(np.int64),
                                       got.astype(np.int64))
 
@@ -881,17 +882,6 @@ def _cache_walk(eng, lens, S, decode_pos):
     return (np.asarray(ck), np.asarray(cv), np.asarray(lg)), (nk, nv, want)
 
 
-def _loops(jaxpr):
-    """Every scan/while equation of a jaxpr, nested ones included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name in ("scan", "while"):
-            yield eqn
-        for sub in eqn.params.values():
-            inner = getattr(sub, "jaxpr", None)
-            if inner is not None:
-                yield from _loops(inner)
-
-
 @pytest.mark.parametrize("kind,S", [("prefill", 8), ("decode", 1)])
 def test_step_carries_the_cache_and_aliases_it(kind, S):
     """The layer loop takes the stacked cache in and hands it out as a
@@ -908,7 +898,7 @@ def test_step_carries_the_cache_and_aliases_it(kind, S):
     jaxpr = jax.make_jaxpr(eng._step[kind])(
         eng._weights, (ck, cv), np.zeros(B, np.int32),
         np.zeros(B, np.int32), np.zeros((B, S), np.int32))
-    loops = list(_loops(jaxpr.jaxpr))
+    loops = list(jaxpr_loops(jaxpr.jaxpr))
     assert [e.primitive.name for e in loops] == ["scan"]
     scan = loops[0]
     n_fixed = scan.params["num_consts"] + scan.params["num_carry"]
