@@ -425,6 +425,25 @@ def require_fed_on_device(tag, engine, prompts, steps, served, timing):
         f"a step, tokens equal the host-picked run")
 
 
+def require_kernel_cache_writes(tag, engine, timing, platform):
+    """On the chip every cache row write of the decode program goes
+    through the in-place kernel (`ops/cache_write.py`) and its text
+    holds no dynamic-update-slice under ``serve.cache_write``; the CPU
+    has no such kernel and writes by rows."""
+    share = timing["decode_cache_write_kernel_share"]
+    text = engine._programs[(timing["bucket"][0], 1)].as_text()
+    by_rows = sum("dynamic-update-slice(" in line
+                  and "serve.cache_write" in line
+                  for line in text.splitlines())
+    say(f"[{tag}] decode program: decode_cache_write_kernel_share {share}, "
+        f"{by_rows} dynamic-update-slice under serve.cache_write")
+    on_chip = platform == "tpu"
+    require(share == float(on_chip),
+            f"{tag}: kernel share {share} of the cache writes on {platform}")
+    require(not (on_chip and by_rows),
+            f"{tag}: {by_rows} row writes left in the decode program")
+
+
 def phase_serve(size, platform, net):
     import jax.numpy as jnp
 
@@ -446,7 +465,7 @@ def phase_serve(size, platform, net):
                 for a in engine._weights + (ck, cv)),
             f"serve: weights or cache not on a {platform} device")
     # the decode step carries the cache: nothing in its compiled
-    # program copies or slices out a layer of it (B x H x W x Dh)
+    # program copies or slices out a layer of it (B x H x Dh x W)
     big = max(size.batch_buckets)
     layer = big * (ck.nbytes // ck.shape[0])
     moved = serving.whole_layer_ops(
@@ -509,6 +528,7 @@ def phase_serve(size, platform, net):
     together, timing = engine.serve_group(group, size.new_tokens)
     require_fed_on_device("serve", engine, group, size.new_tokens,
                           together, timing)
+    require_kernel_cache_writes("serve", engine, timing, platform)
     engine.batch_buckets = (big,)
     try:
         alone = [engine.serve_group([p], size.new_tokens)
@@ -634,6 +654,7 @@ def phase_serve_mimo(size, platform):
             "serve_mimo: a repeated group differs")
     require_fed_on_device("serve_mimo", engine, prompts, size.new_tokens,
                           again, timing)
+    require_kernel_cache_writes("serve_mimo", engine, timing, platform)
     dev = _ctx_for(platform).jax_device
     stats = dev.memory_stats()
     peak = stats["peak_bytes_in_use"] if stats else None

@@ -16,6 +16,7 @@ TPU-first trunk primitives:
 
 from __future__ import annotations
 
+from ...ops import cache_write
 from ..block import HybridBlock
 from .. import nn
 from .bert import ScanTransformerEncoder, TransformerEncoder
@@ -182,8 +183,8 @@ def gpt_tiny(**kwargs):
 # -- KV-cache incremental decoding ---------------------------------------------
 #
 # TPU-native inference engine for the decoder-only family: a STATIC
-# (L, B, H, W, Dh) key/value cache updated with dynamic_update_slice at
-# a traced position, so the per-token step is ONE compiled program doing
+# (L, B, H, Dh, W) key/value cache written in place at a traced position
+# (ops/cache_write.py), so the per-token step is ONE compiled program doing
 # O(W) attention instead of recomputing the O(W²) trunk (the role the
 # reference's inference-time BucketingModule/exec cache plays for RNNs).
 
@@ -286,12 +287,17 @@ class GPTDecoderProgram:
     (docs/serving.md): ``weights()``, ``init_cache(B)``,
     ``step(w, cache, pos, last, toks)``.
 
-    The cache is one ``(L, B, H, W, Dh)`` pair, stage-major like the
-    ``*_stack_*`` weights; the layer loop *carries* it whole: a layer
-    writes its new ``(B, H, S, Dh)`` rows into the stack and attends
-    over its own slice of it.  Under a ``mesh`` the weight stacks follow
-    the Megatron column/row split of TRANSFORMER_TP_RULES and the cache
-    shards on its head axis (parallel/sharding.serving_cache_sharding).
+    The cache is one ``(L, B, H, Dh, W)`` pair, stage-major like the
+    ``*_stack_*`` weights and position-minor, which is how a v5e stores
+    a head under 128 wide whatever the logical order (so a kernel sees
+    the buffer as it lies); the layer loop *carries* it whole: a layer
+    writes its new ``(B, H, Dh, S)`` rows into the stack
+    (`ops/cache_write.py`) and attends over its own slice of it.
+    ``cache_writes[S]`` counts, at trace time, the row writes of the
+    block-``S`` step by the path they took.  Under a ``mesh`` the weight
+    stacks follow the Megatron column/row split of TRANSFORMER_TP_RULES
+    and the cache shards on its head axis
+    (parallel/sharding.serving_cache_sharding).
     """
 
     def __init__(self, model, dtype=None, mesh=None, tp_axis="tp"):
@@ -314,9 +320,10 @@ class GPTDecoderProgram:
                     f"ServingEngine: tp axis size {n_tp} must divide "
                     f"num_heads={self._H} and ffn hidden={F}")
         self._w = self._prepare(stacks, lnf, tok, pos)
-        # how this platform lays a cache out on the device (a v5e puts W
-        # minor-most where Dh is under 128): read off one, not assumed
+        # how this platform lays a cache out on the device: read off
+        # one, not assumed
         self._cache_layout = self.init_cache(1)[0].format.layout
+        self.cache_writes = {}
 
     # -- weights ---------------------------------------------------------------
 
@@ -389,12 +396,13 @@ class GPTDecoderProgram:
         return serving_cache_sharding(self._mesh, tp_axis=self._tp_axis)
 
     def init_cache(self, B):
-        """Fresh zeroed (ck, cv) for batch bucket B: stage-major
-        (L, B, H, W, Dh), serving dtype, head-sharded under tp."""
+        """Fresh zeroed (ck, cv) for batch bucket B: stage-major and
+        position-minor (L, B, H, Dh, W), serving dtype, head-sharded
+        under tp."""
         import jax.numpy as jnp
 
         tok = self._w[0]
-        shape = (self._L, B, self._H, self.window, self._C // self._H)
+        shape = (self._L, B, self._H, self._C // self._H, self.window)
         # committed next to the weights: the engine serves from the
         # device(s) the model was placed on, never from the process
         # default
@@ -406,11 +414,13 @@ class GPTDecoderProgram:
     # -- the traced block step -------------------------------------------------
 
     def step(self, w, cache, pos, last, toks):
-        """cache = (ck, cv), each (L, B, H, W, Dh), donated; pos (B,)
+        """cache = (ck, cv), each (L, B, H, Dh, W), donated; pos (B,)
         per-row write offsets; last (B,) the index in the block of each
         row's last real token; toks (B, S) int32.  Returns ((ck', cv'),
         logits (B, vocab)) at ``last``.  S = seq bucket for prefill, 1
         for decode."""
+        import collections
+
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -441,27 +451,12 @@ class GPTDecoderProgram:
         (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
          g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
         B, S = toks.shape
+        tally = self.cache_writes[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
             x = (jnp.take(tok_e, toks, axis=0) +
                  jnp.take(pos_e, positions, axis=0)
                  ).astype(jnp.float32)                     # (B, S, C)
-
-        def write(c, new, l):
-            """Row b's new (H, S, Dh) block into the carried stack
-            at [l, b, :, pos[b]:pos[b] + S, :], and nothing else: one
-            dynamic_update_slice a row, each at that row's own
-            offset (a start that would run past W is clamped)."""
-            new = new.astype(c.dtype)
-            zero = jnp.int32(0)
-            for b in range(B):
-                c = lax.dynamic_update_slice(
-                    c, new[b][None, None],
-                    (l, jnp.int32(b), zero, pos[b], zero))
-            # keep the stack in the layout the donated buffer came
-            # in: left to itself the TPU compiler re-lays the whole
-            # cache around the loop to make these writes cheaper
-            return keep_layout(c)
 
         def layer(carry, per):
             x, ck, cv = carry
@@ -471,15 +466,21 @@ class GPTDecoderProgram:
                 h = layer_norm(x, g1, b1)
                 qkv = jnp.einsum("bsc,thdc->bsthd", h, qw) + qb
                 qh = qkv[:, :, 0].swapaxes(1, 2)     # (B, H, S, Dh)
-                kh = qkv[:, :, 1].swapaxes(1, 2)
-                vh = qkv[:, :, 2].swapaxes(1, 2)
+                kh = qkv[:, :, 1].transpose(0, 2, 3, 1)  # (B, H, Dh, S)
+                vh = qkv[:, :, 2].transpose(0, 2, 3, 1)
             with jax.named_scope("serve.cache_write"):
-                ck = write(ck, kh, l)
-                cv = write(cv, vh, l)
+                # row b's block at [l, b, :, :, pos[b]:pos[b] + S] and
+                # nothing else (a start that would run past W is
+                # clamped).  The stacks stay in the layout the donated
+                # buffers came in: left to itself the TPU compiler
+                # re-lays the whole cache around the loop to make row
+                # writes cheaper
+                ck, cv = (keep_layout(c) for c in cache_write.write_rows(
+                    (ck, cv), (kh, vh), l, pos, mesh=mesh, tally=tally))
             with jax.named_scope("serve.attn"):
                 ck_l = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
                 cv_l = lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
-                scores = jnp.einsum("bhsd,bhwd->bhsw", qh, ck_l) \
+                scores = jnp.einsum("bhsd,bhdw->bhsw", qh, ck_l) \
                     * (Dh ** -0.5)
                 # per-row causal mask: row b at block offset s may
                 # see cache slots <= pos[b] + s (stale pad garbage
@@ -490,7 +491,7 @@ class GPTDecoderProgram:
                      jnp.arange(S)[None, :, None])         # (B, S, W)
                 scores = jnp.where(mask[:, None], scores, -1e30)
                 p = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum("bhsw,bhwd->bhsd", p, cv_l)
+                attn = jnp.einsum("bhsw,bhdw->bhsd", p, cv_l)
                 attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
                 x = x + attn
             with jax.named_scope("serve.mlp"):

@@ -23,10 +23,11 @@ A decoder whose layers are of two kinds, listed by ``layer_types``:
 step (docs/serving.md, "The decoder program"): two kinds of cache, four
 stacks, each carried, donated and written in place:
 
-- full layers: keys ``(Lf, B, Hkv, W, qk_dim)`` and values
-  ``(.., v_dim)``; row b's block lands at ``pos[b] .. pos[b] + S``;
-- window layers: a ring of ``window`` slots, ``(Lw, B, Hkv', window,
-  ..)``: position p lives in slot ``p mod window``.  Prefill attends
+- full layers: keys ``(Lf, B, Hkv, qk_dim, W)`` and values
+  ``(.., v_dim, W)``, positions on the minor axis (`ops/cache_write.py`
+  says why); row b's block lands at ``pos[b] .. pos[b] + S``;
+- window layers: a ring of ``window`` slots, ``(Lw, B, Hkv', ..,
+  window)``: position p lives in slot ``p mod window``.  Prefill attends
   inside its own block (the engine always prefills from position 0) and
   then writes each row's last ``min(length, window)`` positions; decode
   writes one slot and attends over the ring, masking slots not yet
@@ -47,6 +48,7 @@ from __future__ import annotations
 
 
 from ...base import MXNetError
+from ...ops import cache_write
 from ..block import HybridBlock
 
 _MASKED = -1e30
@@ -147,11 +149,11 @@ def _attend_blocks(q, k, v, sink, window, blk):
 
 def _attend_cache(q, ck, cv, seen, sink):
     """One query position a row over a cache layer: q (B, K, G, D)
-    scaled; ck (B, K, W, D); cv (B, K, W, Dv); ``seen`` (B, W) bool;
+    scaled; ck (B, K, D, W); cv (B, K, Dv, W); ``seen`` (B, W) bool;
     ``sink`` (K, G) or None.  Returns (B, K, G, Dv) float32."""
     import jax.numpy as jnp
 
-    s = jnp.einsum("bkgd,bkwd->bkgw", q, ck,
+    s = jnp.einsum("bkgd,bkdw->bkgw", q, ck,
                    preferred_element_type=jnp.float32)
     s = jnp.where(seen[:, None, None, :], s, _MASKED)
     m = jnp.max(s, axis=-1)
@@ -161,7 +163,7 @@ def _attend_cache(q, ck, cv, seen, sink):
     denom = jnp.sum(p, axis=-1)
     if sink is not None:
         denom = denom + jnp.exp(sink[None] - m)
-    a = jnp.einsum("bkgw,bkwd->bkgd", p.astype(cv.dtype), cv,
+    a = jnp.einsum("bkgw,bkdw->bkgd", p.astype(cv.dtype), cv,
                    preferred_element_type=jnp.float32)
     return a / denom[..., None]
 
@@ -484,6 +486,8 @@ class MiMoV2Program:
         self.window = model._max_length
         self.vocab = model._vocab
         self._pins = None
+        # cache_writes[S]: the row writes of the block-S step, by path
+        self.cache_writes = {}
         z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (tuple(z.layer_types), tuple(z.moe_layers),
@@ -522,10 +526,10 @@ class MiMoV2Program:
         def zeros(shape, dtype=kv_dtype):
             return jnp.zeros(shape, dtype, device=emb.sharding)
 
-        cache = (zeros((Lf, B, Kf, self.window, z.qk_dim)),
-                 zeros((Lf, B, Kf, self.window, z.v_dim)),
-                 zeros((Lw, B, Kw, z.window, z.qk_dim)),
-                 zeros((Lw, B, Kw, z.window, z.v_dim)),
+        cache = (zeros((Lf, B, Kf, z.qk_dim, self.window)),
+                 zeros((Lf, B, Kf, z.v_dim, self.window)),
+                 zeros((Lw, B, Kw, z.qk_dim, z.window)),
+                 zeros((Lw, B, Kw, z.v_dim, z.window)),
                  zeros(self._counter_shape(), jnp.int32))
         if self._pins is None:
             # each stack stays in the layout its donated buffer came in:
@@ -566,6 +570,8 @@ class MiMoV2Program:
         (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
         S > 1 is a prefill from an empty cache: it attends inside the
         block.  S = 1 attends over the caches."""
+        import collections
+
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -580,21 +586,21 @@ class MiMoV2Program:
         decode = S == 1
         R = z.window
         zero = jnp.int32(0)
+        tally = self.cache_writes[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             x = jnp.take(w["embed_weight"], toks, axis=0
                          ).astype(jnp.float32)
             at = pos[:, None] + jnp.arange(S)[None, :]            # (B, S)
             valid = jnp.arange(S)[None, :] <= last[:, None]
 
-        def write_rows(c, new, l, starts, pin):
-            """Row b's (K, S', D) block into stack ``c`` at
-            [l, b, :, starts[b]:, :]: one dynamic_update_slice a row."""
-            new = new.astype(c.dtype)
-            for b in range(B):
-                c = lax.dynamic_update_slice(
-                    c, new[b][None, None],
-                    (jnp.int32(l), jnp.int32(b), zero, starts[b], zero))
-            return c if pin is None else with_layout_constraint(c, pin)
+        def write(stacks, new, l, starts, pin):
+            """Row b's (K, D, S') blocks into the two stacks at
+            [l, b, :, :, starts[b]:], each kept in its layout."""
+            out = cache_write.write_rows(
+                stacks, [a.swapaxes(2, 3) for a in new], l, starts,
+                tally=tally)
+            return [c if p is None else with_layout_constraint(c, p)
+                    for c, p in zip(out, pin)]
 
         def ring_of(k):
             """The ring a prefilled row leaves: slot s holds the latest
@@ -617,17 +623,14 @@ class MiMoV2Program:
                     rows, x, at)
             with jax.named_scope("serve.cache_write"):
                 if kind == "full":
-                    fk = write_rows(fk, k, l, pos, pins[0])
-                    fv = write_rows(fv, v, l, pos, pins[1])
+                    fk, fv = write((fk, fv), (k, v), l, pos, pins[:2])
                 elif decode:
-                    wk = write_rows(wk, k, l, pos % R, pins[2])
-                    wv = write_rows(wv, v, l, pos % R, pins[3])
+                    wk, wv = write((wk, wv), (k, v), l, pos % R, pins[2:])
                 else:
                     at_layer = (jnp.int32(l), zero, zero, zero, zero)
-                    wk = lax.dynamic_update_slice(
-                        wk, ring_of(k).astype(wk.dtype)[None], at_layer)
-                    wv = lax.dynamic_update_slice(
-                        wv, ring_of(v).astype(wv.dtype)[None], at_layer)
+                    wk, wv = (lax.dynamic_update_slice(
+                        c, ring_of(a).swapaxes(2, 3).astype(c.dtype)[None],
+                        at_layer) for c, a in ((wk, k), (wv, v)))
             if decode:
                 with jax.named_scope(f"serve.attn_{kind}"):
                     if kind == "full":

@@ -1,0 +1,138 @@
+"""Writing a step's new keys and values into the serving caches.
+
+A decoder program carries its KV stacks through the layer loop, donated
+and position-minor: ``(L, B, K, D, W)``, W positions on the minor axis
+(what a v5e stores for a head under 128 wide whatever the logical order
+says; with the logical order the same, a kernel sees the buffer as it
+lies).  ``write_rows`` puts row b's new ``(K, D, S)`` block of layer
+``l`` at positions ``starts[b] .. starts[b] + S`` and touches nothing
+else.  Two paths, chosen on what the call can see:
+
+- **kernel** (``S == 1``, a TPU, no mesh): one Pallas call for all the
+  stacks given, each aliased to its output.  Grid over the rows: step b
+  brings in the one lane block of row b, layer l that holds position
+  ``starts[b]`` (all K heads x D x 128 positions), replaces lane
+  ``starts[b] % 128`` with the new values and the pipeline writes the
+  block back.  Different rows' blocks are disjoint, so the pipeline
+  overlaps them.  A position outside the window is clipped into it,
+  where ``dynamic_update_slice``'s clamp puts a one-position write.
+- **rows** (prefill, the CPU, a program with a mesh): one
+  ``lax.dynamic_update_slice`` a row and a stack.  It is also what the
+  kernel is tested against (tests/test_cache_write.py, interpreted).
+
+``tally`` (a ``collections.Counter`` or None) is told at trace time how
+many row writes went by which path: ``tally["kernel"]``,
+``tally["rows"]``.
+"""
+
+from __future__ import annotations
+
+import functools
+import threading
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+_LANE = 128
+
+
+def _import_pallas():
+    from jax.experimental import pallas  # noqa: F401
+    from jax.experimental.pallas import tpu  # noqa: F401
+
+
+# Tracing the kernel imports Pallas: 1.0-1.4 s of Python, which a server
+# would pay inside the compile of its first decode program.  Begun when
+# this module is imported (with the model zoo), it runs beside whatever
+# the process does until then; what of it is hidden is what that work
+# waits for outside the interpreter (reading a checkpoint, the device).
+# A process held to the CPU never traces the kernel.
+if jax.config.jax_platforms != "cpu":
+    threading.Thread(target=_import_pallas, name="import-pallas",
+                     daemon=True).start()
+
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def write_rows(stacks, news, l, starts, mesh=None, tally=None):
+    """``stacks``: arrays ``(L, B, K, D, W)``; ``news``: as many arrays
+    ``(B, K, D, S)``; ``l``: the layer, an int or a traced scalar;
+    ``starts`` (B,) int32.  Returns the stacks, written."""
+    B, S = news[0].shape[0], news[0].shape[-1]
+    news = tuple(n.astype(c.dtype) for c, n in zip(stacks, news))
+    kernel = S == 1 and mesh is None and _on_tpu()
+    if tally is not None:
+        tally["kernel" if kernel else "rows"] += B * len(stacks)
+    if kernel:
+        return _write_kernel(tuple(stacks), news, l, starts)
+    return tuple(_write_by_rows(c, n, l, starts)
+                 for c, n in zip(stacks, news))
+
+
+def _write_by_rows(c, new, l, starts):
+    """One dynamic_update_slice a row, each at that row's own offset (a
+    start that would run past W is clamped by the operation)."""
+    zero = jnp.int32(0)
+    for b in range(new.shape[0]):
+        c = lax.dynamic_update_slice(
+            c, new[b][None, None],
+            (jnp.int32(l), jnp.int32(b), zero, zero, starts[b]))
+    return c
+
+
+def _kernel(l_ref, at_ref, *refs, n, lanes):
+    """refs: n stack blocks (K, D, lanes), n new rows (K, D) as the
+    program's products leave them, n output blocks.  The rows are turned
+    here (in float32, which the chip transposes at any small shape):
+    head k's values are then one column, broadcast over the block's
+    lanes and kept at one of them."""
+    from jax.experimental import pallas as pl
+
+    del l_ref
+    lane = at_ref[pl.program_id(0)] % lanes
+    for c_ref, new_ref, o_ref in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        K, D, _ = c_ref.shape
+        here = lax.broadcasted_iota(jnp.int32, (D, lanes), 1) == lane
+        new = new_ref[...].astype(jnp.float32).T.astype(o_ref.dtype)
+        for k in range(K):
+            o_ref[k] = jnp.where(here, new[:, k:k + 1], c_ref[k])
+
+
+def _write_kernel(stacks, news, l, starts, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = len(stacks)
+    W = stacks[0].shape[-1]
+    lanes = min(_LANE, W)
+    at = jnp.clip(starts.astype(jnp.int32), 0, W - 1)
+    layer = jnp.asarray(l, jnp.int32).reshape(1)
+    B = news[0].shape[0]
+
+    def block_of(c):
+        K, D = c.shape[2:4]
+        return pl.BlockSpec(
+            (None, None, K, D, lanes),
+            lambda b, l_ref, at_ref: (l_ref[0], b, 0, 0,
+                                      at_ref[b] // lanes))
+
+    def row_of(c):
+        K, D = c.shape[2:4]
+        return pl.BlockSpec((None, K, D), lambda b, l_ref, at_ref: (b, 0, 0))
+
+    blocks = [block_of(c) for c in stacks]
+    out = pl.pallas_call(
+        functools.partial(_kernel, n=n, lanes=lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=blocks + [row_of(c) for c in stacks],
+            out_specs=blocks),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in stacks],
+        # operand i (after the two prefetched scalars) is output i
+        input_output_aliases={2 + i: i for i in range(n)},
+        interpret=interpret,
+    )(layer, at, *stacks, *(x[..., 0] for x in news))
+    return tuple(out)

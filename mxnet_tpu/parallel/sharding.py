@@ -258,14 +258,14 @@ TRANSFORMER_TP_RULES = ShardingRules(rules=[
     (r"ffn2_stack_weight$", (None, None, TP)),
 ], default=())
 
-# serving KV cache: stage-major (L, B, H, W, Dh) along the scanned
+# serving KV cache: stage-major (L, B, H, Dh, W) along the scanned
 # trunk — heads shard on the tp axis exactly like the qkv stacks above,
 # so cached keys/values stay resident with the heads that produced them
 SERVING_CACHE_AXES = (None, None, TP, None, None)
 
 
 def serving_cache_sharding(mesh, tp_axis=TP):
-    """NamedSharding for a (L, B, H, W, Dh) serving KV cache on ``mesh``
+    """NamedSharding for a (L, B, H, Dh, W) serving KV cache on ``mesh``
     (None mesh → None, the single-device path)."""
     if mesh is None:
         return None

@@ -275,6 +275,8 @@ class ContinuousBatcher:
                     "decode_steps_fed_on_device"),
                 decode_readback_bytes_per_step=timings.get(
                     "decode_readback_bytes_per_step"),
+                decode_cache_write_kernel_share=timings.get(
+                    "decode_cache_write_kernel_share"),
                 **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step

@@ -221,7 +221,9 @@ class ServingEngine:
       ids ``(B, 1)`` and the next positions ``pos + last + 1``;
     - ``window`` and ``vocab``; optionally ``weights_from_state(state)``
       (a checkpoint convention), ``counters(cache)`` (a dict read back
-      once a group, merged into the timings) and ``signature`` (what a
+      once a group, merged into the timings), ``cache_writes`` (by
+      block length S, the row writes `ops/cache_write.py` counted by
+      path while the step was traced) and ``signature`` (what a
       reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
@@ -607,6 +609,13 @@ class ServingEngine:
             # the prefill's): the gaps are what a streaming caller sees
             "token_t_us": token_t_us,
         }
+        # of the decode program's cache row writes, the share that the
+        # in-place kernel made (ops/cache_write.py counted them when
+        # the program was traced)
+        writes = getattr(self._program, "cache_writes", {}).get(1)
+        if writes:
+            timings["decode_cache_write_kernel_share"] = \
+                writes["kernel"] / sum(writes.values())
         counters = getattr(self._program, "counters", None)
         if counters is not None:
             # what the family counted in its donated carry: one small

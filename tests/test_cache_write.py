@@ -1,0 +1,166 @@
+"""`ops/cache_write.py`: the in-place kernel (interpreted here) against
+the plain path, one ``dynamic_update_slice`` a row."""
+
+import collections
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import cache_write
+
+# (L, B, K, W, the stacks' D): GPT's pair and MiMo's two kinds, a ring
+# among them (W = the window, written at ``pos % W``), at test widths
+SHAPES = {
+    "gpt": (3, 4, 4, 384, (16, 16)),
+    "mimo_full": (2, 4, 2, 256, (24, 16)),
+    "mimo_ring": (3, 4, 4, 128, (24, 16)),
+    "narrow": (3, 4, 2, 16, (8, 8)),        # a window under one lane block
+}
+
+
+def _case(name, dtype, seed=0):
+    L, B, K, W, Ds = SHAPES[name]
+    rng = np.random.RandomState(seed)
+    stacks = tuple(jnp.asarray(rng.randn(L, B, K, D, W), dtype) for D in Ds)
+    news = tuple(jnp.asarray(rng.randn(B, K, D, 1), dtype) for D in Ds)
+    return stacks, news, W
+
+
+def _bits(a):
+    return np.asarray(a.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("layer", ["first", "middle", "last"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_kernel_writes_what_the_rows_path_writes(name, dtype, layer):
+    """Rows at different positions, a pad row at 0 among them: the lane
+    block's first and last lane, the next block's first, the window's
+    last slot, and one past the window (clipped to ``W - 1``, where
+    dynamic_update_slice's clamp puts it).  The kernel's stacks equal
+    the plain path's bit for bit, and so every slot that no row wrote is
+    what it was."""
+    stacks, news, W = _case(name, jnp.dtype(dtype))
+    L = stacks[0].shape[0]
+    l = {"first": 0, "middle": L // 2, "last": L - 1}[layer]
+    for at in ([0, 127, 128, W - 1], [W + 5, 1, 0, W]):
+        pos = jnp.asarray(at, jnp.int32)
+        starts = pos % W if name == "mimo_ring" else pos
+        got = jax.jit(lambda s, n, p: cache_write._write_kernel(
+            s, n, l, p, interpret=True))(stacks, news, starts)
+        want = tuple(cache_write._write_by_rows(c, n, l, starts)
+                     for c, n in zip(stacks, news))
+        for g, w, c, n in zip(got, want, stacks, news):
+            assert g.dtype == c.dtype and g.shape == c.shape
+            np.testing.assert_array_equal(_bits(g), _bits(w))
+            # and said without the plain path: layer l, row b, one slot
+            slot = np.clip(np.asarray(starts), 0, W - 1)
+            touched = np.zeros(c.shape, bool)
+            for b, s in enumerate(slot):
+                touched[l, b, :, :, s] = True
+                np.testing.assert_array_equal(
+                    _bits(g)[l, b, :, :, s], _bits(n)[b, :, :, 0])
+            np.testing.assert_array_equal(_bits(g)[~touched],
+                                          _bits(c)[~touched])
+
+
+def test_a_traced_layer_index_writes_that_layer():
+    """GPT's layer loop hands the kernel its scan index."""
+    stacks, news, W = _case("gpt", jnp.bfloat16, seed=1)
+    pos = jnp.asarray([5, 130, 0, 383], jnp.int32)
+
+    def walk(write):
+        def body(carry, l):
+            return write(carry, l), None
+        return jax.lax.scan(body, stacks,
+                            jnp.arange(stacks[0].shape[0], dtype=jnp.int32))[0]
+
+    got = walk(lambda s, l: cache_write._write_kernel(s, news, l, pos,
+                                                      interpret=True))
+    want = walk(lambda s, l: tuple(
+        cache_write._write_by_rows(c, n, l, pos) for c, n in zip(s, news)))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("S,mesh,tpu,path", [
+    (1, None, True, "kernel"), (8, None, True, "rows"),
+    (1, "a mesh", True, "rows"), (1, None, False, "rows")])
+def test_write_rows_picks_its_path_on_what_it_sees(monkeypatch, S, mesh,
+                                                   tpu, path):
+    """The kernel where the block is one position, the platform a TPU
+    and no mesh is given; one write a row everywhere else.  The tally
+    is told which, once a row and a stack."""
+    stacks, news, W = _case("narrow", jnp.float32)
+    news = tuple(jnp.repeat(n, S, axis=-1) for n in news)
+    pos = jnp.asarray([0, 3, 7, 2], jnp.int32)
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: tpu)
+    took = []
+    monkeypatch.setattr(
+        cache_write, "_write_kernel",
+        lambda s, n, l, p: took.append("kernel") or s)
+    tally = collections.Counter()
+    out = cache_write.write_rows(stacks, news, 1, pos, mesh=mesh,
+                                 tally=tally)
+    assert len(out) == len(stacks)
+    assert (took == ["kernel"]) == (path == "kernel")
+    assert dict(tally) == {path: 4 * len(stacks)}
+    if path == "rows":
+        for g, c, n in zip(out, stacks, news):
+            for b, p in enumerate(np.asarray(pos)):
+                np.testing.assert_array_equal(
+                    _bits(g)[1, b, :, :, p:p + S], _bits(n)[b])
+            assert (_bits(g)[0] == _bits(c)[0]).all()
+
+
+# -- compiled for the chip, without the chip -----------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip: Mosaic and XLA:TPU compile for it here,
+    nothing runs (only this file's worker loads the TPU's library)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name,L,B,K,Ds,W", [
+    ("gpt2_medium", 24, 16, 16, (64, 64), 1024),
+    ("mimo_full", 2, 64, 4, (192, 128), 2048),
+    ("mimo_ring", 5, 64, 8, (192, 128), 128)])
+def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, K, Ds, W):
+    """At the cells' real widths Mosaic takes the kernel (interpret mode
+    cannot say), the stacks are aliased to their outputs, and the
+    compiled program copies no layer of them: it holds no temporary of
+    a stack's size."""
+    from mxnet_tpu import serving
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(stacks, news, pos):
+        def body(c, l):
+            return cache_write._write_kernel(c, news, l, pos), None
+        return jax.lax.scan(body, stacks, jnp.arange(L, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        tuple(sds((L, B, K, D, W)) for D in Ds),
+        tuple(sds((B, K, D, 1)) for D in Ds),
+        sds((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    for i in range(len(Ds)):
+        assert f"{{{i}}}: ({i}, {{}}" in alias, alias
+    layer = B * K * min(Ds) * W * 2
+    assert serving.whole_layer_ops(text, layer) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer

@@ -807,11 +807,11 @@ def _np_step(w, act, ck, cv, pos, toks):
     """The serving step in NumPy, one slot at a time: row b's block is
     written at ``min(pos[b], W - S)`` (dynamic_update_slice's clamp),
     and slot w is visible to (b, s) when ``w <= pos[b] + s``.  Mutates
-    ck/cv (L, B, H, W, Dh) and returns the logits."""
+    ck/cv (L, B, H, Dh, W) and returns the logits."""
     (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
      g1s, b1s, g2s, b2s, lnf_g, lnf_b) = [np.asarray(a, np.float64)
                                           for a in w]
-    L, B, H, W, Dh = ck.shape
+    L, B, H, Dh, W = ck.shape
     S = toks.shape[1]
 
     def ln(x, g, b):
@@ -833,17 +833,17 @@ def _np_step(w, act, ck, cv, pos, toks):
             h = ln(x, g1s[l], b1s[l])
             qkv = np.einsum("sc,thdc->sthd", h, qkvw[l]) + qkvb[l]
             for s in range(S):
-                ck[l, b, :, start + s, :] = qkv[s, 1]
-                cv[l, b, :, start + s, :] = qkv[s, 2]
+                ck[l, b, :, :, start + s] = qkv[s, 1]
+                cv[l, b, :, :, start + s] = qkv[s, 2]
             attn = np.zeros((S, H, Dh))
             for s in range(S):
                 seen = np.arange(W) <= pos[b] + s
-                sc = np.einsum("hd,hwd->hw", qkv[s, 0],
+                sc = np.einsum("hd,hdw->hw", qkv[s, 0],
                                ck[l, b].astype(np.float64)) * Dh ** -0.5
                 sc = np.where(seen[None], sc, -1e30)
                 p = np.exp(sc - sc.max(-1, keepdims=True))
                 p /= p.sum(-1, keepdims=True)
-                attn[s] = np.einsum("hw,hwd->hd", p,
+                attn[s] = np.einsum("hw,hdw->hd", p,
                                     cv[l, b].astype(np.float64))
             x = x + np.einsum("shd,chd->sc", attn, pwh[l]) + pb[l]
             h = ln(x, g2s[l], b2s[l]) @ f1w[l].T + f1b[l]
@@ -944,9 +944,9 @@ def test_cache_holds_exactly_the_rows_written(case):
         written = np.zeros(16, bool)
         written[:8] = True                         # the prefill block
         written[[min(s[b], 15) for s in steps]] = True
-        assert not ck[:, b, :, ~written].any()
-        assert not cv[:, b, :, ~written].any()
-        assert (ck[:, b, :, written] != 0).all()
+        assert not ck[:, b, :, :, ~written].any()
+        assert not cv[:, b, :, :, ~written].any()
+        assert (ck[:, b, :, :, written] != 0).all()
 
 
 def test_tp_cache_holds_exactly_the_rows_written(mesh8):
@@ -959,8 +959,8 @@ def test_tp_cache_holds_exactly_the_rows_written(mesh8):
     for g, n, name in zip(got, want, ("ck", "cv", "logits")):
         np.testing.assert_allclose(g, n, rtol=2e-4, atol=1e-5,
                                    err_msg=name)
-    assert not got[0][:, 0, :, 8:].any()       # row 0 wrote slots 0..7
-    assert not got[1][:, 1, :, 10:].any()      # row 1 wrote up to 9
+    assert not got[0][:, 0, :, :, 8:].any()    # row 0 wrote slots 0..7
+    assert not got[1][:, 1, :, :, 10:].any()   # row 1 wrote up to 9
 
 
 # a decode program's text cut down to the shapes of what the TPU
