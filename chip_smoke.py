@@ -10,10 +10,13 @@ by ``Trainer.train_step`` (the captured whole-step program), then served
 by ``ServingEngine`` + ``ContinuousBatcher``; then a second family
 through the same engine, ``MiMoV2Model`` at its published widths (the
 cut of ``benchmark/configs/mimo-v2.5-ep16.json``: window and full
-layers, two kinds of cache, a chip's share of the routed experts); with
-four chips, the GPT step under ``shard_model`` fsdp and tp.  Phases, in
-order: device, sync, kernel, train, serve, serve_mimo, sharded.  The first failed check raises and the
-process exits non-zero; the last line of stdout is the JSON result only
+layers, two kinds of cache, a chip's share of the routed experts); then
+a third, ``KeyeVL2Model`` at small aligned sizes (a learned indexer with
+a cache stack of its own, attention over its selection, softmax-routed
+experts); with four chips, the GPT step under ``shard_model`` fsdp and
+tp.  Phases, in order: device, sync, kernel, train, serve, serve_mimo,
+serve_keye, sharded.  The first failed check raises and the process
+exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
 ``__main__`` always demands platform ``tpu``.  The phase functions take a
@@ -76,8 +79,8 @@ FULL = Size(
 
 
 @dataclasses.dataclass(frozen=True)
-class MimoSize:
-    kwargs: dict            # MiMoV2Model's
+class FamilySize:
+    kwargs: dict            # the family's constructor's
     batch: int              # the one batch bucket
     prefill_floor: int
     prompt_lens: tuple      # one group: under, at and past the window
@@ -91,9 +94,23 @@ def mimo_full():
     with open(os.path.join(here, "benchmark", "configs",
                            "mimo-v2.5-ep16.json")) as f:
         kwargs = json.load(f)["program"]["kwargs"]
-    return MimoSize(kwargs=kwargs, batch=8, prefill_floor=512,
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
                     prompt_lens=(40, 128, 300, 500, 77, 129, 16, 260),
                     new_tokens=2 * kwargs["window"] + 5)
+
+
+def keye_small():
+    """The third family at the smallest sizes Mosaic's tiles take: heads
+    of 128, an indexer of 2 heads of 64, top-128 of up to 1,024
+    positions, 2 layers, 4 of 8 experts held."""
+    kwargs = dict(vocab_size=512, units=256, num_layers=2, num_heads=4,
+                  kv_heads=2, head_dim=128, index_heads=2, index_dim=64,
+                  topk=128, expert_hidden=128, router_experts=8,
+                  experts_per_token=2, experts_held=[2, 4],
+                  max_length=1024, dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=1024,
+                    prompt_lens=(40, 128, 300, 700, 77, 513, 16, 260),
+                    new_tokens=6)
 
 
 # the two timings of the sync phase may differ by this factor
@@ -567,7 +584,7 @@ def _normal_maker(shape, dtype, std, sharding):
         out_shardings=sharding)
 
 
-def _seed_mimo(net, seed=0):
+def _seed_normal(net, seed=0):
     """Seeded values made on the parameters' own device, one leaf at a
     time (a host draw of 3.4B values would take minutes): matrices
     normal(0.02), sink logits normal(1), correction biases normal(0.1),
@@ -595,7 +612,7 @@ def phase_serve_mimo(size, platform):
     t0 = time.perf_counter()
     net = mimo_v2.MiMoV2Model(**size.kwargs)
     net.initialize(init=mx.init.One(), ctx=_ctx_for(platform))
-    _seed_mimo(net)
+    _seed_normal(net)
     n_params = sum(int(np.prod(p.shape))
                    for p in net.collect_params().values())
     dtype = jnp.dtype(size.kwargs.get("dtype", "float32"))
@@ -667,6 +684,133 @@ def phase_serve_mimo(size, platform):
     return {"params": n_params, "programs": engine.program_count(),
             "retraces": serving.trace_count() - pinned,
             "peak_bytes": peak}
+
+
+# -- serve, a third family -----------------------------------------------------
+
+def require_selection_equals_the_reference(tag, z, S):
+    """The program's two selections on this platform against the plain
+    reference's sort: the prefill kernel for every query of a block of
+    ``S`` positions, the decode path for each row's last query.  The
+    operands hold bfloat16 values, so both sides make the same products
+    and differ in the order of their float32 sums alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import indexed_attention
+
+    from benchmark.references import keye_vl2 as ref
+
+    B, Hi, di, k = 2, z.index_heads, z.index_dim, z.topk
+    rng = np.random.RandomState(3)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+
+    qi, ki, w = draw(B, Hi, S, di), draw(B, di, S), draw(B, S, Hi)
+    last = jnp.asarray([S - 1, S // 3], jnp.int32)
+    got = np.asarray(jax.jit(
+        lambda *a: indexed_attention.select_prefill(*a, k))(
+            qi, w.astype(jnp.float32), ki, last)) != 0
+    f32 = jnp.float32
+    want = np.asarray(ref.select(
+        qi.astype(f32).transpose(0, 2, 1, 3), w.astype(f32),
+        ki.astype(f32).swapaxes(1, 2), 0, {"topk": k}, ref.product))
+    rows = np.arange(B)
+    index = indexed_attention.index_scores_decode(
+        qi[rows, :, last], w.astype(f32)[rows, last], ki)
+    live = jnp.arange(S)[None, :] <= last[:, None]
+    got_decode = np.asarray(jax.jit(
+        lambda i, l: indexed_attention.select_topk(i, l, k))(index, live))
+    for b, n in enumerate(np.asarray(last)):
+        require(np.array_equal(got[b, :n + 1], want[b, :n + 1]),
+                f"{tag}: row {b}: the prefill kernel's selection differs "
+                f"from the reference's in "
+                f"{int((got[b, :n + 1] != want[b, :n + 1]).sum())} places")
+        require(np.array_equal(got_decode[b], want[b, n]),
+                f"{tag}: row {b}: the decode selection differs from the "
+                f"reference's")
+    say(f"[{tag}] selection of top-{k} over {S} positions: the prefill "
+        f"kernel's {int(got[0].sum())} + {int(got[1, :S // 3 + 1].sum())} "
+        f"keys and the decode path's equal the reference's sort")
+
+
+def phase_serve_keye(size, platform):
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import serving
+    from mxnet_tpu.gluon.model_zoo import keye_vl2
+
+    net = keye_vl2.KeyeVL2Model(**size.kwargs)
+    net.initialize(init=mx.init.One(), ctx=_ctx_for(platform))
+    _seed_normal(net)
+    dtype = jnp.dtype(size.kwargs.get("dtype", "float32"))
+    engine = serving.ServingEngine(net, batch_buckets=(size.batch,),
+                                   prefill_floor=size.prefill_floor,
+                                   dtype=dtype)
+    own = {id(p.data()._data) for p in net.collect_params().values()}
+    require(all(id(a) in own for a in engine._weights),
+            "serve_keye: the engine holds a second copy of a parameter")
+    require(all(on_platform(a, platform)
+                for a in engine._weights + engine.init_cache(1)),
+            f"serve_keye: weights or cache not on a {platform} device")
+    vocab, topk = net._vocab, size.kwargs["topk"]
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, vocab, n).tolist() for n in size.prompt_lens]
+    together, timing = engine.serve_group(prompts, size.new_tokens)
+    pinned = serving.trace_count()
+    B, S = timing["bucket"]
+    # the decode program moves no layer-sized piece of any of the three
+    # stacks: keys, values, the indexer's keys
+    text = engine._programs[(B, 1)].as_text()
+    big = engine.init_cache(B)
+    require(len(big) == 5 and big[2].shape[2:4]
+            == (1, size.kwargs["index_dim"]),
+            f"serve_keye: cache {[tuple(c.shape) for c in big]}")
+    for c in big[:3]:
+        moved = serving.whole_layer_ops(text, c.nbytes // c.shape[0])
+        require(not moved, f"serve_keye: the decode program moves whole "
+                           f"layers of a {tuple(c.shape)} stack: {moved}")
+    del big
+    for j, toks in enumerate(together):
+        require(len(toks) == size.new_tokens
+                and all(0 <= int(t) < vocab for t in toks),
+                f"serve_keye: request {j} resolved to {toks}")
+    L = size.kwargs["num_layers"]
+    lens = size.prompt_lens + (1,) * (B - len(prompts))
+    live = L * sum(n * (n + 1) // 2 for n in lens)
+    least = L * sum(min(t + 1, topk) for n in lens for t in range(n))
+    require(timing["attn_keys_live_prefill"] == live
+            and least <= timing["attn_keys_selected_prefill"] < live,
+            f"serve_keye: prefill read {timing['attn_keys_selected_prefill']}"
+            f" of {timing['attn_keys_live_prefill']} keys ({least}, {live})")
+    require(0 < timing["attn_keys_selected_decode"]
+            < timing["attn_keys_live_decode"],
+            f"serve_keye: decode counters {timing}")
+    require(timing["moe_rows_computed_decode"]
+            >= timing["moe_pairs_decode"] > 0,
+            f"serve_keye: decode counters {timing}")
+    for j in (0, len(prompts) - 1):
+        alone, tm = engine.serve_group([prompts[j]], size.new_tokens)
+        if tm["bucket"] == timing["bucket"]:
+            require(np.array_equal(alone[0], together[j]),
+                    f"serve_keye: prompt {j} coalesced {together[j]} != "
+                    f"alone {alone[0]}")
+    again, timing = engine.serve_group(prompts, size.new_tokens)
+    require(all(np.array_equal(a, b) for a, b in zip(again, together)),
+            "serve_keye: a repeated group differs")
+    require_fed_on_device("serve_keye", engine, prompts, size.new_tokens,
+                          again, timing)
+    require_kernel_cache_writes("serve_keye", engine, timing, platform)
+    require_selection_equals_the_reference("serve_keye", net._sizes, S)
+    say(f"[serve_keye] group of {len(prompts)} (prompts "
+        f"{size.prompt_lens}) x {size.new_tokens} tokens through bucket "
+        f"{timing['bucket']}: "
+        f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
+        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn'))} }")
+    return {"programs": engine.program_count(),
+            "retraces": serving.trace_count() - pinned}
 
 
 # -- sharded -------------------------------------------------------------------
@@ -754,6 +898,8 @@ def main():
     run("serve", phase_serve, FULL, platform, train.pop("net"))
     gc.collect()
     run("serve_mimo", phase_serve_mimo, mimo_full(), platform)
+    gc.collect()
+    run("serve_keye", phase_serve_keye, keye_small(), platform)
     gc.collect()
     import jax
 

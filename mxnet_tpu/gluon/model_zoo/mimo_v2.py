@@ -46,47 +46,14 @@ time through attention and the dense feed-forward, so that a bucket of
 
 from __future__ import annotations
 
-
 from ...base import MXNetError
 from ...ops import cache_write
 from ..block import HybridBlock
-
-_MASKED = -1e30
+from . import _decoder_ops as _ops
+from ._decoder_ops import _MASKED
 
 
 # -- pieces shared by the forward pass and the cached step ---------------------
-
-def _mm(spec, a, w):
-    """The one mixed-precision product: the activation in the weight's
-    type, the result float32."""
-    import jax.numpy as jnp
-
-    return jnp.einsum(spec, a.astype(w.dtype), w,
-                      preferred_element_type=jnp.float32)
-
-
-def _rms_norm(x, g, eps):
-    import jax.numpy as jnp
-    from jax import lax
-
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                         + eps) * g.astype(jnp.float32)
-
-
-def _rope(x, pos, theta, rot):
-    """x (B, .., S, D) float32 rotated on its first ``rot`` dimensions at
-    positions ``pos`` (B, S)."""
-    import jax.numpy as jnp
-
-    half = rot // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
-    ang = pos.astype(jnp.float32)[..., None] * freq          # (B, S, half)
-    shape = (pos.shape[0],) + (1,) * (x.ndim - 3) + (pos.shape[1], half)
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
-    a, b = x[..., :half], x[..., half:rot]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
-                            x[..., rot:]], axis=-1)
-
 
 def _attend_blocks(q, k, v, sink, window, blk):
     """Causal attention of a block of S positions over itself, in key
@@ -147,27 +114,6 @@ def _attend_blocks(q, k, v, sink, window, blk):
     return (acc / l[..., None]).reshape(B, K, G, S, Dv)
 
 
-def _attend_cache(q, ck, cv, seen, sink):
-    """One query position a row over a cache layer: q (B, K, G, D)
-    scaled; ck (B, K, D, W); cv (B, K, Dv, W); ``seen`` (B, W) bool;
-    ``sink`` (K, G) or None.  Returns (B, K, G, Dv) float32."""
-    import jax.numpy as jnp
-
-    s = jnp.einsum("bkgd,bkdw->bkgw", q, ck,
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(seen[:, None, None, :], s, _MASKED)
-    m = jnp.max(s, axis=-1)
-    if sink is not None:
-        m = jnp.maximum(m, sink[None])
-    p = jnp.exp(s - m[..., None])
-    denom = jnp.sum(p, axis=-1)
-    if sink is not None:
-        denom = denom + jnp.exp(sink[None] - m)
-    a = jnp.einsum("bkgw,bkdw->bkgd", p.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
-    return a / denom[..., None]
-
-
 class _Sizes:
     """The family's sizes, as the constructor got them."""
 
@@ -225,16 +171,16 @@ def _qkv(z, kind, p, x, pos):
     B, S, _ = x.shape
     dt = p["q_weight"].dtype
     with jax.named_scope("serve.attn_qkv"):
-        u = _rms_norm(x, p["ln1_gamma"], z.eps).astype(dt)
+        u = _ops.rms_norm(x, p["ln1_gamma"], z.eps).astype(dt)
 
         def heads(w, n, d):
-            return _mm("bsc,gc->bsg", u, w).reshape(B, S, n, d
+            return _ops.mm("bsc,gc->bsg", u, w).reshape(B, S, n, d
                                                     ).transpose(0, 2, 1, 3)
 
         theta = z.rope_theta[kind]
-        q = _rope(heads(p["q_weight"], K * G, D), pos, theta,
+        q = _ops.rope(heads(p["q_weight"], K * G, D), pos, theta,
                   z.rotary_dim) * (D ** -0.5)
-        k = _rope(heads(p["k_weight"], K, D), pos, theta, z.rotary_dim)
+        k = _ops.rope(heads(p["k_weight"], K, D), pos, theta, z.rotary_dim)
         v = heads(p["v_weight"], K, Dv) * z.value_scale
         return (q.astype(dt).reshape(B, K, G, S, D), k.astype(dt),
                 v.astype(dt))
@@ -249,40 +195,14 @@ def _sink(z, kind, p):
     return p["sink_bias"].astype(jnp.float32).reshape(K, z.num_heads // K)
 
 
-def _attn_out(z, p, x, a):
-    """x + a Wo for a (B, K, G, S, Dv): heads side by side, then the
-    plain product."""
-    B, K, G, S, Dv = a.shape
-    a = a.transpose(0, 3, 1, 2, 4).reshape(B, S, K * G * Dv)
-    return x + _mm("bsg,cg->bsc", a, p["o_weight"])
-
-
 def _dense(z, p, x):
     import jax
 
     with jax.named_scope("serve.mlp"):
-        u = _rms_norm(x, p["ln2_gamma"], z.eps)
-        h = jax.nn.silu(_mm("bsc,fc->bsf", u, p["gate_weight"])) \
-            * _mm("bsc,fc->bsf", u, p["up_weight"])
-        return x + _mm("bsf,cf->bsc", h, p["down_weight"])
-
-
-def _route(z, p, x):
-    """Layer's second norm and its router on x (B, S, C): (u in the
-    experts' type, chosen (B, S, k), weights (B, S, k))."""
-    import jax
-
-    from ...ops import moe
-
-    B, S, C = x.shape
-    with jax.named_scope("serve.moe.route"):
-        u = _rms_norm(x, p["ln2_gamma"], z.eps)
-        chosen, weights = moe.sigmoid_topk_route(
-            u.reshape(B * S, C), p["router_weight"], p["router_bias"],
-            z.experts_per_token)
-        k = z.experts_per_token
-        return (u.astype(p["experts_down_weight"].dtype),
-                chosen.reshape(B, S, k), weights.reshape(B, S, k))
+        u = _ops.rms_norm(x, p["ln2_gamma"], z.eps)
+        h = jax.nn.silu(_ops.mm("bsc,fc->bsf", u, p["gate_weight"])) \
+            * _ops.mm("bsc,fc->bsf", u, p["up_weight"])
+        return x + _ops.mm("bsf,cf->bsc", h, p["down_weight"])
 
 
 def _experts(z, p, x, route, valid):
@@ -307,51 +227,14 @@ def _experts(z, p, x, route, valid):
 
 def _feed_forward_front(z, i, p, x):
     """What of layer i's feed-forward a token needs no other token for:
-    the whole dense SwiGLU, or the norm and the router.  Returns
-    (x, route or ())."""
+    the whole dense SwiGLU, or the norm and the router (sigmoid scores
+    with the correction bias).  Returns (x, route or ())."""
+    from ...ops import moe
+
     if z.moe_layers[i]:
-        return x, _route(z, p, x)
+        return x, _ops.route(z, p, x, lambda u: moe.sigmoid_topk_route(
+            u, p["router_weight"], p["router_bias"], z.experts_per_token))
     return _dense(z, p, x), ()
-
-
-def _by_rows(fn, rows, x, pos):
-    """``fn(x, pos) -> (x, extras)`` over the rows of a block, ``rows``
-    at a time and one chunk after another, so that only one chunk's
-    temporaries are alive: the residual stream is updated where it
-    lies, and the extras (keys, values, the router's choice) fill
-    buffers of their own.  Whole when one chunk holds every row."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    B = x.shape[0]
-    if rows >= B:
-        return fn(x, pos)
-
-    def chunk(a, c):
-        return lax.dynamic_slice_in_dim(a, c * rows, rows, axis=0)
-
-    _, shapes = jax.eval_shape(fn, chunk(x, 0), chunk(pos, 0))
-    extras = jax.tree_util.tree_map(
-        lambda s: jnp.zeros((B,) + s.shape[1:], s.dtype), shapes)
-
-    def one(c, carry):
-        x, extras = carry
-        xc, ex = fn(chunk(x, c), chunk(pos, c))
-        put = lambda whole, part: lax.dynamic_update_slice_in_dim(
-            whole, part, c * rows, axis=0)
-        return put(x, xc), jax.tree_util.tree_map(put, extras, ex)
-
-    return lax.fori_loop(0, B // rows, one, (x, extras))
-
-
-def _chunk_rows(z, B, S):
-    """Rows a chunk: about ``prefill_chunk_tokens`` tokens, a divisor
-    of B."""
-    rows = max(1, min(B, z.prefill_chunk_tokens // S))
-    while B % rows:
-        rows -= 1
-    return rows
 
 
 def _block_layer(z, i, p, x, pos):
@@ -366,7 +249,7 @@ def _block_layer(z, i, p, x, pos):
     with jax.named_scope(f"serve.attn_{kind}"):
         a = _attend_blocks(q, k, v, _sink(z, kind, p),
                            z.window if kind == "window" else None, blk)
-        x = _attn_out(z, p, x, a)
+        x = _ops.attn_out(z, p, x, a)
     x, route = _feed_forward_front(z, i, p, x)
     return x, (k, v, route)
 
@@ -385,16 +268,16 @@ def _forward(z, names, ids, *weights):
     pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     valid = pos < T
     x = jnp.take(w["embed_weight"], ids, axis=0).astype(jnp.float32)
-    rows = _chunk_rows(z, B, S)
+    rows = _ops.chunk_rows(z, B, S)
     for i in range(len(z.layer_types)):
         p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
-        x, (_, _, route) = _by_rows(
+        x, (_, _, route) = _ops.by_rows(
             lambda x, pos, i=i, p=p: _block_layer(z, i, p, x, pos),
             rows, x, pos)
         if route:
             x, _ = _experts(z, p, x, route, valid)
-    h = _rms_norm(x[:, :T], w["lnf_gamma"], z.eps)
-    return _mm("btc,vc->btv", h, w["head_weight"])
+    h = _ops.rms_norm(x[:, :T], w["lnf_gamma"], z.eps)
+    return _ops.mm("btc,vc->btv", h, w["head_weight"])
 
 
 class MiMoV2Model(HybridBlock):
@@ -495,16 +378,7 @@ class MiMoV2Program:
                           tuple(z.rope_theta.items()), z.value_scale)
 
     def weights(self):
-        """The parameters' own buffers, in the order of their names: no
-        second copy, unless ``dtype`` asks for another type than a
-        parameter has."""
-        out = []
-        for n in self._model._names:
-            a = getattr(self._model, n).data()._data
-            if self._dtype is not None and a.dtype != self._dtype:
-                a = a.astype(self._dtype)
-            out.append(a)
-        return tuple(out)
+        return _ops.own_weights(self._model, self._dtype)
 
     def _counter_shape(self):
         z = self._z
@@ -540,27 +414,10 @@ class MiMoV2Program:
     def counters(self, cache):
         """The expert layers' counters of one served group, read back
         once (docs/observability.md has the table)."""
-        import numpy as np
-
         z = self._z
         if not z.moe_at:
             return {}
-        c = np.asarray(cache[4]).astype(np.int64)
-        n = z.experts_held[1]
-        load = c[:, :, :n]
-        total = load.sum(axis=1)                        # (Lm, n)
-        decode_calls = int(c[0, 1, n + 2])
-        return {
-            "moe_pairs_prefill": int(load[:, 0].sum()),
-            "moe_pairs_decode": int(load[:, 1].sum()),
-            "moe_rows_computed_prefill": int(c[:, 0, n].sum()),
-            "moe_rows_computed_decode": int(c[:, 1, n].sum()),
-            "moe_experts_hit_per_step":
-                float(c[:, 1, n + 1].sum()) / (decode_calls * len(c))
-                if decode_calls else 0.0,
-            "moe_load_max_over_mean": float(np.mean(
-                total.max(axis=1) / np.maximum(total.mean(axis=1), 1e-9))),
-        }
+        return _ops.moe_counters(cache[4], z.experts_held[1])
 
     # -- the traced step -------------------------------------------------------
 
@@ -611,14 +468,14 @@ class MiMoV2Program:
                 k, jnp.clip(p_s, 0, S - 1)[:, None, :, None], axis=2)
             return jnp.where((p_s >= 0)[:, None, :, None], got, 0)
 
-        rows = _chunk_rows(z, B, S)
+        rows = _ops.chunk_rows(z, B, S)
         for i, kind in enumerate(z.layer_types):
             p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
             l = z.of_kind[kind].index(i)
             if decode:
                 q, k, v = _qkv(z, kind, p, x, at)
             else:
-                x, (k, v, route) = _by_rows(
+                x, (k, v, route) = _ops.by_rows(
                     lambda x, at, i=i, p=p: _block_layer(z, i, p, x, at),
                     rows, x, at)
             with jax.named_scope("serve.cache_write"):
@@ -643,22 +500,19 @@ class MiMoV2Program:
                         slot = jnp.arange(R)[None, :]
                         seen = pos[:, None] - (pos[:, None] - slot) % R >= 0
                         ck, cv = wk[l], wv[l]
-                    a = _attend_cache(q[:, :, :, 0], ck, cv, seen,
+                    a = _ops.attend_cache(q[:, :, :, 0], ck, cv, seen,
                                       _sink(z, kind, p))
-                    x = _attn_out(z, p, x, a[:, :, :, None])
+                    x = _ops.attn_out(z, p, x, a[:, :, :, None])
                 x, route = _feed_forward_front(z, i, p, x)
             if route:
                 # padding is routed nowhere: only real tokens cost
                 x, stats = _experts(z, p, x, route,
                                     None if decode else valid)
-                n = z.experts_held[1]
-                row = jnp.concatenate([
-                    stats, jnp.sum(stats[:n] > 0, dtype=jnp.int32)[None],
-                    jnp.ones((1,), jnp.int32)])
-                counts = counts.at[z.moe_at.index(i), int(decode)].add(row)
+                counts = counts.at[z.moe_at.index(i), int(decode)].add(
+                    _ops.moe_count_row(stats, z.experts_held[1]))
         with jax.named_scope("serve.head"):
             h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-            logits = _mm("bc,vc->bv", _rms_norm(h, w["lnf_gamma"], z.eps),
+            logits = _ops.mm("bc,vc->bv", _ops.rms_norm(h, w["lnf_gamma"], z.eps),
                          w["head_weight"])
         return (fk, fv, wk, wv, counts), logits
 

@@ -1,17 +1,23 @@
-"""Writing a step's new keys and values into the serving caches.
+"""Writing a step's new rows into the serving caches.
 
-A decoder program carries its KV stacks through the layer loop, donated
-and position-minor: ``(L, B, K, D, W)``, W positions on the minor axis
-(what a v5e stores for a head under 128 wide whatever the logical order
-says; with the logical order the same, a kernel sees the buffer as it
-lies).  ``write_rows`` puts row b's new ``(K, D, S)`` block of layer
-``l`` at positions ``starts[b] .. starts[b] + S`` and touches nothing
-else.  Two paths, chosen on what the call can see:
+A decoder program carries its cache stacks through the layer loop,
+donated and position-minor: ``(L, B, K, D, W)``, W positions on the
+minor axis (what a v5e stores for a head under 128 wide whatever the
+logical order says; with the logical order the same, a kernel sees the
+buffer as it lies).  A call takes ``n`` stacks that share ``L``, ``B``
+and ``W`` and may each have their own head count ``K`` and width ``D``:
+GPT's keys and values ``(16, 64)``; MiMo's ``(4, 192)`` beside
+``(4, 128)``; Keye-VL-2.0's keys and values ``(4, 128)`` beside its
+indexer's keys ``(1, 64)``.  ``write_rows`` puts row b's new
+``(K, D, S)`` block of layer ``l`` of each stack at positions
+``starts[b] .. starts[b] + S`` and touches nothing else.  Two paths,
+chosen on what the call can see:
 
 - **kernel** (``S == 1``, a TPU, no mesh): one Pallas call for all the
   stacks given, each aliased to its output.  Grid over the rows: step b
-  brings in the one lane block of row b, layer l that holds position
-  ``starts[b]`` (all K heads x D x 128 positions), replaces lane
+  brings in, of each stack, the one lane block of row b, layer l that
+  holds position ``starts[b]`` (that stack's K heads x D x 128
+  positions), replaces lane
   ``starts[b] % 128`` with the new values and the pipeline writes the
   block back.  Different rows' blocks are disjoint, so the pipeline
   overlaps them.  A position outside the window is clipped into it,
@@ -21,8 +27,8 @@ else.  Two paths, chosen on what the call can see:
   kernel is tested against (tests/test_cache_write.py, interpreted).
 
 ``tally`` (a ``collections.Counter`` or None) is told at trace time how
-many row writes went by which path: ``tally["kernel"]``,
-``tally["rows"]``.
+many row writes went by which path, one a row and a stack (``B * n`` a
+call): ``tally["kernel"]``, ``tally["rows"]``.
 """
 
 from __future__ import annotations
@@ -58,9 +64,9 @@ def _on_tpu():
 
 
 def write_rows(stacks, news, l, starts, mesh=None, tally=None):
-    """``stacks``: arrays ``(L, B, K, D, W)``; ``news``: as many arrays
-    ``(B, K, D, S)``; ``l``: the layer, an int or a traced scalar;
-    ``starts`` (B,) int32.  Returns the stacks, written."""
+    """``stacks``: ``n`` arrays ``(L, B, K_i, D_i, W)``; ``news``: as
+    many arrays ``(B, K_i, D_i, S)``; ``l``: the layer, an int or a
+    traced scalar; ``starts`` (B,) int32.  Returns the stacks, written."""
     B, S = news[0].shape[0], news[0].shape[-1]
     news = tuple(n.astype(c.dtype) for c, n in zip(stacks, news))
     kernel = S == 1 and mesh is None and _on_tpu()

@@ -157,6 +157,19 @@ def sigmoid_topk_route(x, router_weight, router_bias, k):
     return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
+def softmax_topk_route(x, router_weight, k):
+    """x (T, M) → (chosen (T, k) int32, weights (T, k) float32).
+
+    Scores are ``softmax(x Wrᵀ)`` over all the router's experts, in
+    float32; the chosen experts are the ``k`` largest and their weights
+    the chosen scores, normalised to sum to one (``norm_topk_prob``)."""
+    logits = jnp.einsum("tm,em->te", x.astype(jnp.float32),
+                        router_weight.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    w, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
                      pass_rows=None, add_to=None):
     """Σ over the held experts e of weight · W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x).

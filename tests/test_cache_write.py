@@ -11,21 +11,27 @@ import jax.numpy as jnp
 
 from mxnet_tpu.ops import cache_write
 
-# (L, B, K, W, the stacks' D): GPT's pair and MiMo's two kinds, a ring
-# among them (W = the window, written at ``pos % W``), at test widths
+# (L, B, the stacks' K, W, the stacks' D): GPT's pair and MiMo's two
+# kinds, a ring among them (W = the window, written at ``pos % W``), and
+# Keye-VL-2.0's three stacks of differing head count and width (keys and
+# values beside the indexer's one narrow head), at test widths
 SHAPES = {
-    "gpt": (3, 4, 4, 384, (16, 16)),
-    "mimo_full": (2, 4, 2, 256, (24, 16)),
-    "mimo_ring": (3, 4, 4, 128, (24, 16)),
-    "narrow": (3, 4, 2, 16, (8, 8)),        # a window under one lane block
+    "gpt": (3, 4, (4, 4), 384, (16, 16)),
+    "mimo_full": (2, 4, (2, 2), 256, (24, 16)),
+    "mimo_ring": (3, 4, (4, 4), 128, (24, 16)),
+    "narrow": (3, 4, (2, 2), 16, (8, 8)),   # a window under one lane block
+    "keye": (3, 4, (2, 2, 1), 256, (16, 16, 8)),
+    "one_head_beside_four": (2, 4, (4, 1), 128, (128, 64)),
 }
 
 
 def _case(name, dtype, seed=0):
-    L, B, K, W, Ds = SHAPES[name]
+    L, B, Ks, W, Ds = SHAPES[name]
     rng = np.random.RandomState(seed)
-    stacks = tuple(jnp.asarray(rng.randn(L, B, K, D, W), dtype) for D in Ds)
-    news = tuple(jnp.asarray(rng.randn(B, K, D, 1), dtype) for D in Ds)
+    stacks = tuple(jnp.asarray(rng.randn(L, B, K, D, W), dtype)
+                   for K, D in zip(Ks, Ds))
+    news = tuple(jnp.asarray(rng.randn(B, K, D, 1), dtype)
+                 for K, D in zip(Ks, Ds))
     return stacks, news, W
 
 
@@ -133,11 +139,12 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("name,L,B,K,Ds,W", [
-    ("gpt2_medium", 24, 16, 16, (64, 64), 1024),
-    ("mimo_full", 2, 64, 4, (192, 128), 2048),
-    ("mimo_ring", 5, 64, 8, (192, 128), 128)])
-def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, K, Ds, W):
+@pytest.mark.parametrize("name,L,B,Ks,Ds,W", [
+    ("gpt2_medium", 24, 16, (16, 16), (64, 64), 1024),
+    ("mimo_full", 2, 64, (4, 4), (192, 128), 2048),
+    ("mimo_ring", 5, 64, (8, 8), (192, 128), 128),
+    ("keye_vl2", 6, 16, (4, 4, 1), (128, 128, 64), 16384)])
+def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, Ks, Ds, W):
     """At the cells' real widths Mosaic takes the kernel (interpret mode
     cannot say), the stacks are aliased to their outputs, and the
     compiled program copies no layer of them: it holds no temporary of
@@ -153,14 +160,47 @@ def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, K, Ds, W):
         return jax.lax.scan(body, stacks, jnp.arange(L, dtype=jnp.int32))[0]
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
-        tuple(sds((L, B, K, D, W)) for D in Ds),
-        tuple(sds((B, K, D, 1)) for D in Ds),
+        tuple(sds((L, B, K, D, W)) for K, D in zip(Ks, Ds)),
+        tuple(sds((B, K, D, 1)) for K, D in zip(Ks, Ds)),
         sds((B,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
     for i in range(len(Ds)):
         assert f"{{{i}}}: ({i}, {{}}" in alias, alias
-    layer = B * K * min(Ds) * W * 2
+    layer = B * min(K * D for K, D in zip(Ks, Ds)) * W * 2
     assert serving.whole_layer_ops(text, layer) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer
+
+
+@pytest.mark.parametrize("kernel", ["select", "attend"])
+def test_the_selection_kernels_compile_for_a_v5e(one_chip, monkeypatch,
+                                                 kernel):
+    """Keye-VL-2.0's two prefill kernels (`ops/indexed_attention.py`) at
+    the cell's real sizes, one row of 16,384 positions: Mosaic takes the
+    query block's 8 MB of keys in VMEM, the int8 mask and the 1,024-row
+    attention tile (interpret mode cannot say).  Kept in this file: one
+    worker describes the chip."""
+    from mxnet_tpu.ops import indexed_attention
+
+    monkeypatch.setattr(indexed_attention, "_use_interpret", lambda: False)
+    S, bf = 16384, jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if kernel == "select":
+        fn = lambda qi, w, ki, last: indexed_attention.select_prefill(
+            qi, w, ki, last, 2048)
+        args = (sds((1, 16, S, 64)), sds((1, S, 16), jnp.float32),
+                sds((1, 64, S)), sds((1,), jnp.int32))
+        out = (1, S, S)
+    else:
+        fn = indexed_attention.attend_prefill
+        args = (sds((1, 4, 8, S, 128)), sds((1, 4, S, 128)),
+                sds((1, 4, S, 128)), sds((1, S, S), jnp.int8),
+                sds((1,), jnp.int32))
+        out = (1, 4, 8, S, 128)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == out

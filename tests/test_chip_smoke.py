@@ -87,8 +87,28 @@ def test_serve_mimo_phase():
                   value_scale=0.707, max_length=64, attn_block=4,
                   grad_req="null")
     assert set(kwargs) - {"attn_block"} <= set(full.kwargs)
-    size = chip_smoke.MimoSize(kwargs=kwargs, batch=4, prefill_floor=16,
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=16,
                                prompt_lens=(3, 4, 13, 16), new_tokens=13)
     out = chip_smoke.phase_serve_mimo(size, "cpu")
     assert out["retraces"] == 0 and out["programs"] == 2
     assert isinstance(mimo_v2.mimo_v2_tiny(), mimo_v2.MiMoV2Model)
+
+
+def test_serve_keye_phase():
+    """The third family's phase at a tiny size: the engine's tuple is the
+    parameters' own buffers, the three stacks stay where they are, the
+    keys read are counted, coalesced == alone, a repeat is identical,
+    and both selections equal the reference's sort."""
+    small = chip_smoke.keye_small()
+    assert small.kwargs["head_dim"] == 128 and small.prefill_floor \
+        == small.kwargs["max_length"]
+    kwargs = dict(vocab_size=96, units=64, num_layers=3, num_heads=4,
+                  kv_heads=2, head_dim=16, index_heads=2, index_dim=8,
+                  topk=8, expert_hidden=32, router_experts=8,
+                  experts_per_token=2, experts_held=[2, 4], max_length=64,
+                  grad_req="null")
+    assert set(kwargs) <= set(small.kwargs)
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(3, 8, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_keye(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
