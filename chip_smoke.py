@@ -442,11 +442,28 @@ def require_fed_on_device(tag, engine, prompts, steps, served, timing):
         f"a step, tokens equal the host-picked run")
 
 
-def require_kernel_cache_writes(tag, engine, timing, platform):
+def require_cache_kernels(tag, engine, timing, platform):
     """On the chip every cache row write of the decode program goes
     through the in-place kernel (`ops/cache_write.py`) and its text
-    holds no dynamic-update-slice under ``serve.cache_write``; the CPU
-    has no such kernel and writes by rows."""
+    holds no dynamic-update-slice under ``serve.cache_write``, and
+    every attention call over a window of more than one lane block
+    goes through the per-row kernel (`ops/cache_attention.py`); the
+    CPU has neither kernel: it writes by rows and reads whole windows."""
+    reads = engine._program.cache_reads[1]
+    calls = sum(reads.values())
+    long_windows = sum(c for (_, W, _), c in reads.items() if W > 128)
+    attn_share = timing["decode_attn_kernel_share"]
+    say(f"[{tag}] decode program: decode_attn_kernel_share {attn_share} "
+        f"({long_windows} of {calls} attention calls over more than one "
+        f"lane block), decode_attn_window_read_pct "
+        f"{timing['decode_attn_window_read_pct']:.2f}")
+    want = long_windows / calls if platform == "tpu" else 0.0
+    require(attn_share == want,
+            f"{tag}: kernel share {attn_share} of the attention calls on "
+            f"{platform}, {want} expected")
+    require((timing["decode_attn_window_read_pct"] < 100.0) == (want > 0),
+            f"{tag}: decode_attn_window_read_pct "
+            f"{timing['decode_attn_window_read_pct']} on {platform}")
     share = timing["decode_cache_write_kernel_share"]
     text = engine._programs[(timing["bucket"][0], 1)].as_text()
     by_rows = sum("dynamic-update-slice(" in line
@@ -545,7 +562,7 @@ def phase_serve(size, platform, net):
     together, timing = engine.serve_group(group, size.new_tokens)
     require_fed_on_device("serve", engine, group, size.new_tokens,
                           together, timing)
-    require_kernel_cache_writes("serve", engine, timing, platform)
+    require_cache_kernels("serve", engine, timing, platform)
     engine.batch_buckets = (big,)
     try:
         alone = [engine.serve_group([p], size.new_tokens)
@@ -671,7 +688,7 @@ def phase_serve_mimo(size, platform):
             "serve_mimo: a repeated group differs")
     require_fed_on_device("serve_mimo", engine, prompts, size.new_tokens,
                           again, timing)
-    require_kernel_cache_writes("serve_mimo", engine, timing, platform)
+    require_cache_kernels("serve_mimo", engine, timing, platform)
     dev = _ctx_for(platform).jax_device
     stats = dev.memory_stats()
     peak = stats["peak_bytes_in_use"] if stats else None
@@ -802,7 +819,7 @@ def phase_serve_keye(size, platform):
             "serve_keye: a repeated group differs")
     require_fed_on_device("serve_keye", engine, prompts, size.new_tokens,
                           again, timing)
-    require_kernel_cache_writes("serve_keye", engine, timing, platform)
+    require_cache_kernels("serve_keye", engine, timing, platform)
     require_selection_equals_the_reference("serve_keye", net._sizes, S)
     say(f"[serve_keye] group of {len(prompts)} (prompts "
         f"{size.prompt_lens}) x {size.new_tokens} tokens through bucket "

@@ -5,8 +5,8 @@ a change here is a change to both, and both cells measure it.
 
 - `mm`, `rms_norm`, `rope`: the mixed-precision product, RMSNorm and
   the rotation (rotate-half over the first ``rot`` dimensions);
-- `attend_cache`: one query a row over a position-minor cache layer
-  under a mask; `attn_out`: heads side by side, then ``x + a Wo``;
+- `attn_out`: heads side by side, then ``x + a Wo`` (attention over
+  the caches is `ops/cache_attention.py`, GPT's too);
 - `route`: the second norm and the router, the routing rule passed in;
   `moe_count_row`, `moe_counters`: an expert layer's counters in the
   donated carry and their read-back (docs/observability.md);
@@ -49,27 +49,6 @@ def rope(x, pos, theta, rot):
     a, b = x[..., :half], x[..., half:rot]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
                             x[..., rot:]], axis=-1)
-
-
-def attend_cache(q, ck, cv, seen, sink):
-    """One query position a row over a cache layer: q (B, K, G, D)
-    scaled; ck (B, K, D, W); cv (B, K, Dv, W); ``seen`` (B, W) bool;
-    ``sink`` (K, G) or None.  Returns (B, K, G, Dv) float32."""
-    import jax.numpy as jnp
-
-    s = jnp.einsum("bkgd,bkdw->bkgw", q, ck,
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(seen[:, None, None, :], s, _MASKED)
-    m = jnp.max(s, axis=-1)
-    if sink is not None:
-        m = jnp.maximum(m, sink[None])
-    p = jnp.exp(s - m[..., None])
-    denom = jnp.sum(p, axis=-1)
-    if sink is not None:
-        denom = denom + jnp.exp(sink[None] - m)
-    a = jnp.einsum("bkgw,bkdw->bkgd", p.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
-    return a / denom[..., None]
 
 
 def attn_out(z, p, x, a):

@@ -16,7 +16,7 @@ TPU-first trunk primitives:
 
 from __future__ import annotations
 
-from ...ops import cache_write
+from ...ops import cache_attention, cache_write
 from ..block import HybridBlock
 from .. import nn
 from .bert import ScanTransformerEncoder, TransformerEncoder
@@ -292,9 +292,12 @@ class GPTDecoderProgram:
     a head under 128 wide whatever the logical order (so a kernel sees
     the buffer as it lies); the layer loop *carries* it whole: a layer
     writes its new ``(B, H, Dh, S)`` rows into the stack
-    (`ops/cache_write.py`) and attends over its own slice of it.
+    (`ops/cache_write.py`) and attends over its own slice of it: a
+    prefill block over the whole window, a decode step through
+    `ops/cache_attention.py`, each row to its own length.
     ``cache_writes[S]`` counts, at trace time, the row writes of the
-    block-``S`` step by the path they took.  Under a ``mesh`` the weight
+    block-``S`` step by the path they took, ``cache_reads[S]`` its
+    attention calls over the cache.  Under a ``mesh`` the weight
     stacks follow the Megatron column/row split of TRANSFORMER_TP_RULES
     and the cache shards on its head axis
     (parallel/sharding.serving_cache_sharding).
@@ -324,6 +327,7 @@ class GPTDecoderProgram:
         # one, not assumed
         self._cache_layout = self.init_cache(1)[0].format.layout
         self.cache_writes = {}
+        self.cache_reads = {}
 
     # -- weights ---------------------------------------------------------------
 
@@ -452,6 +456,7 @@ class GPTDecoderProgram:
          g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
         B, S = toks.shape
         tally = self.cache_writes[S] = collections.Counter()
+        reads = self.cache_reads[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
             x = (jnp.take(tok_e, toks, axis=0) +
@@ -478,20 +483,28 @@ class GPTDecoderProgram:
                 ck, cv = (keep_layout(c) for c in cache_write.write_rows(
                     (ck, cv), (kh, vh), l, pos, mesh=mesh, tally=tally))
             with jax.named_scope("serve.attn"):
-                ck_l = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
-                cv_l = lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
-                scores = jnp.einsum("bhsd,bhdw->bhsw", qh, ck_l) \
-                    * (Dh ** -0.5)
-                # per-row causal mask: row b at block offset s may
-                # see cache slots <= pos[b] + s (stale pad garbage
-                # beyond is invisible — the overwrite-before-attend
-                # invariant)
-                mask = jnp.arange(W)[None, None, :] <= \
-                    (pos[:, None, None] +
-                     jnp.arange(S)[None, :, None])         # (B, S, W)
-                scores = jnp.where(mask[:, None], scores, -1e30)
-                p = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum("bhsw,bhdw->bhsd", p, cv_l)
+                # row b at block offset s may see cache slots
+                # <= pos[b] + s (stale pad garbage beyond is invisible
+                # — the overwrite-before-attend invariant)
+                if S == 1:
+                    # one position a row: (B, H, 1, Dh) is one query a
+                    # key head, over the row's pos[b] + 1 positions
+                    attn = cache_attention.attend_rows(
+                        qh * (Dh ** -0.5), ck, cv, l, pos + 1, mesh=mesh,
+                        tally=reads)
+                else:
+                    ck_l = lax.dynamic_index_in_dim(ck, l, 0,
+                                                    keepdims=False)
+                    cv_l = lax.dynamic_index_in_dim(cv, l, 0,
+                                                    keepdims=False)
+                    scores = jnp.einsum("bhsd,bhdw->bhsw", qh, ck_l) \
+                        * (Dh ** -0.5)
+                    mask = jnp.arange(W)[None, None, :] <= \
+                        (pos[:, None, None] +
+                         jnp.arange(S)[None, :, None])     # (B, S, W)
+                    scores = jnp.where(mask[:, None], scores, -1e30)
+                    p = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum("bhsw,bhdw->bhsd", p, cv_l)
                 attn = jnp.einsum("bhsd,chd->bsc", attn, pw) + pb_l
                 x = x + attn
             with jax.named_scope("serve.mlp"):

@@ -41,7 +41,7 @@ in `_decoder_ops.py`, which both import; neither imports the other.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_write, indexed_attention
+from ...ops import cache_attention, cache_write, indexed_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
 
@@ -314,8 +314,10 @@ class KeyeVL2Program:
         self.window = model._max_length
         self.vocab = model._vocab
         self._pins = None
-        # cache_writes[S]: the row writes of the block-S step, by path
+        # cache_writes[S]: the row writes of the block-S step, by path;
+        # cache_reads[S]: its attention calls over the cache
         self.cache_writes = {}
+        self.cache_reads = {}
         # what a reloaded model must share beyond its shapes
         self.signature = (z.num_heads, z.kv_heads, z.index_heads, z.topk,
                           z.experts_held, z.experts_per_token, z.rope_theta)
@@ -386,6 +388,7 @@ class KeyeVL2Program:
         decode = S == 1
         W, n = self.window, z.experts_held[1]
         tally = self.cache_writes[S] = collections.Counter()
+        reads = self.cache_reads[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             x = jnp.take(w["embed_weight"], toks, axis=0
                          ).astype(jnp.float32)
@@ -419,8 +422,9 @@ class KeyeVL2Program:
                 live = jnp.arange(W)[None, :] <= pos[:, None]
                 mask = indexed_attention.select_topk(index, live, z.topk)
             with jax.named_scope("serve.attn_sparse"):
-                a = _ops.attend_cache(q[:, :, :, 0], of_layer(ck, l),
-                                  of_layer(cv, l), mask, None)
+                a = cache_attention.attend_rows(
+                    q[:, :, :, 0], ck, cv, l, pos + 1, mask=mask,
+                    tally=reads)
             with jax.named_scope("serve.attn_out"):
                 x = _ops.attn_out(z, p, x, a[:, :, :, None])
             seen = jnp.stack([jnp.sum(pos + 1), jnp.sum(mask)])

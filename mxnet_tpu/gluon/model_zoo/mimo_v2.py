@@ -47,7 +47,7 @@ time through attention and the dense feed-forward, so that a bucket of
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_write
+from ...ops import cache_attention, cache_write
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
 from ._decoder_ops import _MASKED
@@ -369,8 +369,10 @@ class MiMoV2Program:
         self.window = model._max_length
         self.vocab = model._vocab
         self._pins = None
-        # cache_writes[S]: the row writes of the block-S step, by path
+        # cache_writes[S]: the row writes of the block-S step, by path;
+        # cache_reads[S]: its attention calls over the caches
         self.cache_writes = {}
+        self.cache_reads = {}
         z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (tuple(z.layer_types), tuple(z.moe_layers),
@@ -444,6 +446,7 @@ class MiMoV2Program:
         R = z.window
         zero = jnp.int32(0)
         tally = self.cache_writes[S] = collections.Counter()
+        reads = self.cache_reads[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             x = jnp.take(w["embed_weight"], toks, axis=0
                          ).astype(jnp.float32)
@@ -490,18 +493,14 @@ class MiMoV2Program:
                         at_layer) for c, a in ((wk, k), (wv, v)))
             if decode:
                 with jax.named_scope(f"serve.attn_{kind}"):
-                    if kind == "full":
-                        seen = jnp.arange(self.window)[None, :] \
-                            <= pos[:, None]
-                        ck, cv = fk[l], fv[l]
-                    else:
-                        # slot s holds position pos - (pos - s) mod R,
-                        # if that position exists
-                        slot = jnp.arange(R)[None, :]
-                        seen = pos[:, None] - (pos[:, None] - slot) % R >= 0
-                        ck, cv = wk[l], wv[l]
-                    a = _ops.attend_cache(q[:, :, :, 0], ck, cv, seen,
-                                      _sink(z, kind, p))
+                    # a ring's slot s holds position pos - (pos - s)
+                    # mod R if that position exists: its first pos + 1
+                    # slots, then all of them
+                    ck, cv, held = (fk, fv, pos + 1) if kind == "full" \
+                        else (wk, wv, jnp.minimum(pos + 1, R))
+                    a = cache_attention.attend_rows(
+                        q[:, :, :, 0], ck, cv, l, held,
+                        sink=_sink(z, kind, p), tally=reads)
                     x = _ops.attn_out(z, p, x, a[:, :, :, None])
                 x, route = _feed_forward_front(z, i, p, x)
             if route:

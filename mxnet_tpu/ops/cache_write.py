@@ -26,6 +26,9 @@ chosen on what the call can see:
   ``lax.dynamic_update_slice`` a row and a stack.  It is also what the
   kernel is tested against (tests/test_cache_write.py, interpreted).
 
+What a decode step then reads of the stacks, each row to its own
+length, is `ops/cache_attention.py`.
+
 ``tally`` (a ``collections.Counter`` or None) is told at trace time how
 many row writes went by which path, one a row and a stack (``B * n`` a
 call): ``tally["kernel"]``, ``tally["rows"]``.

@@ -277,6 +277,10 @@ class ContinuousBatcher:
                     "decode_readback_bytes_per_step"),
                 decode_cache_write_kernel_share=timings.get(
                     "decode_cache_write_kernel_share"),
+                decode_attn_kernel_share=timings.get(
+                    "decode_attn_kernel_share"),
+                decode_attn_window_read_pct=timings.get(
+                    "decode_attn_window_read_pct"),
                 **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step

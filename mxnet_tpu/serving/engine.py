@@ -200,6 +200,23 @@ def whole_layer_ops(hlo_text, layer_bytes):
             for instr in instrs if moved(comp, instr) >= layer_bytes]
 
 
+def _window_read_pct(reads, lens, steps):
+    """Of the ``B x W`` positions a decode attention call could read,
+    over a group's ``steps`` decode steps, the percentage in the lane
+    blocks it was asked for.  ``reads``: the program's
+    ``cache_reads[1]``, calls by (path, W, lanes); step d of a row
+    with a prompt of ``lens[b]`` tokens holds ``lens[b] + d + 1``
+    positions and is read in whole blocks of ``lanes``."""
+    import numpy as np
+
+    held = lens[:, None].astype(np.int64) + np.arange(1, steps + 1)[None, :]
+    asked = whole = 0
+    for (_, W, lanes), calls in reads.items():
+        asked += calls * int(np.minimum(-(-held // lanes) * lanes, W).sum())
+        whole += calls * held.size * W
+    return 100.0 * asked / whole
+
+
 class ServingEngine:
     """Bucketed AOT prefill/decode over a model's decoder program.
 
@@ -223,8 +240,9 @@ class ServingEngine:
       (a checkpoint convention), ``counters(cache)`` (a dict read back
       once a group, merged into the timings), ``cache_writes`` (by
       block length S, the row writes `ops/cache_write.py` counted by
-      path while the step was traced) and ``signature`` (what a
-      reloaded model must share beyond shapes).
+      path while the step was traced), ``cache_reads`` (likewise the
+      attention calls `ops/cache_attention.py` counted) and
+      ``signature`` (what a reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
     pad to the nearest (batch, seq) bucket, one prefill dispatch, one
@@ -616,6 +634,17 @@ class ServingEngine:
         if writes:
             timings["decode_cache_write_kernel_share"] = \
                 writes["kernel"] / sum(writes.values())
+        # of its attention calls over the cache, the share that read
+        # each row to its length (ops/cache_attention.py's kernel), and
+        # what share of the rows' windows their blocks were
+        reads = getattr(self._program, "cache_reads", {}).get(1)
+        if reads:
+            timings["decode_attn_kernel_share"] = sum(
+                c for (path, _, _), c in reads.items()
+                if path == "kernel") / sum(reads.values())
+            if dispatched:
+                timings["decode_attn_window_read_pct"] = \
+                    _window_read_pct(reads, lens, dispatched)
         counters = getattr(self._program, "counters", None)
         if counters is not None:
             # what the family counted in its donated carry: one small

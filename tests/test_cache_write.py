@@ -1,5 +1,8 @@
 """`ops/cache_write.py`: the in-place kernel (interpreted here) against
-the plain path, one ``dynamic_update_slice`` a row."""
+the plain path, one ``dynamic_update_slice`` a row.  Also this file's:
+every compile for a described v5e (this kernel, `ops/cache_attention.py`'s
+and `ops/indexed_attention.py`'s), so that one worker loads the TPU's
+library."""
 
 import collections
 
@@ -171,6 +174,49 @@ def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, Ks, Ds, W):
     layer = B * min(K * D for K, D in zip(Ks, Ds)) * W * 2
     assert serving.whole_layer_ops(text, layer) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer
+
+
+@pytest.mark.parametrize("name,L,B,K,G,D,Dv,W,masked,sunk", [
+    ("gpt2_medium", 24, 16, 16, 1, 64, 64, 1024, False, False),
+    ("mimo_full", 2, 64, 4, 16, 192, 128, 2048, False, False),
+    # the rule keeps a one-block ring on the plain path; the kernel
+    # itself takes it, sink and all
+    ("mimo_ring", 5, 64, 8, 8, 192, 128, 128, False, True),
+    ("keye_vl2", 6, 16, 4, 8, 128, 128, 16384, True, False)])
+def test_attention_kernel_compiles_for_a_v5e(one_chip, name, L, B, K, G, D,
+                                             Dv, W, masked, sunk):
+    """`ops/cache_attention.py`'s kernel at the cells' real widths, in a
+    layer loop with a traced layer index as the decode programs call it:
+    Mosaic takes it (GPT's heads two side by side, MiMo-V2's 192-wide
+    keys, Keye-VL-2.0's mask), and the compiled program copies no layer
+    of the stacks it is given whole: it holds no temporary of a layer's
+    size.  Kept in this file: one worker describes the chip."""
+    from mxnet_tpu import serving
+    from mxnet_tpu.ops import cache_attention
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, ck, cv, n, mask, sink):
+        lanes = cache_attention.block_lanes(ck, cv)
+
+        def body(acc, l):
+            return acc + cache_attention._attend_kernel(
+                q, ck, cv, l, n, mask if masked else None,
+                sink if sunk else None, lanes), None
+        return jax.lax.scan(body, jnp.zeros((B, K, G, Dv), jnp.float32),
+                            jnp.arange(L, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(step).lower(
+        sds((B, K, G, D)), sds((L, B, K, D, W)), sds((L, B, K, Dv, W)),
+        sds((B,), jnp.int32), sds((B, W), jnp.bool_),
+        sds((K, G), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer = B * K * min(D, Dv) * W * 2
+    assert serving.whole_layer_ops(text, layer) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer
+    assert compiled.out_info.shape == (B, K, G, Dv)
 
 
 @pytest.mark.parametrize("kernel", ["select", "attend"])

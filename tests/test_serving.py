@@ -65,6 +65,54 @@ def test_coalesced_batch_bitwise_equals_one_by_one():
                                       got.astype(np.int64))
 
 
+def test_decode_attention_is_counted_and_the_tokens_stand():
+    """The decode step attends through `ops/cache_attention.py`: on the
+    CPU by its plain path over the whole window (share 0.0, every
+    position of every row's window read), and a group's tokens are the
+    full recompute's, as they were when the step held the contraction
+    itself."""
+    net = _model(max_length=32)
+    eng = serving.ServingEngine(net, batch_buckets=(4,))
+    rng = np.random.RandomState(11)
+    prompts = _prompts(4, rng, lo=3, hi=12)
+    outs, timings = eng.serve_group(prompts, [9, 4, 7, 2])
+    for p, got, n in zip(prompts, outs, [9, 4, 7, 2]):
+        seed = mx.nd.array(np.asarray([p], np.float32))
+        ref = gpt.generate(net, seed, max_new_tokens=n).asnumpy()[0, len(p):]
+        np.testing.assert_array_equal(ref.astype(np.int64),
+                                      got.astype(np.int64))
+    assert timings["decode_attn_kernel_share"] == 0.0
+    assert timings["decode_attn_window_read_pct"] == 100.0
+    assert dict(eng._program.cache_reads[1]) == {("xla", 32, 32): 1}
+    # a prefill block attends inside the step: the op is not called
+    assert not eng._program.cache_reads[16]
+    # one token a request: no decode step ran, so nothing was read
+    _, timings = eng.serve_group(prompts, 1)
+    assert "decode_attn_window_read_pct" not in timings
+
+
+@pytest.mark.parametrize("reads,lens,steps,want", [
+    # two rows, three decode steps, blocks of 256 in a window of 1,024:
+    # 29-31 positions are one block, 513-515 three
+    ({("kernel", 1024, 256): 1}, [28, 512], 3, 100.0 * 4 / 8),
+    # a row that crosses a block's edge at its second step
+    ({("kernel", 1024, 128): 1}, [127], 2, 100.0 * (1 + 2) / 16),
+    # the plain path reads the whole window whatever the row holds
+    ({("xla", 64, 64): 1}, [1, 40], 5, 100.0),
+    # MiMo-V2's two kinds of layer: two full layers through the kernel,
+    # five rings of one block each
+    ({("kernel", 2048, 512): 2, ("xla", 128, 128): 5}, [100, 600], 1,
+     100.0 * (2 * (512 + 1024) + 5 * 2 * 128) / (2 * 2 * 2048 + 5 * 2 * 128)),
+    # nothing is read past the window
+    ({("kernel", 256, 128): 1}, [250], 4, 100.0)])
+def test_window_read_pct_follows_lengths_steps_and_blocks(reads, lens, steps,
+                                                          want):
+    from mxnet_tpu.serving.engine import _window_read_pct
+
+    got = _window_read_pct(reads, np.asarray(lens, np.int32), steps)
+    assert got == pytest.approx(want)
+
+
 def test_per_request_max_new_tokens_truncates():
     eng = serving.ServingEngine(_model(), batch_buckets=(2,))
     rng = np.random.RandomState(5)
@@ -243,6 +291,9 @@ def test_batcher_coalesces_and_emits_request_records():
         # device, and the host read (4, 1) int32 of each program
         assert r["decode_steps_fed_on_device"] == 2
         assert r["decode_readback_bytes_per_step"] == 4 * 4
+        # the decode attention's path and reach, as the engine counted
+        assert r["decode_attn_kernel_share"] == 0.0
+        assert r["decode_attn_window_read_pct"] == 100.0
 
 
 def test_batcher_propagates_engine_errors():
