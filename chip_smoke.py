@@ -15,7 +15,7 @@ a third, ``KeyeVL2Model`` at small aligned sizes (a learned indexer with
 a cache stack of its own, attention over its selection, softmax-routed
 experts); with four chips, the GPT step under ``shard_model`` fsdp and
 tp.  Phases, in order: device, sync, kernel, train, serve, serve_mimo,
-serve_keye, sharded.  The first failed check raises and the process
+serve_keye, serve_kimi, sharded.  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
@@ -108,6 +108,27 @@ def keye_small():
                   topk=128, expert_hidden=128, router_experts=8,
                   experts_per_token=2, experts_held=[2, 4],
                   max_length=1024, dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=1024,
+                    prompt_lens=(40, 128, 300, 700, 77, 513, 16, 260),
+                    new_tokens=6)
+
+
+def kimi_small():
+    """The fourth family at small aligned sizes with every mechanism
+    present: 8 heads of 128 + 64 rotated dimensions, latents of 256, a
+    cache entry of 320 (2.5 lane tiles: not a multiple of 128), a dense
+    layer and 2 expert layers with a shared expert, 4 of 8 experts
+    held, YaRN by 8 from 128 positions, rows a prefill works off two at
+    a time and token-wise products 256 positions at a time."""
+    kwargs = dict(vocab_size=512, units=256, num_layers=3, num_heads=8,
+                  q_rank=128, kv_rank=256, nope_dim=128, rope_dim=64,
+                  v_dim=128, hidden_size=512, expert_hidden=128,
+                  router_experts=8, experts_per_token=2,
+                  experts_held=[2, 4], route_scale=2.5, rope_factor=8.0,
+                  rope_original_length=128, mscale=1.0, mscale_all_dim=1.0,
+                  max_length=1024, attn_block=256, token_chunk=256,
+                  prefill_chunk_tokens=2048, dtype="bfloat16",
+                  grad_req="null")
     return FamilySize(kwargs=kwargs, batch=8, prefill_floor=1024,
                     prompt_lens=(40, 128, 300, 700, 77, 513, 16, 260),
                     new_tokens=6)
@@ -619,32 +640,39 @@ def _seed_normal(net, seed=0):
         p.set_data(draw(jax.random.fold_in(key, i)))
 
 
-def phase_serve_mimo(size, platform):
+def serve_family(tag, model, size, platform, stacks, counters_hold):
+    """What every further family's phase requires of ``model(**kwargs)``
+    through `ServingEngine`: the engine's tuple is the parameters' own
+    buffers on the platform, the decode program moves no layer-sized
+    piece of the cache's first ``stacks`` arrays, every request resolves
+    to tokens, ``counters_hold(timing, lens)`` (the family's own
+    counters of the first group, ``lens`` the bucket's prompt lengths,
+    pad rows' included), a coalesced group equals its requests alone, a
+    repeated group is identical, it is fed on the device and its cache
+    ops take their kernels.  Returns (net, engine, timing, what the
+    phase reports)."""
     import jax.numpy as jnp
 
     import mxnet_tpu as mx
     from mxnet_tpu import serving
-    from mxnet_tpu.gluon.model_zoo import mimo_v2
 
     t0 = time.perf_counter()
-    net = mimo_v2.MiMoV2Model(**size.kwargs)
+    net = model(**size.kwargs)
     net.initialize(init=mx.init.One(), ctx=_ctx_for(platform))
     _seed_normal(net)
     n_params = sum(int(np.prod(p.shape))
                    for p in net.collect_params().values())
-    dtype = jnp.dtype(size.kwargs.get("dtype", "float32"))
-    engine = serving.ServingEngine(net, batch_buckets=(size.batch,),
-                                   prefill_floor=size.prefill_floor,
-                                   dtype=dtype)
-    say(f"[serve_mimo] {n_params / 1e9:.2f}B parameters placed and seeded "
+    engine = serving.ServingEngine(
+        net, batch_buckets=(size.batch,), prefill_floor=size.prefill_floor,
+        dtype=jnp.dtype(size.kwargs.get("dtype", "float32")))
+    say(f"[{tag}] {n_params / 1e9:.2f}B parameters placed and seeded "
         f"in {time.perf_counter() - t0:.1f}s")
     own = {id(p.data()._data) for p in net.collect_params().values()}
     require(all(id(a) in own for a in engine._weights),
-            "serve_mimo: the engine holds a second copy of a parameter")
-    cache = engine.init_cache(1)
+            f"{tag}: the engine holds a second copy of a parameter")
     require(all(on_platform(a, platform)
-                for a in engine._weights + cache),
-            f"serve_mimo: weights or cache not on a {platform} device")
+                for a in engine._weights + engine.init_cache(1)),
+            f"{tag}: weights or cache not on a {platform} device")
     vocab = net._vocab
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, vocab, n).tolist() for n in size.prompt_lens]
@@ -652,55 +680,60 @@ def phase_serve_mimo(size, platform):
     together, timing = engine.serve_group(prompts, size.new_tokens)
     first = time.perf_counter() - t0
     pinned = serving.trace_count()
-    B, S = timing["bucket"]
-    # the decode program moves no layer-sized piece of any of the four
-    # stacks (two kinds of cache, keys and values)
+    B = timing["bucket"][0]
     text = engine._programs[(B, 1)].as_text()
-    big = engine.init_cache(B)
-    for c in big[:4]:
+    for c in engine.init_cache(B)[:stacks]:
         moved = serving.whole_layer_ops(text, c.nbytes // c.shape[0])
-        require(not moved, f"serve_mimo: the decode program moves whole "
+        require(not moved, f"{tag}: the decode program moves whole "
                            f"layers of a {tuple(c.shape)} stack: {moved}")
-    del big
     for j, toks in enumerate(together):
         require(len(toks) == size.new_tokens
                 and all(0 <= int(t) < vocab for t in toks),
-                f"serve_mimo: request {j} resolved to {toks}")
-    real = sum(size.prompt_lens)
-    layers = sum(1 for m in size.kwargs["moe_layers"] if m)
-    require(0 < timing["moe_pairs_prefill"]
-            <= real * layers * size.kwargs["experts_per_token"],
-            f"serve_mimo: {timing['moe_pairs_prefill']} prefill "
-            f"assignments for {real} tokens")
-    require(timing["moe_rows_computed_decode"]
+                f"{tag}: request {j} resolved to {toks}")
+    require(counters_hold(timing, size.prompt_lens
+                          + (1,) * (B - len(prompts)))
+            and timing["moe_rows_computed_decode"]
             >= timing["moe_pairs_decode"] > 0,
-            f"serve_mimo: decode counters {timing}")
+            f"{tag}: counters {timing}")
     # a coalesced group == each request alone through the same bucket
     for j in (0, len(prompts) - 1):
         alone, tm = engine.serve_group([prompts[j]], size.new_tokens)
-        require(tm["bucket"][0] == B, "serve_mimo: another batch bucket")
+        require(tm["bucket"][0] == B, f"{tag}: another batch bucket")
         if tm["bucket"] == timing["bucket"]:
             require(np.array_equal(alone[0], together[j]),
-                    f"serve_mimo: prompt {j} coalesced {together[j]} != "
+                    f"{tag}: prompt {j} coalesced {together[j]} != "
                     f"alone {alone[0]}")
     again, timing = engine.serve_group(prompts, size.new_tokens)
     require(all(np.array_equal(a, b) for a, b in zip(again, together)),
-            "serve_mimo: a repeated group differs")
-    require_fed_on_device("serve_mimo", engine, prompts, size.new_tokens,
-                          again, timing)
-    require_cache_kernels("serve_mimo", engine, timing, platform)
-    dev = _ctx_for(platform).jax_device
-    stats = dev.memory_stats()
+            f"{tag}: a repeated group differs")
+    require_fed_on_device(tag, engine, prompts, size.new_tokens, again,
+                          timing)
+    require_cache_kernels(tag, engine, timing, platform)
+    stats = _ctx_for(platform).jax_device.memory_stats()
     peak = stats["peak_bytes_in_use"] if stats else None
-    say(f"[serve_mimo] group of {len(prompts)} (prompts "
-        f"{size.prompt_lens}) x {size.new_tokens} tokens through bucket "
-        f"{timing['bucket']}: first call {first:.1f}s, then "
+    say(f"[{tag}] group of {len(prompts)} (prompts {size.prompt_lens}) x "
+        f"{size.new_tokens} tokens through bucket {timing['bucket']}: "
+        f"first call {first:.1f}s, then "
         f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
-        f"counters { {k: v for k, v in timing.items() if k.startswith('moe')} }; "
+        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn'))} }; "
         f"peak bytes in use {peak}")
-    return {"params": n_params, "programs": engine.program_count(),
-            "retraces": serving.trace_count() - pinned,
-            "peak_bytes": peak}
+    return net, engine, timing, {
+        "params": n_params, "programs": engine.program_count(),
+        "retraces": serving.trace_count() - pinned, "peak_bytes": peak}
+
+
+def phase_serve_mimo(size, platform):
+    from mxnet_tpu.gluon.model_zoo import mimo_v2
+
+    layers = sum(1 for m in size.kwargs["moe_layers"] if m)
+
+    def counters_hold(timing, lens):
+        return 0 < timing["moe_pairs_prefill"] <= sum(size.prompt_lens) \
+            * layers * size.kwargs["experts_per_token"]
+
+    # four stacks: two kinds of cache, keys and values
+    return serve_family("serve_mimo", mimo_v2.MiMoV2Model, size, platform,
+                        4, counters_hold)[3]
 
 
 # -- serve, a third family -----------------------------------------------------
@@ -753,81 +786,51 @@ def require_selection_equals_the_reference(tag, z, S):
 
 
 def phase_serve_keye(size, platform):
-    import jax.numpy as jnp
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import serving
     from mxnet_tpu.gluon.model_zoo import keye_vl2
 
-    net = keye_vl2.KeyeVL2Model(**size.kwargs)
-    net.initialize(init=mx.init.One(), ctx=_ctx_for(platform))
-    _seed_normal(net)
-    dtype = jnp.dtype(size.kwargs.get("dtype", "float32"))
-    engine = serving.ServingEngine(net, batch_buckets=(size.batch,),
-                                   prefill_floor=size.prefill_floor,
-                                   dtype=dtype)
-    own = {id(p.data()._data) for p in net.collect_params().values()}
-    require(all(id(a) in own for a in engine._weights),
-            "serve_keye: the engine holds a second copy of a parameter")
-    require(all(on_platform(a, platform)
-                for a in engine._weights + engine.init_cache(1)),
-            f"serve_keye: weights or cache not on a {platform} device")
-    vocab, topk = net._vocab, size.kwargs["topk"]
-    rng = np.random.RandomState(2)
-    prompts = [rng.randint(0, vocab, n).tolist() for n in size.prompt_lens]
-    together, timing = engine.serve_group(prompts, size.new_tokens)
-    pinned = serving.trace_count()
-    B, S = timing["bucket"]
-    # the decode program moves no layer-sized piece of any of the three
-    # stacks: keys, values, the indexer's keys
-    text = engine._programs[(B, 1)].as_text()
-    big = engine.init_cache(B)
+    L, topk = size.kwargs["num_layers"], size.kwargs["topk"]
+
+    def counters_hold(timing, lens):
+        live = L * sum(n * (n + 1) // 2 for n in lens)
+        least = L * sum(min(t + 1, topk) for n in lens for t in range(n))
+        return timing["attn_keys_live_prefill"] == live \
+            and least <= timing["attn_keys_selected_prefill"] < live \
+            and 0 < timing["attn_keys_selected_decode"] \
+            < timing["attn_keys_live_decode"]
+
+    # three stacks: keys, values, the indexer's keys
+    net, engine, timing, out = serve_family(
+        "serve_keye", keye_vl2.KeyeVL2Model, size, platform, 3,
+        counters_hold)
+    big = engine.init_cache(1)
     require(len(big) == 5 and big[2].shape[2:4]
             == (1, size.kwargs["index_dim"]),
             f"serve_keye: cache {[tuple(c.shape) for c in big]}")
-    for c in big[:3]:
-        moved = serving.whole_layer_ops(text, c.nbytes // c.shape[0])
-        require(not moved, f"serve_keye: the decode program moves whole "
-                           f"layers of a {tuple(c.shape)} stack: {moved}")
-    del big
-    for j, toks in enumerate(together):
-        require(len(toks) == size.new_tokens
-                and all(0 <= int(t) < vocab for t in toks),
-                f"serve_keye: request {j} resolved to {toks}")
+    require_selection_equals_the_reference("serve_keye", net._sizes,
+                                           timing["bucket"][1])
+    return out
+
+
+def phase_serve_kimi(size, platform):
+    from mxnet_tpu.gluon.model_zoo import kimi_k2
+
     L = size.kwargs["num_layers"]
-    lens = size.prompt_lens + (1,) * (B - len(prompts))
-    live = L * sum(n * (n + 1) // 2 for n in lens)
-    least = L * sum(min(t + 1, topk) for n in lens for t in range(n))
-    require(timing["attn_keys_live_prefill"] == live
-            and least <= timing["attn_keys_selected_prefill"] < live,
-            f"serve_keye: prefill read {timing['attn_keys_selected_prefill']}"
-            f" of {timing['attn_keys_live_prefill']} keys ({least}, {live})")
-    require(0 < timing["attn_keys_selected_decode"]
-            < timing["attn_keys_live_decode"],
-            f"serve_keye: decode counters {timing}")
-    require(timing["moe_rows_computed_decode"]
-            >= timing["moe_pairs_decode"] > 0,
-            f"serve_keye: decode counters {timing}")
-    for j in (0, len(prompts) - 1):
-        alone, tm = engine.serve_group([prompts[j]], size.new_tokens)
-        if tm["bucket"] == timing["bucket"]:
-            require(np.array_equal(alone[0], together[j]),
-                    f"serve_keye: prompt {j} coalesced {together[j]} != "
-                    f"alone {alone[0]}")
-    again, timing = engine.serve_group(prompts, size.new_tokens)
-    require(all(np.array_equal(a, b) for a, b in zip(again, together)),
-            "serve_keye: a repeated group differs")
-    require_fed_on_device("serve_keye", engine, prompts, size.new_tokens,
-                          again, timing)
-    require_cache_kernels("serve_keye", engine, timing, platform)
-    require_selection_equals_the_reference("serve_keye", net._sizes, S)
-    say(f"[serve_keye] group of {len(prompts)} (prompts "
-        f"{size.prompt_lens}) x {size.new_tokens} tokens through bucket "
-        f"{timing['bucket']}: "
-        f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
-        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn'))} }")
-    return {"programs": engine.program_count(),
-            "retraces": serving.trace_count() - pinned}
+
+    def counters_hold(timing, lens):
+        return timing["attn_latent_positions_prefill"] \
+            == L * sum(n * (n + 1) // 2 for n in lens) \
+            and timing["attn_latent_positions_decode"] \
+            == L * sum(n + j + 1 for n in lens
+                       for j in range(size.new_tokens - 1))
+
+    # one stack with no heads, and none for the values
+    net, engine, _, out = serve_family(
+        "serve_kimi", kimi_k2.KimiK2Model, size, platform, 1, counters_hold)
+    z, big = net._sizes, engine.init_cache(1)
+    require(len(big) == 3 and big[0].shape
+            == (L, 1, 1, z.kv_rank + z.rope_dim, engine._W),
+            f"serve_kimi: cache {[tuple(c.shape) for c in big]}")
+    return out
 
 
 # -- sharded -------------------------------------------------------------------
@@ -917,6 +920,8 @@ def main():
     run("serve_mimo", phase_serve_mimo, mimo_full(), platform)
     gc.collect()
     run("serve_keye", phase_serve_keye, keye_small(), platform)
+    gc.collect()
+    run("serve_kimi", phase_serve_kimi, kimi_small(), platform)
     gc.collect()
     import jax
 

@@ -1283,7 +1283,7 @@ inline std::vector<NDArray> moe_ffn(
   return op_.Invoke();
 }
 
-// moe_share_ffn(data, router_weight, router_bias, w13, w2; attrs: k, experts_lo, pass_rows, output_stats)
+// moe_share_ffn(data, router_weight, router_bias, w13, w2; attrs: k, experts_lo, pass_rows, output_stats, scale, shared)
 inline std::vector<NDArray> moe_share_ffn(
     const NDArray &data, const NDArray &router_weight, const NDArray &router_bias, const NDArray &w13, const NDArray &w2,
     const std::vector<std::pair<std::string, std::string>> &attrs = {}) {

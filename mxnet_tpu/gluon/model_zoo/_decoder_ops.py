@@ -1,17 +1,23 @@
-"""What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`):
-the pieces of a layer and of a decoder program that do not depend on a
-family's attention or routing rule.  Neither family imports the other;
-a change here is a change to both, and both cells measure it.
+"""What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`,
+`kimi_k2.py`): the pieces of a layer and of a decoder program that do
+not depend on a family's attention or routing rule.  No family imports
+another; a change here is a change to all, and their cells measure it.
 
 - `mm`, `rms_norm`, `rope`: the mixed-precision product, RMSNorm and
-  the rotation (rotate-half over the first ``rot`` dimensions);
+  the rotation (rotate-half over the first ``rot`` dimensions, by
+  ``theta`` or by a table of frequencies: `yarn_frequencies`);
 - `attn_out`: heads side by side, then ``x + a Wo`` (attention over
   the caches is `ops/cache_attention.py`, GPT's too);
-- `route`: the second norm and the router, the routing rule passed in;
-  `moe_count_row`, `moe_counters`: an expert layer's counters in the
+  `attend_causal_blocks`: a block's attention inside itself, query
+  block by query block up to the rows' length;
+- `route`: the second norm and the router, the routing rule passed in
+  (the dense feed-forward and the shared expert are
+  `ops/moe.py::swiglu_ffn`); `experts_of_layer`: a scanned layer's held
+  experts; `moe_count_row`, `moe_counters`: an expert layer's counters in the
   donated carry and their read-back (docs/observability.md);
 - `by_rows`, `chunk_rows`: a prefill block worked off a few rows at a
-  time; `own_weights`: a program's weight tuple.
+  time; `by_tokens`: a few positions at a time; `own_weights`: a
+  program's weight tuple.
 """
 
 from __future__ import annotations
@@ -36,19 +42,102 @@ def rms_norm(x, g, eps):
                          + eps) * g.astype(jnp.float32)
 
 
-def rope(x, pos, theta, rot):
+def rope(x, pos, theta, rot, freq=None):
     """x (B, .., S, D) float32 rotated on its first ``rot`` dimensions at
-    positions ``pos`` (B, S)."""
+    positions ``pos`` (B, S), dimension j paired with j + rot/2, by
+    ``theta ** (-2 j / rot)`` or by the table ``freq`` (rot / 2,)."""
     import jax.numpy as jnp
 
     half = rot // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    if freq is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    else:
+        freq = jnp.asarray(freq, jnp.float32)
     ang = pos.astype(jnp.float32)[..., None] * freq          # (B, S, half)
     shape = (pos.shape[0],) + (1,) * (x.ndim - 3) + (pos.shape[1], half)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
     a, b = x[..., :half], x[..., half:rot]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
                             x[..., rot:]], axis=-1)
+
+
+def yarn_mscale(factor, m):
+    """YaRN's magnitude factor ``0.1 m ln(factor) + 1`` (1 without
+    stretching)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_frequencies(rot, base, factor, original, beta_fast, beta_slow):
+    """YaRN's table of ``rot / 2`` rotary frequencies (float32): pair i
+    keeps ``base ** (-2 i / rot)`` where it turns more than ``beta_fast``
+    times in the ``original`` positions, takes a ``factor``-th of it
+    where it turns less than ``beta_slow`` times, and a linear mix in
+    between."""
+    import math
+
+    import numpy as np
+
+    def turns_at(n):
+        return rot * math.log(original / (2 * math.pi * n)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rot - 1)
+    i = np.arange(rot // 2, dtype=np.float64)
+    f = base ** (-2.0 * i / rot)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
+
+
+def attend_causal_blocks(q, k, v, live, blk):
+    """Causal attention of a block of S positions over itself, one query
+    block of ``blk`` after another and each over the key blocks up to
+    its own, with a running maximum and sum: the work follows the
+    triangle, and query blocks from position ``live`` on (a traced
+    scalar: where no row holds a real token any more) are left zero.
+
+    q (B, S, H, D) scaled; k (B, S, H, D); v (B, S, H, Dv); ``blk``
+    divides S.  Returns (B, S, H, Dv) in q's type."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    B, S, H, _ = q.shape
+    Dv = v.shape[-1]
+    at = jnp.arange(blk)
+
+    def query_block(i, out):
+        qi = lax.dynamic_slice_in_dim(q, i * blk, blk, axis=1)
+
+        def key_block(j, carry):
+            m, l, acc = carry
+            kj = lax.dynamic_slice_in_dim(k, j * blk, blk, axis=1)
+            vj = lax.dynamic_slice_in_dim(v, j * blk, blk, axis=1)
+            s = jnp.einsum("bqhd,bshd->bhqs", qi, kj,
+                           preferred_element_type=jnp.float32)
+            seen = (j * blk + at)[None, :] <= (i * blk + at)[:, None]
+            s = jnp.where(seen, s, _MASKED)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m2[..., None])
+            scale = jnp.exp(m - m2)
+            acc = acc * scale[..., None] + jnp.einsum(
+                "bhqs,bshd->bhqd", p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m2, l * scale + jnp.sum(p, axis=-1), acc
+
+        stat = (B, H, blk)
+        _, l, acc = lax.fori_loop(
+            0, i + 1, key_block,
+            (jnp.full(stat, _MASKED, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (Dv,), jnp.float32)))
+        o = (acc / l[..., None]).swapaxes(1, 2).astype(out.dtype)
+        return lax.dynamic_update_slice_in_dim(out, o, i * blk, axis=1)
+
+    blocks = jnp.clip((live + blk - 1) // blk, 0, S // blk)
+    return lax.fori_loop(0, blocks, query_block,
+                         jnp.zeros((B, S, H, Dv), q.dtype))
 
 
 def attn_out(z, p, x, a):
@@ -73,6 +162,42 @@ def route(z, p, x, choose):
         chosen, weights = choose(u.reshape(B * S, C))
         return (u.astype(p["router_weight"].dtype),
                 chosen.reshape(B, S, k), weights.reshape(B, S, k))
+
+
+def experts_of_layer(z, w13, w2, l, x, route, valid):
+    """x + layer ``l``'s held experts' part for the routed tokens, the
+    experts' weights stacked by layer (``w13`` (L, n, C, 2F), ``w2``
+    (L, n, F, C)); also `held_experts_ffn`'s counts for that layer.
+
+    The grouped product is given the stacks whole, as ``L * n`` groups
+    of which only layer ``l``'s ``n`` can be chosen (an expert held here
+    becomes group ``l * n + e``, any other none): a layer's slice of a
+    stack would be copied for the product's custom call, 151 MB a layer
+    and a decode step at Keye-VL-2.0's sizes (2.8 ms of a 9.4 ms step
+    on the v5e), while empty groups cost nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ...ops import moe
+
+    B, S, C = x.shape
+    u, chosen, weights = route
+    k = z.experts_per_token
+    lo, n = z.experts_held
+    with jax.named_scope("serve.moe.experts"):
+        local = chosen - lo
+        group = jnp.where((local >= 0) & (local < n), local + l * n, -1)
+        y, stats = moe.held_experts_ffn(
+            u.reshape(B * S, C), group.reshape(B * S, k),
+            weights.reshape(B * S, k),
+            w13.reshape((-1,) + w13.shape[2:]),
+            w2.reshape((-1,) + w2.shape[2:]),
+            valid=None if valid is None else valid.reshape(B * S),
+            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C))
+        stats = jnp.concatenate([lax.dynamic_slice(stats, (l * n,), (n,)),
+                                 stats[-1:]])
+        return y.reshape(B, S, C), stats
 
 
 def moe_count_row(stats, n):
@@ -139,6 +264,50 @@ def by_rows(fn, rows, x, *per_row):
         return put(x, xc), jax.tree_util.tree_map(put, extras, ex)
 
     return lax.fori_loop(0, B // rows, one, (x, extras))
+
+
+def by_tokens(fn, tokens, live, x, *per_token):
+    """``fn(x, *per_token) -> (x or None, extras)`` over the positions
+    of a block (axis 1 of every array, in and out), ``tokens`` at a time
+    and one chunk after another, so that only one chunk's temporaries
+    are alive.  Only the chunks that begin before position ``live`` (a
+    traced scalar, or None for all) are worked: past it the residual
+    stream stays what it was and the extras stay zero.  ``fn`` may
+    return None for x where it leaves the stream alone.  Whole when one
+    chunk holds every position.
+
+    `chunk_rows` never cuts below one row, whose token-wise temporaries
+    at a width of 7,168 and 16,384 positions are gigabytes; this is the
+    cut along the other axis."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    S = x.shape[1]
+    if tokens >= S:
+        out, extras = fn(x, *per_token)
+        return (x if out is None else out), extras
+
+    def chunk(a, c):
+        return lax.dynamic_slice_in_dim(a, c * tokens, tokens, axis=1)
+
+    _, shapes = jax.eval_shape(fn, chunk(x, 0),
+                               *(chunk(a, 0) for a in per_token))
+    extras = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape[:1] + (S,) + s.shape[2:], s.dtype),
+        shapes)
+
+    def one(c, carry):
+        x, extras = carry
+        xc, ex = fn(chunk(x, c), *(chunk(a, c) for a in per_token))
+        put = lambda whole, part: lax.dynamic_update_slice_in_dim(
+            whole, part, c * tokens, axis=1)
+        return (x if xc is None else put(x, xc),
+                jax.tree_util.tree_map(put, extras, ex))
+
+    chunks = S // tokens if live is None else \
+        jnp.clip((live + tokens - 1) // tokens, 0, S // tokens)
+    return lax.fori_loop(0, chunks, one, (x, extras))
 
 
 def chunk_rows(z, B, S):

@@ -147,39 +147,9 @@ def _softmax_route(z, p, x):
 
 
 def _experts(z, w, l, x, route, valid):
-    """x + layer ``l``'s held experts' part for the routed tokens; also
-    `held_experts_ffn`'s counts for that layer.
-
-    The grouped product is given the experts' stacks whole, as ``L * n``
-    groups of which only layer ``l``'s ``n`` can be chosen (an expert
-    held here becomes group ``l * n + e``, any other none): a layer's
-    slice of a stack would be copied for the product's custom call, 151
-    MB a layer and a decode step at Keye-VL-2.0's sizes (2.8 ms of a
-    9.4 ms step on the v5e), while empty groups cost nothing."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ...ops import moe
-
-    B, S, C = x.shape
-    u, chosen, weights = route
-    k = z.experts_per_token
-    lo, n = z.experts_held
-    w13, w2 = w["experts_gate_up_weight"], w["experts_down_weight"]
-    with jax.named_scope("serve.moe.experts"):
-        local = chosen - lo
-        group = jnp.where((local >= 0) & (local < n), local + l * n, -1)
-        y, stats = moe.held_experts_ffn(
-            u.reshape(B * S, C), group.reshape(B * S, k),
-            weights.reshape(B * S, k),
-            w13.reshape((-1,) + w13.shape[2:]),
-            w2.reshape((-1,) + w2.shape[2:]),
-            valid=None if valid is None else valid.reshape(B * S),
-            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C))
-        stats = jnp.concatenate([lax.dynamic_slice(stats, (l * n,), (n,)),
-                                 stats[-1:]])
-        return y.reshape(B, S, C), stats
+    return _ops.experts_of_layer(z, w["experts_gate_up_weight"],
+                                 w["experts_down_weight"], l, x, route,
+                                 valid)
 
 
 def _block_layer(z, p, x, pos, last):

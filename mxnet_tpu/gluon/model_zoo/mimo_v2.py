@@ -198,11 +198,12 @@ def _sink(z, kind, p):
 def _dense(z, p, x):
     import jax
 
+    from ...ops import moe
+
     with jax.named_scope("serve.mlp"):
         u = _ops.rms_norm(x, p["ln2_gamma"], z.eps)
-        h = jax.nn.silu(_ops.mm("bsc,fc->bsf", u, p["gate_weight"])) \
-            * _ops.mm("bsc,fc->bsf", u, p["up_weight"])
-        return x + _ops.mm("bsf,cf->bsc", h, p["down_weight"])
+        return x + moe.swiglu_ffn(u, p["gate_weight"], p["up_weight"],
+                                  p["down_weight"])
 
 
 def _experts(z, p, x, route, valid):

@@ -8,8 +8,11 @@ heads a key head, scaled) over layer ``l``'s positions ``< lengths[b]``,
 optionally only those a selection ``mask (B, W)`` keeps (Keye-VL-2.0)
 and with a ``sink (K, G)`` in the softmax's maximum and denominator
 (MiMo-V2's window layers), and returns ``(B, K, G, Dv)`` float32; a row
-that sees no position comes out zero.  Two paths, chosen on what the
-call can see:
+that sees no position comes out zero.  Where there is no stack of
+values (``cv`` None, ``leading = Dv``) the values are the first ``Dv``
+rows of each key: Kimi-K2's latent stack ``(L, B, 1, 576, W)``, whose
+512-wide latent is key and value to all 64 query heads; a block is then
+read once.  Two paths, chosen on what the call can see:
 
 - **kernel** (a TPU, no mesh, a window of more than one lane block):
   one Pallas call, grid over the rows, the stacks left where they are
@@ -17,9 +20,10 @@ call can see:
   walks its lane blocks ``0 .. ceil(lengths[b] / lanes) - 1`` and none
   beyond (an empty row its first, all of it masked): each block's
   ``(K, D, lanes)`` keys and ``(K, Dv, lanes)`` values come in by two
-  asynchronous copies into one of two buffers while the block before is
-  worked, and a row's last block starts the next row's first, so the
-  copies never wait for a grid step.  A running softmax in float32
+  asynchronous copies (one, where the values are rows of the keys) into
+  one of two buffers while the block before is worked, and a row's last
+  block starts the next row's first, so the copies never wait for a
+  grid step.  A running softmax in float32
   (maximum and denominator in scratch, the accumulator in the output
   block), all heads of a block in one batched product on the MXU in the
   stacks' type.  Heads narrower than a 128-deep tile are worked ``r``
@@ -54,13 +58,15 @@ _BLOCK_COST_BYTES = 240 * 1024
 
 
 def block_lanes(ck, cv):
-    """Positions a block.  A row of ``n`` positions costs ``n / lanes``
-    blocks and reads ``lanes / 2`` positions past its length; taking a
+    """Positions a block (``cv`` None: a position's bytes are its
+    key's).  A row of ``n`` positions costs ``n / lanes`` blocks and
+    reads ``lanes / 2`` positions past its length; taking a
     row to hold a quarter of its window, the two balance at
     ``sqrt(W / 2 * block cost / a position's bytes)``.  The power-of-two
     multiple of 128 under that which divides W."""
     K, D, W = ck.shape[2:]
-    a_position = K * (D + cv.shape[3]) * ck.dtype.itemsize
+    a_position = K * (D + (0 if cv is None else cv.shape[3])) \
+        * ck.dtype.itemsize
     best = (W / 2 * _BLOCK_COST_BYTES / a_position) ** 0.5
     lanes = _LANE
     while lanes * 2 <= best and W % (lanes * 2) == 0:
@@ -69,21 +75,24 @@ def block_lanes(ck, cv):
 
 
 def attend_rows(q, ck, cv, l, lengths, mask=None, sink=None, mesh=None,
-                tally=None):
+                tally=None, leading=None):
     """``q`` (B, K, G, D) scaled; ``ck`` (L, B, K, D, W); ``cv``
-    (L, B, K, Dv, W); ``l`` the layer, an int or a traced scalar;
-    ``lengths`` (B,) int32; ``mask`` (B, W) bool or None; ``sink``
-    (K, G) or None.  Returns (B, K, G, Dv) float32."""
+    (L, B, K, Dv, W), or None with ``leading = Dv`` for values that are
+    the keys' first ``Dv`` rows; ``l`` the layer, an int or a traced
+    scalar; ``lengths`` (B,) int32; ``mask`` (B, W) bool or None;
+    ``sink`` (K, G) or None.  Returns (B, K, G, Dv) float32."""
     W = ck.shape[-1]
     kernel = mesh is None and _on_tpu() and W > _LANE and W % _LANE == 0
     lanes = block_lanes(ck, cv) if kernel else W
     if tally is not None:
         tally[("kernel" if kernel else "xla", W, lanes)] += 1
     if kernel:
-        return _attend_kernel(q, ck, cv, l, lengths, mask, sink, lanes)
-    return _attend_xla(q, lax.dynamic_index_in_dim(ck, l, 0, keepdims=False),
-                       lax.dynamic_index_in_dim(cv, l, 0, keepdims=False),
-                       lengths, mask, sink)
+        return _attend_kernel(q, ck, cv, l, lengths, mask, sink, lanes,
+                              leading=leading)
+    ck = lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)
+    cv = ck[:, :, :leading] if cv is None else \
+        lax.dynamic_index_in_dim(cv, l, 0, keepdims=False)
+    return _attend_xla(q, ck, cv, lengths, mask, sink)
 
 
 def _attend_xla(q, ck, cv, lengths, mask, sink):
@@ -118,32 +127,38 @@ def heads_a_tile(K, D, Dv):
     return r
 
 
-def _kernel(l_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, lanes, masked,
-            sunk):
+def _kernel(l_ref, len_ref, q_ref, k_hbm, *refs, lanes, masked, sunk,
+            leading):
     """A grid step: one row.  q (Kp, rows, Dp); the stacks whole, in
-    HBM; then the row's mask (1, W) and the sink (Kp, rows, 1), where
-    given; the output block (Kp, rows, Dvp), which is the accumulator;
-    scratch: two buffers of keys (2, Kp, Dp, lanes) and of values, their
-    copies' semaphores (stack, buffer), the running maximum and
-    denominator (Kp, rows, 1), and which buffer the row's first block
-    is in."""
+    HBM (the values' only where ``leading`` is None); then the row's
+    mask (1, W) and the sink (Kp, rows, 1), where given; the output
+    block (Kp, rows, Dvp), which is the accumulator; scratch: two
+    buffers of keys (2, Kp, Dp, lanes) and of values (none where the
+    values are the keys' first ``leading`` rows), their copies'
+    semaphores (stack, buffer), the running maximum and denominator
+    (Kp, rows, 1), and which buffer the row's first block is in."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     refs = list(refs)
+    v_hbm = refs.pop(0) if leading is None else None
     mask_ref = refs.pop(0) if masked else None
     sink_ref = refs.pop(0) if sunk else None
-    o_ref, k_buf, v_buf, sem, m_scr, l_scr, first = refs
+    o_ref, k_buf = refs.pop(0), refs.pop(0)
+    v_buf = refs.pop(0) if leading is None else None
+    sem, m_scr, l_scr, first = refs
     b, l = pl.program_id(0), l_ref[0]
     n = len_ref[b]
     blocks = jnp.maximum(pl.cdiv(n, lanes), 1)
 
     def copies(row, j, slot):
         at = pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
-        return (pltpu.make_async_copy(k_hbm.at[l, row, :, :, at],
-                                      k_buf.at[slot], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[l, row, :, :, at],
-                                      v_buf.at[slot], sem.at[1, slot]))
+        keys = pltpu.make_async_copy(k_hbm.at[l, row, :, :, at],
+                                     k_buf.at[slot], sem.at[0, slot])
+        if v_hbm is None:
+            return (keys,)
+        return (keys, pltpu.make_async_copy(v_hbm.at[l, row, :, :, at],
+                                            v_buf.at[slot], sem.at[1, slot]))
 
     @pl.when(b == 0)
     def _open():
@@ -192,8 +207,9 @@ def _kernel(l_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, lanes, masked,
         alpha = jnp.exp(m_prev - m_next)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=2, keepdims=True)
         m_scr[...] = m_next
+        v = k_buf[slot, :, :leading] if v_buf is None else v_buf[slot]
         o_ref[...] = alpha * o_ref[...] + jnp.einsum(
-            "kgn,kdn->kgd", p.astype(v_buf.dtype), v_buf[slot],
+            "kgn,kdn->kgd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         return carry
 
@@ -204,13 +220,17 @@ def _kernel(l_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, lanes, masked,
 
 
 def _attend_kernel(q, ck, cv, l, lengths, mask, sink, lanes,
-                   interpret=False):
+                   interpret=False, leading=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, K, G, D = q.shape
-    L, _, _, Dv, W = cv.shape
-    r = heads_a_tile(K, D, Dv)
+    L, W = ck.shape[0], ck.shape[-1]
+    if cv is not None:
+        leading = None
+    Dv = leading or cv.shape[3]
+    # values that are rows of their keys lie head by head as the keys do
+    r = 1 if cv is None else heads_a_tile(K, D, Dv)
     Kp, Gp, Dp, Dvp = K // r, r * G, r * D, r * Dv
     rows = -(-Gp // 16) * 16        # a whole tile of the stacks' type
     # r heads side by side: head j's query in columns j * D .. of row
@@ -224,8 +244,13 @@ def _attend_kernel(q, ck, cv, l, lengths, mask, sink, lanes,
         return pl.BlockSpec((None,) + block, lambda b, l, n: (b, 0, 0, 0))
 
     in_place = pl.BlockSpec(memory_space=pl.ANY)
-    specs = [of_row(Kp, rows, Dp), in_place, in_place]
-    args = [qp, ck.reshape(L, B, Kp, Dp, W), cv.reshape(L, B, Kp, Dvp, W)]
+    specs = [of_row(Kp, rows, Dp), in_place]
+    args = [qp, ck.reshape(L, B, Kp, Dp, W)]
+    scratch = [pltpu.VMEM((2, Kp, Dp, lanes), ck.dtype)]
+    if cv is not None:
+        specs.append(in_place)
+        args.append(cv.reshape(L, B, Kp, Dvp, W))
+        scratch.append(pltpu.VMEM((2, Kp, Dvp, lanes), cv.dtype))
     if mask is not None:
         specs.append(pl.BlockSpec((None, 1, W), lambda b, l, n: (b, 0, 0)))
         args.append(mask.astype(jnp.int32)[:, None, :])
@@ -238,12 +263,11 @@ def _attend_kernel(q, ck, cv, l, lengths, mask, sink, lanes,
         dimension_semantics=("arbitrary",))}
     out = pl.pallas_call(
         functools.partial(_kernel, lanes=lanes, masked=mask is not None,
-                          sunk=sink is not None),
+                          sunk=sink is not None, leading=leading),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,), in_specs=specs,
             out_specs=of_row(Kp, rows, Dvp),
-            scratch_shapes=[pltpu.VMEM((2, Kp, Dp, lanes), ck.dtype),
-                            pltpu.VMEM((2, Kp, Dvp, lanes), cv.dtype),
+            scratch_shapes=scratch + [
                             pltpu.SemaphoreType.DMA((2, 2)),
                             pltpu.VMEM((Kp, rows, 1), jnp.float32),
                             pltpu.VMEM((Kp, rows, 1), jnp.float32),

@@ -8,7 +8,8 @@ buffer as it lies).  A call takes ``n`` stacks that share ``L``, ``B``
 and ``W`` and may each have their own head count ``K`` and width ``D``:
 GPT's keys and values ``(16, 64)``; MiMo's ``(4, 192)`` beside
 ``(4, 128)``; Keye-VL-2.0's keys and values ``(4, 128)`` beside its
-indexer's keys ``(1, 64)``.  ``write_rows`` puts row b's new
+indexer's keys ``(1, 64)``; Kimi-K2's one latent stack ``(1, 576)``.
+``write_rows`` puts row b's new
 ``(K, D, S)`` block of layer ``l`` of each stack at positions
 ``starts[b] .. starts[b] + S`` and touches nothing else.  Two paths,
 chosen on what the call can see:
@@ -66,29 +67,32 @@ def _on_tpu():
     return jax.default_backend() == "tpu"
 
 
-def write_rows(stacks, news, l, starts, mesh=None, tally=None):
+def write_rows(stacks, news, l, starts, mesh=None, tally=None, row=None):
     """``stacks``: ``n`` arrays ``(L, B, K_i, D_i, W)``; ``news``: as
-    many arrays ``(B, K_i, D_i, S)``; ``l``: the layer, an int or a
-    traced scalar; ``starts`` (B,) int32.  Returns the stacks, written."""
-    B, S = news[0].shape[0], news[0].shape[-1]
+    many arrays ``(R, K_i, D_i, S)``; ``l``: the layer, an int or a
+    traced scalar; ``starts`` (R,) int32; ``row``: the stacks' row that
+    the first new row goes to, an int or a traced scalar (a prefill that
+    works its rows off a few at a time), None for ``R == B`` rows from
+    the first.  Returns the stacks, written."""
+    R, S = news[0].shape[0], news[0].shape[-1]
     news = tuple(n.astype(c.dtype) for c, n in zip(stacks, news))
-    kernel = S == 1 and mesh is None and _on_tpu()
+    kernel = S == 1 and mesh is None and row is None and _on_tpu()
     if tally is not None:
-        tally["kernel" if kernel else "rows"] += B * len(stacks)
+        tally["kernel" if kernel else "rows"] += R * len(stacks)
     if kernel:
         return _write_kernel(tuple(stacks), news, l, starts)
-    return tuple(_write_by_rows(c, n, l, starts)
+    return tuple(_write_by_rows(c, n, l, starts, 0 if row is None else row)
                  for c, n in zip(stacks, news))
 
 
-def _write_by_rows(c, new, l, starts):
+def _write_by_rows(c, new, l, starts, row=0):
     """One dynamic_update_slice a row, each at that row's own offset (a
     start that would run past W is clamped by the operation)."""
     zero = jnp.int32(0)
     for b in range(new.shape[0]):
         c = lax.dynamic_update_slice(
             c, new[b][None, None],
-            (jnp.int32(l), jnp.int32(b), zero, zero, starts[b]))
+            (jnp.int32(l), jnp.int32(row + b), zero, zero, starts[b]))
     return c
 
 

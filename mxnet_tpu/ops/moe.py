@@ -141,20 +141,22 @@ def share_pass_rows(tokens, k, held):
     return min(tokens * min(k, held), max(256, tokens // 4))
 
 
-def sigmoid_topk_route(x, router_weight, router_bias, k):
+def sigmoid_topk_route(x, router_weight, router_bias, k, scale=1.0):
     """x (T, M) → (chosen (T, k) int32, weights (T, k) float32).
 
     Scores are ``sigmoid(x Wrᵀ)`` in float32; the chosen experts are the
     top ``k`` of score + ``router_bias`` (the aux-loss-free correction
     bias: it moves the choice, not the weight); weights are the chosen
-    scores, normalised to sum to one."""
+    scores, normalised to sum to ``scale`` (the routed scaling
+    factor)."""
     logits = jnp.einsum("tm,em->te", x.astype(jnp.float32),
                         router_weight.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     score = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(score + router_bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(score, chosen, axis=-1)
-    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), w if scale == 1.0 else w * scale
 
 
 def softmax_topk_route(x, router_weight, k):
@@ -224,19 +226,41 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
     return y, jnp.concatenate([counts, (passes * P)[None]])
 
 
+def swiglu_ffn(x, gate, up, down):
+    """``W2 (silu(W1 x) ⊙ W3 x)`` for x (.., M) with gate, up (F, M) and
+    down (M, F), float32, the activations in the weights' type: a dense
+    feed-forward, and the **shared expert** that every token goes
+    through whatever the router says.  Every chip of an expert-parallel
+    deployment computes that one alike for its own tokens, so it is
+    counted **once** when the chips' shares of a layer are summed."""
+    def mm(spec, a, w):
+        return jnp.einsum(spec, a.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(mm("...m,fm->...f", x, gate)) \
+        * mm("...m,fm->...f", x, up)
+    return mm("...f,mf->...m", h, down)
+
+
 @register("moe_share_ffn")
 def moe_share_ffn(data, router_weight, router_bias, w13, w2, k=8,
-                  experts_lo=0, pass_rows=None, output_stats=False):
+                  experts_lo=0, pass_rows=None, output_stats=False,
+                  scale=1.0, shared=None):
     """A chip's share of a sigmoid-routed, dropless expert layer.
 
     data (..., M); router_weight (E, M) over ALL E experts;
     router_bias (E,); w13 (n, M, 2F) and w2 (n, F, M): the n experts
-    from ``experts_lo`` that this chip holds.  Returns what those
-    experts add (float32, data's shape); with ``output_stats`` also
+    from ``experts_lo`` that this chip holds; ``scale`` the routed
+    scaling factor; ``shared`` the shared expert's (gate, up, down) or
+    None.  Returns what those experts add, the shared one included
+    (float32, data's shape); with ``output_stats`` also
     `held_experts_ffn`'s counts."""
     x = data.reshape(-1, data.shape[-1])
-    chosen, weights = sigmoid_topk_route(x, router_weight, router_bias, k)
+    chosen, weights = sigmoid_topk_route(x, router_weight, router_bias, k,
+                                         scale)
     y, stats = held_experts_ffn(x, chosen, weights, w13, w2,
                                 experts_lo=experts_lo, pass_rows=pass_rows)
+    if shared is not None:
+        y = y + swiglu_ffn(x, *shared)
     y = y.reshape(data.shape)
     return (y, stats) if output_stats else y

@@ -30,10 +30,10 @@ TOL = {"float32": 2e-6, "bfloat16": 4e-3}
 
 
 def _case(name, dtype, lengths, seed=0, L=3, lanes=128):
-    """Stacks whose every position past a row's last block is NaN, and
+    """(``name``: one of SHAPES, or such a tuple.)  Stacks whose every position past a row's last block is NaN, and
     what lies between the row's length and its block's end is large:
     the first must never be read, the second never weigh."""
-    K, G, D, Dv, W, masked, sunk = SHAPES[name]
+    K, G, D, Dv, W, masked, sunk = SHAPES.get(name, name)
     B = len(lengths)
     n = np.asarray([W if x is None else x for x in lengths], np.int32)
     rng = np.random.RandomState(seed)
@@ -153,7 +153,8 @@ def test_attend_rows_picks_its_path_on_what_it_sees(monkeypatch, W, mesh,
     took = []
     monkeypatch.setattr(
         cache_attention, "_attend_kernel",
-        lambda q, ck, cv, l, n, mask, sink, lanes: took.append(lanes)
+        lambda q, ck, cv, l, n, mask, sink, lanes, leading=None:
+        took.append(lanes)
         or jnp.zeros(q.shape[:3] + cv.shape[3:4]))
     tally = collections.Counter()
     out = cache_attention.attend_rows(q, ck, cv, 1, n, mesh=mesh, tally=tally)
@@ -167,6 +168,50 @@ def test_attend_rows_picks_its_path_on_what_it_sees(monkeypatch, W, mesh,
             np.asarray(out),
             np.asarray(cache_attention._attend_xla(q, ck[1], cv[1], n, None,
                                                    None)), atol=1e-6)
+
+
+# -- values that are the leading rows of their keys -----------------------------
+#
+# Kimi-K2's latent stack: one head (K 1) that 64 query heads share, 576
+# rows a position of which the first 512 are also the values (4.5 lane
+# tiles: not a multiple of 128), and a small one of the same build.
+LATENT = {"kimi_latent": (64, 576, 512, 256), "small": (4, 24, 16, 384)}
+
+
+@pytest.mark.parametrize("lengths", list(LENGTHS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("name", list(LATENT))
+def test_values_that_are_the_keys_leading_rows(name, dtype, lengths):
+    """No stack of values: the kernel brings a block in once and takes
+    its first ``Dv`` rows for values; the plain path slices the same
+    rows.  Both equal the plain path given those rows as a stack of
+    their own."""
+    G, D, Dv, W = LATENT[name]
+    q, ck, _, n, _, _ = _case((1, G, D, D, W, False, False),
+                              jnp.dtype(dtype), LENGTHS[lengths][:3] + [W])
+    l = 2
+    got = jax.jit(lambda q, ck, n: cache_attention._attend_kernel(
+        q, ck, None, l, n, None, None, 128, interpret=True, leading=Dv))(
+            q, ck, n)
+    want = _plain(q, ck, ck[:, :, :, :Dv], l, n, None, None)
+    assert got.shape == want.shape == (4, 1, G, Dv)
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    clean = jnp.nan_to_num(ck.astype(jnp.float32)).astype(ck.dtype)
+    tally = collections.Counter()
+    plain = cache_attention.attend_rows(q, clean, None, l, n, leading=Dv,
+                                        tally=tally)
+    assert dict(tally) == {("xla", W, W): 1}
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
+
+
+def test_block_lanes_count_a_shared_position_once():
+    """8 x 16,384 positions of 576 rows read once: blocks of 1,024 (a
+    second stack of 512 rows would make them 512)."""
+    sds = lambda d: jax.ShapeDtypeStruct((5, 8, 1, d, 16384), jnp.bfloat16)
+    assert cache_attention.block_lanes(sds(576), None) == 1024
+    assert cache_attention.block_lanes(sds(576), sds(512)) == 512
 
 
 def test_the_plain_path_is_the_softmax_it_says():

@@ -25,6 +25,8 @@ SHAPES = {
     "narrow": (3, 4, (2, 2), 16, (8, 8)),   # a window under one lane block
     "keye": (3, 4, (2, 2, 1), 256, (16, 16, 8)),
     "one_head_beside_four": (2, 4, (4, 1), 128, (128, 64)),
+    # Kimi-K2's latent stack: one stack, no heads, 4.5 lane tiles wide
+    "kimi_latent": (2, 4, (1,), 256, (576,)),
 }
 
 
@@ -125,6 +127,44 @@ def test_write_rows_picks_its_path_on_what_it_sees(monkeypatch, S, mesh,
             assert (_bits(g)[0] == _bits(c)[0]).all()
 
 
+@pytest.mark.parametrize("name,row", [("kimi_latent", 2), ("keye", 1),
+                                      ("kimi_latent", None)])
+def test_a_prefill_writes_its_rows_at_a_row_offset(name, row):
+    """A prefill that works its rows off a few at a time: two rows'
+    blocks of 5 positions go to rows ``row, row + 1`` of the stacks
+    (traced, as a loop over row chunks hands it over; None: from the
+    first), each at its own position, and nothing else is touched."""
+    stacks, _, W = _case(name, jnp.float32)
+    rng = np.random.RandomState(1)
+    news = tuple(jnp.asarray(rng.randn(2, c.shape[2], c.shape[3], 5),
+                             c.dtype) for c in stacks)
+    pos = jnp.asarray([0, 9], jnp.int32)
+    tally = collections.Counter()
+    if row is None:
+        got = cache_write.write_rows(stacks, news, 1, pos, tally=tally)
+    else:
+        got = jax.jit(lambda s, n, p, r: cache_write.write_rows(
+            s, n, 1, p, tally=tally, row=r))(stacks, news, pos,
+                                             jnp.int32(row))
+    assert dict(tally) == {"rows": 2 * len(stacks)}
+    for g, c, n in zip(got, stacks, news):
+        want = np.array(_bits(c))
+        for b, p in enumerate(np.asarray(pos)):
+            want[1, (row or 0) + b, :, :, p:p + 5] = _bits(n)[b]
+        np.testing.assert_array_equal(_bits(g), want)
+
+
+def test_a_row_offset_keeps_a_decode_write_off_the_kernel(monkeypatch):
+    """The kernel's grid is the stacks' rows from the first."""
+    stacks, news, _ = _case("kimi_latent", jnp.float32)
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    tally = collections.Counter()
+    cache_write.write_rows(stacks, tuple(n[:2] for n in news), 0,
+                           jnp.asarray([3, 4], jnp.int32), tally=tally,
+                           row=1)
+    assert dict(tally) == {"rows": 2}
+
+
 # -- compiled for the chip, without the chip -----------------------------------
 
 @pytest.fixture(scope="module")
@@ -146,7 +186,8 @@ def one_chip():
     ("gpt2_medium", 24, 16, (16, 16), (64, 64), 1024),
     ("mimo_full", 2, 64, (4, 4), (192, 128), 2048),
     ("mimo_ring", 5, 64, (8, 8), (192, 128), 128),
-    ("keye_vl2", 6, 16, (4, 4, 1), (128, 128, 64), 16384)])
+    ("keye_vl2", 6, 16, (4, 4, 1), (128, 128, 64), 16384),
+    ("kimi_k2", 5, 8, (1,), (576,), 16384)])
 def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, Ks, Ds, W):
     """At the cells' real widths Mosaic takes the kernel (interpret mode
     cannot say), the stacks are aliased to their outputs, and the
@@ -170,7 +211,9 @@ def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, Ks, Ds, W):
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
     for i in range(len(Ds)):
-        assert f"{{{i}}}: ({i}, {{}}" in alias, alias
+        # a single stack is the program's whole output, not a tuple's part
+        out = f"{{{i}}}" if len(Ds) > 1 else "{}"
+        assert f"{out}: ({i}, {{}}" in alias, alias
     layer = B * min(K * D for K, D in zip(Ks, Ds)) * W * 2
     assert serving.whole_layer_ops(text, layer) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer
@@ -217,6 +260,39 @@ def test_attention_kernel_compiles_for_a_v5e(one_chip, name, L, B, K, G, D,
     assert serving.whole_layer_ops(text, layer) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer
     assert compiled.out_info.shape == (B, K, G, Dv)
+
+
+def test_the_latent_stacks_kernel_compiles_for_a_v5e(one_chip):
+    """`ops/cache_attention.py`'s kernel over Kimi-K2's latent stack at
+    the cell's real sizes (one head of 576 rows, 64 query heads, values
+    the first 512 rows, blocks of 1,024 positions): Mosaic takes the
+    4.5-tile contraction and the slice of the key buffer, there is no
+    second stack, and the program holds no temporary of a layer's size."""
+    from mxnet_tpu import serving
+    from mxnet_tpu.ops import cache_attention
+
+    L, B, G, D, Dv, W = 5, 8, 64, 576, 512, 16384
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, ck, n):
+        lanes = cache_attention.block_lanes(ck, None)
+
+        def body(acc, l):
+            return acc + cache_attention._attend_kernel(
+                q, ck, None, l, n, None, None, lanes, leading=Dv), None
+        return jax.lax.scan(body, jnp.zeros((B, 1, G, Dv), jnp.float32),
+                            jnp.arange(L, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(step).lower(sds((B, 1, G, D)), sds((L, B, 1, D, W)),
+                                   sds((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer = B * Dv * W * 2
+    assert serving.whole_layer_ops(text, layer) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer
+    assert compiled.out_info.shape == (B, 1, G, Dv)
 
 
 @pytest.mark.parametrize("kernel", ["select", "attend"])

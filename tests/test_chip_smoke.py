@@ -112,3 +112,25 @@ def test_serve_keye_phase():
                                prompt_lens=(3, 8, 21, 40), new_tokens=7)
     out = chip_smoke.phase_serve_keye(size, "cpu")
     assert out["retraces"] == 0 and out["programs"] == 2
+
+
+def test_serve_kimi_phase():
+    """The fourth family's phase at a tiny size: the engine's tuple is
+    the parameters' own buffers, the one latent stack stays where it is,
+    the positions attended to are counted, coalesced == alone (two rows
+    a prefill chunk), a repeat is identical."""
+    small = chip_smoke.kimi_small()
+    assert small.kwargs["kv_rank"] + small.kwargs["rope_dim"] == 320 \
+        and small.prefill_floor == small.kwargs["max_length"]
+    kwargs = dict(vocab_size=96, units=64, num_layers=3, num_heads=4,
+                  q_rank=24, kv_rank=16, nope_dim=16, rope_dim=8, v_dim=12,
+                  hidden_size=96, expert_hidden=32, router_experts=8,
+                  experts_per_token=2, experts_held=[2, 4], route_scale=2.5,
+                  rope_factor=8.0, rope_original_length=8, mscale=1.0,
+                  mscale_all_dim=1.0, max_length=64, attn_block=16,
+                  token_chunk=16, prefill_chunk_tokens=128, grad_req="null")
+    assert set(kwargs) <= set(small.kwargs)
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(3, 8, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_kimi(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
