@@ -5,25 +5,42 @@ contrib/transformer.cu + cuDNN; here the TPU version is a blockwise
 online-softmax kernel (Flash-Attention-2) so neither the (Tq × Tk) score
 matrix nor the whole K/V sequence is ever resident:
 
-- grid (batch·heads, q blocks, kv blocks): K and V stream through VMEM
-  one (block_k, D) tile per grid step — per-step VMEM is bounded by the
-  block sizes and INDEPENDENT of sequence length (long-context safe);
-- the score block Q·Kᵀ runs on the MXU with f32 accumulation;
-- m/l/o accumulators live in VMEM scratch, carried across the kv grid
-  dimension ("arbitrary" semantics); outputs store on the last kv step;
-- m/l are kept lane-replicated (block_q, 128) in VMEM so the
-  online-softmax update is pure elementwise VPU work — the same layout
-  trick the production TPU kernels use; the logsumexp persisted to HBM
-  for the backward is stored TRANSPOSED, (B·H, 8, T): the sequence on
-  the lane axis, 8 sublane copies.  HBM tiles are (8, 128), so this
-  costs 8·T floats per head, where a (T, 8) layout is padded to
-  (T, 128) — 16× (measured on the v5e: 1.05 GB of padding for BERT-base
-  at b32/T512, which alone pushed that step past 16 GB);
-- causal q/kv block pairs above the diagonal skip all compute (pl.when);
-- backward is the FlashAttention-2 recipe: recompute p = exp(s − L) per
-  tile; dq accumulates over the kv grid, dk/dv over the q grid; D_i =
-  rowsum(dO ∘ O) is computed in-kernel from the O/dO tiles (never
-  materialized in HBM).
+- grid (batch·heads, q blocks, kv blocks) of COARSE blocks (up to 1,024
+  positions, `_block_sizes`): a grid step costs some 0.35 us whatever it
+  holds, so at T <= 1,024 a head is one step; K and V stream through
+  VMEM one block per step, so per-step VMEM is bounded by the block
+  sizes and independent of sequence length (long-context safe), but
+  for the backward's dq (below);
+- inside a step the kernel walks sub-tiles (`_sub_tiles`) in
+  ``fori_loop``s: for each query sub-tile the key sub-tiles up to the
+  diagonal, unmasked where they lie wholly below it and masked only
+  where it crosses them; none above it.  A grid step wholly above the
+  diagonal (only where a head is several blocks) walks nothing; its
+  block copies hide behind the step before (index maps clamped to the
+  resident block measured no faster);
+- every product is fed the operands as they arrive (bfloat16 inputs go
+  into the MXU as bfloat16, float32 as float32) and accumulates in
+  float32; scores, the softmax, its running maximum and sum, the
+  logsumexp, delta and all accumulators are float32;
+- forward: m/l are kept lane-replicated (block_q, 128) in VMEM scratch
+  with the output accumulator, carried across the kv grid dimension
+  ("arbitrary" semantics); outputs store on the last kv step.  The
+  logsumexp persisted to HBM for the backward is stored TRANSPOSED,
+  (B·H, 8, T): the sequence on the lane axis, 8 sublane copies.  HBM
+  tiles are (8, 128), so this costs 8·T floats per head, where a
+  (T, 8) layout is padded to (T, 128) — 16× (measured on the v5e:
+  1.05 GB of padding for BERT-base at b32/T512, which alone pushed
+  that step past 16 GB);
+- backward is the FlashAttention-2 recipe in ONE call: per sub-tile one
+  s, p = exp(s − L), dp, ds and from them dv, dk and dq — five
+  products and one set of exponentials.  D_i = rowsum(dO ∘ O) is formed
+  once by XLA, in float32, in the logsumexp's layout.  The scores are
+  held transposed (keys on sublanes, queries on lanes), so the stored
+  logsumexp and delta rows broadcast as they are.  Key blocks are the
+  outer grid axis: dk and dv of a block accumulate in scratch over the
+  query blocks; dq of the WHOLE head accumulates in float32 VMEM
+  scratch and is stored on the head's last step (T·D·(4 + 2·itemsize)
+  bytes: past the default scoped VMEM the call asks for its own limit).
 
 On the CPU (tests, the virtual mesh) the kernels run in interpret mode,
 keeping one code path.  On TPU they compile through Mosaic, which needs
@@ -53,17 +70,71 @@ def _use_interpret():
     return backend == "cpu"
 
 
-def _block_sizes(T):
-    if T % _LANE == 0:
-        # bq capped at 256: the dq backward's f32 working set at bq=512
-        # (dq scratch + (bq,bk) intermediates + double-buffered operand
-        # blocks) blows the ~16MB scoped-VMEM budget at BERT shapes
-        # (measured: b32·h12·T512·D64 fails to compile at 512, fits at
-        # 256)
-        bq = 256 if T % 256 == 0 else _LANE
-        return min(bq, T), _LANE
-    # interpret-mode small/odd shapes; flash_attention refuses them on TPU
-    return T, T
+# The grid's blocks and the sub-tiles a grid step walks (PR 33; measured
+# on a v5e, jax 0.9.0 / libtpu 0.0.34; PERF.md section 6 has the table).
+_MAX_BLOCK = 1024
+# what a grid step's blocks may take of the 16 MiB of scoped VMEM that
+# Mosaic grants a kernel by default, and the need past which a call
+# asks for its own limit (a long head's dq, which the backward keeps
+# whole, is what goes past it: 128 MiB are there)
+_VMEM_BUDGET = 12 << 20
+_VMEM_DEFAULT = 14 << 20
+_SUB_TILE = 512
+
+
+def _aligned_divisors(n, most):
+    """The lane-aligned divisors of ``n`` up to ``most``, largest first."""
+    return [d for d in range(min(n, most) // _LANE * _LANE, 0, -_LANE)
+            if n % d == 0]
+
+
+def _sub_tiles(block_q, block_k):
+    """The (queries, keys) sub-tile a grid step's loops work on, forward
+    and backward: the largest lane-aligned size up to 512 that divides
+    the block (512 x 512 measured best for both kernels), or the block
+    itself where it has none (interpret mode)."""
+    return tuple((_aligned_divisors(b, _SUB_TILE) or [b])[0]
+                 for b in (block_q, block_k))
+
+
+def _vmem_bytes(T, D, dtype, kernel, block_q, block_k):
+    """(what a grid step's blocks take of VMEM, what stays for a whole
+    head): every operand block twice (the pipeline's double buffer),
+    the blocks' float32 accumulators and a sub-tile's float32
+    temporaries (three of them, fitted to what Mosaic accepted at
+    `T` 4,096-16,384, `D` 64-256, both types); for the backward the
+    head's dq besides, its output block twice and its accumulator."""
+    item = jnp.dtype(dtype).itemsize
+    sq, sk = _sub_tiles(block_q, block_k)
+    qb, kb = block_q * D * item, block_k * D * item
+    rows = _LSE_ROWS * block_q * 4
+    tile = 3 * sq * sk * 4
+    if kernel == "fwd":
+        blocks = 2 * qb + 2 * kb + rows             # q, o; k, v; lse
+        scratch = 2 * block_q * _LANE * 4 + block_q * D * 4
+        return 2 * blocks + scratch + tile, 0
+    blocks = 2 * qb + 2 * rows + 4 * kb     # q, g; lse, delta; k, v, dk, dv
+    scratch = 2 * block_k * D * 4
+    return 2 * blocks + scratch + tile, 2 * T * D * item + T * D * 4
+
+
+def _block_sizes(T, D, dtype, kernel):
+    """``(block_q, block_k)`` of the grid for ``kernel`` ("fwd" or
+    "bwd"), from what the kernel sees.
+
+    The rule: the largest lane-aligned block up to 1,024 that divides
+    ``T`` and whose grid step fits `_VMEM_BUDGET`, the same for queries
+    and keys.  A grid step costs some 0.35 us whatever it holds and its
+    copies hide behind the step before, so the grid is as coarse as
+    VMEM allows; inside a step the kernels walk `_sub_tiles` up to the
+    diagonal, so a coarse block spends no work above it."""
+    if T % _LANE:
+        # interpret-mode small/odd shapes; flash_attention refuses them
+        # on TPU
+        return T, T
+    fits = [n for n in _aligned_divisors(T, _MAX_BLOCK)
+            if _vmem_bytes(T, D, dtype, kernel, n, n)[0] <= _VMEM_BUDGET]
+    return (fits or [_LANE])[0], (fits or [_LANE])[0]
 
 
 # sublane copies of the logsumexp row persisted to HBM between fwd and
@@ -75,12 +146,6 @@ def _lse_to_rows(lse):
     """kernel working layout (bq, 128), lane-replicated → the stored
     (8, bq) block with the sequence on lanes."""
     return lse.T[:_LSE_ROWS]
-
-
-def _lse_from_rows(rows, n):
-    """stored (8, bq) block → (bq, n), lane-replicated."""
-    bq = rows.shape[1]
-    return _bcast_lanes(jnp.broadcast_to(rows[:1], (_LANE, bq)).T, n)
 
 
 def _bcast_lanes(x, n):
@@ -95,14 +160,45 @@ def _bcast_lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    """An MXU product of the operands as they are given (bfloat16 inputs
+    go in as bfloat16, float32 as float32), accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry):
+    """Run ``tile(c, carry, masked)`` over those of a block's ``n`` key
+    sub-tiles (``sub_k`` positions each, from ``k0``) that the ``sub_q``
+    queries from ``q0`` can see: unmasked while a sub-tile lies wholly
+    at or below the first query, masked while it holds any key at or
+    below the last one, and none above the diagonal."""
+    loop = jax.lax.fori_loop
+    if not causal:
+        return loop(0, n, lambda c, x: tile(c, x, False), carry)
+    div = jax.lax.div
+    whole = jnp.clip(div(jnp.maximum(q0 + 1 - k0, 0), sub_k), 0, n)
+    some = jnp.clip(div(jnp.maximum(q0 + sub_q - k0 + sub_k - 1, 0),
+                        sub_k), 0, n)
+    carry = loop(0, whole, lambda c, x: tile(c, x, False), carry)
+    return loop(whole, some, lambda c, x: tile(c, x, True), carry)
+
+
 # -- forward -------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, scale, causal, block_q, block_k, nk):
+                acc_scr, *, scale, causal, block_q, block_k, sub_q, sub_k,
+                nk):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    D = acc_scr.shape[1]
 
     @pl.when(kj == 0)
     def _init():
@@ -110,44 +206,43 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def _run():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, _NEG)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_curr = jnp.max(s, axis=1)[:, None]          # (bq, 1)
-        m_next = jnp.maximum(m_prev, m_curr)          # (bq, 128)
-        p = jnp.exp(s - _bcast_lanes(m_next, s.shape[1]))
-        p = jnp.where(s <= _NEG / 2, 0.0, p)
-        alpha = jnp.exp(m_prev - m_next)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-        m_scr[...] = m_next
-        v = v_ref[0]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        D = acc_scr.shape[1]
-        acc_scr[...] = acc_scr[...] * _bcast_lanes(alpha, D) + pv
+    def q_tile(a, carry):
+        rows = pl.ds(pl.multiple_of(a * sub_q, sub_q), sub_q)
+        q0 = qi * block_q + a * sub_q
+        q = q_ref[0, rows, :]
 
-    if causal:
-        pl.when(kj * block_k <= (qi + 1) * block_q - 1)(_run)
-    else:
-        _run()
+        def tile(c, carry, masked):
+            keys = pl.ds(pl.multiple_of(c * sub_k, sub_k), sub_k)
+            s = _dot(q, k_ref[0, keys, :], _NT) * scale   # (sub_q, sub_k)
+            if masked:
+                # query q0 + i sees key k0 + j iff i - j >= k0 - q0; a
+                # row of a visited sub-tile always holds a visible key
+                # or has seen key 0 before, so exp(_NEG - m) is 0.0
+                i_j = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                       - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                s = jnp.where(
+                    i_j >= kj * block_k + c * sub_k - q0, s, _NEG)
+            m_prev = m_scr[rows, :]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+            p = jnp.exp(s - _bcast_lanes(m_next, sub_k))
+            alpha = jnp.exp(m_prev - m_next)
+            l_scr[rows, :] = (alpha * l_scr[rows, :]
+                              + jnp.sum(p, axis=1)[:, None])
+            m_scr[rows, :] = m_next
+            v = v_ref[0, keys, :]
+            acc_scr[rows, :] = (acc_scr[rows, :] * _bcast_lanes(alpha, D)
+                                + _dot(p.astype(v.dtype), v, _NN))
+            return carry
+
+        return _walk(causal, q0, sub_q, kj * block_k, sub_k,
+                     block_k // sub_k, tile, carry)
+
+    jax.lax.fori_loop(0, block_q // sub_q, q_tile, 0)
 
     @pl.when(kj == nk - 1)
     def _store():
         l = l_scr[...]
         lsafe = jnp.where(l == 0.0, 1.0, l)
-        D = acc_scr.shape[1]
         o_ref[0] = (acc_scr[...] / _bcast_lanes(lsafe, D)).astype(
             o_ref.dtype)
         lse_ref[0] = _lse_to_rows(m_scr[...] + jnp.log(lsafe))
@@ -161,11 +256,36 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _flash_call(q, k, v, causal, scale, block_q, block_k, vma=None):
+def _blocks(T, D, dtype, kernel, block_q, block_k):
+    """The grid's blocks for ``kernel``: the caller's where it names
+    them, `_block_sizes`' otherwise; they must divide ``T``."""
+    dbq, dbk = _block_sizes(T, D, dtype, kernel)
+    bq, bk = int(block_q or dbq), int(block_k or dbk)
+    if T % bq or T % bk:
+        raise ValueError(
+            f"flash_attention: block sizes ({bq}, {bk}) must divide "
+            f"sequence length {T} (a non-dividing block would silently "
+            f"leave tail blocks unwritten)")
+    return bq, bk
+
+
+def _compiler_params(T, D, dtype, kernel, block_q, block_k, semantics):
+    from jax.experimental.pallas import tpu as pltpu
+
+    need = sum(_vmem_bytes(T, D, dtype, kernel, block_q, block_k))
+    limit = {} if need <= _VMEM_DEFAULT else {
+        "vmem_limit_bytes": need + need // 4}
+    return pltpu.CompilerParams(dimension_semantics=semantics, **limit)
+
+
+def _flash_call(q, k, v, causal, scale, block_q=None, block_k=None,
+                vma=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
+    block_q, block_k = _blocks(T, D, q.dtype, "fwd", block_q, block_k)
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * H, T, D)
     vr = v.reshape(B * H, T, D)
@@ -173,10 +293,12 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, vma=None):
     interpret = _use_interpret()
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk)
+        block_k=block_k, sub_q=sub_q, sub_k=sub_k, nk=nk)
     kw = {} if interpret else {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+        "compiler_params": _compiler_params(
+            T, D, q.dtype, "fwd", block_q, block_k,
+            ("parallel", "parallel", "arbitrary"))}
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -205,173 +327,123 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, vma=None):
     return out.reshape(B, H, T, D), lse
 
 
-# -- backward (FlashAttention-2) -----------------------------------------------
+# -- backward (FlashAttention-2, one call) -------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref,
-               acc_scr, delta_scr, *, scale, causal, block_q, block_k, nk):
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        o = o_ref[0].astype(jnp.float32)
-        delta_scr[...] = jnp.sum(g * o, axis=1)[:, None] * jnp.ones(
-            (1, _LANE), jnp.float32)
-
-    def _run():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, _NEG)
-        bk = s.shape[1]
-        p = jnp.exp(s - _lse_from_rows(lse_ref[0], bk))
-        p = jnp.where(s <= _NEG / 2, 0.0, p)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(                      # dO · Vᵀ
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _bcast_lanes(delta_scr[...], bk)) * scale
-        acc_scr[...] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(kj * block_k <= (qi + 1) * block_q - 1)(_run)
-    else:
-        _run()
-
-    @pl.when(kj == nk - 1)
-    def _store():
-        dq_ref[0] = acc_scr[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, scale, causal, block_q,
-                block_k, nq):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, scale, causal,
+                block_q, block_k, sub_q, sub_k, nq, nk):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     qj = pl.program_id(2)
 
+    @pl.when((ki == 0) & (qj == 0))
+    def _init_dq():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
     @pl.when(qj == 0)
-    def _init():
+    def _init_dkv():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def _run():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        o = o_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        if causal:
-            qpos = qj * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, _NEG)
-        bk = s.shape[1]
-        p = jnp.exp(s - _lse_from_rows(lse_ref[0], bk))
-        p = jnp.where(s <= _NEG / 2, 0.0, p)
-        delta = jnp.sum(g * o, axis=1)[:, None]        # (bq, 1)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dv_scr[...] += jax.lax.dot_general(            # Pᵀ · dO
-            p.astype(g_ref.dtype), g_ref[0],
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(            # dSᵀ · Q
-            ds.astype(q_ref.dtype), q_ref[0],
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # scores are held transposed, keys on sublanes and queries on lanes:
+    # the stored logsumexp and delta rows broadcast down the sublanes as
+    # they are, and of the five products only dq's takes a transposed
+    # operand.  The query sub-tiles are unrolled (their slices of the
+    # rows are along lanes, which a dynamic index cannot cut).
+    for a in range(block_q // sub_q):
+        rows = slice(a * sub_q, (a + 1) * sub_q)
+        q = q_ref[0, rows, :]
+        g = g_ref[0, rows, :]
+        lse = lse_ref[0, 0:1, rows]                       # (1, sub_q)
+        delta = delta_ref[0, 0:1, rows]
+        q0 = qj * block_q + a * sub_q
 
-    if causal:
-        pl.when((qj + 1) * block_q - 1 >= ki * block_k)(_run)
-    else:
-        _run()
+        def tile(c, dq, masked):
+            keys = pl.ds(pl.multiple_of(c * sub_k, sub_k), sub_k)
+            k = k_ref[0, keys, :]
+            st = _dot(k, q, _NT) * scale                  # (sub_k, sub_q)
+            if masked:
+                i_j = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                       - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
+                st = jnp.where(
+                    i_j >= ki * block_k + c * sub_k - q0, st, _NEG)
+            pt = jnp.exp(st - lse)
+            # ds without its factor ``scale``, which dq and dk take once
+            # in float32 as they are stored
+            dst = (pt * (_dot(v_ref[0, keys, :], g, _NT) - delta)).astype(
+                q.dtype)
+            dv_scr[keys, :] += _dot(pt.astype(g.dtype), g, _NN)
+            dk_scr[keys, :] += _dot(dst, q, _NN)
+            return dq + _dot(dst, k, _TN)                 # (sub_q, D)
+
+        dq = _walk(causal, q0, sub_q, ki * block_k, sub_k,
+                   block_k // sub_k, tile,
+                   jnp.zeros((sub_q, dq_scr.shape[1]), jnp.float32))
+        dq_scr[pl.ds(pl.multiple_of(q0, sub_q), sub_q), :] += dq
 
     @pl.when(qj == nq - 1)
-    def _store():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+    def _store_dkv():
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
+    @pl.when((ki == nk - 1) & (qj == nq - 1))
+    def _store_dq():
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
-def _flash_bwd_call(q, k, v, out, lse, g, causal, scale, block_q,
-                    block_k, vma=None):
+
+def _flash_bwd_call(q, k, v, out, lse, g, causal, scale, block_q=None,
+                    block_k=None, vma=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
+    block_q, block_k = _blocks(T, D, q.dtype, "bwd", block_q, block_k)
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * H, T, D)
     vr = v.reshape(B * H, T, D)
     gr = g.reshape(B * H, T, D)
-    outr = out.reshape(B * H, T, D)
+    # D_i = rowsum(dO * O), once, in float32, laid out as the logsumexp
+    # is: the sequence on lanes
+    delta = jnp.sum(gr.astype(jnp.float32)
+                    * out.reshape(B * H, T, D).astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[:, None, :], lse.shape)
     nq, nk = T // block_q, T // block_k
     interpret = _use_interpret()
     kw = {} if interpret else {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+        "compiler_params": _compiler_params(
+            T, D, q.dtype, "bwd", block_q, block_k,
+            ("parallel", "arbitrary", "arbitrary"))}
 
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    lspec = pl.BlockSpec((1, _LSE_ROWS, block_q),
-                         lambda b, i, j: (b, 0, i))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nk=nk),
-        grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, qspec, lspec],
-        out_specs=qspec,
-        out_shape=_sds((B * H, T, D), q.dtype, vma),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-        ],
-        interpret=interpret,
-        **kw,
-    )(qr, kr, vr, gr, outr, lse)
-
-    # dkv grid: kv block is the revisited (outer) axis, q streams inner
-    qspec2 = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
-    kspec2 = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
-    lspec2 = pl.BlockSpec((1, _LSE_ROWS, block_q),
-                          lambda b, i, j: (b, 0, j))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq=nq),
+    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
+    rspec = pl.BlockSpec((1, _LSE_ROWS, block_q),
+                         lambda b, i, j: (b, 0, j))
+    kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
+    # key blocks outer, query blocks inner: dk and dv of a key block
+    # accumulate in scratch over the inner axis; dq of the whole head
+    # stays in VMEM (float32 scratch) until the head's last step
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, sub_q=sub_q,
+                          sub_k=sub_k, nq=nq, nk=nk),
         grid=(B * H, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, qspec2, lspec2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
+        out_specs=[pl.BlockSpec((1, T, D), lambda b, i, j: (b, 0, 0)),
+                   kspec, kspec],
         out_shape=[
+            _sds((B * H, T, D), q.dtype, vma),
             _sds((B * H, T, D), k.dtype, vma),
             _sds((B * H, T, D), v.dtype, vma),
         ],
         scratch_shapes=[
+            pltpu.VMEM((T, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
         **kw,
-    )(qr, kr, vr, gr, outr, lse)
+    )(qr, kr, vr, gr, lse, delta)
 
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
             dv.reshape(B, H, T, D))
@@ -431,12 +503,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             f"flash_attention: sequence length {T} is not "
             f"{_LANE}-aligned, which the TPU kernel's tiles need; pad "
             "the sequence or use impl='dense'")
-    dbq, dbk = _block_sizes(T)
-    bq, bk = int(block_q or dbq), int(block_k or dbk)
-    if T % bq or T % bk:
-        raise ValueError(
-            f"flash_attention: block sizes ({bq}, {bk}) must divide "
-            f"sequence length {T} (a non-dividing block would silently "
-            f"leave tail blocks unwritten)")
-    return _flash_core(q, k, v, bool(causal), float(scale), bq, bk,
-                       tuple(vma))
+    _blocks(T, q.shape[-1], q.dtype, "fwd", block_q, block_k)  # they divide
+    return _flash_core(q, k, v, bool(causal), float(scale), block_q,
+                       block_k, tuple(vma))

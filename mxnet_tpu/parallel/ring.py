@@ -287,7 +287,7 @@ def ring_attention(q, k, v, mesh=None, axis=SP, causal=False, scale=None,
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from ..ops.pallas_attention import _LANE, _block_sizes, _use_interpret
+    from ..ops.pallas_attention import _LANE, _blocks, _use_interpret
 
     mesh = mesh or default_mesh()
     if mesh is None:
@@ -338,17 +338,18 @@ def ring_attention(q, k, v, mesh=None, axis=SP, causal=False, scale=None,
             f"ring_attention impl='flash': local sequence {Tloc} not "
             f"{_LANE}-aligned on TPU")
     use_flash = impl != "dense" and flash_ok
-    dbq, dbk = _block_sizes(Tloc)
-    bq, bk = int(block_q or dbq), int(block_k or dbk)
-    if use_flash and (Tloc % bq or Tloc % bk):
-        raise MXNetError(
-            f"ring_attention: block sizes ({bq}, {bk}) must divide the "
-            f"local sequence length {Tloc} (a non-dividing block would "
-            "silently leave tail blocks unwritten)")
+    if use_flash:
+        # the kernels choose their own blocks from the local shape
+        # where the caller names none; a named one must divide it
+        try:
+            _blocks(Tloc, q.shape[-1], q.dtype, "fwd", block_q, block_k)
+        except ValueError as e:
+            raise MXNetError(f"ring_attention (local sequence): {e}") \
+                from None
 
     def local_flash(q, k, v):
         return _ring_flash(q, k, v, axis, nshards, bool(causal),
-                           float(scale), bq, bk)
+                           float(scale), block_q, block_k)
 
     # check_vma off for INTERPRET-mode flash only: interpret pallas_call
     # inside a vma-checked manual region hits a jax-internal

@@ -1,8 +1,8 @@
 """`ops/cache_write.py`: the in-place kernel (interpreted here) against
 the plain path, one ``dynamic_update_slice`` a row.  Also this file's:
-every compile for a described v5e (this kernel, `ops/cache_attention.py`'s
-and `ops/indexed_attention.py`'s), so that one worker loads the TPU's
-library."""
+every compile for a described v5e (this kernel, `ops/cache_attention.py`'s,
+`ops/indexed_attention.py`'s and `ops/pallas_attention.py`'s), so that one
+worker loads the TPU's library."""
 
 import collections
 
@@ -326,3 +326,50 @@ def test_the_selection_kernels_compile_for_a_v5e(one_chip, monkeypatch,
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info.shape == out
+
+
+@pytest.mark.parametrize("name,BH,T,D,causal", [
+    ("gpt2m_train_t1024", 64, 1024, 64, True),
+    ("bert_base_b32", 384, 512, 64, False),   # set the parent's 256 cap
+    ("long_heads_of_128", 16, 4096, 128, True)])
+def test_the_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch, name,
+                                             BH, T, D, causal):
+    """`ops/pallas_attention.py`'s forward and backward at the blocks the
+    shape gives (`tests/test_pallas_attention.py` holds their results,
+    interpreted): Mosaic takes both inside the scoped VMEM it grants by
+    default (no limit is asked for), the training cell's length is one
+    grid step a head, and every operand of the two custom calls is
+    bfloat16 but the float32 logsumexp and delta rows: nothing is cast
+    up in front of a kernel.  Kept in this file: one worker describes
+    the chip."""
+    import re
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((1, BH, T, D), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, causal=causal)
+        return jnp.sum(out.astype(jnp.float32))
+
+    lowered = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(x, x, x)
+    calls = {}
+    for line in lowered.as_text().split("\n"):
+        if "@tpu_custom_call" in line:
+            kernel = re.search(r'kernel_name = "(\w+)"', line).group(1)
+            calls[kernel] = re.findall(r"tensor<([\dx]+)x(\w+)>",
+                                       line.split(" : (")[-1])
+    wide, rows = f"{BH}x{T}x{D}", f"{BH}x8x{T}"
+    assert calls == {
+        "_fwd_kernel": [(wide, "bf16")] * 4 + [(rows, "f32")],
+        "_bwd_kernel": ([(wide, "bf16")] * 4 + [(rows, "f32")] * 2
+                        + [(wide, "bf16")] * 3)}
+    for kernel in ("fwd", "bwd"):
+        bq, bk = pa._block_sizes(T, D, jnp.bfloat16, kernel)
+        assert bq == bk == min(T, 1024)
+        assert sum(pa._vmem_bytes(T, D, jnp.bfloat16, kernel, bq,
+                                  bk)) <= pa._VMEM_DEFAULT
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert [o.shape for o in compiled.out_info[1]] == [(1, BH, T, D)] * 3

@@ -1,0 +1,196 @@
+"""`ops/pallas_attention.py`: the flash kernels (interpreted here)
+against the dense oracle at the blocks the shape gives and at forced
+small ones, what their products are fed, and the block rule.  The
+compiles for a described v5e are in `tests/test_cache_write.py` (one
+worker loads the TPU's library)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import pallas_attention as pa
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+# (T, D, causal, dtype): the training cell's shape, BERT's, a length
+# that is lane-aligned and no multiple of 256, heads of 128 over two
+# grid blocks, and float32 inputs
+CASES = [
+    (1024, 64, True, BF16),
+    (512, 64, False, BF16),
+    (384, 64, True, BF16),
+    (2048, 128, True, BF16),
+    (256, 64, True, F32),
+]
+# forced blocks a case: several tiles a row, one the diagonal cuts, one
+# wholly above it (causal), and blocks that differ for queries and keys
+FORCED = {
+    1024: [(128, 128), (256, 512)],
+    512: [(128, 128), (256, 128)],
+    384: [(128, 128), (128, 384)],
+    2048: [(256, 256), (1024, 512)],
+    256: [(128, 128), (128, 256)],
+}
+# test_flash_attention_grad's tolerances (tests/test_parallel.py) for
+# float32; a bfloat16 result carries its own rounding besides (half a
+# unit in the last of 8 bits) and that of p and ds before their products
+TOL = {F32: {"fwd": (2e-4, 2e-5), "bwd": (2e-3, 2e-4)},
+       BF16: {"fwd": (2e-3 + 2.0 ** -8, 2e-3), "bwd": (2e-3 + 2.0 ** -7,
+                                                      2e-2)}}
+
+
+def _inputs(T, D, dtype, seed=0, heads=2):
+    keys = jax.random.split(jax.random.key(seed + T), 4)
+    return [jax.random.normal(k, (1, heads, T, D), dtype) for k in keys]
+
+
+def _oracle(q, k, v, w, causal):
+    """The dense reference in float32 on the inputs' own values."""
+    scale = q.shape[-1] ** -0.5
+    q, k, v, w = (x.astype(F32) for x in (q, k, v, w))
+
+    def loss(q, k, v):
+        return jnp.sum(pa._dense_ref(q, k, v, causal, scale) * w)
+
+    return (pa._dense_ref(q, k, v, causal, scale),
+            jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+
+def _kernel(q, k, v, w, causal, **blocks):
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, causal=causal, **blocks)
+        return jnp.sum(out.astype(F32) * w.astype(F32))
+
+    return (pa.flash_attention(q, k, v, causal=causal, **blocks),
+            jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+
+def _cases():
+    for T, D, causal, dtype in CASES:
+        for blocks in [None] + FORCED[T]:
+            name = "x".join(map(str, blocks)) if blocks else "default"
+            yield pytest.param(
+                T, D, causal, dtype, blocks,
+                id=f"T{T}-D{D}-{'causal' if causal else 'full'}-"
+                   f"{jnp.dtype(dtype).name}-{name}")
+
+
+@pytest.mark.parametrize("T,D,causal,dtype,blocks", list(_cases()))
+def test_forward_and_gradients_match_the_dense_oracle(T, D, causal, dtype,
+                                                      blocks):
+    q, k, v, w = _inputs(T, D, dtype)
+    kw = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+    out, grads = _kernel(q, k, v, w, causal, **kw)
+    ref, ref_grads = _oracle(q, k, v, w, causal)
+    assert out.dtype == dtype and out.shape == q.shape
+    rtol, atol = TOL[dtype]["fwd"]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), rtol=rtol, atol=atol)
+    rtol, atol = TOL[dtype]["bwd"]
+    for got, want, x, name in zip(grads, ref_grads, (q, k, v), "qkv"):
+        assert got.dtype == x.dtype and got.shape == x.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want), rtol=rtol,
+            atol=atol, err_msg=f"d{name}")
+
+
+def _products(jaxpr, found):
+    """Every ``dot_general`` of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _products(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bfloat16", "float32"])
+def test_products_take_the_inputs_dtype_and_accumulate_in_float32(dtype):
+    """No operand is cast up in front of a product: bfloat16 inputs go
+    into the MXU as bfloat16, float32 inputs as float32 (the type
+    follows the input's, it is not forced down), two products forward
+    and five backward, each accumulated in float32."""
+    q, k, v, w = _inputs(256, 64, dtype)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v, causal=True).astype(F32))
+
+    for fn, count in ((lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=True), 2), (jax.grad(loss, (0, 1, 2)), 2 + 5)):
+        dots = _products(jax.make_jaxpr(fn)(q, k, v).jaxpr, [])
+        # the causal walk traces a sub-tile twice: unmasked and masked
+        assert len(dots) == 2 * count
+        for eqn in dots:
+            assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
+            assert eqn.outvars[0].aval.dtype == F32
+            assert eqn.params["preferred_element_type"] == F32
+
+
+@pytest.mark.parametrize("T,D,causal", [(1024, 64, True), (512, 64, False),
+                                        (384, 64, True), (512, 128, True)])
+def test_bfloat16_products_equal_float32_fed_ones(monkeypatch, T, D,
+                                                  causal):
+    """Feeding the products bfloat16 lowers no precision: a product of
+    two bfloat16 values is exact in the float32 accumulator, so the
+    kernels give what they give when every operand is cast to float32
+    in front of its product (the parent's feed), up to the order of
+    float32 additions.  The float32 logsumexp agrees to float32
+    rounding.  The bfloat16 results are equal but for the few values (a
+    probability or a ds that fell on the other side of a rounding
+    boundary moves its sums by a bfloat16 unit of one term): under one
+    in a hundred, none by more than a unit in the last place of the
+    tensor's largest value."""
+    q, k, v, w = _inputs(T, D, BF16, seed=1)
+    scale = D ** -0.5
+
+    def run():
+        out, lse = pa._flash_call(q, k, v, causal, scale)
+        return (out, lse) + pa._flash_bwd_call(q, k, v, out, lse, w,
+                                               causal, scale)
+
+    fed_bf16 = run()
+    dot = pa._dot
+    monkeypatch.setattr(pa, "_dot", lambda a, b, dims: dot(
+        a.astype(F32), b.astype(F32), dims))
+    fed_f32 = run()
+    np.testing.assert_allclose(np.asarray(fed_bf16[1]),
+                               np.asarray(fed_f32[1]), rtol=1e-6, atol=1e-6)
+    for got, want in zip(fed_bf16[:1] + fed_bf16[2:],
+                         fed_f32[:1] + fed_f32[2:]):
+        assert got.dtype == want.dtype == BF16
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert (got != want).mean() < 0.01
+        assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("D", [64, 128])
+def test_block_sizes_divide_every_aligned_length(D):
+    """For every lane-aligned length up to 8,192, both kernels and both
+    operand types: blocks that divide the length, lane-aligned and no
+    larger than the rule's cap, sub-tiles that divide the blocks, and a
+    grid step's VMEM need inside the budget the rule reckons with."""
+    for T in range(pa._LANE, 8192 + 1, pa._LANE):
+        for dtype in (BF16, F32):
+            for kernel in ("fwd", "bwd"):
+                bq, bk = pa._block_sizes(T, D, dtype, kernel)
+                assert T % bq == 0 and T % bk == 0, (T, bq, bk)
+                assert bq % pa._LANE == 0 and bk % pa._LANE == 0
+                assert max(bq, bk) <= pa._MAX_BLOCK
+                sq, sk = pa._sub_tiles(bq, bk)
+                assert bq % sq == 0 and bk % sk == 0
+                assert sq % pa._LANE == 0 and sk % pa._LANE == 0
+                assert pa._vmem_bytes(T, D, dtype, kernel, bq,
+                                      bk)[0] <= pa._VMEM_BUDGET
+    # the largest block wins: the cell's length is one block a head
+    assert pa._block_sizes(1024, 64, BF16, "fwd") == (1024, 1024)
+    assert pa._block_sizes(1024, 64, BF16, "bwd") == (1024, 1024)
+    assert pa._block_sizes(384, 64, BF16, "fwd") == (384, 384)
+    assert pa._block_sizes(1152, 64, BF16, "fwd") == (384, 384)
+    # off the lane grid (interpret mode only): the length itself
+    assert pa._block_sizes(192, 16, F32, "fwd") == (192, 192)
+    assert pa._sub_tiles(192, 192) == (192, 192)
