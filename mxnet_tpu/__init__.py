@@ -21,6 +21,10 @@ A from-scratch rebuild of the capabilities of apache/incubator-mxnet
   parallelism via jax.sharding + shard_map, ring attention over ppermute.
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # the `startup.import` span opens here
+
 __version__ = "0.1.0"
 
 # memory-pool env knobs must hit the XLA client env BEFORE jax loads
@@ -80,3 +84,17 @@ if "attribute" in globals():
     AttrScope = attribute.AttrScope
 if "monitor" in globals():
     mon = globals()["monitor"]  # reference alias: mx.mon.Monitor
+
+# the first spans of the process's start-up timeline (`profiler` is not
+# there yet at the first line: clock reads and `keep_span`): what the
+# process did before this package's first line (the interpreter, and
+# whatever the caller imported and started first: under a caller that
+# has imported jax and asked for its devices, jax and the client), and
+# the import of this package, with that of jax beneath it where the
+# caller left it
+_now = _time.perf_counter()
+_age = telemetry.process_age()
+if _age is not None and _now - _age < _T_IMPORT:
+    telemetry.keep_span("startup.before_import", _now - _age, _T_IMPORT)
+telemetry.keep_span("startup.import", _T_IMPORT, _now)
+del _time, _T_IMPORT, _now, _age

@@ -34,6 +34,7 @@ from .. import autograd as _ag
 from .. import name as _name
 from ..base import MXNetError, np_dtype
 from ..ndarray.ndarray import NDArray, _from_jax
+from ..profiler import scope
 from .parameter import (DeferredInitializationError, Parameter, ParameterDict)
 
 
@@ -95,6 +96,9 @@ class _BlockScope:
         self._name_scope.__exit__(ptype, value, trace)
         self._name_scope = None
         _BlockScope._current.value = self._old_scope
+
+
+_CASTING = threading.local()    # .outer: inside a `Block.cast` already
 
 
 class Block:
@@ -276,6 +280,19 @@ class Block:
             cld.hybridize(active, **kwargs)
 
     def cast(self, dtype):
+        """Cast the parameters of this block and of those below it.  The
+        outermost call is one ``startup.params`` span; its children's
+        calls run inside it."""
+        if getattr(_CASTING, "outer", False):
+            return self._cast_tree(dtype)
+        _CASTING.outer = True
+        try:
+            with scope("startup.params", what="cast", dtype=str(dtype)):
+                self._cast_tree(dtype)
+        finally:
+            _CASTING.outer = False
+
+    def _cast_tree(self, dtype):
         for child in self._children.values():
             child.cast(dtype)
         for _, param in self.params.items():

@@ -999,9 +999,12 @@ class CapturedStep:
         and left out of the call."""
         exe = self._executables.get(attest)
         if exe is None:
-            lowered = self._fn.lower(*args) if attest is None \
-                else self._fn.lower(*args, attest)
-            exe = self._executables[attest] = lowered.compile()
+            from .. import profiler
+
+            with profiler.scope("train.compile"):
+                lowered = self._fn.lower(*args) if attest is None \
+                    else self._fn.lower(*args, attest)
+                exe = self._executables[attest] = lowered.compile()
         return exe
 
     # -- program accounting (mxnet_tpu/telemetry.py) ----------------------------

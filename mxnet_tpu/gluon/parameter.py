@@ -20,6 +20,7 @@ import numpy as _np
 from ..base import MXNetError, np_dtype
 from ..context import Context, current_context, cpu
 from ..ndarray.ndarray import NDArray, _from_jax
+from ..profiler import scope
 from .. import initializer
 
 
@@ -238,18 +239,21 @@ class Parameter:
                 self._init_grad()
 
     def set_data(self, data):
-        """Set the value on every context (reference: Parameter.set_data)."""
-        self.shape = data.shape
-        if self._data is None:
-            assert self._deferred_init, \
-                f"Parameter '{self.name}' has not been initialized"
-            self._init_impl(data if isinstance(data, NDArray)
-                            else _from_jax(data))
-            self._deferred_init = ()
-            return
+        """Set the value on every context (reference: Parameter.set_data).
+        A ``startup.params`` span: loading a model is a run of these."""
         raw = data._data if isinstance(data, NDArray) else data
-        self._data._set_data(raw.astype(self._data._data.dtype)
-                             if hasattr(raw, "astype") else raw)
+        with scope("startup.params", what="set_data",
+                   bytes=int(getattr(raw, "nbytes", 0))):
+            self.shape = data.shape
+            if self._data is None:
+                assert self._deferred_init, \
+                    f"Parameter '{self.name}' has not been initialized"
+                self._init_impl(data if isinstance(data, NDArray)
+                                else _from_jax(data))
+                self._deferred_init = ()
+                return
+            self._data._set_data(raw.astype(self._data._data.dtype)
+                                 if hasattr(raw, "astype") else raw)
 
     def row_sparse_data(self, row_id):
         return self.data()
@@ -450,8 +454,9 @@ class ParameterDict:
                    force_reinit=False):
         if verbose and init is not None:
             init.set_verbosity(verbose=verbose)
-        for _, v in self.items():
-            v.initialize(None, ctx, init, force_reinit=force_reinit)
+        with scope("startup.params", what="initialize", leaves=len(self)):
+            for _, v in self.items():
+                v.initialize(None, ctx, init, force_reinit=force_reinit)
 
     def zero_grad(self):
         for v in self.values():

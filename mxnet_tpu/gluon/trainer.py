@@ -30,51 +30,55 @@ class Trainer:
     def __init__(self, params, optimizer, optimizer_params=None,
                  kvstore="device", compression_params=None,
                  update_on_kvstore=None, clip_global_norm=None):
-        from .. import engine, obs
-        engine.ensure_compile_cache()
-        obs.ensure_from_env()          # MXTPU_METRICS_PORT, if set
-        if isinstance(params, (dict, ParameterDict)):
-            params = list(params.values())
-        if not isinstance(params, (list, tuple)):
-            raise ValueError(
-                "First argument must be a list or dict of Parameters, "
-                f"got {type(params)}.")
-        self._params = []
-        self._param2idx = {}
-        for i, param in enumerate(params):
-            if not isinstance(param, Parameter):
+        from .. import engine, obs, profiler
+
+        # the compile cache (and the client's start, where nothing
+        # touched a device before), the optimizer and its updaters
+        with profiler.scope("startup.trainer"):
+            engine.ensure_compile_cache()
+            obs.ensure_from_env()          # MXTPU_METRICS_PORT, if set
+            if isinstance(params, (dict, ParameterDict)):
+                params = list(params.values())
+            if not isinstance(params, (list, tuple)):
                 raise ValueError(
                     "First argument must be a list or dict of Parameters, "
-                    f"got list of {type(param)}.")
-            self._param2idx[param.name] = i
-            self._params.append(param)
-        self._compression_params = compression_params
-        optimizer_params = optimizer_params if optimizer_params else {}
-        self._scale = float(optimizer_params.get("rescale_grad", 1.0))
-        self._init_optimizer(optimizer, optimizer_params)
-        self._kvstore_params = {
-            "kvstore": kvstore, "update_on_kvstore": update_on_kvstore}
-        self._kv_initialized = False
-        self._kvstore = None
-        self._update_on_kvstore = None
-        self._params_to_init = []
-        self._contains_sparse_weight = False
-        # numerical-health guard (mxnet_tpu/numerics.py): clip_global_norm
-        # falls back to MXTPU_CLIP_GLOBAL_NORM when not given; skipped
-        # steps are recorded here (bounded deque-style list)
-        self._clip_global_norm = None if clip_global_norm is None \
-            else float(clip_global_norm)
-        self.divergence_monitor = None
-        self.skipped_steps = []
-        self._step_count = 0
-        # resumable input pipeline (gluon/data/state.py): when attached,
-        # each guarded step tags the divergence monitor with the batch
-        # that fed it, so a rollback can quarantine the poisoned batch
-        self._data_pipeline = None
-        # integrity plane (mxnet_tpu/integrity.py): attach_integrity
-        # makes the captured step fingerprint the state every
-        # plane.every steps and attest it against the gang
-        self._integrity_plane = None
+                    f"got {type(params)}.")
+            self._params = []
+            self._param2idx = {}
+            for i, param in enumerate(params):
+                if not isinstance(param, Parameter):
+                    raise ValueError(
+                        "First argument must be a list or dict of Parameters, "
+                        f"got list of {type(param)}.")
+                self._param2idx[param.name] = i
+                self._params.append(param)
+            self._compression_params = compression_params
+            optimizer_params = optimizer_params if optimizer_params else {}
+            self._scale = float(optimizer_params.get("rescale_grad", 1.0))
+            self._init_optimizer(optimizer, optimizer_params)
+            self._kvstore_params = {
+                "kvstore": kvstore, "update_on_kvstore": update_on_kvstore}
+            self._kv_initialized = False
+            self._kvstore = None
+            self._update_on_kvstore = None
+            self._params_to_init = []
+            self._contains_sparse_weight = False
+            # numerical-health guard (mxnet_tpu/numerics.py): clip_global_norm
+            # falls back to MXTPU_CLIP_GLOBAL_NORM when not given; skipped
+            # steps are recorded here (bounded deque-style list)
+            self._clip_global_norm = None if clip_global_norm is None \
+                else float(clip_global_norm)
+            self.divergence_monitor = None
+            self.skipped_steps = []
+            self._step_count = 0
+            # resumable input pipeline (gluon/data/state.py): when attached,
+            # each guarded step tags the divergence monitor with the batch
+            # that fed it, so a rollback can quarantine the poisoned batch
+            self._data_pipeline = None
+            # integrity plane (mxnet_tpu/integrity.py): attach_integrity
+            # makes the captured step fingerprint the state every
+            # plane.every steps and attest it against the gang
+            self._integrity_plane = None
 
     def _init_optimizer(self, optimizer, optimizer_params):
         param_dict = {i: param for i, param in enumerate(self._params)}

@@ -44,12 +44,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..profiler import scope
+
 _LANE = 128
 
 
 def _import_pallas():
-    from jax.experimental import pallas  # noqa: F401
-    from jax.experimental.pallas import tpu  # noqa: F401
+    # on this thread's line of the start-up timeline: whether it is
+    # done when the first decode compile wants it
+    with scope("startup.import.pallas"):
+        from jax.experimental import pallas  # noqa: F401
+        from jax.experimental.pallas import tpu  # noqa: F401
 
 
 # Tracing the kernel imports Pallas: 1.0-1.4 s of Python, which a server

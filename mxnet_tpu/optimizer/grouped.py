@@ -493,11 +493,19 @@ def plan_items(updater, index, grad, weight):
     plan = _PLANS.get(type(o))
     groups = {}
     fallback = []
+    fresh = [(i, w) for i, w in zip(index, weight) if i not in upd.states]
+    if fresh:
+        from ..profiler import scope
+
+        # a trainer's first step: its optimizer state is made here
+        with scope("startup.optimizer", leaves=len(fresh)):
+            for i, w in fresh:
+                if i in upd.states:     # an index given twice
+                    continue
+                upd.states[i] = o.create_state_multi_precision(i, w)
+                upd.states_synced[i] = True
+                _place_state_like(upd.states[i], w)
     for i, g, w in zip(index, grad, weight):
-        if i not in upd.states:
-            upd.states[i] = o.create_state_multi_precision(i, w)
-            upd.states_synced[i] = True
-            _place_state_like(upd.states[i], w)
         item = None
         if plan is not None and _groupable(o, w, g):
             item = plan(o, i, w, upd.states[i])

@@ -189,19 +189,23 @@ class scope:
     step=j)``).  The host duration is always measured with
     ``perf_counter`` and forwarded to the telemetry step assembler
     (mxnet_tpu/telemetry.py), which is how StepStats gets its breakdown
-    without a profiler on; ``t0`` and ``t1`` keep the two clock reads
-    for a caller that sums them (serving's ``timings``).
+    without a profiler on, and keeps the process's first spans as its
+    start-up timeline (`telemetry.startup_spans`); ``t0`` and ``t1``
+    keep the two clock reads for a caller that sums them (serving's
+    ``timings``).
     """
 
-    __slots__ = ("name", "t0", "t1", "_jax")
+    __slots__ = ("name", "attrs", "t0", "t1", "_jax")
 
     def __init__(self, name, **attrs):
         self.name = name
+        self.attrs = attrs or None
         self._jax = _TraceMe(name, **attrs)
 
     def set(self, **attrs):
         """Attributes known only once the span is under way (a group's
         size); call before the span closes."""
+        self.attrs = {**self.attrs, **attrs} if self.attrs else attrs
         self._jax.set_metadata(**attrs)
 
     def __enter__(self):
@@ -215,6 +219,7 @@ class scope:
         if _S.running:
             record_span(self.name, "scope", self.t0, t1)
         _telemetry.on_scope(self.name, t1 - self.t0)
+        _telemetry.keep_scope(self)
 
 
 if os.environ.get("MXNET_PROFILER_AUTOSTART", "0") == "1":

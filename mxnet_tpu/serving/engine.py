@@ -59,7 +59,6 @@ import collections
 import os
 import re
 import threading
-import time
 
 from ..base import MXNetError
 from ..obs.spans import wall
@@ -256,21 +255,27 @@ class ServingEngine:
 
     def __init__(self, model, batch_buckets=None, prefill_floor=8,
                  mesh=None, tp_axis="tp", dtype=None):
-        self._mesh = mesh
-        self._tp_axis = tp_axis
-        self._dtype = dtype
-        self._program = self._program_of(model)
-        self._W = self._program.window
-        self.batch_buckets = tuple(sorted(
-            batch_buckets if batch_buckets is not None
-            else batch_buckets_from_env()))
-        self.prefill_buckets = prefill_buckets_for(self._W,
-                                                   floor=prefill_floor)
-        self._reload_lock = threading.Lock()
-        self.generation = 0
-        self._weights = tuple(self._program.weights())
-        self._programs = {}
-        self._step = self._make_step()
+        from .. import engine
+
+        engine.watch_compiles()
+        # the decoder program's ``weights()`` stacks and re-lays the
+        # model's leaves: most of this span
+        with scope("startup.engine"):
+            self._mesh = mesh
+            self._tp_axis = tp_axis
+            self._dtype = dtype
+            self._program = self._program_of(model)
+            self._W = self._program.window
+            self.batch_buckets = tuple(sorted(
+                batch_buckets if batch_buckets is not None
+                else batch_buckets_from_env()))
+            self.prefill_buckets = prefill_buckets_for(
+                self._W, floor=prefill_floor)
+            self._reload_lock = threading.Lock()
+            self.generation = 0
+            self._weights = tuple(self._program.weights())
+            self._programs = {}
+            self._step = self._make_step()
 
     def _program_of(self, model):
         make = getattr(model, "decoder_program", None)
@@ -434,13 +439,14 @@ class ServingEngine:
         global _COMPILE_COUNT
         import jax
 
-        w_avals = tuple(self._aval(x) for x in self._weights)
-        c_avals = tuple(self._aval(c) for c in self.init_cache(B))
-        jfn = jax.jit(self._step["decode" if S == 1 else "prefill"],
-                      donate_argnums=(1,))
-        compiled = jfn.lower(w_avals, c_avals, self._int_aval((B,)),
-                             self._int_aval((B,)),
-                             self._int_aval((B, S))).compile()
+        program = "decode" if S == 1 else "prefill"
+        with scope("serve.compile", B=B, S=S, program=program):
+            w_avals = tuple(self._aval(x) for x in self._weights)
+            c_avals = tuple(self._aval(c) for c in self.init_cache(B))
+            jfn = jax.jit(self._step[program], donate_argnums=(1,))
+            compiled = jfn.lower(w_avals, c_avals, self._int_aval((B,)),
+                                 self._int_aval((B,)),
+                                 self._int_aval((B, S))).compile()
         with _LOCK:
             _COMPILE_COUNT += 1
         self._programs[(B, S)] = compiled
@@ -449,17 +455,13 @@ class ServingEngine:
     def warmup(self):
         """Pre-compile every (batch × prefill) program plus the S=1
         decode program per batch bucket; afterwards the request path is
-        retrace-free (``trace_count()`` is pinned)."""
-        t0 = time.perf_counter()
+        retrace-free (``trace_count()`` is pinned).  Each program is a
+        ``serve.compile`` span and a ``compile`` event
+        (`engine.watch_compiles`)."""
         for B in self.batch_buckets:
             for S in self.prefill_buckets + (1,):
                 if (B, S) not in self._programs:
                     self._compile(B, S)
-        from .. import telemetry
-
-        telemetry.event(
-            "serving_warmup", programs=len(self._programs),
-            compile_ms=round((time.perf_counter() - t0) * 1e3, 1))
         return self
 
     def program_count(self):

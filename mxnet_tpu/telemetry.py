@@ -32,6 +32,13 @@ that attribution, always, at <1% of step time:
   in-memory ring (`recent_steps()`), which is how
   `benchmark/readers/step_span.py` reads them (``path="captured"``).
 
+- Start-up timeline — the first `STARTUP_SPANS` spans a process closes
+  (`profiler.scope` through `keep_scope`, JAX's compile events
+  through `keep_span`), each with both clock reads and its thread:
+  `startup_spans()`.  Where a process's seconds go before its first
+  step or token: import, parameters, traces, lowerings, compiles or
+  cache loads by program (docs/observability.md, "Process start-up").
+
 Controlled by ``MXTPU_TELEMETRY`` (default on).  Zero extra device
 dispatches or host readbacks: everything here is host timers and dict
 assembly (pinned by tests/test_telemetry.py).
@@ -613,10 +620,13 @@ def reset(close_sink=True):
     sink handle — test isolation, not a runtime API."""
     global _SINK, _SINK_SIZE, _LAST_END, _LAST_COUNTS, _CURRENT
     global _PEAK_CACHE, _TRIAL_FP, _CONFIG_FP, _IDENT, _TAIL_BYTES
-    global _GANG_EPOCH
+    global _GANG_EPOCH, _STARTUP_KEPT, _STARTUP_ROOM
     with _LOCK:
         _RECENT.clear()
         _EVENT_COUNTS.clear()
+    del _STARTUP[:]
+    _STARTUP_KEPT = 0
+    _STARTUP_ROOM = STARTUP_SPANS if enabled() else 0
     _CURRENT = None
     _TRIAL_FP = None
     _CONFIG_FP = None
@@ -755,6 +765,68 @@ def step_begin(path="eager"):
     return _CURRENT
 
 
+#: how many spans the start-up timeline keeps.  The five benchmark
+#: cells close 1,468-7,267 spans on their main thread before their
+#: window opens (PERF.md, section 5: most are JAX's traces of the small
+#: functions inside a program's trace); four times the largest fits.
+STARTUP_SPANS = 32768
+# The timeline, flat: name, t0, t1, thread, n, then n (key, value)
+# pairs, a span.  No tuple and no dict a span: nothing the cyclic
+# collector counts may outlive a closed scope.  A steady state that
+# allocates nothing it keeps never runs the collector; one survivor a
+# scope runs a generation-0 pass every few hundred scopes, and the
+# full collection those lead to (50-110 ms in a process that holds
+# jax) lands in somebody's decode step (PR 34's first chip runs).
+_STARTUP = []
+_STARTUP_KEPT = 0
+# spans the store still takes: STARTUP_SPANS, and 0 once it is full or
+# where telemetry is off, so that a closed scope then costs this one
+# comparison
+_STARTUP_ROOM = STARTUP_SPANS if enabled() else 0
+
+
+def keep_span(name, t0, t1, **attrs):
+    """Keep a span in the start-up timeline while the store has room:
+    `keep_scope` for a closed `profiler.scope`, and by itself for a
+    span made after the fact (an interval JAX measured: a trace, a
+    lowering, a compile).  A store that holds `STARTUP_SPANS` spans is
+    full and stays as it is: a reader that finds that many knows the
+    timeline ends there, not the process's work."""
+    global _STARTUP_KEPT, _STARTUP_ROOM
+    if _STARTUP_KEPT >= _STARTUP_ROOM:
+        _STARTUP_ROOM = 0
+        return
+    rec = [name, t0, t1, threading.get_ident(), len(attrs)]
+    for pair in attrs.items():
+        rec.extend(pair)
+    _STARTUP.extend(rec)        # one call: whole under the GIL
+    _STARTUP_KEPT += 1
+
+
+def process_age():
+    """Seconds since this process started, from ``/proc`` (its start in
+    clock ticks since boot against the uptime: 10 ms steps), or None
+    where there is no such file."""
+    try:
+        with open("/proc/self/stat") as f:     # field 22, after "(comm)"
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return up - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def keep_scope(span):
+    """Profiler scope hook, beside `on_scope`: `profiler.scope.__exit__`
+    hands every closed span here, and the first `STARTUP_SPANS` of a
+    process's life are kept with both ``perf_counter`` reads, the
+    thread that closed them and their ``attrs``: the start-up timeline
+    (`startup_spans`)."""
+    if _STARTUP_ROOM:
+        keep_span(span.name, span.t0, span.t1, **(span.attrs or {}))
+
+
 def on_scope(name, dur_s):
     """Profiler scope hook: `profiler.scope.__exit__` forwards every
     annotate duration here.  Only scopes on the step-owning thread count
@@ -763,6 +835,22 @@ def on_scope(name, dur_s):
     if acc is None or threading.get_ident() != acc.tid:
         return
     acc.scopes[name] = acc.scopes.get(name, 0.0) + dur_s
+
+
+def startup_spans():
+    """The process's start-up timeline, in the order the spans closed:
+    ``(name, t0, t1, thread, attrs)``, ``t0`` / ``t1`` on
+    ``perf_counter``'s clock, ``thread`` a ``threading.get_ident()``,
+    ``attrs`` a dict or None."""
+    flat = list(_STARTUP)
+    out, i = [], 0
+    while i < len(flat):
+        name, t0, t1, thread, n = flat[i:i + 5]
+        pairs = flat[i + 5:i + 5 + 2 * n]
+        out.append((name, t0, t1, thread,
+                    dict(zip(pairs[::2], pairs[1::2])) if n else None))
+        i += 5 + 2 * n
+    return out
 
 
 def step_abort(acc):

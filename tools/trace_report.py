@@ -11,6 +11,12 @@ mxnet_tpu/resilience.py) get their own narrative section: who died at
 which step, what each new mesh epoch looks like, and how long each
 recovery took and from which source (peer RAM vs disk).
 
+The ``compile`` events (mxnet_tpu/engine.py `watch_compiles`) become a
+start-up section: the programs by backend-compile seconds, each with
+whether the persistent cache gave it.  In the process itself,
+``report_startup(events, out, spans=telemetry.startup_spans())`` puts
+the categories of the start-up timeline above them.
+
 Stdlib-only on purpose: it must run on a machine with neither jax nor
 the package installed (pull the JSONL off a pod, read it anywhere).
 ``--validate`` additionally loads ``mxnet_tpu/telemetry.py`` standalone
@@ -146,6 +152,7 @@ def report_run(run, records, out):
             ids = [e["step"] for e in group if "step" in e]
             at = f" at steps {ids}" if ids else ""
             out.write(f"    {kind}: {len(group)}{at}\n")
+        report_startup(kinds.get("compile", []), out)
         report_resilience(kinds, out)
         report_fencing(kinds, out)
         report_data(kinds, out)
@@ -157,6 +164,108 @@ def report_run(run, records, out):
             report_integrity({}, attestations, out)
         if trials:
             report_autotune({}, trials, out)
+
+
+#: the start-up timeline's categories and the spans of each (a name
+#: ending in ``.`` or ``_`` is a prefix).  The one list: the benchmark's
+#: ``setup_<category>_s`` metric files are tested against it
+#: (benchmark/tests/test_startup.py), docs/observability.md shows it
+STARTUP_CATEGORIES = (
+    ("before_import", ("startup.before_import",)),
+    ("import", ("startup.import",)),
+    ("params", ("startup.params", "startup.engine", "startup.trainer",
+                "startup.optimizer", "startup.backend")),
+    ("trace_lower", ("compile.trace", "compile.lower", "serve.compile",
+                     "train.compile")),
+    ("compile", ("compile.backend",)),
+    ("warm_run", ("serve.prefill.", "serve.decode.", "serve.group",
+                  "serve.collect", "serve.finish", "train_step",
+                  "captured_", "guard_readback")),
+)
+
+
+def startup_seconds(spans, thread=None, until=None):
+    """{category: seconds} of a start-up timeline
+    (``telemetry.startup_spans()``): of ``thread``'s spans (default: the
+    thread of the first, the importing one) that closed by ``until``
+    (default: all), each instant belongs to the innermost span open
+    then, and a span's seconds go to the category that lists it.
+    ``unattributed`` is the rest of first start to ``until`` (or the
+    last end)."""
+    if not spans:
+        return {}
+    thread = spans[0][3] if thread is None else thread
+    mine = sorted((s for s in spans if s[3] == thread
+                   and (until is None or s[2] <= until)),
+                  key=lambda s: (s[1], -s[2]))
+    if not mine:
+        return {}
+    out = {name: 0.0 for name, _ in STARTUP_CATEGORIES}
+
+    def category(name):
+        for cat, listed in STARTUP_CATEGORIES:
+            if any(name == w or (w[-1] in "._" and name.startswith(w))
+                   for w in listed):
+                return cat
+        return None
+
+    stack = []                          # [end, category, children's time]
+
+    def close():
+        start, end, cat, inner = stack.pop()
+        if cat is not None:
+            out[cat] += (end - start) - inner
+        if stack:
+            stack[-1][3] += end - start
+
+    for name, t0, t1, _thread, _attrs in mine:
+        while stack and stack[-1][1] <= t0:
+            close()
+        if stack:                       # an overhang ends with its parent
+            t1 = min(t1, stack[-1][1])
+        stack.append([t0, t1, category(name), 0.0])
+    while stack:
+        close()
+    end = max(s[2] for s in mine) if until is None else until
+    out["unattributed"] = (end - mine[0][1]) - sum(out.values())
+    return out
+
+
+def report_startup(events, out, spans=None, until=None):
+    """The start-up section: from a timeline (in process) the seconds
+    by category; from ``compile`` events the programs by backend-compile
+    seconds, each with ``hit``, ``miss`` or ``off``."""
+    if spans:
+        secs = startup_seconds(spans, until=until)
+        out.write(f"  start-up ({len(spans)} spans kept):\n")
+        for cat, v in secs.items():
+            out.write(f"    {cat:<14}{v:>10.3f} s\n")
+        out.write(f"    {'sum':<14}{sum(secs.values()):>10.3f} s\n")
+        if not events:
+            events = [dict(s[4], secs=s[2] - s[1]) for s in spans
+                      if s[0] == "compile.backend"]
+    if not events:
+        return
+    by_program = {}
+    for e in events:
+        key = (e.get("program", "?"), e.get("cache", "?"))
+        n, total = by_program.get(key, (0, 0.0))
+        by_program[key] = (n + 1, total + float(e.get("secs", 0.0)))
+    how = {c: sum(n for (_p, cache), (n, _t) in by_program.items()
+                  if cache == c) for c in ("hit", "miss", "off")}
+    out.write(f"  compiles: {len(events)} backend compiles or cache "
+              f"loads, {sum(t for _n, t in by_program.values()):.3f} s "
+              f"({how['hit']} hit, {how['miss']} miss, {how['off']} "
+              f"off)\n")
+    out.write(f"    {'program':<44}{'n':>5}{'s':>10}  cache\n")
+    ranked = sorted(by_program.items(), key=lambda kv: -kv[1][1])
+    for (program, cache), (n, total) in ranked[:12]:
+        out.write(f"    {program:<44}{n:>5}{total:>10.3f}  {cache}\n")
+    if len(ranked) > 12:
+        rest = ranked[12:]
+        out.write(f"    {'(' + str(len(rest)) + ' more)':<44}"
+                  f"{sum(n for _k, (n, _t) in rest):>5}"
+                  f"{sum(t for _k, (_n, t) in rest):>10.3f}\n")
 
 
 def report_pipeline(steps, out):
