@@ -811,17 +811,72 @@ def phase_serve_keye(size, platform):
     return out
 
 
+def require_short_blocks(tag, net, size, counters_hold):
+    """Kimi-K2's prefill attention at blocks shorter than the kernel's
+    128-position tiles, which `ServingEngine`'s default buckets (from 8
+    positions) are: the forward entry against the dense oracle at the
+    model's head widths, each row to its own length and zero past it;
+    then short prompts through an engine with the default floor, whose
+    counters must hold as the long buckets' do."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import serving
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    z = net._sizes
+    D, Dv = z.nope_dim + z.rope_dim, z.v_dim
+    for T in (8, 64, 200):
+        keys = jax.random.split(jax.random.key(T), 3)
+        q, k, v = (jax.random.normal(kk, (2, z.num_heads, T, d),
+                                     jnp.bfloat16)
+                   for kk, d in zip(keys, (D, D, Dv)))
+        lens = (T, max(T // 3, 1))
+        out = np.asarray(jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+            q, k, v, n, scale=D ** -0.5))(q, k, v, jnp.asarray(lens)),
+            np.float32)
+        ref = np.asarray(pa._dense_ref(q, k, v, True, D ** -0.5), np.float32)
+        for b, n in enumerate(lens):
+            err = float(np.abs(out[b, :, :n] - ref[b, :, :n]).max()
+                        / np.abs(ref[b]).max())
+            require(np.isfinite(out).all() and err <= KERNEL_TOL_FWD
+                    and not out[b, :, n:].any(),
+                    f"{tag}: a block of {T} positions, row of {n}: off the "
+                    f"dense oracle by {err}, or not zero past the length")
+    engine = serving.ServingEngine(
+        net, batch_buckets=(size.batch,),
+        dtype=jnp.dtype(size.kwargs.get("dtype", "float32")))
+    rng = np.random.RandomState(4)
+    buckets = []
+    for lens in ((5,), (3, 8, 21, 40)):
+        prompts = [rng.randint(0, net._vocab, n).tolist() for n in lens]
+        toks, timing = engine.serve_group(prompts, size.new_tokens)
+        B, S = timing["bucket"]
+        require(S < 128 and all(
+            len(t) == size.new_tokens
+            and all(0 <= int(x) < net._vocab for x in t) for t in toks)
+            and counters_hold(timing, lens + (1,) * (B - len(lens))),
+            f"{tag}: prompts of {lens} through bucket {(B, S)}: {toks}, "
+            f"{timing}")
+        buckets.append(S)
+    say(f"[{tag}] blocks of 8, 64 and 200 positions equal the dense "
+        f"oracle; short prompts served through buckets {buckets}")
+
+
 def phase_serve_kimi(size, platform):
     from mxnet_tpu.gluon.model_zoo import kimi_k2
 
     L = size.kwargs["num_layers"]
 
     def counters_hold(timing, lens):
+        # and every prefill attention call went through the flash
+        # forward kernel (ops/pallas_attention.py)
         return timing["attn_latent_positions_prefill"] \
             == L * sum(n * (n + 1) // 2 for n in lens) \
             and timing["attn_latent_positions_decode"] \
             == L * sum(n + j + 1 for n in lens
-                       for j in range(size.new_tokens - 1))
+                       for j in range(size.new_tokens - 1)) \
+            and timing["prefill_attn_kernel_share"] == 1.0
 
     # one stack with no heads, and none for the values
     net, engine, _, out = serve_family(
@@ -830,6 +885,7 @@ def phase_serve_kimi(size, platform):
     require(len(big) == 3 and big[0].shape
             == (L, 1, 1, z.kv_rank + z.rope_dim, engine._W),
             f"serve_kimi: cache {[tuple(c.shape) for c in big]}")
+    require_short_blocks("serve_kimi", net, size, counters_hold)
     return out
 
 

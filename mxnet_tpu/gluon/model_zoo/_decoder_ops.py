@@ -7,9 +7,9 @@ another; a change here is a change to all, and their cells measure it.
   the rotation (rotate-half over the first ``rot`` dimensions, by
   ``theta`` or by a table of frequencies: `yarn_frequencies`);
 - `attn_out`: heads side by side, then ``x + a Wo`` (attention over
-  the caches is `ops/cache_attention.py`, GPT's too);
-  `attend_causal_blocks`: a block's attention inside itself, query
-  block by query block up to the rows' length;
+  the caches is `ops/cache_attention.py`, GPT's too; a block's
+  attention inside itself is the family's own: Kimi-K2's goes through
+  `ops/pallas_attention.py::flash_attention_forward`);
 - `route`: the second norm and the router, the routing rule passed in
   (the dense feed-forward and the shared expert are
   `ops/moe.py::swiglu_ffn`); `experts_of_layer`: a scanned layer's held
@@ -89,55 +89,6 @@ def yarn_frequencies(rot, base, factor, original, beta_fast, beta_slow):
     f = base ** (-2.0 * i / rot)
     ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
     return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
-
-
-def attend_causal_blocks(q, k, v, live, blk):
-    """Causal attention of a block of S positions over itself, one query
-    block of ``blk`` after another and each over the key blocks up to
-    its own, with a running maximum and sum: the work follows the
-    triangle, and query blocks from position ``live`` on (a traced
-    scalar: where no row holds a real token any more) are left zero.
-
-    q (B, S, H, D) scaled; k (B, S, H, D); v (B, S, H, Dv); ``blk``
-    divides S.  Returns (B, S, H, Dv) in q's type."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    B, S, H, _ = q.shape
-    Dv = v.shape[-1]
-    at = jnp.arange(blk)
-
-    def query_block(i, out):
-        qi = lax.dynamic_slice_in_dim(q, i * blk, blk, axis=1)
-
-        def key_block(j, carry):
-            m, l, acc = carry
-            kj = lax.dynamic_slice_in_dim(k, j * blk, blk, axis=1)
-            vj = lax.dynamic_slice_in_dim(v, j * blk, blk, axis=1)
-            s = jnp.einsum("bqhd,bshd->bhqs", qi, kj,
-                           preferred_element_type=jnp.float32)
-            seen = (j * blk + at)[None, :] <= (i * blk + at)[:, None]
-            s = jnp.where(seen, s, _MASKED)
-            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m2[..., None])
-            scale = jnp.exp(m - m2)
-            acc = acc * scale[..., None] + jnp.einsum(
-                "bhqs,bshd->bhqd", p.astype(vj.dtype), vj,
-                preferred_element_type=jnp.float32)
-            return m2, l * scale + jnp.sum(p, axis=-1), acc
-
-        stat = (B, H, blk)
-        _, l, acc = lax.fori_loop(
-            0, i + 1, key_block,
-            (jnp.full(stat, _MASKED, jnp.float32),
-             jnp.zeros(stat, jnp.float32),
-             jnp.zeros(stat + (Dv,), jnp.float32)))
-        o = (acc / l[..., None]).swapaxes(1, 2).astype(out.dtype)
-        return lax.dynamic_update_slice_in_dim(out, o, i * blk, axis=1)
-
-    blocks = jnp.clip((live + blk - 1) // blk, 0, S // blk)
-    return lax.fori_loop(0, blocks, query_block,
-                         jnp.zeros((B, S, H, Dv), q.dtype))
 
 
 def attn_out(z, p, x, a):
@@ -266,15 +217,18 @@ def by_rows(fn, rows, x, *per_row):
     return lax.fori_loop(0, B // rows, one, (x, extras))
 
 
-def by_tokens(fn, tokens, live, x, *per_token):
+def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1):
     """``fn(x, *per_token) -> (x or None, extras)`` over the positions
-    of a block (axis 1 of every array, in and out), ``tokens`` at a time
-    and one chunk after another, so that only one chunk's temporaries
-    are alive.  Only the chunks that begin before position ``live`` (a
-    traced scalar, or None for all) are worked: past it the residual
-    stream stays what it was and the extras stay zero.  ``fn`` may
-    return None for x where it leaves the stream alone.  Whole when one
-    chunk holds every position.
+    of a block, ``tokens`` at a time and one chunk after another, so
+    that only one chunk's temporaries are alive.  The positions are
+    axis 1 of x, axis ``axes[i]`` of ``per_token[i]`` (1 where ``axes``
+    is None) and axis ``out_axes`` of the extras (an int for all, or a
+    tuple, one for each of a tuple of extras): heads-first arrays (B, H,
+    S, .) are cut and filled along 2.  Only the chunks that begin
+    before position ``live`` (a traced scalar, or None for all) are
+    worked: past it the residual stream stays what it was and the
+    extras stay zero.  ``fn`` may return None for x where it leaves the
+    stream alone.  Whole when one chunk holds every position.
 
     `chunk_rows` never cuts below one row, whose token-wise temporaries
     at a width of 7,168 and 16,384 positions are gigabytes; this is the
@@ -287,23 +241,28 @@ def by_tokens(fn, tokens, live, x, *per_token):
     if tokens >= S:
         out, extras = fn(x, *per_token)
         return (x if out is None else out), extras
+    axes = (1,) * len(per_token) if axes is None else axes
 
-    def chunk(a, c):
-        return lax.dynamic_slice_in_dim(a, c * tokens, tokens, axis=1)
+    def chunk(a, c, axis=1):
+        return lax.dynamic_slice_in_dim(a, c * tokens, tokens, axis=axis)
 
-    _, shapes = jax.eval_shape(fn, chunk(x, 0),
-                               *(chunk(a, 0) for a in per_token))
+    def cut(c):
+        return [chunk(a, c, axis) for a, axis in zip(per_token, axes)]
+
+    _, shapes = jax.eval_shape(fn, chunk(x, 0), *cut(0))
+    if isinstance(out_axes, int):
+        out_axes = jax.tree_util.tree_map(lambda _: out_axes, shapes)
     extras = jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape[:1] + (S,) + s.shape[2:], s.dtype),
-        shapes)
+        lambda s, axis: jnp.zeros(s.shape[:axis] + (S,) + s.shape[axis + 1:],
+                                  s.dtype), shapes, out_axes)
 
     def one(c, carry):
         x, extras = carry
-        xc, ex = fn(chunk(x, c), *(chunk(a, c) for a in per_token))
-        put = lambda whole, part: lax.dynamic_update_slice_in_dim(
-            whole, part, c * tokens, axis=1)
+        xc, ex = fn(chunk(x, c), *cut(c))
+        put = lambda whole, part, axis=1: lax.dynamic_update_slice_in_dim(
+            whole, part, c * tokens, axis=axis)
         return (x if xc is None else put(x, xc),
-                jax.tree_util.tree_map(put, extras, ex))
+                jax.tree_util.tree_map(put, extras, ex, out_axes))
 
     chunks = S // tokens if live is None else \
         jnp.clip((live + tokens - 1) // tokens, 0, S // tokens)

@@ -37,8 +37,11 @@ it never expands the cache.  ``W_uk`` is folded into the query
 shared key head of ``kv_rank + rope_dim`` whose first ``kv_rank`` rows
 are also the values, one copy a block.  Prefill (S > 1, from an empty
 cache) is *expanded*: the block's latents become ``num_heads`` keys and
-values and the block attends inside itself
-(`_decoder_ops.attend_causal_blocks`).
+values, heads first, and the block attends inside itself through
+`ops/pallas_attention.py::flash_attention_forward`, each row to its own
+length (any block length: the entry pads a bucket of 8-64 positions to
+the kernel's 128).  That call is a forward alone: neither path takes a
+gradient, as the loops with traced bounds before it took none.
 
 **Prefill works a row through all its layers before the next**
 (``prefill_chunk_tokens // S`` rows at a time, one at the published
@@ -55,7 +58,7 @@ at 819 GB/s is 68 ms of a prefill of seconds.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write
+from ...ops import cache_attention, cache_write, pallas_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
 
@@ -164,24 +167,29 @@ def _kv_up(z, p):
 
 
 def _expanded(z, p, cq, latent, pos):
-    """The block's queries, keys and values by head, (B, S, H, .) in the
-    weights' type: q scaled, k = [c W_uk ; k_r], v = c W_uv."""
+    """The block's queries, keys and values heads first, (B, H, S, .) in
+    the weights' type, as the kernel takes them: q scaled, k = [c W_uk ;
+    k_r], v = c W_uv.  q and k end in zeros up to a whole number of lane
+    tiles (192 -> 256), which is how HBM holds their rows anyway: the
+    kernel's copies move whole tiles, and the scores are the same."""
     import jax
     import jax.numpy as jnp
 
     B, S, _ = cq.shape
     H, dt = z.num_heads, cq.dtype
+    D = z.nope_dim + z.rope_dim
+    zeros = jnp.zeros((B, H, S, pallas_attention.lane_tiles(D) - D), dt)
     with jax.named_scope("serve.attn_q_up"):
-        q = jnp.concatenate(_queries(z, p, cq, pos), axis=-1) \
-            * z.softmax_scale
-        q = q.transpose(0, 2, 1, 3).astype(dt)
+        q = jnp.concatenate(
+            [(x * z.softmax_scale).astype(dt)
+             for x in _queries(z, p, cq, pos)] + [zeros], axis=-1)
     with jax.named_scope("serve.attn_kv_up"):
-        kv = _ops.mm("bsr,gr->bsg", latent[..., :z.kv_rank],
-                     p["kv_up_weight"]).reshape(B, S, H, -1).astype(dt)
-        kr = jnp.broadcast_to(latent[:, :, None, z.kv_rank:],
-                              (B, S, H, z.rope_dim))
-        return (q, jnp.concatenate([kv[..., :z.nope_dim], kr], axis=-1),
-                kv[..., z.nope_dim:])
+        kv = _ops.mm("bsr,hdr->bhsd", latent[..., :z.kv_rank],
+                     _kv_up(z, p)).astype(dt)
+        kr = jnp.broadcast_to(latent[:, None, :, z.kv_rank:],
+                              (B, H, S, z.rope_dim))
+        return (q, jnp.concatenate([kv[..., :z.nope_dim], kr, zeros],
+                                   axis=-1), kv[..., z.nope_dim:])
 
 
 def _absorbed_query(z, p, cq, pos):
@@ -239,33 +247,45 @@ def _feed_forward_front(z, p, x):
                                   p["shared_down_weight"]), route
 
 
-def _block_layer(z, p, x, pos, live):
+def _block_layer(z, p, x, pos, lengths, tally=None):
     """A layer on a block (B, S, C) that attends inside itself, as far
     as a row needs no other row: expanded attention, then the dense
-    feed-forward or the router and the shared expert; token-wise
-    products ``token_chunk`` positions at a time up to ``live`` (a
-    traced scalar: the rows' longest, or None).  Returns
+    feed-forward or the router and the shared expert.  ``lengths`` (B,)
+    traced, the rows' real positions, or None for all: token-wise
+    products run ``token_chunk`` positions at a time up to the longest
+    row's, the kernel (in blocks of ``attn_block`` where S is a
+    multiple of it, its own choice for a shorter block) to each row's
+    own, and a row's queries past its length come out zero.  ``tally`` (a
+    Counter or None) is told the attention call's path.  Returns
     (x, latent (B, S, kv_rank + rope_dim), route or ())."""
     import jax
+    import jax.numpy as jnp
 
     S = x.shape[1]
     chunk = min(S, z.token_chunk)
+    live = None if lengths is None else jnp.max(lengths)
 
     def front(x, pos):
         cq, latent = _down(z, p, x, pos)
         return None, (latent,) + _expanded(z, p, cq, latent, pos)
 
     def back(x, a):
+        # heads first as the kernel left them: contracted where they lie
         with jax.named_scope("serve.attn_out"):
-            x = x + _ops.mm("bsg,cg->bsc", a.reshape(a.shape[:2] + (-1,)),
-                            p["o_weight"])
+            x = x + _ops.mm("bhsd,chd->bsc", a, p["o_weight"].reshape(
+                -1, z.num_heads, z.v_dim))
         return _feed_forward_front(z, p, x)
 
-    x, (latent, q, k, v) = _ops.by_tokens(front, chunk, live, x, pos)
+    x, (latent, q, k, v) = _ops.by_tokens(front, chunk, live, x, pos,
+                                          out_axes=(1, 2, 2, 2))
     with jax.named_scope("serve.attn_full"):
-        a = _ops.attend_causal_blocks(q, k, v, S if live is None else live,
-                                      min(S, z.attn_block))
-    x, route = _ops.by_tokens(back, chunk, live, x, a)
+        # the queries arrive scaled (YaRN's factor inside)
+        block = None if S % z.attn_block else z.attn_block
+        a = pallas_attention.flash_attention_forward(
+            q, k, v, lengths, scale=1.0, block_q=block, block_k=block)
+        if tally is not None:
+            tally["kernel"] += 1
+    x, route = _ops.by_tokens(back, chunk, live, x, a, axes=(2,))
     return x, latent, route
 
 
@@ -408,9 +428,11 @@ class KimiK2Program:
         self.vocab = model._vocab
         self._pin = None
         # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the cache
+        # cache_reads[S]: its attention calls over the cache;
+        # block_attends[S]: its attention calls inside the block
         self.cache_writes = {}
         self.cache_reads = {}
+        self.block_attends = {}
         # what a reloaded model must share beyond its shapes
         self.signature = (
             z.num_heads, z.nope_dim, z.rope_dim, z.experts_held,
@@ -483,6 +505,7 @@ class KimiK2Program:
         decode = S == 1
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
+        attends = self.block_attends[S] = collections.Counter()
 
         def write(stack, latent, l, at, row):
             """The rows' latents (R, S, .) into the stack at [l, row + r,
@@ -519,8 +542,8 @@ class KimiK2Program:
                         z, p, _absorbed_out(z, p, x, a))
                     n_seen = jnp.sum(pos + 1)
                 else:
-                    x, latent, route = _block_layer(z, p, x, at,
-                                                    jnp.max(last) + 1)
+                    x, latent, route = _block_layer(z, p, x, at, last + 1,
+                                                    attends)
                     stack = write(stack, latent, l, pos, row)
                     n_live = (last + 1).astype(jnp.uint32)
                     n_seen = jnp.sum(n_live * (n_live + 1) // 2)

@@ -15,9 +15,51 @@ matrix nor the whole K/V sequence is ever resident:
   ``fori_loop``s: for each query sub-tile the key sub-tiles up to the
   diagonal, unmasked where they lie wholly below it and masked only
   where it crosses them; none above it.  A grid step wholly above the
-  diagonal (only where a head is several blocks) walks nothing; its
-  block copies hide behind the step before (index maps clamped to the
-  resident block measured no faster);
+  diagonal (only where a head is several blocks) walks nothing;
+- **one forward body, two ways its blocks arrive.**  `_fwd_tiles` (a
+  key block's part of a query block's running softmax), `_fwd_open` and
+  `_fwd_close` are the forward; `flash_attention` (training:
+  `custom_vjp`, the logsumexp rows written for the backward, one width
+  for keys and values) runs it under `_fwd_kernel`, whose blocks the
+  grid brings, as since PR 33.  `flash_attention_forward` (a serving
+  prefill: causal, no gradient, no logsumexp written) runs it under
+  `_fwd_rows_kernel` (PR 35), which takes what Kimi-K2's expanded heads
+  need.  **A value width of its own**: v is (B, H, T, Dv) and the
+  output and its accumulator are Dv wide (keys 192, values 128);
+  `_vmem_bytes` and `_block_sizes` count D for q and k and Dv for v, o
+  and the accumulator, and at Dv == D give what they gave.  **A length
+  a batch row** (``lengths`` (B,) int32, traced: scalar prefetch): a
+  row's queries at and past its length come out zero.  **No grid step
+  and no copy for work that does not exist**: the grid is (batch·heads,
+  q blocks) alone, 1,024 steps a call at 64 heads and 16,384 positions
+  where the grid over key blocks too has 16,384; a step walks its key
+  blocks to the diagonal in a ``fori_loop``, each brought from HBM
+  into one of two buffers by asynchronous copies while the block before
+  is worked (as `ops/cache_attention.py` does), a step's last block
+  starting the next step's first; a query block past the row's length
+  stores zeros, copies nothing and keeps the resident query block.
+  Measured on the v5e (PERF.md section 6, PR 35): a step that walks
+  nothing costs 0.354 us under the grid over key blocks even with its
+  index maps clamped so that it names the resident blocks and issues no
+  copy (5.80 ms a call of 16,384 empty steps; 1.16 ms here, the zeros'
+  268 MB included); at 8,912 live positions of 16,384 a call takes
+  15.5 ms here against 22.7 ms there, at 15,872 42.6 against 50.2.
+  **Why both drivers stay** (measured at one feed, PERF.md section 6,
+  PR 35 after review): with nothing to skip and equal widths the rows
+  driver is ahead at long heads ((1, 64, 16384, 128): 34.2 ms against
+  39.8; at 256 wide 60.9 against 72.7), but at training's shapes the
+  grid driver is: at (2, 8, 4096, 128) with the logsumexp written
+  0.668-0.679 ms against 0.693-0.695, and at the training cell's
+  (4, 16, 1024, 64) Mosaic refuses the rows driver's copies of 64-wide
+  rows, and with q, k and v padded to 128 lanes it takes 0.322-0.326 ms
+  against 0.274.
+  The copies move whole lane tiles (Mosaic refuses a 192-wide or a
+  64-wide row): keys or values whose width is no multiple of 128 are
+  padded with zeros in front of the call unless they arrive so
+  (Kimi-K2's model makes its keys 256 wide; HBM pads the rows of a
+  192-wide array to 256 anyway, and a 192-deep product takes the MXU's
+  two passes as a 256-deep one does), and so is a block of fewer
+  positions than a tile;
 - every product is fed the operands as they arrive (bfloat16 inputs go
   into the MXU as bfloat16, float32 as float32) and accumulates in
   float32; scores, the softmax, its running maximum and sum, the
@@ -44,8 +86,11 @@ matrix nor the whole K/V sequence is ever resident:
 
 On the CPU (tests, the virtual mesh) the kernels run in interpret mode,
 keeping one code path.  On TPU they compile through Mosaic, which needs
-128-aligned tiles: a sequence length not divisible by 128 raises, and
-nothing is substituted for the kernel the caller named.
+128-aligned tiles: `flash_attention` raises at a sequence length not
+divisible by 128, and nothing is substituted for the kernel the caller
+named; `flash_attention_forward` pads such a block with positions past
+every row's length and cuts its result back, on the CPU as on the TPU
+(a serving engine's small buckets: 8-64 positions).
 """
 
 from __future__ import annotations
@@ -97,30 +142,35 @@ def _sub_tiles(block_q, block_k):
                  for b in (block_q, block_k))
 
 
-def _vmem_bytes(T, D, dtype, kernel, block_q, block_k):
+def _vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv=None):
     """(what a grid step's blocks take of VMEM, what stays for a whole
     head): every operand block twice (the pipeline's double buffer),
     the blocks' float32 accumulators and a sub-tile's float32
     temporaries (three of them, fitted to what Mosaic accepted at
     `T` 4,096-16,384, `D` 64-256, both types); for the backward the
-    head's dq besides, its output block twice and its accumulator."""
+    head's dq besides, its output block twice and its accumulator.
+    Queries and keys are ``D`` wide; values, the output and its
+    accumulator ``Dv`` (``D`` where None; the backward has one width)."""
     item = jnp.dtype(dtype).itemsize
     sq, sk = _sub_tiles(block_q, block_k)
     qb, kb = block_q * D * item, block_k * D * item
     rows = _LSE_ROWS * block_q * 4
     tile = 3 * sq * sk * 4
     if kernel == "fwd":
-        blocks = 2 * qb + 2 * kb + rows             # q, o; k, v; lse
-        scratch = 2 * block_q * _LANE * 4 + block_q * D * 4
+        Dv = D if Dv is None else Dv
+        ob, vb = block_q * Dv * item, block_k * Dv * item
+        blocks = qb + ob + kb + vb + rows           # q, o; k, v; lse
+        scratch = 2 * block_q * _LANE * 4 + block_q * Dv * 4
         return 2 * blocks + scratch + tile, 0
     blocks = 2 * qb + 2 * rows + 4 * kb     # q, g; lse, delta; k, v, dk, dv
     scratch = 2 * block_k * D * 4
     return 2 * blocks + scratch + tile, 2 * T * D * item + T * D * 4
 
 
-def _block_sizes(T, D, dtype, kernel):
+def _block_sizes(T, D, dtype, kernel, Dv=None):
     """``(block_q, block_k)`` of the grid for ``kernel`` ("fwd" or
-    "bwd"), from what the kernel sees.
+    "bwd"), from what the kernel sees (``Dv``: the values' width where
+    it is not the keys').
 
     The rule: the largest lane-aligned block up to 1,024 that divides
     ``T`` and whose grid step fits `_VMEM_BUDGET`, the same for queries
@@ -133,7 +183,7 @@ def _block_sizes(T, D, dtype, kernel):
         # on TPU
         return T, T
     fits = [n for n in _aligned_divisors(T, _MAX_BLOCK)
-            if _vmem_bytes(T, D, dtype, kernel, n, n)[0] <= _VMEM_BUDGET]
+            if _vmem_bytes(T, D, dtype, kernel, n, n, Dv)[0] <= _VMEM_BUDGET]
     return (fits or [_LANE])[0], (fits or [_LANE])[0]
 
 
@@ -191,37 +241,32 @@ def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry):
 
 # -- forward -------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, scale, causal, block_q, block_k, sub_q, sub_k,
-                nk):
+def _fwd_tiles(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, tiles, q_first,
+               k_first, at, *, scale, causal, sub_q, sub_k):
+    """One key block's part of a query block's running softmax: q_ref
+    (1, block_q, D) from position ``q_first``, its first ``tiles``
+    sub-tiles; the key block ``at`` of k_ref (., block_k, D) and v_ref
+    (., block_k, Dv), resident, from position ``k_first``; maximum and
+    sum (block_q, 128) and the accumulator (block_q, Dv) in scratch."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    D = acc_scr.shape[1]
-
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    Dv = acc_scr.shape[1]
 
     def q_tile(a, carry):
         rows = pl.ds(pl.multiple_of(a * sub_q, sub_q), sub_q)
-        q0 = qi * block_q + a * sub_q
+        q0 = q_first + a * sub_q
         q = q_ref[0, rows, :]
 
         def tile(c, carry, masked):
             keys = pl.ds(pl.multiple_of(c * sub_k, sub_k), sub_k)
-            s = _dot(q, k_ref[0, keys, :], _NT) * scale   # (sub_q, sub_k)
+            s = _dot(q, k_ref[at, keys, :], _NT) * scale  # (sub_q, sub_k)
             if masked:
                 # query q0 + i sees key k0 + j iff i - j >= k0 - q0; a
                 # row of a visited sub-tile always holds a visible key
                 # or has seen key 0 before, so exp(_NEG - m) is 0.0
                 i_j = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-                s = jnp.where(
-                    i_j >= kj * block_k + c * sub_k - q0, s, _NEG)
+                s = jnp.where(i_j >= k_first + c * sub_k - q0, s, _NEG)
             m_prev = m_scr[rows, :]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
             p = jnp.exp(s - _bcast_lanes(m_next, sub_k))
@@ -229,23 +274,139 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             l_scr[rows, :] = (alpha * l_scr[rows, :]
                               + jnp.sum(p, axis=1)[:, None])
             m_scr[rows, :] = m_next
-            v = v_ref[0, keys, :]
-            acc_scr[rows, :] = (acc_scr[rows, :] * _bcast_lanes(alpha, D)
+            v = v_ref[at, keys, :]
+            acc_scr[rows, :] = (acc_scr[rows, :] * _bcast_lanes(alpha, Dv)
                                 + _dot(p.astype(v.dtype), v, _NN))
             return carry
 
-        return _walk(causal, q0, sub_q, kj * block_k, sub_k,
-                     block_k // sub_k, tile, carry)
+        return _walk(causal, q0, sub_q, k_first, sub_k,
+                     k_ref.shape[1] // sub_k, tile, carry)
 
-    jax.lax.fori_loop(0, block_q // sub_q, q_tile, 0)
+    jax.lax.fori_loop(0, tiles, q_tile, 0)
 
-    @pl.when(kj == nk - 1)
-    def _store():
-        l = l_scr[...]
-        lsafe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / _bcast_lanes(lsafe, D)).astype(
-            o_ref.dtype)
+
+def _fwd_open(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+
+def _fwd_close(o_ref, lse_ref, m_scr, l_scr, acc_scr, q_first, n):
+    """The query block's output (and logsumexp rows, where asked for)
+    from its finished sums; rows at and past the length ``n`` (None:
+    all live) zero."""
+    l = l_scr[...]
+    lsafe = jnp.where(l == 0.0, 1.0, l)
+    o = acc_scr[...] / _bcast_lanes(lsafe, acc_scr.shape[1])
+    if n is not None:
+        at = q_first + jax.lax.broadcasted_iota(jnp.int32, o.shape, 0)
+        o = jnp.where(at < n, o, 0.0)
+    o_ref[0] = o.astype(o_ref.dtype)
+    if lse_ref is not None:
         lse_ref[0] = _lse_to_rows(m_scr[...] + jnp.log(lsafe))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, scale, causal, block_q, block_k, sub_q, sub_k, nk):
+    """The forward with its blocks brought by the grid (training's): a
+    step is query block ``qi`` of a head over key block ``kj``; the
+    sums are opened on a head's first key block and closed on its last,
+    where the logsumexp rows are written for the backward."""
+    from jax.experimental import pallas as pl
+
+    scr = (m_scr, l_scr, acc_scr)
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    pl.when(kj == 0)(lambda: _fwd_open(*scr))
+    _fwd_tiles(q_ref, k_ref, v_ref, *scr, block_q // sub_q, qi * block_q,
+               kj * block_k, 0, scale=scale, causal=causal, sub_q=sub_q,
+               sub_k=sub_k)
+    pl.when(kj == nk - 1)(lambda: _fwd_close(o_ref, lse_ref, *scr,
+                                             qi * block_q, None))
+
+
+def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+                     m_scr, l_scr, acc_scr, slot_ref, *, scale, block_q,
+                     block_k, sub_q, sub_k, heads):
+    """The causal forward with its key blocks brought by the kernel (a
+    serving prefill's): a step is query block ``qi`` of a head over ALL
+    the key blocks it can see, to its diagonal, and none where the block
+    lies past the row's length (``len_ref``, scalar prefetch; ``heads``
+    grid rows a batch row): it stores zeros.  k and v stay in HBM; a
+    key block's (block_k, D) and (block_k, Dv) come into one of two
+    buffers by asynchronous copies while the block before is worked,
+    and every step leaves the next walking step's first block in
+    flight, so no copy waits for a grid step.  Scratch besides the
+    buffers: the copies' semaphores (k or v, buffer), the running sums,
+    and which buffer the step's first block is in."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    scr = (m_scr, l_scr, acc_scr)
+    b, qi = pl.program_id(0), pl.program_id(1)
+    nh, nq = pl.num_programs(0), pl.num_programs(1)
+    n = len_ref[b // heads]
+    # the block's query sub-tiles that hold a live position
+    tiles = jnp.clip(pl.cdiv(n - qi * block_q, sub_q), 0, block_q // sub_q)
+
+    def blocks_of(head, i):
+        """Key blocks that query block ``i`` of ``head`` walks."""
+        live = i * block_q < len_ref[head // heads]
+        return jnp.where(live, (i * block_q + block_q - 1) // block_k + 1, 0)
+
+    def copies(head, j, slot):
+        at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        return (pltpu.make_async_copy(k_hbm.at[head, at, :], k_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[head, at, :], v_buf.at[slot],
+                                      sem.at[1, slot]))
+
+    blocks = blocks_of(b, qi)
+
+    @pl.when((b == 0) & (qi == 0))
+    def _first():
+        slot_ref[0] = 0
+
+        @pl.when(blocks > 0)
+        def _():
+            for c in copies(b, 0, 0):
+                c.start()
+
+    base = slot_ref[0]
+    wrap = qi + 1 == nq
+    nb, ni = jnp.where(wrap, b + 1, b), jnp.where(wrap, 0, qi + 1)
+
+    def hand_on(slot):
+        """The next step's first block into ``slot``, if it walks any."""
+        @pl.when((nb < nh) & (blocks_of(jnp.minimum(nb, nh - 1), ni) > 0))
+        def _():
+            for c in copies(nb, 0, slot):
+                c.start()
+
+    _fwd_open(*scr)
+
+    def block(j, carry):
+        slot = (base + j) % 2
+
+        # the next block sets out before this one is waited for: the
+        # step's own, or behind its last the next step's first
+        @pl.when(j + 1 < blocks)
+        def _ahead():
+            for c in copies(b, j + 1, 1 - slot):
+                c.start()
+
+        pl.when(j + 1 == blocks)(lambda: hand_on(1 - slot))
+        for c in copies(b, j, slot):
+            c.wait()
+        _fwd_tiles(q_ref, k_buf, v_buf, *scr, tiles, qi * block_q,
+                   j * block_k, slot, scale=scale, causal=True,
+                   sub_q=sub_q, sub_k=sub_k)
+        return carry
+
+    jax.lax.fori_loop(0, blocks, block, 0)
+    pl.when(blocks == 0)(lambda: hand_on(base))
+    slot_ref[0] = (base + blocks) % 2
+    _fwd_close(o_ref, None, *scr, qi * block_q, n)
 
 
 def _sds(shape, dtype, vma):
@@ -256,10 +417,10 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _blocks(T, D, dtype, kernel, block_q, block_k):
+def _blocks(T, D, dtype, kernel, block_q, block_k, Dv=None):
     """The grid's blocks for ``kernel``: the caller's where it names
     them, `_block_sizes`' otherwise; they must divide ``T``."""
-    dbq, dbk = _block_sizes(T, D, dtype, kernel)
+    dbq, dbk = _block_sizes(T, D, dtype, kernel, Dv)
     bq, bk = int(block_q or dbq), int(block_k or dbk)
     if T % bq or T % bk:
         raise ValueError(
@@ -269,10 +430,11 @@ def _blocks(T, D, dtype, kernel, block_q, block_k):
     return bq, bk
 
 
-def _compiler_params(T, D, dtype, kernel, block_q, block_k, semantics):
+def _compiler_params(T, D, dtype, kernel, block_q, block_k, semantics,
+                     Dv=None):
     from jax.experimental.pallas import tpu as pltpu
 
-    need = sum(_vmem_bytes(T, D, dtype, kernel, block_q, block_k))
+    need = sum(_vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv))
     limit = {} if need <= _VMEM_DEFAULT else {
         "vmem_limit_bytes": need + need // 4}
     return pltpu.CompilerParams(dimension_semantics=semantics, **limit)
@@ -325,6 +487,58 @@ def _flash_call(q, k, v, causal, scale, block_q=None, block_k=None,
         **kw,
     )(qr, kr, vr)
     return out.reshape(B, H, T, D), lse
+
+
+def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None):
+    """`_fwd_rows_kernel` over q, k (B, H, T, D), v (B, H, T, Dv) and
+    ``lengths`` (B,) int32 within [0, T]: grid (B·H, query blocks), both
+    axes in order (a step hands the next its first block)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, D = q.shape
+    Dv = v.shape[-1]
+    block_q, block_k = _blocks(T, D, q.dtype, "fwd", block_q, block_k, Dv)
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
+    interpret = _use_interpret()
+    kw = {} if interpret else {
+        "compiler_params": _compiler_params(
+            T, D, q.dtype, "fwd", block_q, block_k,
+            ("arbitrary", "arbitrary"), Dv)}
+
+    def q_at(b, i, lens):
+        # no further than the row's last live block: a step that stores
+        # zeros keeps the query block that is resident
+        return (b, jnp.minimum(i, jnp.maximum(
+            pl.cdiv(lens[b // H], block_q), 1) - 1), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_fwd_rows_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, sub_q=sub_q, sub_k=sub_k,
+                          heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, T // block_q),
+            in_specs=[pl.BlockSpec((1, block_q, D), q_at),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, block_q, Dv),
+                                   lambda b, i, lens: (b, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_k, D), k.dtype),
+                pltpu.VMEM((2, block_k, Dv), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
+        interpret=interpret,
+        **kw,
+    )(lengths, q.reshape(B * H, T, D), k.reshape(B * H, T, D),
+      v.reshape(B * H, T, Dv))
+    return out.reshape(B, H, T, Dv)
 
 
 # -- backward (FlashAttention-2, one call) -------------------------------------
@@ -496,6 +710,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     varying-mesh-axes set when calling from inside a check_vma=True
     shard_map region (ring/ulysses)."""
     T = q.shape[2]
+    if v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            "flash_attention: the backward kernel has one width for keys "
+            f"and values, got {q.shape[-1]} and {v.shape[-1]} "
+            "(flash_attention_forward takes a value width of its own)")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if T % _LANE != 0 and not _use_interpret():
@@ -506,3 +725,48 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     _blocks(T, q.shape[-1], q.dtype, "fwd", block_q, block_k)  # they divide
     return _flash_core(q, k, v, bool(causal), float(scale), block_q,
                        block_k, tuple(vma))
+
+
+def lane_tiles(n):
+    """``n`` up to a whole number of lane tiles (128): the width at
+    which `flash_attention_forward` takes queries and keys as they are,
+    and the length it works a shorter block at."""
+    return n + -n % _LANE
+
+
+def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
+                            block_k=None):
+    """Causal attention, the forward alone, for a caller that takes no
+    gradient (a serving prefill): no ``custom_vjp``, no logsumexp
+    written, and no derivative (JAX has none for the call).  q, k (B, H,
+    T, D); v (B, H, T, Dv), ``Dv`` need not be ``D``; returns (B, H, T,
+    Dv) in q's type.  ``scale`` is the caller's to give: the entry
+    cannot tell a head's width from zeros it arrives padded with.
+
+    ``lengths`` (B,) int32, a traced operand (None: all ``T``): row b
+    holds ``lengths[b]`` real positions from 0.  Its queries at and past
+    the length come out zero, and no grid step, copy or product is
+    spent on them or above the diagonal (`_fwd_rows_kernel`).
+
+    The kernel's tiles and copies are whole lane tiles, here and on the
+    TPU alike (`lane_tiles`).  **Any ``T``**: a block whose length is no
+    multiple of 128 (a serving bucket of 8-64 positions) is padded with
+    positions past every row's length, which cost a step that stores
+    zeros at most, and the result is cut back to ``T``; ``block_q`` and
+    ``block_k`` then divide the padded length.  A width ``D`` or ``Dv``
+    that is no multiple of 128 is padded with zeros (Mosaic refuses the
+    copy of a narrower row), one more pass over the operand: a caller
+    that can make q and k that wide to begin with spares it (HBM pads
+    their rows to whole tiles anyway), and the scores are the same."""
+    B, T, Dv = q.shape[0], q.shape[2], v.shape[-1]
+    lengths = (jnp.full((B,), T, jnp.int32) if lengths is None
+               else jnp.clip(lengths.astype(jnp.int32), 0, T))
+
+    def whole(x):
+        """x's positions and width up to whole lane tiles."""
+        more = [(0, lane_tiles(n) - n) for n in x.shape[2:]]
+        return jnp.pad(x, [(0, 0), (0, 0)] + more) if any(
+            m for _, m in more) else x
+
+    return _flash_rows_call(whole(q), whole(k), whole(v), float(scale),
+                            lengths, block_q, block_k)[:, :, :T, :Dv]

@@ -281,6 +281,8 @@ class ContinuousBatcher:
                     "decode_attn_kernel_share"),
                 decode_attn_window_read_pct=timings.get(
                     "decode_attn_window_read_pct"),
+                prefill_attn_kernel_share=timings.get(
+                    "prefill_attn_kernel_share"),
                 **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step

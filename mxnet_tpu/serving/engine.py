@@ -240,7 +240,10 @@ class ServingEngine:
       once a group, merged into the timings), ``cache_writes`` (by
       block length S, the row writes `ops/cache_write.py` counted by
       path while the step was traced), ``cache_reads`` (likewise the
-      attention calls `ops/cache_attention.py` counted) and
+      attention calls `ops/cache_attention.py` counted),
+      ``block_attends`` (likewise a prefill block's attention calls
+      inside itself, by path: ``"kernel"`` through
+      `ops/pallas_attention.py`) and
       ``signature`` (what a reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
@@ -647,6 +650,14 @@ class ServingEngine:
             if dispatched:
                 timings["decode_attn_window_read_pct"] = \
                     _window_read_pct(reads, lens, dispatched)
+        # of the prefill program's attention calls inside its block, the
+        # share that went through the flash forward kernel
+        # (ops/pallas_attention.py), as the family's program counted
+        # them while it was traced
+        attends = getattr(self._program, "block_attends", {}).get(int(S))
+        if attends:
+            timings["prefill_attn_kernel_share"] = \
+                attends["kernel"] / sum(attends.values())
         counters = getattr(self._program, "counters", None)
         if counters is not None:
             # what the family counted in its donated carry: one small

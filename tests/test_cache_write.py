@@ -373,3 +373,106 @@ def test_the_flash_kernels_compile_for_a_v5e(one_chip, monkeypatch, name,
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
     assert [o.shape for o in compiled.out_info[1]] == [(1, BH, T, D)] * 3
+
+
+def test_the_prefill_flash_kernel_compiles_for_a_v5e(one_chip, monkeypatch):
+    """`flash_attention_forward` at Kimi-K2.6's prefill shape (one row a
+    chunk, 64 heads, a bucket of 16,384, keys 192 wide arriving padded
+    to 256 and values 128, the row's length traced): Mosaic takes the
+    kernel that brings its own key blocks at blocks of 1,024 inside the
+    default scoped VMEM, bfloat16 in and out, the length its scalar
+    prefetch, and no logsumexp is written; keys that arrive 192 wide
+    are padded in front of the same call."""
+    import re
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    H, T, D, Dv = 64, 16384, 256, 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pa._block_sizes(T, D, jnp.bfloat16, "fwd", Dv) == (1024, 1024)
+    assert sum(pa._vmem_bytes(T, D, jnp.bfloat16, "fwd", 1024, 1024,
+                              Dv)) <= pa._VMEM_DEFAULT
+    for width in (D, 192):
+        lowered = jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+            q, k, v, n, scale=1.0)).lower(
+                sds((1, H, T, width)), sds((1, H, T, width)),
+                sds((1, H, T, Dv)), sds((1,), jnp.int32))
+        call, = [line for line in lowered.as_text().split("\n")
+                 if "@tpu_custom_call" in line]
+        assert re.search(r'kernel_name = "(\w+)"',
+                         call).group(1) == "_fwd_rows_kernel"
+        assert re.findall(r"tensor<([\dx]+)x(\w+)>",
+                          call.split(" : (")[-1]) == [
+            ("1", "i32"), (f"{H}x{T}x{D}", "bf16"), (f"{H}x{T}x{D}", "bf16"),
+            (f"{H}x{T}x{Dv}", "bf16"), (f"{H}x{T}x{Dv}", "bf16")]
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (1, H, T, Dv)
+
+
+@pytest.mark.parametrize("S,D,Dv", [(8, 256, 128), (64, 256, 128),
+                                    (200, 256, 128), (128, 64, 64)])
+def test_the_prefill_flash_kernel_compiles_at_a_small_bucket(
+        one_chip, monkeypatch, S, D, Dv):
+    """A serving engine's small prefill buckets (its floor is 8) at
+    Kimi-K2.6's widths, eight rows a chunk: a block shorter than the
+    kernel's 128-position tiles is padded in front of the call and cut
+    back behind it, so Mosaic takes it whatever ``S``; the blocks are
+    the kernel's own choice (128 here).  And heads of 64, whose rows
+    Mosaic's copies refuse: keys and values both arrive padded."""
+    import re
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    B, H, T = 8, 64, pa.lane_tiles(S)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+        q, k, v, n, scale=1.0)).lower(
+            sds((B, H, S, D)), sds((B, H, S, D)), sds((B, H, S, Dv)),
+            sds((B,), jnp.int32))
+    call, = [line for line in lowered.as_text().split("\n")
+             if "@tpu_custom_call" in line]
+    assert re.findall(r"tensor<([\dx]+)x(\w+)>", call.split(" : (")[-1]) == [
+        (f"{B}", "i32")] + [
+        (f"{B * H}x{T}x{pa.lane_tiles(d)}", "bf16") for d in (D, D, Dv, Dv)]
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (B, H, S, Dv)
+
+
+@pytest.mark.parametrize("S", [8, 64])
+def test_kimis_block_layer_compiles_at_a_small_bucket(one_chip, monkeypatch,
+                                                      S):
+    """Kimi-K2's prefill layer (`kimi_k2._block_layer`: the expanded
+    heads, the flash forward kernel to each row's length, the dense
+    feed-forward) at the buckets a default `ServingEngine` warms up
+    (its prefill floor is 8), at `chip_smoke.kimi_small`'s widths:
+    XLA:TPU and Mosaic take it, the kernel in it."""
+    import chip_smoke
+    from mxnet_tpu.gluon.model_zoo import kimi_k2
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    z = kimi_k2.KimiK2Model(**chip_smoke.kimi_small().kwargs)._sizes
+    B = 8
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {n: sds(z.shape_of("l0_" + n))
+         for n in kimi_k2._ATTN_LEAVES + kimi_k2._DENSE_LEAVES}
+    compiled = jax.jit(
+        lambda p, x, pos, n: kimi_k2._block_layer(z, p, x, pos, n)[:2]).lower(
+            p, sds((B, S, z.units), jnp.float32), sds((B, S), jnp.int32),
+            sds((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert [o.shape for o in compiled.out_info] == [
+        (B, S, z.units), (B, S, z.latent)]

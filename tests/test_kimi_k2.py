@@ -216,13 +216,18 @@ def test_the_float8_reference_fails_the_bfloat16_tolerance():
 
 # -- (c) the absorbed path equals the expanded one -----------------------------
 
-@pytest.mark.parametrize("layer", [0, 1])
-def test_the_absorbed_path_equals_the_expanded_one(layer):
+@pytest.mark.parametrize("layer,short", [(0, False), (0, True), (1, True)],
+                         ids=["l0-whole", "l0-to-lengths", "l1-to-lengths"])
+def test_the_absorbed_path_equals_the_expanded_one(layer, short):
     """One layer's attention for each row's last query: W_uk folded into
     the query and W_uv into the output over the cached latents, against
-    keys and values expanded by head; and both against the reference's
-    layer."""
+    keys and values expanded by head through the flash forward kernel
+    (in blocks of 8; ``short``: each row to its own length, short of S
+    for two of the three, and zero past it); and both against the
+    reference's layer."""
     import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention
 
     cfg = _config()
     net, values = _net(cfg)
@@ -235,9 +240,16 @@ def test_the_absorbed_path_equals_the_expanded_one(layer):
     last = np.asarray([S - 1, 20, 4])
     cq, latent = kimi_k2._down(z, p, x, pos)
     q, k, v = kimi_k2._expanded(z, p, cq, latent, pos)
-    a = ops.attend_causal_blocks(q, k, v, S, 8)
+    a = pallas_attention.flash_attention_forward(
+        q, k, v, jnp.asarray(last + 1) if short else None, scale=1.0,
+        block_q=8, block_k=8)
+    assert a.shape == (B, z.num_heads, S, z.v_dim)
+    if short:
+        for b, n in enumerate(last):
+            assert not np.asarray(a[b, :, n + 1:]).any()
     expanded = np.asarray(x + ops.mm(
-        "bsg,cg->bsc", a.reshape(B, S, -1), p["o_weight"]))
+        "bhsd,chd->bsc", a, p["o_weight"].reshape(-1, z.num_heads,
+                                                  z.v_dim)))
     rows = jnp.arange(B)
     at = jnp.asarray(last)
     qa = kimi_k2._absorbed_query(z, p, cq[rows, at][:, None], at[:, None])
@@ -254,6 +266,38 @@ def test_the_absorbed_path_equals_the_expanded_one(layer):
                                    rtol=1e-4)
         np.testing.assert_allclose(absorbed[b], want[b, n], atol=2e-5,
                                    rtol=1e-4)
+
+
+def test_the_prefill_kernel_share_reaches_the_batchers_record():
+    """Every attention call of the prefill program inside its block
+    (layer 0's and the scanned layers' one) goes through the flash
+    forward kernel, interpreted here: ``prefill_attn_kernel_share`` is
+    1.0 in a served group's timings and in each request's record, and
+    the decode program's tally holds no such call."""
+    from mxnet_tpu import telemetry
+
+    net, _ = _net(_config())
+    eng = serving.ServingEngine(net, batch_buckets=(2,))
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, 96, n) for n in (11, 5)]
+    _, timings = eng.serve_group(prompts, 2)
+    assert timings["bucket"] == [2, 16]
+    assert timings["prefill_attn_kernel_share"] == 1.0
+    assert dict(eng._program.block_attends[16]) == {"kernel": 2}
+    assert not eng._program.block_attends[1]
+    telemetry.reset()
+    batcher = serving.ContinuousBatcher(eng, max_delay_ms=150, max_batch=2)
+    try:
+        futs = [batcher.submit(p, 2) for p in prompts]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        batcher.close()
+    requests = telemetry.recent_requests()
+    assert len(requests) == 2
+    for r in requests:
+        telemetry.validate_record(r)
+        assert r["prefill_attn_kernel_share"] == 1.0
 
 
 # -- (d) the share ties to the model -------------------------------------------

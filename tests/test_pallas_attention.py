@@ -1,6 +1,8 @@
 """`ops/pallas_attention.py`: the flash kernels (interpreted here)
 against the dense oracle at the blocks the shape gives and at forced
-small ones, what their products are fed, and the block rule.  The
+small ones, what their products are fed, and the block rule; the
+forward-only entry with a value width of its own and a length a row,
+and that training's call lowers to the grid it had.  The
 compiles for a described v5e are in `tests/test_cache_write.py` (one
 worker loads the TPU's library)."""
 
@@ -96,17 +98,157 @@ def test_forward_and_gradients_match_the_dense_oracle(T, D, causal, dtype,
             atol=atol, err_msg=f"d{name}")
 
 
-def _products(jaxpr, found):
-    """Every ``dot_general`` of a jaxpr and of the jaxprs inside it."""
+# -- the forward-only entry: a value width of its own, a length a row ----------
+
+# each batch row its own length: none, one position, a block's edge, one
+# past it, the middle of a sub-tile, all
+LENGTHS = [0, 1, 128, 129, 300, 512]
+
+
+def _wide_inputs(D, Dv, dtype, T=512, rows=len(LENGTHS), heads=2):
+    keys = jax.random.split(jax.random.key(D + Dv), 3)
+    return [jax.random.normal(k, (rows, heads, T, d), dtype)
+            for k, d in zip(keys, (D, D, Dv))]
+
+
+@pytest.mark.parametrize("blocks", [None, (128, 128), (256, 128)],
+                         ids=["default", "128x128", "256x128"])
+@pytest.mark.parametrize("given", [False, True], ids=["whole", "lengths"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("D,Dv", [(192, 128), (32, 16)])
+def test_forward_entry_matches_the_dense_oracle(D, Dv, dtype, given, blocks):
+    """`flash_attention_forward` with values narrower than keys (Kimi's
+    192 / 128 and a small pair; keys no multiple of 128 wide are padded
+    inside): the dense oracle's result, (B, H, T, Dv); with ``lengths``
+    each row equals the oracle below its length and is exactly zero at
+    and past it, whatever the blocks (several key blocks a step through
+    the two buffers, a step's first block handed on by the step before,
+    also over rows and query blocks that walk nothing)."""
+    q, k, v = _wide_inputs(D, Dv, dtype)
+    kw = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+    lengths = jnp.asarray(LENGTHS, jnp.int32) if given else None
+    out = jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+        q, k, v, n, scale=D ** -0.5, **kw))(q, k, v, lengths)
+    assert out.dtype == dtype and out.shape == v.shape
+    ref = np.asarray(pa._dense_ref(*(x.astype(F32) for x in (q, k, v)),
+                                   True, D ** -0.5))
+    out = np.asarray(out, np.float32)
+    rtol, atol = TOL[dtype]["fwd"]
+    for b, n in enumerate(LENGTHS if given else [512] * len(LENGTHS)):
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=rtol,
+                                   atol=atol, err_msg=f"row of {n}")
+        assert not out[b, :, n:].any(), f"row of {n}"
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T,lengths", [(8, (8, 3, 0)), (64, (64, 1, 40)),
+                                       (200, (200, 128, 129))])
+def test_forward_entry_takes_a_block_shorter_than_its_tiles(T, lengths,
+                                                            dtype):
+    """A serving engine's small buckets (8-64 positions; any ``T`` that
+    is no multiple of 128): the entry pads the block with positions past
+    every row's length, the kernel works whole tiles as it must on the
+    TPU, and the result is cut back: the dense oracle's below each
+    row's length, zero at and past it, (B, H, T, Dv)."""
+    D, Dv = 192, 128
+    q, k, v = _wide_inputs(D, Dv, dtype, T=T, rows=len(lengths))
+    fn = jax.jit(lambda n: pa.flash_attention_forward(q, k, v, n,
+                                                      scale=D ** -0.5))
+    call, = _pallas_calls(jax.make_jaxpr(fn)(jnp.asarray(lengths)).jaxpr, [])
+    assert [x.aval.shape[1:] for x in call.invars[1:]] == [
+        (pa.lane_tiles(T), 256)] * 2 + [(pa.lane_tiles(T), Dv)]
+    ref = np.asarray(pa._dense_ref(*(x.astype(F32) for x in (q, k, v)),
+                                   True, D ** -0.5))
+    rtol, atol = TOL[dtype]["fwd"]
+    for given in (jnp.asarray(lengths, jnp.int32), None):
+        out = fn(given) if given is not None else jax.jit(
+            lambda: pa.flash_attention_forward(q, k, v, scale=D ** -0.5))()
+        assert out.dtype == dtype and out.shape == v.shape
+        out = np.asarray(out, np.float32)
+        for b, n in enumerate(lengths if given is not None
+                              else (T,) * len(lengths)):
+            np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n],
+                                       rtol=rtol, atol=atol,
+                                       err_msg=f"row of {n}")
+            assert not out[b, :, n:].any(), f"row of {n}"
+
+
+def test_what_the_entries_refuse():
+    q, k, v = _wide_inputs(32, 16, F32, T=128, rows=1)
+    with pytest.raises(ValueError, match="one width"):
+        pa.flash_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="must divide"):
+        pa.flash_attention_forward(q, k, v, scale=1.0, block_q=96)
+    # a head's width cannot be told from the zeros it arrives padded with
+    with pytest.raises(TypeError, match="scale"):
+        pa.flash_attention_forward(q, k, v)
+
+
+def _pallas_calls(jaxpr, found):
+    return _eqns(jaxpr, "pallas_call", found)
+
+
+@pytest.mark.parametrize("shape,grid", [((4, 16, 1024, 64), (64, 1, 1)),
+                                        ((2, 8, 4096, 128), (16, 4, 4))],
+                         ids=["train-cell", "2x8x4096x128"])
+def test_equal_widths_and_no_length_lower_to_the_parents_grid(shape, grid):
+    """Training's call (equal widths, no length) has the grid, the
+    block shapes and the sub-tiles it had before the forward body took
+    a value width and a length: blocks of 1,024, sub-tiles of 512, no
+    scalar prefetch, and index maps that are the grid indices as they
+    are (no clamp)."""
+    B, H, T, D = shape
+    assert pa._block_sizes(T, D, BF16, "fwd") == (1024, 1024)
+    assert pa._block_sizes(T, D, BF16, "fwd", D) == (1024, 1024)
+    assert pa._sub_tiles(1024, 1024) == (512, 512)
+    x = jax.ShapeDtypeStruct(shape, BF16)
+    fwd, = _pallas_calls(jax.make_jaxpr(lambda q, k, v: pa.flash_attention(
+        q, k, v, causal=True))(x, x, x).jaxpr, [])
+    mapping = fwd.params["grid_mapping"]
+    assert mapping.grid == grid
+    assert mapping.num_index_operands == 0
+    blocks = [tuple(int(getattr(d, "block_size", d) or 1)
+                    for d in m.block_shape) for m in mapping.block_mappings]
+    assert blocks == [(1, 1024, D)] * 4 + [(1, 8, 1024)]      # q k v o lse
+    for m in mapping.block_mappings:
+        assert not m.index_map_jaxpr.jaxpr.eqns     # (b, i, 0), (b, j, 0)
+
+
+def test_the_forward_entry_is_a_grid_over_query_blocks():
+    """Kimi-K2.6's prefill shape: 1,024 steps a call (64 heads x 16
+    query blocks of 1,024) where the grid over key blocks too would be
+    16,384; the lengths are the scalar prefetch, q and o come in blocks
+    and k and v stay where they are; keys of 192 arrive 256 wide."""
+    H, T = 64, 16384
+    call, = _pallas_calls(jax.make_jaxpr(
+        lambda q, k, v, n: pa.flash_attention_forward(q, k, v, n, scale=1.0))(
+            *(jax.ShapeDtypeStruct((1, H, T, d), BF16) for d in (192, 192,
+                                                                 128)),
+            jax.ShapeDtypeStruct((1,), jnp.int32)).jaxpr, [])
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (H, T // 1024)
+    assert mapping.num_index_operands == 1
+    assert [v.aval.shape for v in call.invars] == [
+        (1,), (H, T, 256), (H, T, 256), (H, T, 128)]
+    assert pa._block_sizes(T, 256, BF16, "fwd", 128) == (1024, 1024)
+
+
+def _eqns(jaxpr, primitive, found):
+    """Every equation of that primitive in a jaxpr and the jaxprs inside
+    it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
+        if eqn.primitive.name == primitive:
             found.append(eqn)
         for val in eqn.params.values():
             for sub in (val if isinstance(val, (list, tuple)) else [val]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _products(sub, found)
+                    _eqns(sub, primitive, found)
     return found
+
+
+def _products(jaxpr, found):
+    return _eqns(jaxpr, "dot_general", found)
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bfloat16", "float32"])
