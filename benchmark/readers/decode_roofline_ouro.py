@@ -1,0 +1,51 @@
+"""The Ouro decode step's share of its roofline: the least time the chip
+could take for one step (the larger of required bytes over HBM bandwidth
+and required operations over the bf16 peak, `flops_ouro.py`) over the
+decode program's device time in the trace (the median execution of
+``jit_serve_decode``).  Bytes: the layers' weights once a loop step, the
+head once, and of the caches what a live row's step has to read: its
+positions to its length, in every (loop step, layer) slot.  Mean over
+the traced groups' steps, counting only rows that still wanted a token.
+None where the records carry no loop counters (a program without them)
+or there is no trace."""
+
+import statistics
+
+import numpy as np
+
+from benchmark import flops_ouro as flops
+
+
+def read(run, params):
+    modules = run["trace"]["modules"]
+    times = [t for k, v in modules.items()
+             if k.startswith(params.get("program", "jit_serve_decode"))
+             for t in v]
+    if not times:
+        return None
+    device_s = statistics.median(times)
+    config = run["cell"]["config"]
+    itemsize = np.dtype(params.get("itemsize_of", "float16")).itemsize
+    groups = {}
+    for rec in run["records"]:
+        if "t_decode0" in rec and "loop_passes_decode" in rec:
+            groups.setdefault(rec["t_decode0"], []).append(rec)
+    need_bytes, need_flops = [], []
+    for recs in groups.values():
+        steps = max(len(r["tokens"]) for r in recs) - 1
+        for j in range(steps):
+            live = [len(r["prompt"]) + j + 1 for r in recs
+                    if len(r["tokens"]) > j + 1]
+            need_bytes.append(flops.decode_step_bytes(config, itemsize,
+                                                      live))
+            need_flops.append(flops.decode_step_flops(
+                config, len(live), flops.slots(config) * sum(live)))
+    if not need_bytes or device_s <= 0:
+        return None
+    t_bytes = statistics.mean(need_bytes) / run["peaks"]["hbm_bytes_per_s"]
+    t_flops = statistics.mean(need_flops) / run["peaks"]["bf16_flops_per_s"]
+    run.setdefault("notes", []).append(
+        f"decode step: bound by {'bytes' if t_bytes >= t_flops else 'flops'}"
+        f" ({t_bytes * 1e3:.3f} ms against {t_flops * 1e3:.3f} ms), "
+        f"jit_serve_decode took {device_s * 1e3:.3f} ms on the device")
+    return 100.0 * max(t_bytes, t_flops) / device_s

@@ -13,9 +13,12 @@ cut of ``benchmark/configs/mimo-v2.5-ep16.json``: window and full
 layers, two kinds of cache, a chip's share of the routed experts); then
 a third, ``KeyeVL2Model`` at small aligned sizes (a learned indexer with
 a cache stack of its own, attention over its selection, softmax-routed
-experts); with four chips, the GPT step under ``shard_model`` fsdp and
-tp.  Phases, in order: device, sync, kernel, train, serve, serve_mimo,
-serve_keye, serve_kimi, sharded.  The first failed check raises and the process
+experts); a fourth, ``KimiK2Model`` (latent attention); a fifth,
+``OuroModel`` (a dense stack run several times a token over one set of
+weights, a cache slot for every (loop step, layer), held to the float32
+reference's logits); with four chips, the GPT step under
+``shard_model`` fsdp and tp.  Phases, in order: device, sync, kernel,
+train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro, sharded.  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
@@ -134,6 +137,18 @@ def kimi_small():
                     new_tokens=6)
 
 
+def ouro_small():
+    """The fifth family at small sizes with heads of the published width
+    (4 of 128): 2 layers run 3 times over one set of weights, 6 cache
+    slots, the cell's window and prefill bucket of 512."""
+    kwargs = dict(vocab_size=512, units=256, num_layers=2, num_heads=4,
+                  kv_heads=4, head_dim=128, hidden_size=512, loop_steps=3,
+                  max_length=512, dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
+                    prompt_lens=(40, 128, 300, 77, 129, 16, 260),
+                    new_tokens=6)
+
+
 # the two timings of the sync phase may differ by this factor
 SYNC_FACTOR = 2.0
 # max |kernel - dense| / max |dense| on bf16 operands (both sides round
@@ -145,6 +160,8 @@ INIT_LOSS_TOL = 0.5
 # sharded vs single-chip step-0 loss, bf16 activations reduced in another
 # order
 SHARDED_LOSS_TOL = 5e-2
+# max |served logits - float32 reference| / max |reference| in bfloat16
+SERVED_LOGITS_TOL = 5e-2
 
 
 def on_platform(arr, platform):
@@ -691,9 +708,7 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
                 and all(0 <= int(t) < vocab for t in toks),
                 f"{tag}: request {j} resolved to {toks}")
     require(counters_hold(timing, size.prompt_lens
-                          + (1,) * (B - len(prompts)))
-            and timing["moe_rows_computed_decode"]
-            >= timing["moe_pairs_decode"] > 0,
+                          + (1,) * (B - len(prompts))),
             f"{tag}: counters {timing}")
     # a coalesced group == each request alone through the same bucket
     for j in (0, len(prompts) - 1):
@@ -715,11 +730,17 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
         f"{size.new_tokens} tokens through bucket {timing['bucket']}: "
         f"first call {first:.1f}s, then "
         f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
-        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn'))} }; "
+        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn', 'loop'))} }; "
         f"peak bytes in use {peak}")
     return net, engine, timing, {
         "params": n_params, "programs": engine.program_count(),
         "retraces": serving.trace_count() - pinned, "peak_bytes": peak}
+
+
+def moe_rows_hold(timing):
+    """The grouped product was given at least the rows its pairs need."""
+    return timing["moe_rows_computed_decode"] \
+        >= timing["moe_pairs_decode"] > 0
 
 
 def phase_serve_mimo(size, platform):
@@ -729,7 +750,8 @@ def phase_serve_mimo(size, platform):
 
     def counters_hold(timing, lens):
         return 0 < timing["moe_pairs_prefill"] <= sum(size.prompt_lens) \
-            * layers * size.kwargs["experts_per_token"]
+            * layers * size.kwargs["experts_per_token"] \
+            and moe_rows_hold(timing)
 
     # four stacks: two kinds of cache, keys and values
     return serve_family("serve_mimo", mimo_v2.MiMoV2Model, size, platform,
@@ -796,7 +818,7 @@ def phase_serve_keye(size, platform):
         return timing["attn_keys_live_prefill"] == live \
             and least <= timing["attn_keys_selected_prefill"] < live \
             and 0 < timing["attn_keys_selected_decode"] \
-            < timing["attn_keys_live_decode"]
+            < timing["attn_keys_live_decode"] and moe_rows_hold(timing)
 
     # three stacks: keys, values, the indexer's keys
     net, engine, timing, out = serve_family(
@@ -876,7 +898,8 @@ def phase_serve_kimi(size, platform):
             and timing["attn_latent_positions_decode"] \
             == L * sum(n + j + 1 for n in lens
                        for j in range(size.new_tokens - 1)) \
-            and timing["prefill_attn_kernel_share"] == 1.0
+            and timing["prefill_attn_kernel_share"] == 1.0 \
+            and moe_rows_hold(timing)
 
     # one stack with no heads, and none for the values
     net, engine, _, out = serve_family(
@@ -886,6 +909,83 @@ def phase_serve_kimi(size, platform):
             == (L, 1, 1, z.kv_rank + z.rope_dim, engine._W),
             f"serve_kimi: cache {[tuple(c.shape) for c in big]}")
     require_short_blocks("serve_kimi", net, size, counters_hold)
+    return out
+
+
+# -- serve, a fifth family: a looped stack --------------------------------------
+
+def require_served_logits_equal_the_reference(tag, net, engine, size):
+    """Prefill then decode through the ``T L`` slots against the plain
+    float32 reference's full forward (benchmark/references/ouro.py,
+    given the model's own weights) at every served position."""
+    import jax.numpy as jnp
+
+    from benchmark.references import ouro as ref
+    from mxnet_tpu.test_utils import serving_host_walk
+
+    z = net._sizes
+    config = {"hidden_size": z.units, "num_hidden_layers": z.num_layers,
+              "num_attention_heads": z.num_heads,
+              "num_key_value_heads": z.kv_heads, "head_dim": z.head_dim,
+              "intermediate_size": z.hidden_size,
+              "total_ut_steps": z.loop_steps,
+              "early_exit_threshold": z.exit_threshold,
+              "vocab_size": z.vocab, "rms_norm_eps": z.eps,
+              "rope_theta": z.rope_theta, "hidden_act": "silu",
+              "tie_word_embeddings": False}
+    values = {n: getattr(net, n).data()._data for n in net._names}
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, z.vocab, n).tolist()
+               for n in size.prompt_lens]
+    toks, logits = serving_host_walk(engine, prompts, size.new_tokens)
+    worst = 0.0
+    # the shortest, the middle and the longest row: the reference
+    # compiles anew for every length
+    order = np.argsort(size.prompt_lens)
+    for i in order[[0, len(order) // 2, -1]]:
+        p = prompts[i]
+        full = jnp.asarray([list(p) + list(toks[i, :-1])])
+        want = ref.logits(values, full, config)[0, len(p) - 1:]
+        worst = max(worst, float(np.abs(logits[i] - want).max()
+                                 / np.abs(want).max()))
+    require(worst <= SERVED_LOGITS_TOL,
+            f"{tag}: served logits off the reference by {worst} of its "
+            f"largest")
+    say(f"[{tag}] 3 x {size.new_tokens} served positions equal the "
+        f"float32 reference to {worst:.4f} of its largest logit")
+
+
+def phase_serve_ouro(size, platform):
+    from mxnet_tpu.gluon.model_zoo import ouro
+
+    T, L = size.kwargs["loop_steps"], size.kwargs["num_layers"]
+    steps = size.new_tokens - 1
+
+    def counters_hold(timing, lens):
+        # every pass runs, every row leaves at the last step, and every
+        # prefill attention call went through the flash forward kernel
+        return timing["loop_passes_prefill"] == T \
+            and timing["loop_passes_decode"] == T * steps \
+            and timing["loop_exit_step_prefill"] == [0] * (T - 1) \
+            + [len(lens)] \
+            and timing["loop_exit_step_decode"] == [0] * (T - 1) \
+            + [len(lens) * steps] \
+            and timing["attn_positions_prefill"] \
+            == T * L * sum(n * (n + 1) // 2 for n in lens) \
+            and timing["attn_positions_decode"] \
+            == T * L * sum(n + j + 1 for n in lens for j in range(steps)) \
+            and timing["prefill_attn_kernel_share"] == 1.0
+
+    # two stacks, a slot for every (loop step, layer)
+    net, engine, _, out = serve_family(
+        "serve_ouro", ouro.OuroModel, size, platform, 2, counters_hold)
+    z, big = net._sizes, engine.init_cache(1)
+    require(len(big) == 3 and big[0].shape == big[1].shape
+            == (T * L, 1, z.kv_heads, z.head_dim, engine._W)
+            and net.qkv_weight.shape[0] == L,
+            f"serve_ouro: cache {[tuple(c.shape) for c in big]}")
+    require_served_logits_equal_the_reference("serve_ouro", net, engine,
+                                              size)
     return out
 
 
@@ -978,6 +1078,8 @@ def main():
     run("serve_keye", phase_serve_keye, keye_small(), platform)
     gc.collect()
     run("serve_kimi", phase_serve_kimi, kimi_small(), platform)
+    gc.collect()
+    run("serve_ouro", phase_serve_ouro, ouro_small(), platform)
     gc.collect()
     import jax
 

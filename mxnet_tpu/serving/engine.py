@@ -278,6 +278,7 @@ class ServingEngine:
             self.generation = 0
             self._weights = tuple(self._program.weights())
             self._programs = {}
+            self._cache_avals = {}      # by batch bucket: `_compile`
             self._step = self._make_step()
 
     def _program_of(self, model):
@@ -445,7 +446,15 @@ class ServingEngine:
         program = "decode" if S == 1 else "prefill"
         with scope("serve.compile", B=B, S=S, program=program):
             w_avals = tuple(self._aval(x) for x in self._weights)
-            c_avals = tuple(self._aval(c) for c in self.init_cache(B))
+            # the cache's shapes are read off an allocated one, once a
+            # batch bucket: a cache can be most of the chip's memory,
+            # and a later program of the bucket (the decode program,
+            # compiled behind the group's first prefill) is compiled
+            # while a group's own cache is alive
+            c_avals = self._cache_avals.get(B)
+            if c_avals is None:
+                c_avals = self._cache_avals[B] = tuple(
+                    self._aval(c) for c in self.init_cache(B))
             jfn = jax.jit(self._step[program], donate_argnums=(1,))
             compiled = jfn.lower(w_avals, c_avals, self._int_aval((B,)),
                                  self._int_aval((B,)),
@@ -571,6 +580,12 @@ class ServingEngine:
             return cache, pos, ids
 
         with scope("serve.prefill.dispatch") as sp_dispatch:
+            # the prefill program exists before the group's cache does:
+            # a bucket's first `_compile` allocates a cache of its own
+            # to read the shapes off, and two caches do not fit beside
+            # the weights where one is most of the chip's memory
+            if (B, S) not in self._programs:
+                self._compile(B, S)
             cache, pos, ids = dispatch(S, self.init_cache(B),
                                        np.zeros(B, np.int32), lens - 1,
                                        toks)

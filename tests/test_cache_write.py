@@ -476,3 +476,68 @@ def test_kimis_block_layer_compiles_at_a_small_bucket(one_chip, monkeypatch,
     assert "tpu_custom_call" in compiled.as_text()
     assert [o.shape for o in compiled.out_info] == [
         (B, S, z.units), (B, S, z.latent)]
+
+
+@pytest.mark.parametrize("S", [1, 512])
+def test_ouros_programs_compile_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch, S):
+    """Ouro-2.6B's decode step and its 8 x 512 prefill
+    (`gluon/model_zoo/ouro.py::OuroProgram.step`, the sizes of
+    benchmark/configs/ouro-2.6b.json: 48 stacked layers of weights, 192
+    cache slots) lower and compile for a described v5e with the kernels
+    in them: both 3.2 GB stacks are written into their donated
+    arguments through the two nested loops, with a traced slot index,
+    and neither program's buffer assignment holds a copy of a stack
+    (the decode program's temporaries are under one slot's 16.8 MB; the
+    prefill's are the block's own activations)."""
+    import json
+    import os
+
+    from mxnet_tpu.gluon.model_zoo import ouro
+    from mxnet_tpu.ops import cache_attention, pallas_attention as pa
+
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ouro-2.6b.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    net = ouro.OuroModel(**kwargs)        # no parameter is allocated
+    z, B, W = net._sizes, 8, kwargs["max_length"]
+    program = ouro.OuroProgram(net, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots = z.loop_steps * z.num_layers
+    assert (slots, z.num_layers) == (192, 48)
+    stack = sds((slots, B, z.kv_heads, z.head_dim, W))
+    # the layout a donated stack arrives in, as `init_cache` reads it
+    # off an allocated one on the chip
+    program._pin = jax.jit(lambda x: x).lower(stack).compile(
+        ).input_formats[0][0].layout
+    weights = tuple(sds(z.shape_of(n)) for n in net._names)
+    assert weights[net._names.index("qkv_weight")].shape[0] == 48
+    compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
+        weights, (stack, stack, sds((2, 2 + z.loop_steps), jnp.uint32)),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, S), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    for i in range(3):
+        assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
+    slot_bytes = B * z.kv_heads * z.head_dim * W * 2
+    stack_bytes = slots * slot_bytes
+    assert stack_bytes == 3_221_225_472
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * stack_bytes
+    if S == 1:
+        assert mem.temp_size_in_bytes < slot_bytes
+        assert dict(program.cache_writes[1]) == {"kernel": 2 * B}
+        assert dict(program.cache_reads[1]) == {("kernel", W, 128): 1}
+    else:
+        assert mem.temp_size_in_bytes < stack_bytes // 8
+        assert dict(program.block_attends[S]) == {"kernel": 1}
+    assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])

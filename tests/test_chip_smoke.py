@@ -134,3 +134,22 @@ def test_serve_kimi_phase():
                                prompt_lens=(3, 8, 21, 40), new_tokens=7)
     out = chip_smoke.phase_serve_kimi(size, "cpu")
     assert out["retraces"] == 0 and out["programs"] == 2
+
+
+def test_serve_ouro_phase():
+    """The fifth family's phase at a tiny size: the engine's tuple is the
+    parameters' own buffers, the two stacks of ``T L`` slots stay where
+    they are, the passes, exit steps and positions are counted,
+    coalesced == alone, a repeat is identical, and the served logits
+    equal the float32 reference's."""
+    small = chip_smoke.ouro_small()
+    assert small.kwargs["head_dim"] == 128 \
+        and small.prefill_floor == small.kwargs["max_length"] == 512
+    kwargs = dict(vocab_size=96, units=64, num_layers=2, num_heads=4,
+                  kv_heads=4, head_dim=16, hidden_size=96, loop_steps=3,
+                  max_length=64, grad_req="null")
+    assert set(kwargs) <= set(small.kwargs)
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(3, 8, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_ouro(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
