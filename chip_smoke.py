@@ -480,6 +480,30 @@ def require_fed_on_device(tag, engine, prompts, steps, served, timing):
         f"a step, tokens equal the host-picked run")
 
 
+def require_dead_rows_change_nothing(tag, engine, prompts, steps, served):
+    """A group of unequal answers, rows that want no more token between
+    rows that do: the decode steps are handed the mask (fewer live
+    row-steps than row-steps), a finished row reads one cache block and
+    goes to no expert, and every request's tokens are those it got in
+    ``served``, the same group with every answer ``steps`` long."""
+    wants = [steps if i % 2 else max(1, steps // 3)
+             for i in range(len(prompts))]
+    outs, timing = engine.serve_group(prompts, wants)
+    for i, (got, want) in enumerate(zip(outs, served)):
+        require(np.array_equal(got, want[:wants[i]]),
+                f"{tag}: prompt {i} with answers of {wants}: {got} != "
+                f"{want[:wants[i]]} of the group of equal answers")
+    live, all_ = (timing["decode_row_steps_live"],
+                  timing["decode_row_steps"])
+    require(live == sum(w - 1 for w in wants) < all_
+            == timing["bucket"][0] * (steps - 1),
+            f"{tag}: {live} live of {all_} row-steps for answers of {wants}")
+    say(f"[{tag}] answers of {wants}: {live} of {all_} row-steps live, "
+        f"tokens equal the group of equal answers', "
+        f"decode_attn_window_read_pct "
+        f"{timing['decode_attn_window_read_pct']:.2f}")
+
+
 def require_cache_kernels(tag, engine, timing, platform):
     """On the chip every cache row write of the decode program goes
     through the in-place kernel (`ops/cache_write.py`) and its text
@@ -601,6 +625,8 @@ def phase_serve(size, platform, net):
     require_fed_on_device("serve", engine, group, size.new_tokens,
                           together, timing)
     require_cache_kernels("serve", engine, timing, platform)
+    require_dead_rows_change_nothing("serve", engine, group,
+                                     size.new_tokens, together)
     engine.batch_buckets = (big,)
     try:
         alone = [engine.serve_group([p], size.new_tokens)
@@ -662,12 +688,14 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
     through `ServingEngine`: the engine's tuple is the parameters' own
     buffers on the platform, the decode program moves no layer-sized
     piece of the cache's first ``stacks`` arrays, every request resolves
-    to tokens, ``counters_hold(timing, lens)`` (the family's own
-    counters of the first group, ``lens`` the bucket's prompt lengths,
-    pad rows' included), a coalesced group equals its requests alone, a
-    repeated group is identical, it is fed on the device and its cache
-    ops take their kernels.  Returns (net, engine, timing, what the
-    phase reports)."""
+    to tokens, ``counters_hold(timing, lens, pads)`` (the family's own
+    counters of the first group, ``lens`` its prompts' lengths; the
+    bucket's ``pads`` pad rows hold one token each, which the prefill
+    counts, and want none, so no decode step does), a coalesced group
+    equals its requests alone, a repeated group is identical, it is fed
+    on the device, its cache ops take their kernels, and rows that want
+    no more token change nothing for the others.  Returns (net, engine,
+    timing, what the phase reports)."""
     import jax.numpy as jnp
 
     import mxnet_tpu as mx
@@ -707,8 +735,7 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
         require(len(toks) == size.new_tokens
                 and all(0 <= int(t) < vocab for t in toks),
                 f"{tag}: request {j} resolved to {toks}")
-    require(counters_hold(timing, size.prompt_lens
-                          + (1,) * (B - len(prompts))),
+    require(counters_hold(timing, size.prompt_lens, B - len(prompts)),
             f"{tag}: counters {timing}")
     # a coalesced group == each request alone through the same bucket
     for j in (0, len(prompts) - 1):
@@ -724,6 +751,8 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
     require_fed_on_device(tag, engine, prompts, size.new_tokens, again,
                           timing)
     require_cache_kernels(tag, engine, timing, platform)
+    require_dead_rows_change_nothing(tag, engine, prompts, size.new_tokens,
+                                     again)
     stats = _ctx_for(platform).jax_device.memory_stats()
     peak = stats["peak_bytes_in_use"] if stats else None
     say(f"[{tag}] group of {len(prompts)} (prompts {size.prompt_lens}) x "
@@ -748,7 +777,7 @@ def phase_serve_mimo(size, platform):
 
     layers = sum(1 for m in size.kwargs["moe_layers"] if m)
 
-    def counters_hold(timing, lens):
+    def counters_hold(timing, lens, pads):
         return 0 < timing["moe_pairs_prefill"] <= sum(size.prompt_lens) \
             * layers * size.kwargs["experts_per_token"] \
             and moe_rows_hold(timing)
@@ -812,7 +841,8 @@ def phase_serve_keye(size, platform):
 
     L, topk = size.kwargs["num_layers"], size.kwargs["topk"]
 
-    def counters_hold(timing, lens):
+    def counters_hold(timing, lens, pads):
+        lens = lens + (1,) * pads
         live = L * sum(n * (n + 1) // 2 for n in lens)
         least = L * sum(min(t + 1, topk) for n in lens for t in range(n))
         return timing["attn_keys_live_prefill"] == live \
@@ -877,7 +907,7 @@ def require_short_blocks(tag, net, size, counters_hold):
         require(S < 128 and all(
             len(t) == size.new_tokens
             and all(0 <= int(x) < net._vocab for x in t) for t in toks)
-            and counters_hold(timing, lens + (1,) * (B - len(lens))),
+            and counters_hold(timing, lens, B - len(lens)),
             f"{tag}: prompts of {lens} through bucket {(B, S)}: {toks}, "
             f"{timing}")
         buckets.append(S)
@@ -890,11 +920,11 @@ def phase_serve_kimi(size, platform):
 
     L = size.kwargs["num_layers"]
 
-    def counters_hold(timing, lens):
+    def counters_hold(timing, lens, pads):
         # and every prefill attention call went through the flash
         # forward kernel (ops/pallas_attention.py)
         return timing["attn_latent_positions_prefill"] \
-            == L * sum(n * (n + 1) // 2 for n in lens) \
+            == L * sum(n * (n + 1) // 2 for n in lens + (1,) * pads) \
             and timing["attn_latent_positions_decode"] \
             == L * sum(n + j + 1 for n in lens
                        for j in range(size.new_tokens - 1)) \
@@ -961,17 +991,17 @@ def phase_serve_ouro(size, platform):
     T, L = size.kwargs["loop_steps"], size.kwargs["num_layers"]
     steps = size.new_tokens - 1
 
-    def counters_hold(timing, lens):
+    def counters_hold(timing, lens, pads):
         # every pass runs, every row leaves at the last step, and every
         # prefill attention call went through the flash forward kernel
         return timing["loop_passes_prefill"] == T \
             and timing["loop_passes_decode"] == T * steps \
             and timing["loop_exit_step_prefill"] == [0] * (T - 1) \
-            + [len(lens)] \
+            + [len(lens) + pads] \
             and timing["loop_exit_step_decode"] == [0] * (T - 1) \
             + [len(lens) * steps] \
             and timing["attn_positions_prefill"] \
-            == T * L * sum(n * (n + 1) // 2 for n in lens) \
+            == T * L * sum(n * (n + 1) // 2 for n in lens + (1,) * pads) \
             and timing["attn_positions_decode"] \
             == T * L * sum(n + j + 1 for n in lens for j in range(steps)) \
             and timing["prefill_attn_kernel_share"] == 1.0

@@ -417,12 +417,13 @@ class GPTDecoderProgram:
 
     # -- the traced block step -------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         """cache = (ck, cv), each (L, B, H, Dh, W), donated; pos (B,)
         per-row write offsets; last (B,) the index in the block of each
         row's last real token; toks (B, S) int32.  Returns ((ck', cv'),
         logits (B, vocab)) at ``last``.  S = seq bucket for prefill, 1
-        for decode."""
+        for decode, where ``live`` (B,) bool marks the rows that still
+        want a token (None: all): another row attends to nothing."""
         import collections
 
         import jax
@@ -455,6 +456,8 @@ class GPTDecoderProgram:
         (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
          g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
         B, S = toks.shape
+        # a decode step's positions a row, itself included
+        held = pos + 1 if live is None else jnp.where(live, pos + 1, 0)
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
@@ -488,9 +491,10 @@ class GPTDecoderProgram:
                 # — the overwrite-before-attend invariant)
                 if S == 1:
                     # one position a row: (B, H, 1, Dh) is one query a
-                    # key head, over the row's pos[b] + 1 positions
+                    # key head, over the row's pos[b] + 1 positions (a
+                    # row that wants no token: none, and zeros)
                     attn = cache_attention.attend_rows(
-                        qh * (Dh ** -0.5), ck, cv, l, pos + 1, mesh=mesh,
+                        qh * (Dh ** -0.5), ck, cv, l, held, mesh=mesh,
                         tally=reads)
                 else:
                     ck_l = lax.dynamic_index_in_dim(ck, l, 0,

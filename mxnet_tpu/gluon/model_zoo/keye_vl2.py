@@ -275,7 +275,7 @@ class KeyeVL2Model(HybridBlock):
 
 class KeyeVL2Program:
     """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks)``."""
+    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
 
     def __init__(self, model, dtype=None):
         self._model = model
@@ -337,12 +337,14 @@ class KeyeVL2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         """cache donated; pos (B,) each row's first position; last (B,)
         the index in the block of each row's last real token; toks
         (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
         S > 1 is a prefill from an empty cache: it attends inside the
-        block.  S = 1 attends over the caches."""
+        block.  S = 1 attends over the caches; there ``live`` (B,) bool
+        marks the rows that still want a token (None: all): another row
+        attends to nothing, goes to no expert and is counted nowhere."""
         import collections
 
         import jax
@@ -356,6 +358,10 @@ class KeyeVL2Program:
         pins = self._pins      # `init_cache` read them off a real cache
         B, S = toks.shape
         decode = S == 1
+        if live is None:
+            live = jnp.ones((B,), bool)
+        # a decode step's positions a row, itself included
+        held = jnp.where(live, pos + 1, 0)
         W, n = self.window, z.experts_held[1]
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
@@ -389,15 +395,15 @@ class KeyeVL2Program:
                 index = indexed_attention.index_scores_decode(
                     qi[:, :, 0], wi[:, 0], of_layer(ci, l)[:, 0])
             with jax.named_scope("serve.attn_select"):
-                live = jnp.arange(W)[None, :] <= pos[:, None]
-                mask = indexed_attention.select_topk(index, live, z.topk)
+                written = jnp.arange(W)[None, :] <= pos[:, None]
+                mask = indexed_attention.select_topk(index, written, z.topk)
             with jax.named_scope("serve.attn_sparse"):
                 a = cache_attention.attend_rows(
-                    q[:, :, :, 0], ck, cv, l, pos + 1, mask=mask,
-                    tally=reads)
+                    q[:, :, :, 0], ck, cv, l, held, mask=mask, tally=reads)
             with jax.named_scope("serve.attn_out"):
                 x = _ops.attn_out(z, p, x, a[:, :, :, None])
-            seen = jnp.stack([jnp.sum(pos + 1), jnp.sum(mask)])
+            seen = jnp.stack([jnp.sum(held),
+                              jnp.sum(mask & live[:, None])])
             return x, stacks, _softmax_route(z, p, x), seen
 
         def prefill_layer(x, stacks, p, l):
@@ -414,9 +420,10 @@ class KeyeVL2Program:
             p, l = per
             x, stacks, route, seen = (decode_layer if decode
                                       else prefill_layer)(x, stacks, p, l)
-            # padding is routed nowhere: only real tokens cost
+            # padding and rows that want no token are routed nowhere:
+            # only tokens that are kept cost
             x, stats = _experts(z, w, l, x, route,
-                                None if decode else valid)
+                                live[:, None] if decode else valid)
             moe_counts = moe_counts.at[l, int(decode)].add(
                 _ops.moe_count_row(stats, n))
             attn_counts = attn_counts.at[l, int(decode)].add(

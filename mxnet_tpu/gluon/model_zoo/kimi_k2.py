@@ -418,7 +418,7 @@ class KimiK2Model(HybridBlock):
 
 class KimiK2Program:
     """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks)``."""
+    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
 
     def __init__(self, model, dtype=None):
         self._model = model
@@ -483,13 +483,16 @@ class KimiK2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         """cache donated; pos (B,) each row's first position; last (B,)
         the index in the block of each row's last real token; toks
         (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
         S > 1 is a prefill from an empty cache: expanded attention
         inside the block, a row chunk through all layers before the
-        next.  S = 1 is absorbed attention over the latent stack."""
+        next.  S = 1 is absorbed attention over the latent stack; there
+        ``live`` (B,) bool marks the rows that still want a token (None:
+        all): another row attends to nothing, goes to no routed expert
+        and is counted nowhere."""
         import collections
 
         import jax
@@ -503,6 +506,10 @@ class KimiK2Program:
         pin = self._pin        # `init_cache` read it off a real cache
         B, S = toks.shape
         decode = S == 1
+        if live is None:
+            live = jnp.ones((B,), bool)
+        # a decode step's positions a row, itself included
+        held = jnp.where(live, pos + 1, 0)
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
         attends = self.block_attends[S] = collections.Counter()
@@ -536,11 +543,11 @@ class KimiK2Program:
                     q = _absorbed_query(z, p, cq, at)
                     with jax.named_scope("serve.attn_latent"):
                         a = cache_attention.attend_rows(
-                            q, stack, None, l, pos + 1, tally=reads,
+                            q, stack, None, l, held, tally=reads,
                             leading=z.kv_rank)
                     x, route = _feed_forward_front(
                         z, p, _absorbed_out(z, p, x, a))
-                    n_seen = jnp.sum(pos + 1)
+                    n_seen = jnp.sum(held)
                 else:
                     x, latent, route = _block_layer(z, p, x, at, last + 1,
                                                     attends)
@@ -553,7 +560,7 @@ class KimiK2Program:
             # the experts' counters: this phase's column of the carry
             stack, seen, moe_counts = carry
             x, (stack, seen), phase = _layers(
-                z, w, x, None if decode else valid, (stack, seen),
+                z, w, x, live[:, None] if decode else valid, (stack, seen),
                 moe_counts[:, int(decode)], attend)
             moe_counts = moe_counts.at[:, int(decode)].set(phase)
             with jax.named_scope("serve.head"):

@@ -361,7 +361,7 @@ class MiMoV2Model(HybridBlock):
 
 class MiMoV2Program:
     """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks)``."""
+    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
 
     def __init__(self, model, dtype=None):
         self._model = model
@@ -424,12 +424,14 @@ class MiMoV2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         """cache donated; pos (B,) each row's first position; last (B,)
         the index in the block of each row's last real token; toks
         (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
         S > 1 is a prefill from an empty cache: it attends inside the
-        block.  S = 1 attends over the caches."""
+        block.  S = 1 attends over the caches; there ``live`` (B,) bool
+        marks the rows that still want a token (None: all): another row
+        attends to nothing, goes to no expert and is counted nowhere."""
         import collections
 
         import jax
@@ -444,6 +446,8 @@ class MiMoV2Program:
         pins = self._pins      # `init_cache` read them off a real cache
         B, S = toks.shape
         decode = S == 1
+        if live is None:
+            live = jnp.ones((B,), bool)
         R = z.window
         zero = jnp.int32(0)
         tally = self.cache_writes[S] = collections.Counter()
@@ -500,14 +504,15 @@ class MiMoV2Program:
                     ck, cv, held = (fk, fv, pos + 1) if kind == "full" \
                         else (wk, wv, jnp.minimum(pos + 1, R))
                     a = cache_attention.attend_rows(
-                        q[:, :, :, 0], ck, cv, l, held,
+                        q[:, :, :, 0], ck, cv, l, jnp.where(live, held, 0),
                         sink=_sink(z, kind, p), tally=reads)
                     x = _ops.attn_out(z, p, x, a[:, :, :, None])
                 x, route = _feed_forward_front(z, i, p, x)
             if route:
-                # padding is routed nowhere: only real tokens cost
+                # padding and rows that want no token are routed
+                # nowhere: only tokens that are kept cost
                 x, stats = _experts(z, p, x, route,
-                                    None if decode else valid)
+                                    live[:, None] if decode else valid)
                 counts = counts.at[z.moe_at.index(i), int(decode)].add(
                     _ops.moe_count_row(stats, z.experts_held[1]))
         with jax.named_scope("serve.head"):

@@ -279,7 +279,7 @@ class OuroModel(HybridBlock):
 
 class OuroProgram:
     """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks)``."""
+    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
 
     def __init__(self, model, dtype=None):
         self._model = model
@@ -328,9 +328,9 @@ class OuroProgram:
 
     def counters(self, cache):
         """The counters of one served group, read back once
-        (docs/observability.md has the table).  The program is handed no
-        mask of the rows that still want a token: every row of the
-        bucket counts."""
+        (docs/observability.md has the table).  A decode step counts
+        the rows that still want a token (``live``); its passes run for
+        the whole bucket and count once a step."""
         import numpy as np
 
         z = self._z
@@ -346,13 +346,15 @@ class OuroProgram:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         """cache donated; pos (B,) each row's first position; last (B,)
         the index in the block of each row's last real token; toks
         (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``,
         of each row's exit step).  S > 1 is a prefill from an empty
         cache: it attends inside the block.  S = 1 attends over the
-        caches."""
+        caches; there ``live`` (B,) bool marks the rows that still want
+        a token (None: all): another row attends to nothing and is
+        counted nowhere."""
         import collections
 
         import jax
@@ -365,6 +367,10 @@ class OuroProgram:
         pin = self._pin        # `init_cache` read it off a real cache
         B, S = toks.shape
         decode = S == 1
+        if live is None:
+            live = jnp.ones((B,), bool)
+        # a decode step's positions a row, itself included
+        held = jnp.where(live, pos + 1, 0)
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
         attends = self.block_attends[S] = collections.Counter()
@@ -388,7 +394,7 @@ class OuroProgram:
                     q = (q[:, :, 0] * z.head_dim ** -0.5).astype(k.dtype)
                     a = cache_attention.attend_rows(
                         q.reshape(B, z.kv_heads, z.groups, z.head_dim),
-                        *stacks, slot, pos + 1, tally=reads)
+                        *stacks, slot, held, tally=reads)
                     a = a.reshape(B, 1, -1)
             else:
                 a = _block_attention(z, q, k, v, last + 1, attends)
@@ -400,12 +406,14 @@ class OuroProgram:
                                           axis=1)[:, 0])
         left, logits = _head(z, w, kept, gates)
         n = (last + 1).astype(jnp.uint32)
-        seen = jnp.sum(pos.astype(jnp.uint32) + 1) if decode \
+        seen = jnp.sum(held.astype(jnp.uint32)) if decode \
             else jnp.sum(n * (n + 1) // 2)
+        left_at = left[:, None] == jnp.arange(z.loop_steps)[None, :]
+        if decode:
+            left_at &= live[:, None]
         counts = cache[2].at[int(decode)].add(jnp.concatenate([
             jnp.stack([jnp.uint32(z.loop_steps), seen]),
-            jnp.sum(left[:, None] == jnp.arange(z.loop_steps)[None, :],
-                    axis=0, dtype=jnp.uint32)]))
+            jnp.sum(left_at, axis=0, dtype=jnp.uint32)]))
         return stacks + (counts,), logits
 
 

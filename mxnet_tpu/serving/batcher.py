@@ -275,6 +275,9 @@ class ContinuousBatcher:
                     "decode_steps_fed_on_device"),
                 decode_readback_bytes_per_step=timings.get(
                     "decode_readback_bytes_per_step"),
+                decode_row_steps=timings.get("decode_row_steps"),
+                decode_row_steps_live=timings.get(
+                    "decode_row_steps_live"),
                 decode_cache_write_kernel_share=timings.get(
                     "decode_cache_write_kernel_share"),
                 decode_attn_kernel_share=timings.get(

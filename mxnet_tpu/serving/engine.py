@@ -34,7 +34,10 @@ request path.  Four properties, all pinned by tests/test_serving.py:
   dispatches ``_STEPS_IN_FLIGHT`` steps ahead and reads the ``(B, 1)``
   ids of a step while the next one runs; the ``(B, vocab)`` logits are
   fetched only for a request with a ``temperature``, for which the
-  host draws the token.
+  host draws the token.  How many tokens each row still wants is
+  carried the same way, so a decode step knows which rows are live: a
+  row past its answer, or a pad row, attends to nothing and goes to no
+  expert (docs/serving.md, "The decoder program").
 - **Hot reload without recompile.**  Weights are *arguments* to the
   compiled programs, not closed-over constants: swapping in new
   weights (from a live model or an AsyncCheckpointer state dict) is an
@@ -199,16 +202,20 @@ def whole_layer_ops(hlo_text, layer_bytes):
             for instr in instrs if moved(comp, instr) >= layer_bytes]
 
 
-def _window_read_pct(reads, lens, steps):
+def _window_read_pct(reads, lens, steps, left=None):
     """Of the ``B x W`` positions a decode attention call could read,
     over a group's ``steps`` decode steps, the percentage in the lane
     blocks it was asked for.  ``reads``: the program's
     ``cache_reads[1]``, calls by (path, W, lanes); step d of a row
     with a prompt of ``lens[b]`` tokens holds ``lens[b] + d + 1``
-    positions and is read in whole blocks of ``lanes``."""
+    positions and is read in whole blocks of ``lanes``.  ``left``
+    (B,): the decode steps row b is live in (None: all); past them it
+    is asked for no position and read in its first block."""
     import numpy as np
 
     held = lens[:, None].astype(np.int64) + np.arange(1, steps + 1)[None, :]
+    if left is not None:
+        held = np.where(np.arange(steps)[None, :] < left[:, None], held, 1)
     asked = whole = 0
     for (_, W, lanes), calls in reads.items():
         asked += calls * int(np.minimum(-(-held // lanes) * lanes, W).sum())
@@ -229,12 +236,16 @@ class ServingEngine:
       first argument (swapped on reload, never closed over);
     - ``init_cache(B)`` → a flat tuple of arrays, every one donated to
       each step and handed back by it;
-    - ``step(w, cache, pos, last, toks)`` → ``(cache, logits (B,
-      vocab))``: ``pos`` (B,) each row's first position, ``last`` (B,)
-      the index in the block of each row's last real token, ``toks``
-      (B, S) with S a prefill bucket or 1.  The engine compiles it
-      with two more outputs, the next step's own inputs: the greedy
-      ids ``(B, 1)`` and the next positions ``pos + last + 1``;
+    - ``step(w, cache, pos, last, toks, live=None)`` → ``(cache,
+      logits (B, vocab))``: ``pos`` (B,) each row's first position,
+      ``last`` (B,) the index in the block of each row's last real
+      token, ``toks`` (B, S) with S a prefill bucket or 1, ``live``
+      (B,) bool in a decode step the rows that still want a token
+      (None: all).  The engine compiles it with two more outputs, the
+      next step's own inputs: the greedy ids ``(B, 1)`` and the next
+      positions ``pos + last + 1``; the decode program also takes
+      ``left`` (B,), the tokens each row wants beyond the one it is
+      fed, makes ``live = left > 0`` of it and returns it one lower;
     - ``window`` and ``vocab``; optionally ``weights_from_state(state)``
       (a checkpoint convention), ``counters(cache)`` (a dict read back
       once a group, merged into the timings), ``cache_writes`` (by
@@ -390,21 +401,30 @@ class ServingEngine:
         def named(name):
             # one traced function under two names: a program is called
             # ``jit_<__name__>`` in its HLO and in every device trace
-            def fn(w, cache, pos, last, toks):
+            def fn(w, cache, pos, last, toks, left=None):
                 _mark_trace()
-                cache, logits = program.step(w, cache, pos, last, toks)
+                # ``left`` (B,), the decode program's: the tokens a row
+                # wants beyond the one this step is fed.  A row with
+                # none left is not live: its answer is complete (or it
+                # is padding) and nothing it computes is returned
+                cache, logits = program.step(w, cache, pos, last, toks) \
+                    if left is None else program.step(
+                        w, cache, pos, last, toks, live=left > 0)
                 # what the next decode step takes, made where it is
                 # used: the greedy token by the rule the host samples
                 # by (`_sample`: float32 argmax, first index on ties,
-                # as NumPy's) and each row's next position
+                # as NumPy's), each row's next position and what it
+                # then still wants
                 with jax.named_scope("serve.sample"):
                     ids = _sample(logits.astype(jnp.float32), None,
                                   None)[:, None]
-                    nxt = pos + last + 1
+                    small = (ids, pos + last + 1)
+                    if left is not None:
+                        small += (jnp.maximum(left - 1, 0),)
                 if replicated is not None:
-                    ids, nxt = jax.lax.with_sharding_constraint(
-                        (ids, nxt), replicated)
-                return tuple(cache), logits, ids, nxt
+                    small = jax.lax.with_sharding_constraint(
+                        small, replicated)
+                return (tuple(cache), logits) + small
 
             fn.__name__ = fn.__qualname__ = name
             return fn
@@ -456,9 +476,10 @@ class ServingEngine:
                 c_avals = self._cache_avals[B] = tuple(
                     self._aval(c) for c in self.init_cache(B))
             jfn = jax.jit(self._step[program], donate_argnums=(1,))
-            compiled = jfn.lower(w_avals, c_avals, self._int_aval((B,)),
-                                 self._int_aval((B,)),
-                                 self._int_aval((B, S))).compile()
+            # pos, last, toks; the decode program also takes ``left``
+            ints = [(B,), (B,), (B, S)] + [(B,)] * (program == "decode")
+            compiled = jfn.lower(w_avals, c_avals, *(
+                self._int_aval(shape) for shape in ints)).compile()
         with _LOCK:
             _COMPILE_COUNT += 1
         self._programs[(B, S)] = compiled
@@ -491,23 +512,32 @@ class ServingEngine:
         return jax.device_put(np.asarray(ints, np.int32),
                               self._input_sharding())
 
-    def _call(self, B, S, cache, pos, last, toks):
+    def _call(self, B, S, cache, pos, last, toks, left=None):
         """One dispatch of bucket (B, S): returns ``(cache, logits (B,
         vocab), ids (B, 1), next pos (B,))``, all on the device; the
         last two are the greedy tokens and the positions a decode step
         that follows takes as ``toks`` and ``pos``.  ``cache`` is
-        donated."""
+        donated.  ``left`` (B,), for a decode step: the tokens each row
+        wants beyond the one it is fed; the step treats a row with none
+        as not live and hands back a fifth output, what the rows want
+        after it.  Without it every row is live."""
         global _DISPATCH_COUNT
+        import numpy as np
 
         compiled = self._programs.get((B, S))
         if compiled is None:
             compiled = self._compile(B, S)
-        pos, last, toks = (self._place(x) for x in (pos, last, toks))
+        ints = [pos, last, toks]
+        if S == 1:
+            ints.append(np.full(B, np.iinfo(np.int32).max)
+                        if left is None else left)
+        ints = [self._place(x) for x in ints]
         with _LOCK:
             _DISPATCH_COUNT += 1
         with self._reload_lock:
             w = self._weights
-        return compiled(w, tuple(cache), pos, last, toks)
+        out = compiled(w, tuple(cache), *ints)
+        return out if left is not None else out[:4]
 
     # -- request path ----------------------------------------------------------
 
@@ -570,14 +600,14 @@ class ServingEngine:
 
         flight = collections.deque()    # dispatched, not yet read
 
-        def dispatch(width, cache, pos, last, block):
-            cache, logits, ids, pos = self._call(B, width, cache, pos,
-                                                 last, block)
+        def dispatch(width, cache, pos, last, block, left=None):
+            cache, logits, ids, pos, *left = self._call(
+                B, width, cache, pos, last, block, left)
             # what the host will read of this program sets out for the
             # host as soon as the program has made it
             flight.append(logits if host_picks else ids)
             flight[-1].copy_to_host_async()
-            return cache, pos, ids
+            return (cache, pos, ids, *left)
 
         with scope("serve.prefill.dispatch") as sp_dispatch:
             # the prefill program exists before the group's cache does:
@@ -589,8 +619,14 @@ class ServingEngine:
             cache, pos, ids = dispatch(S, self.init_cache(B),
                                        np.zeros(B, np.int32), lens - 1,
                                        toks)
-            # a decode block is one token: placed once a group
+            # a decode block is one token: placed once a group, as is
+            # what each row wants beyond the first token the decode
+            # steps are fed (a pad row nothing): from there on the
+            # count lives on the device beside the positions
             step_last = self._place(np.zeros(B, np.int32))
+            wanted = np.zeros(B, np.int32)
+            wanted[:n] = np.asarray(per_req, np.int32) - 1
+            left = self._place(wanted)
         with scope("serve.prefill.readback") as sp_readback:
             read = np.asarray(flight.popleft())
         t0, t1 = sp_dispatch.t0, sp_readback.t1
@@ -612,8 +648,8 @@ class ServingEngine:
                 if host_picks:
                     ids = self._place(nxt[:, None])
                 while dispatched < steps - 1 and len(flight) < ahead:
-                    cache, pos, ids = dispatch(1, cache, pos, step_last,
-                                               ids)
+                    cache, pos, ids, left = dispatch(
+                        1, cache, pos, step_last, ids, left)
                     dispatched += 1
             dispatch_s += sp.t1 - sp.t0
             with scope("serve.decode.readback", step=j) as sp:
@@ -643,6 +679,12 @@ class ServingEngine:
             # device, and what the host read of each program
             "decode_steps_fed_on_device": 0 if host_picks else dispatched,
             "decode_readback_bytes_per_step": int(read.nbytes),
+            # the bucket's rows x the decode steps dispatched, and those
+            # of them a row still wanted a token in: the others read one
+            # cache block a layer and went to no expert
+            "decode_row_steps": int(B) * dispatched,
+            "decode_row_steps_live": int(
+                np.minimum(wanted, dispatched).sum()),
             # when the host held each token, from t_decode0 (token 0 is
             # the prefill's): the gaps are what a streaming caller sees
             "token_t_us": token_t_us,
@@ -664,7 +706,7 @@ class ServingEngine:
                 if path == "kernel") / sum(reads.values())
             if dispatched:
                 timings["decode_attn_window_read_pct"] = \
-                    _window_read_pct(reads, lens, dispatched)
+                    _window_read_pct(reads, lens, dispatched, wanted)
         # of the prefill program's attention calls inside its block, the
         # share that went through the flash forward kernel
         # (ops/pallas_attention.py), as the family's program counted

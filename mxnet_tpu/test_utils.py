@@ -95,6 +95,42 @@ def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
     return np.stack(out, 1), np.stack(logits, 1)
 
 
+# What the families' tests hand `serving_unequal_answers` as ``wants``:
+# answers of unequal length (a row that ends between rows that go on), of
+# equal length (every row live in every step: the counts of a group
+# before there was a mask), and three requests in a bucket of four with
+# one that wants a single token (dead, like the pad row, from the first
+# decode step)
+UNEQUAL_ANSWERS = ([2, 9, 5, 9], [9, 9, 9, 9], [5, 1, 7])
+
+
+def serving_unequal_answers(engine, prompts, wants):
+    """One group whose requests want ``wants[i]`` tokens each through
+    `ServingEngine.serve_group`, held to what makes a finished row
+    harmless (AssertionError otherwise): every request gets exactly the
+    tokens it gets served alone and in a group whose rows all run to
+    ``max(wants)``, so a row's tokens depend neither on its own end nor
+    on its neighbours'; and the engine's two counts of row-steps are
+    the bucket's and the wanted ones.  Returns the group's timings and
+    ``live``, the ``(row, decode step)`` pairs in which a row still
+    wanted a token: what a family's decode counters sum over."""
+    outs, timings = engine.serve_group(prompts, wants)
+    steps = max(wants)
+    equal, _ = engine.serve_group(prompts, steps)
+    for i, (p, k) in enumerate(zip(prompts, wants)):
+        alone, _ = engine.serve_group([p], k)
+        assert len(outs[i]) == k, (i, outs[i])
+        np.testing.assert_array_equal(
+            outs[i], alone[0], err_msg=f"request {i} of {wants}: alone")
+        np.testing.assert_array_equal(
+            outs[i], equal[i][:k],
+            err_msg=f"request {i} of {wants}: in a group of equal answers")
+    live = [(i, j) for i, k in enumerate(wants) for j in range(k - 1)]
+    assert timings["decode_row_steps"] == timings["bucket"][0] * (steps - 1)
+    assert timings["decode_row_steps_live"] == len(live)
+    return timings, live
+
+
 def jaxpr_loops(jaxpr):
     """Every scan/while equation of a jaxpr, nested ones included."""
     for eqn in jaxpr.eqns:

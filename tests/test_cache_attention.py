@@ -24,8 +24,14 @@ SHAPES = {
     "keye_sunk": (4, 8, 128, 128, 256, True, True),
     "small_heads": (4, 2, 16, 16, 256, False, False),   # four side by side
 }
-# lengths that differ by row and sit on the edges of a 128-lane block
-LENGTHS = {"edges": [1, 127, 128, 129], "whole": [None, 0, 130, 256]}
+# lengths that differ by row and sit on the edges of a 128-lane block;
+# "dead": rows that want no token (length 0: their first block, all of
+# it masked) among rows that do and at both ends, so that the copy a
+# row's last block starts for the next row is awaited by an empty row,
+# and an empty row's one block starts the copy of a row of two (the
+# latent shapes take the first three and a whole window)
+LENGTHS = {"edges": [1, 127, 128, 129], "whole": [None, 0, 130, 256],
+           "dead": [0, 130, 0, 0, 256, 0]}
 TOL = {"float32": 2e-6, "bfloat16": 4e-3}
 
 

@@ -22,7 +22,8 @@ from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import _decoder_ops as ops   # noqa: E402
 from mxnet_tpu.gluon.model_zoo import kimi_k2               # noqa: E402
 from mxnet_tpu.ops import cache_attention, moe              # noqa: E402
-from mxnet_tpu.test_utils import serving_host_walk as _walk  # noqa: E402
+from mxnet_tpu.test_utils import (                          # noqa: E402
+    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import kimi_k2 as ref             # noqa: E402
@@ -490,7 +491,29 @@ def test_a_greedy_group_is_fed_on_the_device(served, steps):
     np.testing.assert_array_equal(np.stack(outs), want)
     assert timings["decode_steps_fed_on_device"] == steps - 1
     assert timings["decode_readback_bytes_per_step"] == 4 * 4
-    assert timings["moe_pairs_decode"] == 4 * 2 * 2 * (steps - 1)
+    # the three rows that want a token, not the pad row
+    assert timings["moe_pairs_decode"] == 3 * 2 * 2 * (steps - 1)
+
+
+@pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
+def test_a_row_that_wants_no_token_changes_nothing(served, wants):
+    """The decode step is handed which rows still want a token: the
+    others attend to nothing and go to no routed expert, every
+    request's tokens are what it gets alone and in a group of equal
+    answers, and the counters are the live row-steps': the latent
+    positions attended to, 3 layers, and 2 pairs a token in each of the
+    2 expert layers (all 8 experts are held)."""
+    _, _, _, eng = served
+    eng.warmup()
+    rng = np.random.RandomState(10)
+    prompts = [rng.randint(0, 96, n).tolist()
+               for n in (2, 8, 23, 5)[:len(wants)]]
+    pinned = (serving.trace_count(), serving.compile_count())
+    timings, live = serving_unequal_answers(eng, prompts, wants)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    assert timings["attn_latent_positions_decode"] == \
+        3 * sum(len(prompts[i]) + j + 1 for i, j in live)
+    assert timings["moe_pairs_decode"] == len(live) * 2 * 2
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

@@ -19,7 +19,8 @@ from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import mimo_v2               # noqa: E402
 from mxnet_tpu.ops import moe                               # noqa: E402
-from mxnet_tpu.test_utils import serving_host_walk as _walk  # noqa: E402
+from mxnet_tpu.test_utils import (                          # noqa: E402
+    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import mimo_v2 as ref             # noqa: E402
@@ -113,10 +114,11 @@ def test_serving_equals_the_reference_at_every_served_position(chunk):
     for i, o in enumerate(outs):
         np.testing.assert_array_equal(o, toks[i])
     # every pair a real token makes is held here (all 8 experts are):
-    # 2 a token a layer, 6 expert layers; the pad row has one token
+    # 2 a token a layer, 6 expert layers; the pad row has one token,
+    # and wants none: it is dead from the first decode step
     real = sum(len(p) for p in prompts) + 1
     assert timings["moe_pairs_prefill"] == real * 2 * 6
-    assert timings["moe_pairs_decode"] == 4 * 2 * 6 * (steps - 1)
+    assert timings["moe_pairs_decode"] == 3 * 2 * 6 * (steps - 1)
     assert timings["moe_rows_computed_decode"] \
         >= timings["moe_pairs_decode"]
     assert 1 <= timings["moe_experts_hit_per_step"] <= 8
@@ -281,8 +283,31 @@ def test_a_greedy_group_is_fed_on_the_device(served, steps):
     assert timings["decode_readback_bytes_per_step"] == 4 * 4
     ts = timings["token_t_us"]
     assert len(ts) == steps and all(a < b for a, b in zip(ts, ts[1:]))
-    # the family's counters are still read once, after the last step
-    assert timings["moe_pairs_decode"] == 4 * 2 * 6 * (steps - 1)
+    # the family's counters are still read once, after the last step:
+    # the three rows that want a token, not the pad row
+    assert timings["moe_pairs_decode"] == 3 * 2 * 6 * (steps - 1)
+
+
+@pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
+def test_a_row_that_wants_no_token_changes_nothing(served, wants):
+    """The decode step is handed which rows still want a token: the
+    others attend to nothing (full layers and rings alike) and go to no
+    expert, every request's tokens are what it gets alone and in a
+    group of equal answers, and the experts' counters are the live
+    row-steps' (all 8 experts are held: 2 pairs a token in each of the
+    6 expert layers)."""
+    _, _, _, eng = served
+    eng.warmup()
+    rng = np.random.RandomState(10)
+    prompts = [rng.randint(0, 96, n).tolist()
+               for n in (3, 11, WINDOW, 7)[:len(wants)]]
+    pinned = (serving.trace_count(), serving.compile_count())
+    timings, live = serving_unequal_answers(eng, prompts, wants)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    assert timings["moe_pairs_decode"] == len(live) * 2 * 6
+    assert timings["moe_rows_computed_decode"] \
+        >= timings["moe_pairs_decode"]
+    assert 1 <= timings["moe_experts_hit_per_step"] <= 8
 
 
 def test_a_sampled_group_draws_on_the_host(served):

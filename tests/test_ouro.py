@@ -23,7 +23,8 @@ import mxnet_tpu as mx                                      # noqa: E402
 from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import ouro                  # noqa: E402
-from mxnet_tpu.test_utils import serving_host_walk as _walk  # noqa: E402
+from mxnet_tpu.test_utils import (                          # noqa: E402
+    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import ouro as ref                # noqa: E402
@@ -262,8 +263,9 @@ def test_rows_of_one_batch_leave_at_different_steps():
 @pytest.mark.parametrize("steps", [1, 2, 9])
 def test_the_counters_of_a_served_group(steps):
     """``T`` passes a program execution; the positions read over all
-    ``T L`` slots, every row of the bucket; and the group is fed on the
-    device like every family's."""
+    ``T L`` slots, every row of the bucket in the prefill and every row
+    that wants a token in a decode step (the pad row wants none); and
+    the group is fed on the device like every family's."""
     net, _ = _net(_config())
     eng = serving.ServingEngine(net, batch_buckets=(4,))
     prompts = _prompts(lens=(2, 8, 23))
@@ -281,8 +283,8 @@ def test_the_counters_of_a_served_group(steps):
     assert timings["attn_positions_prefill"] == \
         T * L * sum(n * (n + 1) // 2 for n in lens)
     assert timings["attn_positions_decode"] == \
-        T * L * sum(n + j + 1 for n in lens for j in range(steps - 1))
-    assert sum(timings["loop_exit_step_decode"]) == 4 * (steps - 1)
+        T * L * sum(n + j + 1 for n in lens[:3] for j in range(steps - 1))
+    assert sum(timings["loop_exit_step_decode"]) == 3 * (steps - 1)
     # on the CPU both cache ops take their XLA paths; the block's
     # attention is the flash forward kernel, interpreted
     if steps > 1:
@@ -400,6 +402,25 @@ def test_a_coalesced_group_is_bitwise_the_requests_served_alone(served):
         t1, l1 = _walk(eng, [p], 10)
         np.testing.assert_array_equal(t1[0], toks[i])
         np.testing.assert_array_equal(l1[0], logits[i])
+
+
+@pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
+def test_a_row_that_wants_no_token_changes_nothing(served, wants):
+    """The decode step is handed which rows still want a token: the
+    others attend to nothing, every request's tokens are what it gets
+    alone and in a group of equal answers, and the counters are the
+    live row-steps' (the positions read in all ``T L`` slots, the rows
+    that left at each step) while the passes stay ``T`` a step."""
+    _, _, _, eng = served
+    eng.warmup()
+    prompts = _prompts(seed=10, lens=(2, 8, 23, 5)[:len(wants)])
+    pinned = (serving.trace_count(), serving.compile_count())
+    timings, live = serving_unequal_answers(eng, prompts, wants)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    assert timings["attn_positions_decode"] == \
+        T * L * sum(len(prompts[i]) + j + 1 for i, j in live)
+    assert timings["loop_exit_step_decode"] == [0] * (T - 1) + [len(live)]
+    assert timings["loop_passes_decode"] == T * (max(wants) - 1)
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

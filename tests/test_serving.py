@@ -21,8 +21,9 @@ from mxnet_tpu import checkpoint, serving, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import gpt
 from mxnet_tpu.serving.replica import FrontDoor, ReplicaServer
-from mxnet_tpu.test_utils import (cpu_child_env, jaxpr_loops,
-                                  serving_host_walk)
+from mxnet_tpu.test_utils import (UNEQUAL_ANSWERS, cpu_child_env,
+                                  jaxpr_loops, serving_host_walk,
+                                  serving_unequal_answers)
 
 
 def _model(seed=7, **kwargs):
@@ -91,25 +92,37 @@ def test_decode_attention_is_counted_and_the_tokens_stand():
     assert "decode_attn_window_read_pct" not in timings
 
 
-@pytest.mark.parametrize("reads,lens,steps,want", [
+@pytest.mark.parametrize("reads,lens,steps,left,want", [
     # two rows, three decode steps, blocks of 256 in a window of 1,024:
     # 29-31 positions are one block, 513-515 three
-    ({("kernel", 1024, 256): 1}, [28, 512], 3, 100.0 * 4 / 8),
+    ({("kernel", 1024, 256): 1}, [28, 512], 3, None, 100.0 * 4 / 8),
     # a row that crosses a block's edge at its second step
-    ({("kernel", 1024, 128): 1}, [127], 2, 100.0 * (1 + 2) / 16),
+    ({("kernel", 1024, 128): 1}, [127], 2, None, 100.0 * (1 + 2) / 16),
     # the plain path reads the whole window whatever the row holds
-    ({("xla", 64, 64): 1}, [1, 40], 5, 100.0),
+    ({("xla", 64, 64): 1}, [1, 40], 5, None, 100.0),
     # MiMo-V2's two kinds of layer: two full layers through the kernel,
     # five rings of one block each
-    ({("kernel", 2048, 512): 2, ("xla", 128, 128): 5}, [100, 600], 1,
+    ({("kernel", 2048, 512): 2, ("xla", 128, 128): 5}, [100, 600], 1, None,
      100.0 * (2 * (512 + 1024) + 5 * 2 * 128) / (2 * 2 * 2048 + 5 * 2 * 128)),
     # nothing is read past the window
-    ({("kernel", 256, 128): 1}, [250], 4, 100.0)])
+    ({("kernel", 256, 128): 1}, [250], 4, None, 100.0),
+    # three requests and a pad row, three steps: a row live in two of
+    # them (one block each, and one block dead), a row that wanted one
+    # token (three blocks today, one a step now), a row live throughout
+    # (301-303 positions: two blocks), the pad row (one block a step)
+    ({("kernel", 1024, 256): 1}, [28, 512, 300, 1], 3, [2, 0, 3, 0],
+     100.0 * (3 + 3 + 3 * 2 + 3) / (4 * 3 * 4)),
+    # every row live in every step: what it read before
+    ({("kernel", 1024, 256): 1}, [28, 512], 3, [3, 3], 100.0 * 4 / 8),
+    # a dead row on the plain path is still the whole window
+    ({("xla", 64, 64): 1}, [1, 40], 5, [0, 5], 100.0)])
 def test_window_read_pct_follows_lengths_steps_and_blocks(reads, lens, steps,
-                                                          want):
+                                                          left, want):
     from mxnet_tpu.serving.engine import _window_read_pct
 
-    got = _window_read_pct(reads, np.asarray(lens, np.int32), steps)
+    got = _window_read_pct(
+        reads, np.asarray(lens, np.int32), steps,
+        None if left is None else np.asarray(left, np.int32))
     assert got == pytest.approx(want)
 
 
@@ -122,6 +135,91 @@ def test_per_request_max_new_tokens_truncates():
     # the short request's tokens are a prefix of its solo 6-token run
     full = eng.serve_group([prompts[0]], 6)[0][0]
     np.testing.assert_array_equal(outs[0], full[:2])
+
+
+# -- rows that want no more token ----------------------------------------------
+
+@pytest.fixture(scope="module")
+def unequal():
+    eng = serving.ServingEngine(_model(max_length=32), batch_buckets=(4,))
+    return eng.warmup(), _prompts(4, np.random.RandomState(13), lo=3, hi=12)
+
+
+@pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
+def test_a_row_that_wants_no_token_changes_nothing(unequal, wants):
+    """The decode step is handed which rows still want a token and the
+    others attend to nothing: every request's tokens are what it gets
+    alone and in a group of equal answers; nothing is traced or compiled
+    for it, and the host reads what it read."""
+    eng, prompts = unequal
+    prompts = prompts[:len(wants)]
+    pinned = (serving.trace_count(), serving.compile_count())
+    d0 = serving.dispatch_count()
+    timings, live = serving_unequal_answers(eng, prompts, wants)
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    # the group, the group of equal answers, each request alone
+    steps = max(wants)
+    assert serving.dispatch_count() - d0 == 2 * steps + sum(wants)
+    assert timings["decode_steps_fed_on_device"] == steps - 1
+    assert timings["decode_readback_bytes_per_step"] == 4 * 4
+    # the CPU's plain path reads a dead row's whole window too
+    assert timings["decode_attn_window_read_pct"] == 100.0
+
+
+def test_a_sampled_group_is_handed_the_same_mask(unequal):
+    """With a temperature the host draws every row's token from each
+    program's logits, a dead row's too (one draw a row keeps the
+    generator's stream): the rows that still want a token get the
+    tokens of the path with the host in every step and no mask."""
+    eng, prompts = unequal
+    wants = [2, 9, 5, 9]
+    want, _ = serving_host_walk(eng, prompts, 9, temperature=0.7,
+                                rng=np.random.default_rng(5))
+    outs, timings = eng.serve_group(prompts, wants, temperature=0.7,
+                                    rng=np.random.default_rng(5))
+    for i, k in enumerate(wants):
+        np.testing.assert_array_equal(outs[i], want[i, :k])
+    assert timings["decode_steps_fed_on_device"] == 0
+    assert timings["decode_row_steps_live"] == sum(k - 1 for k in wants)
+
+
+@pytest.mark.parametrize("live", [None, [True] * 4,
+                                  [False, True, False, True]], ids=str)
+def test_the_step_without_live_is_the_step_of_every_row(unequal, live):
+    """``program.step`` as its direct callers call it, with no ``live``,
+    is the step with every row live, bit for bit; a row handed as dead
+    leaves every other row's logits and cache rows as they were, still
+    writes its own cache row (the first layer's is what it was: later
+    layers follow an attention that read nothing), and its own logits
+    stay finite."""
+    import jax
+
+    eng, prompts = unequal
+    program, B = eng._program, 4
+    step = jax.jit(program.step)
+    lens = np.asarray([len(p) for p in prompts], np.int32)
+    toks = np.zeros((B, 16), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    w = eng._weights
+    cache, _ = step(w, eng.init_cache(B), np.zeros(B, np.int32), lens - 1,
+                    toks)
+    one = np.asarray([[7], [8], [9], [10]], np.int32)
+    zero = np.zeros(B, np.int32)
+    (ck, cv), want = step(w, cache, lens, zero, one)
+    (ck2, cv2), got = step(w, cache, lens, zero, one) if live is None \
+        else step(w, cache, lens, zero, one, live=np.asarray(live))
+    kept = np.asarray([True] * B if live is None else live)
+    for a, b in ((ck, ck2), (cv, cv2)):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a[:, kept], b[:, kept])
+        np.testing.assert_array_equal(a[0], b[0])
+        assert b[-1][np.arange(B), :, :, lens].any(axis=(1, 2)).all()
+    np.testing.assert_array_equal(np.asarray(got)[kept],
+                                  np.asarray(want)[kept])
+    assert np.isfinite(np.asarray(got)).all()
+    if not kept.all():
+        assert (np.asarray(got)[~kept] != np.asarray(want)[~kept]).any()
 
 
 # -- AOT warmup / retrace pin --------------------------------------------------
@@ -202,7 +300,8 @@ def test_sampled_group_draws_on_the_host_from_the_programs_logits(seed):
 
 class _TableProgram:
     """A family of one table and no layers: a row's logits are the
-    table's row of its last token, the cache counts the programs run."""
+    table's row of its last token, the cache counts the programs run
+    and the rows they were handed as live."""
 
     window, vocab = 64, 6
 
@@ -217,16 +316,18 @@ class _TableProgram:
     def init_cache(self, B):
         import jax.numpy as jnp
 
-        return (jnp.zeros((B,), jnp.int32),)
+        return (jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
 
-    def step(self, w, cache, pos, last, toks):
+    def step(self, w, cache, pos, last, toks, live=None):
         import jax.numpy as jnp
 
         tok = jnp.take_along_axis(toks, last[:, None], axis=1)[:, 0]
-        return (cache[0] + 1,), w[0][tok]
+        handed = 0 if live is None else live.astype(jnp.int32)
+        return (cache[0] + 1, cache[1] + handed), w[0][tok]
 
     def counters(self, cache):
-        return {"programs_run": int(np.asarray(cache[0])[0])}
+        return {"programs_run": int(np.asarray(cache[0])[0]),
+                "live_handed": np.asarray(cache[1]).tolist()}
 
 
 class _TableModel:
@@ -259,6 +360,45 @@ def test_ties_go_to_the_lowest_index_as_numpys_argmax():
         assert got.tolist() == want
     # the family's counters are read once, after the last step
     assert timings["programs_run"] == steps
+
+
+@pytest.mark.parametrize("temperature", [None, 0.7])
+def test_the_decode_steps_are_handed_the_rows_that_want_a_token(
+        temperature):
+    """Three requests in a bucket of four, wanting 3, 1 and 5 tokens:
+    four decode steps are dispatched; decode step j is fed token j, so
+    it is handed row i as live when ``j + 1 < wants[i]``: 2, 0 and 4
+    steps, and the pad row never.  The prefill is handed no mask.  The
+    count of wanted tokens lives on the device: nothing more is read a
+    step, no step waits for the host, no program is traced or compiled
+    for it.  With a temperature the host draws, and the steps are
+    handed the same mask."""
+    table = np.arange(36, dtype=np.float32).reshape(6, 6) % 7
+    eng = serving.ServingEngine(_TableModel(table), batch_buckets=(4,))
+    prompts, wants = [[5, 0], [2], [1, 1, 3]], [3, 1, 5]
+    eng.serve_group(prompts, wants)                     # compiles
+    pinned = (serving.trace_count(), serving.compile_count())
+    d0 = serving.dispatch_count()
+    outs, timings = eng.serve_group(
+        prompts, wants, temperature=temperature,
+        rng=np.random.default_rng(3))
+    assert [len(o) for o in outs] == wants
+    assert (serving.trace_count(), serving.compile_count()) == pinned
+    assert serving.dispatch_count() - d0 == 1 + 4
+    assert timings["programs_run"] == 5
+    assert timings["live_handed"] == [2, 0, 4, 0]
+    assert timings["decode_row_steps"] == 4 * 4
+    assert timings["decode_row_steps_live"] == 2 + 0 + 4
+    if temperature:
+        assert timings["decode_steps_fed_on_device"] == 0
+        assert timings["decode_readback_bytes_per_step"] == 4 * 4 * 6
+    else:
+        assert timings["decode_steps_fed_on_device"] == 4
+        assert timings["decode_readback_bytes_per_step"] == 4 * 4
+    # one token a request: no decode step, no row-step
+    _, timings = eng.serve_group(prompts, 1)
+    assert timings["decode_row_steps"] == 0 \
+        and timings["decode_row_steps_live"] == 0
 
 
 # -- continuous batcher --------------------------------------------------------
@@ -294,6 +434,9 @@ def test_batcher_coalesces_and_emits_request_records():
         # the decode attention's path and reach, as the engine counted
         assert r["decode_attn_kernel_share"] == 0.0
         assert r["decode_attn_window_read_pct"] == 100.0
+        # four requests of three tokens in a bucket of four: two decode
+        # steps, every row live in both
+        assert r["decode_row_steps"] == r["decode_row_steps_live"] == 8
 
 
 def test_batcher_propagates_engine_errors():
@@ -613,7 +756,14 @@ def test_tp_serving_matches_unsharded(mesh8):
     assert timings["bucket"] == [2, 8]
     pinned = serving.trace_count()
     tp.serve_group(prompts, 4)
+    # the count of tokens each row wants is placed where the positions
+    # are: a row that ends early changes nothing for itself or the other
+    short, timings = tp.serve_group(prompts, [2, 4])
     assert serving.trace_count() == pinned
+    for got, want, k in zip(short, outs, [2, 4]):
+        np.testing.assert_array_equal(got, want[:k])
+    assert (timings["decode_row_steps"],
+            timings["decode_row_steps_live"]) == (6, 4)
 
 
 # -- env knobs -----------------------------------------------------------------
