@@ -1,0 +1,57 @@
+"""The Command A+ decode step's share of its roofline: the least time
+the chip could take for one step (the larger of required bytes over HBM
+bandwidth and required operations over the bf16 peak,
+`flops_cohere2_moe.py`) over the decode program's device time in the
+trace (the median execution of ``jit_serve_decode``).  Bytes: the
+non-expert weights once (the four shared experts and the tied head
+among them), the routed experts hit (the engine's
+``moe_experts_hit_per_step`` of each group), and of the caches what a
+live row's step has to read: a ring to ``min(length, 4096)``, the full
+layer to the length.  Mean over the traced groups' steps, counting only
+rows that still wanted a token.  None where the records carry no such
+counters (a program without them) or there is no trace."""
+
+import statistics
+
+import numpy as np
+
+from benchmark import flops_cohere2_moe as flops
+
+
+def read(run, params):
+    modules = run["trace"]["modules"]
+    times = [t for k, v in modules.items()
+             if k.startswith(params.get("program", "jit_serve_decode"))
+             for t in v]
+    if not times:
+        return None
+    device_s = statistics.median(times)
+    config = run["cell"]["config"]
+    itemsize = np.dtype(params.get("itemsize_of", "float16")).itemsize
+    groups = {}
+    for rec in run["records"]:
+        if "t_decode0" in rec and "attn_window_pairs_decode" in rec \
+                and "moe_experts_hit_per_step" in rec:
+            groups.setdefault(rec["t_decode0"], []).append(rec)
+    need_bytes, need_flops = [], []
+    for recs in groups.values():
+        steps = max(len(r["tokens"]) for r in recs) - 1
+        hit = float(recs[0]["moe_experts_hit_per_step"])
+        pairs = recs[0]["moe_pairs_decode"] / max(1, steps)
+        for j in range(steps):
+            live = [len(r["prompt"]) + j + 1 for r in recs
+                    if len(r["tokens"]) > j + 1]
+            need_bytes.append(flops.decode_step_bytes(
+                config, itemsize, live, hit))
+            need_flops.append(flops.decode_step_flops(
+                config, len(live), pairs,
+                sum(flops.positions_read(config, n) for n in live)))
+    if not need_bytes or device_s <= 0:
+        return None
+    t_bytes = statistics.mean(need_bytes) / run["peaks"]["hbm_bytes_per_s"]
+    t_flops = statistics.mean(need_flops) / run["peaks"]["bf16_flops_per_s"]
+    run.setdefault("notes", []).append(
+        f"decode step: bound by {'bytes' if t_bytes >= t_flops else 'flops'}"
+        f" ({t_bytes * 1e3:.3f} ms against {t_flops * 1e3:.3f} ms), "
+        f"jit_serve_decode took {device_s * 1e3:.3f} ms on the device")
+    return 100.0 * max(t_bytes, t_flops) / device_s

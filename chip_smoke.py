@@ -16,9 +16,15 @@ a cache stack of its own, attention over its selection, softmax-routed
 experts); a fourth, ``KimiK2Model`` (latent attention); a fifth,
 ``OuroModel`` (a dense stack run several times a token over one set of
 weights, a cache slot for every (loop step, layer), held to the float32
-reference's logits); with four chips, the GPT step under
+reference's logits); a sixth, ``Cohere2MoeModel`` (Command A+: a
+parallel attention-and-experts block, rotary-free full layers beside
+rings of several lane blocks), small and then at the published widths of
+``benchmark/configs/command-a-plus-ep16.json``, both held to the
+float32 reference's logits; with four chips, the GPT step under
 ``shard_model`` fsdp and tp.  Phases, in order: device, sync, kernel,
-train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro, sharded.  The first failed check raises and the process
+train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro,
+serve_cmda, serve_cmda_full, sharded (``--phases a,b``: the device
+phase and only those).  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
@@ -29,6 +35,7 @@ wall-clock seconds of a smoke run — set-up facts, not performance
 figures.
 """
 
+import argparse
 import dataclasses
 import functools
 import gc
@@ -146,6 +153,40 @@ def ouro_small():
                   max_length=512, dtype="bfloat16", grad_req="null")
     return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
                     prompt_lens=(40, 128, 300, 77, 129, 16, 260),
+                    new_tokens=6)
+
+
+def cmda_small():
+    """The sixth family at small aligned sizes with every mechanism
+    present: 16 query heads over 2 key heads of 128, three window
+    layers with rings of 256 slots (two lane blocks: the per-row kernel
+    reads them) and a full one, 2 shared experts, 4 of 8 experts held,
+    rows a prefill works off two at a time and token-wise products 256
+    positions at a time; prompts under, at and past the window, one
+    that wraps its ring within the answer."""
+    kwargs = dict(vocab_size=512, units=256,
+                  layer_types=["window", "window", "window", "full"],
+                  num_heads=16, kv_heads=2, head_dim=128, window=256,
+                  expert_hidden=128, router_experts=8, experts_per_token=2,
+                  experts_held=[2, 4], shared_experts=2, max_length=1024,
+                  token_chunk=256, prefill_chunk_tokens=2048,
+                  dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=1024,
+                    prompt_lens=(40, 256, 300, 700, 77, 510, 16, 260),
+                    new_tokens=6)
+
+
+def cmda_full():
+    """The benchmark configuration's own constructor arguments: the
+    published widths, one period of 4 layers, 8 of 128 experts a layer,
+    rings of 4,096, the cell's bucket of 8 x 16,384; prompts of 1-4
+    windows, one that wraps its rings within the answer."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "command-a-plus-ep16.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=16384,
+                    prompt_lens=(4096, 5257, 8190, 12765, 15872, 300),
                     new_tokens=6)
 
 
@@ -944,33 +985,25 @@ def phase_serve_kimi(size, platform):
 
 # -- serve, a fifth family: a looped stack --------------------------------------
 
-def require_served_logits_equal_the_reference(tag, net, engine, size):
-    """Prefill then decode through the ``T L`` slots against the plain
-    float32 reference's full forward (benchmark/references/ouro.py,
-    given the model's own weights) at every served position."""
+def require_served_logits_equal_the_reference(tag, net, engine, size, ref,
+                                              config):
+    """Prefill then decode through the family's caches against its plain
+    float32 reference's full forward (``ref``, a module of
+    benchmark/references, given the model's own weights and its sizes
+    under the source's keys, ``config``) at every served position of the
+    shortest, the middle and the longest prompt: the reference compiles
+    anew for every length."""
     import jax.numpy as jnp
 
-    from benchmark.references import ouro as ref
     from mxnet_tpu.test_utils import serving_host_walk
 
     z = net._sizes
-    config = {"hidden_size": z.units, "num_hidden_layers": z.num_layers,
-              "num_attention_heads": z.num_heads,
-              "num_key_value_heads": z.kv_heads, "head_dim": z.head_dim,
-              "intermediate_size": z.hidden_size,
-              "total_ut_steps": z.loop_steps,
-              "early_exit_threshold": z.exit_threshold,
-              "vocab_size": z.vocab, "rms_norm_eps": z.eps,
-              "rope_theta": z.rope_theta, "hidden_act": "silu",
-              "tie_word_embeddings": False}
     values = {n: getattr(net, n).data()._data for n in net._names}
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, z.vocab, n).tolist()
                for n in size.prompt_lens]
     toks, logits = serving_host_walk(engine, prompts, size.new_tokens)
     worst = 0.0
-    # the shortest, the middle and the longest row: the reference
-    # compiles anew for every length
     order = np.argsort(size.prompt_lens)
     for i in order[[0, len(order) // 2, -1]]:
         p = prompts[i]
@@ -1014,8 +1047,90 @@ def phase_serve_ouro(size, platform):
             == (T * L, 1, z.kv_heads, z.head_dim, engine._W)
             and net.qkv_weight.shape[0] == L,
             f"serve_ouro: cache {[tuple(c.shape) for c in big]}")
-    require_served_logits_equal_the_reference("serve_ouro", net, engine,
-                                              size)
+    from benchmark.references import ouro as ref
+
+    require_served_logits_equal_the_reference(
+        "serve_ouro", net, engine, size, ref, {
+            "hidden_size": z.units, "num_hidden_layers": z.num_layers,
+            "num_attention_heads": z.num_heads,
+            "num_key_value_heads": z.kv_heads, "head_dim": z.head_dim,
+            "intermediate_size": z.hidden_size,
+            "total_ut_steps": z.loop_steps,
+            "early_exit_threshold": z.exit_threshold,
+            "vocab_size": z.vocab, "rms_norm_eps": z.eps,
+            "rope_theta": z.rope_theta, "hidden_act": "silu",
+            "tie_word_embeddings": False})
+    return out
+
+
+# -- serve, a sixth family -----------------------------------------------------
+
+def phase_serve_cmda(size, platform, tag="serve_cmda"):
+    from mxnet_tpu.gluon.model_zoo import cohere2_moe
+
+    kw = size.kwargs
+    R = kw["window"]
+    types = kw["layer_types"]
+    n_window, n_full = types.count("window"), types.count("full")
+    steps = size.new_tokens - 1
+
+    def band(n):
+        m = min(n, R)
+        return m * (m + 1) // 2 + (n - m) * R
+
+    def counters_hold(timing, lens, pads):
+        # the pairs each kind of layer was asked to score, the band's on
+        # the window layers; the pad rows' one token counts in a prefill
+        # and wants nothing after it; every prefill attention call went
+        # through the flash forward kernel
+        rows = lens + (1,) * pads
+        causal = sum(n * (n + 1) // 2 for n in rows)
+        return timing["attn_window_pairs_prefill"] \
+            == n_window * sum(band(n) for n in rows) \
+            and timing["attn_window_pairs_causal_prefill"] \
+            == n_window * causal \
+            and timing["attn_full_pairs_prefill"] == n_full * causal \
+            and timing["attn_window_pairs_decode"] == n_window * sum(
+                min(n + j + 1, R) for n in lens for j in range(steps)) \
+            and timing["attn_full_pairs_decode"] == n_full * sum(
+                n + j + 1 for n in lens for j in range(steps)) \
+            and timing["prefill_attn_kernel_share"] == 1.0 \
+            and 0 < timing["moe_pairs_prefill"] <= sum(lens) \
+            * len(types) * kw["experts_per_token"] \
+            and moe_rows_hold(timing)
+
+    # four stacks: two kinds of cache, keys and values
+    net, engine, _, out = serve_family(
+        tag, cohere2_moe.Cohere2MoeModel, size, platform, 4, counters_hold)
+    z, big = net._sizes, engine.init_cache(1)
+    require(len(big) == 6 and big[0].shape == big[1].shape
+            == (n_full, 1, z.kv_heads, z.head_dim, engine._W)
+            and big[2].shape == big[3].shape
+            == (n_window, 1, z.kv_heads, z.head_dim, R),
+            f"{tag}: cache {[tuple(c.shape) for c in big]}")
+    from benchmark.references import cohere2_moe as ref
+
+    require_served_logits_equal_the_reference(tag, net, engine, size, ref, {
+        "hidden_size": z.units, "num_hidden_layers": len(types),
+        "layer_types": [{"window": "sliding_attention",
+                         "full": "full_attention"}[t] for t in types],
+        "num_attention_heads": z.num_heads,
+        "num_key_value_heads": z.kv_heads, "head_dim": z.head_dim,
+        "sliding_window": R, "rope_theta": z.rope_theta, "rotary_pct": 1,
+        "position_embedding_type": "rope_gptj",
+        "intermediate_size": z.expert_hidden,
+        "num_experts": z.experts_held[1],
+        "router_experts": z.router_experts,
+        "experts_held": list(z.experts_held),
+        "num_experts_per_tok": z.experts_per_token,
+        "num_shared_experts": z.shared_experts,
+        "shared_expert_combination_strategy": "average",
+        "expert_selection_fn": "sigmoid", "norm_topk_prob": True,
+        "first_k_dense_replace": 0, "use_parallel_block": True,
+        "use_qk_norm": False, "attention_bias": False,
+        "use_gated_activation": True, "hidden_act": "silu",
+        "tie_word_embeddings": True, "logit_scale": z.logit_scale,
+        "layer_norm_eps": z.eps, "vocab_size": z.vocab})
     return out
 
 
@@ -1078,12 +1193,21 @@ def phase_sharded(size, platform, single_loss0, single_peak):
 
 # -- main ----------------------------------------------------------------------
 
-def main():
+def main(argv=()):
+    """``--phases a,b`` runs the device phase and then only those named
+    (``serve`` needs ``train``): a builder who changed one family spends
+    the chip's minutes on that one."""
     platform = "tpu"    # not configurable: this script proves the chip
     t_start = time.perf_counter()
     timings = {}
+    ap = argparse.ArgumentParser(description=main.__doc__)
+    ap.add_argument("--phases", default="")
+    only = set(filter(None, ap.parse_args(list(argv)).phases.split(",")))
 
     def run(name, fn, *args):
+        if only and name != "device" and name not in only:
+            say(f"[{name}] skipped: --phases {sorted(only)}")
+            return None
         t0, c0 = time.perf_counter(), len(COMPILES)
         res = fn(*args)
         timings[name] = {
@@ -1101,7 +1225,8 @@ def main():
     run("sync", phase_sync, FULL, platform)
     run("kernel", phase_kernel, FULL, platform)
     train = run("train", phase_train, FULL, platform)
-    run("serve", phase_serve, FULL, platform, train.pop("net"))
+    if train is not None:
+        run("serve", phase_serve, FULL, platform, train.pop("net"))
     gc.collect()
     run("serve_mimo", phase_serve_mimo, mimo_full(), platform)
     gc.collect()
@@ -1111,9 +1236,16 @@ def main():
     gc.collect()
     run("serve_ouro", phase_serve_ouro, ouro_small(), platform)
     gc.collect()
+    run("serve_cmda", phase_serve_cmda, cmda_small(), platform)
+    gc.collect()
+    run("serve_cmda_full", phase_serve_cmda, cmda_full(), platform,
+        "serve_cmda_full")
+    gc.collect()
     import jax
 
-    if jax.local_device_count() >= 4:
+    if train is None:
+        say("[sharded] skipped: it is held to the train phase's loss")
+    elif jax.local_device_count() >= 4:
         run("sharded", phase_sharded, FULL, platform,
             train["losses"][0], train["peak_bytes"])
     else:
@@ -1125,4 +1257,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    main(sys.argv[1:])

@@ -1,11 +1,13 @@
 """What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`,
-`kimi_k2.py`): the pieces of a layer and of a decoder program that do
-not depend on a family's attention or routing rule.  No family imports
+`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`): the pieces of a layer and of
+a decoder program that do not depend on a family's attention or routing
+rule.  No family imports
 another; a change here is a change to all, and their cells measure it.
 
-- `mm`, `rms_norm`, `rope`: the mixed-precision product, RMSNorm and
-  the rotation (rotate-half over the first ``rot`` dimensions, by
-  ``theta`` or by a table of frequencies: `yarn_frequencies`);
+- `mm`, `rms_norm`, `layer_norm`, `rope`: the mixed-precision product,
+  RMSNorm, the bias-free LayerNorm and the rotation (over the first
+  ``rot`` dimensions, by ``theta`` or by a table of frequencies:
+  `yarn_frequencies`; rotate-half, or Cohere's interleaved pairs);
 - `attn_out`: heads side by side, then ``x + a Wo`` (attention over
   the caches is `ops/cache_attention.py`, GPT's too; a block's
   attention inside itself is the family's own: Kimi-K2's goes through
@@ -42,10 +44,23 @@ def rms_norm(x, g, eps):
                          + eps) * g.astype(jnp.float32)
 
 
-def rope(x, pos, theta, rot, freq=None):
+def layer_norm(x, g, eps):
+    """LayerNorm with a gain and no bias, the statistics in x's float32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                         + eps) * g.astype(jnp.float32)
+
+
+def rope(x, pos, theta, rot, freq=None, pairs="halves"):
     """x (B, .., S, D) float32 rotated on its first ``rot`` dimensions at
-    positions ``pos`` (B, S), dimension j paired with j + rot/2, by
-    ``theta ** (-2 j / rot)`` or by the table ``freq`` (rot / 2,)."""
+    positions ``pos`` (B, S) by ``theta ** (-2 j / rot)`` or by the
+    table ``freq`` (rot / 2,).  ``pairs``: ``"halves"`` pairs dimension
+    j with j + rot/2 (rotate-half); ``"interleaved"`` pairs 2j with
+    2j + 1 (``rope_gptj``: Cohere's).  The two are a relabelling of the
+    projection's columns and not interchangeable under given weights."""
     import jax.numpy as jnp
 
     half = rot // 2
@@ -56,6 +71,14 @@ def rope(x, pos, theta, rot, freq=None):
     ang = pos.astype(jnp.float32)[..., None] * freq          # (B, S, half)
     shape = (pos.shape[0],) + (1,) * (x.ndim - 3) + (pos.shape[1], half)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    if pairs == "interleaved":
+        a, b = x[..., 0:rot:2], x[..., 1:rot:2]
+        turned = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return jnp.concatenate(
+            [turned.reshape(x.shape[:-1] + (rot,)), x[..., rot:]], axis=-1)
+    if pairs != "halves":
+        raise ValueError(f"rope: pairs is 'halves' or 'interleaved', "
+                         f"got {pairs!r}")
     a, b = x[..., :half], x[..., half:rot]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
                             x[..., rot:]], axis=-1)

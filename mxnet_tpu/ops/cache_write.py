@@ -27,6 +27,15 @@ chosen on what the call can see:
   ``lax.dynamic_update_slice`` a row and a stack.  It is also what the
   kernel is tested against (tests/test_cache_write.py, interpreted).
 
+**Rings.**  A window layer's stack is a ring of ``W`` = window slots:
+position p lives in slot ``p mod W``.  A decode step writes it through
+``write_rows`` at ``starts = pos % W`` (the kernel, as any other row
+write).  A prefilled block leaves a ring through ``write_ring``: of row
+b's ``lengths[b]`` positions the last ``min(lengths[b], W)``, each in
+its slot, which where they wrap are two pieces of the block: one
+dynamic slice of ``W`` positions, turned by the slot its first
+position falls to, and one ``dynamic_update_slice`` a row and a stack.
+
 What a decode step then reads of the stacks, each row to its own
 length, is `ops/cache_attention.py`.
 
@@ -88,6 +97,44 @@ def write_rows(stacks, news, l, starts, mesh=None, tally=None, row=None):
         return _write_kernel(tuple(stacks), news, l, starts)
     return tuple(_write_by_rows(c, n, l, starts, 0 if row is None else row)
                  for c, n in zip(stacks, news))
+
+
+def write_ring(stacks, news, l, lengths, tally=None, row=None):
+    """``stacks``: ``n`` rings ``(L, B, K_i, D_i, W)``; ``news``: as
+    many blocks ``(R, K_i, D_i, S)`` holding positions ``0 .. S``;
+    ``lengths`` (R,) int32, each row's real positions (1 at least).
+    Slot ``s`` of row b's ring of layer ``l`` gets the latest position
+    ``p < lengths[b]`` with ``p mod W == s``; a slot no such position
+    falls to (a row shorter than the ring) gets whatever the block
+    holds past the row's length, which nothing reads before a decode
+    step has written it (`cache_attention.attend_rows` is asked for
+    ``min(pos + 1, W)`` slots).  ``row`` as `write_rows`.  Returns the
+    rings, written."""
+    R, S = news[0].shape[0], news[0].shape[-1]
+    W = stacks[0].shape[-1]
+    if tally is not None:
+        tally["rows"] += R * len(stacks)
+    if S <= W:      # nothing wraps: the block from slot 0
+        zeros = jnp.zeros((R,), jnp.int32)
+        return tuple(
+            _write_by_rows(c, n.astype(c.dtype), l, zeros,
+                           0 if row is None else row)
+            for c, n in zip(stacks, news))
+    first = jnp.clip(lengths.astype(jnp.int32) - W, 0, S - W)      # (R,)
+    zero = jnp.int32(0)
+    out = []
+    for c, new in zip(stacks, news):
+        for b in range(R):
+            # positions first .. first + W, the one at first + i bound
+            # for slot (first + i) mod W: turned by first mod W
+            kept = lax.dynamic_slice_in_dim(new[b], first[b], W, axis=-1)
+            c = lax.dynamic_update_slice(
+                c, jnp.roll(kept, first[b] % W, axis=-1).astype(c.dtype)[
+                    None, None],
+                (jnp.int32(l), jnp.int32((0 if row is None else row) + b),
+                 zero, zero, zero))
+        out.append(c)
+    return tuple(out)
 
 
 def _write_by_rows(c, new, l, starts, row=0):

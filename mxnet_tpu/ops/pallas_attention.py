@@ -222,12 +222,16 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry):
+def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None):
     """Run ``tile(c, carry, masked)`` over those of a block's ``n`` key
     sub-tiles (``sub_k`` positions each, from ``k0``) that the ``sub_q``
     queries from ``q0`` can see: unmasked while a sub-tile lies wholly
     at or below the first query, masked while it holds any key at or
-    below the last one, and none above the diagonal."""
+    below the last one, and none above the diagonal.  With ``window``
+    (an int; causal) a query sees its own position and the ``window -
+    1`` before it: none of the sub-tiles that lie wholly behind the
+    first query's band, masked those that hold a key behind the last
+    query's."""
     loop = jax.lax.fori_loop
     if not causal:
         return loop(0, n, lambda c, x: tile(c, x, False), carry)
@@ -235,14 +239,25 @@ def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry):
     whole = jnp.clip(div(jnp.maximum(q0 + 1 - k0, 0), sub_k), 0, n)
     some = jnp.clip(div(jnp.maximum(q0 + sub_q - k0 + sub_k - 1, 0),
                         sub_k), 0, n)
-    carry = loop(0, whole, lambda c, x: tile(c, x, False), carry)
+    if window is None:
+        carry = loop(0, whole, lambda c, x: tile(c, x, False), carry)
+        return loop(whole, some, lambda c, x: tile(c, x, True), carry)
+    # the first sub-tile with a key at or past the first query's band,
+    # and the first whose keys all lie inside the last query's
+    begin = jnp.clip(div(jnp.maximum(q0 - (window - 1) - k0, 0), sub_k),
+                     0, some)
+    inside = jnp.clip(div(jnp.maximum(q0 + sub_q - window - k0 + sub_k - 1,
+                                      0), sub_k), begin, some)
+    whole = jnp.clip(whole, inside, some)
+    carry = loop(begin, inside, lambda c, x: tile(c, x, True), carry)
+    carry = loop(inside, whole, lambda c, x: tile(c, x, False), carry)
     return loop(whole, some, lambda c, x: tile(c, x, True), carry)
 
 
 # -- forward -------------------------------------------------------------------
 
 def _fwd_tiles(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, tiles, q_first,
-               k_first, at, *, scale, causal, sub_q, sub_k):
+               k_first, at, *, scale, causal, sub_q, sub_k, window=None):
     """One key block's part of a query block's running softmax: q_ref
     (1, block_q, D) from position ``q_first``, its first ``tiles``
     sub-tiles; the key block ``at`` of k_ref (., block_k, D) and v_ref
@@ -266,7 +281,15 @@ def _fwd_tiles(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, tiles, q_first,
                 # or has seen key 0 before, so exp(_NEG - m) is 0.0
                 i_j = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-                s = jnp.where(i_j >= k_first + c * sub_k - q0, s, _NEG)
+                seen = i_j >= k_first + c * sub_k - q0
+                if window is not None:
+                    # and no further behind than the band.  A row whose
+                    # band begins past this sub-tile sees nothing yet:
+                    # its sums take exp(0) of every key here and are
+                    # wiped (exp(_NEG - m) is 0.0) by the first key it
+                    # does see, at the latest its own
+                    seen = seen & (i_j < k_first + c * sub_k - q0 + window)
+                s = jnp.where(seen, s, _NEG)
             m_prev = m_scr[rows, :]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
             p = jnp.exp(s - _bcast_lanes(m_next, sub_k))
@@ -280,7 +303,7 @@ def _fwd_tiles(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, tiles, q_first,
             return carry
 
         return _walk(causal, q0, sub_q, k_first, sub_k,
-                     k_ref.shape[1] // sub_k, tile, carry)
+                     k_ref.shape[1] // sub_k, tile, carry, window)
 
     jax.lax.fori_loop(0, tiles, q_tile, 0)
 
@@ -327,7 +350,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
                      m_scr, l_scr, acc_scr, slot_ref, *, scale, block_q,
-                     block_k, sub_q, sub_k, heads):
+                     block_k, sub_q, sub_k, heads, window=None, group=1):
     """The causal forward with its key blocks brought by the kernel (a
     serving prefill's): a step is query block ``qi`` of a head over ALL
     the key blocks it can see, to its diagonal, and none where the block
@@ -338,7 +361,16 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     and every step leaves the next walking step's first block in
     flight, so no copy waits for a grid step.  Scratch besides the
     buffers: the copies' semaphores (k or v, buffer), the running sums,
-    and which buffer the step's first block is in."""
+    and which buffer the step's first block is in.
+
+    ``window`` (an int or None): a query sees its own position and the
+    ``window - 1`` before it, so a step's walk begins at the first key
+    block that holds a key of its first query's band (``first_of``)
+    and no block wholly behind the band is copied or multiplied.
+    ``group``: query heads a key head; grid row ``h`` reads key head
+    ``h // group`` of k_hbm and v_hbm, which then hold ``1 / group`` as
+    many heads as q (grouped-query attention, nothing repeated in
+    HBM)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -349,19 +381,30 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     # the block's query sub-tiles that hold a live position
     tiles = jnp.clip(pl.cdiv(n - qi * block_q, sub_q), 0, block_q // sub_q)
 
+    def first_of(i):
+        """The first key block that query block ``i`` walks."""
+        return jnp.maximum(i * block_q - (window - 1), 0) // block_k
+
     def blocks_of(head, i):
         """Key blocks that query block ``i`` of ``head`` walks."""
         live = i * block_q < len_ref[head // heads]
-        return jnp.where(live, (i * block_q + block_q - 1) // block_k + 1, 0)
+        to_diagonal = (i * block_q + block_q - 1) // block_k + 1
+        if window is not None:
+            to_diagonal = to_diagonal - first_of(i)
+        return jnp.where(live, to_diagonal, 0)
 
     def copies(head, j, slot):
         at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        if group != 1:
+            head = head // group
         return (pltpu.make_async_copy(k_hbm.at[head, at, :], k_buf.at[slot],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[head, at, :], v_buf.at[slot],
                                       sem.at[1, slot]))
 
     blocks = blocks_of(b, qi)
+    # the walk's first block: 0 with no window, as a Python int
+    first = 0 if window is None else first_of(qi)
 
     @pl.when((b == 0) & (qi == 0))
     def _first():
@@ -380,27 +423,28 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         """The next step's first block into ``slot``, if it walks any."""
         @pl.when((nb < nh) & (blocks_of(jnp.minimum(nb, nh - 1), ni) > 0))
         def _():
-            for c in copies(nb, 0, slot):
+            for c in copies(nb, 0 if window is None else first_of(ni), slot):
                 c.start()
 
     _fwd_open(*scr)
 
     def block(j, carry):
         slot = (base + j) % 2
+        at = j if window is None else first + j
 
         # the next block sets out before this one is waited for: the
         # step's own, or behind its last the next step's first
         @pl.when(j + 1 < blocks)
         def _ahead():
-            for c in copies(b, j + 1, 1 - slot):
+            for c in copies(b, at + 1, 1 - slot):
                 c.start()
 
         pl.when(j + 1 == blocks)(lambda: hand_on(1 - slot))
-        for c in copies(b, j, slot):
+        for c in copies(b, at, slot):
             c.wait()
         _fwd_tiles(q_ref, k_buf, v_buf, *scr, tiles, qi * block_q,
-                   j * block_k, slot, scale=scale, causal=True,
-                   sub_q=sub_q, sub_k=sub_k)
+                   at * block_k, slot, scale=scale, causal=True,
+                   sub_q=sub_q, sub_k=sub_k, window=window)
         return carry
 
     jax.lax.fori_loop(0, blocks, block, 0)
@@ -489,15 +533,18 @@ def _flash_call(q, k, v, causal, scale, block_q=None, block_k=None,
     return out.reshape(B, H, T, D), lse
 
 
-def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None):
-    """`_fwd_rows_kernel` over q, k (B, H, T, D), v (B, H, T, Dv) and
-    ``lengths`` (B,) int32 within [0, T]: grid (B·H, query blocks), both
-    axes in order (a step hands the next its first block)."""
+def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None,
+                     window=None):
+    """`_fwd_rows_kernel` over q (B, H, T, D), k (B, Hk, T, D), v (B,
+    Hk, T, Dv) (``Hk`` divides ``H``: ``H / Hk`` query heads read a key
+    head) and ``lengths`` (B,) int32 within [0, T]: grid (B·H, query
+    blocks), both axes in order (a step hands the next its first
+    block).  ``window``: static, None for all earlier positions."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
-    Dv = v.shape[-1]
+    Hk, Dv = k.shape[1], v.shape[-1]
     block_q, block_k = _blocks(T, D, q.dtype, "fwd", block_q, block_k, Dv)
     sub_q, sub_k = _sub_tiles(block_q, block_k)
     interpret = _use_interpret()
@@ -505,6 +552,11 @@ def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None):
         "compiler_params": _compiler_params(
             T, D, q.dtype, "fwd", block_q, block_k,
             ("arbitrary", "arbitrary"), Dv)}
+    # with no window and a key head a query head, the kernel the parent
+    # built: neither is an operand, and neither adds an equation
+    more = {} if window is None else {"window": int(window)}
+    if Hk != H:
+        more["group"] = H // Hk
 
     def q_at(b, i, lens):
         # no further than the row's last live block: a step that stores
@@ -515,7 +567,7 @@ def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None):
     out = pl.pallas_call(
         functools.partial(_fwd_rows_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, sub_q=sub_q, sub_k=sub_k,
-                          heads=H),
+                          heads=H, **more),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * H, T // block_q),
@@ -536,8 +588,8 @@ def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None):
         out_shape=jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
         interpret=interpret,
         **kw,
-    )(lengths, q.reshape(B * H, T, D), k.reshape(B * H, T, D),
-      v.reshape(B * H, T, Dv))
+    )(lengths, q.reshape(B * H, T, D), k.reshape(B * Hk, T, D),
+      v.reshape(B * Hk, T, Dv))
     return out.reshape(B, H, T, Dv)
 
 
@@ -735,13 +787,25 @@ def lane_tiles(n):
 
 
 def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
-                            block_k=None):
+                            block_k=None, window=None):
     """Causal attention, the forward alone, for a caller that takes no
     gradient (a serving prefill): no ``custom_vjp``, no logsumexp
-    written, and no derivative (JAX has none for the call).  q, k (B, H,
-    T, D); v (B, H, T, Dv), ``Dv`` need not be ``D``; returns (B, H, T,
-    Dv) in q's type.  ``scale`` is the caller's to give: the entry
-    cannot tell a head's width from zeros it arrives padded with.
+    written, and no derivative (JAX has none for the call).  q (B, H,
+    T, D); k (B, Hk, T, D); v (B, Hk, T, Dv), ``Dv`` need not be ``D``;
+    returns (B, H, T, Dv) in q's type.  ``Hk`` is ``H`` or a divisor of
+    it: query head h then reads key head ``h // (H / Hk)`` where it
+    lies, and nothing is repeated in HBM (Command A+: 128 over 8).
+    ``scale`` is the caller's to give: the entry cannot tell a head's
+    width from zeros it arrives padded with.
+
+    ``window`` (a static int, or None for every earlier position):
+    query i sees keys ``i - window + 1 .. i``.  The walk of a query
+    block over its key blocks starts at the first block that holds a
+    visible key, so no grid step, copy or product is spent behind the
+    band either; ``window`` need not be a multiple of a block.  With
+    None the call builds the kernel it built before the operand
+    existed (tests/test_pallas_attention.py holds its grid and
+    operands).
 
     ``lengths`` (B,) int32, a traced operand (None: all ``T``): row b
     holds ``lengths[b]`` real positions from 0.  Its queries at and past
@@ -759,6 +823,14 @@ def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
     that can make q and k that wide to begin with spares it (HBM pads
     their rows to whole tiles anyway), and the scores are the same."""
     B, T, Dv = q.shape[0], q.shape[2], v.shape[-1]
+    if q.shape[1] % k.shape[1] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"flash_attention_forward: {k.shape[1]} key heads do not "
+            f"divide {q.shape[1]} query heads (q {q.shape}, k {k.shape}, "
+            f"v {v.shape})")
+    if window is not None and int(window) < 1:
+        raise ValueError("flash_attention_forward: a window holds the "
+                         f"query's own position at least, got {window}")
     lengths = (jnp.full((B,), T, jnp.int32) if lengths is None
                else jnp.clip(lengths.astype(jnp.int32), 0, T))
 
@@ -769,4 +841,5 @@ def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
             m for _, m in more) else x
 
     return _flash_rows_call(whole(q), whole(k), whole(v), float(scale),
-                            lengths, block_q, block_k)[:, :, :T, :Dv]
+                            lengths, block_q, block_k,
+                            window)[:, :, :T, :Dv]

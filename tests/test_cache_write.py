@@ -541,3 +541,70 @@ def test_ouros_programs_compile_for_a_v5e_at_the_published_widths(
         assert mem.temp_size_in_bytes < stack_bytes // 8
         assert dict(program.block_attends[S]) == {"kernel": 1}
     assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])
+
+
+@pytest.mark.parametrize("S", [1, 16384])
+def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch, S):
+    """Command A+'s decode step and its 8 x 16,384 prefill
+    (`gluon/model_zoo/cohere2_moe.py::Cohere2MoeProgram.step`, the sizes
+    of benchmark/configs/command-a-plus-ep16.json) lower and compile for
+    a described v5e with the kernels in them: the rings of 4,096 go
+    through the row-write and the per-row attention kernels (16 query
+    heads a key head, 32 lane blocks a ring), the prefill through the
+    flash forward kernel with 8 key heads under 128 query heads and, on
+    the window layers, ``window=4096``; all four stacks are written into
+    their donated arguments (the prefill a row at a time), and the
+    decode program holds no temporary of a ring layer's size."""
+    import json
+    import os
+
+    from mxnet_tpu.gluon.model_zoo import cohere2_moe
+    from mxnet_tpu.ops import cache_attention, pallas_attention as pa
+
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-ep16.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    net = cohere2_moe.Cohere2MoeModel(**kwargs)   # no parameter allocated
+    z, B, W = net._sizes, 8, kwargs["max_length"]
+    program = cohere2_moe.Cohere2MoeProgram(net, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    full = sds((1, B, z.kv_heads, z.head_dim, W))
+    ring = sds((3, B, z.kv_heads, z.head_dim, z.window))
+    assert (z.window, z.groups, W) == (4096, 16, 16384)
+    # the layouts donated stacks arrive in, as `init_cache` reads them
+    # off allocated ones on the chip
+    program._pins = [jax.jit(lambda x: x).lower(c).compile(
+        ).input_formats[0][0].layout for c in (full, full, ring, ring)]
+    weights = tuple(sds(shape) for _, shape in z.leaves())
+    assert sum(int(np.prod(w.shape)) for w in weights) == 3_122_679_808
+    compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
+        weights, (full, full, ring, ring, sds((4, 2, 11), jnp.int32),
+                  sds((4, 2, 2), jnp.uint32)),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, S), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    for i in range(6):
+        assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
+    ring_layer = B * z.kv_heads * z.head_dim * z.window * 2
+    mem = compiled.memory_analysis()
+    if S == 1:
+        assert mem.temp_size_in_bytes < ring_layer
+        assert dict(program.cache_writes[1]) == {"kernel": 4 * 2 * B}
+        assert dict(program.cache_reads[1]) == {
+            ("kernel", 4096, 256): 3, ("kernel", W, 512): 1}
+    else:
+        # one row's stream, queries, attention output and a token
+        # chunk's shared-expert hidden, not eight rows'
+        assert mem.temp_size_in_bytes < 4 << 30
+        assert dict(program.block_attends[S]) == {"kernel": 4}
+    assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])

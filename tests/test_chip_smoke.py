@@ -153,3 +153,29 @@ def test_serve_ouro_phase():
                                prompt_lens=(3, 8, 21, 40), new_tokens=7)
     out = chip_smoke.phase_serve_ouro(size, "cpu")
     assert out["retraces"] == 0 and out["programs"] == 2
+
+
+def test_serve_cmda_phase():
+    """The sixth family's phase at a tiny size: the engine's tuple is the
+    parameters' own buffers, the four stacks (two rings among them) stay
+    where they are, the pairs of each kind of layer are counted (the
+    band's on the window layers), coalesced == alone, a repeat is
+    identical, and the served logits equal the float32 reference's; the
+    phase at the published widths reads the cell's own configuration."""
+    small, full = chip_smoke.cmda_small(), chip_smoke.cmda_full()
+    assert small.kwargs["head_dim"] == 128 and small.kwargs["window"] == 256
+    assert full.kwargs["window"] == 4096 and full.prefill_floor \
+        == full.kwargs["max_length"] == 16384
+    assert max(full.prompt_lens) + full.new_tokens <= 16384
+    kwargs = dict(vocab_size=96, units=64,
+                  layer_types=["window", "window", "window", "full"],
+                  num_heads=8, kv_heads=2, head_dim=16, window=8,
+                  expert_hidden=32, router_experts=8, experts_per_token=2,
+                  experts_held=[2, 4], shared_experts=2, max_length=64,
+                  token_chunk=16, prefill_chunk_tokens=128,
+                  grad_req="null")
+    assert set(kwargs) <= set(small.kwargs) | {"dtype"}
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(3, 8, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_cmda(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2

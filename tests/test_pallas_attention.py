@@ -2,7 +2,9 @@
 against the dense oracle at the blocks the shape gives and at forced
 small ones, what their products are fed, and the block rule; the
 forward-only entry with a value width of its own and a length a row,
-and that training's call lowers to the grid it had.  The
+its window and its key heads fewer than query heads (Command A+'s
+prefill), and that training's call and the entry without a window
+lower to the grids they had.  The
 compiles for a described v5e are in `tests/test_cache_write.py` (one
 worker loads the TPU's library)."""
 
@@ -173,6 +175,123 @@ def test_forward_entry_takes_a_block_shorter_than_its_tiles(T, lengths,
             assert not out[b, :, n:].any(), f"row of {n}"
 
 
+def _band_ref(q, k, v, scale, window):
+    """The dense masked softmax in float32: query i over keys
+    ``i - window + 1 .. i``, query head h over key head ``h // group``."""
+    group = q.shape[1] // k.shape[1]
+    q, k, v = (x.astype(F32) for x in (q, k, v))
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    i = jnp.arange(s.shape[-1])
+    seen = (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _window_cases():
+    for T in (128, 384, 1000):
+        for window in (1, 100, 128, 300, T, 2 * T):
+            for D, Dv, group in ((128, 128, 2), (256, 128, 1)):
+                yield pytest.param(T, window, D, Dv, group,
+                                   id=f"T{T}-w{window}-D{D}x{Dv}-g{group}")
+
+
+@pytest.mark.parametrize("T,window,D,Dv,group", list(_window_cases()))
+def test_forward_entry_with_a_window_matches_the_masked_softmax(
+        T, window, D, Dv, group):
+    """`flash_attention_forward(window=)` against a dense masked
+    softmax, in blocks of 128 so that a query block of a long row walks
+    several key blocks and starts past those behind its band: windows
+    of one position, inside a block, a block, across blocks, the whole
+    row and more; ragged lengths; Command A+'s heads (128 / 128, two
+    query heads a key head, read where they lie) and Kimi's widths
+    (256 / 128); a length that is no multiple of a tile.  float32
+    inputs: the tolerance is the forward's float32 one, which a
+    bfloat16 product (2**-8) fails by an order of magnitude."""
+    lengths = (T, max(1, (5 * T) // 8))
+    keys = jax.random.split(jax.random.key(T + window), 3)
+    q, k, v = (jax.random.normal(kk, (2, h, T, d), F32)
+               for kk, h, d in zip(keys, (2 * group, 2, 2), (D, D, Dv)))
+    out = jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+        q, k, v, n, scale=D ** -0.5, window=window, block_q=128,
+        block_k=128))(q, k, v, jnp.asarray(lengths, jnp.int32))
+    assert out.shape == (2, 2 * group, T, Dv)
+    ref = np.asarray(_band_ref(q, k, v, D ** -0.5, window))
+    out = np.asarray(out)
+    rtol, atol = TOL[F32]["fwd"]
+    for b, n in enumerate(lengths):
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=rtol,
+                                   atol=atol, err_msg=f"row of {n}")
+        assert not out[b, :, n:].any(), f"row of {n}"
+
+
+def test_a_window_walks_only_the_blocks_of_its_band():
+    """What the band saves is not a mask over work done anyway: keys
+    wholly behind a query block's band are never read.  NaNs planted in
+    every key and value block that no query of a row's last query block
+    can see (and zero queries elsewhere would hide nothing: the whole
+    row is live) leave that block's output finite and equal to the
+    oracle's; with no window the same NaNs reach every query."""
+    T, w, D = 1024, 200, 128
+    keys = jax.random.split(jax.random.key(7), 3)
+    q, k, v = (jax.random.normal(kk, (1, 1, T, D), F32) for kk in keys)
+    ref = np.asarray(_band_ref(q, k, v, D ** -0.5, w))
+    # the last query block (896..1023) sees keys 697..1023: blocks 5-7
+    hole = jnp.arange(T)[None, None, :, None] < 5 * 128
+    kn, vn = (jnp.where(hole, jnp.nan, x) for x in (k, v))
+    fn = jax.jit(lambda q, k, v, window: pa.flash_attention_forward(
+        q, k, v, scale=D ** -0.5, window=window, block_q=128, block_k=128),
+        static_argnums=3)
+    out = np.asarray(fn(q, kn, vn, w))
+    rtol, atol = TOL[F32]["fwd"]
+    np.testing.assert_allclose(out[0, 0, 896:], ref[0, 0, 896:], rtol=rtol,
+                               atol=atol)
+    assert np.isnan(np.asarray(fn(q, kn, vn, None))[0, 0, 896:]).all()
+
+
+def test_no_window_builds_the_kernel_it_built_before():
+    """`flash_attention_forward(window=None)` with a key head a query
+    head is Kimi-K2's and Ouro's prefill call: the kernel has the
+    operands and the grid it had before the window existed (lengths, q,
+    k, v; heads x query blocks), its body is told of no window and no
+    group, and its jaxpr is the one a window at least as long as the
+    row walks less of, never more: no equation is added for a band that
+    is not there.  With a window the operands and the grid stay the
+    same: the band is in the walk's bounds, not in a new operand or
+    grid axis.  (The compiled text of both families' tiny prefill was
+    compared with the parent's once, CHANGES.md PR 39.)"""
+    H, T, D = 4, 512, 128
+    x = jax.ShapeDtypeStruct((1, H, T, D), BF16)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32)
+
+    def call(**kw):
+        found, = _pallas_calls(jax.make_jaxpr(
+            lambda q, k, v, n: pa.flash_attention_forward(
+                q, k, v, n, scale=1.0, block_q=128, block_k=128, **kw))(
+                    x, x, x, n).jaxpr, [])
+        return found
+
+    plain, banded = call(), call(window=200)
+    for c in (plain, banded):
+        mapping = c.params["grid_mapping"]
+        assert mapping.grid == (H, T // 128)
+        assert mapping.num_index_operands == 1
+        assert [v.aval.shape for v in c.invars] == [(1,)] + [(H, T, D)] * 3
+    assert str(plain.params["jaxpr"]) == str(call(window=None).params["jaxpr"])
+    assert len(_eqns(plain.params["jaxpr"], None, [])) < len(
+        _eqns(banded.params["jaxpr"], None, []))
+    # a key head for every two query heads: half the rows of k and v,
+    # still no further operand
+    half = jax.ShapeDtypeStruct((1, H // 2, T, D), BF16)
+    grouped, = _pallas_calls(jax.make_jaxpr(
+        lambda q, k, v, n: pa.flash_attention_forward(
+            q, k, v, n, scale=1.0, window=200))(x, half, half, n).jaxpr, [])
+    assert [v.aval.shape for v in grouped.invars] == [
+        (1,), (H, T, D), (H // 2, T, D), (H // 2, T, D)]
+
+
 def test_what_the_entries_refuse():
     q, k, v = _wide_inputs(32, 16, F32, T=128, rows=1)
     with pytest.raises(ValueError, match="one width"):
@@ -182,6 +301,11 @@ def test_what_the_entries_refuse():
     # a head's width cannot be told from the zeros it arrives padded with
     with pytest.raises(TypeError, match="scale"):
         pa.flash_attention_forward(q, k, v)
+    with pytest.raises(ValueError, match="own position"):
+        pa.flash_attention_forward(q, k, v, scale=1.0, window=0)
+    with pytest.raises(ValueError, match="do not divide"):
+        pa.flash_attention_forward(jnp.concatenate([q, q[:, :1]], axis=1),
+                                   k, v, scale=1.0)
 
 
 def _pallas_calls(jaxpr, found):
@@ -234,10 +358,10 @@ def test_the_forward_entry_is_a_grid_over_query_blocks():
 
 
 def _eqns(jaxpr, primitive, found):
-    """Every equation of that primitive in a jaxpr and the jaxprs inside
-    it."""
+    """Every equation of that primitive (None: of any) in a jaxpr and
+    the jaxprs inside it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == primitive:
+        if primitive is None or eqn.primitive.name == primitive:
             found.append(eqn)
         for val in eqn.params.values():
             for sub in (val if isinstance(val, (list, tuple)) else [val]):
