@@ -524,12 +524,24 @@ def require_fed_on_device(tag, engine, prompts, steps, served, timing):
 def require_dead_rows_change_nothing(tag, engine, prompts, steps, served):
     """A group of unequal answers, rows that want no more token between
     rows that do: the decode steps are handed the mask (fewer live
-    row-steps than row-steps), a finished row reads one cache block and
-    goes to no expert, and every request's tokens are those it got in
-    ``served``, the same group with every answer ``steps`` long."""
+    row-steps than row-steps), a finished row reads one cache block,
+    goes to no expert and writes nothing into the cache (every row
+    write the kernel made, it made knowing the live rows; the stacks of
+    the rows that are not live come back bit for bit), and every
+    request's tokens are those it got in ``served``, the same group
+    with every answer ``steps`` long."""
+    from mxnet_tpu.test_utils import serving_dead_rows_keep_their_cache
+
     wants = [steps if i % 2 else max(1, steps // 3)
              for i in range(len(prompts))]
     outs, timing = engine.serve_group(prompts, wants)
+    told, made = (timing["decode_cache_write_live_share"],
+                  timing["decode_cache_write_kernel_share"])
+    require(told == made,
+            f"{tag}: decode_cache_write_live_share {told} beside a kernel "
+            f"share of {made}")
+    serving_dead_rows_keep_their_cache(engine, prompts,
+                                       [i % 2 == 1 for i in range(len(prompts))])
     for i, (got, want) in enumerate(zip(outs, served)):
         require(np.array_equal(got, want[:wants[i]]),
                 f"{tag}: prompt {i} with answers of {wants}: {got} != "
@@ -540,7 +552,8 @@ def require_dead_rows_change_nothing(tag, engine, prompts, steps, served):
             == timing["bucket"][0] * (steps - 1),
             f"{tag}: {live} live of {all_} row-steps for answers of {wants}")
     say(f"[{tag}] answers of {wants}: {live} of {all_} row-steps live, "
-        f"tokens equal the group of equal answers', "
+        f"decode_cache_write_live_share {told}, the other rows' stacks "
+        f"untouched, tokens equal the group of equal answers', "
         f"decode_attn_window_read_pct "
         f"{timing['decode_attn_window_read_pct']:.2f}")
 

@@ -424,6 +424,7 @@ class Cohere2MoeProgram:
         pins = self._pins      # `init_cache` read them off a real cache
         B, S = toks.shape
         decode = S == 1
+        given = live    # as handed: None from the prefill, whose write takes none
         if live is None:
             live = jnp.ones((B,), bool)
         R, D, n_held = z.window, z.head_dim, z.experts_held[1]
@@ -441,10 +442,11 @@ class Cohere2MoeProgram:
             with jax.named_scope("serve.cache_write"):
                 if kind == "full":
                     out = cache_write.write_rows((fk, fv), new, l, pos,
-                                                 tally=tally, row=row)
+                                                 tally=tally, row=row,
+                                                 live=given)
                 elif decode:
                     out = cache_write.write_rows((wk, wv), new, l, pos % R,
-                                                 tally=tally)
+                                                 tally=tally, live=given)
                 else:
                     out = cache_write.write_ring((wk, wv), new, l, held,
                                                  tally=tally, row=row)

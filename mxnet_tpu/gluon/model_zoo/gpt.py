@@ -484,7 +484,8 @@ class GPTDecoderProgram:
                 # re-lays the whole cache around the loop to make row
                 # writes cheaper
                 ck, cv = (keep_layout(c) for c in cache_write.write_rows(
-                    (ck, cv), (kh, vh), l, pos, mesh=mesh, tally=tally))
+                    (ck, cv), (kh, vh), l, pos, mesh=mesh, tally=tally,
+                    live=live))
             with jax.named_scope("serve.attn"):
                 # row b at block offset s may see cache slots
                 # <= pos[b] + s (stale pad garbage beyond is invisible

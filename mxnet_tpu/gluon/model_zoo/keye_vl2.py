@@ -358,6 +358,7 @@ class KeyeVL2Program:
         pins = self._pins      # `init_cache` read them off a real cache
         B, S = toks.shape
         decode = S == 1
+        given = live    # as handed: None from the prefill, whose write takes none
         if live is None:
             live = jnp.ones((B,), bool)
         # a decode step's positions a row, itself included
@@ -380,7 +381,7 @@ class KeyeVL2Program:
                 out = cache_write.write_rows(
                     stacks, [k.swapaxes(2, 3), v.swapaxes(2, 3),
                              ki.swapaxes(1, 2)[:, None]], l, pos,
-                    tally=tally)
+                    tally=tally, live=given)
                 return [c if p is None else with_layout_constraint(c, p)
                         for c, p in zip(out, pins)]
 

@@ -506,6 +506,7 @@ class KimiK2Program:
         pin = self._pin        # `init_cache` read it off a real cache
         B, S = toks.shape
         decode = S == 1
+        given = live    # as handed: None from the prefill, whose write takes none
         if live is None:
             live = jnp.ones((B,), bool)
         # a decode step's positions a row, itself included
@@ -520,7 +521,7 @@ class KimiK2Program:
             with jax.named_scope("serve.cache_write"):
                 stack, = cache_write.write_rows(
                     [stack], [latent.swapaxes(1, 2)[:, None]], l, at,
-                    tally=tally, row=row)
+                    tally=tally, row=row, live=given)
                 return stack if pin is None else \
                     with_layout_constraint(stack, pin)
 

@@ -446,6 +446,7 @@ class MiMoV2Program:
         pins = self._pins      # `init_cache` read them off a real cache
         B, S = toks.shape
         decode = S == 1
+        given = live    # as handed: None from the prefill, whose write takes none
         if live is None:
             live = jnp.ones((B,), bool)
         R = z.window
@@ -463,7 +464,7 @@ class MiMoV2Program:
             [l, b, :, :, starts[b]:], each kept in its layout."""
             out = cache_write.write_rows(
                 stacks, [a.swapaxes(2, 3) for a in new], l, starts,
-                tally=tally)
+                tally=tally, live=given)
             return [c if p is None else with_layout_constraint(c, p)
                     for c, p in zip(out, pin)]
 

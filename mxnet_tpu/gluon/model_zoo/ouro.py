@@ -367,6 +367,7 @@ class OuroProgram:
         pin = self._pin        # `init_cache` read it off a real cache
         B, S = toks.shape
         decode = S == 1
+        given = live    # as handed: None from the prefill, whose write takes none
         if live is None:
             live = jnp.ones((B,), bool)
         # a decode step's positions a row, itself included
@@ -388,7 +389,7 @@ class OuroProgram:
                     c if pin is None else with_layout_constraint(c, pin)
                     for c in cache_write.write_rows(
                         stacks, (k.swapaxes(2, 3), v.swapaxes(2, 3)), slot,
-                        pos, tally=tally))
+                        pos, tally=tally, live=given))
             if decode:
                 with jax.named_scope("serve.attn"):
                     q = (q[:, :, 0] * z.head_dim ** -0.5).astype(k.dtype)

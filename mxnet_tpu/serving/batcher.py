@@ -280,6 +280,8 @@ class ContinuousBatcher:
                     "decode_row_steps_live"),
                 decode_cache_write_kernel_share=timings.get(
                     "decode_cache_write_kernel_share"),
+                decode_cache_write_live_share=timings.get(
+                    "decode_cache_write_live_share"),
                 decode_attn_kernel_share=timings.get(
                     "decode_attn_kernel_share"),
                 decode_attn_window_read_pct=timings.get(

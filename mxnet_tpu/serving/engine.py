@@ -36,8 +36,9 @@ request path.  Four properties, all pinned by tests/test_serving.py:
   fetched only for a request with a ``temperature``, for which the
   host draws the token.  How many tokens each row still wants is
   carried the same way, so a decode step knows which rows are live: a
-  row past its answer, or a pad row, attends to nothing and goes to no
-  expert (docs/serving.md, "The decoder program").
+  row past its answer, or a pad row, attends to nothing, writes
+  nothing into the cache and goes to no expert (docs/serving.md, "The
+  decoder program").
 - **Hot reload without recompile.**  Weights are *arguments* to the
   compiled programs, not closed-over constants: swapping in new
   weights (from a live model or an AsyncCheckpointer state dict) is an
@@ -59,6 +60,7 @@ from one chip refuses a mesh.
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import re
 import threading
@@ -480,6 +482,13 @@ class ServingEngine:
             ints = [(B,), (B,), (B, S)] + [(B,)] * (program == "decode")
             compiled = jfn.lower(w_avals, c_avals, *(
                 self._int_aval(shape) for shape in ints)).compile()
+            # a trace and its lowering leave the cyclic collector
+            # hundreds of thousands of objects to count, and its next
+            # full pass due soon: 46-50 ms with the interpreter stopped
+            # on a v5e host, which among the first requests outlasts a
+            # batcher's delay and splits a closed loop's round for good
+            # (PERF.md, PR 40).  Made here, beside seconds of compile.
+            gc.collect()
         with _LOCK:
             _COMPILE_COUNT += 1
         self._programs[(B, S)] = compiled
@@ -690,12 +699,16 @@ class ServingEngine:
             "token_t_us": token_t_us,
         }
         # of the decode program's cache row writes, the share that the
-        # in-place kernel made (ops/cache_write.py counted them when
-        # the program was traced)
+        # in-place kernel made, and the share it made knowing which rows
+        # still want a token, so that the others moved no block
+        # (ops/cache_write.py counted them when the program was traced)
         writes = getattr(self._program, "cache_writes", {}).get(1)
         if writes:
+            made = writes["kernel"] + writes["rows"]
             timings["decode_cache_write_kernel_share"] = \
-                writes["kernel"] / sum(writes.values())
+                writes["kernel"] / made
+            timings["decode_cache_write_live_share"] = \
+                writes["kernel_live"] / made
         # of its attention calls over the cache, the share that read
         # each row to its length (ops/cache_attention.py's kernel), and
         # what share of the rows' windows their blocks were

@@ -56,6 +56,20 @@ def cpu_child_env(devices=1, **extra):
     return env
 
 
+def _padded_group(engine, prompts):
+    """``prompts`` in the buckets `serve_group` would pick: ``(B, each
+    row's length (B,), the padded block (B, S))``."""
+    B = engine._pick_bucket(engine.batch_buckets, len(prompts), "group size")
+    lens = np.ones(B, np.int32)         # pad rows hold one dummy token
+    lens[:len(prompts)] = [len(p) for p in prompts]
+    S = engine._pick_bucket(engine.prefill_buckets, int(lens.max()),
+                            "prompt length")
+    toks = np.zeros((B, S), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    return B, lens, toks
+
+
 def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
     """One group through a `ServingEngine`'s compiled programs with the
     host in every step, the reference for `serve_group`'s device-fed
@@ -68,14 +82,7 @@ def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
     from .gluon.model_zoo.gpt import _sample
 
     n = len(prompts)
-    B = engine._pick_bucket(engine.batch_buckets, n, "group size")
-    lens = np.ones(B, np.int32)         # pad rows hold one dummy token
-    lens[:n] = [len(p) for p in prompts]
-    S = engine._pick_bucket(engine.prefill_buckets, int(lens.max()),
-                            "prompt length")
-    toks = np.zeros((B, S), np.int32)
-    for i, p in enumerate(prompts):
-        toks[i, :len(p)] = p
+    B, lens, toks = _padded_group(engine, prompts)
     pos, last = np.zeros(B, np.int32), lens - 1
     cache, out, logits = engine.init_cache(B), [], []
     for j in range(steps):
@@ -129,6 +136,44 @@ def serving_unequal_answers(engine, prompts, wants):
     assert timings["decode_row_steps"] == timings["bucket"][0] * (steps - 1)
     assert timings["decode_row_steps_live"] == len(live)
     return timings, live
+
+
+def serving_dead_rows_keep_their_cache(engine, prompts, live):
+    """A prefill of ``prompts`` and one decode step handed ``live`` (a
+    bool a prompt: does the row still want a token), through the
+    engine's own programs: in every stack of the cache (its arrays
+    ``(L, B, K, D, W)``) a row that is not live, a pad row among them,
+    comes back bit for bit what the prefill left, in every layer, and
+    the live rows' come back changed (AssertionError otherwise)."""
+    import jax.numpy as jnp
+
+    B, lens, toks = _padded_group(engine, prompts)
+    zero = np.zeros(B, np.int32)
+    cache, _, ids, pos = engine._call(B, toks.shape[1], engine.init_cache(B),
+                                      zero, lens - 1, toks)
+    left = np.zeros(B, np.int32)
+    left[:len(prompts)] = np.where(live, 2, 0)
+    rows = {kind: np.flatnonzero((left > 0) == (kind == "live"))
+            for kind in ("live", "dead")}
+
+    def stacks(cache):
+        # gathered into buffers of their own: the step below is given
+        # the cache's
+        return {kind: [c[:, at] for c in cache if c.ndim == 5]
+                for kind, at in rows.items()}
+
+    before = stacks(cache)
+    cache, *_ = engine._call(B, 1, cache, pos, zero, ids, left)
+    after = stacks(cache)
+    assert before["dead"], "a cache with no stack (L, B, K, D, W)"
+    for i, (a, b) in enumerate(zip(before["dead"], after["dead"])):
+        assert bool(jnp.array_equal(a, b)), \
+            f"stack {i}: a row of {rows['dead']} that wants no token " \
+            f"was written (live {list(live)})"
+    if len(rows["live"]):
+        for i, (a, b) in enumerate(zip(before["live"], after["live"])):
+            assert not bool(jnp.array_equal(a, b)), \
+                f"stack {i}: the live rows {rows['live']} wrote nothing"
 
 
 def jaxpr_loops(jaxpr):

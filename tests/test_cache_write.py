@@ -78,6 +78,111 @@ def test_kernel_writes_what_the_rows_path_writes(name, dtype, layer):
                                           _bits(c)[~touched])
 
 
+# which of four rows still want a token
+LIVE = {
+    "all": [True, True, True, True],
+    "none": [False, False, False, False],
+    "leading_dead": [False, False, True, True],
+    "trailing_dead": [True, True, False, False],
+    "alternating": [True, False, True, False],
+    "one_live": [False, False, True, False],
+}
+
+
+@pytest.mark.parametrize("live", list(LIVE))
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_a_row_that_is_not_live_writes_nothing(monkeypatch, name, live):
+    """A decode step's write told which rows still want a token: the
+    kernel's stacks equal the rows path's bit for bit, as the CPU
+    builds that path (a condition a row) and as a TPU does (a select
+    between the new value and the position as it was); the stacks of a
+    row that is not live are the input's in every layer, whatever its
+    new values hold (NaN here) and wherever it stands; a live row's are
+    what the write told of no row gives it."""
+    stacks, news, W = _case(name, jnp.bfloat16, seed=2)
+    keep = np.asarray(LIVE[live])
+    news = tuple(jnp.where(keep[:, None, None, None], n, jnp.nan)
+                 for n in news)
+    pos = jnp.asarray([W + 5, 127, 128 % W, W - 1], jnp.int32)
+    starts = pos % W if name == "mimo_ring" else pos
+    l = stacks[0].shape[0] - 1
+    got = jax.jit(lambda s, n, p, v: cache_write._write_kernel(
+        s, n, l, p, v, interpret=True))(stacks, news, starts,
+                                        jnp.asarray(keep))
+
+    def by_rows():
+        return tuple(cache_write._write_by_rows(c, n, l, starts, 0,
+                                                jnp.asarray(keep))
+                     for c, n in zip(stacks, news))
+
+    want = by_rows()
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    selected = by_rows()
+    every = cache_write._write_kernel(stacks, news, l, starts,
+                                      interpret=True)
+    for g, w, t, c, e in zip(got, want, selected, stacks, every):
+        assert g.dtype == c.dtype and g.shape == c.shape
+        g, w, t, c, e = _bits(g), _bits(w), _bits(t), _bits(c), _bits(e)
+        assert not np.isnan(g).any()
+        np.testing.assert_array_equal(g, w)
+        np.testing.assert_array_equal(t, w)
+        np.testing.assert_array_equal(g[:, ~keep], c[:, ~keep])
+        np.testing.assert_array_equal(g[:, keep], e[:, keep])
+
+
+@pytest.mark.parametrize("path", ["kernel", "rows"])
+@pytest.mark.parametrize("S", [2, 8])
+def test_live_is_a_decode_steps(monkeypatch, path, S):
+    """A prefill has no row to skip: ``live`` with a block of more than
+    one position is refused, on either path."""
+    stacks, news, W = _case("narrow", jnp.float32)
+    news = tuple(jnp.repeat(n, S, axis=-1) for n in news)
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: path == "kernel")
+    with pytest.raises(ValueError, match="decode step"):
+        cache_write.write_rows(stacks, news, 0, jnp.zeros(4, jnp.int32),
+                               live=jnp.ones(4, bool))
+
+
+def _pallas_call(fn, *args):
+    eqn, = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    return eqn
+
+
+@pytest.mark.parametrize("name", ["keye", "gpt", "kimi_latent"])
+def test_told_of_no_row_it_is_the_same_kernel(name):
+    """One kernel whether the call is told the live rows or not: one
+    invocation a call, the layer and the positions its two prefetched
+    scalars, the stacks whole and aliased to their outputs, the new
+    rows as the program leaves them, and the same body.  What differs
+    is the positions handed it: -1 where a row is not live."""
+    stacks, news, W = _case(name, jnp.bfloat16)
+    pos = jnp.asarray([5, 130, 0, 255], jnp.int32)
+    B, n = pos.shape[0], len(stacks)
+
+    def call(*live):
+        return _pallas_call(lambda s, n, p: cache_write._write_kernel(
+            s, n, 1, p, *live, interpret=True), stacks, news, pos)
+
+    plain, told = call(), call(jnp.asarray([True, False, True, False]))
+    for eqn in (plain, told):
+        mapping = eqn.params["grid_mapping"]
+        assert mapping.grid == (1,) and mapping.num_index_operands == 2
+        assert [tuple(v.aval.shape) for v in eqn.invars] == \
+            [(1,), (B,)] + [tuple(c.shape) for c in stacks] + \
+            [tuple(x.shape[:3]) for x in news]
+        assert dict(eqn.params["input_output_aliases"]) == {
+            2 + i: i for i in range(n)}
+    assert str(plain.params["jaxpr"]) == str(told.params["jaxpr"])
+    assert str(plain.params["grid_mapping"]) == \
+        str(told.params["grid_mapping"])
+    every = cache_write._write_kernel(stacks, news, 1, pos, interpret=True)
+    ones = cache_write._write_kernel(stacks, news, 1, pos,
+                                     jnp.ones(B, bool), interpret=True)
+    for e, o in zip(every, ones):
+        np.testing.assert_array_equal(_bits(e), _bits(o))
+
+
 def test_a_traced_layer_index_writes_that_layer():
     """GPT's layer loop hands the kernel its scan index."""
     stacks, news, W = _case("gpt", jnp.bfloat16, seed=1)
@@ -97,33 +202,45 @@ def test_a_traced_layer_index_writes_that_layer():
         np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
-@pytest.mark.parametrize("S,mesh,tpu,path", [
-    (1, None, True, "kernel"), (8, None, True, "rows"),
-    (1, "a mesh", True, "rows"), (1, None, False, "rows")])
+@pytest.mark.parametrize("S,mesh,tpu,live,path", [
+    (1, None, True, None, "kernel"), (8, None, True, None, "rows"),
+    (1, "a mesh", True, None, "rows"), (1, None, False, None, "rows"),
+    (1, None, True, "alternating", "kernel"),
+    (1, "a mesh", True, "alternating", "rows"),
+    (1, None, False, "one_live", "rows")])
 def test_write_rows_picks_its_path_on_what_it_sees(monkeypatch, S, mesh,
-                                                   tpu, path):
+                                                   tpu, live, path):
     """The kernel where the block is one position, the platform a TPU
     and no mesh is given; one write a row everywhere else.  The tally
-    is told which, once a row and a stack."""
+    is told which, once a row and a stack, and which of the kernel's
+    were handed ``live``; the kernel is handed it as it came."""
     stacks, news, W = _case("narrow", jnp.float32)
     news = tuple(jnp.repeat(n, S, axis=-1) for n in news)
     pos = jnp.asarray([0, 3, 7, 2], jnp.int32)
+    keep = np.asarray(LIVE[live or "all"])
     monkeypatch.setattr(cache_write, "_on_tpu", lambda: tpu)
     took = []
     monkeypatch.setattr(
         cache_write, "_write_kernel",
-        lambda s, n, l, p: took.append("kernel") or s)
+        lambda s, n, l, p, live: took.append(live) or s)
     tally = collections.Counter()
-    out = cache_write.write_rows(stacks, news, 1, pos, mesh=mesh,
-                                 tally=tally)
+    out = cache_write.write_rows(
+        stacks, news, 1, pos, mesh=mesh, tally=tally,
+        live=None if live is None else jnp.asarray(keep))
     assert len(out) == len(stacks)
-    assert (took == ["kernel"]) == (path == "kernel")
-    assert dict(tally) == {path: 4 * len(stacks)}
+    assert len(took) == (path == "kernel")
+    want = {path: 4 * len(stacks)}
+    if path == "kernel":
+        assert (took[0] is None) == (live is None)
+        if live is not None:
+            want["kernel_live"] = 4 * len(stacks)
+    assert dict(tally) == want
     if path == "rows":
         for g, c, n in zip(out, stacks, news):
             for b, p in enumerate(np.asarray(pos)):
                 np.testing.assert_array_equal(
-                    _bits(g)[1, b, :, :, p:p + S], _bits(n)[b])
+                    _bits(g)[1, b, :, :, p:p + S],
+                    _bits(n)[b] if keep[b] else _bits(c)[1, b, :, :, p:p + S])
             assert (_bits(g)[0] == _bits(c)[0]).all()
 
 
@@ -168,18 +285,77 @@ def test_a_row_offset_keeps_a_decode_write_off_the_kernel(monkeypatch):
 # -- compiled for the chip, without the chip -----------------------------------
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip: Mosaic and XLA:TPU compile for it here,
-    nothing runs (only this file's worker loads the TPU's library)."""
+def v5e():
+    """Four described v5e chips: Mosaic and XLA:TPU compile for them
+    here, nothing runs (only this file's worker loads the TPU's
+    library)."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:      # no libtpu here, or it is held elsewhere
         pytest.skip(f"no v5e topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e[0])
+
+
+@pytest.mark.parametrize("told", [False, True], ids=["every_row", "live"])
+def test_the_rows_path_compiles_for_four_v5es_in_place(v5e, monkeypatch,
+                                                       told):
+    """A `tp` engine's decode write (GPT-2 medium's stacks, their heads
+    over four chips, the layout pinned shard by shard as
+    `gluon/model_zoo/gpt.py` pins it): the rows path, told of the live
+    rows and not, compiles for the chips with the stacks aliased to
+    their outputs and no temporary of a layer's size, so no chip copies
+    its shard of a stack for a row that is not live."""
+    from jax.experimental.layout import with_layout_constraint
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu import serving
+    from mxnet_tpu.parallel.sharding import serving_cache_sharding
+
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    L, B, K, D, W = 24, 16, 16, 64, 1024
+    mesh = Mesh(np.array(v5e).reshape(4), ("tp",))
+    heads = serving_cache_sharding(mesh)
+
+    def sds(shape, dtype=jnp.bfloat16, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    stack = sds((L, B, K, D, W), spec=heads.spec)
+    pin = jax.jit(lambda x: x).lower(stack).compile(
+        ).input_formats[0][0].layout
+    keep_layout = jax.shard_map(
+        lambda c: with_layout_constraint(c, pin), mesh=mesh,
+        in_specs=heads.spec, out_specs=heads.spec)
+    tally = collections.Counter()
+
+    def step(stacks, news, pos, live):
+        def body(c, l):
+            out = cache_write.write_rows(
+                c, news, l, pos, mesh=mesh, tally=tally,
+                live=live if told else None)
+            return tuple(keep_layout(o) for o in out), None
+        return jax.lax.scan(body, stacks, jnp.arange(L, dtype=jnp.int32))[0]
+
+    new = sds((B, K, D, 1), spec=P(None, "tp"))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        (stack, stack), (new, new), sds((B,), jnp.int32),
+        sds((B,), jnp.bool_)).compile()
+    assert dict(tally) == {"rows": 2 * B}
+    text = compiled.as_text()
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
+    layer = B * K // 4 * D * W * 2      # a chip's shard of one layer
+    assert serving.whole_layer_ops(text, layer) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer
 
 
 @pytest.mark.parametrize("name,L,B,Ks,Ds,W", [
@@ -187,26 +363,31 @@ def one_chip():
     ("mimo_full", 2, 64, (4, 4), (192, 128), 2048),
     ("mimo_ring", 5, 64, (8, 8), (192, 128), 128),
     ("keye_vl2", 6, 16, (4, 4, 1), (128, 128, 64), 16384),
-    ("kimi_k2", 5, 8, (1,), (576,), 16384)])
-def test_kernel_compiles_for_a_v5e_in_place(one_chip, name, L, B, Ks, Ds, W):
+    ("kimi_k2", 5, 8, (1,), (576,), 16384),
+    ("ouro", 6, 8, (16, 16), (128, 128), 512),
+    ("cmda_ring", 3, 8, (8, 8), (128, 128), 4096)])
+@pytest.mark.parametrize("told", [False, True], ids=["every_row", "live"])
+def test_kernel_compiles_for_a_v5e_in_place(one_chip, told, name, L, B, Ks,
+                                            Ds, W):
     """At the cells' real widths Mosaic takes the kernel (interpret mode
-    cannot say), the stacks are aliased to their outputs, and the
-    compiled program copies no layer of them: it holds no temporary of
-    a stack's size."""
+    cannot say), told of the live rows and not, the stacks are aliased
+    to their outputs, and the compiled program copies no layer of them:
+    it holds no temporary of a stack's size."""
     from mxnet_tpu import serving
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def step(stacks, news, pos):
+    def step(stacks, news, pos, live):
         def body(c, l):
-            return cache_write._write_kernel(c, news, l, pos), None
+            return cache_write._write_kernel(
+                c, news, l, pos, live if told else None), None
         return jax.lax.scan(body, stacks, jnp.arange(L, dtype=jnp.int32))[0]
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         tuple(sds((L, B, K, D, W)) for K, D in zip(Ks, Ds)),
         tuple(sds((B, K, D, 1)) for K, D in zip(Ks, Ds)),
-        sds((B,), jnp.int32)).compile()
+        sds((B,), jnp.int32), sds((B,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
@@ -519,10 +700,12 @@ def test_ouros_programs_compile_for_a_v5e_at_the_published_widths(
         ).input_formats[0][0].layout
     weights = tuple(sds(z.shape_of(n)) for n in net._names)
     assert weights[net._names.index("qkv_weight")].shape[0] == 48
+    # the decode step as the engine compiles it: handed the live rows
+    live = (sds((B,), jnp.bool_),) if S == 1 else ()
     compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
         weights, (stack, stack, sds((2, 2 + z.loop_steps), jnp.uint32)),
         sds((B,), jnp.int32), sds((B,), jnp.int32),
-        sds((B, S), jnp.int32)).compile()
+        sds((B, S), jnp.int32), *live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
@@ -535,7 +718,8 @@ def test_ouros_programs_compile_for_a_v5e_at_the_published_widths(
     assert mem.alias_size_in_bytes >= 2 * stack_bytes
     if S == 1:
         assert mem.temp_size_in_bytes < slot_bytes
-        assert dict(program.cache_writes[1]) == {"kernel": 2 * B}
+        assert dict(program.cache_writes[1]) == {"kernel": 2 * B,
+                                                 "kernel_live": 2 * B}
         assert dict(program.cache_reads[1]) == {("kernel", W, 128): 1}
     else:
         assert mem.temp_size_in_bytes < stack_bytes // 8
@@ -585,11 +769,13 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
         ).input_formats[0][0].layout for c in (full, full, ring, ring)]
     weights = tuple(sds(shape) for _, shape in z.leaves())
     assert sum(int(np.prod(w.shape)) for w in weights) == 3_122_679_808
+    # the decode step as the engine compiles it: handed the live rows
+    live = (sds((B,), jnp.bool_),) if S == 1 else ()
     compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
         weights, (full, full, ring, ring, sds((4, 2, 11), jnp.int32),
                   sds((4, 2, 2), jnp.uint32)),
         sds((B,), jnp.int32), sds((B,), jnp.int32),
-        sds((B, S), jnp.int32)).compile()
+        sds((B, S), jnp.int32), *live).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
@@ -599,7 +785,8 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
     mem = compiled.memory_analysis()
     if S == 1:
         assert mem.temp_size_in_bytes < ring_layer
-        assert dict(program.cache_writes[1]) == {"kernel": 4 * 2 * B}
+        assert dict(program.cache_writes[1]) == {"kernel": 4 * 2 * B,
+                                                 "kernel_live": 4 * 2 * B}
         assert dict(program.cache_reads[1]) == {
             ("kernel", 4096, 256): 3, ("kernel", W, 512): 1}
     else:

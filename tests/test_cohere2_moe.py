@@ -25,7 +25,8 @@ from mxnet_tpu.gluon.model_zoo import _decoder_ops as ops   # noqa: E402
 from mxnet_tpu.gluon.model_zoo import cohere2_moe as cm     # noqa: E402
 from mxnet_tpu.ops import cache_write                       # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
+    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import cohere2_moe as ref         # noqa: E402
@@ -187,7 +188,8 @@ def test_serving_equals_the_reference_at_every_served_position(served):
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     """Unequal answers in a group: a row that is done attends to
-    nothing and goes to no routed expert, every request's tokens are
+    nothing, goes to no routed expert and writes nothing into the
+    rings or the full stacks, every request's tokens are
     what it gets alone and in a group of equal answers, and the
     counters are the live row-steps'."""
     _, _, _, eng = served
@@ -199,6 +201,9 @@ def test_a_row_that_wants_no_token_changes_nothing(served, wants):
         sum(lens[i] + j + 1 for i, j in live)
     assert timings["attn_window_pairs_decode"] == \
         3 * sum(min(lens[i] + j + 1, WINDOW) for i, j in live)
+    # and in every layer of every stack a finished row's cache rows are
+    # what they were
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
 
 
 # -- (c) the share ties to the model -------------------------------------------

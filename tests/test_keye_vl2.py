@@ -21,7 +21,8 @@ from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import keye_vl2              # noqa: E402
 from mxnet_tpu.ops import indexed_attention, moe            # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
+    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import keye_vl2 as ref            # noqa: E402
@@ -449,9 +450,10 @@ def test_a_greedy_group_is_fed_on_the_device(served, steps):
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     """The decode step is handed which rows still want a token: the
-    others attend to nothing and go to no expert, every request's
-    tokens are what it gets alone and in a group of equal answers, and
-    the counters are the live row-steps': the keys attention could read
+    others attend to nothing, go to no expert and write nothing into
+    the three stacks, every request's tokens are what it gets alone and
+    in a group of equal answers, and the counters are the live
+    row-steps': the keys attention could read
     and read, 3 layers, and 2 pairs a token a layer (all 8 experts are
     held)."""
     _, _, _, eng = served
@@ -468,6 +470,9 @@ def test_a_row_that_wants_no_token_changes_nothing(served, wants):
         <= timings["attn_keys_selected_decode"] \
         <= timings["attn_keys_live_decode"]
     assert timings["moe_pairs_decode"] == len(live) * 2 * 3
+    # and in every layer of every stack a finished row's cache rows are
+    # what they were
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

@@ -23,7 +23,8 @@ from mxnet_tpu.gluon.model_zoo import _decoder_ops as ops   # noqa: E402
 from mxnet_tpu.gluon.model_zoo import kimi_k2               # noqa: E402
 from mxnet_tpu.ops import cache_attention, moe              # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
+    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import kimi_k2 as ref             # noqa: E402
@@ -498,7 +499,8 @@ def test_a_greedy_group_is_fed_on_the_device(served, steps):
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     """The decode step is handed which rows still want a token: the
-    others attend to nothing and go to no routed expert, every
+    others attend to nothing, go to no routed expert and write nothing
+    into the latent stack, every
     request's tokens are what it gets alone and in a group of equal
     answers, and the counters are the live row-steps': the latent
     positions attended to, 3 layers, and 2 pairs a token in each of the
@@ -514,6 +516,9 @@ def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     assert timings["attn_latent_positions_decode"] == \
         3 * sum(len(prompts[i]) + j + 1 for i, j in live)
     assert timings["moe_pairs_decode"] == len(live) * 2 * 2
+    # and in every layer of every stack a finished row's cache rows are
+    # what they were
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

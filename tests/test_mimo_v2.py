@@ -20,7 +20,8 @@ from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import mimo_v2               # noqa: E402
 from mxnet_tpu.ops import moe                               # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
+    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import mimo_v2 as ref             # noqa: E402
@@ -291,7 +292,8 @@ def test_a_greedy_group_is_fed_on_the_device(served, steps):
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     """The decode step is handed which rows still want a token: the
-    others attend to nothing (full layers and rings alike) and go to no
+    others attend to nothing and write nothing (full layers and rings
+    alike) and go to no
     expert, every request's tokens are what it gets alone and in a
     group of equal answers, and the experts' counters are the live
     row-steps' (all 8 experts are held: 2 pairs a token in each of the
@@ -308,6 +310,9 @@ def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     assert timings["moe_rows_computed_decode"] \
         >= timings["moe_pairs_decode"]
     assert 1 <= timings["moe_experts_hit_per_step"] <= 8
+    # and in every layer of every stack a finished row's cache rows are
+    # what they were
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
 
 
 def test_a_sampled_group_draws_on_the_host(served):

@@ -24,7 +24,8 @@ from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.base import MXNetError                       # noqa: E402
 from mxnet_tpu.gluon.model_zoo import ouro                  # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_host_walk as _walk, serving_unequal_answers)
+    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
 from benchmark.references import ouro as ref                # noqa: E402
@@ -407,7 +408,8 @@ def test_a_coalesced_group_is_bitwise_the_requests_served_alone(served):
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(served, wants):
     """The decode step is handed which rows still want a token: the
-    others attend to nothing, every request's tokens are what it gets
+    others attend to nothing and write into none of the ``T L`` slots,
+    every request's tokens are what it gets
     alone and in a group of equal answers, and the counters are the
     live row-steps' (the positions read in all ``T L`` slots, the rows
     that left at each step) while the passes stay ``T`` a step."""
@@ -421,6 +423,9 @@ def test_a_row_that_wants_no_token_changes_nothing(served, wants):
         T * L * sum(len(prompts[i]) + j + 1 for i, j in live)
     assert timings["loop_exit_step_decode"] == [0] * (T - 1) + [len(live)]
     assert timings["loop_passes_decode"] == T * (max(wants) - 1)
+    # and in every layer of every stack a finished row's cache rows are
+    # what they were
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
 
 
 def test_a_mesh_is_refused_and_reload_goes_through_weights(served):

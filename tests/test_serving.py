@@ -23,6 +23,7 @@ from mxnet_tpu.gluon.model_zoo import gpt
 from mxnet_tpu.serving.replica import FrontDoor, ReplicaServer
 from mxnet_tpu.test_utils import (UNEQUAL_ANSWERS, cpu_child_env,
                                   jaxpr_loops, serving_host_walk,
+                                  serving_dead_rows_keep_their_cache,
                                   serving_unequal_answers)
 
 
@@ -148,12 +149,14 @@ def unequal():
 @pytest.mark.parametrize("wants", UNEQUAL_ANSWERS, ids=str)
 def test_a_row_that_wants_no_token_changes_nothing(unequal, wants):
     """The decode step is handed which rows still want a token and the
-    others attend to nothing: every request's tokens are what it gets
-    alone and in a group of equal answers; nothing is traced or compiled
-    for it, and the host reads what it read."""
+    others attend to nothing and write nothing into the cache: every
+    request's tokens are what it gets alone and in a group of equal
+    answers; nothing is traced or compiled for it, and the host reads
+    what it read."""
     eng, prompts = unequal
     prompts = prompts[:len(wants)]
     pinned = (serving.trace_count(), serving.compile_count())
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
     d0 = serving.dispatch_count()
     timings, live = serving_unequal_answers(eng, prompts, wants)
     assert (serving.trace_count(), serving.compile_count()) == pinned
@@ -188,10 +191,10 @@ def test_a_sampled_group_is_handed_the_same_mask(unequal):
 def test_the_step_without_live_is_the_step_of_every_row(unequal, live):
     """``program.step`` as its direct callers call it, with no ``live``,
     is the step with every row live, bit for bit; a row handed as dead
-    leaves every other row's logits and cache rows as they were, still
-    writes its own cache row (the first layer's is what it was: later
-    layers follow an attention that read nothing), and its own logits
-    stay finite."""
+    leaves every other row's logits and cache rows as they were, writes
+    nothing into its own cache rows in any layer (a live row's position
+    is written in the last layer too), and its own logits stay
+    finite."""
     import jax
 
     eng, prompts = unequal
@@ -210,16 +213,71 @@ def test_the_step_without_live_is_the_step_of_every_row(unequal, live):
     (ck2, cv2), got = step(w, cache, lens, zero, one) if live is None \
         else step(w, cache, lens, zero, one, live=np.asarray(live))
     kept = np.asarray([True] * B if live is None else live)
-    for a, b in ((ck, ck2), (cv, cv2)):
-        a, b = np.asarray(a), np.asarray(b)
+    for a, b, was in ((ck, ck2, cache[0]), (cv, cv2, cache[1])):
+        a, b, was = np.asarray(a), np.asarray(b), np.asarray(was)
         np.testing.assert_array_equal(a[:, kept], b[:, kept])
-        np.testing.assert_array_equal(a[0], b[0])
-        assert b[-1][np.arange(B), :, :, lens].any(axis=(1, 2)).all()
+        np.testing.assert_array_equal(was[:, ~kept], b[:, ~kept])
+        assert b[-1][np.arange(B), :, :, lens].any(axis=(1, 2))[kept].all()
     np.testing.assert_array_equal(np.asarray(got)[kept],
                                   np.asarray(want)[kept])
     assert np.isfinite(np.asarray(got)).all()
     if not kept.all():
         assert (np.asarray(got)[~kept] != np.asarray(want)[~kept]).any()
+
+
+@pytest.mark.parametrize("path", ["rows", "kernel"])
+def test_the_cache_write_says_whether_it_was_told_the_live_rows(monkeypatch,
+                                                                path):
+    """``decode_cache_write_live_share`` is in a group's timings and in
+    each request's record, beside ``decode_cache_write_kernel_share``:
+    of the decode program's row writes, those that went through the
+    kernel and were handed ``live``.  0.0 on the CPU's rows path; with
+    the kernel in the program (interpreted here, as on a TPU with no
+    mesh) 1.0, and a group of unequal answers gets the rows path's
+    tokens.  A step handed no ``live`` (the program's direct callers')
+    tallies none."""
+    import functools
+
+    import jax
+
+    from mxnet_tpu.ops import cache_write
+
+    net = _model(max_length=32)
+    prompts = _prompts(4, np.random.RandomState(13), lo=3, hi=12)
+    wants = [2, 9, 5, 9]
+    want, _ = serving.ServingEngine(net, batch_buckets=(4,)).serve_group(
+        prompts, wants)
+    if path == "kernel":
+        monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+        monkeypatch.setattr(cache_write, "_write_kernel", functools.partial(
+            cache_write._write_kernel, interpret=True))
+    share = float(path == "kernel")
+    eng = serving.ServingEngine(net, batch_buckets=(4,))
+    outs, timings = eng.serve_group(prompts, wants)
+    for got, w in zip(outs, want):
+        np.testing.assert_array_equal(got, w)
+    assert timings["decode_cache_write_kernel_share"] == share
+    assert timings["decode_cache_write_live_share"] == share
+    told = {"kernel": 8, "kernel_live": 8} if share else {"rows": 8}
+    assert dict(eng._program.cache_writes[1]) == told
+    serving_dead_rows_keep_their_cache(eng, prompts, [k > 1 for k in wants])
+    telemetry.reset()
+    batcher = serving.ContinuousBatcher(eng, max_delay_ms=150, max_batch=4)
+    try:
+        futs = [batcher.submit(p, k) for p, k in zip(prompts, wants)]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        batcher.close()
+    for r in telemetry.recent_requests():
+        telemetry.validate_record(r)
+        assert r["decode_cache_write_kernel_share"] == share
+        assert r["decode_cache_write_live_share"] == share
+    zero = np.zeros(4, np.int32)
+    jax.eval_shape(eng._program.step, eng._weights, eng.init_cache(4), zero,
+                   zero, np.zeros((4, 1), np.int32))
+    assert dict(eng._program.cache_writes[1]) == (
+        {"kernel": 8} if share else {"rows": 8})
 
 
 # -- AOT warmup / retrace pin --------------------------------------------------
@@ -437,6 +495,27 @@ def test_batcher_coalesces_and_emits_request_records():
         # four requests of three tokens in a bucket of four: two decode
         # steps, every row live in both
         assert r["decode_row_steps"] == r["decode_row_steps_live"] == 8
+
+
+def test_a_compile_collects_what_its_trace_left():
+    """`_compile` ends with a full pass of the cyclic collector, so
+    that none is left due among the first requests: one is seen inside
+    it, and the second generation's counter stands at 0 after it."""
+    import gc
+
+    eng = serving.ServingEngine(_model(), batch_buckets=(2,))
+    full = []
+
+    def seen(phase, info):
+        if phase == "stop" and info["generation"] == 2:
+            full.append(info)
+
+    gc.callbacks.append(seen)
+    try:
+        eng._compile(2, 1)
+    finally:
+        gc.callbacks.remove(seen)
+    assert full and gc.get_count()[2] == 0
 
 
 def test_batcher_propagates_engine_errors():
