@@ -9,6 +9,12 @@ from the seed: the seed moves who asks what and in what company, never
 how much is asked.  Sampling is greedy.
 
 One thread drives all clients through the futures the batcher returns.
+A client submits again the moment its own answer arrives; the window
+admits a round whole or not at all (`window.py`: ``group``).  No prompt
+is drawn between a round's first and last submission: set-up makes round
+0's, and the driver makes each client's next one while the batcher is at
+work (no answer for ``QUIET_S``).  A prompt that is missing all the same
+is drawn where it is needed and counted (``prompts_drawn_late``).
 """
 
 import concurrent.futures
@@ -16,8 +22,11 @@ import concurrent.futures
 import numpy as np
 
 
-# rounds whose prompts set-up makes ahead of the window
-READY_ROUNDS = 16
+# no answer for this long: the round is handed over and the batcher is
+# serving it, so the driver's thread has nothing to hold up.  Well over
+# the gap between two answers of one group (the batcher hands them over
+# one by one, under a millisecond apart), well under any cell's prefill
+QUIET_S = 0.02
 
 
 # -- traffic -------------------------------------------------------------------
@@ -42,6 +51,11 @@ def schedule(traffic, seed, rounds):
 def prompt_ids(seed, client, r, length, vocab):
     rng = np.random.default_rng([int(seed), 2, client, r])
     return rng.integers(0, vocab, length).astype(np.int32)
+
+
+def planned_prompt(seed, plan, vocab, client, r):
+    """The prompt ``plan`` has ``client`` send in round ``r``."""
+    return prompt_ids(seed, client, r, int(plan[r, client, 0]), vocab)
 
 
 # -- set-up --------------------------------------------------------------------
@@ -78,11 +92,12 @@ def setup(ctx):
     longest = max(traffic["output_lengths"])
     rounds = 2 + int(ctx["seconds"] / (longest * 1e-3))
     plan = schedule(traffic, ctx["seed"], rounds)
-    # the first rounds' prompts are made now: a client that draws its
-    # prompt while it submits spreads a round's submissions over more
-    # than the batcher's delay, and the round splits into two groups
-    ready = {(c, r): prompt_ids(ctx["seed"], c, r, int(plan[r, c, 0]), vocab)
-             for r in range(min(rounds, READY_ROUNDS)) for c in range(n)}
+    # round 0's prompts are made now and each later one by `drive`,
+    # ahead of its submission: a client that draws its prompt while it
+    # submits spreads a round's submissions towards the batcher's delay,
+    # and a round that outlasts it splits into two groups
+    ready = {(c, 0): planned_prompt(ctx["seed"], plan, vocab, c, 0)
+             for c in range(n)}
     return {"net": net, "ready": ready, "engine": engine, "batcher": batcher,
             "serving": serving, "vocab": vocab,
             "plan": plan, "n": n,
@@ -97,21 +112,50 @@ def arrays(state):
 
 # -- the measured window -------------------------------------------------------
 
+def _wait(pending, timeout):
+    """The futures of ``pending`` that are done, once one is or
+    ``timeout`` seconds (None: no limit) have passed."""
+    done, _ = concurrent.futures.wait(
+        pending, timeout=timeout,
+        return_when=concurrent.futures.FIRST_COMPLETED)
+    return done
+
+
+def rounds_split(records):
+    """How many rounds had their requests served in more than one
+    group.  The requests of a group carry the group's own bucket and its
+    two clock readings, so two groups never agree in all three."""
+    groups = {}
+    for rec in records:
+        groups.setdefault(rec["round"], set()).add(
+            (tuple(rec["bucket"]), rec["prefill_us"], rec["collect_us"]))
+    return sum(len(g) > 1 for g in groups.values())
+
+
 def drive(state, window, ctx):
     batcher, plan, n = state["batcher"], state["plan"], state["n"]
-    seed, vocab = ctx["seed"], state["vocab"]
-    records, failed = [], 0
+    seed, vocab, ready = ctx["seed"], state["vocab"], state["ready"]
+    records, failed, drawn_late = [], 0, 0
     pending = {}
     turn = [0] * n
+    live = set(range(n))        # clients the window has not yet refused
+
+    def wanted():
+        """(client, round) of every next prompt not yet made."""
+        return [(c, turn[c]) for c in live
+                if turn[c] < len(plan) and (c, turn[c]) not in ready]
 
     def send(client):
+        nonlocal drawn_late
         r = turn[client]
-        if r >= len(plan) or not window.submit(client):
+        if r >= len(plan) or not window.submit(client, group=r):
+            live.discard(client)
             return
-        length, new = (int(v) for v in plan[r, client])
-        prompt = state["ready"].get((client, r))
+        prompt = ready.pop((client, r), None)
         if prompt is None:
-            prompt = prompt_ids(seed, client, r, length, vocab)
+            drawn_late += 1
+            prompt = planned_prompt(seed, plan, vocab, client, r)
+        new = int(plan[r, client, 1])
         fut = batcher.submit(prompt, new)
         pending[fut] = (client, r, prompt, new)
         turn[client] += 1
@@ -120,8 +164,14 @@ def drive(state, window, ctx):
         send(client)
     attempted = 0
     while pending:
-        done, _ = concurrent.futures.wait(
-            pending, return_when=concurrent.futures.FIRST_COMPLETED)
+        want = wanted()
+        done = _wait(pending, QUIET_S if want else None)
+        if not done:
+            for c, r in want:
+                if any(fut.done() for fut in pending):
+                    break       # an answer waits: its client goes first
+                ready[c, r] = planned_prompt(seed, plan, vocab, c, r)
+            continue
         for fut in done:
             client, r, prompt, new = pending.pop(fut)
             attempted += 1
@@ -140,8 +190,9 @@ def drive(state, window, ctx):
             send(client)
     retraced = state["serving"].trace_count() - state["pinned"]
     return {"records": records, "attempted": attempted, "failed": failed,
-            "faults": ([f"{retraced} retraces inside the window"]
-                       if retraced else [])}
+            "faults": {"retraces_in_window": retraced},
+            "watched": {"rounds_split": rounds_split(records),
+                        "prompts_drawn_late": drawn_late}}
 
 
 def close(state):

@@ -149,7 +149,7 @@ def drive(state, window, ctx):
         records.append({"step_ms": took * 1e3, "loss": loss})
     bad = [r for r in records if not np.isfinite(r["loss"])]
     return {"records": records, "attempted": len(records),
-            "failed": len(bad), "faults": []}
+            "failed": len(bad), "faults": {}}
 
 
 def close(state):

@@ -93,3 +93,89 @@ def test_nothing_completed_gives_no_rate_and_failures_no_work():
     w.submit(0)
     w.abandon(0)
     assert w.in_flight == 0 and w.work == 0
+
+
+# -- a group is admitted whole or not at all -----------------------------------
+
+CLIENTS, WORK, ROUND_S = 6, 10, 12.3
+# the batcher hands a group's answers over one by one: client c holds its
+# answer, and submits again, c milliseconds after client 0
+SPREAD_S = (CLIENTS - 1) * 1e-3
+
+
+def staggered(seconds, grouped):
+    """Lock-step rounds of ``ROUND_S`` whose answers arrive a
+    millisecond apart; each client submits again on its own answer, with
+    its round number as the group or with none.  Returns the window and,
+    by round, the clients admitted."""
+    clock = Clock()
+    w = Window(seconds, clock=clock)
+    t_round, r, admitted = clock.now, 0, {}
+    live = list(range(CLIENTS))
+    while live:
+        let_in = []
+        for c in live:
+            clock.now = t_round + c * 1e-3
+            if r:
+                w.complete(c, WORK)
+            if w.submit(c, group=r if grouped else None):
+                let_in.append(c)
+        admitted[r] = let_in
+        live, r, t_round = let_in, r + 1, t_round + ROUND_S
+    return w, admitted
+
+
+# the line walked across the fourth round's end, a millisecond a case
+# (and half a one, to keep off floating-point ties): client c asks for
+# round 3 at 3 x ROUND_S + c ms, and its answer is due ROUND_S later
+@pytest.mark.parametrize("ms", range(-4, 12))
+def test_a_group_is_admitted_whole_or_not_at_all(ms):
+    seconds = 4 * ROUND_S + (ms + 0.5) * 1e-3
+    w, admitted = staggered(seconds, grouped=True)
+    assert all(len(a) in (0, CLIENTS) for a in admitted.values()), admitted
+    rounds = 4 if ms >= 0 else 3         # the first asker's answer for all
+    assert [len(a) for a in admitted.values()] == \
+        [CLIENTS] * rounds + [0]
+    assert len(w.completed) == rounds * CLIENTS
+    assert w.elapsed == pytest.approx(rounds * ROUND_S + SPREAD_S)
+    # whole rounds over their own duration: the lock-step rate, but for
+    # the last answers' way out
+    assert w.rate() == pytest.approx(CLIENTS * WORK / ROUND_S, rel=2e-4)
+    assert w.rate() == pytest.approx(
+        rounds * CLIENTS * WORK / (rounds * ROUND_S + SPREAD_S))
+
+
+@pytest.mark.parametrize("ms", range(-4, 12))
+def test_without_a_group_each_lane_is_decided_alone(ms):
+    """Today's rule, kept for the lanes that hand no group: the line
+    inside a round's resubmissions lets some clients in."""
+    seconds = 4 * ROUND_S + (ms + 0.5) * 1e-3
+    w, admitted = staggered(seconds, grouped=False)
+    last = admitted[3]
+    assert last == [c for c in range(CLIENTS) if c <= ms]
+    if 0 <= ms < CLIENTS - 1:
+        assert 0 < len(last) < CLIENTS       # the group of a few
+
+
+def test_a_group_is_decided_once_on_the_slowest_piece_of_any_lane():
+    clock = Clock()
+    w = Window(10.0, clock=clock)
+    assert w.submit("a", group=0) and w.submit("b", group=0)
+    clock.now += 3.0
+    w.complete("a", 1)
+    clock.now += 1.0
+    w.complete("b", 1)                   # b's piece took 4
+    clock.now = 106.5
+    assert not w.submit("a", group=1)    # 6.5 + 4 > 10, though a's took 3
+    assert not w.submit("b", group=1)
+    w2 = Window(10.0, clock=clock)
+    clock.now = 100.0
+    assert w2.submit("a", group=0) and w2.submit("b", group=0)
+    clock.now = 104.0
+    w2.complete("a", 1)
+    w2.complete("b", 1)
+    clock.now = 105.9
+    assert w2.submit("a", group=1)       # 5.9 + 4 <= 10: decided
+    clock.now = 106.5
+    assert w2.submit("b", group=1)       # later, and still the answer
+    assert w2.in_flight == 2

@@ -12,6 +12,18 @@ its first completion a lane is always admitted.  Work admitted is waited
 for and counted even when it ends after ``seconds``: the elapsed time
 then runs to its completion.
 
+Lanes that issue their pieces together hand ``submit`` a ``group`` (the
+closed loop: its round number).  A group is admitted whole or not at
+all: the decision is made once, when the first lane asks for that group,
+on the slowest completed piece of ANY lane, and every lane that asks for
+the same group later is given that answer.  Decided lane by lane, a
+round that ends within milliseconds of the line is let in for some
+clients and not for others, and what the few are served as (a smaller
+group, another compiled shape) is then drawn by where the line fell in
+the round, not by the program under test.  The rule is no barrier: a
+lane still submits when its own piece has ended, and it says nothing
+about how the scheduler batches what it is handed.
+
 Under a scheduler that runs lock-step rounds this gives whole rounds over
 their own duration, whatever ``seconds`` is.  Under a scheduler that
 admits work at every step it is the same arithmetic with a short drain at
@@ -32,15 +44,26 @@ class Window:
         self.completed = []         # (lane, started, finished, work)
         self._started = {}
         self._slowest = {}
+        self._groups = {}           # group -> admitted, decided once
 
-    def submit(self, lane=0):
+    def _fits(self, now, slowest):
+        return slowest is None or now + slowest <= self.t0 + self.seconds
+
+    def submit(self, lane=0, group=None):
         """True when the lane may start its next piece now; the first
-        call opens the window."""
+        call opens the window.  With a ``group`` the answer is the one
+        the group's first asker got."""
         now = self.clock()
         if self.t0 is None:
             self.t0 = now
-        slowest = self._slowest.get(lane)
-        if slowest is not None and now + slowest > self.t0 + self.seconds:
+        if group is None:
+            admitted = self._fits(now, self._slowest.get(lane))
+        else:
+            admitted = self._groups.get(group)
+            if admitted is None:
+                admitted = self._groups[group] = self._fits(
+                    now, max(self._slowest.values(), default=None))
+        if not admitted:
             return False
         self._started[lane] = now
         return True
