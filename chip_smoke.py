@@ -899,10 +899,14 @@ def phase_serve_keye(size, platform):
         lens = lens + (1,) * pads
         live = L * sum(n * (n + 1) // 2 for n in lens)
         least = L * sum(min(t + 1, topk) for n in lens for t in range(n))
+        # and every prefill attention call went through the flash
+        # forward kernel under the selection (ops/pallas_attention.py)
         return timing["attn_keys_live_prefill"] == live \
             and least <= timing["attn_keys_selected_prefill"] < live \
             and 0 < timing["attn_keys_selected_decode"] \
-            < timing["attn_keys_live_decode"] and moe_rows_hold(timing)
+            < timing["attn_keys_live_decode"] \
+            and timing["prefill_attn_kernel_share"] == 1.0 \
+            and moe_rows_hold(timing)
 
     # three stacks: keys, values, the indexer's keys
     net, engine, timing, out = serve_family(

@@ -28,8 +28,11 @@ counter arrays ride in the same carry (``counters``).
 
 Prefill (a block of S positions from position 0) works a row chunk at a
 time: the selection kernel turns the indexer's scores into an int8 mask
-without the scores leaving VMEM, and the attention kernel runs a
-blockwise softmax under that mask.  Decode scores the indexer's cache
+without the scores leaving VMEM, and the block attends inside itself
+under that mask through
+`ops/pallas_attention.py::flash_attention_forward(keep=)`, the forward
+body Kimi-K2's, Ouro's and Command A+'s prefills run, each row to its
+own length.  Decode scores the indexer's cache
 row, finds the threshold by the same search, and attends over the row's
 cache under the mask.
 
@@ -41,7 +44,8 @@ in `_decoder_ops.py`, which both import; neither imports the other.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write, indexed_attention
+from ...ops import (cache_attention, cache_write, indexed_attention,
+                    pallas_attention)
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
 
@@ -152,14 +156,15 @@ def _experts(z, w, l, x, route, valid):
                                  valid)
 
 
-def _block_layer(z, p, x, pos, last):
+def _block_layer(z, p, x, pos, last, tally=None):
     """A layer on a block (B, S, C) that attends inside itself, as far as
     a row needs no other row: the selection, attention over it and the
-    router.  Returns (x, (k, v, ki, route, keys selected a row))."""
+    router.  Returns (x, (k, v, ki, route, keys selected a row)).
+    ``tally``: a Counter of the attention calls, by path."""
     import jax
     import jax.numpy as jnp
 
-    S = x.shape[1]
+    B, S, _ = x.shape
     u, q, k, v = _qkv(z, p, x, pos)
     qi, ki, w = _index(z, p, u, pos)
     with jax.named_scope("serve.attn_select"):
@@ -170,7 +175,13 @@ def _block_layer(z, p, x, pos, last):
             jnp.where(real, jnp.sum(mask, axis=-1, dtype=jnp.int32), 0),
             axis=-1).astype(jnp.uint32)
     with jax.named_scope("serve.attn_sparse"):
-        a = indexed_attention.attend_prefill(q, k, v, mask, last)
+        # the flash forward body under the mask: a key head's query
+        # heads lie side by side, the queries arrive scaled
+        a = pallas_attention.flash_attention_forward(
+            q.reshape(B, z.num_heads, S, z.head_dim), k, v, last + 1,
+            scale=1.0, keep=mask).reshape(q.shape)
+        if tally is not None:
+            tally["kernel"] += 1
     with jax.named_scope("serve.attn_out"):
         x = _ops.attn_out(z, p, x, a)
     return x, (k, v, ki, _softmax_route(z, p, x), selected)
@@ -285,9 +296,11 @@ class KeyeVL2Program:
         self.vocab = model._vocab
         self._pins = None
         # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the cache
+        # cache_reads[S]: its attention calls over the cache;
+        # block_attends[S]: its attention calls inside the block
         self.cache_writes = {}
         self.cache_reads = {}
+        self.block_attends = {}
         # what a reloaded model must share beyond its shapes
         self.signature = (z.num_heads, z.kv_heads, z.index_heads, z.topk,
                           z.experts_held, z.experts_per_token, z.rope_theta)
@@ -366,6 +379,7 @@ class KeyeVL2Program:
         W, n = self.window, z.experts_held[1]
         tally = self.cache_writes[S] = collections.Counter()
         reads = self.cache_reads[S] = collections.Counter()
+        attends = self.block_attends[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             x = jnp.take(w["embed_weight"], toks, axis=0
                          ).astype(jnp.float32)
@@ -409,7 +423,8 @@ class KeyeVL2Program:
 
         def prefill_layer(x, stacks, p, l):
             x, (k, v, ki, route, selected) = _ops.by_rows(
-                lambda x, at, last: _block_layer(z, p, x, at, last),
+                lambda x, at, last: _block_layer(z, p, x, at, last,
+                                                  attends),
                 rows, x, at, last)
             n_live = (last + 1).astype(jnp.uint32)
             seen = jnp.stack([jnp.sum(n_live * (n_live + 1) // 2),

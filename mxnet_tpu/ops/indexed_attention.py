@@ -21,10 +21,11 @@ set, and exact ties at the threshold are all kept.
   live key are made on the MXU into a VMEM scratch, searched there, and
   only the selection leaves as an int8 mask ``(B, S, S)``: the scores
   never reach HBM (a float32 ``(B, S, S)`` array is 17 GB at 16 x 16,384).
-- `attend_prefill` (Pallas): blockwise attention with a running softmax
-  (grown from `pallas_attention._fwd_kernel`) under that mask, the ``G``
-  query heads that share a key/value head worked as one ``(G * bq, d)``
-  tile; tiles above the diagonal or past a row's last token are skipped.
+- attention under that mask is not here: the prefill calls
+  `pallas_attention.flash_attention_forward(keep=mask)`, the flash
+  forward body that Kimi-K2's, Ouro's and Command A+'s prefills run,
+  with the mask one more operand (PR 43; this file's own kernel, a grid
+  over key blocks too, spent four of five grid steps on nothing).
 - decode (`index_scores_decode`, `select_topk`): one query a row against
   the indexer's cache, plain XLA.
 
@@ -39,10 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_attention import _bcast_lanes, _use_interpret
+from .pallas_attention import _use_interpret
 
 _INT_MIN = -2 ** 31
-_NEG = -1e30
 _LANE = 128
 # a query block's keys for 16,384 positions are 8 MB of VMEM, beside the
 # double-buffered indexer keys and mask: more than the 16 MB default
@@ -212,108 +212,3 @@ def select_prefill(qi, w, ki, last, k):
         out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.int8),
         interpret=interpret, **kw,
     )(last.astype(jnp.int32), qi, w.astype(jnp.float32), ki)
-
-
-# -- prefill: attention under the selection ------------------------------------
-
-def _attend_kernel(last_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr,
-                   l_scr, acc_scr, p_scr, *, bq, bk, nk, G):
-    from jax.experimental import pallas as pl
-
-    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    last = last_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    @pl.when((j * bk <= i * bq + bq - 1) & (i * bq <= last)
-             & (j * bk <= last))
-    def _run():
-        s_all = lax.dot_general(q_ref[...], k_ref[...],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        keep = mask_ref[...] != 0                            # (bq, bk)
-        alphas = []
-        for g in range(G):
-            rows = slice(g * bq, (g + 1) * bq)
-            s = jnp.where(keep, s_all[rows], _NEG)
-            m_prev = m_scr[rows]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-            p = jnp.exp(s - _bcast_lanes(m_next, bk))
-            p = jnp.where(s <= _NEG / 2, 0.0, p)
-            alpha = jnp.exp(m_prev - m_next)
-            l_scr[rows] = alpha * l_scr[rows] + jnp.sum(p, axis=1)[:, None]
-            m_scr[rows] = m_next
-            p_scr[rows] = p.astype(p_scr.dtype)
-            alphas.append(alpha)
-        pv = lax.dot_general(p_scr[...], v_ref[...],
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        d = acc_scr.shape[1]
-        for g in range(G):
-            rows = slice(g * bq, (g + 1) * bq)
-            acc_scr[rows] = acc_scr[rows] * _bcast_lanes(alphas[g], d) + pv[rows]
-
-    @pl.when(j == nk - 1)
-    def _store():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / _bcast_lanes(l, acc_scr.shape[1])
-                      ).astype(o_ref.dtype)
-
-
-def attend_prefill(q, k, v, mask, last):
-    """Causal attention of a block of S positions over itself, each
-    query over the positions its ``mask`` row keeps.
-
-    q (B, K, G, S, d), scaled; k, v (B, K, S, d); ``mask`` int8
-    (B, S, S); ``last`` (B,).  Returns (B, K, G, S, d) in q's type;
-    queries past ``last[b]``'s block are left zero."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, K, G, S, d = q.shape
-    bq, bk = _blocks(S)
-    nq, nk = S // bq, S // bk
-    # the G heads of a group, one query block: a (G * bq, d) tile
-    qt = q.reshape(B, K, G, nq, bq, d).swapaxes(2, 3).reshape(
-        B, K, nq, G * bq, d)
-    interpret = _use_interpret()
-    kw = {} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))}
-
-    def diag(i, j):
-        # a tile above the diagonal is never read: name the last one
-        # that is, so that the pipeline fetches nothing new
-        return jnp.minimum(j, (i * bq + bq - 1) // bk)
-
-    lanes = min(_LANE, d)
-    out = pl.pallas_call(
-        functools.partial(_attend_kernel, bq=bq, bk=bk, nk=nk, G=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, K, nq, nk),
-            in_specs=[
-                pl.BlockSpec((None, None, None, G * bq, d),
-                             lambda b, h, i, j, last: (b, h, i, 0, 0)),
-                pl.BlockSpec((None, None, bk, d),
-                             lambda b, h, i, j, last: (b, h, diag(i, j), 0)),
-                pl.BlockSpec((None, None, bk, d),
-                             lambda b, h, i, j, last: (b, h, diag(i, j), 0)),
-                pl.BlockSpec((None, bq, bk),
-                             lambda b, h, i, j, last: (b, i, diag(i, j)))],
-            out_specs=pl.BlockSpec(
-                (None, None, None, G * bq, d),
-                lambda b, h, i, j, last: (b, h, i, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((G * bq, lanes), jnp.float32),
-                            pltpu.VMEM((G * bq, lanes), jnp.float32),
-                            pltpu.VMEM((G * bq, d), jnp.float32),
-                            pltpu.VMEM((G * bq, bk), v.dtype)]),
-        out_shape=jax.ShapeDtypeStruct((B, K, nq, G * bq, d), q.dtype),
-        interpret=interpret, **kw,
-    )(last.astype(jnp.int32), qt, k, v, mask)
-    return out.reshape(B, K, nq, G, bq, d).swapaxes(2, 3).reshape(
-        B, K, G, S, d)

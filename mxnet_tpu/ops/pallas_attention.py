@@ -53,6 +53,33 @@ matrix nor the whole K/V sequence is ever resident:
   (4, 16, 1024, 64) Mosaic refuses the rows driver's copies of 64-wide
   rows, and with q, k and v padded to 128 lanes it takes 0.322-0.326 ms
   against 0.274.
+  **Four callers of the one rows driver, and what a caller that hands
+  nothing gets.**  Kimi-K2's prefill (PR 35), Ouro's (PR 37), Command
+  A+'s (PR 39: ``window=``, key heads fewer than query heads) and
+  Keye-VL-2.0's (PR 43: ``keep=``, a **selection mask**).  The mask is
+  int8 (B, T, T), ``[b, t, s] != 0`` where query t of row b attends to
+  key s (`indexed_attention.select_prefill` writes it; it holds
+  causality); it stays in HBM and the (block_q, block_k) window of it
+  that belongs to a key block travels with that block into one of two
+  buffers, by the same copies on the same schedule; `_fwd_tiles` masks
+  every sub-tile's scores with it in place of the diagonal's iota
+  comparison, and the walk stays dense to the diagonal (Keye's top-2,048
+  of 4k-16k positions leave no 512 x 512 sub-tile empty).  Under a mask
+  a grid step is a query block of a key head's ``G`` query heads, not of
+  one: one copy of keys, values and mask, one comparison of the mask's
+  sub-tile, ``G`` heads' products (measured at Keye's (1, 32 over 4,
+  16384, 128), ms a call at 4,096 / 8,519 / 16,128 positions: a head a
+  step 2.30 / 5.98 / 17.5, eight with the heads in a loop 2.04 / 5.41 /
+  16.1, eight unrolled 1.88 / 4.76 / 13.97, the kernel of PR 30 with
+  its grid over key blocks 5.71 / 8.92 / 18.87, by PR 41's builder; read
+  again in PR 43: 1.86-1.90 / 4.76 / 13.91-14.01 against 5.70-5.73 /
+  8.90-8.96 / 18.87-18.92, the results equal bit for bit; PERF.md
+  section 6).  ``window``, a group and ``keep`` are static: with
+  ``window=None``, ``keep=None`` the call builds the kernel it built
+  before either existed, operand for operand and equation for equation,
+  grouped or not (tests/test_pallas_attention.py counts them), and
+  grouping the heads of the callers without a mask is theirs to measure
+  on their own cells.
   The copies move whole lane tiles (Mosaic refuses a 192-wide or a
   64-wide row): keys or values whose width is no multiple of 128 are
   padded with zeros in front of the call unless they arrive so
@@ -142,7 +169,8 @@ def _sub_tiles(block_q, block_k):
                  for b in (block_q, block_k))
 
 
-def _vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv=None):
+def _vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv=None, keep=False,
+                G=1):
     """(what a grid step's blocks take of VMEM, what stays for a whole
     head): every operand block twice (the pipeline's double buffer),
     the blocks' float32 accumulators and a sub-tile's float32
@@ -150,24 +178,31 @@ def _vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv=None):
     `T` 4,096-16,384, `D` 64-256, both types); for the backward the
     head's dq besides, its output block twice and its accumulator.
     Queries and keys are ``D`` wide; values, the output and its
-    accumulator ``Dv`` (``D`` where None; the backward has one width)."""
+    accumulator ``Dv`` (``D`` where None; the backward has one width).
+    ``keep``: the forward under a selection mask holds two int8
+    (block_q, block_k) windows of it besides, and ``G`` query blocks,
+    output blocks and sets of sums, one a head of the step; its heads
+    are unrolled, and Mosaic then holds nine sub-tile temporaries
+    (19.69 MB by its count at G 8, blocks of 512, D 128)."""
     item = jnp.dtype(dtype).itemsize
     sq, sk = _sub_tiles(block_q, block_k)
     qb, kb = block_q * D * item, block_k * D * item
     rows = _LSE_ROWS * block_q * 4
-    tile = 3 * sq * sk * 4
+    tile = (3 if G == 1 else 9) * sq * sk * 4
     if kernel == "fwd":
         Dv = D if Dv is None else Dv
         ob, vb = block_q * Dv * item, block_k * Dv * item
-        blocks = qb + ob + kb + vb + rows           # q, o; k, v; lse
-        scratch = 2 * block_q * _LANE * 4 + block_q * Dv * 4
+        blocks = G * (qb + ob) + kb + vb + rows     # q, o; k, v; lse
+        scratch = G * (2 * block_q * _LANE * 4 + block_q * Dv * 4)
+        if keep:
+            scratch += 2 * block_q * block_k
         return 2 * blocks + scratch + tile, 0
     blocks = 2 * qb + 2 * rows + 4 * kb     # q, g; lse, delta; k, v, dk, dv
     scratch = 2 * block_k * D * 4
     return 2 * blocks + scratch + tile, 2 * T * D * item + T * D * 4
 
 
-def _block_sizes(T, D, dtype, kernel, Dv=None):
+def _block_sizes(T, D, dtype, kernel, Dv=None, keep=False, G=1):
     """``(block_q, block_k)`` of the grid for ``kernel`` ("fwd" or
     "bwd"), from what the kernel sees (``Dv``: the values' width where
     it is not the keys').
@@ -177,13 +212,18 @@ def _block_sizes(T, D, dtype, kernel, Dv=None):
     and keys.  A grid step costs some 0.35 us whatever it holds and its
     copies hide behind the step before, so the grid is as coarse as
     VMEM allows; inside a step the kernels walk `_sub_tiles` up to the
-    diagonal, so a coarse block spends no work above it."""
+    diagonal, so a coarse block spends no work above it.  A step of
+    several heads (``G`` > 1: under a mask ``keep``) may take twice the
+    budget and asks for its own limit: inside one budget eight heads of
+    128 get blocks of 256 (16.9 ms a call at 16,128 positions against
+    13.97 at 512: PR 41's builder, PERF.md section 6)."""
     if T % _LANE:
         # interpret-mode small/odd shapes; flash_attention refuses them
         # on TPU
         return T, T
     fits = [n for n in _aligned_divisors(T, _MAX_BLOCK)
-            if _vmem_bytes(T, D, dtype, kernel, n, n, Dv)[0] <= _VMEM_BUDGET]
+            if _vmem_bytes(T, D, dtype, kernel, n, n, Dv, keep, G)[0]
+            <= _VMEM_BUDGET * (1 if G == 1 else 2)]
     return (fits or [_LANE])[0], (fits or [_LANE])[0]
 
 
@@ -222,7 +262,8 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None):
+def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None,
+          always=False):
     """Run ``tile(c, carry, masked)`` over those of a block's ``n`` key
     sub-tiles (``sub_k`` positions each, from ``k0``) that the ``sub_q``
     queries from ``q0`` can see: unmasked while a sub-tile lies wholly
@@ -231,7 +272,9 @@ def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None):
     (an int; causal) a query sees its own position and the ``window -
     1`` before it: none of the sub-tiles that lie wholly behind the
     first query's band, masked those that hold a key behind the last
-    query's."""
+    query's.  ``always``: one loop over every sub-tile up to the
+    diagonal, all masked (the caller's mask is not the diagonal's: a
+    selection)."""
     loop = jax.lax.fori_loop
     if not causal:
         return loop(0, n, lambda c, x: tile(c, x, False), carry)
@@ -239,6 +282,8 @@ def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None):
     whole = jnp.clip(div(jnp.maximum(q0 + 1 - k0, 0), sub_k), 0, n)
     some = jnp.clip(div(jnp.maximum(q0 + sub_q - k0 + sub_k - 1, 0),
                         sub_k), 0, n)
+    if always:
+        return loop(0, some, lambda c, x: tile(c, x, True), carry)
     if window is None:
         carry = loop(0, whole, lambda c, x: tile(c, x, False), carry)
         return loop(whole, some, lambda c, x: tile(c, x, True), carry)
@@ -257,53 +302,85 @@ def _walk(causal, q0, sub_q, k0, sub_k, n, tile, carry, window=None):
 # -- forward -------------------------------------------------------------------
 
 def _fwd_tiles(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, tiles, q_first,
-               k_first, at, *, scale, causal, sub_q, sub_k, window=None):
+               k_first, at, *, scale, causal, sub_q, sub_k, window=None,
+               keep_ref=None):
     """One key block's part of a query block's running softmax: q_ref
     (1, block_q, D) from position ``q_first``, its first ``tiles``
     sub-tiles; the key block ``at`` of k_ref (., block_k, D) and v_ref
     (., block_k, Dv), resident, from position ``k_first``; maximum and
-    sum (block_q, 128) and the accumulator (block_q, Dv) in scratch."""
+    sum (block_q, 128) and the accumulator (block_q, Dv) in scratch.
+    ``keep_ref`` (., block_q, block_k) int8 or None: window ``at`` of a
+    selection mask, the query block's rows by the key block's keys; a
+    query then sees the keys its row marks and no other (the mask holds
+    causality), in every sub-tile the walk visits.  Several heads a
+    step (the rows kernel under a mask): q_ref (1, G, block_q, D), the
+    scratch G times as tall, head after head; a sub-tile of keys,
+    values and mask is then worked for the G heads in turn."""
     from jax.experimental import pallas as pl
 
     Dv = acc_scr.shape[1]
+    G = 1 if len(q_ref.shape) == 3 else q_ref.shape[1]
+    block_q = m_scr.shape[0] // G
 
     def q_tile(a, carry):
         rows = pl.ds(pl.multiple_of(a * sub_q, sub_q), sub_q)
         q0 = q_first + a * sub_q
-        q = q_ref[0, rows, :]
+        # one head's queries stay loaded over its key sub-tiles
+        q = q_ref[0, rows, :] if G == 1 else None
 
         def tile(c, carry, masked):
             keys = pl.ds(pl.multiple_of(c * sub_k, sub_k), sub_k)
-            s = _dot(q, k_ref[at, keys, :], _NT) * scale  # (sub_q, sub_k)
-            if masked:
-                # query q0 + i sees key k0 + j iff i - j >= k0 - q0; a
-                # row of a visited sub-tile always holds a visible key
-                # or has seen key 0 before, so exp(_NEG - m) is 0.0
-                i_j = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                       - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-                seen = i_j >= k_first + c * sub_k - q0
-                if window is not None:
-                    # and no further behind than the band.  A row whose
-                    # band begins past this sub-tile sees nothing yet:
-                    # its sums take exp(0) of every key here and are
-                    # wiped (exp(_NEG - m) is 0.0) by the first key it
-                    # does see, at the latest its own
-                    seen = seen & (i_j < k_first + c * sub_k - q0 + window)
-                s = jnp.where(seen, s, _NEG)
-            m_prev = m_scr[rows, :]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-            p = jnp.exp(s - _bcast_lanes(m_next, sub_k))
-            alpha = jnp.exp(m_prev - m_next)
-            l_scr[rows, :] = (alpha * l_scr[rows, :]
-                              + jnp.sum(p, axis=1)[:, None])
-            m_scr[rows, :] = m_next
-            v = v_ref[at, keys, :]
-            acc_scr[rows, :] = (acc_scr[rows, :] * _bcast_lanes(alpha, Dv)
-                                + _dot(p.astype(v.dtype), v, _NN))
+            # the mask's sub-tile is read and compared once for the
+            # heads that share it
+            kept = None if keep_ref is None else \
+                keep_ref[at, rows, keys] != 0
+            for g in range(G):
+                mine = rows if g == 0 else pl.ds(pl.multiple_of(
+                    g * block_q + a * sub_q, sub_q), sub_q)
+                s = _dot(q if G == 1 else q_ref[0, g, rows, :],
+                         k_ref[at, keys, :], _NT) * scale  # (sub_q, sub_k)
+                if kept is not None:
+                    # a row need mark no key of the first sub-tiles it
+                    # visits, nor its own position: like a row whose
+                    # band begins later (below), it is wiped by the
+                    # first key it does see, and every live row marks
+                    # one
+                    s = jnp.where(kept, s, _NEG)
+                elif masked:
+                    # query q0 + i sees key k0 + j iff i - j >= k0 - q0;
+                    # a row of a visited sub-tile always holds a visible
+                    # key or has seen key 0 before, so exp(_NEG - m) is
+                    # 0.0
+                    i_j = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                           - jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                      1))
+                    seen = i_j >= k_first + c * sub_k - q0
+                    if window is not None:
+                        # and no further behind than the band.  A row
+                        # whose band begins past this sub-tile sees
+                        # nothing yet: its sums take exp(0) of every
+                        # key here and are wiped (exp(_NEG - m) is 0.0)
+                        # by the first key it does see, at the latest
+                        # its own
+                        seen = seen & (i_j < k_first + c * sub_k - q0
+                                       + window)
+                    s = jnp.where(seen, s, _NEG)
+                m_prev = m_scr[mine, :]
+                m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+                p = jnp.exp(s - _bcast_lanes(m_next, sub_k))
+                alpha = jnp.exp(m_prev - m_next)
+                l_scr[mine, :] = (alpha * l_scr[mine, :]
+                                  + jnp.sum(p, axis=1)[:, None])
+                m_scr[mine, :] = m_next
+                v = v_ref[at, keys, :]
+                acc_scr[mine, :] = (acc_scr[mine, :]
+                                    * _bcast_lanes(alpha, Dv)
+                                    + _dot(p.astype(v.dtype), v, _NN))
             return carry
 
         return _walk(causal, q0, sub_q, k_first, sub_k,
-                     k_ref.shape[1] // sub_k, tile, carry, window)
+                     k_ref.shape[1] // sub_k, tile, carry, window,
+                     always=keep_ref is not None)
 
     jax.lax.fori_loop(0, tiles, q_tile, 0)
 
@@ -350,7 +427,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
                      m_scr, l_scr, acc_scr, slot_ref, *, scale, block_q,
-                     block_k, sub_q, sub_k, heads, window=None, group=1):
+                     block_k, sub_q, sub_k, heads, window=None, group=1,
+                     keep_hbm=None, keep_buf=None):
     """The causal forward with its key blocks brought by the kernel (a
     serving prefill's): a step is query block ``qi`` of a head over ALL
     the key blocks it can see, to its diagonal, and none where the block
@@ -370,7 +448,16 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     ``group``: query heads a key head; grid row ``h`` reads key head
     ``h // group`` of k_hbm and v_hbm, which then hold ``1 / group`` as
     many heads as q (grouped-query attention, nothing repeated in
-    HBM)."""
+    HBM).  ``keep_hbm`` (batch rows, T, T) int8 in HBM, or None: a
+    selection mask, ``[r, t, s] != 0`` where query ``t`` of batch row
+    ``r`` sees key ``s``.  Its (block_q, block_k) window travels with
+    each key block, into ``keep_buf`` (2, block_q, block_k) under a
+    third pair of semaphores, on the same schedule.  Under a mask a
+    grid row is a key head and the step works all its ``G`` query
+    heads over one copy of keys, values and mask: q_ref (1, G,
+    block_q, D), o_ref (1, G, block_q, Dv), ``heads`` the key heads a
+    batch row, ``group`` 1 (PERF.md section 6, PR 43: 13.9-14.0 ms a
+    call at 16,128 positions against 17.5 with a head a step)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -393,14 +480,22 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
             to_diagonal = to_diagonal - first_of(i)
         return jnp.where(live, to_diagonal, 0)
 
-    def copies(head, j, slot):
+    def copies(head, i, j, slot):
+        """Key block ``j`` of ``head`` (and its window of the mask for
+        query block ``i``) into buffer ``slot``."""
         at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        masks = ()
+        if keep_hbm is not None:
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            masks = (pltpu.make_async_copy(
+                keep_hbm.at[head // heads, rows, at], keep_buf.at[slot],
+                sem.at[2, slot]),)
         if group != 1:
             head = head // group
         return (pltpu.make_async_copy(k_hbm.at[head, at, :], k_buf.at[slot],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[head, at, :], v_buf.at[slot],
-                                      sem.at[1, slot]))
+                                      sem.at[1, slot])) + masks
 
     blocks = blocks_of(b, qi)
     # the walk's first block: 0 with no window, as a Python int
@@ -412,7 +507,7 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
 
         @pl.when(blocks > 0)
         def _():
-            for c in copies(b, 0, 0):
+            for c in copies(b, qi, 0, 0):
                 c.start()
 
     base = slot_ref[0]
@@ -423,7 +518,8 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         """The next step's first block into ``slot``, if it walks any."""
         @pl.when((nb < nh) & (blocks_of(jnp.minimum(nb, nh - 1), ni) > 0))
         def _():
-            for c in copies(nb, 0 if window is None else first_of(ni), slot):
+            for c in copies(nb, ni, 0 if window is None else first_of(ni),
+                            slot):
                 c.start()
 
     _fwd_open(*scr)
@@ -436,21 +532,38 @@ def _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         # step's own, or behind its last the next step's first
         @pl.when(j + 1 < blocks)
         def _ahead():
-            for c in copies(b, at + 1, 1 - slot):
+            for c in copies(b, qi, at + 1, 1 - slot):
                 c.start()
 
         pl.when(j + 1 == blocks)(lambda: hand_on(1 - slot))
-        for c in copies(b, at, slot):
+        for c in copies(b, qi, at, slot):
             c.wait()
         _fwd_tiles(q_ref, k_buf, v_buf, *scr, tiles, qi * block_q,
                    at * block_k, slot, scale=scale, causal=True,
-                   sub_q=sub_q, sub_k=sub_k, window=window)
+                   sub_q=sub_q, sub_k=sub_k, window=window,
+                   keep_ref=keep_buf)
         return carry
 
     jax.lax.fori_loop(0, blocks, block, 0)
     pl.when(blocks == 0)(lambda: hand_on(base))
     slot_ref[0] = (base + blocks) % 2
-    _fwd_close(o_ref, None, *scr, qi * block_q, n)
+    if len(q_ref.shape) == 3:
+        _fwd_close(o_ref, None, *scr, qi * block_q, n)
+    else:
+        # several heads a step: o_ref (1, G, block_q, Dv), the sums head
+        # after head
+        for g in range(q_ref.shape[1]):
+            rows = pl.ds(g * block_q, block_q)
+            _fwd_close(o_ref.at[0, pl.ds(g, 1)], None,
+                       *(r.at[rows] for r in scr), qi * block_q, n)
+
+
+def _fwd_rows_kept_kernel(len_ref, q_ref, k_hbm, v_hbm, keep_hbm, o_ref,
+                          keep_buf, *scratch, **static):
+    """`_fwd_rows_kernel` under a selection mask: the mask the fourth
+    operand, its two windows the first scratch."""
+    _fwd_rows_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *scratch,
+                     keep_hbm=keep_hbm, keep_buf=keep_buf, **static)
 
 
 def _sds(shape, dtype, vma):
@@ -461,10 +574,11 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _blocks(T, D, dtype, kernel, block_q, block_k, Dv=None):
+def _blocks(T, D, dtype, kernel, block_q, block_k, Dv=None, keep=False,
+            G=1):
     """The grid's blocks for ``kernel``: the caller's where it names
     them, `_block_sizes`' otherwise; they must divide ``T``."""
-    dbq, dbk = _block_sizes(T, D, dtype, kernel, Dv)
+    dbq, dbk = _block_sizes(T, D, dtype, kernel, Dv, keep, G)
     bq, bk = int(block_q or dbq), int(block_k or dbk)
     if T % bq or T % bk:
         raise ValueError(
@@ -475,10 +589,11 @@ def _blocks(T, D, dtype, kernel, block_q, block_k, Dv=None):
 
 
 def _compiler_params(T, D, dtype, kernel, block_q, block_k, semantics,
-                     Dv=None):
+                     Dv=None, keep=False, G=1):
     from jax.experimental.pallas import tpu as pltpu
 
-    need = sum(_vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv))
+    need = sum(_vmem_bytes(T, D, dtype, kernel, block_q, block_k, Dv, keep,
+                           G))
     limit = {} if need <= _VMEM_DEFAULT else {
         "vmem_limit_bytes": need + need // 4}
     return pltpu.CompilerParams(dimension_semantics=semantics, **limit)
@@ -534,62 +649,82 @@ def _flash_call(q, k, v, causal, scale, block_q=None, block_k=None,
 
 
 def _flash_rows_call(q, k, v, scale, lengths, block_q=None, block_k=None,
-                     window=None):
+                     window=None, keep=None):
     """`_fwd_rows_kernel` over q (B, H, T, D), k (B, Hk, T, D), v (B,
     Hk, T, Dv) (``Hk`` divides ``H``: ``H / Hk`` query heads read a key
     head) and ``lengths`` (B,) int32 within [0, T]: grid (B·H, query
     blocks), both axes in order (a step hands the next its first
-    block).  ``window``: static, None for all earlier positions."""
+    block).  ``window``: static, None for all earlier positions.
+    ``keep``: (B, T, T) int8 or None, the selection mask; with it the
+    grid is (B·Hk, query blocks), a key head's query heads one step."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Hk, Dv = k.shape[1], v.shape[-1]
-    block_q, block_k = _blocks(T, D, q.dtype, "fwd", block_q, block_k, Dv)
+    kept = keep is not None
+    # under a mask a step works the G query heads of a key head over one
+    # copy of its keys, values and mask
+    G = H // Hk if kept else 1
+    block_q, block_k = _blocks(T, D, q.dtype, "fwd", block_q, block_k, Dv,
+                               kept, G)
     sub_q, sub_k = _sub_tiles(block_q, block_k)
     interpret = _use_interpret()
     kw = {} if interpret else {
         "compiler_params": _compiler_params(
             T, D, q.dtype, "fwd", block_q, block_k,
-            ("arbitrary", "arbitrary"), Dv)}
-    # with no window and a key head a query head, the kernel the parent
-    # built: neither is an operand, and neither adds an equation
+            ("arbitrary", "arbitrary"), Dv, kept, G)}
+    # with no window, no mask and a key head a query head, the kernel
+    # the parent built: none is an operand, and none adds an equation
     more = {} if window is None else {"window": int(window)}
-    if Hk != H:
+    if Hk != H and G == 1:
         more["group"] = H // Hk
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # grid rows a batch row, and a step's heads: an axis of q and of the
+    # output where they are several
+    R = H // G
+    several = (G,) if G > 1 else ()
 
     def q_at(b, i, lens):
         # no further than the row's last live block: a step that stores
         # zeros keeps the query block that is resident
-        return (b, jnp.minimum(i, jnp.maximum(
-            pl.cdiv(lens[b // H], block_q), 1) - 1), 0)
+        return (b,) + (0,) * len(several) + (jnp.minimum(i, jnp.maximum(
+            pl.cdiv(lens[b // R], block_q), 1) - 1), 0)
 
+    operands = [lengths, q.reshape((B * R,) + several + (T, D)),
+                k.reshape(B * Hk, T, D), v.reshape(B * Hk, T, Dv)]
+    in_specs = [pl.BlockSpec((1,) + several + (block_q, D), q_at), hbm, hbm]
+    scratch = [pltpu.VMEM((2, block_k, D), k.dtype),
+               pltpu.VMEM((2, block_k, Dv), v.dtype),
+               pltpu.SemaphoreType.DMA((3 if kept else 2, 2)),
+               pltpu.VMEM((G * block_q, _LANE), jnp.float32),
+               pltpu.VMEM((G * block_q, _LANE), jnp.float32),
+               pltpu.VMEM((G * block_q, Dv), jnp.float32),
+               pltpu.SMEM((1,), jnp.int32)]
+    kernel = _fwd_rows_kernel
+    if kept:
+        # the mask the last operand, its two windows the first scratch
+        kernel = _fwd_rows_kept_kernel
+        operands.append(keep)
+        in_specs.append(hbm)
+        scratch.insert(0, pltpu.VMEM((2, block_q, block_k), jnp.int8))
     out = pl.pallas_call(
-        functools.partial(_fwd_rows_kernel, scale=scale, block_q=block_q,
+        functools.partial(kernel, scale=scale, block_q=block_q,
                           block_k=block_k, sub_q=sub_q, sub_k=sub_k,
-                          heads=H, **more),
+                          heads=R, **more),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B * H, T // block_q),
-            in_specs=[pl.BlockSpec((1, block_q, D), q_at),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, block_q, Dv),
-                                   lambda b, i, lens: (b, i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, block_k, D), k.dtype),
-                pltpu.VMEM((2, block_k, Dv), v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((block_q, _LANE), jnp.float32),
-                pltpu.VMEM((block_q, _LANE), jnp.float32),
-                pltpu.VMEM((block_q, Dv), jnp.float32),
-                pltpu.SMEM((1,), jnp.int32),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
+            grid=(B * R, T // block_q),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1,) + several + (block_q, Dv),
+                lambda b, i, lens: (b,) + (0,) * len(several) + (i, 0)),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B * R,) + several + (T, Dv),
+                                       q.dtype),
         interpret=interpret,
         **kw,
-    )(lengths, q.reshape(B * H, T, D), k.reshape(B * Hk, T, D),
-      v.reshape(B * Hk, T, Dv))
+    )(*operands)
     return out.reshape(B, H, T, Dv)
 
 
@@ -787,7 +922,7 @@ def lane_tiles(n):
 
 
 def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
-                            block_k=None, window=None):
+                            block_k=None, window=None, keep=None):
     """Causal attention, the forward alone, for a caller that takes no
     gradient (a serving prefill): no ``custom_vjp``, no logsumexp
     written, and no derivative (JAX has none for the call).  q (B, H,
@@ -806,6 +941,18 @@ def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
     None the call builds the kernel it built before the operand
     existed (tests/test_pallas_attention.py holds its grid and
     operands).
+
+    ``keep`` (B, T, T) int8, or None: a selection mask, ``[b, t, s] !=
+    0`` where query ``t`` of row ``b`` attends to key ``s``, and to no
+    other (Keye-VL-2.0's learned selection, as
+    `indexed_attention.select_prefill` writes it).  The mask holds
+    causality: it marks no key past its query, and for every query
+    below the row's length at least one key.  It stays in HBM; a step
+    brings the window of it that belongs to each key block it walks
+    beside that block, and the walk stays dense to the diagonal: grid,
+    steps and products are those of the call without a mask.  With
+    None the call builds the kernel it built before the operand
+    existed, as with ``window``.
 
     ``lengths`` (B,) int32, a traced operand (None: all ``T``): row b
     holds ``lengths[b]`` real positions from 0.  Its queries at and past
@@ -831,15 +978,24 @@ def flash_attention_forward(q, k, v, lengths=None, *, scale, block_q=None,
     if window is not None and int(window) < 1:
         raise ValueError("flash_attention_forward: a window holds the "
                          f"query's own position at least, got {window}")
+    if keep is not None and (keep.shape != (B, T, T)
+                             or keep.dtype != jnp.int8):
+        raise ValueError(
+            f"flash_attention_forward: keep is int8 {(B, T, T)}, a row of "
+            f"keys for every query, got {keep.dtype} {keep.shape}")
+    if keep is not None and window is not None:
+        raise ValueError("flash_attention_forward: a mask holds its own "
+                         "band; keep and window are not combined")
     lengths = (jnp.full((B,), T, jnp.int32) if lengths is None
                else jnp.clip(lengths.astype(jnp.int32), 0, T))
 
-    def whole(x):
+    def whole(x, first=2):
         """x's positions and width up to whole lane tiles."""
-        more = [(0, lane_tiles(n) - n) for n in x.shape[2:]]
-        return jnp.pad(x, [(0, 0), (0, 0)] + more) if any(
-            m for _, m in more) else x
+        more = [(0, 0)] * first + [(0, lane_tiles(n) - n)
+                                   for n in x.shape[first:]]
+        return jnp.pad(x, more) if any(m for _, m in more) else x
 
     return _flash_rows_call(whole(q), whole(k), whole(v), float(scale),
-                            lengths, block_q, block_k,
-                            window)[:, :, :T, :Dv]
+                            lengths, block_q, block_k, window,
+                            None if keep is None else whole(keep, 1)
+                            )[:, :, :T, :Dv]

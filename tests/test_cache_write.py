@@ -479,14 +479,20 @@ def test_the_latent_stacks_kernel_compiles_for_a_v5e(one_chip):
 @pytest.mark.parametrize("kernel", ["select", "attend"])
 def test_the_selection_kernels_compile_for_a_v5e(one_chip, monkeypatch,
                                                  kernel):
-    """Keye-VL-2.0's two prefill kernels (`ops/indexed_attention.py`) at
-    the cell's real sizes, one row of 16,384 positions: Mosaic takes the
-    query block's 8 MB of keys in VMEM, the int8 mask and the 1,024-row
-    attention tile (interpret mode cannot say).  Kept in this file: one
-    worker describes the chip."""
-    from mxnet_tpu.ops import indexed_attention
+    """Keye-VL-2.0's two prefill kernels at the cell's real sizes, one
+    row of 16,384 positions: the selection (`ops/indexed_attention.py`:
+    Mosaic takes the query block's 8 MB of keys in VMEM and the int8
+    mask) and attention under it, the flash forward body with the mask
+    an operand (`ops/pallas_attention.py::flash_attention_forward(keep=)`:
+    32 query heads over 4 key heads, a key head's eight heads a step in
+    blocks of 512, so a grid of 4 x 32 steps where the kernel this file
+    compiled until PR 43 had 16,384; the step's 20 MB of VMEM are asked
+    for).  Interpret mode cannot say.  Kept in this file: one worker
+    describes the chip."""
+    from mxnet_tpu.ops import indexed_attention, pallas_attention
 
     monkeypatch.setattr(indexed_attention, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas_attention, "_use_interpret", lambda: False)
     S, bf = 16384, jnp.bfloat16
 
     def sds(shape, dtype=bf):
@@ -499,11 +505,21 @@ def test_the_selection_kernels_compile_for_a_v5e(one_chip, monkeypatch,
                 sds((1, 64, S)), sds((1,), jnp.int32))
         out = (1, S, S)
     else:
-        fn = indexed_attention.attend_prefill
-        args = (sds((1, 4, 8, S, 128)), sds((1, 4, S, 128)),
-                sds((1, 4, S, 128)), sds((1, S, S), jnp.int8),
-                sds((1,), jnp.int32))
-        out = (1, 4, 8, S, 128)
+        fn = lambda q, k, v, n, keep: \
+            pallas_attention.flash_attention_forward(q, k, v, n, scale=1.0,
+                                                     keep=keep)
+        args = (sds((1, 32, S, 128)), sds((1, 4, S, 128)),
+                sds((1, 4, S, 128)), sds((1,), jnp.int32),
+                sds((1, S, S), jnp.int8))
+        out = (1, 32, S, 128)
+        call, = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        block_q, block_k = pallas_attention._block_sizes(
+            S, 128, bf, "fwd", 128, True, 8)
+        assert (block_q, block_k) == (512, 512)
+        assert call.params["grid_mapping"].grid == (4, S // block_q)
+        assert [v.aval.shape for v in call.invars] == [
+            (1,), (4, 8, S, 128), (4, S, 128), (4, S, 128), (1, S, S)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info.shape == out

@@ -382,6 +382,39 @@ def test_no_retrace_after_warmup(served):
     assert eng.program_count() == len(eng.prefill_buckets) + 1
 
 
+def test_the_prefill_kernel_share_reaches_the_batchers_record(served):
+    """The prefill program's attention under the selection is the one
+    flash forward call a scanned layer makes
+    (`pallas_attention.flash_attention_forward(keep=)`, interpreted
+    here): ``prefill_attn_kernel_share`` is 1.0 in a served group's
+    timings and in each request's record, as Kimi-K2's, Ouro's and
+    Command A+'s is, and the decode program's tally holds no such call
+    (it attends through `cache_attention.attend_rows`)."""
+    from mxnet_tpu import telemetry
+
+    _, _, _, eng = served
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, 96, n) for n in (11, 5)]
+    _, timings = eng.serve_group(prompts, 2)
+    assert timings["bucket"] == [4, 16]
+    assert timings["prefill_attn_kernel_share"] == 1.0
+    assert dict(eng._program.block_attends[16]) == {"kernel": 1}
+    assert not eng._program.block_attends[1]
+    telemetry.reset()
+    batcher = serving.ContinuousBatcher(eng, max_delay_ms=150, max_batch=4)
+    try:
+        futs = [batcher.submit(p, 2) for p in prompts]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        batcher.close()
+    requests = telemetry.recent_requests()
+    assert len(requests) == 2
+    for r in requests:
+        telemetry.validate_record(r)
+        assert r["prefill_attn_kernel_share"] == 1.0
+
+
 @pytest.mark.parametrize("kind,S", [("prefill", 16), ("decode", 1)])
 def test_the_three_stacks_alias_their_inputs(served, kind, S):
     """Every array of the cache is written into its donated argument,

@@ -175,19 +175,26 @@ def test_forward_entry_takes_a_block_shorter_than_its_tiles(T, lengths,
             assert not out[b, :, n:].any(), f"row of {n}"
 
 
-def _band_ref(q, k, v, scale, window):
-    """The dense masked softmax in float32: query i over keys
-    ``i - window + 1 .. i``, query head h over key head ``h // group``."""
+def _masked_ref(q, k, v, scale, seen):
+    """The dense masked softmax in float32 over the keys ``seen`` marks
+    (it broadcasts against (B, H, T, T)), query head h over key head
+    ``h // group``; a query that sees no key comes out zero."""
     group = q.shape[1] // k.shape[1]
     q, k, v = (x.astype(F32) for x in (q, k, v))
     k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    precision=jax.lax.Precision.HIGHEST) * scale
-    i = jnp.arange(s.shape[-1])
-    seen = (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
-    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1),
+                  0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v,
                       precision=jax.lax.Precision.HIGHEST)
+
+
+def _band_ref(q, k, v, scale, window):
+    """Query i over keys ``i - window + 1 .. i``."""
+    i = jnp.arange(q.shape[2])
+    return _masked_ref(q, k, v, scale, (i[None, :] <= i[:, None])
+                       & (i[None, :] > i[:, None] - window))
 
 
 def _window_cases():
@@ -292,6 +299,122 @@ def test_no_window_builds_the_kernel_it_built_before():
         (1,), (H, T, D), (H // 2, T, D), (H // 2, T, D)]
 
 
+def test_no_mask_builds_the_kernels_the_parent_built():
+    """`flash_attention_forward(keep=None)` is Kimi-K2's, Ouro's and
+    Command A+'s prefill call, and training's `_flash_call` shares the
+    forward body: since the body learnt a selection mask (PR 43) the
+    plain, the windowed and the grouped call, with and without a
+    window, and training's forward and backward have the grid, the
+    operands and the number of equations they had on the parent (counted
+    there, commit c7ab047, at these shapes).  The mask is one more
+    operand, one more scratch and the heads of a key head in one step
+    only where a caller hands one."""
+    H, T, D = 4, 512, 128
+    x = jax.ShapeDtypeStruct((1, H, T, D), BF16)
+    half = jax.ShapeDtypeStruct((1, H // 2, T, D), BF16)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32)
+
+    def calls(fn, *args):
+        return [(c.params["grid_mapping"].grid,
+                 [v.aval.shape for v in c.invars],
+                 len(_eqns(c.params["jaxpr"], None, [])))
+                for c in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr, [])]
+
+    def entry(**kw):
+        return lambda q, k, v, n: pa.flash_attention_forward(
+            q, k, v, n, scale=1.0, block_q=128, block_k=128, **kw)
+
+    whole, halved = (H, T, D), (H // 2, T, D)
+    for kv, shapes, plain, banded in ((x, [whole] * 3, 352, 520),
+                                      (half, [whole, halved, halved], 412,
+                                       580)):
+        assert calls(entry(), x, kv, kv, n) == [
+            ((H, T // 128), [(1,)] + shapes, plain)]
+        assert calls(entry(keep=None, window=200), x, kv, kv, n) == [
+            ((H, T // 128), [(1,)] + shapes, banded)]
+    rows = (H, 8, T)
+    assert calls(jax.grad(lambda q, k, v: jnp.sum(pa.flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128).astype(F32)),
+        (0, 1, 2)), x, x, x) == [
+            ((H, T // 128, T // 128), [whole] * 3, 132),
+            ((H, T // 128, T // 128), [whole] * 4 + [rows] * 2, 131)]
+    # and with a mask: one more operand, a key head's heads a step
+    masked, = calls(entry(keep=jnp.zeros((1, T, T), jnp.int8)), x, half,
+                    half, n)
+    assert masked[:2] == ((H // 2, T // 128),
+                          [(1,), (H // 2, 2, T, D), halved, halved,
+                           (1, T, T)])
+
+
+KEPT_T = 384
+KEPT_LENGTHS = (1, 127, 128, 129, KEPT_T, 0)
+
+
+def _kept_mask(kind, lengths, T):
+    """(B, T, T) int8, causal, at least one key a query.  ``late``: a
+    query marks its own position and the 15 before it only, so a query
+    past the first block marks nothing in the first sub-tiles it
+    visits.  ``not_self``: every second earlier key and never its own
+    position (but query 0, which has no other).  ``topk``: what
+    `indexed_attention.select_prefill` writes for seeded indexer
+    projections, the 32 best of each query's earlier positions, each
+    row to its last position.  ``causal``: every earlier key."""
+    t, s = np.arange(T)[:, None], np.arange(T)[None, :]
+    if kind == "topk":
+        from mxnet_tpu.ops import indexed_attention
+        keys = jax.random.split(jax.random.key(7), 3)
+        B = len(lengths)
+        return indexed_attention.select_prefill(
+            jax.random.normal(keys[0], (B, 2, T, 8), F32),
+            jax.random.normal(keys[1], (B, T, 2), F32),
+            jax.random.normal(keys[2], (B, 8, T), F32),
+            jnp.asarray(lengths, jnp.int32) - 1, 32)
+    keep = {"late": (s <= t) & (s > t - 16),
+            "not_self": ((s < t) & (s % 2 == 0)) | ((t == 0) & (s == 0)),
+            "causal": s <= t}[kind]
+    return jnp.asarray(np.broadcast_to(keep, (len(lengths), T, T)),
+                       jnp.int8)
+
+
+@pytest.mark.parametrize("kind", ["late", "not_self", "topk", "causal"])
+@pytest.mark.parametrize("group", [8, 1], ids=["8-heads-a-key-head",
+                                               "a-key-head-a-head"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+def test_forward_entry_under_a_selection_mask(dtype, group, kind):
+    """`flash_attention_forward(keep=)` (Keye-VL-2.0's prefill) against
+    the dense softmax over the marked keys, in blocks of 128 so that a
+    query block walks several key blocks, each with its window of the
+    mask: rows of 1, 127, 128, 129, all and no positions; a key head's
+    eight query heads in one step and a head of its own; a query whose
+    first visited sub-tiles hold none of its selection and one that
+    does not select itself (their sums take exp(0) of what they do not
+    see and are wiped by the first key they do); the selection kernel's
+    own top-k mask, which marks keys for the padding queries of a row's
+    last block too.  Queries at and past a row's length come out zero."""
+    T, D, lengths = KEPT_T, 128, KEPT_LENGTHS
+    keys = jax.random.split(jax.random.key(group + len(kind)), 3)
+    Hk = 1 if group > 1 else 2
+    q, k, v = (jax.random.normal(kk, (len(lengths), h, T, D), dtype)
+               for kk, h in zip(keys, (group * Hk, Hk, Hk)))
+    keep = _kept_mask(kind, lengths, T)
+    out = jax.jit(lambda q, k, v, n, m: pa.flash_attention_forward(
+        q, k, v, n, scale=D ** -0.5, keep=m, block_q=128, block_k=128))(
+            q, k, v, jnp.asarray(lengths, jnp.int32), keep)
+    assert out.dtype == dtype and out.shape == q.shape
+    ref = np.asarray(_masked_ref(q, k, v, D ** -0.5,
+                                 (keep != 0)[:, None]))
+    out = np.asarray(out, np.float32)
+    rtol, atol = TOL[dtype]["fwd"]
+    if dtype == BF16:
+        # a softmax over 16 keys rounds few, large p to 8 bits: a unit
+        # in the last place of a value near 1
+        atol = 2.0 ** -7
+    for b, n in enumerate(lengths):
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=rtol,
+                                   atol=atol, err_msg=f"row of {n}")
+        assert not out[b, :, n:].any(), f"row of {n}"
+
+
 def test_what_the_entries_refuse():
     q, k, v = _wide_inputs(32, 16, F32, T=128, rows=1)
     with pytest.raises(ValueError, match="one width"):
@@ -306,6 +429,14 @@ def test_what_the_entries_refuse():
     with pytest.raises(ValueError, match="do not divide"):
         pa.flash_attention_forward(jnp.concatenate([q, q[:, :1]], axis=1),
                                    k, v, scale=1.0)
+    # a mask is a row of keys for every query of every batch row, int8
+    for keep in (jnp.ones((1, 128, 64), jnp.int8),
+                 jnp.ones((1, 128, 128), bool)):
+        with pytest.raises(ValueError, match="keep is int8"):
+            pa.flash_attention_forward(q, k, v, scale=1.0, keep=keep)
+    with pytest.raises(ValueError, match="its own band"):
+        pa.flash_attention_forward(q, k, v, scale=1.0, window=8,
+                                   keep=jnp.ones((1, 128, 128), jnp.int8))
 
 
 def _pallas_calls(jaxpr, found):
