@@ -1,7 +1,6 @@
 """What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`,
-`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`): the pieces of a layer and of
-a decoder program that do not depend on a family's attention or routing
-rule.  No family imports
+`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`): the pieces of a layer that do
+not depend on a family's attention or routing rule.  No family imports
 another; a change here is a change to all, and their cells measure it.
 
 - `mm`, `rms_norm`, `layer_norm`, `rope`: the mixed-precision product,
@@ -18,8 +17,12 @@ another; a change here is a change to all, and their cells measure it.
   experts; `moe_count_row`, `moe_counters`: an expert layer's counters in the
   donated carry and their read-back (docs/observability.md);
 - `by_rows`, `chunk_rows`: a prefill block worked off a few rows at a
-  time; `by_tokens`: a few positions at a time; `own_weights`: a
-  program's weight tuple.
+  time; `by_tokens`: a few positions at a time; `embed`: a block's way
+  in.
+
+What a decoder program is apart from its layers (its weights, its cache,
+the step's prologue, the cache write and the attention over the cache)
+is `_decoder_program.py`.
 """
 
 from __future__ import annotations
@@ -112,6 +115,22 @@ def yarn_frequencies(rot, base, factor, original, beta_fast, beta_slow):
     f = base ** (-2.0 * i / rot)
     ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
     return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
+
+
+def embed(table, toks, pos, last):
+    """A block's way in: ``toks`` (B, S) → the stream x (B, S, C)
+    float32 (an unscaled embedding), each token's position ``at`` (B, S)
+    from the rows' first positions ``pos`` (B,), and ``valid`` (B, S):
+    the tokens up to each row's ``last``, the others padding."""
+    import jax
+    import jax.numpy as jnp
+
+    S = toks.shape[1]
+    with jax.named_scope("serve.embed"):
+        x = jnp.take(table, toks, axis=0).astype(jnp.float32)
+        at = pos[:, None] + jnp.arange(S)[None, :]
+        valid = jnp.arange(S)[None, :] <= last[:, None]
+    return x, at, valid
 
 
 def attn_out(z, p, x, a):
@@ -299,16 +318,3 @@ def chunk_rows(z, B, S):
     while B % rows:
         rows -= 1
     return rows
-
-
-def own_weights(model, dtype):
-    """A program's weight tuple: the parameters' own buffers, in the
-    order of their names: no second copy, unless ``dtype`` asks for
-    another type than a parameter has."""
-    out = []
-    for n in model._names:
-        a = getattr(model, n).data()._data
-        if dtype is not None and a.dtype != dtype:
-            a = a.astype(dtype)
-        out.append(a)
-    return tuple(out)

@@ -31,10 +31,10 @@ An unscaled embedding, a final LayerNorm, and a **tied head**:
 ``logit_scale x LN(x) Eᵀ``, the embedding read as it lies.
 
 ``hybrid_forward`` is the uncached full-sequence forward.
-``decoder_program`` hands `serving.ServingEngine` the family's cached
-step (docs/serving.md, "The decoder program").  Two kinds of cache,
-four stacks, each carried, donated and written in place, positions on
-the minor axis (`ops/cache_write.py`):
+``decoder_program`` hands `serving.ServingEngine` the family's program
+(`_decoder_program.DecoderProgram`; docs/serving.md, "The decoder
+program"), of which this file states the cache's shapes and the layer
+body.  Two kinds of cache, four stacks:
 
 - full layers: keys and values ``(Lf, B, K, D, W)``; row b's block
   lands at ``pos[b] ..``;
@@ -63,9 +63,10 @@ row's queries are 537 MB and its shared experts' hidden 1.07 GB.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write, pallas_attention
+from ...ops import pallas_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
+from ._decoder_program import DecoderProgram
 
 _LAYER_LEAVES = ("ln_gamma", "q_weight", "k_weight", "v_weight", "o_weight",
                  "router_weight", "shared_gate_weight", "shared_up_weight",
@@ -324,61 +325,37 @@ class Cohere2MoeModel(HybridBlock):
         return Cohere2MoeProgram(self, dtype)
 
 
-class Cohere2MoeProgram:
-    """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
+class Cohere2MoeProgram(DecoderProgram):
+    """The family's decoder program (docs/serving.md,
+    `_decoder_program.py`): its cache's shapes, its layers and its head."""
 
     def __init__(self, model, dtype=None):
-        self._model = model
-        self._z = z = model._sizes
-        self._dtype = dtype
-        self.window = model._max_length
-        self.vocab = model._vocab
-        self._pins = None
-        # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the caches;
-        # block_attends[S]: its attention calls inside the block
-        self.cache_writes = {}
-        self.cache_reads = {}
-        self.block_attends = {}
+        super().__init__(model, dtype)
+        z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (tuple(z.layer_types), z.num_heads, z.kv_heads,
                           z.window, z.rope_theta, z.experts_held,
                           z.experts_per_token, z.shared_experts,
                           z.logit_scale)
 
-    def weights(self):
-        return _ops.own_weights(self._model, self._dtype)
-
-    def init_cache(self, B):
-        """(full keys, full values, window keys, window values, expert
-        counters, attention counters), zeroed, beside the embedding."""
+    def cache_shapes(self, B):
+        """(full keys, full values, window keys, window values), then
+        the expert and the attention counters."""
         import jax.numpy as jnp
 
         z = self._z
-        emb = self._model.embed_weight.data()._data
         Lf = max(1, len(z.of_kind["full"]))
         Lw = max(1, len(z.of_kind["window"]))
         K, D, L = z.kv_heads, z.head_dim, len(z.layer_types)
-        kv_dtype = self._dtype or emb.dtype
-
-        def zeros(shape, dtype=kv_dtype):
-            return jnp.zeros(shape, dtype, device=emb.sharding)
-
-        cache = (zeros((Lf, B, K, D, self.window)),
-                 zeros((Lf, B, K, D, self.window)),
-                 zeros((Lw, B, K, D, z.window)),
-                 zeros((Lw, B, K, D, z.window)),
-                 zeros((L, 2, z.experts_held[1] + 3), jnp.int32),
+        return ([((Lf, B, K, D, self.window), None),
+                 ((Lf, B, K, D, self.window), None),
+                 ((Lw, B, K, D, z.window), None),
+                 ((Lw, B, K, D, z.window), None)],
+                [((L, 2, z.experts_held[1] + 3), jnp.int32),
                  # [layer, asked / causal, prefill / decode]: a layer's
                  # pairs of an 8 x 16,384 prefill are 3.7e8, all
                  # layers' pass 2**31
-                 zeros((L, 2, 2), jnp.uint32))
-        if self._pins is None:
-            # each stack stays in the layout its donated buffer came in:
-            # read off an allocated cache, as GPT's program does
-            self._pins = [c.format.layout for c in cache[:4]]
-        return cache
+                 ((L, 2, 2), jnp.uint32)])
 
     def counters(self, cache):
         """The counters of one served group, read back once
@@ -402,73 +379,45 @@ class Cohere2MoeProgram:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache donated; pos (B,) each row's first position; last (B,)
-        the index in the block of each row's last real token; toks
-        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
-        S > 1 is a prefill from an empty cache: attention inside the
+    def body(self, ctx, w, cache, toks):
+        """S > 1 is a prefill from an empty cache: attention inside the
         block, a row chunk through all layers before the next.  S = 1
-        attends over the caches; there ``live`` (B,) bool marks the rows
-        that still want a token (None: all): another row attends to
-        nothing, goes to no routed expert and is counted nowhere."""
-        import collections
-
+        attends over the caches, a row that wants no token to nothing;
+        it goes to no routed expert and is counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        from jax.experimental.layout import with_layout_constraint
-
         z = self._z
-        w = dict(zip(self._model._names, w))
-        pins = self._pins      # `init_cache` read them off a real cache
-        B, S = toks.shape
-        decode = S == 1
-        given = live    # as handed: None from the prefill, whose write takes none
-        if live is None:
-            live = jnp.ones((B,), bool)
+        B, decode, live = ctx.B, ctx.decode, ctx.live
         R, D, n_held = z.window, z.head_dim, z.experts_held[1]
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
-        attends = self.block_attends[S] = collections.Counter()
 
-        def write(stacks, kind, k, v, l, held, row):
+        def write(stacks, kind, k, v, l, pos, held, row):
             """Layer ``l``'s new keys and values (R, K, S', D) into its
-            kind's two stacks, each kept in its layout: a full layer's
-            at ``pos``, a ring's at ``pos mod window`` (decode) or as
-            the ring a prefilled row of ``held`` positions leaves."""
+            kind's two stacks: a full layer's at ``pos``, a ring's at
+            ``pos mod window`` (decode) or as the ring a prefilled row of
+            ``held`` positions leaves."""
             fk, fv, wk, wv = stacks
             new = [a.astype(fk.dtype).swapaxes(2, 3) for a in (k, v)]
             with jax.named_scope("serve.cache_write"):
                 if kind == "full":
-                    out = cache_write.write_rows((fk, fv), new, l, pos,
-                                                 tally=tally, row=row,
-                                                 live=given)
-                elif decode:
-                    out = cache_write.write_rows((wk, wv), new, l, pos % R,
-                                                 tally=tally, live=given)
-                else:
-                    out = cache_write.write_ring((wk, wv), new, l, held,
-                                                 tally=tally, row=row)
-            pin = pins[:2] if kind == "full" else pins[2:]
-            out = [c if p is None else with_layout_constraint(c, p)
-                   for c, p in zip(out, pin)]
-            return (*out, wk, wv) if kind == "full" else (fk, fv, *out)
+                    return (*ctx.write((fk, fv), new, l, pos, row=row),
+                            wk, wv)
+                if decode:
+                    return (fk, fv, *ctx.write((wk, wv), new, l, pos % R,
+                                               first=2))
+                return (fk, fv, *ctx.write_ring((wk, wv), new, l, held,
+                                                row=row, first=2))
 
         def rows(toks, pos, last, row, carry):
             """Rows ``row ..`` of the group through every layer; carry
             (the four stacks, expert counters, attention counters).
             Returns (carry, the rows' logits)."""
             *stacks, moe_counts, pairs = carry
-            S = toks.shape[1]
-            with jax.named_scope("serve.embed"):
-                x = jnp.take(w["embed_weight"], toks, axis=0
-                             ).astype(jnp.float32)
-                at = pos[:, None] + jnp.arange(S)[None, :]
-                valid = jnp.arange(S)[None, :] <= last[:, None]
+            x, at, valid = _ops.embed(w["embed_weight"], toks, pos, last)
             # a row's positions, itself included: all of them in a
             # prefill, none of a decode row that wants no token
-            held = jnp.where(live, pos + 1, 0) if decode else last + 1
+            held = ctx.held if decode else last + 1
             n = held.astype(jnp.uint32)
             for i, kind in enumerate(z.layer_types):
                 p = {name: w[f"l{i}_{name}"] for name in _LAYER_LEAVES}
@@ -481,13 +430,13 @@ class Cohere2MoeProgram:
                         jnp.sum(n)
                     h = _norm(z, p, x)
                     q, k, v = _qkv(z, kind, p, h, at)
-                    stacks = write(stacks, kind, k, v, l, held, None)
+                    stacks = write(stacks, kind, k, v, l, pos, held, None)
                     with jax.named_scope(f"serve.attn_{kind}"):
                         ck, cv = stacks[:2] if kind == "full" else stacks[2:]
-                        a = cache_attention.attend_rows(
+                        a = ctx.attend(
                             (q[:, :, 0] * D ** -0.5).astype(ck.dtype).reshape(
                                 B, z.kv_heads, z.groups, D),
-                            ck, cv, l, seen, tally=reads)
+                            ck, cv, l, lengths=seen)
                     with jax.named_scope("serve.attn_out"):
                         a = _ops.mm("bg,cg->bc", a.reshape(B, -1),
                                     p["o_weight"])[:, None]
@@ -501,8 +450,8 @@ class Cohere2MoeProgram:
                         m = jnp.minimum(n, R)
                         asked = jnp.sum(m * (m + 1) // 2 + (n - m) * R)
                     x, k, v, stats = _block_layer(z, kind, p, x, at, held,
-                                                  valid, attends)
-                    stacks = write(stacks, kind, k, v, l, held, row)
+                                                  valid, ctx.attends)
+                    stacks = write(stacks, kind, k, v, l, pos, held, row)
                 moe_counts = moe_counts.at[i, int(decode)].add(
                     _ops.moe_count_row(stats, n_held))
                 pairs = pairs.at[i, :, int(decode)].add(
@@ -512,16 +461,16 @@ class Cohere2MoeProgram:
                     x, last[:, None, None], axis=1)[:, 0])
             return (*stacks, moe_counts, pairs), logits
 
-        Rows = B if decode else _ops.chunk_rows(z, B, S)
+        Rows = B if decode else _ops.chunk_rows(z, B, ctx.S)
         if Rows == B:
-            return rows(toks, pos, last, None, tuple(cache))
+            return rows(toks, ctx.pos, ctx.last, None, tuple(cache))
 
         def chunk(c, state):
             carry, logits = state
             cut = lambda a: lax.dynamic_slice_in_dim(a, c * Rows, Rows,
                                                      axis=0)
-            carry, part = rows(cut(toks), cut(pos), cut(last), c * Rows,
-                               carry)
+            carry, part = rows(cut(toks), cut(ctx.pos), cut(ctx.last),
+                               c * Rows, carry)
             return carry, lax.dynamic_update_slice_in_dim(
                 logits, part, c * Rows, axis=0)
 

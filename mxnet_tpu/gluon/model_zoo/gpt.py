@@ -16,9 +16,10 @@ TPU-first trunk primitives:
 
 from __future__ import annotations
 
-from ...ops import cache_attention, cache_write
+from ...ops.sampling import _sample
 from ..block import HybridBlock
 from .. import nn
+from ._decoder_program import DecoderProgram
 from .bert import ScanTransformerEncoder, TransformerEncoder
 
 
@@ -111,24 +112,6 @@ def _windowed_last_logits(model, flat, nd_mod, np_mod):
             axis=1)
     logits = model(nd_mod.array(ctx.astype(np_mod.float32))).asnumpy()
     return logits[:, cur - 1]
-
-
-def _sample(last, temperature, rng):
-    """Pick next tokens from (B, vocab) logits: greedy, or softmax
-    sampling at the given temperature (one home for both decode paths).
-    The greedy branch uses array methods only, so it also traces: the
-    serving programs pick their token with it on the device
-    (serving/engine.py::_make_step)."""
-    import numpy as np
-
-    if temperature:
-        z = last / temperature
-        z = z - z.max(axis=-1, keepdims=True)
-        p = np.exp(z) / np.exp(z).sum(axis=-1, keepdims=True)
-        rng = rng or np.random.default_rng()
-        return np.stack([rng.choice(p.shape[-1], p=row)
-                         for row in p]).astype(np.int32)
-    return last.argmax(axis=-1).astype(np.int32)
 
 
 def generate(model, ids, max_new_tokens=16, temperature=None, rng=None):
@@ -282,10 +265,9 @@ def stacks_from_state(state):
             get1("tok_embed_weight"), get1("pos_embed_weight"))
 
 
-class GPTDecoderProgram:
+class GPTDecoderProgram(DecoderProgram):
     """GPT's decoder program for `serving.ServingEngine`
-    (docs/serving.md): ``weights()``, ``init_cache(B)``,
-    ``step(w, cache, pos, last, toks)``.
+    (docs/serving.md, `_decoder_program.py`).
 
     The cache is one ``(L, B, H, Dh, W)`` pair, stage-major like the
     ``*_stack_*`` weights and position-minor, which is how a v5e stores
@@ -294,25 +276,22 @@ class GPTDecoderProgram:
     writes its new ``(B, H, Dh, S)`` rows into the stack
     (`ops/cache_write.py`) and attends over its own slice of it: a
     prefill block over the whole window, a decode step through
-    `ops/cache_attention.py`, each row to its own length.
-    ``cache_writes[S]`` counts, at trace time, the row writes of the
-    block-``S`` step by the path they took, ``cache_reads[S]`` its
-    attention calls over the cache.  Under a ``mesh`` the weight
-    stacks follow the Megatron column/row split of TRANSFORMER_TP_RULES
-    and the cache shards on its head axis
-    (parallel/sharding.serving_cache_sharding).
+    `ops/cache_attention.py`, each row to its own length.  Under a
+    ``mesh`` the weight stacks follow the Megatron column/row split of
+    TRANSFORMER_TP_RULES and the cache shards on its head axis
+    (parallel/sharding.serving_cache_sharding).  The weights are a
+    packed tuple of this family's own (`_prepare`), not the parameters'
+    buffers.
     """
 
     def __init__(self, model, dtype=None, mesh=None, tp_axis="tp"):
         from ...base import MXNetError
 
-        self._mesh, self._tp_axis, self._dtype = mesh, tp_axis, dtype
-        self.window = model._max_length
+        super().__init__(model, dtype, mesh, tp_axis)
         (stacks, lnf, tok, pos, self._H,
          self._act) = extract_decoder_stacks(model)
         self._C = int(tok.shape[1])
         self._L = int(stacks["qkv_stack_weight"].shape[0])
-        self.vocab = int(tok.shape[0])
         # what a reloaded model must share beyond its shapes
         self.signature = (self._H, self._act)
         if mesh is not None:
@@ -323,11 +302,6 @@ class GPTDecoderProgram:
                     f"ServingEngine: tp axis size {n_tp} must divide "
                     f"num_heads={self._H} and ffn hidden={F}")
         self._w = self._prepare(stacks, lnf, tok, pos)
-        # how this platform lays a cache out on the device: read off
-        # one, not assumed
-        self._cache_layout = self.init_cache(1)[0].format.layout
-        self.cache_writes = {}
-        self.cache_reads = {}
 
     # -- weights ---------------------------------------------------------------
 
@@ -377,6 +351,12 @@ class GPTDecoderProgram:
     def weights(self):
         return self._w
 
+    def _named(self, w):
+        return w        # the packed tuple, as `body` unpacks it
+
+    def _embedding(self):
+        return self._w[0]
+
     def weights_from_state(self, state):
         """The weight tuple of an AsyncCheckpointer state dict
         (``serving.state_for_serving`` convention)."""
@@ -394,72 +374,32 @@ class GPTDecoderProgram:
 
     # -- cache -----------------------------------------------------------------
 
-    def _cache_sharding(self):
-        from ...parallel.sharding import serving_cache_sharding
-
-        return serving_cache_sharding(self._mesh, tp_axis=self._tp_axis)
-
-    def init_cache(self, B):
-        """Fresh zeroed (ck, cv) for batch bucket B: stage-major and
-        position-minor (L, B, H, Dh, W), serving dtype, head-sharded
-        under tp."""
-        import jax.numpy as jnp
-
-        tok = self._w[0]
+    def cache_shapes(self, B):
+        """(ck, cv): stage-major and position-minor (L, B, H, Dh, W)."""
         shape = (self._L, B, self._H, self._C // self._H, self.window)
-        # committed next to the weights: the engine serves from the
-        # device(s) the model was placed on, never from the process
-        # default
-        where = tok.sharding if self._mesh is None \
-            else self._cache_sharding()
-        return (jnp.zeros(shape, tok.dtype, device=where),
-                jnp.zeros(shape, tok.dtype, device=where))
+        return [(shape, None), (shape, None)], []
 
     # -- the traced block step -------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache = (ck, cv), each (L, B, H, Dh, W), donated; pos (B,)
-        per-row write offsets; last (B,) the index in the block of each
-        row's last real token; toks (B, S) int32.  Returns ((ck', cv'),
-        logits (B, vocab)) at ``last``.  S = seq bucket for prefill, 1
-        for decode, where ``live`` (B,) bool marks the rows that still
-        want a token (None: all): another row attends to nothing."""
-        import collections
-
+    def body(self, ctx, w, cache, toks):
+        """cache = (ck, cv), each (L, B, H, Dh, W), donated.  Returns
+        ((ck', cv'), logits (B, vocab)) at ``ctx.last``.  S = seq bucket
+        for prefill, 1 for decode, where a row that wants no token
+        attends to nothing."""
         import jax
         import jax.numpy as jnp
         from jax import lax
-
-        from jax.experimental.layout import with_layout_constraint
 
         from ...ops.nn import layer_norm
 
         H, W = self._H, self.window
         Dh = self._C // H
         act = self._act
-        mesh = self._mesh
-        cache_ns = self._cache_sharding() if mesh is not None else None
-        cache_layout = self._cache_layout
-
-        def keep_layout(c):
-            return with_layout_constraint(c, cache_layout)
-
-        if mesh is not None:
-            # the constraint has no partitioning rule (the partitioner
-            # would gather the cache to apply it), so each shard pins
-            # its own
-            keep_layout = jax.shard_map(
-                keep_layout, mesh=mesh, in_specs=cache_ns.spec,
-                out_specs=cache_ns.spec)
+        pos, last, S = ctx.pos, ctx.last, ctx.S
 
         ck, cv = cache
         (tok_e, pos_e, qkvw, qkvb, pwh, pb, f1w, f1b, f2w, f2b,
          g1s, b1s, g2s, b2s, lnf_g, lnf_b) = w
-        B, S = toks.shape
-        # a decode step's positions a row, itself included
-        held = pos + 1 if live is None else jnp.where(live, pos + 1, 0)
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
         with jax.named_scope("serve.embed"):
             positions = pos[:, None] + jnp.arange(S)[None, :]  # (B, S)
             x = (jnp.take(tok_e, toks, axis=0) +
@@ -479,24 +419,17 @@ class GPTDecoderProgram:
             with jax.named_scope("serve.cache_write"):
                 # row b's block at [l, b, :, :, pos[b]:pos[b] + S] and
                 # nothing else (a start that would run past W is
-                # clamped).  The stacks stay in the layout the donated
-                # buffers came in: left to itself the TPU compiler
-                # re-lays the whole cache around the loop to make row
-                # writes cheaper
-                ck, cv = (keep_layout(c) for c in cache_write.write_rows(
-                    (ck, cv), (kh, vh), l, pos, mesh=mesh, tally=tally,
-                    live=live))
+                # clamped)
+                ck, cv = ctx.write((ck, cv), (kh, vh), l, pos)
             with jax.named_scope("serve.attn"):
                 # row b at block offset s may see cache slots
                 # <= pos[b] + s (stale pad garbage beyond is invisible
                 # — the overwrite-before-attend invariant)
-                if S == 1:
+                if ctx.decode:
                     # one position a row: (B, H, 1, Dh) is one query a
                     # key head, over the row's pos[b] + 1 positions (a
                     # row that wants no token: none, and zeros)
-                    attn = cache_attention.attend_rows(
-                        qh * (Dh ** -0.5), ck, cv, l, held, mesh=mesh,
-                        tally=reads)
+                    attn = ctx.attend(qh * (Dh ** -0.5), ck, cv, l)
                 else:
                     ck_l = lax.dynamic_index_in_dim(ck, l, 0,
                                                     keepdims=False)
@@ -532,9 +465,10 @@ class GPTDecoderProgram:
             # the head reads one position a row: the last real token's
             h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
             logits = layer_norm(h, lnf_g, lnf_b) @ tok_e.T
-        if cache_ns is not None:
+        if self._mesh is not None:
             # pin the donated buffers' output layout to the input
             # layout, so the next AOT call sees identical shardings
+            cache_ns = self._cache_sharding()
             ck2 = lax.with_sharding_constraint(ck2, cache_ns)
             cv2 = lax.with_sharding_constraint(cv2, cache_ns)
         return (ck2, cv2), logits

@@ -19,12 +19,12 @@ decoder whose every layer has
 Layers are alike, so the parameters are stacked by layer
 (``q_weight`` is ``(L, heads * head_dim, units)``) and both passes scan
 them.  ``hybrid_forward`` is the uncached full-sequence forward.
-``decoder_program`` hands `serving.ServingEngine` the family's cached
-step (docs/serving.md, "The decoder program").  Its cache holds **three
-kinds of stack**, position-minor, each carried, donated and written in
-place by `ops/cache_write.py`: keys and values ``(L, B, Hkv, head_dim,
-W)`` and the indexer's keys ``(L, B, 1, index_dim, W)``; two small
-counter arrays ride in the same carry (``counters``).
+``decoder_program`` hands `serving.ServingEngine` the family's program
+(`_decoder_program.DecoderProgram`; docs/serving.md, "The decoder
+program"), of which this file states the cache's shapes and the layer
+body.  Its cache holds **three kinds of stack**: keys and values ``(L,
+B, Hkv, head_dim, W)`` and the indexer's keys ``(L, B, 1, index_dim,
+W)``; two small counter arrays ride in the same carry (``counters``).
 
 Prefill (a block of S positions from position 0) works a row chunk at a
 time: the selection kernel turns the indexer's scores into an int8 mask
@@ -44,10 +44,10 @@ in `_decoder_ops.py`, which both import; neither imports the other.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import (cache_attention, cache_write, indexed_attention,
-                    pallas_attention)
+from ...ops import indexed_attention, pallas_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
+from ._decoder_program import DecoderProgram
 
 _LAYER_LEAVES = ("ln1_gamma", "q_weight", "k_weight", "v_weight", "o_weight",
                  "q_norm_gamma", "k_norm_gamma", "index_q_weight",
@@ -284,55 +284,31 @@ class KeyeVL2Model(HybridBlock):
         return KeyeVL2Program(self, dtype)
 
 
-class KeyeVL2Program:
-    """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
+class KeyeVL2Program(DecoderProgram):
+    """The family's decoder program (docs/serving.md,
+    `_decoder_program.py`): its cache's shapes, its layers and its head."""
 
     def __init__(self, model, dtype=None):
-        self._model = model
-        self._z = z = model._sizes
-        self._dtype = dtype
-        self.window = model._max_length
-        self.vocab = model._vocab
-        self._pins = None
-        # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the cache;
-        # block_attends[S]: its attention calls inside the block
-        self.cache_writes = {}
-        self.cache_reads = {}
-        self.block_attends = {}
+        super().__init__(model, dtype)
+        z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (z.num_heads, z.kv_heads, z.index_heads, z.topk,
                           z.experts_held, z.experts_per_token, z.rope_theta)
 
-    def weights(self):
-        return _ops.own_weights(self._model, self._dtype)
-
-    def init_cache(self, B):
-        """(keys, values, indexer keys, expert counters, attention
-        counters), zeroed, beside the embedding."""
+    def cache_shapes(self, B):
+        """(keys, values, indexer keys), then the expert and the
+        attention counters."""
         import jax.numpy as jnp
 
         z = self._z
-        emb = self._model.embed_weight.data()._data
         L, W = z.num_layers, self.window
-        kv_dtype = self._dtype or emb.dtype
-
-        def zeros(shape, dtype=kv_dtype):
-            return jnp.zeros(shape, dtype, device=emb.sharding)
-
-        cache = (zeros((L, B, z.kv_heads, z.head_dim, W)),
-                 zeros((L, B, z.kv_heads, z.head_dim, W)),
-                 zeros((L, B, 1, z.index_dim, W)),
-                 zeros((L, 2, z.experts_held[1] + 3), jnp.int32),
+        return ([((L, B, z.kv_heads, z.head_dim, W), None),
+                 ((L, B, z.kv_heads, z.head_dim, W), None),
+                 ((L, B, 1, z.index_dim, W), None)],
+                [((L, 2, z.experts_held[1] + 3), jnp.int32),
                  # [layer, prefill / decode, live / selected]: a layer's
                  # live keys of a 16 x 16,384 prefill pass 2**31
-                 zeros((L, 2, 2), jnp.uint32))
-        if self._pins is None:
-            # each stack stays in the layout its donated buffer came in:
-            # read off an allocated cache, as GPT's program does
-            self._pins = [c.format.layout for c in cache[:3]]
-        return cache
+                 ((L, 2, 2), jnp.uint32)])
 
     def counters(self, cache):
         """The counters of one served group, read back once
@@ -350,54 +326,28 @@ class KeyeVL2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache donated; pos (B,) each row's first position; last (B,)
-        the index in the block of each row's last real token; toks
-        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
-        S > 1 is a prefill from an empty cache: it attends inside the
-        block.  S = 1 attends over the caches; there ``live`` (B,) bool
-        marks the rows that still want a token (None: all): another row
-        attends to nothing, goes to no expert and is counted nowhere."""
-        import collections
-
+    def body(self, ctx, w, cache, toks):
+        """S > 1 is a prefill from an empty cache: it attends inside the
+        block.  S = 1 attends over the caches, a row that wants no token
+        to nothing; it goes to no expert and is counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        from jax.experimental.layout import with_layout_constraint
-
         z = self._z
-        w = dict(zip(self._model._names, w))
-        pins = self._pins      # `init_cache` read them off a real cache
-        B, S = toks.shape
-        decode = S == 1
-        given = live    # as handed: None from the prefill, whose write takes none
-        if live is None:
-            live = jnp.ones((B,), bool)
-        # a decode step's positions a row, itself included
-        held = jnp.where(live, pos + 1, 0)
+        pos, last, live, held = ctx.pos, ctx.last, ctx.live, ctx.held
+        decode = ctx.decode
         W, n = self.window, z.experts_held[1]
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
-        attends = self.block_attends[S] = collections.Counter()
-        with jax.named_scope("serve.embed"):
-            x = jnp.take(w["embed_weight"], toks, axis=0
-                         ).astype(jnp.float32)
-            at = pos[:, None] + jnp.arange(S)[None, :]            # (B, S)
-            valid = jnp.arange(S)[None, :] <= last[:, None]
-        rows = _ops.chunk_rows(z, B, S)
+        x, at, valid = _ops.embed(w["embed_weight"], toks, pos, last)
+        rows = _ops.chunk_rows(z, ctx.B, ctx.S)
 
         def write(stacks, k, v, ki, l):
             """Row b's new keys, values and indexer keys (.., S, D) into
-            the three stacks at [l, b, :, :, pos[b]:], each kept in its
-            layout."""
+            the three stacks at [l, b, :, :, pos[b]:]."""
             with jax.named_scope("serve.cache_write"):
-                out = cache_write.write_rows(
+                return ctx.write(
                     stacks, [k.swapaxes(2, 3), v.swapaxes(2, 3),
-                             ki.swapaxes(1, 2)[:, None]], l, pos,
-                    tally=tally, live=given)
-                return [c if p is None else with_layout_constraint(c, p)
-                        for c, p in zip(out, pins)]
+                             ki.swapaxes(1, 2)[:, None]], l, pos)
 
         def of_layer(c, l):
             return lax.dynamic_index_in_dim(c, l, 0, keepdims=False)
@@ -413,8 +363,7 @@ class KeyeVL2Program:
                 written = jnp.arange(W)[None, :] <= pos[:, None]
                 mask = indexed_attention.select_topk(index, written, z.topk)
             with jax.named_scope("serve.attn_sparse"):
-                a = cache_attention.attend_rows(
-                    q[:, :, :, 0], ck, cv, l, held, mask=mask, tally=reads)
+                a = ctx.attend(q[:, :, :, 0], ck, cv, l, mask=mask)
             with jax.named_scope("serve.attn_out"):
                 x = _ops.attn_out(z, p, x, a[:, :, :, None])
             seen = jnp.stack([jnp.sum(held),
@@ -424,7 +373,7 @@ class KeyeVL2Program:
         def prefill_layer(x, stacks, p, l):
             x, (k, v, ki, route, selected) = _ops.by_rows(
                 lambda x, at, last: _block_layer(z, p, x, at, last,
-                                                  attends),
+                                                  ctx.attends),
                 rows, x, at, last)
             n_live = (last + 1).astype(jnp.uint32)
             seen = jnp.stack([jnp.sum(n_live * (n_live + 1) // 2),
@@ -449,12 +398,12 @@ class KeyeVL2Program:
         # the stacks are carried, not scanned (a scanned output is a new
         # stacked buffer); the weights are scanned
         (x, stacks, moe_counts, attn_counts), _ = lax.scan(
-            layer, (x, list(cache[:3]), cache[3], cache[4]), _scanned(z, w))
+            layer, (x, tuple(cache[:3]), cache[3], cache[4]), _scanned(z, w))
         with jax.named_scope("serve.head"):
             h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
             logits = _ops.mm("bc,vc->bv", _ops.rms_norm(h, w["lnf_gamma"], z.eps),
                          w["head_weight"])
-        return tuple(stacks) + (moe_counts, attn_counts), logits
+        return stacks + (moe_counts, attn_counts), logits
 
 
 def keye_vl2_tiny(**kwargs):

@@ -22,13 +22,13 @@ language model: a decoder whose every layer has
 Layer 0 has parameters of its own (``l0_*``); the expert layers are
 alike, stacked by layer and scanned.  ``hybrid_forward`` is the uncached
 full-sequence forward by the expanded equations.  ``decoder_program``
-hands `serving.ServingEngine` the family's cached step
-(docs/serving.md, "The decoder program").  Its cache is **one stack
-with no heads**, ``(L, B, 1, kv_rank + rope_dim, W)``, position-minor,
-carried, donated and written in place by `ops/cache_write.py`: a
-position's ``[c ; k_r]``, 1,152 B in bfloat16 at the published sizes
-where 64 heads of keys and values would take 40,960 B.  Two counter
-arrays ride in the same carry (``counters``).
+hands `serving.ServingEngine` the family's program
+(`_decoder_program.DecoderProgram`; docs/serving.md, "The decoder
+program"), of which this file states the cache's shapes and the layer
+body.  Its cache is **one stack with no heads**, ``(L, B, 1, kv_rank +
+rope_dim, W)``: a position's ``[c ; k_r]``, 1,152 B in bfloat16 at the
+published sizes where 64 heads of keys and values would take 40,960 B.
+Two counter arrays ride in the same carry (``counters``).
 
 **Two attention paths in one program.**  Decode (S = 1) is *absorbed*:
 it never expands the cache.  ``W_uk`` is folded into the query
@@ -58,9 +58,10 @@ at 819 GB/s is 68 ms of a prefill of seconds.
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write, pallas_attention
+from ...ops import pallas_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
+from ._decoder_program import DecoderProgram
 
 _ATTN_LEAVES = ("ln1_gamma", "q_down_weight", "q_norm_gamma", "q_up_weight",
                 "kv_down_weight", "kv_norm_gamma", "kv_up_weight",
@@ -416,23 +417,13 @@ class KimiK2Model(HybridBlock):
         return KimiK2Program(self, dtype)
 
 
-class KimiK2Program:
-    """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
+class KimiK2Program(DecoderProgram):
+    """The family's decoder program (docs/serving.md,
+    `_decoder_program.py`): its cache's shapes, its layers and its head."""
 
     def __init__(self, model, dtype=None):
-        self._model = model
-        self._z = z = model._sizes
-        self._dtype = dtype
-        self.window = model._max_length
-        self.vocab = model._vocab
-        self._pin = None
-        # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the cache;
-        # block_attends[S]: its attention calls inside the block
-        self.cache_writes = {}
-        self.cache_reads = {}
-        self.block_attends = {}
+        super().__init__(model, dtype)
+        z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (
             z.num_heads, z.nope_dim, z.rope_dim, z.experts_held,
@@ -440,33 +431,19 @@ class KimiK2Program:
             z.rope_original_length, z.beta_fast, z.beta_slow, z.mscale,
             z.mscale_all_dim)
 
-    def weights(self):
-        return _ops.own_weights(self._model, self._dtype)
-
-    def init_cache(self, B):
-        """(the latent stack, expert counters, attention counters),
-        zeroed, beside the embedding: ``L x B x (kv_rank + rope_dim) x
-        W`` elements for attention and no more."""
+    def cache_shapes(self, B):
+        """The latent stack, ``L x B x (kv_rank + rope_dim) x W``
+        elements for attention and no more; then the expert and the
+        attention counters."""
         import jax.numpy as jnp
 
         z = self._z
-        emb = self._model.embed_weight.data()._data
         L = z.num_layers
-
-        def zeros(shape, dtype):
-            return jnp.zeros(shape, dtype, device=emb.sharding)
-
-        cache = (zeros((L, B, 1, z.latent, self.window),
-                       self._dtype or emb.dtype),
-                 zeros((L - 1, 2, z.experts_held[1] + 3), jnp.int32),
+        return ([((L, B, 1, z.latent, self.window), None)],
+                [((L - 1, 2, z.experts_held[1] + 3), jnp.int32),
                  # [layer, prefill / decode]: a layer's positions of an
                  # 8 x 16,384 prefill are 3.7e8, all layers' pass 2**31
-                 zeros((L, 2), jnp.uint32))
-        if self._pin is None:
-            # the stack stays in the layout its donated buffer came in:
-            # read off an allocated cache, as GPT's program does
-            self._pin = cache[0].format.layout
-        return cache
+                 ((L, 2), jnp.uint32)])
 
     def counters(self, cache):
         """The counters of one served group, read back once
@@ -483,58 +460,33 @@ class KimiK2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache donated; pos (B,) each row's first position; last (B,)
-        the index in the block of each row's last real token; toks
-        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
-        S > 1 is a prefill from an empty cache: expanded attention
+    def body(self, ctx, w, cache, toks):
+        """S > 1 is a prefill from an empty cache: expanded attention
         inside the block, a row chunk through all layers before the
-        next.  S = 1 is absorbed attention over the latent stack; there
-        ``live`` (B,) bool marks the rows that still want a token (None:
-        all): another row attends to nothing, goes to no routed expert
-        and is counted nowhere."""
-        import collections
-
+        next.  S = 1 is absorbed attention over the latent stack, a row
+        that wants no token to nothing; it goes to no routed expert and
+        is counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        from jax.experimental.layout import with_layout_constraint
-
         z = self._z
-        w = dict(zip(self._model._names, w))
-        pin = self._pin        # `init_cache` read it off a real cache
-        B, S = toks.shape
-        decode = S == 1
-        given = live    # as handed: None from the prefill, whose write takes none
-        if live is None:
-            live = jnp.ones((B,), bool)
-        # a decode step's positions a row, itself included
-        held = jnp.where(live, pos + 1, 0)
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
-        attends = self.block_attends[S] = collections.Counter()
+        B, decode, live, held = ctx.B, ctx.decode, ctx.live, ctx.held
 
         def write(stack, latent, l, at, row):
             """The rows' latents (R, S, .) into the stack at [l, row + r,
-            0, :, at[r]:], kept in its layout."""
+            0, :, at[r]:]."""
             with jax.named_scope("serve.cache_write"):
-                stack, = cache_write.write_rows(
+                stack, = ctx.write(
                     [stack], [latent.swapaxes(1, 2)[:, None]], l, at,
-                    tally=tally, row=row, live=given)
-                return stack if pin is None else \
-                    with_layout_constraint(stack, pin)
+                    row=row)
+                return stack
 
         def rows(toks, pos, last, row, carry):
             """Rows ``row ..`` of the group through every layer; carry
             (stack, attention counters, expert counters).  Returns
             (carry, the rows' logits)."""
-            S = toks.shape[1]
-            with jax.named_scope("serve.embed"):
-                x = jnp.take(w["embed_weight"], toks, axis=0
-                             ).astype(jnp.float32)
-                at = pos[:, None] + jnp.arange(S)[None, :]
-                valid = jnp.arange(S)[None, :] <= last[:, None]
+            x, at, valid = _ops.embed(w["embed_weight"], toks, pos, last)
 
             def attend(x, carry, p, l):
                 stack, seen = carry
@@ -543,15 +495,14 @@ class KimiK2Program:
                     stack = write(stack, latent, l, pos, None)
                     q = _absorbed_query(z, p, cq, at)
                     with jax.named_scope("serve.attn_latent"):
-                        a = cache_attention.attend_rows(
-                            q, stack, None, l, held, tally=reads,
-                            leading=z.kv_rank)
+                        a = ctx.attend(q, stack, None, l,
+                                       leading=z.kv_rank)
                     x, route = _feed_forward_front(
                         z, p, _absorbed_out(z, p, x, a))
                     n_seen = jnp.sum(held)
                 else:
                     x, latent, route = _block_layer(z, p, x, at, last + 1,
-                                                    attends)
+                                                    ctx.attends)
                     stack = write(stack, latent, l, pos, row)
                     n_live = (last + 1).astype(jnp.uint32)
                     n_seen = jnp.sum(n_live * (n_live + 1) // 2)
@@ -572,16 +523,17 @@ class KimiK2Program:
             return (stack, seen, moe_counts), logits
 
         stack, moe_counts, seen = cache
-        R = B if decode else _ops.chunk_rows(z, B, S)
+        R = B if decode else _ops.chunk_rows(z, B, ctx.S)
         if R == B:
             (stack, seen, moe_counts), logits = rows(
-                toks, pos, last, None, (stack, seen, moe_counts))
+                toks, ctx.pos, ctx.last, None, (stack, seen, moe_counts))
             return (stack, moe_counts, seen), logits
 
         def chunk(c, state):
             carry, logits = state
             cut = lambda a: lax.dynamic_slice_in_dim(a, c * R, R, axis=0)
-            carry, part = rows(cut(toks), cut(pos), cut(last), c * R, carry)
+            carry, part = rows(cut(toks), cut(ctx.pos), cut(ctx.last),
+                               c * R, carry)
             return carry, lax.dynamic_update_slice_in_dim(
                 logits, part, c * R, axis=0)
 

@@ -19,9 +19,10 @@ A decoder whose layers are of two kinds, listed by ``layer_types``:
   would add is left out, and nothing stands in for their exchange.
 
 ``hybrid_forward`` is the uncached full-sequence forward.
-``decoder_program`` hands `serving.ServingEngine` the family's cached
-step (docs/serving.md, "The decoder program"): two kinds of cache, four
-stacks, each carried, donated and written in place:
+``decoder_program`` hands `serving.ServingEngine` the family's program
+(`_decoder_program.DecoderProgram`; docs/serving.md, "The decoder
+program"), of which this file states the cache's shapes and the layer
+body: two kinds of cache, four stacks:
 
 - full layers: keys ``(Lf, B, Hkv, qk_dim, W)`` and values
   ``(.., v_dim, W)``, positions on the minor axis (`ops/cache_write.py`
@@ -47,10 +48,10 @@ time through attention and the dense feed-forward, so that a bucket of
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
 from ._decoder_ops import _MASKED
+from ._decoder_program import DecoderProgram
 
 
 # -- pieces shared by the forward pass and the cached step ---------------------
@@ -359,60 +360,33 @@ class MiMoV2Model(HybridBlock):
         return MiMoV2Program(self, dtype)
 
 
-class MiMoV2Program:
-    """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
+class MiMoV2Program(DecoderProgram):
+    """The family's decoder program (docs/serving.md,
+    `_decoder_program.py`): its cache's shapes, its layers and its head."""
 
     def __init__(self, model, dtype=None):
-        self._model = model
-        self._z = model._sizes
-        self._dtype = dtype
-        self.window = model._max_length
-        self.vocab = model._vocab
-        self._pins = None
-        # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the caches
-        self.cache_writes = {}
-        self.cache_reads = {}
+        super().__init__(model, dtype)
         z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (tuple(z.layer_types), tuple(z.moe_layers),
                           z.experts_held, z.window, z.rotary_dim,
                           tuple(z.rope_theta.items()), z.value_scale)
 
-    def weights(self):
-        return _ops.own_weights(self._model, self._dtype)
-
-    def _counter_shape(self):
-        z = self._z
-        return (max(1, len(z.moe_at)), 2, z.experts_held[1] + 3)
-
-    def init_cache(self, B):
-        """(full keys, full values, window keys, window values,
-        counters), zeroed, beside the embedding."""
+    def cache_shapes(self, B):
+        """(full keys, full values, window keys, window values), then
+        the expert layers' counters."""
         import jax.numpy as jnp
 
         z = self._z
-        emb = self._model.embed_weight.data()._data
         Lf = max(1, len(z.of_kind["full"]))
         Lw = max(1, len(z.of_kind["window"]))
         Kf, Kw = z.kv_heads["full"], z.kv_heads["window"]
-
-        kv_dtype = self._dtype or emb.dtype
-
-        def zeros(shape, dtype=kv_dtype):
-            return jnp.zeros(shape, dtype, device=emb.sharding)
-
-        cache = (zeros((Lf, B, Kf, z.qk_dim, self.window)),
-                 zeros((Lf, B, Kf, z.v_dim, self.window)),
-                 zeros((Lw, B, Kw, z.qk_dim, z.window)),
-                 zeros((Lw, B, Kw, z.v_dim, z.window)),
-                 zeros(self._counter_shape(), jnp.int32))
-        if self._pins is None:
-            # each stack stays in the layout its donated buffer came in:
-            # read off an allocated cache, as GPT's program does
-            self._pins = [c.format.layout for c in cache[:4]]
-        return cache
+        return ([((Lf, B, Kf, z.qk_dim, self.window), None),
+                 ((Lf, B, Kf, z.v_dim, self.window), None),
+                 ((Lw, B, Kw, z.qk_dim, z.window), None),
+                 ((Lw, B, Kw, z.v_dim, z.window), None)],
+                [((max(1, len(z.moe_at)), 2, z.experts_held[1] + 3),
+                  jnp.int32)])
 
     def counters(self, cache):
         """The expert layers' counters of one served group, read back
@@ -424,49 +398,26 @@ class MiMoV2Program:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache donated; pos (B,) each row's first position; last (B,)
-        the index in the block of each row's last real token; toks
-        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``).
-        S > 1 is a prefill from an empty cache: it attends inside the
-        block.  S = 1 attends over the caches; there ``live`` (B,) bool
-        marks the rows that still want a token (None: all): another row
-        attends to nothing, goes to no expert and is counted nowhere."""
-        import collections
-
+    def body(self, ctx, w, cache, toks):
+        """S > 1 is a prefill from an empty cache: it attends inside the
+        block.  S = 1 attends over the caches, a row that wants no token
+        to nothing; it goes to no expert and is counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        from jax.experimental.layout import with_layout_constraint
-
         z = self._z
-        w = dict(zip(self._model._names, w))
         fk, fv, wk, wv, counts = cache
-        pins = self._pins      # `init_cache` read them off a real cache
-        B, S = toks.shape
-        decode = S == 1
-        given = live    # as handed: None from the prefill, whose write takes none
-        if live is None:
-            live = jnp.ones((B,), bool)
+        pos, last, S, decode = ctx.pos, ctx.last, ctx.S, ctx.decode
         R = z.window
         zero = jnp.int32(0)
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
-        with jax.named_scope("serve.embed"):
-            x = jnp.take(w["embed_weight"], toks, axis=0
-                         ).astype(jnp.float32)
-            at = pos[:, None] + jnp.arange(S)[None, :]            # (B, S)
-            valid = jnp.arange(S)[None, :] <= last[:, None]
+        x, at, valid = _ops.embed(w["embed_weight"], toks, pos, last)
 
-        def write(stacks, new, l, starts, pin):
-            """Row b's (K, D, S') blocks into the two stacks at
-            [l, b, :, :, starts[b]:], each kept in its layout."""
-            out = cache_write.write_rows(
-                stacks, [a.swapaxes(2, 3) for a in new], l, starts,
-                tally=tally, live=given)
-            return [c if p is None else with_layout_constraint(c, p)
-                    for c, p in zip(out, pin)]
+        def write(stacks, new, l, starts, first=0):
+            """Row b's (K, S', D) blocks into the two stacks at
+            [l, b, :, :, starts[b]:]."""
+            return ctx.write(stacks, [a.swapaxes(2, 3) for a in new], l,
+                             starts, first=first)
 
         def ring_of(k):
             """The ring a prefilled row leaves: slot s holds the latest
@@ -477,7 +428,7 @@ class MiMoV2Program:
                 k, jnp.clip(p_s, 0, S - 1)[:, None, :, None], axis=2)
             return jnp.where((p_s >= 0)[:, None, :, None], got, 0)
 
-        rows = _ops.chunk_rows(z, B, S)
+        rows = _ops.chunk_rows(z, ctx.B, S)
         for i, kind in enumerate(z.layer_types):
             p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
             l = z.of_kind[kind].index(i)
@@ -489,9 +440,9 @@ class MiMoV2Program:
                     rows, x, at)
             with jax.named_scope("serve.cache_write"):
                 if kind == "full":
-                    fk, fv = write((fk, fv), (k, v), l, pos, pins[:2])
+                    fk, fv = write((fk, fv), (k, v), l, pos)
                 elif decode:
-                    wk, wv = write((wk, wv), (k, v), l, pos % R, pins[2:])
+                    wk, wv = write((wk, wv), (k, v), l, pos % R, first=2)
                 else:
                     at_layer = (jnp.int32(l), zero, zero, zero, zero)
                     wk, wv = (lax.dynamic_update_slice(
@@ -502,18 +453,17 @@ class MiMoV2Program:
                     # a ring's slot s holds position pos - (pos - s)
                     # mod R if that position exists: its first pos + 1
                     # slots, then all of them
-                    ck, cv, held = (fk, fv, pos + 1) if kind == "full" \
-                        else (wk, wv, jnp.minimum(pos + 1, R))
-                    a = cache_attention.attend_rows(
-                        q[:, :, :, 0], ck, cv, l, jnp.where(live, held, 0),
-                        sink=_sink(z, kind, p), tally=reads)
+                    ck, cv, lengths = (fk, fv, None) if kind == "full" \
+                        else (wk, wv, jnp.minimum(ctx.held, R))
+                    a = ctx.attend(q[:, :, :, 0], ck, cv, l, lengths,
+                                   sink=_sink(z, kind, p))
                     x = _ops.attn_out(z, p, x, a[:, :, :, None])
                 x, route = _feed_forward_front(z, i, p, x)
             if route:
                 # padding and rows that want no token are routed
                 # nowhere: only tokens that are kept cost
                 x, stats = _experts(z, p, x, route,
-                                    live[:, None] if decode else valid)
+                                    ctx.live[:, None] if decode else valid)
                 counts = counts.at[z.moe_at.index(i), int(decode)].add(
                     _ops.moe_count_row(stats, z.experts_held[1]))
         with jax.named_scope("serve.head"):

@@ -20,9 +20,8 @@ embedding, an untied head) whose stack of ``num_layers`` layers is **run
 has keys and values of its own: a position attends, in that pass, to the
 earlier positions' keys of the same pass.  So the weights are
 ``num_layers`` stacked layers and the cache ``loop_steps x num_layers``
-slots, ``(T L, B, K, head_dim, W)`` keys beside as many values,
-position-minor, carried, donated and written in place by
-`ops/cache_write.py`; slot ``t L + l`` is a traced index.  A step is one
+slots, ``(T L, B, K, head_dim, W)`` keys beside as many values; slot
+``t L + l`` is a traced index into the write and the read.  A step is one
 traced layer body, scanned over the layers' weights, inside one traced
 loop over ``t`` that carries the stream and the two stacks: the weights
 are held once and read ``T`` times.
@@ -33,9 +32,11 @@ row's early exit.  What the rule decides is which ``h_t`` the head reads.
 
 ``hybrid_forward`` is the uncached full-sequence forward (the rule at
 every position).  ``decoder_program`` hands `serving.ServingEngine` the
-cached step (docs/serving.md, "The decoder program"): decode (S = 1)
-attends over the caches through `ops/cache_attention.py::attend_rows`,
-prefill (S > 1, from an empty cache) inside the block through
+family's program (`_decoder_program.DecoderProgram`; docs/serving.md,
+"The decoder program"; this file states the cache's shapes and the layer
+body): decode (S = 1) attends over the caches through
+`ops/cache_attention.py::attend_rows`, prefill (S > 1, from an empty
+cache) inside the block through
 `ops/pallas_attention.py::flash_attention_forward`, each row to its own
 length; neither takes a gradient.  One counter array rides in the
 donated carry (``counters``).
@@ -44,9 +45,10 @@ donated carry (``counters``).
 from __future__ import annotations
 
 from ...base import MXNetError
-from ...ops import cache_attention, cache_write, pallas_attention
+from ...ops import pallas_attention
 from ..block import HybridBlock
 from . import _decoder_ops as _ops
+from ._decoder_program import DecoderProgram
 
 _LAYER_LEAVES = ("ln1_gamma", "qkv_weight", "o_weight", "ln2_gamma",
                  "ln3_gamma", "gate_weight", "up_weight", "down_weight",
@@ -277,54 +279,31 @@ class OuroModel(HybridBlock):
         return OuroProgram(self, dtype)
 
 
-class OuroProgram:
-    """The family's decoder program (docs/serving.md): ``weights()``,
-    ``init_cache(B)``, ``step(w, cache, pos, last, toks, live=None)``."""
+class OuroProgram(DecoderProgram):
+    """The family's decoder program (docs/serving.md,
+    `_decoder_program.py`): its cache's shapes, its layers and its head.
+    The one layer body is traced once: a call its tallies count stands
+    for its T L runs."""
 
     def __init__(self, model, dtype=None):
-        self._model = model
-        self._z = z = model._sizes
-        self._dtype = dtype
-        self.window = model._max_length
-        self.vocab = model._vocab
-        self._pin = None
-        # cache_writes[S]: the row writes of the block-S step, by path;
-        # cache_reads[S]: its attention calls over the cache;
-        # block_attends[S]: its attention calls inside the block.  The
-        # one layer body is traced once: a call stands for its T L runs
-        self.cache_writes = {}
-        self.cache_reads = {}
-        self.block_attends = {}
+        super().__init__(model, dtype)
+        z = self._z
         # what a reloaded model must share beyond its shapes
         self.signature = (z.num_heads, z.kv_heads, z.head_dim, z.loop_steps,
                           z.exit_threshold, z.rope_theta, z.eps)
 
-    def weights(self):
-        return _ops.own_weights(self._model, self._dtype)
-
-    def init_cache(self, B):
-        """(keys, values, counters), zeroed, beside the embedding: a
-        slot for every (loop step, layer)."""
+    def cache_shapes(self, B):
+        """(keys, values): a slot for every (loop step, layer); then the
+        counters."""
         import jax.numpy as jnp
 
         z = self._z
-        emb = self._model.embed_weight.data()._data
         shape = (z.loop_steps * z.num_layers, B, z.kv_heads, z.head_dim,
                  self.window)
-
-        def zeros(shape, dtype):
-            return jnp.zeros(shape, dtype, device=emb.sharding)
-
-        kv_dtype = self._dtype or emb.dtype
-        cache = (zeros(shape, kv_dtype), zeros(shape, kv_dtype),
-                 # [prefill / decode, (passes, positions a slot, rows
-                 # that left at each step)]
-                 zeros((2, 2 + z.loop_steps), jnp.uint32))
-        if self._pin is None:
-            # the stacks stay in the layout their donated buffers came
-            # in: read off an allocated cache, as GPT's program does
-            self._pin = cache[0].format.layout
-        return cache
+        return ([(shape, None), (shape, None)],
+                # [prefill / decode, (passes, positions a slot, rows
+                # that left at each step)]
+                [((2, 2 + z.loop_steps), jnp.uint32)])
 
     def counters(self, cache):
         """The counters of one served group, read back once
@@ -346,59 +325,33 @@ class OuroProgram:
 
     # -- the traced step -------------------------------------------------------
 
-    def step(self, w, cache, pos, last, toks, live=None):
-        """cache donated; pos (B,) each row's first position; last (B,)
-        the index in the block of each row's last real token; toks
-        (B, S).  Returns (cache, logits (B, vocab) float32 at ``last``,
-        of each row's exit step).  S > 1 is a prefill from an empty
-        cache: it attends inside the block.  S = 1 attends over the
-        caches; there ``live`` (B,) bool marks the rows that still want
-        a token (None: all): another row attends to nothing and is
+    def body(self, ctx, w, cache, toks):
+        """The logits are of each row's exit step.  S > 1 is a prefill
+        from an empty cache: it attends inside the block.  S = 1 attends
+        over the caches, a row that wants no token to nothing; it is
         counted nowhere."""
-        import collections
-
         import jax
         import jax.numpy as jnp
 
-        from jax.experimental.layout import with_layout_constraint
-
         z = self._z
-        w = dict(zip(self._model._names, w))
-        pin = self._pin        # `init_cache` read it off a real cache
-        B, S = toks.shape
-        decode = S == 1
-        given = live    # as handed: None from the prefill, whose write takes none
-        if live is None:
-            live = jnp.ones((B,), bool)
-        # a decode step's positions a row, itself included
-        held = jnp.where(live, pos + 1, 0)
-        tally = self.cache_writes[S] = collections.Counter()
-        reads = self.cache_reads[S] = collections.Counter()
-        attends = self.block_attends[S] = collections.Counter()
-        with jax.named_scope("serve.embed"):
-            x = jnp.take(w["embed_weight"], toks, axis=0
-                         ).astype(jnp.float32)
-            at = pos[:, None] + jnp.arange(S)[None, :]            # (B, S)
+        B, decode, pos, last = ctx.B, ctx.decode, ctx.pos, ctx.last
+        x, at, _ = _ops.embed(w["embed_weight"], toks, pos, last)
 
         def layer(x, stacks, p, slot):
             q, k, v = _qkv(z, p, x, at)
             with jax.named_scope("serve.cache_write"):
-                # row b's block at [slot, b, :, :, pos[b]:], the stacks
-                # kept in the layout their donated buffers came in
-                stacks = tuple(
-                    c if pin is None else with_layout_constraint(c, pin)
-                    for c in cache_write.write_rows(
-                        stacks, (k.swapaxes(2, 3), v.swapaxes(2, 3)), slot,
-                        pos, tally=tally, live=given))
+                # row b's block at [slot, b, :, :, pos[b]:]
+                stacks = ctx.write(
+                    stacks, (k.swapaxes(2, 3), v.swapaxes(2, 3)), slot, pos)
             if decode:
                 with jax.named_scope("serve.attn"):
                     q = (q[:, :, 0] * z.head_dim ** -0.5).astype(k.dtype)
-                    a = cache_attention.attend_rows(
+                    a = ctx.attend(
                         q.reshape(B, z.kv_heads, z.groups, z.head_dim),
-                        *stacks, slot, held, tally=reads)
+                        *stacks, slot)
                     a = a.reshape(B, 1, -1)
             else:
-                a = _block_attention(z, q, k, v, last + 1, attends)
+                a = _block_attention(z, q, k, v, last + 1, ctx.attends)
             return _branches(z, p, x, a), stacks
 
         stacks, kept, gates = _loops(
@@ -407,11 +360,11 @@ class OuroProgram:
                                           axis=1)[:, 0])
         left, logits = _head(z, w, kept, gates)
         n = (last + 1).astype(jnp.uint32)
-        seen = jnp.sum(held.astype(jnp.uint32)) if decode \
+        seen = jnp.sum(ctx.held.astype(jnp.uint32)) if decode \
             else jnp.sum(n * (n + 1) // 2)
         left_at = left[:, None] == jnp.arange(z.loop_steps)[None, :]
         if decode:
-            left_at &= live[:, None]
+            left_at &= ctx.live[:, None]
         counts = cache[2].at[int(decode)].add(jnp.concatenate([
             jnp.stack([jnp.uint32(z.loop_steps), seen]),
             jnp.sum(left_at, axis=0, dtype=jnp.uint32)]))

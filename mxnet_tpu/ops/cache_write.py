@@ -55,6 +55,13 @@ many row writes went by which path, one a row and a stack (``B * n`` a
 call): ``tally["kernel"]``, ``tally["rows"]``; and, beside them,
 ``tally["kernel_live"]``: the kernel's row writes that were handed
 ``live``.
+
+**A written stack stays in the layout its donated buffer came in.**
+Left to itself the TPU compiler re-lays the whole cache around the layer
+loop to make row writes cheaper.  `layouts_of` reads the formats off
+allocated stacks; handed back as ``pins``, they are what `write_rows`
+and `write_ring` constrain their results to.  That is this module's
+decision and no model file's: none imports ``jax.experimental.layout``.
 """
 
 from __future__ import annotations
@@ -100,8 +107,39 @@ def _on_tpu():
     return jax.default_backend() == "tpu"
 
 
+def layouts_of(stacks):
+    """The formats (device layout and sharding) the allocated ``stacks``
+    lie in, one a stack: the ``pins`` of the writes into them."""
+    return [c.format for c in stacks]
+
+
+def _pinned(stacks, pins, mesh=None):
+    """Each stack under its pin's layout (``pins``: one format a stack,
+    None for no constraint; or None for none at all)."""
+    if pins is None:
+        return tuple(stacks)
+    from jax.experimental.layout import with_layout_constraint
+
+    out = []
+    for c, pin in zip(stacks, pins):
+        if pin is not None:
+            def keep_layout(c, pin=pin):
+                return with_layout_constraint(c, pin.layout)
+
+            if mesh is not None:
+                # the constraint has no partitioning rule (the
+                # partitioner would gather the cache to apply it), so
+                # each shard pins its own
+                keep_layout = jax.shard_map(
+                    keep_layout, mesh=mesh, in_specs=pin.sharding.spec,
+                    out_specs=pin.sharding.spec)
+            c = keep_layout(c)
+        out.append(c)
+    return tuple(out)
+
+
 def write_rows(stacks, news, l, starts, mesh=None, tally=None, row=None,
-               live=None):
+               live=None, pins=None):
     """``stacks``: ``n`` arrays ``(L, B, K_i, D_i, W)``; ``news``: as
     many arrays ``(R, K_i, D_i, S)``; ``l``: the layer, an int or a
     traced scalar; ``starts`` (R,) int32; ``row``: the stacks' row that
@@ -109,8 +147,9 @@ def write_rows(stacks, news, l, starts, mesh=None, tally=None, row=None,
     works its rows off a few at a time), None for ``R == B`` rows from
     the first; ``live`` (R,) bool, a decode step's rows that still want
     a token (``S == 1`` only; None: all): the stacks of a row that is
-    not live come back bit for bit what they were.  Returns the stacks,
-    written."""
+    not live come back bit for bit what they were; ``pins``: the
+    stacks' formats (`layouts_of`), None for no constraint.  Returns the
+    stacks, written, each in its pin's layout."""
     R, S = news[0].shape[0], news[0].shape[-1]
     if live is not None and S != 1:
         raise ValueError(
@@ -123,13 +162,15 @@ def write_rows(stacks, news, l, starts, mesh=None, tally=None, row=None,
         if kernel and live is not None:
             tally["kernel_live"] += R * len(stacks)
     if kernel:
-        return _write_kernel(tuple(stacks), news, l, starts, live)
-    return tuple(
-        _write_by_rows(c, n, l, starts, 0 if row is None else row, live)
-        for c, n in zip(stacks, news))
+        out = _write_kernel(tuple(stacks), news, l, starts, live)
+    else:
+        out = tuple(
+            _write_by_rows(c, n, l, starts, 0 if row is None else row, live)
+            for c, n in zip(stacks, news))
+    return _pinned(out, pins, mesh)
 
 
-def write_ring(stacks, news, l, lengths, tally=None, row=None):
+def write_ring(stacks, news, l, lengths, tally=None, row=None, pins=None):
     """``stacks``: ``n`` rings ``(L, B, K_i, D_i, W)``; ``news``: as
     many blocks ``(R, K_i, D_i, S)`` holding positions ``0 .. S``;
     ``lengths`` (R,) int32, each row's real positions (1 at least).
@@ -138,18 +179,18 @@ def write_ring(stacks, news, l, lengths, tally=None, row=None):
     falls to (a row shorter than the ring) gets whatever the block
     holds past the row's length, which nothing reads before a decode
     step has written it (`cache_attention.attend_rows` is asked for
-    ``min(pos + 1, W)`` slots).  ``row`` as `write_rows`.  Returns the
-    rings, written."""
+    ``min(pos + 1, W)`` slots).  ``row``, ``pins`` as `write_rows`.
+    Returns the rings, written."""
     R, S = news[0].shape[0], news[0].shape[-1]
     W = stacks[0].shape[-1]
     if tally is not None:
         tally["rows"] += R * len(stacks)
     if S <= W:      # nothing wraps: the block from slot 0
         zeros = jnp.zeros((R,), jnp.int32)
-        return tuple(
+        return _pinned(tuple(
             _write_by_rows(c, n.astype(c.dtype), l, zeros,
                            0 if row is None else row)
-            for c, n in zip(stacks, news))
+            for c, n in zip(stacks, news)), pins)
     first = jnp.clip(lengths.astype(jnp.int32) - W, 0, S - W)      # (R,)
     zero = jnp.int32(0)
     out = []
@@ -164,7 +205,7 @@ def write_ring(stacks, news, l, lengths, tally=None, row=None):
                 (jnp.int32(l), jnp.int32((0 if row is None else row) + b),
                  zero, zero, zero))
         out.append(c)
-    return tuple(out)
+    return _pinned(out, pins)
 
 
 def _write_by_rows(c, new, l, starts, row=0, live=None):

@@ -68,7 +68,7 @@ import threading
 from ..base import MXNetError
 from ..obs.spans import wall
 from ..profiler import scope
-from ..gluon.model_zoo.gpt import _sample
+from ..ops.sampling import _sample
 
 # -- counters (the retrace-free pin) -------------------------------------------
 

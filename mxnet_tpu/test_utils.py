@@ -73,13 +73,14 @@ def _padded_group(engine, prompts):
 def serving_host_walk(engine, prompts, steps, temperature=None, rng=None):
     """One group through a `ServingEngine`'s compiled programs with the
     host in every step, the reference for `serve_group`'s device-fed
-    loop: read each program's logits, pick on the host (`gpt._sample`:
-    greedy, or drawn with ``rng``), put ids and positions back.  Holds
-    each program's own greedy ids and next positions to what the host
-    computes from the same step (AssertionError otherwise).  The group
-    runs in the buckets `serve_group` would pick.  Returns
-    ``(tokens (n, steps), logits (n, steps, vocab))``."""
-    from .gluon.model_zoo.gpt import _sample
+    loop: read each program's logits, pick on the host
+    (`ops.sampling._sample`: greedy, or drawn with ``rng``), put ids and
+    positions back.  Holds each program's own greedy ids and next
+    positions to what the host computes from the same step
+    (AssertionError otherwise).  The group runs in the buckets
+    `serve_group` would pick.  Returns ``(tokens (n, steps), logits (n,
+    steps, vocab))``."""
+    from .ops.sampling import _sample
 
     n = len(prompts)
     B, lens, toks = _padded_group(engine, prompts)

@@ -710,10 +710,10 @@ def test_ouros_programs_compile_for_a_v5e_at_the_published_widths(
     slots = z.loop_steps * z.num_layers
     assert (slots, z.num_layers) == (192, 48)
     stack = sds((slots, B, z.kv_heads, z.head_dim, W))
-    # the layout a donated stack arrives in, as `init_cache` reads it
+    # the format a donated stack arrives in, as `init_cache` reads it
     # off an allocated one on the chip
-    program._pin = jax.jit(lambda x: x).lower(stack).compile(
-        ).input_formats[0][0].layout
+    program._layouts = [jax.jit(lambda x: x).lower(stack).compile(
+        ).input_formats[0][0]] * 2
     weights = tuple(sds(z.shape_of(n)) for n in net._names)
     assert weights[net._names.index("qkv_weight")].shape[0] == 48
     # the decode step as the engine compiles it: handed the live rows
@@ -779,10 +779,10 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
     full = sds((1, B, z.kv_heads, z.head_dim, W))
     ring = sds((3, B, z.kv_heads, z.head_dim, z.window))
     assert (z.window, z.groups, W) == (4096, 16, 16384)
-    # the layouts donated stacks arrive in, as `init_cache` reads them
+    # the formats donated stacks arrive in, as `init_cache` reads them
     # off allocated ones on the chip
-    program._pins = [jax.jit(lambda x: x).lower(c).compile(
-        ).input_formats[0][0].layout for c in (full, full, ring, ring)]
+    program._layouts = [jax.jit(lambda x: x).lower(c).compile(
+        ).input_formats[0][0] for c in (full, full, ring, ring)]
     weights = tuple(sds(shape) for _, shape in z.leaves())
     assert sum(int(np.prod(w.shape)) for w in weights) == 3_122_679_808
     # the decode step as the engine compiles it: handed the live rows
