@@ -117,13 +117,17 @@ def run_cell(cells, workload, seed, seconds, trace, platform="tpu",
     read the control in the same process; the caller then closes."""
     t_process = time.perf_counter() if t_process is None else t_process
     cell = cells.cell(workload)
+    # the package first, and the client's start inside its own span
+    # (``startup.backend``): jax's import and the client are then read as
+    # ``setup_import_s`` and ``setup_params_s``, not as time before the
+    # program's first line; the same work in another order
+    from mxnet_tpu import engine
+
+    cache = engine.ensure_compile_cache()
     info, devices = device_info(platform, cell["chips"])
     peaks = cells.data(".", "peaks")
     if info["kind"] not in peaks:
         raise NoChip(f"peaks.json holds no device kind {info['kind']!r}")
-    from mxnet_tpu import engine
-
-    cache = engine.ensure_compile_cache()
     _watch_compiles()
     out_dir = os.path.join(out_root or os.path.join(cells.root,
                                                     "benchmark_out"),

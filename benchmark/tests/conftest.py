@@ -20,6 +20,24 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# what every serving cell reports under one name (a cell joins a metric by
+# its name in the entry's ``workloads``, no file of its own), the experts'
+# padding that the four expert cells share, and the start-up metrics of
+# every cell: each in BENCHMARK.json's order, where the generic entries
+# come before any family's own and the start-up entries last
+SERVING = ["device_idle_pct.serve", "decode_rows_useful_pct",
+           "serve_ttft_ms_p50", "decode_ms_per_step_p50",
+           "serve_token_gap_ms_p95", "idle_readback_pct.serve",
+           "idle_host_pct.serve", "idle_collect_pct.serve",
+           "idle_unattributed_pct.serve"]
+PADDED = "moe_rows_padded_pct"
+SETUP = ["setup_before_import_s", "setup_import_s", "setup_params_s",
+         "setup_trace_lower_s", "setup_compile_s", "setup_warm_run_s",
+         "setup_unattributed_s"]
+# of SERVING, what a CPU run reads (the others need a device plane)
+SERVING_ON_THE_CPU = ["decode_rows_useful_pct", "serve_ttft_ms_p50",
+                      "decode_ms_per_step_p50", "serve_token_gap_ms_p95"]
+
 TINY_GPT = {
     "name": "tiny-gpt", "source": "a test's own", "model_type": "gpt2",
     "n_embd": 32, "n_layer": 2, "n_head": 2, "n_positions": 64,

@@ -1,5 +1,11 @@
 """Every name and unit of BENCHMARK.json and of metrics/ holds only the
-characters the contract allows, and the files agree with each other."""
+characters the contract allows, and the files agree with each other.
+
+An entry's ``workloads`` in BENCHMARK.json is the one list of a metric's
+cells (PR 45): a cell joins a metric that exists by its name there and
+by no edit under ``benchmark/``; a metric file lists ``cells`` only
+while no entry names it (it waits for a later PR).  No two entered
+metrics may be the same reading under two names."""
 
 import glob
 import json
@@ -10,7 +16,12 @@ import pytest
 
 from benchmark.cells import HERE, Cells
 
-from conftest import ROOT
+from conftest import ROOT, TINY_GPT, TINY_SERVE, write_bench
+
+PER_LAYER_MAX = 128     # the contract's limit on ``per_layer``
+# what makes a metric the reading it is; two entered files that agree in
+# all of it are one metric under two names
+READING = ("reader", "params", "unit", "better", "source", "layer", "moves")
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -59,8 +70,81 @@ def test_metric_files_agree_with_the_entries(bench):
             continue
         for key in ("unit", "better", "source", "layer", "moves"):
             assert entry[key] == desc[key], (desc["name"], key)
-        assert entry["moves"] in e2e
-        assert set(entry["workloads"]) <= set(desc["cells"])
+        assert entry["moves"] in e2e and entry["workloads"]
+        # an entered file lists no cells of its own; one that still does
+        # (a test's own) holds the entry to them
+        assert set(entry["workloads"]) <= set(desc.get("cells",
+                                                       entry["workloads"]))
+
+
+def _entered():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return [m["name"] for m in json.load(f)["per_layer"]]
+
+
+@pytest.fixture(scope="module")
+def described(bench):
+    """{entered metric: its file's description}, each file read once."""
+    out = {}
+    for m in bench["per_layer"]:
+        with open(os.path.join(HERE, "metrics", m["name"] + ".json")) as f:
+            out[m["name"]] = json.load(f)
+    return out
+
+
+@pytest.mark.parametrize("name", _entered())
+def test_no_entered_metric_is_another_under_a_second_name(bench, described,
+                                                          name):
+    """A cell that wants a reading some entry already gives joins that
+    entry's ``workloads``; a copy of the file under a suffix is refused
+    here, so the 40 twins that filled ``per_layer`` cannot come back."""
+    assert len(bench["per_layer"]) <= PER_LAYER_MAX
+    assert "cells" not in described[name], \
+        "an entered metric's cells are its entry's workloads"
+
+    def reading(desc):
+        return [desc.get(k, {}) for k in READING]
+
+    assert [n for n, desc in described.items()
+            if reading(desc) == reading(described[name])] == [name]
+
+
+def test_a_cell_joins_a_metric_by_its_name_in_workloads_alone(tmp_path,
+                                                              quiet):
+    """A benchmark of a test's own: its cell reports
+    ``decode_ms_per_step_p50`` because the entry names it, with the
+    harness's own metric file, of which no byte says the cell's name."""
+    from benchmark import run
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = dict(next(m for m in json.load(f)["per_layer"]
+                          if m["name"] == "decode_ms_per_step_p50"),
+                     workloads=["scratch-cell"])
+    write_bench(str(tmp_path), {"tiny": TINY_GPT}, {"tiny-serve": TINY_SERVE},
+                [{"name": "scratch-cell", "config": "tiny",
+                  "traffic": "tiny-serve", "chips": 1, "why": "a test"}],
+                [entry])
+    assert not os.listdir(os.path.join(str(tmp_path), "tb", "metrics"))
+    cells = Cells(str(tmp_path))
+    assert [m["name"] for m in cells.metrics("per_layer", "scratch-cell")] \
+        == ["decode_ms_per_step_p50"]
+    desc, read = cells.reader("decode_ms_per_step_p50")
+    with open(os.path.join(HERE, "metrics",
+                           "decode_ms_per_step_p50.json")) as f:
+        assert "scratch-cell" not in f.read()
+    assert read({"records": [{"t_decode0": 1.0,
+                              "decode_us_per_token": 2500.0}]},
+                desc["params"]) == pytest.approx(2.5)
+    # and the three tests of this file's first half pass on it as they
+    # stand: names, the entry against its file, the cell's metrics
+    with open(os.path.join(str(tmp_path), "BENCHMARK.json")) as f:
+        scratch = json.load(f)
+    test_names_and_units(scratch)
+    test_metric_files_agree_with_the_entries(scratch)
+    traced = run.run_cell(cells, "scratch-cell", 2 ** 31 + 45, 0.3, True,
+                          platform="cpu", log=quiet[1])
+    assert traced["correct"] is True, quiet[0]
+    assert traced["metrics"]["decode_ms_per_step_p50"]["value"] > 0
 
 
 def test_every_cell_has_its_files_and_metrics(bench):
@@ -71,7 +155,9 @@ def test_every_cell_has_its_files_and_metrics(bench):
         ends = [m["name"] for m in cells.metrics("end_to_end", w["name"])]
         assert sorted(ends) == sorted([rate, "setup_s"])
         layers = cells.metrics("per_layer", w["name"])
-        assert layers and all(m["moves"] == rate for m in layers)
+        assert layers and all(m["moves"] in (rate, "setup_s")
+                              for m in layers)
+        assert any(m["moves"] == rate for m in layers)
         for m in layers:
             cells.reader(m["name"])
 
