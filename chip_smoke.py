@@ -20,10 +20,13 @@ reference's logits); a sixth, ``Cohere2MoeModel`` (Command A+: a
 parallel attention-and-experts block, rotary-free full layers beside
 rings of several lane blocks), small and then at the published widths of
 ``benchmark/configs/command-a-plus-ep16.json``, both held to the
-float32 reference's logits; with four chips, the GPT step under
+float32 reference's logits; a seventh, ``JambaModel`` (Mamba layers
+whose state and convolution tail ride in the donated cache beside an
+attention layer's keys and values), held to the float32 reference's
+logits; with four chips, the GPT step under
 ``shard_model`` fsdp and tp.  Phases, in order: device, sync, kernel,
 train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro,
-serve_cmda, serve_cmda_full, sharded (``--phases a,b``: the device
+serve_cmda, serve_cmda_full, serve_jamba, sharded (``--phases a,b``: the device
 phase and only those).  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
@@ -153,6 +156,22 @@ def ouro_small():
                   max_length=512, dtype="bfloat16", grad_req="null")
     return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
                     prompt_lens=(40, 128, 300, 77, 129, 16, 260),
+                    new_tokens=6)
+
+
+def jamba_small():
+    """The seventh family at small sizes with heads and channel blocks
+    of the published kind (4 query heads of 128 over one key head, 1,024
+    channels: two blocks of the scan kernel): one period of 14 layers
+    with its attention layer at 7, the cell's prefill bucket of 512 and
+    a window of 704 (stacks of 768 slots: whole lane blocks)."""
+    kwargs = dict(vocab_size=512, units=512, num_layers=14, num_heads=4,
+                  kv_heads=1, hidden_size=1024, attn_period=14,
+                  attn_offset=7, d_state=16, d_conv=4, dt_rank=32, expand=2,
+                  max_length=704, prefill_chunk_tokens=2048,
+                  dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
+                    prompt_lens=(40, 128, 300, 77, 129, 1, 260),
                     new_tokens=6)
 
 
@@ -813,7 +832,7 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
         f"{size.new_tokens} tokens through bucket {timing['bucket']}: "
         f"first call {first:.1f}s, then "
         f"{timing['decode_us_per_token'] / 1e3:.2f} ms a decode step; "
-        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn', 'loop'))} }; "
+        f"counters { {k: v for k, v in timing.items() if k.startswith(('moe', 'attn', 'loop', 'ssm'))} }; "
         f"peak bytes in use {peak}")
     return net, engine, timing, {
         "params": n_params, "programs": engine.program_count(),
@@ -1156,6 +1175,84 @@ def phase_serve_cmda(size, platform, tag="serve_cmda"):
 SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
 
 
+# -- serve, a seventh family: states beside the stacks --------------------------
+
+def phase_serve_jamba(size, platform):
+    from mxnet_tpu.gluon.model_zoo import jamba
+    from mxnet_tpu.ops import ssm
+
+    steps = size.new_tokens - 1
+    S, kw = size.prefill_floor, size.kwargs
+    La = sum(i % kw["attn_period"] == kw["attn_offset"]
+             for i in range(kw["num_layers"]))
+    Lm = kw["num_layers"] - La
+
+    def counters_hold(timing, lens, pads):
+        # the scan stopped at each row's length (the kernel walks whole
+        # chunks to it, the plain path the bucket), every live row's
+        # state moved on once a step and layer, and on the chip both
+        # went through their kernels, the update told the live rows
+        real = lens + (1,) * pads
+        Tc = ssm.scan_chunk(S)
+        walked = sum(-(-n // Tc) * Tc for n in real) \
+            if platform == "tpu" else len(real) * S
+        on = float(platform == "tpu")
+        return timing["ssm_positions_prefill"] == Lm * sum(real) \
+            and timing["ssm_positions_scanned_prefill"] == Lm * walked \
+            and timing["ssm_row_updates_decode"] == Lm * len(lens) * steps \
+            and timing["attn_pairs_prefill"] \
+            == La * sum(n * (n + 1) // 2 for n in real) \
+            and timing["attn_positions_decode"] \
+            == La * sum(n + j + 1 for n in lens for j in range(steps)) \
+            and timing["prefill_attn_kernel_share"] == 1.0 \
+            and timing["prefill_state_scan_kernel_share"] == on \
+            and timing["decode_state_update_kernel_share"] == on \
+            and timing["decode_state_update_live_share"] == on
+
+    # two stacks, of which no layer moves; the CPU's plain update writes
+    # a layer's states whole, at any size, so it is checked nothing
+    net, engine, timing, out = serve_family(
+        "serve_jamba", jamba.JambaModel, size, platform,
+        2 if platform == "tpu" else 0, counters_hold)
+    z, big = net._sizes, engine.init_cache(1)
+    if platform == "tpu":
+        # the states are updated where they lie: the decode program
+        # makes no value of a layer's states' shape at all (a byte
+        # threshold would also catch this size's weights)
+        import re
+
+        B = timing["bucket"][0]
+        layer = re.compile(r" = f32\[(1,)?%d,%d,%d\]" % (B, z.d_state,
+                                                          z.inner))
+        made = [line.split(" = ")[0].strip() for line in engine._programs[
+            (B, 1)].as_text().splitlines() if layer.search(line)]
+        require(not made, f"serve_jamba: the decode program makes a "
+                          f"layer of the states: {made[:8]}")
+        say(f"[serve_jamba] decode program, batch {B}: no value of a "
+            f"layer's states ({B} x {z.d_state} x {z.inner} float32)")
+    require([tuple(c.shape) for c in big] == [
+        (1, 1, z.kv_heads, z.head_dim, -(-engine._W // 128) * 128)] * 2
+        + [(Lm, 1, z.d_state, z.inner),
+           (Lm, 1, (z.d_conv - 1) * z.inner), (5,)]
+        and str(big[2].dtype) == "float32",
+        f"serve_jamba: cache {[(tuple(c.shape), c.dtype) for c in big]}")
+    from benchmark.references import jamba as ref
+
+    require_served_logits_equal_the_reference(
+        "serve_jamba", net, engine, size, ref, {
+            "hidden_size": z.units, "num_hidden_layers": z.num_layers,
+            "num_attention_heads": z.num_heads,
+            "num_key_value_heads": z.kv_heads,
+            "intermediate_size": z.hidden_size, "vocab_size": z.vocab,
+            "rms_norm_eps": z.eps, "hidden_act": "silu",
+            "tie_word_embeddings": True, "mamba_expand": z.expand,
+            "mamba_d_state": z.d_state, "mamba_dt_rank": z.dt_rank,
+            "mamba_d_conv": z.d_conv, "mamba_conv_bias": True,
+            "mamba_proj_bias": False, "attn_layer_period": z.attn_period,
+            "attn_layer_offset": z.attn_offset, "num_experts": 1})
+    return out
+
+
 def phase_sharded(size, platform, single_loss0, single_peak):
     """The train path again under shard_model, on four devices: same
     seed, so step 0 must reproduce the single-chip loss."""
@@ -1257,6 +1354,8 @@ def main(argv=()):
     gc.collect()
     run("serve_cmda_full", phase_serve_cmda, cmda_full(), platform,
         "serve_cmda_full")
+    gc.collect()
+    run("serve_jamba", phase_serve_jamba, jamba_small(), platform)
     gc.collect()
     import jax
 

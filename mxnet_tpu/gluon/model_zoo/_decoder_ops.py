@@ -1,5 +1,5 @@
 """What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`,
-`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`): the pieces of a layer that do
+`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`, `jamba.py`): the pieces of a layer that do
 not depend on a family's attention or routing rule.  No family imports
 another; a change here is a change to all, and their cells measure it.
 

@@ -1,29 +1,34 @@
 """What a served decoder program is, apart from its layers
 (docs/serving.md, "The decoder program"): `DecoderProgram`, the base of
 every family's program (`gpt.py`, `mimo_v2.py`, `keye_vl2.py`,
-`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`).
+`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`, `jamba.py`).
 
 A family states
 
 - ``signature``, in its constructor: what a reloaded model must share
   beyond its shapes;
-- ``cache_shapes(B)``: its cache, as data;
+- ``cache_shapes(B)``: its cache, as data: the stacks that grow by a
+  position a token, optionally the **states** that hold one block a
+  (layer, row) and are replaced whole each step (a state-space layer's
+  state, its convolution's tail), and the counters;
 - ``body(ctx, w, cache, toks)``: its layers and its head on one block,
   traced;
 - ``counters(cache)`` where it counts in the donated carry.
 
 The base gives the rest of the contract `serving.ServingEngine` sees:
 ``weights()``, ``init_cache(B)``, ``step(..)``, ``window``, ``vocab`` and
-the three tallies.  What every family decides alike is decided here and
-in `ops/cache_write.py`, once: which rows of a decode step still count
-(``live``), what a row's write is told of them, that a written stack
-stays in the layout its donated buffer came in, and what is counted while
-a step is traced.
+the four tallies.  What every family decides alike is decided here and
+in `ops/cache_write.py` and `ops/ssm.py`, once: which rows of a decode
+step still count (``live``), what a row's write and a row's state are
+told of them (a row that is not live keeps its positions, its state and
+its tail as they were), that a written stack or state stays in the
+layout its donated buffer came in, and what is counted while a step is
+traced.
 """
 
 from __future__ import annotations
 
-from ...ops import cache_attention, cache_write
+from ...ops import cache_attention, cache_write, ssm
 
 
 def own_weights(model, dtype):
@@ -54,11 +59,14 @@ class Step:
       program's ``cache_writes[S]``, ``cache_reads[S]``,
       ``block_attends[S]``): the row writes by path, the attention calls
       over the cache by path, and the attention calls inside the block,
-      which a family's body counts itself (``attends["kernel"] += 1``).
+      which a family's body counts itself (``attends["kernel"] += 1``);
+      ``updates`` (``state_updates[S]``): the rows whose state a scan
+      or an update moved on, by path.
 
-    A family does three things with ``live`` in its decode branch and no
-    fourth: lengths from ``held``, ``valid=live[:, None]`` to the
-    experts, writes through ``write``.
+    A family does four things with ``live`` in its decode branch and no
+    fifth: lengths from ``held``, ``valid=live[:, None]`` to the
+    experts, writes through ``write``, states through ``update`` and
+    ``conv``.
     """
 
     def __init__(self, program, pos, last, toks, live, given):
@@ -73,6 +81,7 @@ class Step:
         self.writes = program.cache_writes[self.S] = collections.Counter()
         self.reads = program.cache_reads[self.S] = collections.Counter()
         self.attends = program.block_attends[self.S] = collections.Counter()
+        self.updates = program.state_updates[self.S] = collections.Counter()
         self._given = given
         self._mesh = program._mesh
         self._layouts = program._layouts
@@ -116,6 +125,58 @@ class Step:
             mask=mask, sink=sink, mesh=self._mesh, tally=self.reads,
             leading=leading)
 
+    # -- states: one block a (layer, row), replaced whole ---------------------
+
+    def _put(self, states, block, l, row, first):
+        """A block's rows ``block`` (R, ...) into ``states[l, row:]``,
+        the result in the layout `init_cache` read for place
+        ``first``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        at = (jnp.int32(l), jnp.int32(0 if row is None else row)) \
+            + (jnp.int32(0),) * (states.ndim - 2)
+        return self._pin(lax.dynamic_update_slice(
+            states, block.astype(states.dtype)[None], at), first)
+
+    def _pin(self, states, first):
+        return cache_write._pinned((states,), self._layouts_of(first, 1))[0]
+
+    def scan(self, states, l, c, dt, A, B, C, D, lengths, row=None,
+             first=0):
+        """A prefilled block through layer ``l``'s recurrence from an
+        empty state (`ssm.selective_scan_rows`), each row to
+        ``lengths`` (B,): returns (y, the states ``(L, B, N, E)`` with
+        rows ``row ..`` of layer ``l`` what each row's last real
+        position left).  ``row``, ``first`` and the scope as `write`."""
+        y, h = ssm.selective_scan_rows(c, dt, A, B, C, D, lengths,
+                                       tally=self.updates)
+        return y, self._put(states, h, l, row, first)
+
+    def update(self, states, l, c, dt, A, B, C, D, first=0):
+        """A decode step's one position a row (`ssm.state_update_rows`),
+        in place on the donated states and told the rows that are live
+        as the step was handed them: another row's state is not moved.
+        Returns (y (B, E), the states)."""
+        y, states = ssm.state_update_rows(
+            states, l, c, dt, A, B, C, D, live=self._given,
+            tally=self.updates)
+        return y, self._pin(states, first)
+
+    def conv(self, tails, l, a, w, b, lengths=None, row=None, first=0):
+        """The causal depthwise convolution before the recurrence, and
+        the tail it leaves in ``tails`` ``(L, B, (k - 1) E)``: a block
+        ``a`` (R, S, E) from no history, each row to ``lengths``
+        (`ssm.causal_conv_rows`), or a decode step's one position from
+        the tails (`ssm.conv_step`), where the tail of a row that is
+        not live comes back as it was.  Returns (c, the tails)."""
+        if self.decode:
+            c, tails = ssm.conv_step(tails, l, a[:, 0], w, b,
+                                     live=self._given)
+            return c[:, None], self._pin(tails, first)
+        c, tail = ssm.causal_conv_rows(a, w, b, lengths)
+        return c, self._put(tails, tail, l, row, first)
+
 
 class DecoderProgram:
     """A family's decoder program for `serving.ServingEngine`:
@@ -136,18 +197,23 @@ class DecoderProgram:
         # by block length S, told while the block-S step is traced:
         # cache_writes[S] its row writes, by path; cache_reads[S] its
         # attention calls over the caches, by path; block_attends[S]
-        # its attention calls inside the block, by path
+        # its attention calls inside the block, by path;
+        # state_updates[S] the rows whose state it moved on, by path
         self.cache_writes = {}
         self.cache_reads = {}
         self.block_attends = {}
+        self.state_updates = {}
 
     # -- what a family states --------------------------------------------------
 
     def cache_shapes(self, B):
-        """``(stacks, counters)``: two lists of ``(shape, dtype)``, the
-        cache of batch bucket B in its order, the stacks ``(L, B, K, D,
-        W)`` first (dtype None: the serving type) and then what rides in
-        the same donated carry."""
+        """``(stacks, counters)`` or ``(stacks, states, counters)``:
+        lists of ``(shape, dtype)``, the cache of batch bucket B in its
+        order: the stacks ``(L, B, K, D, W)`` first (dtype None: the
+        serving type), then the states ``(L, B, ...)``, one block a
+        (layer, row) that a step replaces whole (zero in a fresh cache,
+        which is the start of a sequence), and then what rides in the
+        same donated carry."""
         raise NotImplementedError
 
     def body(self, ctx, w, cache, toks):
@@ -183,17 +249,21 @@ class DecoderProgram:
 
     def init_cache(self, B):
         """The family's cache for batch bucket B, zeroed: the stacks,
-        then the counters.  Committed next to the weights (the engine
-        serves from the device(s) the model was placed on, never from
-        the process default), the stacks head-sharded under a mesh."""
+        the states, then the counters.  Committed next to the weights
+        (the engine serves from the device(s) the model was placed on,
+        never from the process default), the stacks head-sharded under
+        a mesh; states lie beside the embedding (a family that has them
+        serves from one chip)."""
         import jax.numpy as jnp
 
         emb = self._embedding()
-        stacks, counters = self.cache_shapes(B)
+        stacks, *states, counters = self.cache_shapes(B)
         where = self._cache_sharding()
         kv_dtype = self._dtype or emb.dtype
         stacks = tuple(jnp.zeros(shape, dtype or kv_dtype, device=where)
-                       for shape, dtype in stacks)
+                       for shape, dtype in stacks) + tuple(
+            jnp.zeros(shape, dtype or kv_dtype, device=emb.sharding)
+            for shape, dtype in (states[0] if states else ()))
         if self._layouts is None:
             # how this platform lays a stack out on the device: read off
             # an allocated one, not assumed
@@ -211,7 +281,8 @@ class DecoderProgram:
         S > 1 is a prefill: it is handed no ``live``.  S = 1 is a decode
         step; there ``live`` (B,) bool marks the rows that still want a
         token (None: all): another row attends to nothing, goes to no
-        expert, is counted nowhere and leaves the cache as it was."""
+        expert, is counted nowhere and leaves the cache as it was, its
+        state and its tail too."""
         import jax.numpy as jnp
 
         given = live    # as handed: None from the prefill, whose write takes none

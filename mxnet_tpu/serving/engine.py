@@ -256,7 +256,8 @@ class ServingEngine:
       attention calls `ops/cache_attention.py` counted),
       ``block_attends`` (likewise a prefill block's attention calls
       inside itself, by path: ``"kernel"`` through
-      `ops/pallas_attention.py`) and
+      `ops/pallas_attention.py`), ``state_updates`` (likewise the rows
+      whose state `ops/ssm.py` moved on, by path) and
       ``signature`` (what a reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
@@ -720,6 +721,22 @@ class ServingEngine:
             if dispatched:
                 timings["decode_attn_window_read_pct"] = \
                     _window_read_pct(reads, lens, dispatched, wanted)
+        # of the rows whose state (a state-space layer's) a decode step
+        # moved on, the share the in-place kernel moved, and the share it
+        # moved knowing which rows still want a token, so that another
+        # row's state stayed where it was; of a prefill's scans, the
+        # share that ran in the kernel (ops/ssm.py counted them)
+        updates = getattr(self._program, "state_updates", {})
+        moved, scanned = updates.get(1), updates.get(int(S))
+        if moved:
+            made = moved["kernel"] + moved["plain"]
+            timings["decode_state_update_kernel_share"] = \
+                moved["kernel"] / made
+            timings["decode_state_update_live_share"] = \
+                moved["kernel_live"] / made
+        if scanned:
+            timings["prefill_state_scan_kernel_share"] = \
+                scanned["kernel"] / (scanned["kernel"] + scanned["plain"])
         # of the prefill program's attention calls inside its block, the
         # share that went through the flash forward kernel
         # (ops/pallas_attention.py), as the family's program counted

@@ -143,9 +143,11 @@ def serving_dead_rows_keep_their_cache(engine, prompts, live):
     """A prefill of ``prompts`` and one decode step handed ``live`` (a
     bool a prompt: does the row still want a token), through the
     engine's own programs: in every stack of the cache (its arrays
-    ``(L, B, K, D, W)``) a row that is not live, a pad row among them,
-    comes back bit for bit what the prefill left, in every layer, and
-    the live rows' come back changed (AssertionError otherwise)."""
+    ``(L, B, K, D, W)``) and every state (its floating arrays ``(L, B,
+    ...)`` of fewer axes: a state-space layer's state and tail) a row
+    that is not live, a pad row among them, comes back bit for bit what
+    the prefill left, in every layer, and the live rows' come back
+    changed (AssertionError otherwise)."""
     import jax.numpy as jnp
 
     B, lens, toks = _padded_group(engine, prompts)
@@ -160,7 +162,9 @@ def serving_dead_rows_keep_their_cache(engine, prompts, live):
     def stacks(cache):
         # gathered into buffers of their own: the step below is given
         # the cache's
-        return {kind: [c[:, at] for c in cache if c.ndim == 5]
+        return {kind: [c[:, at] for c in cache if c.ndim == 5 or (
+            c.ndim in (3, 4) and c.shape[1] == B
+            and jnp.issubdtype(c.dtype, jnp.floating))]
                 for kind, at in rows.items()}
 
     before = stacks(cache)

@@ -23,6 +23,10 @@ SHAPES = {
     "keye": (4, 8, 128, 128, 512, True, False),
     "keye_sunk": (4, 8, 128, 128, 256, True, True),
     "small_heads": (4, 2, 16, 16, 256, False, False),   # four side by side
+    # Jamba2-3B's attention layers: 20 query heads over one key head of
+    # 128 (a group that is no power of two: 32 rows of a tile, 12 of
+    # them padding), a window of 704 in stacks of 768 slots
+    "jamba": (1, 20, 128, 128, 768, False, False),
 }
 # lengths that differ by row and sit on the edges of a 128-lane block;
 # "dead": rows that want no token (length 0: their first block, all of
@@ -130,7 +134,8 @@ def test_heads_side_by_side_fill_a_tile(K, D, Dv, r):
 @pytest.mark.parametrize("K,D,Dv,W,lanes", [
     (16, 64, 64, 1024, 128), (4, 128, 128, 16384, 512),
     (4, 192, 128, 2048, 256), (16, 64, 64, 384, 128),
-    (8, 192, 128, 128, 128), (1, 64, 64, 65536, 4096)])
+    (8, 192, 128, 128, 128), (1, 64, 64, 65536, 4096),
+    (1, 128, 128, 768, 256)])
 def test_block_lanes_follow_the_stacks(K, D, Dv, W, lanes):
     """Longer windows and narrower positions take larger blocks (the
     three cells' stacks first: the sizes measured best on the v5e), a

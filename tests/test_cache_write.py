@@ -811,3 +811,99 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
         assert mem.temp_size_in_bytes < 4 << 30
         assert dict(program.block_attends[S]) == {"kernel": 4}
     assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])
+
+
+@pytest.mark.parametrize("kernel", ["scan", "update"])
+def test_the_state_space_kernels_compile_for_a_v5e(one_chip, kernel):
+    """`ops/ssm.py`'s chunked scan at a row chunk of Jamba2-3B's prefill
+    (16 rows x 512 positions x 5,120 channels, 16 states: grid 16 x 10
+    x 4) and its one-position update at the cell's 128 rows, in place
+    on all 26 layers' states (1.09 GB, aliased to the output)."""
+    from mxnet_tpu.ops import ssm
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, S, E, N, L, B = 16, 512, 5120, 16, 26, 128
+    if kernel == "scan":
+        compiled = jax.jit(ssm._scan_kernel_call).lower(
+            sds((R, S, E)), sds((R, S, E)), sds((N, E)), sds((R, S, N)),
+            sds((R, S, N)), sds((E,)), sds((R,), jnp.int32)).compile()
+        assert [tuple(o.shape) for o in compiled.out_info] == [
+            (R, S, E), (R, N, E)]
+    else:
+        compiled = jax.jit(
+            lambda st, *a: ssm._update_kernel_call(st, 3, *a),
+            donate_argnums=(0,)).lower(
+            sds((L, B, N, E)), sds((B, E)), sds((B, E)), sds((N, E)),
+            sds((B, N)), sds((B, N)), sds((E,)),
+            sds((B,), jnp.bool_)).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            == L * B * N * E * 4
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_jambas_decode_step_compiles_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """Jamba2-3B's decode step
+    (`gluon/model_zoo/jamba.py::JambaProgram.step`, the sizes of
+    benchmark/configs/jamba2-3b.json) lowers and compiles for a
+    described v5e with the kernels in it: the 26 Mamba layers'
+    one-position update (`ops/ssm.py`), the two attention layers' row
+    write and per-row attention (20 query heads over one key head,
+    stacks of 768 slots in blocks of 256); the two stacks, the states
+    and the tails are written into their donated arguments, and the
+    program holds no temporary near the states' size.  (The 128 x 512
+    prefill compiles the same way in 50 s, with 16 rows a chunk and
+    `state_updates[512] == {"kernel": 26 * 16}`: the chip runs hold
+    it, not this file.)"""
+    import json
+    import os
+
+    from mxnet_tpu.gluon.model_zoo import jamba
+    from mxnet_tpu.ops import cache_attention, pallas_attention as pa, ssm
+
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2-3b.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    net = jamba.JambaModel(**kwargs)      # no parameter allocated
+    z, B = net._sizes, 128
+    program = jamba.JambaProgram(net, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stacks, states, counters = program.cache_shapes(B)
+    assert [s for s, _ in stacks] == [(2, B, 1, 128, 768)] * 2
+    assert [s for s, _ in states] == [(26, B, 16, 5120), (26, B, 15360)]
+    cache = tuple(sds(s, d or jnp.bfloat16)
+                  for s, d in stacks + states + counters)
+    # the formats donated arrays arrive in, as `init_cache` reads them
+    # off allocated ones on the chip
+    program._layouts = [jax.jit(lambda x: x).lower(c).compile(
+        ).input_formats[0][0] for c in cache[:4]]
+    weights = tuple(sds(shape) for _, shape in z.leaves())
+    assert sum(int(np.prod(w.shape)) for w in weights) == 3_029_337_472
+    compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
+        weights, cache, sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, 1), jnp.int32), sds((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    for i in range(5):
+        assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
+    mem = compiled.memory_analysis()
+    # the logits (128, 65,536) float32 are 33.5 MB; a layer's states
+    # 41.9 MB, all layers' 1.09 GB
+    assert mem.temp_size_in_bytes < 128 << 20
+    assert dict(program.state_updates[1]) == {
+        "kernel": 26 * B, "kernel_live": 26 * B}
+    assert dict(program.cache_writes[1]) == {"kernel": 2 * 2 * B,
+                                             "kernel_live": 2 * 2 * B}
+    assert dict(program.cache_reads[1]) == {("kernel", 768, 256): 2}
+    assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])

@@ -155,6 +155,27 @@ def test_serve_ouro_phase():
     assert out["retraces"] == 0 and out["programs"] == 2
 
 
+def test_serve_jamba_phase():
+    """The seventh family's phase at a tiny size: the engine's tuple is
+    the parameters' own buffers, the two stacks stay where they are, the
+    scan's positions (real and walked), the live rows' state updates and
+    the attention layer's pairs are counted, coalesced == alone, a
+    repeat is identical, a row that wants no token keeps its state and
+    tail, and the served logits equal the float32 reference's."""
+    small = chip_smoke.jamba_small()
+    assert small.kwargs["units"] // small.kwargs["num_heads"] == 128 \
+        and small.prefill_floor == 512 and small.kwargs["max_length"] == 704
+    kwargs = dict(vocab_size=96, units=64, num_layers=5, num_heads=4,
+                  kv_heads=1, hidden_size=96, attn_period=5, attn_offset=2,
+                  d_state=16, d_conv=4, dt_rank=4, expand=2, max_length=64,
+                  prefill_chunk_tokens=128, grad_req="null")
+    assert set(kwargs) <= set(small.kwargs)
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(1, 3, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_jamba(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
+
+
 def test_serve_cmda_phase():
     """The sixth family's phase at a tiny size: the engine's tuple is the
     parameters' own buffers, the four stacks (two rings among them) stay

@@ -197,6 +197,30 @@ def _band_ref(q, k, v, scale, window):
                        & (i[None, :] > i[:, None] - window))
 
 
+@pytest.mark.parametrize("T,blocks", [(128, None), (384, 128), (512, None)])
+def test_forward_entry_with_twenty_query_heads_over_one_key_head(T, blocks):
+    """Jamba2-3B's attention layers: 20 query heads read the one key
+    head of 128 where it lies (a group that is no power of two; the
+    widest before it was 16), each row to its own length, at the blocks
+    `_block_sizes` gives and at forced blocks of 128."""
+    D, lengths = 128, (T, max(1, (5 * T) // 8), 1)
+    keys = jax.random.split(jax.random.key(T), 3)
+    q, k, v = (jax.random.normal(kk, (3, h, T, D), F32)
+               for kk, h in zip(keys, (20, 1, 1)))
+    out = np.asarray(jax.jit(lambda q, k, v, n: pa.flash_attention_forward(
+        q, k, v, n, scale=D ** -0.5, block_q=blocks, block_k=blocks))(
+        q, k, v, jnp.asarray(lengths, jnp.int32)))
+    assert out.shape == (3, 20, T, D)
+    i = jnp.arange(T)
+    ref = np.asarray(_masked_ref(q, k, v, D ** -0.5,
+                                 i[None, :] <= i[:, None]))
+    rtol, atol = TOL[F32]["fwd"]
+    for b, n in enumerate(lengths):
+        np.testing.assert_allclose(out[b, :, :n], ref[b, :, :n], rtol=rtol,
+                                   atol=atol, err_msg=f"row of {n}")
+        assert not out[b, :, n:].any(), f"row of {n}"
+
+
 def _window_cases():
     for T in (128, 384, 1000):
         for window in (1, 100, 128, 300, T, 2 * T):
