@@ -1178,7 +1178,9 @@ SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
 # -- serve, a seventh family: states beside the stacks --------------------------
 
 def phase_serve_jamba(size, platform):
-    from mxnet_tpu.gluon.model_zoo import jamba
+    import types
+
+    from mxnet_tpu.gluon.model_zoo import _decoder_ops, jamba
     from mxnet_tpu.ops import ssm
 
     steps = size.new_tokens - 1
@@ -1196,8 +1198,16 @@ def phase_serve_jamba(size, platform):
         Tc = ssm.scan_chunk(S)
         walked = sum(-(-n // Tc) * Tc for n in real) \
             if platform == "tpu" else len(real) * S
+        # the token-wise products worked a row chunk's packed tokens in
+        # whole tiles, up to the one that holds its last
+        R = _decoder_ops.chunk_rows(types.SimpleNamespace(**kw), len(real), S)
+        tile = min(jamba._TILE, R * S)
+        worked = sum(-(-sum(real[r:r + R]) // tile) * tile
+                     for r in range(0, len(real), R))
         on = float(platform == "tpu")
         return timing["ssm_positions_prefill"] == Lm * sum(real) \
+            and timing["prefill_positions"] == sum(real) \
+            and timing["prefill_positions_worked"] == worked \
             and timing["ssm_positions_scanned_prefill"] == Lm * walked \
             and timing["ssm_row_updates_decode"] == Lm * len(lens) * steps \
             and timing["attn_pairs_prefill"] \
@@ -1233,7 +1243,7 @@ def phase_serve_jamba(size, platform):
     require([tuple(c.shape) for c in big] == [
         (1, 1, z.kv_heads, z.head_dim, -(-engine._W // 128) * 128)] * 2
         + [(Lm, 1, z.d_state, z.inner),
-           (Lm, 1, (z.d_conv - 1) * z.inner), (5,)]
+           (Lm, 1, (z.d_conv - 1) * z.inner), (7,)]
         and str(big[2].dtype) == "float32",
         f"serve_jamba: cache {[(tuple(c.shape), c.dtype) for c in big]}")
     from benchmark.references import jamba as ref

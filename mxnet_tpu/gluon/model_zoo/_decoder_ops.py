@@ -18,7 +18,12 @@ another; a change here is a change to all, and their cells measure it.
   donated carry and their read-back (docs/observability.md);
 - `by_rows`, `chunk_rows`: a prefill block worked off a few rows at a
   time; `by_tokens`: a few positions at a time; `embed`: a block's way
-  in.
+  in;
+- `packing`, `pack`, `unpack`: a block's real tokens, row after row, at
+  the front of one flat row of positions, so that what acts on one
+  token at a time (`by_tokens` over that row) works no padding, and the
+  way back into rows for what needs a row's order (`jamba.py`'s prefill;
+  MiMo's and Keye's are the next callers).
 
 What a decoder program is apart from its layers (its weights, its cache,
 the step's prologue, the cache write and the attention over the cache)
@@ -27,7 +32,11 @@ is `_decoder_program.py`.
 
 from __future__ import annotations
 
+import collections
+
 _MASKED = -1e30
+# `packing`'s answer
+Packing = collections.namedtuple("Packing", "src slot n last tile")
 
 
 def mm(spec, a, w):
@@ -259,7 +268,24 @@ def by_rows(fn, rows, x, *per_row):
     return lax.fori_loop(0, B // rows, one, (x, extras))
 
 
-def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1):
+def _chunks(tokens, live, S):
+    """The chunks of ``tokens`` positions that begin before position
+    ``live`` (a traced scalar, or None for all) of ``S``."""
+    import jax.numpy as jnp
+
+    return S // tokens if live is None else \
+        jnp.clip((live + tokens - 1) // tokens, 0, S // tokens)
+
+
+def tokens_worked(tokens, live, S):
+    """The positions of ``S`` that `by_tokens` hands its ``fn`` at
+    ``tokens`` a chunk and ``live``: all where one chunk holds them,
+    else whole chunks up to the one that holds position ``live - 1``."""
+    return S if tokens >= S else _chunks(tokens, live, S) * tokens
+
+
+def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1,
+              into=None):
     """``fn(x, *per_token) -> (x or None, extras)`` over the positions
     of a block, ``tokens`` at a time and one chunk after another, so
     that only one chunk's temporaries are alive.  The positions are
@@ -269,8 +295,12 @@ def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1):
     S, .) are cut and filled along 2.  Only the chunks that begin
     before position ``live`` (a traced scalar, or None for all) are
     worked: past it the residual stream stays what it was and the
-    extras stay zero.  ``fn`` may return None for x where it leaves the
-    stream alone.  Whole when one chunk holds every position.
+    extras stay zero, or what ``into`` held: buffers of the extras'
+    shapes to fill in place of fresh zeros (a caller that comes back a
+    layer later with extras of hundreds of megabytes hands the last
+    layer's dead ones in and fills nothing).  ``fn`` may return None
+    for x where it leaves the stream alone.  Whole when one chunk holds
+    every position.
 
     `chunk_rows` never cuts below one row, whose token-wise temporaries
     at a width of 7,168 and 16,384 positions are gigabytes; this is the
@@ -291,10 +321,12 @@ def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1):
     def cut(c):
         return [chunk(a, c, axis) for a, axis in zip(per_token, axes)]
 
-    _, shapes = jax.eval_shape(fn, chunk(x, 0), *cut(0))
+    shapes = into
+    if into is None:
+        _, shapes = jax.eval_shape(fn, chunk(x, 0), *cut(0))
     if isinstance(out_axes, int):
         out_axes = jax.tree_util.tree_map(lambda _: out_axes, shapes)
-    extras = jax.tree_util.tree_map(
+    extras = into if into is not None else jax.tree_util.tree_map(
         lambda s, axis: jnp.zeros(s.shape[:axis] + (S,) + s.shape[axis + 1:],
                                   s.dtype), shapes, out_axes)
 
@@ -306,9 +338,7 @@ def by_tokens(fn, tokens, live, x, *per_token, axes=None, out_axes=1):
         return (x if xc is None else put(x, xc),
                 jax.tree_util.tree_map(put, extras, ex, out_axes))
 
-    chunks = S // tokens if live is None else \
-        jnp.clip((live + tokens - 1) // tokens, 0, S // tokens)
-    return lax.fori_loop(0, chunks, one, (x, extras))
+    return lax.fori_loop(0, _chunks(tokens, live, S), one, (x, extras))
 
 
 def chunk_rows(z, B, S):
@@ -318,3 +348,61 @@ def chunk_rows(z, B, S):
     while B % rows:
         rows -= 1
     return rows
+
+
+# -- a block's real tokens, packed ---------------------------------------------
+
+def packing(lengths, S, tile):
+    """Where a block's real tokens lie when they are packed, row after
+    row, at the front of one flat row of positions: from the rows'
+    ``lengths`` (R,) of ``S`` padded positions each, a `Packing` of
+
+    - ``src`` (1, P) int32: the flat source ``row * S + position`` of
+      every packed slot; a slot past the ``n`` real tokens reads some
+      position of the last row, which nobody reads.  P is ``R * S`` in
+      whole tiles;
+    - ``slot`` (R, S) int32: the packed slot of every (row, position),
+      past a row's length its last real token's, so that the way back
+      into rows is a gather too, and no scatter;
+    - ``n`` (): the real tokens, ``sum(lengths)``;
+    - ``last`` (R,) int32: each row's last real token's slot;
+    - ``tile``: the slots a tile, ``tile`` or the whole of a smaller
+      block: what `by_tokens` is handed with ``live = n``.
+    """
+    import jax.numpy as jnp
+
+    R = lengths.shape[0]
+    tile = min(tile, R * S)
+    P = -(-R * S // tile) * tile
+    held = lengths.astype(jnp.int32)
+    ends = jnp.cumsum(held)
+    starts = ends - held
+    i = jnp.arange(P, dtype=jnp.int32)
+    row = jnp.minimum(jnp.searchsorted(ends, i, side="right"), R - 1)
+    src = row * S + jnp.clip(i - starts[row], 0, S - 1)
+    last = jnp.maximum(ends - 1, 0)
+    slot = jnp.minimum(starts[:, None] + jnp.arange(S, dtype=jnp.int32),
+                       last[:, None])
+    return Packing(src[None], slot, ends[-1], last, tile)
+
+
+def pack(x, src):
+    """Rows ``x`` (R, S, ..) → the packed block (1, P, ..): slot i is
+    ``x`` at `packing`'s ``src[0, i]``."""
+    import jax
+
+    with jax.named_scope("serve.pack"):
+        flat = x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+        return flat.at[src].get(mode="promise_in_bounds",
+                                indices_are_sorted=True)
+
+
+def unpack(x, slot):
+    """The packed block ``x`` (1, P, ..) → rows (R, S, ..): position s
+    of row r is slot `packing`'s ``slot[r, s]``, past the row's length
+    its last real token again."""
+    import jax
+
+    with jax.named_scope("serve.pack"):
+        return x[0].at[slot].get(mode="promise_in_bounds",
+                                 indices_are_sorted=True)

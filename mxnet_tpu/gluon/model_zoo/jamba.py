@@ -33,6 +33,22 @@ on the right to its bucket, so the scan and the convolution are told
 each row's length (`ops/ssm.py`): past it the state does not change, and
 the tail is of the row's last real inputs.  Decode (S = 1) moves each
 live row's state on one position in place.
+
+**A row chunk's residual stream lives packed** (`_decoder_ops.packing`):
+its real tokens, row after row, at the front of one flat row of
+positions.  What acts on one token at a time (a mixer's way in with the
+first norm, its way out with the gate, the SwiGLU with its norm) works
+that row in tiles of ``_TILE`` packed tokens, only the tiles that hold a
+real token (`_decoder_ops.by_tokens`), one loop between two layers'
+rows: of the served cell's 128 x 512 positions three in five are
+padding, and these products are four fifths of the prefill.  What needs
+a row's order is laid back in rows (`unpack`: the scan's input before
+the convolution, ``W_x`` and the scan, which run on (R, S, E) to each
+row's length; q, k and v before the row write and the attention kernel)
+and what it puts out is packed again (`pack`) to meet the gate, which
+never left the packed layout.  A padded position's values were never
+read by anyone, so no request's numbers change.  `packing`, `pack` and
+`unpack` name no family: MiMo's and Keye's prefills call them next.
 """
 
 from __future__ import annotations
@@ -44,6 +60,8 @@ from . import _decoder_ops as _ops
 from ._decoder_program import DecoderProgram
 
 _LANE = 128
+# packed tokens a tile of the prefill's token-wise products
+_TILE = 512
 _ALL_LEAVES = ("ln1_gamma", "ln2_gamma", "gate_weight", "up_weight",
                "down_weight")
 _SSM_LEAVES = ("in_weight", "conv_weight", "conv_bias", "x_weight",
@@ -141,22 +159,29 @@ def _ssm_out(z, p, x, y, gate):
                            p["out_weight"])
 
 
-def _qkv(z, p, g1, x):
-    """x (B, S, C) → q (B, H, S, d), k and v (B, K, S, d) float32,
-    unscaled, unrotated."""
+def _attn_in(z, p, g1, x):
+    """x (B, S, C) → q (B, S, H d), k and v (B, S, K d) float32, heads
+    side by side, unscaled, unrotated."""
     import jax
 
-    B, S, _ = x.shape
     with jax.named_scope("serve.attn_qkv"):
         u = _ops.rms_norm(x, g1, z.eps)
+        return tuple(_ops.mm("bsc,gc->bsg", u, p[n])
+                     for n in ("q_weight", "k_weight", "v_weight"))
 
-        def heads(w, n):
-            return _ops.mm("bsc,gc->bsg", u, w).reshape(
-                B, S, n, z.head_dim).transpose(0, 2, 1, 3)
 
-        return (heads(p["q_weight"], z.num_heads),
-                heads(p["k_weight"], z.kv_heads),
-                heads(p["v_weight"], z.kv_heads))
+def _heads(z, a):
+    """(B, S, n d), heads side by side → (B, n, S, d), heads first."""
+    B, S, G = a.shape
+    return a.reshape(B, S, G // z.head_dim, z.head_dim).transpose(0, 2, 1, 3)
+
+
+def _attn_out(z, p, x, a):
+    """x + a Wo for a (B, S, H d), heads side by side."""
+    import jax
+
+    with jax.named_scope("serve.attn_out"):
+        return x + _ops.mm("bsg,cg->bsc", a, p["o_weight"])
 
 
 def _block_attention(z, q, k, v, lengths, tally=None):
@@ -216,7 +241,7 @@ def _forward(z, names, ids, *weights):
             x = _ssm_out(z, p, x, y, gate)
         else:
             p = _of_layer(w, _ATTN_LEAVES, j)
-            q, k, v = _qkv(z, p, g1, x)
+            q, k, v = (_heads(z, t) for t in _attn_in(z, p, g1, x))
             a = _block_attention(z, q, k.astype(dt), v.astype(dt), None)
             x = x + _ops.mm("bhsd,chd->bsc", a, p["o_weight"].reshape(
                 -1, z.num_heads, z.head_dim))
@@ -310,8 +335,9 @@ class JambaProgram(DecoderProgram):
                  ((Lm, B, (z.d_conv - 1) * z.inner), None)],
                 # the scan's positions walked and real, the decode
                 # steps' row updates, the attention layers' pairs in a
-                # prefill and positions in a decode step
-                [((5,), jnp.uint32)])
+                # prefill and positions in a decode step, the positions
+                # the prefill's token-wise tiles worked and the real ones
+                [((7,), jnp.uint32)])
 
     def counters(self, cache):
         """The counters of one served group, read back once
@@ -322,7 +348,8 @@ class JambaProgram(DecoderProgram):
         c = np.asarray(cache[4]).astype(np.int64)
         out = dict(zip(("ssm_positions_scanned_prefill",
                         "ssm_positions_prefill", "ssm_row_updates_decode",
-                        "attn_pairs_prefill", "attn_positions_decode"),
+                        "attn_pairs_prefill", "attn_positions_decode",
+                        "prefill_positions_worked", "prefill_positions"),
                        (int(n) for n in c)))
         scanned = out["ssm_positions_scanned_prefill"]
         # of the positions the prefill's scans walked, those past their
@@ -332,17 +359,27 @@ class JambaProgram(DecoderProgram):
         if scanned:
             out["ssm_scan_padded_pct"] = \
                 100.0 * out["ssm_positions_padded_prefill"] / scanned
+        # of the positions the token-wise tiles worked (one layer's
+        # worth: every layer works the same tiles), those that hold no
+        # token
+        worked = out["prefill_positions_worked"]
+        out["prefill_positions_padded"] = worked - out["prefill_positions"]
+        if worked:
+            out["prefill_tokens_padded_pct"] = \
+                100.0 * out["prefill_positions_padded"] / worked
         return out
 
     # -- the traced step -------------------------------------------------------
 
     def body(self, ctx, w, cache, toks):
         """S > 1 is a prefill from an empty cache: a row chunk through
-        all layers before the next, attention inside the block, the
-        scan to each row's length.  S = 1 attends over the caches and
-        moves a state on one position; a row that wants no token
-        attends to nothing, keeps its state and tail and is counted
-        nowhere."""
+        all layers before the next, its real tokens packed for what
+        acts on one token at a time and laid back in rows for the
+        convolution, the scan to each row's length and attention inside
+        the block.  S = 1 attends over the caches and moves a state on
+        one position: a row is its one token, so the block is packed as
+        it lies; a row that wants no token attends to nothing, keeps its
+        state and tail and is counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -359,16 +396,61 @@ class JambaProgram(DecoderProgram):
             (keys, values, states, tails, counters).  Returns (carry,
             the rows' logits)."""
             ck, cv, states, tails, counts = carry
-            x, _, _ = _ops.embed(w["embed_weight"], toks, pos, last)
             R = toks.shape[0]
             # a row's positions, itself included: all of them in a
             # prefill, none of a decode row that wants no token
             held = ctx.held if decode else last + 1
-            for i, kind in enumerate(z.kinds):
-                g1, j = w["ln1_gamma"][i], z.place[i]
+            if decode:
+                # a row is its one token: the block is packed as it lies
+                tile, real, spare, at = 1, None, None, toks
+                to_rows, pack = (lambda a: a), (lambda a, at: a)
+                x, _, _ = _ops.embed(w["embed_weight"], toks, pos, last)
+            else:
+                pk = _ops.packing(held, S, _TILE)
+                tile, real, at, pack = pk.tile, pk.n, pk.src, _ops.pack
+                to_rows = lambda a: _ops.unpack(a, pk.slot)
+                x, _, _ = _ops.embed(w["embed_weight"], pack(toks, at),
+                                     jnp.zeros((1,), jnp.int32),
+                                     real[None] - 1)
+                # what a Mamba layer's way in fills: the scan's input,
+                # dead once it lies in rows, and the gates of every
+                # other layer by turns (the last layer's is being read)
+                spare = [jnp.zeros((1, x.shape[1], E), jnp.float32)] * 3
+            # what the last mixer left: in rows, to be packed a tile at
+            # a time, and what never left the packed block
+            left, kept = (), ()
+            for i, kind in enumerate(z.kinds + [None]):
+                # one token at a time, the packed block's live tiles
+                # only: the mixer's way out and the SwiGLU of the layer
+                # before, this layer's norm and its mixer's way in
+                def tokenwise(x, at, *kept, i=i, kind=kind, left=left):
+                    if i:
+                        outs = tuple(pack(a, at) for a in left) + kept
+                        before, q = z.kinds[i - 1], z.place[i - 1]
+                        x = _ssm_out(z, _of_layer(w, _SSM_LEAVES, q), x,
+                                     *outs) if before == "ssm" else \
+                            _attn_out(z, _of_layer(w, _ATTN_LEAVES, q), x,
+                                      *outs)
+                        x = _mlp(z, w, i - 1, x)
+                    if kind is None:
+                        return x, ()
+                    enter = _ssm_in if kind == "ssm" else _attn_in
+                    return (x if i else None), enter(
+                        z, _of_layer(w, _SSM_LEAVES if kind == "ssm"
+                                     else _ATTN_LEAVES, z.place[i]),
+                        w["ln1_gamma"][i], x)
+
+                j = z.place[i] if kind else None
+                into = (spare[0], spare[1 + j % 2]) \
+                    if spare and kind == "ssm" else None
+                x, ins = _ops.by_tokens(tokenwise, tile, real, x, at, *kept,
+                                        into=into)
                 if kind == "ssm":
                     p = _of_layer(w, _SSM_LEAVES, j)
-                    a, gate = _ssm_in(z, p, g1, x)
+                    a, gate = ins
+                    if spare:
+                        spare[0], spare[1 + j % 2] = a, gate
+                    a = to_rows(a)
                     with jax.named_scope("serve.ssm_conv"):
                         c, tails = ctx.conv(
                             tails, j, a, p["conv_weight"].T, p["conv_bias"],
@@ -386,47 +468,43 @@ class JambaProgram(DecoderProgram):
                             y, states = ctx.scan(states, j, c, dt, A, Bm,
                                                  Cm, D, held, row=row,
                                                  first=2)
-                    x = _ssm_out(z, p, x, y, gate)
-                else:
-                    p = _of_layer(w, _ATTN_LEAVES, j)
-                    q, k, v = _qkv(z, p, g1, x)
-                    k, v = k.astype(ck.dtype), v.astype(ck.dtype)
+                    left, kept = (y,), (gate,)
+                elif kind == "attn":
+                    q, k, v = (to_rows(t) for t in ins)
                     with jax.named_scope("serve.cache_write"):
                         # row b's block at [j, row + b, :, :, pos[b]:]
+                        k = _heads(z, k.astype(ck.dtype))
+                        v = _heads(z, v.astype(ck.dtype))
                         ck, cv = ctx.write(
                             (ck, cv), (k.swapaxes(2, 3), v.swapaxes(2, 3)),
                             j, pos, row=row)
                     if decode:
                         with jax.named_scope("serve.attn"):
                             a = ctx.attend(
-                                (q[:, :, 0] * d ** -0.5).astype(
+                                (q[:, 0] * d ** -0.5).astype(
                                     ck.dtype).reshape(R, z.kv_heads,
                                                       z.groups, d),
-                                ck, cv, j)
-                        with jax.named_scope("serve.attn_out"):
-                            x = x + _ops.mm("bg,cg->bc", a.reshape(R, -1),
-                                            p["o_weight"])[:, None]
+                                ck, cv, j).reshape(R, 1, -1)
                     else:
-                        a = _block_attention(z, q, k, v, held, ctx.attends)
-                        with jax.named_scope("serve.attn_out"):
-                            # heads first as the kernel left them
-                            x = x + _ops.mm(
-                                "bhsd,chd->bsc", a, p["o_weight"].reshape(
-                                    -1, z.num_heads, d))
-                x = _mlp(z, w, i, x)
+                        # heads side by side again for the way out
+                        a = _block_attention(
+                            z, _heads(z, q), k, v, held,
+                            ctx.attends).transpose(0, 2, 1, 3).reshape(
+                                R, S, -1)
+                    left, kept = (a,), ()
             with jax.named_scope("serve.head"):
-                logits = _head(z, w, jnp.take_along_axis(
-                    x, last[:, None, None], axis=1)[:, 0])
+                logits = _head(z, w, x[:, 0] if decode else x[0, pk.last])
             n = held.astype(jnp.uint32)
             if decode:
                 live = jnp.sum(ctx.live, dtype=jnp.uint32)
-                add = [0, 0, Lm * live, 0, La * jnp.sum(n)]
+                add = [0, 0, Lm * live, 0, La * jnp.sum(n), 0, 0]
             else:
                 Tc = ssm.scan_chunk(S)
                 walked = jnp.sum((n + Tc - 1) // Tc * Tc) \
                     if ctx.updates["kernel"] else jnp.uint32(R * S)
                 add = [Lm * walked, Lm * jnp.sum(n), 0,
-                       La * jnp.sum(n * (n + 1) // 2), 0]
+                       La * jnp.sum(n * (n + 1) // 2), 0,
+                       _ops.tokens_worked(tile, real, x.shape[1]), real]
             counts = counts + jnp.stack([jnp.uint32(a) for a in add])
             return (ck, cv, states, tails, counts), logits
 

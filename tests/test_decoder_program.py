@@ -5,7 +5,9 @@ served by `ServingEngine` and held to a plain NumPy walk of its weights;
 (b) for each of the six families' tiny models, what the base does on a
 family's behalf: the layouts read once, the tallies filled while a step
 is traced, and what a row write is told of ``live``; (c) the sampling
-rule's one home."""
+rule's one home; (d) a block's real tokens packed and laid back in rows
+(`_decoder_ops.packing`, `pack`, `unpack`) and the token-wise tiles over
+the packed block (`by_tokens`, `tokens_worked`)."""
 
 import functools
 import os
@@ -22,7 +24,7 @@ import mxnet_tpu as mx                                      # noqa: E402
 from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.gluon.block import HybridBlock               # noqa: E402
 from mxnet_tpu.gluon.model_zoo import (                     # noqa: E402
-    cohere2_moe, gpt, keye_vl2, kimi_k2, mimo_v2, ouro)
+    _decoder_ops, cohere2_moe, gpt, keye_vl2, kimi_k2, mimo_v2, ouro)
 from mxnet_tpu.gluon.model_zoo._decoder_program import (    # noqa: E402
     DecoderProgram)
 from mxnet_tpu.ops import cache_write                       # noqa: E402
@@ -223,3 +225,97 @@ def test_the_sampling_rule_has_one_home():
     logits = np.array([[0.1, 2.0, 2.0], [3.0, -1.0, 0.0]], np.float32)
     np.testing.assert_array_equal(sampling._sample(logits, None, None),
                                   [1, 0])
+
+
+# -- (d) a block's real tokens, packed and laid back in rows ---------------------
+
+PACK_S = 160
+RAGGED = {"ragged": (1, 2, 127, 128, 129, PACK_S), "full": (PACK_S,) * 4,
+          "ones": (1,) * 5}
+
+
+def _packed(lens, tile, width=3, seed=0):
+    import jax.numpy as jnp
+
+    x = np.random.RandomState(seed).randn(len(lens), PACK_S, width).astype(
+        np.float32)
+    pk = _decoder_ops.packing(jnp.asarray(lens, jnp.int32), PACK_S, tile)
+    return x, pk
+
+
+@pytest.mark.parametrize("lens", RAGGED.values(), ids=RAGGED.keys())
+def test_pack_then_unpack_gives_back_every_real_position(lens):
+    """Bit for bit; past a row's length its last real token again; ``n``
+    and the last tokens' slots are the lengths' own sums; the packed
+    block is the rows' real tokens, row after row, in whole tiles."""
+    import jax.numpy as jnp
+
+    x, pk = _packed(lens, 64)
+    R, n = len(lens), sum(lens)
+    ends = np.cumsum(lens)
+    assert int(pk.n) == n and pk.tile == 64
+    np.testing.assert_array_equal(pk.last, ends - 1)
+    assert pk.src.shape == (1, -(-R * PACK_S // 64) * 64) \
+        and pk.slot.shape == (R, PACK_S)
+    packed = _decoder_ops.pack(jnp.asarray(x), pk.src)
+    back = np.asarray(_decoder_ops.unpack(packed, pk.slot))
+    packed = np.asarray(packed)
+    assert packed.shape == pk.src.shape + (3,)
+    np.testing.assert_array_equal(
+        packed[0, :n], np.concatenate([x[r, :k] for r, k in enumerate(lens)]))
+    assert back.shape == x.shape
+    for r, k in enumerate(lens):
+        np.testing.assert_array_equal(back[r, :k], x[r, :k])
+        np.testing.assert_array_equal(
+            back[r, k:], np.broadcast_to(x[r, k - 1], (PACK_S - k, 3)))
+    # a block smaller than a tile is one tile, whole
+    assert _decoder_ops.packing(pk.n[None], 8, 64).src.shape == (1, 8)
+
+
+@pytest.mark.parametrize("tile", [32, 64, 4096])
+@pytest.mark.parametrize("lens", RAGGED.values(), ids=RAGGED.keys())
+def test_the_tiled_product_is_the_whole_product_on_the_tiles_that_hold_a_token(
+        lens, tile):
+    """`by_tokens` over the packed block with ``live`` the real tokens:
+    ``ceil(n / tile)`` tiles are worked (`tokens_worked` counts their
+    positions, as the family's counter does), the product on every real
+    token is the whole block's, the stream past the worked tiles stays
+    what it was and the extras there what ``into`` held."""
+    import jax
+    import jax.numpy as jnp
+
+    x, pk = _packed(lens, tile, width=8, seed=1)
+    w = np.random.RandomState(2).randn(8, 8).astype(np.float32)
+    n, P = sum(lens), pk.src.shape[1]
+    whole = P <= tile
+    want_worked = P if whole else -(-n // tile) * tile
+    assert int(_decoder_ops.tokens_worked(pk.tile, pk.n, P)) == want_worked
+
+    def fn(x, mark):
+        y = x @ w
+        return x + y, (y, mark + 1)
+
+    @jax.jit
+    def run(x, lens):
+        pk = _decoder_ops.packing(lens, PACK_S, tile)
+        xp = _decoder_ops.pack(x, pk.src)
+        return xp, _decoder_ops.by_tokens(
+            fn, pk.tile, pk.n, xp, jnp.zeros(xp.shape[:2], jnp.int32),
+            into=(jnp.full(xp.shape, 7.0), jnp.full(xp.shape[:2], 7)))
+
+    xp, (out, (y, mark)) = jax.tree_util.tree_map(
+        np.asarray, run(x, jnp.asarray(lens, jnp.int32)))
+    ref = np.asarray(jnp.asarray(xp) @ w)     # the whole block at once
+    # the tiles worked, counted by what they left behind
+    assert int((mark == 1).sum()) == want_worked
+    np.testing.assert_array_equal(y[0, :want_worked], ref[0, :want_worked])
+    np.testing.assert_array_equal(out[0, :want_worked],
+                                  (xp + ref)[0, :want_worked])
+    np.testing.assert_array_equal(out[0, want_worked:], xp[0, want_worked:])
+    assert (y[0, want_worked:] == 7.0).all() \
+        and (mark[0, want_worked:] == 7).all()
+    # and laid back in rows every real position has its product
+    back = np.asarray(_decoder_ops.unpack(jnp.asarray(y), pk.slot))
+    for r, k in enumerate(lens):
+        np.testing.assert_allclose(back[r, :k], x[r, :k] @ w, rtol=1e-5,
+                                   atol=1e-5)

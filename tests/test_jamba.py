@@ -5,8 +5,9 @@ lengths in one padded bucket, (c) the carried state and tail: what the
 prefill leaves is the reference's, and zeroing either between prefill
 and decode fails the comparison by a stated margin, as does a token 256
 positions back under the configuration's seeding, (d) the counters and
-``live``, (e) a float8 control for the bfloat16 tolerance, and the
-engine's pins for the family."""
+``live``, (e) a float8 control for the bfloat16 tolerance, (f) the
+packed prefill against the row layout's, in tiles and across row chunks,
+and the engine's pins for the family."""
 
 import json
 import os
@@ -324,6 +325,121 @@ def test_the_float8_reference_fails_the_bfloat16_tolerance(served_bf16):
     assert worst > 3 * BF16_ATOL, worst
 
 
+# -- (f) the prefill works its real tokens only ---------------------------------
+
+def _row_layout_prefill(net, toks, lens):
+    """The prefill in the row layout, every product over the whole
+    padded block (R, S, .), from the family's own pieces: (logits at
+    each row's last token, states, tails, keys, values)."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.gluon.model_zoo import _decoder_ops as ops
+    from mxnet_tpu.ops import ssm
+
+    z = net._sizes
+    w = dict(zip(net._names, net.decoder_program().weights()))
+    lens = jnp.asarray(lens, jnp.int32)
+    x = jnp.take(w["embed_weight"], jnp.asarray(toks), axis=0)
+    states, tails, keys, values = [], [], [], []
+    for i, kind in enumerate(z.kinds):
+        g1, j = w["ln1_gamma"][i], z.place[i]
+        if kind == "ssm":
+            p = jamba._of_layer(w, jamba._SSM_LEAVES, j)
+            a, gate = jamba._ssm_in(z, p, g1, x)
+            c, tail = ssm.causal_conv_rows(a, p["conv_weight"].T,
+                                           p["conv_bias"], lens)
+            dt, Bm, Cm = jamba._ssm_x(z, p, c)
+            A, D = jamba._ssm_consts(p)
+            y, h = ssm.selective_scan_rows(c, dt, A, Bm, Cm, D, lens)
+            x = jamba._ssm_out(z, p, x, y, gate)
+            states.append(h), tails.append(tail)
+        else:
+            p = jamba._of_layer(w, jamba._ATTN_LEAVES, j)
+            q, k, v = (jamba._heads(z, t)
+                       for t in jamba._attn_in(z, p, g1, x))
+            a = jamba._block_attention(z, q, k, v, lens)
+            x = x + ops.mm("bhsd,chd->bsc", a, p["o_weight"].reshape(
+                -1, z.num_heads, z.head_dim))
+            keys.append(k.swapaxes(2, 3)), values.append(v.swapaxes(2, 3))
+        x = jamba._mlp(z, w, i, x)
+    last = jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)[:, 0]
+    return tuple(np.asarray(t) for t in (
+        jamba._head(z, w, last), jnp.stack(states), jnp.stack(tails),
+        jnp.stack(keys), jnp.stack(values)))
+
+
+# two rows a chunk of the bucket of 4 x 64, tiles of 16 packed tokens:
+# 41 tokens in 3 tiles of the first chunk's 8, 81 in 6 of the second's
+TILED = dict(tile=16, chunk=128, lens=(1, 40, 17, 64), worked=(3 + 6) * 16)
+
+
+@pytest.fixture
+def tiled(monkeypatch):
+    monkeypatch.setattr(jamba, "_TILE", TILED["tile"])
+    net, _ = _net(_config(), prefill_chunk_tokens=TILED["chunk"])
+    return net, serving.ServingEngine(net, batch_buckets=(4,))
+
+
+def test_the_packed_prefill_equals_the_row_layouts_across_two_row_chunks(
+        tiled):
+    """Ragged lengths, two row chunks, the token-wise products in tiles
+    of the packed block: the logits, the states, the tails and every
+    real position's cached keys and values are the row layout's, and
+    the counters the prompts' own sums."""
+    net, eng = tiled
+    lens = TILED["lens"]
+    prompts = _prompts(seed=12, lens=lens)
+    B, n, toks = _padded_group(eng, prompts)
+    assert (B, toks.shape[1]) == (4, 64)
+    cache, logits, _, _ = eng._call(B, 64, eng.init_cache(B),
+                                    np.zeros(B, np.int32), n - 1, toks)
+    want = _row_layout_prefill(net, toks, n)
+    np.testing.assert_allclose(np.asarray(logits), want[0], atol=ATOL,
+                               rtol=RTOL)
+    np.testing.assert_allclose(np.asarray(cache[2]), want[1], atol=ATOL,
+                               rtol=RTOL)
+    np.testing.assert_allclose(np.asarray(cache[3]), want[2], atol=ATOL,
+                               rtol=RTOL)
+    for got, rows in ((np.asarray(cache[0]), want[3]),
+                      (np.asarray(cache[1]), want[4])):
+        for r, k in enumerate(lens):
+            np.testing.assert_allclose(got[:, r, :, :, :k], rows[:, r, :, :, :k],
+                                       atol=ATOL, rtol=RTOL)
+    counts = eng._program.counters(cache)
+    assert counts["prefill_positions"] == sum(lens)
+    assert counts["prefill_positions_worked"] == TILED["worked"]
+    assert counts["prefill_positions_padded"] == TILED["worked"] - sum(lens)
+    assert counts["prefill_tokens_padded_pct"] == pytest.approx(
+        100.0 * (1 - sum(lens) / TILED["worked"]))
+    assert counts["ssm_positions_prefill"] == LM * sum(lens)
+
+
+def test_a_packed_group_in_tiles_is_bitwise_the_requests_served_alone(tiled):
+    """A request alone lies in other tiles, beside three pad rows of one
+    token, than in its group: its tokens and logits are the same bits."""
+    _, eng = tiled
+    prompts = _prompts(seed=13, lens=TILED["lens"])
+    toks, logits = _walk(eng, prompts, 3)
+    for i, p in enumerate(prompts):
+        t1, l1 = _walk(eng, [p], 3)
+        np.testing.assert_array_equal(t1[0], toks[i])
+        np.testing.assert_array_equal(l1[0], logits[i])
+
+
+def test_the_prefills_counters_where_one_tile_holds_the_block(served):
+    """At the family's own tile a block of 4 x 64 is one tile, worked
+    whole: every position of the bucket, of which the prompts' are
+    real (a pad row holds one token)."""
+    _, _, _, eng = served
+    prompts = _prompts(seed=14, lens=(5, 33))
+    _, timings = eng.serve_group(prompts, 2)
+    S = timings["bucket"][1]
+    assert timings["prefill_positions"] == 5 + 33 + 2
+    assert timings["prefill_positions_worked"] == 4 * S
+    assert timings["prefill_tokens_padded_pct"] == pytest.approx(
+        100.0 * (1 - 40 / (4 * S)))
+
+
 # -- the engine's pins for the seventh family ----------------------------------
 
 def test_the_caches_shapes_no_retrace_a_mesh_refused_and_reload(served):
@@ -332,7 +448,7 @@ def test_the_caches_shapes_no_retrace_a_mesh_refused_and_reload(served):
     cache = eng.init_cache(4)
     assert [c.shape for c in cache] == [
         (LA, 4, 1, 16, 128), (LA, 4, 1, 16, 128), (LM, 4, 16, 128),
-        (LM, 4, 3 * 128), (5,)]
+        (LM, 4, 3 * 128), (7,)]
     assert cache[2].dtype == np.float32
     pinned = serving.trace_count()
     eng.serve_group(_prompts(seed=8), 3)
