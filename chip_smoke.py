@@ -845,15 +845,40 @@ def moe_rows_hold(timing):
         >= timing["moe_pairs_decode"] > 0
 
 
+def packed_positions(kwargs, tile, real, S):
+    """The positions a packed prefill's token-wise tiles work, one
+    layer's worth: each row chunk's real tokens (``real``: every row's
+    length, the pad rows' too) in whole tiles, up to the one that holds
+    its last."""
+    import types
+
+    from mxnet_tpu.gluon.model_zoo import _decoder_ops
+
+    R = _decoder_ops.chunk_rows(types.SimpleNamespace(**kwargs), len(real), S)
+    tile = min(tile, R * S)
+    return sum(-(-sum(real[r:r + R]) // tile) * tile
+               for r in range(0, len(real), R))
+
+
 def phase_serve_mimo(size, platform):
+    import inspect
+
     from mxnet_tpu.gluon.model_zoo import mimo_v2
 
     layers = sum(1 for m in size.kwargs["moe_layers"] if m)
+    # the constructor's own default where the benchmark's file gives none
+    chunk = {"prefill_chunk_tokens": inspect.signature(
+        mimo_v2.MiMoV2Model).parameters["prefill_chunk_tokens"].default,
+        **size.kwargs}
 
     def counters_hold(timing, lens, pads):
+        real = lens + (1,) * pads
         return 0 < timing["moe_pairs_prefill"] <= sum(size.prompt_lens) \
             * layers * size.kwargs["experts_per_token"] \
-            and moe_rows_hold(timing)
+            and moe_rows_hold(timing) \
+            and timing["prefill_positions"] == sum(real) \
+            and timing["prefill_positions_worked"] == packed_positions(
+                chunk, mimo_v2._TILE, real, timing["bucket"][1])
 
     # four stacks: two kinds of cache, keys and values
     return serve_family("serve_mimo", mimo_v2.MiMoV2Model, size, platform,
@@ -1178,9 +1203,7 @@ SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
 # -- serve, a seventh family: states beside the stacks --------------------------
 
 def phase_serve_jamba(size, platform):
-    import types
-
-    from mxnet_tpu.gluon.model_zoo import _decoder_ops, jamba
+    from mxnet_tpu.gluon.model_zoo import jamba
     from mxnet_tpu.ops import ssm
 
     steps = size.new_tokens - 1
@@ -1198,12 +1221,7 @@ def phase_serve_jamba(size, platform):
         Tc = ssm.scan_chunk(S)
         walked = sum(-(-n // Tc) * Tc for n in real) \
             if platform == "tpu" else len(real) * S
-        # the token-wise products worked a row chunk's packed tokens in
-        # whole tiles, up to the one that holds its last
-        R = _decoder_ops.chunk_rows(types.SimpleNamespace(**kw), len(real), S)
-        tile = min(jamba._TILE, R * S)
-        worked = sum(-(-sum(real[r:r + R]) // tile) * tile
-                     for r in range(0, len(real), R))
+        worked = packed_positions(kw, jamba._TILE, real, S)
         on = float(platform == "tpu")
         return timing["ssm_positions_prefill"] == Lm * sum(real) \
             and timing["prefill_positions"] == sum(real) \

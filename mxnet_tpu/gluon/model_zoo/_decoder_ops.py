@@ -22,8 +22,9 @@ another; a change here is a change to all, and their cells measure it.
 - `packing`, `pack`, `unpack`: a block's real tokens, row after row, at
   the front of one flat row of positions, so that what acts on one
   token at a time (`by_tokens` over that row) works no padding, and the
-  way back into rows for what needs a row's order (`jamba.py`'s prefill;
-  MiMo's and Keye's are the next callers).
+  way back into rows for what needs a row's order (`jamba.py`'s and
+  `mimo_v2.py`'s prefills; Keye's is the next caller);
+  `packed_counters`: the two counters such a prefill keeps, read back.
 
 What a decoder program is apart from its layers (its weights, its cache,
 the step's prologue, the cache write and the attention over the cache)
@@ -406,3 +407,19 @@ def unpack(x, slot):
     with jax.named_scope("serve.pack"):
         return x[0].at[slot].get(mode="promise_in_bounds",
                                  indices_are_sorted=True)
+
+
+def packed_counters(c):
+    """A served group's packed-prefill counters ``c`` (2,), read back
+    (docs/observability.md has the table): the positions the token-wise
+    tiles worked (one layer's worth: every layer works the same tiles,
+    summed over row chunks), the real ones, and of the worked those that
+    hold no token."""
+    import numpy as np
+
+    worked, real = (int(n) for n in np.asarray(c))
+    out = {"prefill_positions_worked": worked, "prefill_positions": real,
+           "prefill_positions_padded": worked - real}
+    if worked:
+        out["prefill_tokens_padded_pct"] = 100.0 * (worked - real) / worked
+    return out

@@ -48,7 +48,7 @@ row's length; q, k and v before the row write and the attention kernel)
 and what it puts out is packed again (`pack`) to meet the gate, which
 never left the packed layout.  A padded position's values were never
 read by anyone, so no request's numbers change.  `packing`, `pack` and
-`unpack` name no family: MiMo's and Keye's prefills call them next.
+`unpack` name no family: MiMo's prefill calls them too, Keye's next.
 """
 
 from __future__ import annotations
@@ -348,8 +348,7 @@ class JambaProgram(DecoderProgram):
         c = np.asarray(cache[4]).astype(np.int64)
         out = dict(zip(("ssm_positions_scanned_prefill",
                         "ssm_positions_prefill", "ssm_row_updates_decode",
-                        "attn_pairs_prefill", "attn_positions_decode",
-                        "prefill_positions_worked", "prefill_positions"),
+                        "attn_pairs_prefill", "attn_positions_decode"),
                        (int(n) for n in c)))
         scanned = out["ssm_positions_scanned_prefill"]
         # of the positions the prefill's scans walked, those past their
@@ -359,14 +358,7 @@ class JambaProgram(DecoderProgram):
         if scanned:
             out["ssm_scan_padded_pct"] = \
                 100.0 * out["ssm_positions_padded_prefill"] / scanned
-        # of the positions the token-wise tiles worked (one layer's
-        # worth: every layer works the same tiles), those that hold no
-        # token
-        worked = out["prefill_positions_worked"]
-        out["prefill_positions_padded"] = worked - out["prefill_positions"]
-        if worked:
-            out["prefill_tokens_padded_pct"] = \
-                100.0 * out["prefill_positions_padded"] / worked
+        out.update(_ops.packed_counters(c[5:]))
         return out
 
     # -- the traced step -------------------------------------------------------

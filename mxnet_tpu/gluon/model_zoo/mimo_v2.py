@@ -34,8 +34,9 @@ body: two kinds of cache, four stacks:
   writes one slot and attends over the ring, masking slots not yet
   written (a slot's position is the latest one congruent to it, so it
   is never older than the window);
-- a small int32 array of expert-layer counters rides in the same
-  donated carry and is read back once a group (``counters``).
+- two small arrays of counters ride in the same donated carry and are
+  read back once a group (``counters``): the expert layers', and the
+  positions the prefill's token-wise tiles worked beside the real ones.
 
 Prefill attention runs in blocks of ``attn_block`` keys with a running
 maximum and sum (the sink enters the sum once), window layers visiting
@@ -43,6 +44,26 @@ only the blocks their window reaches; no ``(B, H, S, W)`` array exists
 for S > 1.  Rows are worked off ``prefill_chunk_tokens`` tokens at a
 time through attention and the dense feed-forward, so that a bucket of
 64 x 1,024 tokens fits beside the weights.
+
+**What a token needs no other token for works a row chunk's real tokens
+only** (`_decoder_ops.packing`; the engine pads a prompt on the right to
+its bucket, and of the served cell's 64 x 1,024 positions two in three
+are padding): the first norm, the three projections, the rotation (by a
+token's own position, which is packed with it), the scales and the
+casts, and after attention the way out, the dense SwiGLU or the second
+norm and the router, run on the chunk's tokens packed at the front of
+one flat row, in tiles of ``_TILE``, only the tiles that hold a token
+(`_decoder_ops.by_tokens`).  What needs rows gets rows: q, k and v are
+laid back (`unpack`) for `_attend_blocks`, which takes no lengths and
+walks every position of every row, and for the cache writes; the stream
+and the router's choice are laid back for the experts, which run over
+the whole bucket between two layers with ``valid`` as before.  Past its
+length a row holds its last real token again, which nobody reads:
+causal attention never looks right of a query, and the rings and the
+decode steps read no position past a row's last.  The path is taken
+where the rows' lengths are given: the cache-less forward pass hands
+none and keeps its rows, and a decode step is one token a row, packed
+as it lies.
 """
 
 from __future__ import annotations
@@ -52,6 +73,9 @@ from ..block import HybridBlock
 from . import _decoder_ops as _ops
 from ._decoder_ops import _MASKED
 from ._decoder_program import DecoderProgram
+
+# packed tokens a tile of the prefill's token-wise products
+_TILE = 512
 
 
 # -- pieces shared by the forward pass and the cached step ---------------------
@@ -239,21 +263,70 @@ def _feed_forward_front(z, i, p, x):
     return _dense(z, p, x), ()
 
 
-def _block_layer(z, i, p, x, pos):
+def _block_layer(z, i, p, x, pos, held=None):
     """Layer i on a block (B, S, C) that attends inside itself, as far
     as a row needs no other row: attention, then the dense feed-forward
-    or the router.  Returns (x, (k, v, route))."""
+    or the router.  Returns (x, (k, v, route)).
+
+    Given the rows' lengths ``held`` (B,), what acts on one token at a
+    time works the block's real tokens packed, a tile at a time, and
+    only attention works rows; past its length a row then holds its
+    last real token's values again."""
     import jax
+    import jax.numpy as jnp
 
     kind = z.layer_types[i]
-    blk = min(x.shape[1], z.attn_block)
-    q, k, v = _qkv(z, kind, p, x, pos)
-    with jax.named_scope(f"serve.attn_{kind}"):
-        a = _attend_blocks(q, k, v, _sink(z, kind, p),
-                           z.window if kind == "window" else None, blk)
-        x = _ops.attn_out(z, p, x, a)
-    x, route = _feed_forward_front(z, i, p, x)
-    return x, (k, v, route)
+    B, S, C = x.shape
+    K = z.kv_heads[kind]
+    G = z.num_heads // K
+
+    def attend(q, k, v):
+        with jax.named_scope(f"serve.attn_{kind}"):
+            return _attend_blocks(q, k, v, _sink(z, kind, p),
+                                  z.window if kind == "window" else None,
+                                  min(S, z.attn_block))
+
+    def way_out(x, a):
+        with jax.named_scope(f"serve.attn_{kind}"):
+            return _ops.attn_out(z, p, x, a)
+
+    if held is None:
+        q, k, v = _qkv(z, kind, p, x, pos)
+        x, route = _feed_forward_front(z, i, p, way_out(x, attend(q, k, v)))
+        return x, (k, v, route)
+
+    pk = _ops.packing(held, S, _TILE)
+
+    def in_rows(a, *heads):
+        """Packed (1, P, ..) → rows; with ``heads``, (B, *heads, S, .)."""
+        a = _ops.unpack(a, pk.slot)
+        return jnp.moveaxis(a.reshape((B, S) + heads + (-1,)), 1, -2) \
+            if heads else a
+
+    # a packed token is a row of one position: `_qkv` and `attn_out`
+    # take it as they take a decode step's
+    def front(x, pos):
+        t = x.shape[1]
+        return None, tuple(a.reshape(1, t, -1) for a in _qkv(
+            z, kind, p, x.reshape(t, 1, C), pos.reshape(t, 1)))
+
+    x = _ops.pack(x, pk.src)
+    _, (q, k, v) = _ops.by_tokens(front, pk.tile, pk.n, x,
+                                  _ops.pack(pos, pk.src))
+    k, v = in_rows(k, K), in_rows(v, K)
+    a = attend(in_rows(q, K, G), k, v)
+    # heads side by side in the weights' type, as the way out casts it:
+    # packed a tile at a time, only the tiles that are worked
+    a = jnp.moveaxis(a, 3, 1).reshape(B, S, -1).astype(p["o_weight"].dtype)
+
+    def back(x, src):
+        t = x.shape[1]
+        x = way_out(x.reshape(t, 1, C),
+                    _ops.pack(a, src).reshape(t, K, G, 1, -1))
+        return _feed_forward_front(z, i, p, x.reshape(1, t, C))
+
+    x, route = _ops.by_tokens(back, pk.tile, pk.n, x, pk.src)
+    return in_rows(x), (k, v, tuple(in_rows(r) for r in route))
 
 
 def _forward(z, names, ids, *weights):
@@ -374,7 +447,8 @@ class MiMoV2Program(DecoderProgram):
 
     def cache_shapes(self, B):
         """(full keys, full values, window keys, window values), then
-        the expert layers' counters."""
+        the expert layers' counters and the prefill's: the positions
+        its token-wise tiles worked and the real ones."""
         import jax.numpy as jnp
 
         z = self._z
@@ -386,28 +460,32 @@ class MiMoV2Program(DecoderProgram):
                  ((Lw, B, Kw, z.qk_dim, z.window), None),
                  ((Lw, B, Kw, z.v_dim, z.window), None)],
                 [((max(1, len(z.moe_at)), 2, z.experts_held[1] + 3),
-                  jnp.int32)])
+                  jnp.int32), ((2,), jnp.uint32)])
 
     def counters(self, cache):
-        """The expert layers' counters of one served group, read back
-        once (docs/observability.md has the table)."""
+        """The counters of one served group, read back once
+        (docs/observability.md has the table): the expert layers', and
+        the prefill's packed positions under Jamba's names."""
         z = self._z
-        if not z.moe_at:
-            return {}
-        return _ops.moe_counters(cache[4], z.experts_held[1])
+        out = _ops.moe_counters(cache[4], z.experts_held[1]) \
+            if z.moe_at else {}
+        out.update(_ops.packed_counters(cache[5]))
+        return out
 
     # -- the traced step -------------------------------------------------------
 
     def body(self, ctx, w, cache, toks):
         """S > 1 is a prefill from an empty cache: it attends inside the
-        block.  S = 1 attends over the caches, a row that wants no token
-        to nothing; it goes to no expert and is counted nowhere."""
+        block, and works each row chunk's real tokens packed for what
+        acts on one token at a time.  S = 1 attends over the caches, a
+        row that wants no token to nothing; it goes to no expert and is
+        counted nowhere."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
         z = self._z
-        fk, fv, wk, wv, counts = cache
+        fk, fv, wk, wv, counts, packed = cache
         pos, last, S, decode = ctx.pos, ctx.last, ctx.S, ctx.decode
         R = z.window
         zero = jnp.int32(0)
@@ -429,6 +507,15 @@ class MiMoV2Program(DecoderProgram):
             return jnp.where((p_s >= 0)[:, None, :, None], got, 0)
 
         rows = _ops.chunk_rows(z, ctx.B, S)
+        if not decode:
+            held = last + 1
+            # one layer's worth, every layer packs the same row chunks:
+            # a chunk's real tokens, and the whole tiles that hold them
+            n = jnp.sum(held.reshape(-1, rows), axis=1)
+            tile = min(_TILE, rows * S)
+            packed = packed + jnp.stack(
+                [jnp.sum(-(-n // tile) * tile), jnp.sum(n)]).astype(
+                    packed.dtype)
         for i, kind in enumerate(z.layer_types):
             p = {n: w[f"l{i}_{n}"] for n in z.layer_names(i)}
             l = z.of_kind[kind].index(i)
@@ -436,8 +523,8 @@ class MiMoV2Program(DecoderProgram):
                 q, k, v = _qkv(z, kind, p, x, at)
             else:
                 x, (k, v, route) = _ops.by_rows(
-                    lambda x, at, i=i, p=p: _block_layer(z, i, p, x, at),
-                    rows, x, at)
+                    lambda x, at, held, i=i, p=p: _block_layer(
+                        z, i, p, x, at, held), rows, x, at, held)
             with jax.named_scope("serve.cache_write"):
                 if kind == "full":
                     fk, fv = write((fk, fv), (k, v), l, pos)
@@ -470,7 +557,7 @@ class MiMoV2Program(DecoderProgram):
             h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
             logits = _ops.mm("bc,vc->bv", _ops.rms_norm(h, w["lnf_gamma"], z.eps),
                          w["head_weight"])
-        return (fk, fv, wk, wv, counts), logits
+        return (fk, fv, wk, wv, counts, packed), logits
 
 
 def mimo_v2_tiny(**kwargs):

@@ -68,12 +68,15 @@ def test_train_serve_sharded_phases(mesh8):
     assert [s["mode"] for s in sharded] == ["fsdp", "tp"]
 
 
-def test_serve_mimo_phase():
+def test_serve_mimo_phase(monkeypatch):
     """The second family's phase at a tiny size: the engine's tuple is
     the parameters' own buffers, the four stacks stay where they are,
-    coalesced == alone, a repeat is identical."""
+    coalesced == alone, a repeat is identical, and the packed prefill's
+    counters are the prompts' own sums (two rows a chunk in tiles of 8,
+    so that the tile loop runs)."""
     from mxnet_tpu.gluon.model_zoo import mimo_v2
 
+    monkeypatch.setattr(mimo_v2, "_TILE", 8)
     full = chip_smoke.mimo_full()
     assert full.kwargs["units"] == 4096 and full.kwargs["window"] == 128
     assert full.new_tokens > 2 * full.kwargs["window"]
@@ -85,8 +88,9 @@ def test_serve_mimo_phase():
                   hidden_size=96, expert_hidden=32, router_experts=8,
                   experts_per_token=2, experts_held=[0, 4],
                   value_scale=0.707, max_length=64, attn_block=4,
-                  grad_req="null")
-    assert set(kwargs) - {"attn_block"} <= set(full.kwargs)
+                  prefill_chunk_tokens=32, grad_req="null")
+    assert set(kwargs) - {"attn_block", "prefill_chunk_tokens"} \
+        <= set(full.kwargs)
     size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=16,
                                prompt_lens=(3, 4, 13, 16), new_tokens=13)
     out = chip_smoke.phase_serve_mimo(size, "cpu")

@@ -17,10 +17,10 @@ if ROOT not in sys.path:
 import mxnet_tpu as mx                                      # noqa: E402
 from mxnet_tpu import serving                               # noqa: E402
 from mxnet_tpu.base import MXNetError                       # noqa: E402
-from mxnet_tpu.gluon.model_zoo import mimo_v2               # noqa: E402
+from mxnet_tpu.gluon.model_zoo import _decoder_ops, mimo_v2  # noqa: E402
 from mxnet_tpu.ops import moe                               # noqa: E402
 from mxnet_tpu.test_utils import (                          # noqa: E402
-    UNEQUAL_ANSWERS, serving_dead_rows_keep_their_cache,
+    UNEQUAL_ANSWERS, _padded_group, serving_dead_rows_keep_their_cache,
     serving_host_walk as _walk, serving_unequal_answers)
 
 from benchmark import program, weights                      # noqa: E402
@@ -76,11 +76,26 @@ def _ref_logits(values, ids, cfg):
 
 # -- 1. the uncached forward ---------------------------------------------------
 
-@pytest.mark.parametrize("T,chunk", [(13, 4096), (16, 16), (3, 4096)])
-def test_forward_equals_the_reference(T, chunk):
+def _never_packs(monkeypatch, tile):
+    """A small tile, and a `packing` that fails: for what hands
+    `_block_layer` no lengths and must keep its rows."""
+    def packing(*args):
+        raise AssertionError("the row path packs nothing")
+
+    monkeypatch.setattr(mimo_v2, "_TILE", tile)
+    monkeypatch.setattr(_decoder_ops, "packing", packing)
+
+
+@pytest.mark.parametrize("T,chunk,tile", [
+    (13, 4096, None), (16, 16, None), (3, 4096, None),
+    (13, 4096, 4), (16, 16, 4)])
+def test_forward_equals_the_reference(T, chunk, tile, monkeypatch):
     """`hybrid_forward` over whole sequences (a length that is no
     multiple of the attention block; rows worked off in chunks; one
-    shorter than a block)."""
+    shorter than a block).  It hands no lengths and keeps its rows
+    whatever the prefill's tile."""
+    if tile:
+        _never_packs(monkeypatch, tile)
     cfg = _config()
     net, values = _net(cfg, prefill_chunk_tokens=chunk)
     ids = np.random.RandomState(0).randint(0, 96, (4, T))
@@ -242,7 +257,7 @@ def test_the_four_stacks_alias_their_inputs(served, kind, S):
     text = eng._compile(B, S).as_text()
     n_w = len(eng._weights)
     cache = eng.init_cache(B)
-    assert len(cache) == 5
+    assert len(cache) == 6
     alias = text[text.index("input_output_alias="):].split("\n")[0]
     for i in range(len(cache)):
         assert f"{{{i}}}: ({n_w + i}, {{}}" in alias, (i, alias)
@@ -252,8 +267,13 @@ def test_the_four_stacks_alias_their_inputs(served, kind, S):
                 text, c.nbytes // c.shape[0]) == []
 
 
-def test_a_coalesced_group_is_bitwise_the_requests_served_alone(served):
-    _, _, _, eng = served
+@pytest.mark.parametrize("tile", [None, 4])
+def test_a_coalesced_group_is_bitwise_the_requests_served_alone(
+        served, tile, monkeypatch):
+    """Also in tiles smaller than a chunk's tokens: a request alone,
+    beside three pad rows of one token, lies in other tiles than in its
+    group."""
+    eng = served[3] if tile is None else _tiled(monkeypatch, tile)[1]
     rng = np.random.RandomState(6)
     prompts = [rng.randint(0, 96, n).tolist() for n in (3, 11, WINDOW, 7)]
     toks, logits = _walk(eng, prompts, 10)
@@ -261,6 +281,111 @@ def test_a_coalesced_group_is_bitwise_the_requests_served_alone(served):
         t1, l1 = _walk(eng, [p], 10)
         np.testing.assert_array_equal(t1[0], toks[i])
         np.testing.assert_array_equal(l1[0], logits[i])
+
+
+# -- 5b. the prefill works its real tokens only --------------------------------
+
+def _tiled(monkeypatch, tile, **kw):
+    """(net, engine) whose prefill packs in tiles of ``tile``: the tile
+    is read while a program is traced, so the engine is the test's
+    own."""
+    monkeypatch.setattr(mimo_v2, "_TILE", tile)
+    net, _ = _net(_config(), **kw)
+    return net, serving.ServingEngine(net, batch_buckets=(4,))
+
+
+def _prefilled(eng, prompts):
+    """One prefill through the engine's own program: (cache, logits)."""
+    B, n, toks = _padded_group(eng, prompts)
+    cache, logits, *_ = eng._call(B, toks.shape[1], eng.init_cache(B),
+                                  np.zeros(B, np.int32), n - 1, toks)
+    return cache, np.asarray(logits)
+
+
+# a bucket of 4 x 16: a row of one token, a full row, and (two rows a
+# chunk) a second chunk whose 16 tokens end on the edge of a tile of 4
+# or 8.  (tile, tokens a row chunk, the positions its tiles work)
+LENS = (1, 16, 7, 9)
+TILED = [(4, 32, 20 + 16), (8, 32, 24 + 16), (8, 16, 8 + 16 + 8 + 16),
+         (3, 32, 18 + 18), (512, 32, 32 + 32)]
+
+
+@pytest.mark.parametrize("tile,chunk,worked", TILED)
+def test_the_packed_prefill_equals_the_row_layout(tile, chunk, worked,
+                                                  monkeypatch):
+    """Ragged lengths across row chunks, the token-wise products in
+    tiles of the packed block (the family's own tile holds a chunk
+    whole): the logits, the full layers' keys and values up to each
+    row's last token, the rings and every expert counter are what the
+    row layout gives (`_block_layer` handed no lengths: the forward
+    pass's path), and the two counters of the packing are the prompts'
+    own sums, in whole tiles a chunk."""
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(0, 96, n).tolist() for n in LENS]
+    net, eng = _tiled(monkeypatch, tile, prefill_chunk_tokens=chunk)
+    cache, logits = _prefilled(eng, prompts)
+    counts = eng._program.counters(cache)
+
+    packed = mimo_v2._block_layer
+    monkeypatch.setattr(
+        mimo_v2, "_block_layer",
+        lambda z, i, p, x, pos, held=None: packed(z, i, p, x, pos))
+    _never_packs(monkeypatch, tile)
+    rows = serving.ServingEngine(net, batch_buckets=(4,))
+    want_cache, want = _prefilled(rows, prompts)
+    want_counts = rows._program.counters(want_cache)
+
+    np.testing.assert_allclose(logits, want, atol=2e-5, rtol=1e-5)
+    for got, ref_ in zip(cache[:2], want_cache[:2]):
+        for r, n in enumerate(LENS):
+            np.testing.assert_allclose(
+                np.asarray(got)[:, r, :, :, :n],
+                np.asarray(ref_)[:, r, :, :, :n], atol=2e-5, rtol=1e-5)
+    for got, ref_ in zip(cache[2:4], want_cache[2:4]):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref_),
+                                   atol=2e-5, rtol=1e-5)
+    for name, n in want_counts.items():
+        if name.startswith("moe_"):
+            assert counts[name] == n, name
+    assert want_counts["moe_pairs_prefill"] == sum(LENS) * 2 * 6
+    assert counts["prefill_positions"] == sum(LENS)
+    assert counts["prefill_positions_worked"] == worked
+    assert counts["prefill_positions_padded"] == worked - sum(LENS)
+    assert counts["prefill_tokens_padded_pct"] == pytest.approx(
+        100.0 * (1 - sum(LENS) / worked))
+
+
+def test_the_prefills_counters_reach_a_groups_timings(served):
+    """At the family's own tile a block of 4 x 16 is one tile, worked
+    whole: every position of the bucket, of which the prompts' are real
+    (a pad row holds one token); a decode step adds nothing."""
+    _, _, _, eng = served
+    rng = np.random.RandomState(12)
+    prompts = [rng.randint(0, 96, n).tolist() for n in (5, 13)]
+    _, timings = eng.serve_group(prompts, 3)
+    S = timings["bucket"][1]
+    assert timings["prefill_positions"] == 5 + 13 + 2
+    assert timings["prefill_positions_worked"] == 4 * S
+    assert timings["prefill_tokens_padded_pct"] == pytest.approx(
+        100.0 * (1 - 20 / (4 * S)))
+
+
+def test_only_the_prefill_packs(monkeypatch):
+    """The path is taken where the rows' lengths are given: the prefill
+    program packs each layer's row chunks, the decode step (one token a
+    row) never enters it, whatever the tile."""
+    _, eng = _tiled(monkeypatch, 4)
+    calls = []
+    packing = _decoder_ops.packing
+    monkeypatch.setattr(
+        _decoder_ops, "packing",
+        lambda *a: calls.append(a[1:]) or packing(*a))
+    eng._compile(4, 1)
+    assert calls == []
+    eng._compile(4, 8)
+    # a layer's row chunk is traced twice (its shapes, then the loop)
+    # where the bucket is cut, once where a chunk holds it
+    assert calls == [(8, 4)] * 7
 
 
 @pytest.mark.parametrize("steps", [1, 2, 9])
