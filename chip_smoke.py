@@ -23,11 +23,14 @@ rings of several lane blocks), small and then at the published widths of
 float32 reference's logits; a seventh, ``JambaModel`` (Mamba layers
 whose state and convolution tail ride in the donated cache beside an
 attention layer's keys and values), held to the float32 reference's
-logits; with four chips, the GPT step under
+logits; an eighth, ``GraniteHybridModel`` (Mamba-2 layers whose heads'
+states ride in the donated cache, routed experts and a shared one in
+every layer), after its two kernels alone against their plain paths at
+the published widths (``ssd``); with four chips, the GPT step under
 ``shard_model`` fsdp and tp.  Phases, in order: device, sync, kernel,
 train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro,
-serve_cmda, serve_cmda_full, serve_jamba, sharded (``--phases a,b``: the device
-phase and only those).  The first failed check raises and the process
+serve_cmda, serve_cmda_full, serve_jamba, ssd, serve_granite, sharded
+(``--phases a,b``: the device phase and only those).  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
 
@@ -170,6 +173,56 @@ def jamba_small():
                   attn_offset=7, d_state=16, d_conv=4, dt_rank=32, expand=2,
                   max_length=704, prefill_chunk_tokens=2048,
                   dtype="bfloat16", grad_req="null")
+    return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
+                    prompt_lens=(40, 128, 300, 77, 129, 1, 260),
+                    new_tokens=6)
+
+
+@dataclasses.dataclass(frozen=True)
+class SsdSize:
+    """The Mamba-2 kernels' operands: H heads of P channels over N
+    states, a block of R rows x S positions to ``lengths``, a stack of L
+    layers x B rows of which ``live`` are; ``tiles``: the kernels'
+    (chunk, heads a block, channels a tile), None for their own."""
+    H: int
+    P: int
+    N: int
+    S: int
+    lengths: tuple
+    L: int
+    live: tuple
+    tiles: tuple = None
+
+
+def ssd_full():
+    """Granite 4.0-H's widths (128 heads of 64 over 128 states: a row's
+    state is 4 MB), a prefill block of 512 with rows that end inside a
+    chunk, on a chunk's edge and at the block's, and eight rows of a
+    three-layer stack of which five are live."""
+    return SsdSize(H=128, P=64, N=128, S=512,
+                   lengths=(1, 100, 128, 129, 300, 384, 511, 512), L=3,
+                   live=(1, 0, 1, 1, 0, 0, 1, 1))
+
+
+def granite_small():
+    """The eighth family at small sizes with heads of the published
+    kind (Mamba-2 heads of 64 channels over 128 states, 16 of them: one
+    head block of the scan kernel, eight tiles of the update's; 4 query
+    heads of 128 over one key head): five layers with the attention
+    layer inside, eight experts of which two are held, three a token,
+    the cell's prefill bucket of 512 in two row chunks and a window of
+    704."""
+    kwargs = dict(vocab_size=512, units=512,
+                  layer_types=["mamba", "mamba", "attention", "mamba",
+                               "mamba"],
+                  num_heads=4, kv_heads=1, ssm_heads=16, ssm_head_dim=64,
+                  d_state=128, d_conv=4, expert_hidden=256,
+                  shared_hidden=512, router_experts=8, experts_per_token=3,
+                  experts_held=(0, 2), embedding_multiplier=12.0,
+                  residual_multiplier=0.22, attention_multiplier=0.0078125,
+                  logits_scaling=16.0, max_length=704,
+                  prefill_chunk_tokens=2048, dtype="bfloat16",
+                  grad_req="null")
     return FamilySize(kwargs=kwargs, batch=8, prefill_floor=512,
                     prompt_lens=(40, 128, 300, 77, 129, 1, 260),
                     new_tokens=6)
@@ -1202,6 +1255,23 @@ SHARDED_LAYOUTS = (({"dp": 4}, "fsdp"), ({"tp": 2, "dp": 2}, "tp"))
 
 # -- serve, a seventh family: states beside the stacks --------------------------
 
+def require_states_in_place(tag, engine, B, block):
+    """On the chip the states are updated where they lie: the decode
+    program of batch bucket ``B`` makes no value of a layer's states'
+    shape ``(B,) + block`` float32 at all (a byte threshold would also
+    catch a small size's weights)."""
+    import re
+
+    dims = ",".join(str(d) for d in (B,) + tuple(block))
+    layer = re.compile(r" = f32\[(1,)?%s\]" % dims)
+    made = [line.split(" = ")[0].strip() for line in engine._programs[
+        (B, 1)].as_text().splitlines() if layer.search(line)]
+    require(not made, f"{tag}: the decode program makes a layer of the "
+                      f"states: {made[:8]}")
+    say(f"[{tag}] decode program, batch {B}: no value of a layer's states "
+        f"({dims} float32)")
+
+
 def phase_serve_jamba(size, platform):
     from mxnet_tpu.gluon.model_zoo import jamba
     from mxnet_tpu.ops import ssm
@@ -1244,20 +1314,8 @@ def phase_serve_jamba(size, platform):
         2 if platform == "tpu" else 0, counters_hold)
     z, big = net._sizes, engine.init_cache(1)
     if platform == "tpu":
-        # the states are updated where they lie: the decode program
-        # makes no value of a layer's states' shape at all (a byte
-        # threshold would also catch this size's weights)
-        import re
-
-        B = timing["bucket"][0]
-        layer = re.compile(r" = f32\[(1,)?%d,%d,%d\]" % (B, z.d_state,
-                                                          z.inner))
-        made = [line.split(" = ")[0].strip() for line in engine._programs[
-            (B, 1)].as_text().splitlines() if layer.search(line)]
-        require(not made, f"serve_jamba: the decode program makes a "
-                          f"layer of the states: {made[:8]}")
-        say(f"[serve_jamba] decode program, batch {B}: no value of a "
-            f"layer's states ({B} x {z.d_state} x {z.inner} float32)")
+        require_states_in_place("serve_jamba", engine, timing["bucket"][0],
+                                (z.d_state, z.inner))
     require([tuple(c.shape) for c in big] == [
         (1, 1, z.kv_heads, z.head_dim, -(-engine._W // 128) * 128)] * 2
         + [(Lm, 1, z.d_state, z.inner),
@@ -1278,6 +1336,175 @@ def phase_serve_jamba(size, platform):
             "mamba_d_conv": z.d_conv, "mamba_conv_bias": True,
             "mamba_proj_bias": False, "attn_layer_period": z.attn_period,
             "attn_layer_offset": z.attn_offset, "num_experts": 1})
+    return out
+
+
+# -- the Mamba-2 kernels, and an eighth family: heads' states and experts -------
+
+# the kernels against their plain paths, of the largest value: the
+# update on the vector unit in float32 (the sums in another order); the
+# scan's products with float32 operands, which the MXU makes of several
+# bfloat16 passes (on the v5e 1.1e-4 to 3.0e-4 over two draws, PR 49;
+# interpreted 1e-5), and with bfloat16 operands (1.8e-3 to 2.5e-3)
+SSD_TOL_F32, SSD_TOL_SCAN_F32, SSD_TOL_BF16 = 1e-4, 1e-3, 2e-2
+
+
+def phase_ssd(size, platform):
+    """`ops/ssm.py`'s Mamba-2 scan and one-position update through their
+    kernels (compiled on the chip, interpreted elsewhere) against their
+    plain paths: ragged lengths, the state returned, ``live``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import ssm
+
+    H, P, N, S = size.H, size.P, size.N, size.S
+    R, B = len(size.lengths), len(size.live)
+    here = platform != "tpu"
+    chunk, heads, rows = size.tiles or (None, None, None)
+    require(here or (ssm._mamba2_scan_fits(H, P, N)
+                     and ssm._mamba2_update_fits(H * P, N)),
+            "ssd: the kernels would not take these sizes on the chip")
+    ks = jax.random.split(jax.random.key(S), 7)
+    x = jax.random.normal(ks[0], (R, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (R, S, H)) - 1.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=-6.0, maxval=6.0))
+    Bm, Cm = (0.3 * jax.random.normal(k, (R, S, N)) for k in ks[3:5])
+    D = jax.random.normal(ks[5], (H,))
+    n = jnp.array(size.lengths, jnp.int32)
+
+    def err(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        require(np.isfinite(a).all(), "ssd: non-finite output")
+        return float(np.abs(a - b).max() / np.abs(b).max())
+
+    def real(y):
+        return jnp.where(jnp.arange(S)[None, :, None, None]
+                         < n[:, None, None, None], y, 0.0)
+
+    out = {}
+    y0, s0 = jax.jit(ssm._mamba2_scan_plain)(x, dt, A, Bm, Cm, D, n)
+    for name, operands, tol in (("float32", None, SSD_TOL_SCAN_F32),
+                                ("bfloat16", jnp.bfloat16, SSD_TOL_BF16)):
+        t0 = time.perf_counter()
+        scan = jax.jit(functools.partial(
+            ssm._mamba2_scan_kernel_call, operands=operands, interpret=here,
+            chunk=chunk, heads=heads)).lower(x, dt, A, Bm, Cm, D, n)
+        require(here or "tpu_custom_call" in scan.as_text(),
+                "ssd: no Mosaic custom call in the lowered scan")
+        y1, s1 = scan.compile()(x, dt, A, Bm, Cm, D, n)
+        jax.block_until_ready(s1)
+        secs = time.perf_counter() - t0
+        e = (err(real(y1), real(y0)), err(s1, s0))
+        say(f"[ssd] scan ({R} x {S}, {H} heads of {P} over {N}), {name} "
+            f"operands: compile+run {secs:.1f}s, y err {e[0]:.5f}, state "
+            f"err {e[1]:.5f} (tol {tol})")
+        require(max(e) <= tol, f"ssd: the scan's {name} products off the "
+                               f"plain path by {e}")
+        require(on_platform(s1, platform), "ssd: state off the device")
+        out["scan_" + name] = e
+    state = jax.random.normal(ks[6], (size.L, B, H * P, N))
+    live = jnp.array(size.live, bool)
+    args = (x[:B, 0], dt[:B, 0], A, Bm[:B, 0], Cm[:B, 0], D, live)
+    ya, sa = jax.jit(lambda st, *a: ssm._mamba2_update_plain(st, 1, *a))(
+        state, *args)
+    update = jax.jit(lambda st, *a: ssm._mamba2_update_kernel_call(
+        st, 1, *a, interpret=here, rows=rows), donate_argnums=(0,))
+    yb, sb = update(state + 0, *args)
+    e = (err(yb, ya), err(sb, sa))
+    dead = [r for r in range(B) if not size.live[r]]
+    require(max(e) <= SSD_TOL_F32,
+            f"ssd: the update off the plain path by {e}")
+    require(all(np.array_equal(np.asarray(sb[1, r]), np.asarray(state[1, r]))
+                for r in dead)
+            and np.array_equal(np.asarray(sb[0]), np.asarray(state[0])),
+            "ssd: a row that is not live, or another layer, changed")
+    say(f"[ssd] update ({size.L} x {B} x {H * P} x {N}, {B - len(dead)} "
+        f"live): y err {e[0]:.6f}, state err {e[1]:.6f} (tol "
+        f"{SSD_TOL_F32}); {len(dead)} dead rows bit for bit")
+    out["update"] = e
+    return out
+
+
+def phase_serve_granite(size, platform):
+    from mxnet_tpu.gluon.model_zoo import granite_hybrid
+    from mxnet_tpu.ops import ssm
+
+    steps = size.new_tokens - 1
+    S, kw = size.prefill_floor, size.kwargs
+    La = kw["layer_types"].count("attention")
+    Lm = len(kw["layer_types"]) - La
+
+    def counters_hold(timing, lens, pads):
+        # Jamba's counters under Jamba's names (the scan to each row's
+        # length in whole chunks of the Mamba-2 kernel, every live row's
+        # heads moved on once a step and layer, both through their
+        # kernels on the chip, the update told the live rows) and the
+        # expert families'
+        real = lens + (1,) * pads
+        Tc = ssm.mamba2_chunk()
+        walked = sum(-(-n // Tc) * Tc for n in real) \
+            if platform == "tpu" else len(real) * S
+        worked = packed_positions(kw, granite_hybrid._TILE, real, S)
+        on = float(platform == "tpu")
+        return timing["ssm_positions_prefill"] == Lm * sum(real) \
+            and timing["prefill_positions"] == sum(real) \
+            and timing["prefill_positions_worked"] == worked \
+            and timing["ssm_positions_scanned_prefill"] == Lm * walked \
+            and timing["ssm_row_updates_decode"] == Lm * len(lens) * steps \
+            and timing["attn_pairs_prefill"] \
+            == La * sum(n * (n + 1) // 2 for n in real) \
+            and timing["attn_positions_decode"] \
+            == La * sum(n + j + 1 for n in lens for j in range(steps)) \
+            and timing["prefill_attn_kernel_share"] == 1.0 \
+            and timing["prefill_state_scan_kernel_share"] == on \
+            and timing["decode_state_update_kernel_share"] == on \
+            and timing["decode_state_update_live_share"] == on \
+            and 0 < timing["moe_pairs_prefill"] \
+            <= (Lm + La) * sum(real) * kw["experts_held"][1] \
+            and moe_rows_hold(timing)
+
+    # two stacks, of which no layer moves; the CPU's plain update writes
+    # a layer's states whole, at any size, so it is checked nothing
+    net, engine, timing, out = serve_family(
+        "serve_granite", granite_hybrid.GraniteHybridModel, size, platform,
+        2 if platform == "tpu" else 0, counters_hold)
+    z, big = net._sizes, engine.init_cache(1)
+    if platform == "tpu":
+        require_states_in_place("serve_granite", engine,
+                                timing["bucket"][0], (z.inner, z.d_state))
+    require([tuple(c.shape) for c in big] == [
+        (1, 1, z.kv_heads, z.head_dim, -(-engine._W // 128) * 128)] * 2
+        + [(Lm, 1, z.inner, z.d_state), (Lm, 1, (z.d_conv - 1) * z.conv_dim),
+           (Lm + La, 2, z.experts_held[1] + 3), (7,)]
+        and str(big[2].dtype) == "float32",
+        f"serve_granite: cache {[(tuple(c.shape), c.dtype) for c in big]}")
+    from benchmark.references import granite_hybrid as ref
+
+    require_served_logits_equal_the_reference(
+        "serve_granite", net, engine, size, ref, {
+            "hidden_size": z.units, "num_hidden_layers": Lm + La,
+            "layer_types": list(z.layer_types),
+            "num_attention_heads": z.num_heads,
+            "num_key_value_heads": z.kv_heads,
+            "intermediate_size": z.expert_hidden,
+            "shared_intermediate_size": z.shared_hidden,
+            "num_local_experts": z.experts_held[1],
+            "router_experts": z.router_experts,
+            "experts_held": list(z.experts_held),
+            "num_experts_per_tok": z.experts_per_token,
+            "vocab_size": z.vocab, "rms_norm_eps": z.eps,
+            "hidden_act": "silu", "tie_word_embeddings": True,
+            "mamba_expand": z.inner / z.units,
+            "mamba_n_heads": z.ssm_heads, "mamba_d_head": z.ssm_head_dim,
+            "mamba_d_state": z.d_state, "mamba_n_groups": 1,
+            "mamba_d_conv": z.d_conv, "mamba_conv_bias": True,
+            "mamba_proj_bias": False, "attention_bias": False,
+            "position_embedding_type": "nope",
+            "embedding_multiplier": z.embedding_multiplier,
+            "residual_multiplier": z.residual_multiplier,
+            "attention_multiplier": z.attention_multiplier,
+            "logits_scaling": z.logits_scaling})
     return out
 
 
@@ -1384,6 +1611,10 @@ def main(argv=()):
         "serve_cmda_full")
     gc.collect()
     run("serve_jamba", phase_serve_jamba, jamba_small(), platform)
+    gc.collect()
+    run("ssd", phase_ssd, ssd_full(), platform)
+    gc.collect()
+    run("serve_granite", phase_serve_granite, granite_small(), platform)
     gc.collect()
     import jax
 

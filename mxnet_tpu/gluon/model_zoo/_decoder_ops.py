@@ -1,5 +1,5 @@
 """What the served decoder families share (`mimo_v2.py`, `keye_vl2.py`,
-`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`, `jamba.py`): the pieces of a layer that do
+`kimi_k2.py`, `ouro.py`, `cohere2_moe.py`, `jamba.py`, `granite_hybrid.py`): the pieces of a layer that do
 not depend on a family's attention or routing rule.  No family imports
 another; a change here is a change to all, and their cells measure it.
 
@@ -17,14 +17,17 @@ another; a change here is a change to all, and their cells measure it.
   experts; `moe_count_row`, `moe_counters`: an expert layer's counters in the
   donated carry and their read-back (docs/observability.md);
 - `by_rows`, `chunk_rows`: a prefill block worked off a few rows at a
-  time; `by_tokens`: a few positions at a time; `embed`: a block's way
+  time; `rows_in_chunks`: a few rows through every layer before the
+  next (the families whose carry holds states); `by_tokens`: a few positions at a time; `embed`: a block's way
   in;
 - `packing`, `pack`, `unpack`: a block's real tokens, row after row, at
   the front of one flat row of positions, so that what acts on one
   token at a time (`by_tokens` over that row) works no padding, and the
   way back into rows for what needs a row's order (`jamba.py`'s and
   `mimo_v2.py`'s prefills; Keye's is the next caller);
-  `packed_counters`: the two counters such a prefill keeps, read back.
+  `packed_counters`: the two counters such a prefill keeps, read back;
+  `state_counters`: a state-space family's five (`jamba.py`,
+  `granite_hybrid.py`).
 
 What a decoder program is apart from its layers (its weights, its cache,
 the step's prologue, the cache write and the attention over the cache)
@@ -269,6 +272,31 @@ def by_rows(fn, rows, x, *per_row):
     return lax.fori_loop(0, B // rows, one, (x, extras))
 
 
+def rows_in_chunks(fn, rows, carry, vocab, *per_row):
+    """``fn(*per_row's rows, first row, carry) -> (carry, logits)`` over
+    the rows of a group, ``rows`` at a time and one chunk after another
+    through **all** that ``fn`` does (a family's every layer), the
+    donated ``carry`` threaded through: (carry, logits (B, vocab)
+    float32).  Whole, with ``None`` for the first row, when one chunk
+    holds every row."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    B = per_row[0].shape[0]
+    if rows >= B:
+        return fn(*per_row, None, carry)
+
+    def chunk(c, state):
+        carry, logits = state
+        carry, part = fn(*(lax.dynamic_slice_in_dim(a, c * rows, rows, axis=0)
+                           for a in per_row), c * rows, carry)
+        return carry, lax.dynamic_update_slice_in_dim(logits, part, c * rows,
+                                                      axis=0)
+
+    return lax.fori_loop(0, B // rows, chunk,
+                         (carry, jnp.zeros((B, vocab), jnp.float32)))
+
+
 def _chunks(tokens, live, S):
     """The chunks of ``tokens`` positions that begin before position
     ``live`` (a traced scalar, or None for all) of ``S``."""
@@ -407,6 +435,28 @@ def unpack(x, slot):
     with jax.named_scope("serve.pack"):
         return x[0].at[slot].get(mode="promise_in_bounds",
                                  indices_are_sorted=True)
+
+
+def state_counters(c):
+    """A served group's state-space and attention counters ``c`` (5,),
+    read back (docs/observability.md has the table): the positions the
+    prefill's scans walked and the real ones, the decode steps' row
+    updates, the attention layers' pairs in a prefill and positions in
+    a decode step, each summed over its layers; and of the positions
+    walked those past their row's length."""
+    import numpy as np
+
+    out = dict(zip(("ssm_positions_scanned_prefill", "ssm_positions_prefill",
+                    "ssm_row_updates_decode", "attn_pairs_prefill",
+                    "attn_positions_decode"),
+                   (int(n) for n in np.asarray(c))))
+    scanned = out["ssm_positions_scanned_prefill"]
+    out["ssm_positions_padded_prefill"] = \
+        scanned - out["ssm_positions_prefill"]
+    if scanned:
+        out["ssm_scan_padded_pct"] = \
+            100.0 * out["ssm_positions_padded_prefill"] / scanned
+    return out
 
 
 def packed_counters(c):

@@ -63,6 +63,13 @@ class Step:
       ``updates`` (``state_updates[S]``): the rows whose state a scan
       or an update moved on, by path.
 
+    The states' recurrence is `ops/ssm.py`'s Mamba-1 pair (a decay a
+    state element, a state ``(N, E)``) unless the family hands `scan`
+    and `update` another pair's functions (``rows=``: Mamba-2's, a decay
+    a head, a state ``(H P, N)``).  Both keep the same contract: a
+    block from an empty state to each row's length, one position in
+    place told ``live``.
+
     A family does four things with ``live`` in its decode branch and no
     fifth: lengths from ``held``, ``valid=live[:, None]`` to the
     experts, writes through ``write``, states through ``update`` and
@@ -143,24 +150,23 @@ class Step:
         return cache_write._pinned((states,), self._layouts_of(first, 1))[0]
 
     def scan(self, states, l, c, dt, A, B, C, D, lengths, row=None,
-             first=0):
+             first=0, rows=ssm.selective_scan_rows):
         """A prefilled block through layer ``l``'s recurrence from an
-        empty state (`ssm.selective_scan_rows`), each row to
-        ``lengths`` (B,): returns (y, the states ``(L, B, N, E)`` with
-        rows ``row ..`` of layer ``l`` what each row's last real
+        empty state (``rows``, which says what its operands are), each
+        row to ``lengths`` (B,): returns (y, the states ``(L, B, ...)``
+        with rows ``row ..`` of layer ``l`` what each row's last real
         position left).  ``row``, ``first`` and the scope as `write`."""
-        y, h = ssm.selective_scan_rows(c, dt, A, B, C, D, lengths,
-                                       tally=self.updates)
+        y, h = rows(c, dt, A, B, C, D, lengths, tally=self.updates)
         return y, self._put(states, h, l, row, first)
 
-    def update(self, states, l, c, dt, A, B, C, D, first=0):
-        """A decode step's one position a row (`ssm.state_update_rows`),
-        in place on the donated states and told the rows that are live
-        as the step was handed them: another row's state is not moved.
-        Returns (y (B, E), the states)."""
-        y, states = ssm.state_update_rows(
-            states, l, c, dt, A, B, C, D, live=self._given,
-            tally=self.updates)
+    def update(self, states, l, c, dt, A, B, C, D, first=0,
+               rows=ssm.state_update_rows):
+        """A decode step's one position a row (``rows``), in place on
+        the donated states and told the rows that are live as the step
+        was handed them: another row's state is not moved.  Returns (y,
+        zero for a row that is not live; the states)."""
+        y, states = rows(states, l, c, dt, A, B, C, D, live=self._given,
+                         tally=self.updates)
         return y, self._pin(states, first)
 
     def conv(self, tails, l, a, w, b, lengths=None, row=None, first=0):

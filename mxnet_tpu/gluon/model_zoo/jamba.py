@@ -346,18 +346,7 @@ class JambaProgram(DecoderProgram):
         import numpy as np
 
         c = np.asarray(cache[4]).astype(np.int64)
-        out = dict(zip(("ssm_positions_scanned_prefill",
-                        "ssm_positions_prefill", "ssm_row_updates_decode",
-                        "attn_pairs_prefill", "attn_positions_decode"),
-                       (int(n) for n in c)))
-        scanned = out["ssm_positions_scanned_prefill"]
-        # of the positions the prefill's scans walked, those past their
-        # row's length
-        out["ssm_positions_padded_prefill"] = \
-            scanned - out["ssm_positions_prefill"]
-        if scanned:
-            out["ssm_scan_padded_pct"] = \
-                100.0 * out["ssm_positions_padded_prefill"] / scanned
+        out = _ops.state_counters(c[:5])
         out.update(_ops.packed_counters(c[5:]))
         return out
 
@@ -374,7 +363,6 @@ class JambaProgram(DecoderProgram):
         state and tail and is counted nowhere."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
         from ...ops import ssm
 
@@ -500,22 +488,9 @@ class JambaProgram(DecoderProgram):
             counts = counts + jnp.stack([jnp.uint32(a) for a in add])
             return (ck, cv, states, tails, counts), logits
 
-        Rows = B if decode else _ops.chunk_rows(z, B, S)
-        if Rows == B:
-            return rows(toks, ctx.pos, ctx.last, None, tuple(cache))
-
-        def chunk(c, state):
-            carry, logits = state
-            cut = lambda a: lax.dynamic_slice_in_dim(a, c * Rows, Rows,
-                                                     axis=0)
-            carry, part = rows(cut(toks), cut(ctx.pos), cut(ctx.last),
-                               c * Rows, carry)
-            return carry, lax.dynamic_update_slice_in_dim(
-                logits, part, c * Rows, axis=0)
-
-        return lax.fori_loop(
-            0, B // Rows, chunk,
-            (tuple(cache), jnp.zeros((B, self.vocab), jnp.float32)))
+        return _ops.rows_in_chunks(
+            rows, B if decode else _ops.chunk_rows(z, B, S), tuple(cache),
+            self.vocab, toks, ctx.pos, ctx.last)
 
 
 def jamba_tiny(**kwargs):

@@ -295,21 +295,18 @@ def _update_plain(state, l, c, dt, A, B, C, D, live=None):
                                        (jnp.int32(l), zero, zero, zero))
 
 
-def _update_kernel(l_ref, live_ref, st_hbm, c_ref, dt_ref, b_ref, cc_ref,
-                   a_ref, d_ref, out_hbm, y_ref, buf, sem, rows):
-    """One invocation a call.  The states whole, in HBM (read through
-    the output they are aliased to); c, dt (B, E); b, cc (B, N, 1): a
-    row's states are a column; a (N, E), d (1, E); y (B, E); scratch:
-    ``_SLOTS`` state buffers (N, E), the copies' semaphores (in / out,
-    buffer), the live rows in order.  The live rows are walked with the
-    next one's state on its way in while this one's is changed and sent
-    back; a row that is not live starts no copy."""
+def _walk_live_rows(l_ref, live_ref, out_hbm, buf, sem, rows, change):
+    """What both update kernels do around a row's arithmetic: the live
+    rows of layer ``l_ref[0]`` of the states ``out_hbm`` (L, B, ...)
+    walked in order with ``_SLOTS`` buffers ``buf``, the next one's
+    state on its way in while ``change(b, slot)`` works row ``b``'s in
+    ``buf[slot]`` and it is sent back; a row that is not live starts no
+    copy.  ``sem``: the copies' semaphores (in / out, buffer); ``rows``:
+    the live rows in order (SMEM)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    del st_hbm
     l, n_rows = l_ref[0], live_ref.shape[0]
-    y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
 
     def count(b, k):
         @pl.when(live_ref[b] != 0)
@@ -342,12 +339,7 @@ def _update_kernel(l_ref, live_ref, st_hbm, c_ref, dt_ref, b_ref, cc_ref,
             copy(k + 1, 0).start()
 
         copy(k, 0).wait()
-        b, slot = rows[k], k % _SLOTS
-        dt, c = dt_ref[pl.ds(b, 1), :], c_ref[pl.ds(b, 1), :]
-        h = jnp.exp(dt * a_ref[...]) * buf[slot] + (dt * c) * b_ref[b]
-        buf[slot] = h.astype(buf.dtype)
-        y_ref[pl.ds(b, 1), :] = jnp.sum(h * cc_ref[b], axis=0,
-                                        keepdims=True) + d_ref[...] * c
+        change(rows[k], k % _SLOTS)
         copy(k, 1).start()
         return carry
 
@@ -356,6 +348,29 @@ def _update_kernel(l_ref, live_ref, st_hbm, c_ref, dt_ref, b_ref, cc_ref,
         @pl.when(n_live >= back)
         def _():
             copy(n_live - back, 1).wait()
+
+
+def _update_kernel(l_ref, live_ref, st_hbm, c_ref, dt_ref, b_ref, cc_ref,
+                   a_ref, d_ref, out_hbm, y_ref, buf, sem, rows):
+    """One invocation a call.  The states whole, in HBM (read through
+    the output they are aliased to); c, dt (B, E); b, cc (B, N, 1): a
+    row's states are a column; a (N, E), d (1, E); y (B, E); scratch:
+    ``_SLOTS`` state buffers (N, E), the copies' semaphores and the
+    live rows in order (`_walk_live_rows`, which says how the rows are
+    walked)."""
+    from jax.experimental import pallas as pl
+
+    del st_hbm
+    y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
+
+    def change(b, slot):
+        dt, c = dt_ref[pl.ds(b, 1), :], c_ref[pl.ds(b, 1), :]
+        h = jnp.exp(dt * a_ref[...]) * buf[slot] + (dt * c) * b_ref[b]
+        buf[slot] = h.astype(buf.dtype)
+        y_ref[pl.ds(b, 1), :] = jnp.sum(h * cc_ref[b], axis=0,
+                                        keepdims=True) + d_ref[...] * c
+
+    _walk_live_rows(l_ref, live_ref, out_hbm, buf, sem, rows, change)
 
 
 def _update_kernel_call(state, l, c, dt, A, B, C, D, live=None,
@@ -390,3 +405,346 @@ def _update_kernel_call(state, l, c, dt, A, B, C, D, live=None,
       c.astype(f32), dt.astype(f32), B.astype(f32)[..., None],
       C.astype(f32)[..., None], A.astype(f32), D.astype(f32)[None])
     return y, state
+
+
+# -- Mamba-2: one decay a head, a state (H P, N) -------------------------------
+#
+# Dao & Gu, arXiv:2405.21060.  A layer has H heads of P channels; head h
+# of a row carries a state (P, N) float32, and a position's B_t and C_t
+# (N) are shared by all heads.  With dt_t (H, after its softplus), the
+# layer's A (H, negative) and D (H):
+#
+#     S_t^h = exp(dt_t^h A^h) S_{t-1}^h + dt_t^h x_t^h B_t^T
+#     y_t^h = S_t^h C_t + D^h x_t^h
+#
+# A row's state lies as (H P, N), **the N states minor**: Granite 4.0-H's
+# N = 128 is exactly the lane width, so nothing pads, and a head's (P,
+# N) block is whole sublane tiles.
+#
+# - `mamba2_scan_rows`: a prefill block, each row to its own length.
+#   **kernel** (a TPU): the chunked matrix form.  Grid (rows, head
+#   blocks, time chunks), the chunks in order with the head block's
+#   states resident in the output block.  With c_t the cumulative
+#   log-decay inside a chunk (float32, made by the caller's XLA: a
+#   cumulative sum over 128 positions), a chunk's output is
+#   ``((B C^T) * exp(c_t - c_s)[s <= t]) (dt x)`` plus ``exp(c_t) S C_t``,
+#   and the state moves to ``exp(c_last) S + (exp(c_last - c_s) dt_s
+#   x_s) B``: on the MXU, the decay-masked product a (head, chunk) and
+#   the other two once a (head block, chunk) against operands all heads
+#   share (``C``, ``B``), their operands in the type ``operands`` names
+#   and their sums float32; ``B C^T`` is formed once a grid step and
+#   shared by the block's heads.  A chunk past the row's
+#   length is neither copied in nor worked; past ``lengths[r]`` inside a
+#   chunk ``dt`` is taken as 0.  **plain**: a ``lax.scan`` over the
+#   positions, the oracle (tests/test_ssd_ops.py) and the path off the
+#   TPU.
+# - `mamba2_update_rows`: one position for every row of layer ``l`` of a
+#   stack ``(L, B, H P, N)``, in place, told ``live`` as
+#   `state_update_rows` is.  The **kernel** walks the live rows with its
+#   own copies, a row's whole state (4 MB at Granite's sizes) in,
+#   changed a tile of 128 channels at a time on the vector unit, and
+#   back out; what is a column against the state's tiles (the decay and
+#   ``dt x`` a channel, the output) is handed over and given back as
+#   tiles ``(128, H P / 128)``, a block's column a lane.
+
+# positions a chunk and heads a block of the Mamba-2 scan kernel;
+# channels a tile of the update kernel
+_CHUNK = 128
+_HEADS = 16
+_ROWS = 128
+
+
+def mamba2_chunk():
+    """Positions a time chunk of the Mamba-2 scan kernel."""
+    return _CHUNK
+
+
+def mamba2_scan_rows(x, dt, A, B, C, D, lengths, operands=None,
+                     tally=None):
+    """``x`` (R, S, H, P); ``dt`` (R, S, H) after its softplus; ``A``
+    (H,) negative; ``B``, ``C`` (R, S, N); ``D`` (H,); ``lengths`` (R,)
+    int32.  Returns (y (R, S, H, P) float32, state (R, H P, N) float32
+    after each row's last real position, from an empty one); ``y`` past
+    a row's length is finite and means nothing.  The kernel's products
+    take their operands in the type ``operands`` (None: ``x``'s) and sum
+    in float32; the plain path is float32 throughout."""
+    R, _, H, P = x.shape
+    kernel = _on_tpu() and _mamba2_scan_fits(H, P, B.shape[-1])
+    if tally is not None:
+        tally["kernel" if kernel else "plain"] += R
+    if kernel:
+        return _mamba2_scan_kernel_call(x, dt, A, B, C, D, lengths,
+                                        operands=operands)
+    return _mamba2_scan_plain(x, dt, A, B, C, D, lengths)
+
+
+def _mamba2_scan_fits(H, P, N):
+    """Whole tiles: a head's channels whole sublane tiles, the states
+    whole lane tiles, a head block a whole number of lane tiles when it
+    is turned."""
+    hb = min(_HEADS, H)
+    return P % 8 == 0 and N % _LANE == 0 and H % hb == 0 \
+        and (hb * P) % _LANE == 0
+
+
+def _mamba2_scan_plain(x, dt, A, B, C, D, lengths):
+    R, S, H, P = x.shape
+    f32 = jnp.float32
+    real = jnp.arange(S)[None, :] < lengths[:, None]
+    dt = jnp.where(real[..., None], dt.astype(f32), 0.0)
+    A, D = A.astype(f32), D.astype(f32)
+
+    def step(h, at):
+        x_t, dt_t, B_t, C_t = at            # (R, H, P), (R, H), (R, N) x 2
+        h = jnp.exp(dt_t * A)[:, :, None, None] * h \
+            + (dt_t[:, :, None] * x_t)[..., None] * B_t[:, None, None, :]
+        return h, jnp.sum(h * C_t[:, None, None, :], axis=-1) \
+            + D[:, None] * x_t
+
+    h, y = lax.scan(step, jnp.zeros((R, H, P, B.shape[-1]), f32), tuple(
+        a.astype(f32).swapaxes(0, 1) for a in (x, dt, B, C)))
+    return y.swapaxes(0, 1), h.reshape(R, H * P, -1)
+
+
+def _mxu(spec_dims, a, b):
+    """A kernel's product: float32 sums; float32 operands at full
+    precision, narrower ones as they are."""
+    return lax.dot_general(
+        a, b, (spec_dims, ((), ())), preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST if a.dtype == jnp.float32 else None)
+
+
+def _mamba2_scan_kernel(len_ref, x_ref, dt_ref, cs_ref, b_ref, c_ref, d_ref,
+                        y_ref, st_ref, xt, yt, *, chunk, heads, width):
+    """A grid step: time chunk ``t`` of head block ``e`` of row ``r``.
+    x, y (chunk, heads * width) float32, a position a row; dt, cs, d
+    (heads, chunk): a head's steps, cumulative log-decays and D along
+    the lanes; b, c (chunk, N); the state (heads * width, N), resident
+    over the row's chunks: it is the carry.  Scratch: the block turned,
+    channels on the sublanes (heads * width, chunk), in and out."""
+    from jax.experimental import pallas as pl
+
+    r, t = pl.program_id(0), pl.program_id(2)
+    n, t0 = len_ref[r], t * chunk
+    Q, P = chunk, width
+
+    @pl.when(t == 0)
+    def _first():
+        st_ref[...] = jnp.zeros(st_ref.shape, jnp.float32)
+
+    @pl.when(t0 >= n)
+    def _past():
+        y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
+
+    @pl.when(t0 < n)
+    def _walk():
+        Bm, Cm = b_ref[...], c_ref[...]
+        mxu = Bm.dtype
+        # [s, t] = B_s . C_t, once for the block's heads
+        bct = _mxu(((1,), (1,)), Bm, Cm)
+        s_at = lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        t_at = lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        xt[...] = x_ref[...].T
+        # what the chunk's first state puts out, every head of the block
+        # in one product: a head scales its rows by exp(c_t) below
+        yt[...] = _mxu(((1,), (1,)), st_ref[...].astype(mxu), Cm)
+
+        def head(j, carry):
+            at = pl.ds(pl.multiple_of(j * P, P), P)
+            cs, dt = cs_ref[pl.ds(j, 1), :], dt_ref[pl.ds(j, 1), :]
+            # the cumulative log-decay at s, down the sublanes
+            cs_s = jnp.sum(jnp.where(s_at == t_at, cs, 0.0), axis=1,
+                           keepdims=True)
+            g = jnp.where(s_at <= t_at,
+                          jnp.exp(jnp.minimum(cs - cs_s, 0.0)), 0.0) * bct
+            xh = xt[at, :]
+            yt[at, :] = _mxu(((1,), (0,)), (xh * dt).astype(mxu),
+                             g.astype(mxu)) + yt[at, :] * jnp.exp(cs) \
+                + d_ref[pl.ds(j, 1), :] * xh
+            # the chunk's whole log-decay: the sums never rise
+            last = jnp.min(cs, axis=1, keepdims=True)
+            st_ref[at, :] = jnp.exp(last) * st_ref[at, :]
+            # x weighed by what is left of a position at the chunk's end
+            xt[at, :] = xh * (jnp.exp(last - cs) * dt)
+            return carry
+
+        lax.fori_loop(0, heads, head, 0)
+        # the positions' parts of the new states, the block's heads in
+        # one product
+        st_ref[...] = st_ref[...] + _mxu(((1,), (0,)),
+                                         xt[...].astype(mxu), Bm)
+        y_ref[...] = yt[...].T
+
+
+def _mamba2_scan_kernel_call(x, dt, A, B, C, D, lengths, operands=None,
+                             interpret=False, chunk=None, heads=None):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, S, H, P = x.shape
+    N = B.shape[-1]
+    f32 = jnp.float32
+    Q, hb = chunk or _CHUNK, heads or min(_HEADS, H)
+    Sp = -(-S // Q) * Q
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, S)
+    mxu = jnp.dtype(operands or x.dtype)
+    # past a row's length a position leaves the state alone; the
+    # cumulative log-decay inside each chunk, float32
+    dt = jnp.where(jnp.arange(S)[None, :, None] < lengths[:, None, None],
+                   dt.astype(f32), 0.0)
+    x = x.reshape(R, S, H * P).astype(f32)
+    if Sp != S:
+        dt, x, B, C = (jnp.pad(a, ((0, 0), (0, Sp - S), (0, 0)))
+                       for a in (dt, x, B, C))
+    cs = jnp.cumsum((dt * A.astype(f32)).reshape(R, Sp // Q, Q, H),
+                    axis=2).reshape(R, Sp, H)
+
+    def held(r, t, lens):
+        # no further than the row's last real chunk: a chunk that is
+        # not worked keeps the blocks that are resident
+        return jnp.minimum(t, jnp.maximum(pl.cdiv(lens[r], Q), 1) - 1)
+
+    by_time = pl.BlockSpec((None, Q, hb * P),
+                           lambda r, e, t, lens: (r, held(r, t, lens), e))
+    by_head = pl.BlockSpec((None, hb, Q),
+                           lambda r, e, t, lens: (r, e, held(r, t, lens)))
+    by_state = pl.BlockSpec((None, Q, N),
+                            lambda r, e, t, lens: (r, held(r, t, lens), 0))
+    kw = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    y, state = pl.pallas_call(
+        functools.partial(_mamba2_scan_kernel, chunk=Q, heads=hb, width=P),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R, H // hb, Sp // Q),
+            in_specs=[by_time, by_head, by_head, by_state, by_state,
+                      pl.BlockSpec((hb, Q), lambda r, e, t, lens: (e, 0))],
+            out_specs=[pl.BlockSpec((None, Q, hb * P),
+                                    lambda r, e, t, lens: (r, t, e)),
+                       pl.BlockSpec((None, hb * P, N),
+                                    lambda r, e, t, lens: (r, e, 0))],
+            scratch_shapes=[pltpu.VMEM((hb * P, Q), f32)] * 2),
+        out_shape=[jax.ShapeDtypeStruct((R, Sp, H * P), f32),
+                   jax.ShapeDtypeStruct((R, H * P, N), f32)],
+        interpret=interpret, **kw,
+    )(lengths, x, dt.swapaxes(1, 2), cs.swapaxes(1, 2), B.astype(mxu),
+      C.astype(mxu), jnp.broadcast_to(D.astype(f32)[:, None], (H, Q)))
+    return y[:, :S].reshape(R, S, H, P), state
+
+
+def mamba2_update_rows(state, l, x, dt, A, B, C, D, live=None, tally=None):
+    """``state`` (L, B, H P, N) float32, donated; ``l`` the layer, an
+    int or a traced scalar; ``x`` (B, H, P); ``dt`` (B, H); ``A``, ``D``
+    (H,); ``B``, ``C`` (B, N); ``live`` (B,) bool or None for all.
+    Returns (y (B, H, P) float32, zero for a row that is not live; the
+    states, layer ``l``'s live rows moved on one position)."""
+    R, H, P = x.shape
+    kernel = _on_tpu() and _mamba2_update_fits(H * P, B.shape[-1])
+    if tally is not None:
+        tally["kernel" if kernel else "plain"] += R
+        if kernel and live is not None:
+            tally["kernel_live"] += R
+    update = _mamba2_update_kernel_call if kernel else _mamba2_update_plain
+    return update(state, l, x, dt, A, B, C, D, live)
+
+
+def _mamba2_update_fits(E, N):
+    """Whole tiles of ``_ROWS`` channels, whose two columns a tile (the
+    decay and ``dt x``) fit one lane tile side by side."""
+    return E % _ROWS == 0 and 2 * (E // _ROWS) <= _LANE and N % _LANE == 0
+
+
+def _mamba2_update_plain(state, l, x, dt, A, B, C, D, live=None):
+    f32 = jnp.float32
+    R, H, P = x.shape
+    x, dt, B, C = (a.astype(f32) for a in (x, dt, B, C))
+    old = lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
+    h = jnp.exp(dt * A.astype(f32))[:, :, None, None] \
+        * old.astype(f32).reshape(R, H, P, -1) \
+        + (dt[:, :, None] * x)[..., None] * B[:, None, None, :]
+    y = jnp.sum(h * C[:, None, None, :], axis=-1) \
+        + D.astype(f32)[:, None] * x
+    h = h.reshape(old.shape).astype(state.dtype)
+    if live is not None:
+        h = jnp.where(live[:, None, None], h, old)
+        y = jnp.where(live[:, None, None], y, 0.0)
+    zero = jnp.int32(0)
+    return y, lax.dynamic_update_slice(state, h[None],
+                                       (jnp.int32(l), zero, zero, zero))
+
+
+def _mamba2_update_kernel(l_ref, live_ref, st_hbm, cols_ref, b_ref, c_ref,
+                          out_hbm, y_ref, buf, sem, rows, *, tiles):
+    """One invocation a call.  The states whole, in HBM (read through
+    the output they are aliased to); cols (B, rows a tile, 2 tiles): a
+    row's decays a channel and then its ``dt x``, a tile's column a
+    lane; b, c (B, N); y (B, rows a tile, tiles), a tile's column a
+    lane; scratch: ``_SLOTS`` state buffers (H P, N), the copies'
+    semaphores and the live rows in order (`_walk_live_rows`)."""
+    from jax.experimental import pallas as pl
+
+    del st_hbm
+    sub = cols_ref.shape[1]
+    y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, y_ref.shape[1:], 1)
+
+    def change(b, slot):
+        cols = cols_ref[b]
+        Bt, Ct = b_ref[pl.ds(b, 1), :], c_ref[pl.ds(b, 1), :]
+        y = jnp.zeros(y_ref.shape[1:], jnp.float32)
+        for i in range(tiles):
+            at = pl.ds(i * sub, sub)
+            h = cols[:, i:i + 1] * buf[slot, at, :] \
+                + cols[:, tiles + i:tiles + i + 1] * Bt
+            buf[slot, at, :] = h
+            y = jnp.where(lane == i, jnp.sum(h * Ct, axis=1, keepdims=True),
+                          y)
+        y_ref[b] = y
+
+    _walk_live_rows(l_ref, live_ref, out_hbm, buf, sem, rows, change)
+
+
+def _mamba2_update_kernel_call(state, l, x, dt, A, B, C, D, live=None,
+                               interpret=False, rows=None):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, H, P = x.shape
+    E, N = state.shape[2:]
+    f32 = jnp.float32
+    sub = rows or _ROWS
+    tiles = E // sub
+    x, dt = x.astype(f32), dt.astype(f32)
+    live = jnp.ones((R,), jnp.int32) if live is None \
+        else live.astype(jnp.int32)
+
+    def columns(a):
+        """(R, E) a channel → (R, sub, tiles): a tile's column a lane."""
+        return a.reshape(R, tiles, sub).swapaxes(1, 2)
+
+    decay = jnp.broadcast_to(jnp.exp(dt * A.astype(f32))[:, :, None],
+                             (R, H, P)).reshape(R, E)
+    cols = jnp.concatenate(
+        [columns(decay), columns((dt[:, :, None] * x).reshape(R, E))],
+        axis=-1)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    kw = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=96 * 1024 * 1024)}
+    state, y = pl.pallas_call(
+        functools.partial(_mamba2_update_kernel, tiles=tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[in_place] + [whole] * 3,
+            out_specs=[in_place, whole],
+            scratch_shapes=[pltpu.VMEM((_SLOTS, E, N), state.dtype),
+                            pltpu.SemaphoreType.DMA((2, _SLOTS)),
+                            pltpu.SMEM((R,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((R, sub, tiles), f32)],
+        # operand 0 (after the two prefetched scalars) is output 0
+        input_output_aliases={2: 0},
+        interpret=interpret, **kw,
+    )(jnp.asarray(l, jnp.int32).reshape(1), live, state, cols,
+      B.astype(f32), C.astype(f32))
+    y = y.swapaxes(1, 2).reshape(R, H, P) + D.astype(f32)[:, None] * x
+    return jnp.where(live[:, None, None] != 0, y, 0.0), state

@@ -180,6 +180,41 @@ def test_serve_jamba_phase():
     assert out["retraces"] == 0 and out["programs"] == 2
 
 
+def test_ssd_and_serve_granite_phases():
+    """The Mamba-2 kernels' phase, interpreted at tiny tiles (ragged
+    lengths, a live subset), and the eighth family's at a tiny size: the
+    engine's tuple is the parameters' own buffers, the scan's positions,
+    the live rows' updates, the attention layer's pairs and the experts'
+    are counted, coalesced == alone, a repeat is identical, a row that
+    wants no token keeps its states and tails, and the served logits
+    equal the float32 reference's.  The chip's sizes are the published
+    widths and the cell's bucket."""
+    full, small = chip_smoke.ssd_full(), chip_smoke.granite_small()
+    assert (full.H, full.P, full.N, full.S) == (128, 64, 128, 512)
+    assert small.kwargs["ssm_head_dim"] == 64 \
+        and small.kwargs["d_state"] == 128 and small.prefill_floor == 512 \
+        and small.kwargs["max_length"] == 704
+    out = chip_smoke.phase_ssd(chip_smoke.SsdSize(
+        H=4, P=8, N=16, S=24, lengths=(1, 8, 13, 24), L=2,
+        live=(1, 0, 1, 1), tiles=(8, 2, 8)), "cpu")
+    assert set(out) == {"scan_float32", "scan_bfloat16", "update"}
+    kwargs = dict(vocab_size=96, units=64,
+                  layer_types=["mamba", "mamba", "attention", "mamba",
+                               "mamba"],
+                  num_heads=4, kv_heads=2, ssm_heads=4, ssm_head_dim=8,
+                  d_state=16, d_conv=4, expert_hidden=24, shared_hidden=48,
+                  router_experts=8, experts_per_token=3,
+                  experts_held=(0, 2), embedding_multiplier=12.0,
+                  residual_multiplier=0.22, attention_multiplier=0.0625,
+                  logits_scaling=4.0, max_length=64,
+                  prefill_chunk_tokens=128, grad_req="null")
+    assert set(kwargs) <= set(small.kwargs)
+    size = chip_smoke.FamilySize(kwargs=kwargs, batch=4, prefill_floor=64,
+                               prompt_lens=(1, 3, 21, 40), new_tokens=7)
+    out = chip_smoke.phase_serve_granite(size, "cpu")
+    assert out["retraces"] == 0 and out["programs"] == 2
+
+
 def test_serve_cmda_phase():
     """The sixth family's phase at a tiny size: the engine's tuple is the
     parameters' own buffers, the four stacks (two rings among them) stay
