@@ -26,10 +26,13 @@ attention layer's keys and values), held to the float32 reference's
 logits; an eighth, ``GraniteHybridModel`` (Mamba-2 layers whose heads'
 states ride in the donated cache, routed experts and a shared one in
 every layer), after its two kernels alone against their plain paths at
-the published widths (``ssd``); with four chips, the GPT step under
-``shard_model`` fsdp and tp.  Phases, in order: device, sync, kernel,
-train, serve, serve_mimo, serve_keye, serve_kimi, serve_ouro,
-serve_cmda, serve_cmda_full, serve_jamba, ssd, serve_granite, sharded
+the published widths (``ssd``); the held experts' grouped product alone
+(``moe_grouped``: `ops/moe.py`'s kernel against ``lax.ragged_dot`` at
+the five expert families' decode and prefill shapes, both timed); with
+four chips, the GPT step under ``shard_model`` fsdp and tp.  Phases, in
+order: device, sync, kernel, train, serve, serve_mimo, serve_keye,
+serve_kimi, serve_ouro, serve_cmda, serve_cmda_full, serve_jamba, ssd,
+serve_granite, moe_grouped, sharded
 (``--phases a,b``: the device phase and only those).  The first failed check raises and the process
 exits non-zero; the last line of stdout is the JSON result only
 when every phase passed.
@@ -202,6 +205,50 @@ def ssd_full():
     return SsdSize(H=128, P=64, N=128, S=512,
                    lengths=(1, 100, 128, 129, 300, 384, 511, 512), L=3,
                    live=(1, 0, 1, 1, 0, 0, 1, 1))
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedSize:
+    """The grouped product's cases: (name, P rows of the buffer, M, F, n
+    experts a layer, the rows of each group in the pass), each over a
+    stack of ``layers`` layers, timed over ``reps`` passes."""
+    cases: tuple
+    layers: int = 2
+    reps: int = 20
+
+
+def moe_grouped_full():
+    """A decode step's pass and a prefill pass of each expert family at
+    its cell's widths and bucket (benchmark/configs): the buffer
+    `ops/moe.py::share_pass_rows` gives, and the groups the cell's
+    traffic makes of it (PERF.md section 5: Granite's 18 experts all hit
+    by 107 pairs a step, MiMo's 4 of 16, Keye's 5 of 16, Kimi's 1 of 12,
+    Command A+'s 2 of 8; a prefill pass holds the few experts its sorted
+    rows fall to)."""
+    def spread(rows, hit, n, first=0):
+        """``rows`` pairs over ``hit`` of ``n`` experts, every
+        ``n // hit``-th from ``first``, as evenly as they go."""
+        sizes = [0] * n
+        for i in range(hit):
+            sizes[(first + i * (n // hit)) % n] = \
+                rows // hit + (i < rows % hit)
+        return tuple(sizes)
+
+    return GroupedSize(cases=(
+        ("granite.decode", 256, 4096, 768, 18, spread(107, 18, 18)),
+        ("granite.prefill", 1024, 4096, 768, 18,
+         (0,) * 5 + (211, 569, 244) + (0,) * 10),
+        ("mimo.decode", 256, 4096, 2048, 16, spread(32, 4, 16, 1)),
+        ("mimo.prefill", 1024, 4096, 2048, 16, spread(1024, 8, 8) + (0,) * 8),
+        ("keye.decode", 128, 2048, 768, 16, spread(12, 5, 16)),
+        ("keye.prefill", 4096, 2048, 768, 16,
+         (0,) * 4 + (512, 1024, 1024, 1024, 512) + (0,) * 7),
+        ("kimi.decode", 64, 7168, 2048, 12, spread(2, 1, 12, 7)),
+        ("kimi.prefill", 4096, 7168, 2048, 12, spread(4096, 12, 12)),
+        ("cmda.decode", 64, 4096, 4096, 8, spread(4, 2, 8, 3)),
+        ("cmda.prefill", 4096, 4096, 4096, 8, (0, 0, 1024, 1024, 1024, 1024,
+                                               0, 0)),
+    ))
 
 
 def granite_small():
@@ -892,10 +939,14 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
         "retraces": serving.trace_count() - pinned, "peak_bytes": peak}
 
 
-def moe_rows_hold(timing):
-    """The grouped product was given at least the rows its pairs need."""
+def moe_rows_hold(timing, platform):
+    """The grouped product was given at least the rows its pairs need,
+    and on the chip every held experts' call of the group's two programs
+    went through the kernel of `ops/moe.py` (elsewhere none: the kernel
+    runs interpreted in tests/test_moe_grouped.py)."""
     return timing["moe_rows_computed_decode"] \
-        >= timing["moe_pairs_decode"] > 0
+        >= timing["moe_pairs_decode"] > 0 \
+        and timing["moe_grouped_kernel_share"] == float(platform == "tpu")
 
 
 def packed_positions(kwargs, tile, real, S):
@@ -928,7 +979,7 @@ def phase_serve_mimo(size, platform):
         real = lens + (1,) * pads
         return 0 < timing["moe_pairs_prefill"] <= sum(size.prompt_lens) \
             * layers * size.kwargs["experts_per_token"] \
-            and moe_rows_hold(timing) \
+            and moe_rows_hold(timing, platform) \
             and timing["prefill_positions"] == sum(real) \
             and timing["prefill_positions_worked"] == packed_positions(
                 chunk, mimo_v2._TILE, real, timing["bucket"][1])
@@ -1003,7 +1054,7 @@ def phase_serve_keye(size, platform):
             and 0 < timing["attn_keys_selected_decode"] \
             < timing["attn_keys_live_decode"] \
             and timing["prefill_attn_kernel_share"] == 1.0 \
-            and moe_rows_hold(timing)
+            and moe_rows_hold(timing, platform)
 
     # three stacks: keys, values, the indexer's keys
     net, engine, timing, out = serve_family(
@@ -1084,7 +1135,7 @@ def phase_serve_kimi(size, platform):
             == L * sum(n + j + 1 for n in lens
                        for j in range(size.new_tokens - 1)) \
             and timing["prefill_attn_kernel_share"] == 1.0 \
-            and moe_rows_hold(timing)
+            and moe_rows_hold(timing, platform)
 
     # one stack with no heads, and none for the values
     net, engine, _, out = serve_family(
@@ -1211,7 +1262,7 @@ def phase_serve_cmda(size, platform, tag="serve_cmda"):
             and timing["prefill_attn_kernel_share"] == 1.0 \
             and 0 < timing["moe_pairs_prefill"] <= sum(lens) \
             * len(types) * kw["experts_per_token"] \
-            and moe_rows_hold(timing)
+            and moe_rows_hold(timing, platform)
 
     # four stacks: two kinds of cache, keys and values
     net, engine, _, out = serve_family(
@@ -1426,6 +1477,103 @@ def phase_ssd(size, platform):
     return out
 
 
+MOE_GROUPED_TOL = 1e-4
+
+
+def phase_moe_grouped(size, platform):
+    """`ops/moe.py`'s grouped product through its kernel (compiled on
+    the chip, interpreted elsewhere) against ``lax.ragged_dot`` over the
+    same layer of the same stack, both products of a pass (the second
+    fed the same rows), at layer 1 of a stack so that the offset into it
+    is used: the largest difference over the rows that hold a pair, as a
+    share of the largest value, and on the chip the time of a pass by
+    each path beside the time the hit experts' bytes take at the chip's
+    memory rate."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mxnet_tpu.ops import moe
+
+    here = platform != "tpu"
+    L, reps = size.layers, size.reps
+    out = {}
+    for name, P, M, F, n, sizes in size.cases:
+        require(here or moe._fits(P, M, F, n, jnp.bfloat16),
+                f"moe_grouped: the kernel would not take {name} on the chip")
+        ks = jax.random.split(jax.random.key(P + M + F), 3)
+        w13 = jax.random.normal(ks[0], (L, n, M, 2 * F), jnp.bfloat16) \
+            * M ** -0.5
+        w2 = jax.random.normal(ks[1], (L, n, F, M), jnp.bfloat16) * F ** -0.5
+        x = jax.random.normal(ks[2], (P, M), jnp.bfloat16)
+        hi = jnp.cumsum(jnp.array(sizes, jnp.int32))
+        lo = hi - jnp.array(sizes, jnp.int32)
+        total = int(hi[-1])
+
+        def act(h):
+            return (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(jnp.bfloat16)
+
+        def by_kernel(x, w13, w2, l, h=None):
+            walk = moe._walk(lo, hi, l * n, P)
+            g = moe._grouped_kernel_call(
+                x, w13.reshape(L * n, M, 2 * F), walk, interpret=here)
+            return g, moe._grouped_kernel_call(
+                act(g) if h is None else h, w2.reshape(L * n, F, M), walk,
+                interpret=here)
+
+        def by_ragged(x, w13, w2, l, h=None):
+            # the parent's call: the stack whole, layer l's groups in it
+            every = lax.dynamic_update_slice(
+                jnp.zeros((L * n,), jnp.int32), hi - lo, (l * n,))
+            g = lax.ragged_dot(x, w13.reshape(L * n, M, 2 * F), every,
+                               preferred_element_type=jnp.float32)
+            return g, lax.ragged_dot(
+                act(g) if h is None else h, w2.reshape(L * n, F, M), every,
+                preferred_element_type=jnp.float32)
+
+        g0, _ = jax.jit(by_ragged)(x, w13, w2, 1)
+        h = act(g0)
+        want = jax.jit(by_ragged)(x, w13, w2, 1, h)
+        got = jax.jit(by_kernel)(x, w13, w2, jnp.int32(1), h)
+        require(here or "tpu_custom_call" in jax.jit(by_kernel).lower(
+            x, w13, w2, 1).as_text(),
+            "moe_grouped: no Mosaic custom call in the lowered product")
+        err = []
+        for a, b in zip(got, want):
+            a, b = (np.asarray(v[:total], np.float32) for v in (a, b))
+            require(np.isfinite(a).all(), f"moe_grouped: {name} not finite")
+            err.append(float(np.abs(a - b).max() / np.abs(b).max()))
+        require(max(err) <= MOE_GROUPED_TOL,
+                f"moe_grouped: {name} off lax.ragged_dot by {err}")
+        out[name] = {"err": err}
+        line = f"[moe_grouped] {name} ({P} x {M} x {2 * F}, " \
+            f"{sum(s > 0 for s in sizes)} of {n} hit by {total}): " \
+            f"err {err[0]:.1e}, {err[1]:.1e} (tol {MOE_GROUPED_TOL})"
+        if not here:
+            def passes(path):
+                def many(x, w13, w2):
+                    def one(i, acc):
+                        return acc + path(x, w13, w2, i % L)[1][0, 0]
+                    return lax.fori_loop(0, reps, one, jnp.float32(0))
+                run = jax.jit(many)
+                jax.block_until_ready(run(x, w13, w2))
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(x, w13, w2))
+                return (time.perf_counter() - t0) / reps * 1e6
+
+            us = {"kernel_us": passes(by_kernel),
+                  "ragged_us": passes(by_ragged),
+                  "bytes_us": sum(s > 0 for s in sizes) * 3 * M * F * 2
+                  / 819e9 * 1e6}
+            out[name].update(us)
+            line += "; a pass {kernel_us:.0f} us by the kernel, " \
+                "{ragged_us:.0f} by lax.ragged_dot, {bytes_us:.0f} the hit " \
+                "experts' bytes at 819 GB/s".format(**us)
+        say(line)
+        del w13, w2
+    return out
+
+
 def phase_serve_granite(size, platform):
     from mxnet_tpu.gluon.model_zoo import granite_hybrid
     from mxnet_tpu.ops import ssm
@@ -1462,7 +1610,7 @@ def phase_serve_granite(size, platform):
             and timing["decode_state_update_live_share"] == on \
             and 0 < timing["moe_pairs_prefill"] \
             <= (Lm + La) * sum(real) * kw["experts_held"][1] \
-            and moe_rows_hold(timing)
+            and moe_rows_hold(timing, platform)
 
     # two stacks, of which no layer moves; the CPU's plain update writes
     # a layer's states whole, at any size, so it is checked nothing
@@ -1615,6 +1763,8 @@ def main(argv=()):
     run("ssd", phase_ssd, ssd_full(), platform)
     gc.collect()
     run("serve_granite", phase_serve_granite, granite_small(), platform)
+    gc.collect()
+    run("moe_grouped", phase_moe_grouped, moe_grouped_full(), platform)
     gc.collect()
     import jax
 

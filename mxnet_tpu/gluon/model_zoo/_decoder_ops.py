@@ -170,39 +170,36 @@ def route(z, p, x, choose):
                 chosen.reshape(B, S, k), weights.reshape(B, S, k))
 
 
-def experts_of_layer(z, w13, w2, l, x, route, valid):
+def experts_of_layer(z, w13, w2, l, x, route, valid, tally=None):
     """x + layer ``l``'s held experts' part for the routed tokens, the
     experts' weights stacked by layer (``w13`` (L, n, C, 2F), ``w2``
     (L, n, F, C)); also `held_experts_ffn`'s counts for that layer.
 
-    The grouped product is given the stacks whole, as ``L * n`` groups
-    of which only layer ``l``'s ``n`` can be chosen (an expert held here
-    becomes group ``l * n + e``, any other none): a layer's slice of a
-    stack would be copied for the product's custom call, 151 MB a layer
-    and a decode step at Keye-VL-2.0's sizes (2.8 ms of a 9.4 ms step
-    on the v5e), while empty groups cost nothing."""
+    The stacks are handed whole with the layer beside them, and read
+    where they lie: on a TPU the grouped product's kernel takes ``l`` as
+    a scalar operand and finds expert e's block at ``l * n + e`` of the
+    stack seen as ``(L n, ...)``, so the pairs are sorted into the
+    layer's own ``n`` groups and no slice of a stack is made.  A slice
+    would be copied for the product: 151 MB a layer and a decode step at
+    Keye-VL-2.0's sizes, 2.8 ms of a 9.4 ms step on the v5e (PR 30,
+    which for that reason gave ``lax.ragged_dot`` the whole stack as
+    ``L n`` groups, all but ``n`` of them empty; the plain path, which
+    the chip takes only for widths the kernel has no tiles for, slices).
+    ``tally``: the program's count of such calls by path."""
     import jax
-    import jax.numpy as jnp
-    from jax import lax
 
     from ...ops import moe
 
     B, S, C = x.shape
     u, chosen, weights = route
     k = z.experts_per_token
-    lo, n = z.experts_held
     with jax.named_scope("serve.moe.experts"):
-        local = chosen - lo
-        group = jnp.where((local >= 0) & (local < n), local + l * n, -1)
         y, stats = moe.held_experts_ffn(
-            u.reshape(B * S, C), group.reshape(B * S, k),
-            weights.reshape(B * S, k),
-            w13.reshape((-1,) + w13.shape[2:]),
-            w2.reshape((-1,) + w2.shape[2:]),
+            u.reshape(B * S, C), chosen.reshape(B * S, k),
+            weights.reshape(B * S, k), w13, w2, experts_lo=z.experts_held[0],
             valid=None if valid is None else valid.reshape(B * S),
-            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C))
-        stats = jnp.concatenate([lax.dynamic_slice(stats, (l * n,), (n,)),
-                                 stats[-1:]])
+            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C), layer=l,
+            tally=tally)
         return y.reshape(B, S, C), stats
 
 

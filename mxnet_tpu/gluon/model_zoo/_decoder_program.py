@@ -17,7 +17,7 @@ A family states
 
 The base gives the rest of the contract `serving.ServingEngine` sees:
 ``weights()``, ``init_cache(B)``, ``step(..)``, ``window``, ``vocab`` and
-the four tallies.  What every family decides alike is decided here and
+the five tallies.  What every family decides alike is decided here and
 in `ops/cache_write.py` and `ops/ssm.py`, once: which rows of a decode
 step still count (``live``), what a row's write and a row's state are
 told of them (a row that is not live keeps its positions, its state and
@@ -61,7 +61,10 @@ class Step:
       over the cache by path, and the attention calls inside the block,
       which a family's body counts itself (``attends["kernel"] += 1``);
       ``updates`` (``state_updates[S]``): the rows whose state a scan
-      or an update moved on, by path.
+      or an update moved on, by path; ``products``
+      (``grouped_products[S]``): the held experts' calls
+      (`ops/moe.py::held_experts_ffn`, which a family hands it), by the
+      path their grouped products took.
 
     The states' recurrence is `ops/ssm.py`'s Mamba-1 pair (a decay a
     state element, a state ``(N, E)``) unless the family hands `scan`
@@ -89,6 +92,8 @@ class Step:
         self.reads = program.cache_reads[self.S] = collections.Counter()
         self.attends = program.block_attends[self.S] = collections.Counter()
         self.updates = program.state_updates[self.S] = collections.Counter()
+        self.products = program.grouped_products[self.S] = \
+            collections.Counter()
         self._given = given
         self._mesh = program._mesh
         self._layouts = program._layouts
@@ -204,11 +209,13 @@ class DecoderProgram:
         # cache_writes[S] its row writes, by path; cache_reads[S] its
         # attention calls over the caches, by path; block_attends[S]
         # its attention calls inside the block, by path;
-        # state_updates[S] the rows whose state it moved on, by path
+        # state_updates[S] the rows whose state it moved on, by path;
+        # grouped_products[S] its held experts' calls, by path
         self.cache_writes = {}
         self.cache_reads = {}
         self.block_attends = {}
         self.state_updates = {}
+        self.grouped_products = {}
 
     # -- what a family states --------------------------------------------------
 

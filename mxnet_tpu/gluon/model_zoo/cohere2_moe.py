@@ -163,9 +163,10 @@ def _experts_front(z, p, h):
     return shared, (chosen.reshape(B, S, k), weights.reshape(B, S, k))
 
 
-def _experts(z, p, h, route, valid, add_to):
+def _experts(z, p, h, route, valid, add_to, tally=None):
     """``add_to`` + the held experts' part for the routed tokens; also
-    `held_experts_ffn`'s counts."""
+    `held_experts_ffn`'s counts.  ``tally``: the program's count of
+    such calls by the grouped product's path."""
     import jax
 
     from ...ops import moe
@@ -179,17 +180,20 @@ def _experts(z, p, h, route, valid, add_to):
             weights.reshape(B * S, k), p["experts_gate_up_weight"],
             p["experts_down_weight"], experts_lo=z.experts_held[0],
             valid=None if valid is None else valid.reshape(B * S),
-            add_to=add_to.reshape(B * S, C))
+            add_to=add_to.reshape(B * S, C), tally=tally)
         return y.reshape(B, S, C), stats
 
 
-def _block_layer(z, kind, p, x, pos, lengths, valid, tally=None):
+def _block_layer(z, kind, p, x, pos, lengths, valid, tally=None,
+                 products=None):
     """A layer on a block (B, S, C) that attends inside itself.
     ``lengths`` (B,) traced, the rows' real positions, or None for all:
     token-wise products run ``token_chunk`` positions at a time up to
     the longest row's, the kernel to each row's own, and a row's queries
-    past its length come out zero.  Returns (x, k, v (B, K, S, D) in the
-    weights' type, `held_experts_ffn`'s counts)."""
+    past its length come out zero.  ``tally``, ``products``: the
+    program's counts of the attention calls and of the experts' calls,
+    by path.  Returns (x, k, v (B, K, S, D) in the weights' type,
+    `held_experts_ffn`'s counts)."""
     import jax
     import jax.numpy as jnp
 
@@ -224,7 +228,7 @@ def _block_layer(z, kind, p, x, pos, lengths, valid, tally=None):
             tally["kernel"] += 1
     x, (h, chosen, weights) = _ops.by_tokens(back, chunk, live, x, a,
                                              axes=(2,))
-    x, stats = _experts(z, p, h, (chosen, weights), valid, x)
+    x, stats = _experts(z, p, h, (chosen, weights), valid, x, products)
     return x, k, v, stats
 
 
@@ -442,7 +446,7 @@ class Cohere2MoeProgram(DecoderProgram):
                                     p["o_weight"])[:, None]
                     shared, route = _experts_front(z, p, h)
                     x, stats = _experts(z, p, h, route, live[:, None],
-                                        x + a + shared)
+                                        x + a + shared, ctx.products)
                 else:
                     causal = jnp.sum(n * (n + 1) // 2)
                     asked = causal
@@ -450,7 +454,8 @@ class Cohere2MoeProgram(DecoderProgram):
                         m = jnp.minimum(n, R)
                         asked = jnp.sum(m * (m + 1) // 2 + (n - m) * R)
                     x, k, v, stats = _block_layer(z, kind, p, x, at, held,
-                                                  valid, ctx.attends)
+                                                  valid, ctx.attends,
+                                                  ctx.products)
                     stacks = write(stacks, kind, k, v, l, pos, held, row)
                 moe_counts = moe_counts.at[i, int(decode)].add(
                     _ops.moe_count_row(stats, n_held))

@@ -246,12 +246,12 @@ def _feed_forward_front(z, w, i, x):
     return x, (u, chosen, weights * z.residual_multiplier)
 
 
-def _experts(z, w, i, x, route, valid):
+def _experts(z, w, i, x, route, valid, tally=None):
     """x + r times layer i's held experts' part; also
     `held_experts_ffn`'s counts."""
     return _ops.experts_of_layer(
         z, w["experts_gate_up_weight"], w["experts_down_weight"], i, x,
-        route, valid)
+        route, valid, tally)
 
 
 def _head(z, w, x):
@@ -497,7 +497,8 @@ class GraniteHybridProgram(DecoderProgram):
                                               *kept)
                     # padding and rows that want no token are routed
                     # nowhere: only tokens that are kept cost
-                    x, stats = _experts(z, w, i - 1, x, route, valid)
+                    x, stats = _experts(z, w, i - 1, x, route, valid,
+                                        ctx.products)
                     moe_counts = moe_counts.at[i - 1, int(decode)].add(
                         _ops.moe_count_row(stats, n_held))
                 if kind is None:

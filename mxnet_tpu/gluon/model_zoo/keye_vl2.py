@@ -150,10 +150,10 @@ def _softmax_route(z, p, x):
         u, p["router_weight"], z.experts_per_token))
 
 
-def _experts(z, w, l, x, route, valid):
+def _experts(z, w, l, x, route, valid, tally=None):
     return _ops.experts_of_layer(z, w["experts_gate_up_weight"],
                                  w["experts_down_weight"], l, x, route,
-                                 valid)
+                                 valid, tally)
 
 
 def _block_layer(z, p, x, pos, last, tally=None):
@@ -388,7 +388,8 @@ class KeyeVL2Program(DecoderProgram):
             # padding and rows that want no token are routed nowhere:
             # only tokens that are kept cost
             x, stats = _experts(z, w, l, x, route,
-                                live[:, None] if decode else valid)
+                                live[:, None] if decode else valid,
+                                ctx.products)
             moe_counts = moe_counts.at[l, int(decode)].add(
                 _ops.moe_count_row(stats, n))
             attn_counts = attn_counts.at[l, int(decode)].add(

@@ -290,13 +290,14 @@ def _block_layer(z, p, x, pos, lengths, tally=None):
     return x, latent, route
 
 
-def _layers(z, w, x, valid, carry, moe_counts, attend):
+def _layers(z, w, x, valid, carry, moe_counts, attend, tally=None):
     """x through layer 0 and the scanned expert layers.  ``attend(x,
     carry, p, l) -> (x, carry, route or ())`` is a layer's attention and
     what of its feed-forward needs no other token (the cached step
     writes and reads its cache there, in ``carry``); ``valid`` (B, S)
     marks the real tokens (None: all), ``moe_counts`` (L - 1, n + 3) are
-    the expert layers' counters.  Returns (x, carry, moe_counts)."""
+    the expert layers' counters, ``tally`` the program's count of the
+    experts' calls by path.  Returns (x, carry, moe_counts)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -311,7 +312,7 @@ def _layers(z, w, x, valid, carry, moe_counts, attend):
         # padding is routed nowhere: only real tokens cost
         x, stats = _ops.experts_of_layer(
             z, w["experts_gate_up_weight"], w["experts_down_weight"], l - 1,
-            x, route, valid)
+            x, route, valid, tally)
         return (x, carry, moe_counts.at[l - 1].add(
             _ops.moe_count_row(stats, n))), None
 
@@ -513,7 +514,7 @@ class KimiK2Program(DecoderProgram):
             stack, seen, moe_counts = carry
             x, (stack, seen), phase = _layers(
                 z, w, x, live[:, None] if decode else valid, (stack, seen),
-                moe_counts[:, int(decode)], attend)
+                moe_counts[:, int(decode)], attend, ctx.products)
             moe_counts = moe_counts.at[:, int(decode)].set(phase)
             with jax.named_scope("serve.head"):
                 h = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]

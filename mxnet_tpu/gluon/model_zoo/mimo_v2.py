@@ -231,9 +231,10 @@ def _dense(z, p, x):
                                   p["down_weight"])
 
 
-def _experts(z, p, x, route, valid):
+def _experts(z, p, x, route, valid, tally=None):
     """x + the held experts' part for the routed tokens; also
-    `held_experts_ffn`'s counts."""
+    `held_experts_ffn`'s counts.  ``tally``: the program's count of
+    such calls by the grouped product's path."""
     import jax
 
     from ...ops import moe
@@ -247,7 +248,8 @@ def _experts(z, p, x, route, valid):
             weights.reshape(B * S, k), p["experts_gate_up_weight"],
             p["experts_down_weight"], experts_lo=z.experts_held[0],
             valid=None if valid is None else valid.reshape(B * S),
-            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C))
+            pass_rows=z.moe_pass_rows, add_to=x.reshape(B * S, C),
+            tally=tally)
         return y.reshape(B, S, C), stats
 
 
@@ -550,7 +552,8 @@ class MiMoV2Program(DecoderProgram):
                 # padding and rows that want no token are routed
                 # nowhere: only tokens that are kept cost
                 x, stats = _experts(z, p, x, route,
-                                    ctx.live[:, None] if decode else valid)
+                                    ctx.live[:, None] if decode else valid,
+                                    ctx.products)
                 counts = counts.at[z.moe_at.index(i), int(decode)].add(
                     _ops.moe_count_row(stats, z.experts_held[1]))
         with jax.named_scope("serve.head"):

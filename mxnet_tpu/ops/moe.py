@@ -16,6 +16,7 @@ pass through the residual (combine weight 0).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -173,23 +174,49 @@ def softmax_topk_route(x, router_weight, k):
 
 
 def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
-                     pass_rows=None, add_to=None):
+                     pass_rows=None, add_to=None, layer=None, tally=None):
     """Σ over the held experts e of weight · W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x).
 
     x (T, M); chosen/weights (T, k) from the router, expert ids global;
     w13 (n, M, 2F), gate beside up; w2 (n, F, M): experts
-    ``experts_lo .. experts_lo + n``.  ``valid`` (T,) bool masks tokens
-    that are padding; ``add_to`` (T, M) float32 is what the sum is added
-    to (the residual stream; zeros if None).  Returns (y (T, M) float32,
-    stats (n + 1,) int32:
+    ``experts_lo .. experts_lo + n``.  With ``layer`` (an int or a
+    traced scalar) the two are stacks by layer, ``(L, n, M, 2F)`` and
+    ``(L, n, F, M)``, of which that layer's experts are meant.
+    ``valid`` (T,) bool masks tokens that are padding; ``add_to`` (T, M)
+    float32 is what the sum is added to (the residual stream; zeros if
+    None).  Returns (y (T, M) float32, stats (n + 1,) int32:
     assignments per held expert, then the rows the grouped product was
     given, padding included).  No assignment is dropped: the pairs are
-    worked off in passes of ``pass_rows`` rows until none is left."""
-    T, _ = x.shape
+    worked off in passes of ``pass_rows`` rows until none is left.
+
+    The two grouped products of a pass go by one of two paths, chosen on
+    what the call can see (`_fits`), and ``tally`` (a
+    ``collections.Counter`` or None) is told at trace time which, once a
+    call: ``"kernel"`` (a TPU: `_grouped_kernel_call`, which reads a hit
+    expert's weights once, where they lie in the stack, and works only
+    the row tiles that hold a pair) or ``"plain"`` (``lax.ragged_dot``
+    over the layer's experts: the oracle the kernel is tested against,
+    tests/test_moe_grouped.py)."""
+    T, M = x.shape
     k = chosen.shape[1]
-    n, _, F2 = w13.shape
+    n, _, F2 = w13.shape[-3:]
     F = F2 // 2
     P = int(pass_rows or share_pass_rows(T, k, n))
+    kernel = _on_tpu() and _fits(P, M, F, n, w13.dtype)
+    if tally is not None:
+        tally["kernel" if kernel else "plain"] += 1
+    if kernel:
+        # the stacks as they lie, a layer's experts from ``layer * n``
+        # (a reshape of leading axes moves nothing)
+        dot = _grouped_kernel_call
+        w13, w2 = (w.reshape((-1,) + w.shape[-2:]) for w in (w13, w2))
+        first = 0 if layer is None else layer * n
+    else:
+        dot = _grouped_plain
+        if layer is not None:
+            w13, w2 = (jax.lax.dynamic_index_in_dim(w, layer, 0,
+                                                    keepdims=False)
+                       for w in (w13, w2))
     local = chosen - experts_lo
     here = (local >= 0) & (local < n)
     if valid is not None:
@@ -209,13 +236,14 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
         idx = jax.lax.dynamic_slice(order, (base,), (P,))
         live = base + jnp.arange(P, dtype=jnp.int32) < total
         tok = idx // k
-        sizes = jnp.clip(ends - base, 0, P) - jnp.clip(starts - base, 0, P)
-        h = jax.lax.ragged_dot(jnp.take(xs_all, tok, axis=0), w13, sizes,
-                               preferred_element_type=jnp.float32)
+        lo, hi = jnp.clip(starts - base, 0, P), jnp.clip(ends - base, 0, P)
+        groups = _walk(lo, hi, first, P) if kernel else hi - lo
+        h = dot(jnp.take(xs_all, tok, axis=0), w13, groups)
         h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(w2.dtype)
-        o = jax.lax.ragged_dot(h, w2, sizes,
-                               preferred_element_type=jnp.float32)
-        # rows past the pairs hold whatever the product left there
+        o = dot(h, w2, groups)
+        # rows past the pairs hold whatever the product left there, or
+        # (the kernel, which visits no tile past them) whatever the
+        # buffer held: a select keeps them out, a product would not
         o = jnp.where(live[:, None], o * flat_w[idx][:, None], 0.0)
         return y.at[jnp.where(live, tok, T)].add(o, mode="drop")
 
@@ -224,6 +252,167 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
         0, passes, one_pass,
         jnp.zeros(x.shape, jnp.float32) if add_to is None else add_to)
     return y, jnp.concatenate([counts, (passes * P)[None]])
+
+
+# -- the grouped product as one kernel -----------------------------------------
+#
+# ``x`` (P, K) holds a pass's rows sorted by expert: group g's rows are
+# ``lo[g] .. hi[g]``, and what lies past the last group is padding.
+# ``w`` (G, K, N) is the experts' stack as it lies, a layer after
+# another; group g's matrix is ``w[first + g]``.  The kernel walks the
+# pairs (row tile, group) in which the group has rows, in order, the
+# tiles of N outermost: a grid step takes the tile's rows (``tm`` of
+# them, all K wide), the group's block of ``tn`` columns (all K deep),
+# and stores the product's rows that are the group's into the output
+# tile, which stays where it is while the groups that share it pass.
+# Block indices follow the walk, so
+#
+# - a weight block is copied in when the group changes and not
+#   otherwise: each hit expert's weights are read once a pass, an
+#   expert no pair chose is not read, and no slice of a stack is made;
+# - a row tile at or past the last pair is neither copied in nor
+#   multiplied, and the output's rows there are never written: they
+#   hold what the buffer held;
+# - the grid ends with the walk (its second bound is the pass's own
+#   count of visits, a scalar operand; the walk's arrays are sized for
+#   the most a pass can need, the row tiles + n - 1): a step that would
+#   do nothing would also keep the next block's copy from starting
+#   behind the step before it, which is where a call with few visits a
+#   column tile spends its time (PERF.md, PR 50).
+#
+# K is not cut: the sums over it are one product's, float32 inside the
+# MXU's accumulation, and a group that spans row tiles finds its block
+# where the tile before left it.
+
+_LANE = 128
+# rows a tile: what one pass of the MXU's 128 x 128 takes; a larger
+# tile multiplies more rows of other groups for each pair it visits
+_ROWS = 128
+# the most a weight block (all K deep, ``tn`` columns) may take.  Two are
+# in flight; a call's first has nothing to hide behind, which a decode
+# step pays twice a layer, and the rows are read again for every column
+# tile, which a prefill pass pays: at 16 MB the cells' decode passes
+# take 2-4 % longer and their prefill passes 1-2 % less (PERF.md, PR 50)
+_WEIGHT_BLOCK = 8 << 20
+_VMEM_DEFAULT = 14 << 20
+
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def _row_tile(P, n):
+    """The rows of a grid step: `_ROWS` (a shorter buffer whole), twice
+    as many where the buffer holds that many for each of the ``n``
+    experts (a prefill pass of few large groups: the MXU keeps a weight
+    tile for 256 rows, and a visit's rows of other groups are few beside
+    the group's own; measured on the chip, PERF.md, PR 50)."""
+    return min(P, 2 * _ROWS if P >= 2 * _ROWS * n else _ROWS)
+
+
+def _tiles(P, K, N, n, itemsize):
+    """``(tm, tn)``: the rows and the columns of a grid step, from the
+    shapes: `_row_tile`, and the widest lane-aligned divisor of N whose
+    block (all K deep) fits `_WEIGHT_BLOCK`; N whole where it has no
+    lane-aligned divisor (interpret-mode shapes)."""
+    wide = [d for d in range(N // _LANE * _LANE, 0, -_LANE)
+            if N % d == 0 and K * d * itemsize <= _WEIGHT_BLOCK]
+    return _row_tile(P, n), (wide or [N])[0]
+
+
+def _fits(P, M, F, n, dtype):
+    """Whether both products of a pass (``(P, M) x (M, 2F)`` and ``(P, F)
+    x (F, M)``) are whole tiles for the kernel: lane-aligned widths, row
+    tiles of whole packed sublanes that divide the buffer, and a weight
+    block of 128 columns inside `_WEIGHT_BLOCK`."""
+    item = jnp.dtype(dtype).itemsize
+    tm = _row_tile(P, n)
+    return item in (2, 4) and M % _LANE == 0 and F % _LANE == 0 \
+        and P % tm == 0 and tm % (32 // item) == 0 \
+        and max(M, F) * _LANE * item <= _WEIGHT_BLOCK
+
+
+def _walk(lo, hi, first, P):
+    """The kernel's scalar operands for a pass whose group g holds rows
+    ``lo[g] .. hi[g]`` (n,) int32 of a buffer of ``P`` rows: ``(first (1,),
+    visits (1,), group (V,), tile (V,), lo, hi)``, V = the row tiles +
+    n - 1 at the most.  Visit v < ``visits`` works ``tile[v]`` for
+    ``group[v]``: each group's tiles in order, a group after another, a
+    group with no row left out."""
+    n = lo.shape[0]
+    tm = _row_tile(P, n)
+    V = P // tm + n - 1
+    tile0 = lo // tm
+    tiles = jnp.where(hi > lo, (hi - 1) // tm - tile0 + 1, 0)
+    upto = jnp.cumsum(tiles)
+    v = jnp.arange(V, dtype=jnp.int32)
+    group = jnp.minimum(
+        jnp.sum(v[:, None] >= upto[None, :], axis=1, dtype=jnp.int32), n - 1)
+    tile = jnp.take(tile0, group) + v - jnp.take(upto - tiles, group)
+    return (jnp.asarray(first, jnp.int32).reshape(1), upto[-1:], group,
+            tile, lo, hi)
+
+
+def _grouped_plain(x, w, sizes):
+    """``x`` (P, K) times ``w[g]`` (K, N) for the ``sizes[g]`` rows of
+    each group g in turn, float32: what the kernel is held to."""
+    return jax.lax.ragged_dot(x, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_kernel(first_ref, visits_ref, group_ref, tile_ref, lo_ref,
+                    hi_ref, x_ref, w_ref, o_ref):
+    """One (column tile, visit): the tile's rows times the group's block,
+    the group's rows of it stored."""
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(1)
+    g, tm = group_ref[v], o_ref.shape[0]
+    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    mine = (row >= lo_ref[g]) & (row < hi_ref[g])
+    o_ref[...] = jnp.where(
+        mine, jnp.dot(x_ref[...], w_ref[...],
+                      preferred_element_type=jnp.float32), o_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _grouped_kernel_call(x, w, walk, interpret=False):
+    """``x`` (P, K) times ``w[first + g]`` (K, N) for the rows of each
+    group g of ``walk`` (`_walk`), float32 (P, N); rows of no group are
+    not written.  Jitted, so that a program whose layers are unrolled
+    traces and lowers the call once for all of them (1.6 s of a warm
+    start at Granite's 40 calls otherwise: PERF.md, PR 50)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    P, K = x.shape
+    N = w.shape[-1]
+    item = jnp.dtype(w.dtype).itemsize
+    tm, tn = _tiles(P, K, N, walk[4].shape[0], item)
+    # every block twice (the pipeline's double buffer) and the product
+    # before its rows are chosen
+    need = 2 * (tm * K * item + K * tn * item + tm * tn * 4) + 2 * tm * tn * 4
+    kw = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        **({} if need <= _VMEM_DEFAULT
+           else {"vmem_limit_bytes": need + need // 4}))}
+    def spec(block, index):
+        """A block at ``index(column tile, the visit's row tile, the
+        visit's place in the stack)``."""
+        return pl.BlockSpec(
+            block, lambda j, v, first, visits, group, tile, lo, hi: index(
+                j, tile[v], first[0] + group[v]))
+
+    return pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(N // tn, walk[1][0]),
+            in_specs=[spec((tm, K), lambda j, t, g: (t, 0)),
+                      spec((None, K, tn), lambda j, t, g: (g, 0, j))],
+            out_specs=spec((tm, tn), lambda j, t, g: (t, j))),
+        out_shape=jax.ShapeDtypeStruct((P, N), jnp.float32),
+        name="moe_grouped", interpret=interpret, **kw,
+    )(*walk, x, w)
 
 
 def swiglu_ffn(x, gate, up, down):

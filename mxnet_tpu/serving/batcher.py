@@ -288,6 +288,8 @@ class ContinuousBatcher:
                     "decode_attn_window_read_pct"),
                 prefill_attn_kernel_share=timings.get(
                     "prefill_attn_kernel_share"),
+                moe_grouped_kernel_share=timings.get(
+                    "moe_grouped_kernel_share"),
                 **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step

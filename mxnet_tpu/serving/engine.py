@@ -257,7 +257,9 @@ class ServingEngine:
       ``block_attends`` (likewise a prefill block's attention calls
       inside itself, by path: ``"kernel"`` through
       `ops/pallas_attention.py`), ``state_updates`` (likewise the rows
-      whose state `ops/ssm.py` moved on, by path) and
+      whose state `ops/ssm.py` moved on, by path), ``grouped_products``
+      (likewise the held experts' calls, by the path `ops/moe.py` took
+      for their grouped products) and
       ``signature`` (what a reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
@@ -745,6 +747,17 @@ class ServingEngine:
         if attends:
             timings["prefill_attn_kernel_share"] = \
                 attends["kernel"] / sum(attends.values())
+        # of the held experts' calls in the group's two programs, the
+        # share whose grouped products went through the kernel of
+        # ops/moe.py (a hit expert's weights read once, where they lie)
+        # and not through lax.ragged_dot
+        products = collections.Counter()
+        for s in {1, int(S)}:
+            products.update(getattr(self._program, "grouped_products",
+                                    {}).get(s, {}))
+        if products:
+            timings["moe_grouped_kernel_share"] = \
+                products["kernel"] / sum(products.values())
         counters = getattr(self._program, "counters", None)
         if counters is not None:
             # what the family counted in its donated carry: one small

@@ -760,10 +760,11 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
     import os
 
     from mxnet_tpu.gluon.model_zoo import cohere2_moe
-    from mxnet_tpu.ops import cache_attention, pallas_attention as pa
+    from mxnet_tpu.ops import cache_attention, moe, pallas_attention as pa
 
     monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
     monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
@@ -797,6 +798,9 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
     alias = text[text.index("input_output_alias="):].split("\n")[0]
     for i in range(6):
         assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
+    # the four layers' held experts by the grouped product's kernel
+    assert dict(program.grouped_products[S]) == {"kernel": 4}
+    assert "ragged" not in text
     ring_layer = B * z.kv_heads * z.head_dim * z.window * 2
     mem = compiled.memory_analysis()
     if S == 1:
@@ -948,6 +952,78 @@ def test_the_mamba2_kernels_compile_for_a_v5e(one_chip, kernel):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _grouped_calls(jaxpr):
+    """(column tiles, the most visits the walk's arrays hold, groups) of
+    each grouped product's Pallas call in ``jaxpr`` and in what it
+    calls, in order: the grid's first bound, and the shapes of the
+    scalar operands behind its second (the pass's own count of
+    visits)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and "moe_grouped" in str(
+                eqn.params.get("name_and_src_info", "")) + str(
+                    eqn.params.get("name", "")):
+            mapping = eqn.params["grid_mapping"]
+            assert mapping.num_dynamic_grid_bounds == 1
+            first, visits, group, tile, lo, hi = (
+                v.aval.shape for v in eqn.invars[1:7])
+            assert first == visits == (1,) and group == tile and lo == hi
+            out.append((mapping.grid[0], group[0], lo[0]))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out.extend(_grouped_calls(sub))
+    return out
+
+
+# (M, F, n held, layers in the stack, a decode step's buffer, a prefill
+# pass's): the five expert families' cells (benchmark/configs) and the
+# smallest the smoke serves
+GROUPED = {
+    "mimo": (4096, 2048, 16, 1, 256, 1024),
+    "keye": (2048, 768, 16, 6, 128, 4096),
+    "kimi": (7168, 2048, 12, 4, 64, 4096),
+    "cmda": (4096, 4096, 8, 1, 64, 4096),
+    "granite": (4096, 768, 18, 10, 256, 1024),
+    # `chip_smoke.py`'s small families: 8 rows of 2 of 4 held experts
+    "smoke": (256, 128, 4, 2, 16, 1024),
+}
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("family", list(GROUPED))
+def test_the_grouped_kernel_compiles_for_a_v5e(one_chip, family, phase):
+    """A pass's two grouped products (`ops/moe.py`) at each expert
+    family's widths and buffers compile for a described v5e through
+    Mosaic, the layer a traced scalar into the stack as it lies: no
+    temporary but the first product's result and the activation, so no
+    slice of a stack."""
+    from mxnet_tpu.ops import moe
+
+    M, F, n, L, *rows = GROUPED[family]
+    P = rows[phase == "prefill"]
+    assert moe._fits(P, M, F, n, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pass_(x, w13, w2, lo, hi, l):
+        walk = moe._walk(lo, hi, l * n, P)
+        h = moe._grouped_kernel_call(x, w13.reshape(L * n, M, 2 * F), walk)
+        h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(w2.dtype)
+        return moe._grouped_kernel_call(h, w2.reshape(L * n, F, M), walk)
+
+    compiled = jax.jit(pass_).lower(
+        sds((P, M)), sds((L, n, M, 2 * F)), sds((L, n, F, M)),
+        sds((n,), jnp.int32), sds((n,), jnp.int32),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "ragged" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= P * (2 * F * 4 + F * 2) + (1 << 20)
+
+
 def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
         one_chip, monkeypatch):
     """Granite 4.0-H's decode step
@@ -966,11 +1042,14 @@ def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
     import os
 
     from mxnet_tpu.gluon.model_zoo import granite_hybrid
-    from mxnet_tpu.ops import cache_attention, pallas_attention as pa, ssm
+    from mxnet_tpu.ops import (cache_attention, moe, pallas_attention as pa,
+                               ssm)
+    from mxnet_tpu.serving.engine import whole_layer_ops
 
     monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
     monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
@@ -992,9 +1071,10 @@ def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
         ).input_formats[0][0] for c in cache[:4]]
     weights = tuple(sds(shape) for _, shape in z.leaves())
     assert sum(int(np.prod(w.shape)) for w in weights) == 2_955_758_208
-    compiled = jax.jit(program.step, donate_argnums=(1,)).lower(
+    traced = jax.jit(program.step, donate_argnums=(1,)).trace(
         weights, cache, sds((B,), jnp.int32), sds((B,), jnp.int32),
-        sds((B, 1), jnp.int32), sds((B,), jnp.bool_)).compile()
+        sds((B, 1), jnp.int32), sds((B,), jnp.bool_))
+    compiled = traced.lower().compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     alias = text[text.index("input_output_alias="):].split("\n")[0]
@@ -1003,9 +1083,74 @@ def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 5.29e9
     assert mem.temp_size_in_bytes < 128 << 20
+    # the held experts (PR 50): every layer's call by the kernel, two
+    # products each over the layer's own 18 groups (a buffer of 256 rows
+    # in tiles of 128: 2 + 17 visits at the most, of which the grid runs
+    # a pass's own count; not 10 x 18 groups), no
+    # ragged_dot left, and neither a stack of experts nor a layer's
+    # slice of one (113 MB the smaller) copied or sliced out
+    assert dict(program.grouped_products[1]) == {"kernel": 10}
+    n = z.experts_held[1]
+    calls = _grouped_calls(traced.jaxpr.jaxpr)
+    assert calls == [(2, 2 + n - 1, n), (1, 2 + n - 1, n)] * 10, calls
+    assert "ragged" not in text
+    assert whole_layer_ops(text, n * z.expert_hidden * z.units * 2) == []
     assert dict(program.state_updates[1]) == {
         "kernel": 9 * B, "kernel_live": 9 * B}
     assert dict(program.cache_writes[1]) == {"kernel": 2 * B,
                                              "kernel_live": 2 * B}
     assert dict(program.cache_reads[1]) == {("kernel", 768, 128): 1}
     assert compiled.out_info[1].shape == (B, kwargs["vocab_size"])
+
+
+def test_mimos_decode_step_compiles_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """MiMo-V2's decode step (`gluon/model_zoo/mimo_v2.py`, the sizes of
+    benchmark/configs/mimo-v2.5-ep16.json, the cell's 64 rows), whose
+    expert layers hold their own leaves: the six layers' held experts go
+    through the grouped product's kernel with no layer handed (a buffer
+    of 256 rows: 2 + 15 visits at the most), the program holds no
+    ``ragged_dot`` and neither copies nor slices a layer's experts (268
+    MB the smaller leaf), and the four stacks are written into their
+    donated arguments."""
+    import json
+    import os
+
+    from mxnet_tpu.gluon.model_zoo import mimo_v2
+    from mxnet_tpu.ops import cache_attention, moe, pallas_attention as pa
+    from mxnet_tpu.serving.engine import whole_layer_ops
+
+    monkeypatch.setattr(cache_write, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cache_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        kwargs = json.load(f)["program"]["kwargs"]
+    net = mimo_v2.MiMoV2Model(**kwargs)     # nothing allocated
+    z, B = net._sizes, 64
+    program = mimo_v2.MiMoV2Program(net, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stacks, counters = program.cache_shapes(B)
+    cache = tuple(sds(s, d or jnp.bfloat16) for s, d in stacks + counters)
+    program._layouts = [jax.jit(lambda x: x).lower(c).compile(
+        ).input_formats[0][0] for c in cache[:len(stacks)]]
+    weights = tuple(sds(getattr(net, name).shape) for name in net._names)
+    traced = jax.jit(program.step, donate_argnums=(1,)).trace(
+        weights, cache, sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, 1), jnp.int32), sds((B,), jnp.bool_))
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    for i in range(len(cache)):
+        assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
+    n, F, M = z.experts_held[1], kwargs["expert_hidden"], kwargs["units"]
+    assert dict(program.grouped_products[1]) == {"kernel": 6}
+    assert _grouped_calls(traced.jaxpr.jaxpr) \
+        == [(4, 2 + n - 1, n), (2, 2 + n - 1, n)] * 6
+    assert "ragged" not in text
+    assert whole_layer_ops(text, n * F * M * 2) == []

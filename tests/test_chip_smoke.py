@@ -215,6 +215,31 @@ def test_ssd_and_serve_granite_phases():
     assert out["retraces"] == 0 and out["programs"] == 2
 
 
+def test_moe_grouped_phase(monkeypatch):
+    """The grouped product's phase, interpreted at tiny tiles: both
+    products of a pass equal ``lax.ragged_dot``'s over layer 1 of the
+    stack, a group over three tiles, empty groups and an empty tail
+    among the cases; no time is taken off the chip.  The chip's cases
+    are the five expert families' widths and buffers, each taken by the
+    kernel."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    full = chip_smoke.moe_grouped_full()
+    assert [c[0] for c in full.cases] == [
+        f"{f}.{p}" for f in ("granite", "mimo", "keye", "kimi", "cmda")
+        for p in ("decode", "prefill")]
+    for _, P, M, F, n, sizes in full.cases:
+        assert moe._fits(P, M, F, n, jnp.bfloat16)
+        assert len(sizes) == n and 0 < sum(sizes) <= P
+    monkeypatch.setattr(moe, "_ROWS", 16)
+    out = chip_smoke.phase_moe_grouped(chip_smoke.GroupedSize(cases=(
+        ("a", 64, 128, 128, 5, (0, 40, 0, 3, 7)),
+        ("b", 32, 256, 128, 3, (32, 0, 0))), reps=2), "cpu")
+    assert set(out) == {"a", "b"} and set(out["a"]) == {"err"}
+
+
 def test_serve_cmda_phase():
     """The sixth family's phase at a tiny size: the engine's tuple is the
     parameters' own buffers, the four stacks (two rings among them) stay
