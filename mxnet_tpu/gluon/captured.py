@@ -1032,18 +1032,17 @@ class CapturedStep:
         (arguments + outputs + XLA temp allocations, donation aliases
         counted once), or None when the compiler doesn't expose it."""
         if self._peak_bytes is _SENTINEL_UNSET:
+            from .. import telemetry
+
             compiled = self._compiled_for_stats()
             if compiled is None:
                 return None
-            try:
-                ma = compiled.memory_analysis()
-                total = (int(ma.temp_size_in_bytes)
-                         + int(ma.argument_size_in_bytes)
-                         + int(ma.output_size_in_bytes)
-                         - int(getattr(ma, "alias_size_in_bytes", 0)))
-                self._peak_bytes = max(total, 0)
-            except Exception:
-                self._peak_bytes = None
+            needs = telemetry.memory_of_compiled(compiled)
+            self._peak_bytes = None if needs is None else max(
+                needs["temp_size_in_bytes"]
+                + needs["argument_size_in_bytes"]
+                + needs["output_size_in_bytes"]
+                - needs["alias_size_in_bytes"], 0)
         return self._peak_bytes
 
     def pipeline_stats(self):

@@ -228,7 +228,10 @@ class ContinuousBatcher:
                 return
             span.set(B=timings["bucket"][0], S=timings["bucket"][1],
                      steps=max(len(o) for o in outs),
-                     generation=timings["generation"])
+                     generation=timings["generation"],
+                     **{k: timings[k] for k in ("cache_bytes_reserved",
+                                                "cache_bytes_written")
+                        if k in timings})
             self.groups_served += 1
             self.requests_served += len(group)
             with scope("serve.finish") as fin:
@@ -236,8 +239,22 @@ class ContinuousBatcher:
                                                collect_us=collect_us),
                              t_batch, fin.t0)
 
+    #: the engine's fields that every request's ``request`` record
+    #: carries where the engine reports them (its counts of what the
+    #: group's programs did, and the memory ledger); the family
+    #: counters stay in the future's record
+    _ENGINE_FIELDS = (
+        "decode_host_us_per_step", "decode_steps_fed_on_device",
+        "decode_readback_bytes_per_step", "decode_row_steps",
+        "decode_row_steps_live", "decode_cache_write_kernel_share",
+        "decode_cache_write_live_share", "decode_attn_kernel_share",
+        "decode_attn_window_read_pct", "prefill_attn_kernel_share",
+        "moe_grouped_kernel_share") + telemetry.MEMORY_FIELDS
+
     def _finish(self, group, outs, timings, t_batch, t_finish):
         t_done = time.time()
+        passed_on = {k: timings[k] for k in self._ENGINE_FIELDS
+                     if k in timings}
         for r, toks in zip(group, outs):
             queue_us = (t_batch - r.t_enqueue) * 1e6
             rec = dict(timings)
@@ -269,28 +286,7 @@ class ContinuousBatcher:
                 generation=timings["generation"],
                 deadline_exceeded=False, replica_id=self.replica_id,
                 collect_us=timings["collect_us"],
-                decode_host_us_per_step=timings.get(
-                    "decode_host_us_per_step"),
-                decode_steps_fed_on_device=timings.get(
-                    "decode_steps_fed_on_device"),
-                decode_readback_bytes_per_step=timings.get(
-                    "decode_readback_bytes_per_step"),
-                decode_row_steps=timings.get("decode_row_steps"),
-                decode_row_steps_live=timings.get(
-                    "decode_row_steps_live"),
-                decode_cache_write_kernel_share=timings.get(
-                    "decode_cache_write_kernel_share"),
-                decode_cache_write_live_share=timings.get(
-                    "decode_cache_write_live_share"),
-                decode_attn_kernel_share=timings.get(
-                    "decode_attn_kernel_share"),
-                decode_attn_window_read_pct=timings.get(
-                    "decode_attn_window_read_pct"),
-                prefill_attn_kernel_share=timings.get(
-                    "prefill_attn_kernel_share"),
-                moe_grouped_kernel_share=timings.get(
-                    "moe_grouped_kernel_share"),
-                **r.trace.to_fields())
+                **passed_on, **r.trace.to_fields())
             # from the group's last token to this request's answer
             # handed over: one clock read a request, none a step
             rec["finish_us"] = (time.perf_counter() - t_finish) * 1e6

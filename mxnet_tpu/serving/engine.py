@@ -65,6 +65,7 @@ import os
 import re
 import threading
 
+from .. import telemetry
 from ..base import MXNetError
 from ..obs.spans import wall
 from ..profiler import scope
@@ -225,6 +226,53 @@ def _window_read_pct(reads, lens, steps, left=None):
     return 100.0 * asked / whole
 
 
+# -- the memory ledger: what the chip holds, by name ---------------------------
+
+# the runtime's ``memory_stats()`` under the ledger's names
+_MEMORY_STATS = (("memory_in_use_bytes", "bytes_in_use"),
+                 ("memory_peak_bytes", "peak_bytes_in_use"),
+                 ("memory_limit_bytes", "bytes_limit"),
+                 ("memory_largest_free_block_bytes",
+                  "largest_free_block_bytes"),
+                 ("memory_num_allocs", "num_allocs"))
+
+
+def _device_memory(device):
+    """What the runtime says ``device`` holds at this instant, under
+    the ledger's names; ``{}`` on a backend that keeps no such count
+    (the CPU)."""
+    stats = device.memory_stats()
+    if not stats:
+        return {}
+    return {name: int(stats[key]) for name, key in _MEMORY_STATS
+            if key in stats}
+
+
+def _in_use_and_peak(now, suffix=""):
+    """A `_device_memory` reading's two counts as a span's attributes,
+    ``in_use`` and ``peak``; none off a chip."""
+    return {k + suffix: now[f"memory_{k}_bytes"] for k in ("in_use", "peak")
+            if f"memory_{k}_bytes" in now}
+
+
+# the gauges a group's end sets, and the ledger's field each shows
+_GROUP_GAUGES = (("memory.cache_bytes_reserved", "cache_bytes_reserved"),
+                 ("memory.cache_bytes_written", "cache_bytes_written"),
+                 ("memory.in_use_bytes", "memory_in_use_bytes"),
+                 ("memory.unaccounted_bytes", "memory_unaccounted_bytes"))
+
+
+def _held(weights):
+    """By device of this process: the bytes of ``weights`` that lie on
+    it, a buffer that several leaves share counted once."""
+    at = {}
+    for w in weights:
+        for shard in w.addressable_shards:
+            at.setdefault(shard.device, {})[
+                shard.data.unsafe_buffer_pointer()] = shard.data.nbytes
+    return {device: sum(each.values()) for device, each in at.items()}
+
+
 class ServingEngine:
     """Bucketed AOT prefill/decode over a model's decoder program.
 
@@ -274,12 +322,17 @@ class ServingEngine:
 
     def __init__(self, model, batch_buckets=None, prefill_floor=8,
                  mesh=None, tp_axis="tp", dtype=None):
+        import jax
+
         from .. import engine
 
         engine.watch_compiles()
         # the decoder program's ``weights()`` stacks and re-lays the
-        # model's leaves: most of this span
-        with scope("startup.engine"):
+        # model's leaves: most of this span, and of what the chip holds
+        # more at its end than at its start
+        with scope("startup.engine") as span:
+            before = {d: _device_memory(d) for d in jax.local_devices()} \
+                if telemetry.enabled() else {}
             self._mesh = mesh
             self._tp_axis = tp_axis
             self._dtype = dtype
@@ -295,7 +348,18 @@ class ServingEngine:
             self._weights = tuple(self._program.weights())
             self._programs = {}
             self._cache_avals = {}      # by batch bucket: `_compile`
+            self._cache_ledgers = {}    # likewise: `_account_cache`
+            self.program_memory = {}    # by (B, S): `_account_program`
             self._step = self._make_step()
+            self._ledger = self._account_weights()
+            if telemetry.enabled():
+                # what the engine's stacked and re-laid weights cost
+                # beside the model's own leaves, and whether the
+                # process's peak was made before the engine was
+                span.set(weights_bytes=self._ledger["weights_bytes"],
+                         **_in_use_and_peak(before.get(self._device, {}),
+                                            "_before"),
+                         **_in_use_and_peak(_device_memory(self._device)))
 
     def _program_of(self, model):
         make = getattr(model, "decoder_program", None)
@@ -335,7 +399,7 @@ class ServingEngine:
         instead of serving corrupt weights — end-to-end coverage of
         the restore path itself, past the per-shard CRCs."""
         if expect_fp is not None:
-            from .. import integrity, telemetry
+            from .. import integrity
 
             got = integrity.fingerprint_host(state)
             if got != int(expect_fp):
@@ -357,8 +421,6 @@ class ServingEngine:
         self._swap(from_state(state), step=step)
 
     def _swap(self, weights, step=None):
-        from .. import telemetry
-
         import jax
 
         weights = tuple(weights)
@@ -382,7 +444,105 @@ class ServingEngine:
             self._weights = new_w
             self.generation += 1
             gen = self.generation
+        self._ledger = self._account_weights()
         telemetry.event("serving_reload", generation=gen, step=step)
+
+    # -- the memory ledger -----------------------------------------------------
+
+    def _account_weights(self):
+        """The weights' lines of the ledger, at ``__init__`` and at a
+        swap: their bytes on a device (a buffer that two leaves share
+        once; under a mesh the largest device's sum) and how many
+        leaves.  The ledger reads the memory of the first device from
+        then on, and holds what it says against that device's own
+        sum.  Nothing with telemetry off."""
+        if not telemetry.enabled():
+            self._device = None
+            return {}
+        held = _held(self._weights)
+        device = min(held, key=lambda d: d.id)
+        self._weights_here, self._device = held[device], device
+        ledger = {"weights_bytes": max(held.values()),
+                  "weights_leaves": len(self._weights)}
+        telemetry.gauge_set("memory.weights_bytes", ledger["weights_bytes"])
+        return ledger
+
+    def _account_cache(self, B, avals):
+        """What a cache of batch bucket B reserves on a device, by kind
+        (the family's ``cache_shapes(B)`` says which leaf is a stack, a
+        state or a counter; ``avals`` their types and placement), and
+        what `_account_group` needs to say how much of it a group
+        wrote: a stack's bytes a (row, position) and its last axis,
+        the states' bytes a row, the counters'.  None for a family that
+        states its cache in another form."""
+        import numpy as np
+
+        shapes = getattr(self._program, "cache_shapes", None)
+        if shapes is None or not telemetry.enabled():
+            return None
+        stacks, *states, counters = shapes(B)
+        kinds = ["stack"] * len(stacks) + ["state"] * sum(
+            len(s) for s in states) + ["counter"] * len(counters)
+        if len(kinds) != len(avals):
+            return None
+        nbytes = [int(np.prod(a.sharding.shard_shape(a.shape)))
+                  * np.dtype(a.dtype).itemsize for a in avals]
+        by_kind = {k: sum(n for n, kind in zip(nbytes, kinds) if kind == k)
+                   for k in ("stack", "state", "counter")}
+        fields = {"cache_bytes_reserved": sum(nbytes),
+                  "cache_stack_bytes": by_kind["stack"],
+                  "cache_state_bytes": by_kind["state"],
+                  "cache_counter_bytes": by_kind["counter"]}
+        # a ring's last axis is its window: a row that wrapped filled it
+        positions = [(n // (B * a.shape[-1]), a.shape[-1])
+                     for n, a in zip(nbytes, avals[:len(stacks)])]
+        return fields, positions, by_kind["state"] // B, by_kind["counter"]
+
+    def _account_program(self, span, compiled, program, B, S):
+        """What program (B, S) needs beside its arguments, as the
+        compiler reckons it, and what the chip holds now that it is
+        compiled: on its ``serve.compile`` span, kept by (B, S) and as
+        one ``program_memory`` event."""
+        needs = telemetry.memory_of_compiled(compiled)
+        if needs is None or self._device is None:
+            return
+        now = _in_use_and_peak(_device_memory(self._device))
+        self.program_memory[(B, S)] = needs
+        span.set(**needs, **now)
+        telemetry.event("program_memory", program=program, B=B, S=S,
+                        **needs, **now)
+
+    def _account_group(self, B, held):
+        """The ledger's fields of one group, read once its last
+        readback is in and the device has nothing of it in flight:
+        ``held`` (n,) the positions each real row wrote (its prompt and
+        the decode steps it was live in).  Host arithmetic over shapes
+        and one ``memory_stats()``.  ``{}`` for an engine made with
+        telemetry off."""
+        import numpy as np
+
+        if self._device is None:
+            return {}
+        out = dict(self._ledger)
+        reserved = self._cache_ledgers.get(B)
+        if reserved is not None:
+            fields, positions, state_row, counters = reserved
+            out.update(fields)
+            out["cache_bytes_written"] = sum(
+                each * int(np.minimum(held, W).sum())
+                for each, W in positions) + state_row * len(held) + counters
+        now = _device_memory(self._device)
+        out.update(now)
+        if reserved is not None and "memory_in_use_bytes" in now:
+            # in use less what the ledger names of it, this device's
+            # weights and this group's cache: signed, so that a buffer
+            # counted twice shows and is not clamped away
+            out["memory_unaccounted_bytes"] = now["memory_in_use_bytes"] \
+                - self._weights_here - out["cache_bytes_reserved"]
+        for gauge, field in _GROUP_GAUGES:
+            if field in out:
+                telemetry.gauge_set(gauge, out[field])
+        return out
 
     # -- cache -----------------------------------------------------------------
 
@@ -469,7 +629,7 @@ class ServingEngine:
         import jax
 
         program = "decode" if S == 1 else "prefill"
-        with scope("serve.compile", B=B, S=S, program=program):
+        with scope("serve.compile", B=B, S=S, program=program) as span:
             w_avals = tuple(self._aval(x) for x in self._weights)
             # the cache's shapes are read off an allocated one, once a
             # batch bucket: a cache can be most of the chip's memory,
@@ -480,6 +640,7 @@ class ServingEngine:
             if c_avals is None:
                 c_avals = self._cache_avals[B] = tuple(
                     self._aval(c) for c in self.init_cache(B))
+                self._cache_ledgers[B] = self._account_cache(B, c_avals)
             jfn = jax.jit(self._step[program], donate_argnums=(1,))
             # pos, last, toks; the decode program also takes ``left``
             ints = [(B,), (B,), (B, S)] + [(B,)] * (program == "decode")
@@ -492,6 +653,8 @@ class ServingEngine:
             # batcher's delay and splits a closed loop's round for good
             # (PERF.md, PR 40).  Made here, beside seconds of compile.
             gc.collect()
+            if telemetry.enabled():
+                self._account_program(span, compiled, program, B, S)
         with _LOCK:
             _COMPILE_COUNT += 1
         self._programs[(B, S)] = compiled
@@ -763,4 +926,7 @@ class ServingEngine:
             # what the family counted in its donated carry: one small
             # readback a group, after the last step's logits are in
             timings.update(counters(cache))
+        if telemetry.enabled():
+            timings.update(self._account_group(
+                B, lens[:n] + np.minimum(wanted[:n], dispatched)))
         return [out[i, :per_req[i]].copy() for i in range(n)], timings

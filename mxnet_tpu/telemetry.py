@@ -665,6 +665,18 @@ def event(kind, /, **fields):
     _emit(rec)
 
 
+#: the serving engine's memory ledger as a request record carries it
+#: (docs/observability.md, "Device memory"): optional, non-negative
+#: ints all but ``memory_unaccounted_bytes``, which is signed (a
+#: negative reading says the ledger counts a buffer twice)
+MEMORY_FIELDS = (
+    "weights_bytes", "weights_leaves", "cache_bytes_reserved",
+    "cache_stack_bytes", "cache_state_bytes", "cache_counter_bytes",
+    "cache_bytes_written", "memory_in_use_bytes", "memory_peak_bytes",
+    "memory_limit_bytes", "memory_largest_free_block_bytes",
+    "memory_num_allocs", "memory_unaccounted_bytes")
+
+
 def request_record(queue_us, prefill_us, decode_us_per_token, bucket,
                    padded_fraction, new_tokens=None, generation=None,
                    **fields):
@@ -1011,6 +1023,31 @@ def flops_of_compiled(compiled):
         return None
 
 
+#: what `memory_of_compiled` reads off a program's memory analysis
+_PROGRAM_MEMORY = ("argument_size_in_bytes", "output_size_in_bytes",
+                   "temp_size_in_bytes", "alias_size_in_bytes",
+                   "generated_code_size_in_bytes")
+
+
+def memory_of_compiled(compiled):
+    """What a `jax.stages.Compiled` needs on each device, as the
+    compiler reckons it (``compiled.memory_analysis()``): a dict of
+    ints, its arguments, its outputs, the temporaries beside them, the
+    outputs that are arguments' own buffers (donated) and the code
+    itself (the last two 0 where the analysis has no such line), or
+    None where the compiler gives none.  The training step's
+    ``device_peak_bytes`` and the serving programs' ``program_memory``
+    events are made of it."""
+    try:
+        ma = compiled.memory_analysis()
+        needs = {name: int(getattr(ma, name)) for name in _PROGRAM_MEMORY[:3]}
+        needs.update((name, int(getattr(ma, name, 0)))
+                     for name in _PROGRAM_MEMORY[3:])
+    except Exception:
+        return None
+    return needs
+
+
 _COLLECTIVE_RE = None
 
 
@@ -1221,6 +1258,16 @@ def validate_record(rec):
         de = rec.get("deadline_exceeded")
         if de is not None and not isinstance(de, bool):
             fail("deadline_exceeded must be a bool or absent")
+        # the engine's memory ledger: optional fields a reader of any
+        # version may meet or not, so the version stays
+        for key in MEMORY_FIELDS:
+            val = rec.get(key)
+            signed = key == "memory_unaccounted_bytes"
+            if val is not None and (
+                    not isinstance(val, (int, float))
+                    or isinstance(val, bool) or (val < 0 and not signed)):
+                fail(f"{key} must be a "
+                     f"{'' if signed else 'non-negative '}number or absent")
         return rec
     if kind == "event":
         if not isinstance(rec.get("event"), str) or not rec["event"]:

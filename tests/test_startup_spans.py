@@ -383,9 +383,16 @@ def test_a_serving_engine_gives_its_spans_and_compiles_once(fresh):
     assert all(_inside(s, built) for s in telemetry.startup_spans())
     eng.serve_group(PROMPTS, 3)
     compiles = _named("serve.compile")
-    assert [s[4] for s in compiles] == [
+    # beside them each span carries what its program needs on the
+    # device and, on a chip, what that held once it was compiled
+    # (tests/test_serving_memory.py): those, and nothing else
+    needs = set(telemetry._PROGRAM_MEMORY)
+    assert [{k: v for k, v in s[4].items()
+             if k not in needs | {"in_use", "peak"}}
+            for s in compiles] == [
         {"B": 4, "S": 16, "program": "prefill"},
         {"B": 4, "S": 1, "program": "decode"}]
+    assert all(needs <= set(s[4]) for s in compiles)
     (prefill_dispatch,) = _named("serve.prefill.dispatch")
     assert _inside(compiles[0], prefill_dispatch)
     for compiled, program in zip(compiles, ("serve_prefill",

@@ -110,6 +110,8 @@ def report_run(run, records, out):
               + "\n")
     if requests:
         report_requests(requests, out)
+    report_memory([e for e in events if e.get("event") == "program_memory"],
+                  requests, out)
     if steps:
         wall = _mean([s.get("wall_us") for s in steps])
         interval = _mean([s.get("interval_us") for s in steps])
@@ -436,6 +438,93 @@ def report_requests(requests, out):
     if len(gens) > 1:
         out.write(f"    weight generations served: {gens} "
                   f"(hot reload mid-run)\n")
+
+
+def _gb(n):
+    return "-" if n is None else f"{n / 1e9:.3f} GB"
+
+
+def report_memory(events, requests, out, spans=None):
+    """The serving engine's memory ledger (docs/observability.md,
+    "Device memory"): from the ``request`` records the weights, the
+    cache a bucket reserves by kind and the share of it the groups
+    wrote, what the chip held at a group's end and the part of it the
+    ledger does not name; from the ``program_memory`` events what each
+    compiled program needs beside its arguments; and the phase that
+    made the process's peak: the first reading, in order, at which the
+    runtime's high-water mark stood where it ended (``spans``: the
+    process's own ``telemetry.startup_spans()``, whose ``startup.engine``
+    says what was there before the engine)."""
+    held = [r for r in requests if "weights_bytes" in r]
+    if not events and not held:
+        return
+    out.write("  memory:\n")
+    if held:
+        out.write(f"    weights {_gb(held[-1]['weights_bytes'])} in "
+                  f"{held[-1].get('weights_leaves', '?')} leaves\n")
+    by_bucket = {}
+    for r in held:
+        if "cache_bytes_reserved" in r:
+            by_bucket.setdefault(tuple(r.get("bucket", ())), []).append(r)
+    for bucket, recs in sorted(by_bucket.items()):
+        r = recs[-1]
+        # the requests of one group carry the group's own sums, and its
+        # two clock readings tell it from every other
+        groups = {(x.get("prefill_us"), x.get("collect_us")):
+                  x["cache_bytes_written"] for x in recs
+                  if "cache_bytes_written" in x}
+        written = sum(groups.values())
+        used = 100.0 * written / (len(groups) * r["cache_bytes_reserved"]) \
+            if groups else None
+        out.write(f"    cache of bucket {'x'.join(map(str, bucket))}: "
+                  f"{_gb(r['cache_bytes_reserved'])} reserved (stacks "
+                  f"{_gb(r.get('cache_stack_bytes'))}, states "
+                  f"{_gb(r.get('cache_state_bytes'))}, counters "
+                  f"{r.get('cache_counter_bytes', '-')} B), "
+                  f"{_fmt(used, 2)} % of it written over "
+                  f"{len(groups)} groups\n")
+    if events:
+        out.write(f"    {'program':<18}{'temporaries':>14}{'arguments':>14}"
+                  f"{'aliased':>14}{'outputs':>14}{'code':>12}\n")
+        for e in events:
+            name = f"{e.get('program', '?')} {e.get('B')}x{e.get('S')}"
+            out.write(f"    {name:<18}"
+                      f"{_gb(e.get('temp_size_in_bytes')):>14}"
+                      f"{_gb(e.get('argument_size_in_bytes')):>14}"
+                      f"{_gb(e.get('alias_size_in_bytes')):>14}"
+                      f"{_gb(e.get('output_size_in_bytes')):>14}"
+                      f"{_gb(e.get('generated_code_size_in_bytes')):>12}\n")
+    ends = [r for r in held if "memory_in_use_bytes" in r]
+    if ends:
+        r = ends[-1]
+        unnamed = r.get("memory_unaccounted_bytes")
+        share = 100.0 * unnamed / r["memory_in_use_bytes"] \
+            if unnamed is not None and r["memory_in_use_bytes"] else None
+        out.write(f"    at a group's end: in use "
+                  f"{_gb(r['memory_in_use_bytes'])} of "
+                  f"{_gb(r.get('memory_limit_bytes'))}, of it unaccounted "
+                  f"{_gb(unnamed)} ({_fmt(share, 2)} %), largest free "
+                  f"block {_gb(r.get('memory_largest_free_block_bytes'))}\n")
+    # the high-water mark only rises: the phase that made it is the
+    # first reading that shows it where it ended
+    readings = []
+    for name, _t0, _t1, _thread, attrs in spans or ():
+        if name == "startup.engine" and attrs:
+            readings.append(("before the engine", attrs.get("peak_before")))
+            readings.append(("startup.engine", attrs.get("peak")))
+    later = [(e.get("t", 0.0), f"the compile of {e.get('program')} "
+              f"{e.get('B')}x{e.get('S')}", e.get("peak")) for e in events]
+    later += [(r.get("t", 0.0), "a group", r.get("memory_peak_bytes"))
+              for r in ends]
+    readings += [(label, peak) for _t, label, peak in sorted(
+        later, key=lambda x: x[0])]
+    readings = [(label, peak) for label, peak in readings
+                if peak is not None]
+    if readings:
+        top = max(peak for _label, peak in readings)
+        first = next(label for label, peak in readings if peak == top)
+        out.write(f"    peak {_gb(top)}, first read at the end of: "
+                  f"{first}\n")
 
 
 def report_resilience(kinds, out):
