@@ -28,7 +28,9 @@ states ride in the donated cache, routed experts and a shared one in
 every layer), after its two kernels alone against their plain paths at
 the published widths (``ssd``); the held experts' grouped product alone
 (``moe_grouped``: `ops/moe.py`'s kernel against ``lax.ragged_dot`` at
-the five expert families' decode and prefill shapes, both timed); with
+the five expert families' decode and prefill shapes, both timed, and a
+pass's way out into the stream by its walk of the token tiles against
+XLA's scatter-add at the same ten programs' shapes, both timed); with
 four chips, the GPT step under ``shard_model`` fsdp and tp.  Phases, in
 order: device, sync, kernel, train, serve, serve_mimo, serve_keye,
 serve_kimi, serve_ouro, serve_cmda, serve_cmda_full, serve_jamba, ssd,
@@ -211,10 +213,14 @@ def ssd_full():
 class GroupedSize:
     """The grouped product's cases: (name, P rows of the buffer, M, F, n
     experts a layer, the rows of each group in the pass), each over a
-    stack of ``layers`` layers, timed over ``reps`` passes."""
+    stack of ``layers`` layers, timed over ``reps`` passes; and the
+    cases of a pass's way out into the stream: (name, T tokens of the
+    stream, P, M, the pass's live rows, the experts they fall to, the
+    tokens' rows of that length of which some are real)."""
     cases: tuple
     layers: int = 2
     reps: int = 20
+    ways_out: tuple = ()
 
 
 def moe_grouped_full():
@@ -248,6 +254,20 @@ def moe_grouped_full():
         ("cmda.decode", 64, 4096, 4096, 8, spread(4, 2, 8, 3)),
         ("cmda.prefill", 4096, 4096, 4096, 8, (0, 0, 1024, 1024, 1024, 1024,
                                                0, 0)),
+    ), ways_out=(
+        # a decode step's stream is the bucket's rows, of which the
+        # traffic's live share still wants a token; a prefill call's is
+        # a row chunk (Keye's the whole bucket), its rows' real tokens
+        ("granite.decode", 128, 256, 4096, 107, 18, 1),
+        ("granite.prefill", 4096, 1024, 4096, 1024, 2, 512),
+        ("mimo.decode", 64, 256, 4096, 32, 4, 1),
+        ("mimo.prefill", 4096, 1024, 4096, 1024, 8, 512),
+        ("keye.decode", 16, 128, 2048, 12, 5, 1),
+        ("keye.prefill", 262144, 65536, 2048, 65536, 5, 16384),
+        ("kimi.decode", 8, 64, 7168, 2, 1, 1),
+        ("kimi.prefill", 16384, 4096, 7168, 4096, 12, 8192),
+        ("cmda.decode", 8, 64, 4096, 4, 2, 1),
+        ("cmda.prefill", 16384, 4096, 4096, 4096, 4, 16384),
     ))
 
 
@@ -942,11 +962,13 @@ def serve_family(tag, model, size, platform, stacks, counters_hold):
 def moe_rows_hold(timing, platform):
     """The grouped product was given at least the rows its pairs need,
     and on the chip every held experts' call of the group's two programs
-    went through the kernel of `ops/moe.py` (elsewhere none: the kernel
-    runs interpreted in tests/test_moe_grouped.py)."""
+    went through the kernels of `ops/moe.py`, its products and its way
+    out (elsewhere none: the kernels run interpreted in
+    tests/test_moe_grouped.py)."""
     return timing["moe_rows_computed_decode"] \
         >= timing["moe_pairs_decode"] > 0 \
-        and timing["moe_grouped_kernel_share"] == float(platform == "tpu")
+        and timing["moe_grouped_kernel_share"] == float(platform == "tpu") \
+        and timing["moe_combine_kernel_share"] == float(platform == "tpu")
 
 
 def packed_positions(kwargs, tile, real, S):
@@ -1571,6 +1593,93 @@ def phase_moe_grouped(size, platform):
                 "experts' bytes at 819 GB/s".format(**us)
         say(line)
         del w13, w2
+    for case in size.ways_out:
+        out[case[0] + ".way_out"] = way_out_holds(case, reps, platform)
+    return out
+
+
+def way_out_holds(case, reps, platform):
+    """A pass's way out into the stream (`ops/moe.py`): the walk of the
+    token tiles (compiled on the chip, interpreted elsewhere) against
+    XLA's scatter-add over the same rows, the rows past the pairs NaN:
+    the largest difference as a share of the largest value, the tiles no
+    row falls in bit for bit, and on the chip the time of a pass by each
+    path beside the time its bytes take (the touched tiles read and
+    written, the rows read, and gathered before where the stream is
+    more than a tile)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mxnet_tpu.ops import moe
+
+    name, T, P, M, live, experts, row = case
+    here = platform != "tpu"
+    require(here or moe._combine_fits(T, P, M),
+            f"moe_grouped: the way out's kernel would not take {name}")
+    rng = np.random.RandomState(T + P)
+    # each row of the stream real to a length of its own; the pass's
+    # tokens in expert order, an expert's a sorted draw of the real ones
+    # (a decode step's rows are one token long: half of them live)
+    real = np.sort(rng.choice(T, max(1, T // 2), replace=False)) \
+        if row == 1 else np.concatenate([r * row + np.arange(rng.randint(
+            row // 4, row + 1)) for r in range(T // row)])
+    tok = np.full(P, T, np.int32)
+    at = 0
+    for e in range(experts):
+        n = min(live // experts + (e < live % experts), len(real))
+        tok[at:at + n] = np.sort(rng.choice(real, n, replace=False))
+        at += n
+    tt = moe._combine_tiles(T, P, M)[0]
+    tiles = np.unique(tok[:at] // tt)
+    ks = jax.random.split(jax.random.key(T + M), 3)
+    y = jax.random.normal(ks[0], (T, M), jnp.float32)
+    o = jnp.where((jnp.arange(P) < at)[:, None],
+                  jax.random.normal(ks[1], (P, M), jnp.float32), jnp.nan)
+    w = jax.random.uniform(ks[2], (P,), jnp.float32)
+    tok = jnp.asarray(tok)
+
+    def by_kernel(y, o, w, tok):
+        return moe._combine_kernel_call(y, o, w, tok, interpret=here)
+
+    want = np.asarray(jax.jit(moe._combine_plain)(y, o, w, tok))
+    got = np.asarray(jax.jit(by_kernel)(y, o, w, tok))
+    require(np.isfinite(got).all(), f"moe_grouped: {name}'s way out not "
+            f"finite")
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    require(err <= MOE_GROUPED_TOL,
+            f"moe_grouped: {name}'s way out off the scatter-add by {err}")
+    kept = np.ones(T // tt, bool)
+    kept[tiles] = False
+    kept = np.repeat(kept, tt)
+    require((got[kept] == np.asarray(y)[kept]).all(),
+            f"moe_grouped: {name}'s way out moved a tile no row falls in")
+    out = {"err": err, "tiles": int(len(tiles))}
+    line = f"[moe_grouped] {name}'s way out ({at} of {P} rows into " \
+        f"{len(tiles)} of {T // tt} tiles of {tt} x {M}): err {err:.1e}"
+    del want, got
+    if not here:
+        def passes(path):
+            # the tokens shifted by whole tiles and the weights scaled
+            # with the pass, so that nothing is hoisted out of the loop
+            def one(i, y):
+                t = jnp.where(tok < T, (tok + i * tt) % T, T)
+                return path(y, o, w * (1.0 + 0.001 * i), t)
+            run = jax.jit(lambda y: lax.fori_loop(0, reps, one, y),
+                          donate_argnums=(0,))
+            warm = jax.block_until_ready(run(jnp.array(y)))
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(warm))
+            return (time.perf_counter() - t0) / reps * 1e6
+
+        out.update(
+            kernel_us=passes(by_kernel), scatter_us=passes(moe._combine_plain),
+            bytes_us=(2 * len(tiles) * tt + (3 if T > tt else 1) * at)
+            * M * 4 / 819e9 * 1e6)
+        line += "; a pass {kernel_us:.0f} us by the walk, {scatter_us:.0f} " \
+            "by the scatter-add, {bytes_us:.0f} its bytes at 819 GB/s" \
+            .format(**out)
+    say(line)
     return out
 
 

@@ -64,7 +64,9 @@ class Step:
       or an update moved on, by path; ``products``
       (``grouped_products[S]``): the held experts' calls
       (`ops/moe.py::held_experts_ffn`, which a family hands it), by the
-      path their grouped products took.
+      path their grouped products took (``"kernel"``, ``"plain"``) and
+      by the one their passes took into the stream
+      (``"combine_kernel"``, ``"combine_plain"``).
 
     The states' recurrence is `ops/ssm.py`'s Mamba-1 pair (a decay a
     state element, a state ``(N, E)``) unless the family hands `scan`
@@ -210,7 +212,8 @@ class DecoderProgram:
         # attention calls over the caches, by path; block_attends[S]
         # its attention calls inside the block, by path;
         # state_updates[S] the rows whose state it moved on, by path;
-        # grouped_products[S] its held experts' calls, by path
+        # grouped_products[S] its held experts' calls, by the paths of
+        # their products and of their way out
         self.cache_writes = {}
         self.cache_reads = {}
         self.block_attends = {}

@@ -196,15 +196,28 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
     expert's weights once, where they lie in the stack, and works only
     the row tiles that hold a pair) or ``"plain"`` (``lax.ragged_dot``
     over the layer's experts: the oracle the kernel is tested against,
-    tests/test_moe_grouped.py)."""
+    tests/test_moe_grouped.py).
+
+    A pass's weighted rows reach the stream by one of two paths as well
+    (`_combine_fits`), told beside the first: ``"combine_kernel"`` (a
+    TPU: the rows sorted by token and `_combine_kernel_call`, which
+    walks the stream's token tiles that a row of the pass falls in,
+    reads each once, adds its rows and writes it once) or
+    ``"combine_plain"`` (XLA's scatter-add of the buffer's rows, one at
+    a time: what every CPU test runs and the oracle of the other).  The
+    sum is the same float32 sum either way; a token's parts are added in
+    another order."""
     T, M = x.shape
     k = chosen.shape[1]
     n, _, F2 = w13.shape[-3:]
     F = F2 // 2
     P = int(pass_rows or share_pass_rows(T, k, n))
     kernel = _on_tpu() and _fits(P, M, F, n, w13.dtype)
+    walked = _on_tpu() and _combine_fits(T, P, M)
+    combine = _combine_kernel_call if walked else _combine_plain
     if tally is not None:
         tally["kernel" if kernel else "plain"] += 1
+        tally["combine_kernel" if walked else "combine_plain"] += 1
     if kernel:
         # the stacks as they lie, a layer's experts from ``layer * n``
         # (a reshape of leading axes moves nothing)
@@ -238,14 +251,12 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
         tok = idx // k
         lo, hi = jnp.clip(starts - base, 0, P), jnp.clip(ends - base, 0, P)
         groups = _walk(lo, hi, first, P) if kernel else hi - lo
-        h = dot(jnp.take(xs_all, tok, axis=0), w13, groups)
+        # ``tok`` is under T whatever the row (the padding of ``order``
+        # is pair 0): the gather is told so, and fills nothing
+        h = dot(xs_all.at[tok].get(mode="promise_in_bounds"), w13, groups)
         h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(w2.dtype)
         o = dot(h, w2, groups)
-        # rows past the pairs hold whatever the product left there, or
-        # (the kernel, which visits no tile past them) whatever the
-        # buffer held: a select keeps them out, a product would not
-        o = jnp.where(live[:, None], o * flat_w[idx][:, None], 0.0)
-        return y.at[jnp.where(live, tok, T)].add(o, mode="drop")
+        return combine(y, o, flat_w[idx], jnp.where(live, tok, T))
 
     passes = (total + P - 1) // P
     y = jax.lax.fori_loop(
@@ -283,6 +294,11 @@ def held_experts_ffn(x, chosen, weights, w13, w2, experts_lo=0, valid=None,
 # K is not cut: the sums over it are one product's, float32 inside the
 # MXU's accumulation, and a group that spans row tiles finds its block
 # where the tile before left it.
+#
+# A pass has a second walk, on its way out (`_combine_kernel_call`,
+# further down): the second product's rows sorted by token, and the
+# stream's token tiles walked as the groups are here, a tile that no
+# row falls in left as it is.
 
 _LANE = 128
 # rows a tile: what one pass of the MXU's 128 x 128 takes; a larger
@@ -413,6 +429,183 @@ def _grouped_kernel_call(x, w, walk, interpret=False):
         out_shape=jax.ShapeDtypeStruct((P, N), jnp.float32),
         name="moe_grouped", interpret=interpret, **kw,
     )(*walk, x, w)
+
+
+# -- a pass's way out, by token tile -------------------------------------------
+#
+# The second product leaves a pass's rows sorted by expert; the stream
+# ``y`` (T, M) float32 wants them by token.  XLA's scatter-add takes the
+# buffer's rows one at a time, the dead ones too (0.3-3.3 us a row on
+# the v5e: PERF.md, PR 52).  The second walk: the rows are sorted by
+# token (P int32 keys, the dead rows last; stable, so a token's rows
+# stay in expert order) and gathered into that order, and the stream is
+# cut in tiles of ``tt`` tokens as the buffer is in tiles of ``tr``
+# rows.  A visit is a (token tile, row tile) in which a row of the tile
+# falls to a token of the tile; sorted, both only grow along the rows,
+# so the visits of a token tile follow one another and a pass has at
+# most the row tiles + the token tiles - 1 of them.  A grid step takes
+# the stream's tile (it stays while the visits that share it pass), the
+# row tile with its tokens and weights, and adds ``onehot (tt, tr) x
+# weighted rows`` to the tile: the one-hot is exact in bfloat16 and the
+# float32 rows go as three bfloat16 parts that sum to them exactly, so
+# the products are exact and the sums float32, in three passes of the
+# MXU where ``Precision.HIGHEST`` takes six.  The stream is aliased in
+# and out:
+#
+# - a tile no row of the pass falls in is not visited and keeps what it
+#   held, to the bit; row tiles past the pairs are not visited;
+# - each touched tile is read once and written once a pass and column
+#   tile, whatever the rows that fall in it;
+# - the walk is made from the sorted tokens by differences, a
+#   cumulative sum and a binary search (a stream has thousands of
+#   tiles: `_walk`'s comparison of every visit with every group would
+#   be millions), and the grid ends with its count of visits;
+# - a stream of one tile (a decode step's rows) needs no order among
+#   its rows: they are taken as they lie, and nothing is sorted or
+#   gathered.
+
+# tokens a tile of the stream
+_TOKENS = 128
+# the most a tile of the stream (``tt`` tokens, ``tn`` columns, float32)
+# may take: it is in flight four times (in and out, double-buffered)
+# beside two row tiles and the parts they are split into
+_STREAM_BLOCK = 1 << 20
+
+
+def _combine_tiles(T, P, M):
+    """``(tt, tr, tn)``: the tokens, the rows and the columns of a grid
+    step, from the shapes: `_TOKENS` and `_ROWS` (a shorter stream or
+    buffer whole) and the widest lane-aligned divisor of M whose tile of
+    the stream fits `_STREAM_BLOCK`; M whole where it has none."""
+    tt, tr = min(T, _TOKENS), min(P, _ROWS)
+    wide = [d for d in range(M // _LANE * _LANE, 0, -_LANE)
+            if M % d == 0 and tt * d * 4 <= _STREAM_BLOCK]
+    return tt, tr, (wide or [M])[0]
+
+
+def _combine_fits(T, P, M):
+    """Whether a pass's way out is whole tiles for the kernel: a
+    lane-aligned width, and tiles of whole sublanes that divide the
+    stream's tokens and the buffer's rows."""
+    tt, tr, _ = _combine_tiles(T, P, M)
+    return M % _LANE == 0 and T % tt == 0 and P % tr == 0 \
+        and tt % 8 == 0 and tr % 8 == 0
+
+
+def _combine_plain(y, o, w, tok):
+    """``y`` (T, M) float32 plus row r of ``o`` (P, M) times ``w[r]``
+    at token ``tok[r]``, for the rows whose token is under T: XLA's
+    scatter-add, what the kernel is held to."""
+    # rows past the pairs hold whatever the product left there, or (the
+    # kernel, which visits no tile past them) whatever the buffer held:
+    # a select keeps them out, a product would not
+    o = jnp.where((tok < y.shape[0])[:, None], o * w[:, None], 0.0)
+    return y.at[tok].add(o, mode="drop")
+
+
+def _token_walk(tok, T, tt, tr):
+    """The kernel's scalar operands for a pass whose rows fall to the
+    tokens ``tok`` (P,) int32, sorted, T for a row past the pairs:
+    ``(visits (1,), token tile (V,), row tile (V,))``, V = the row tiles
+    + the token tiles (or the rows, if fewer) - 1.  Visit v < ``visits``
+    adds the rows of ``row tile[v]`` that fall in ``token tile[v]``: a
+    visit starts at each live row that is the first of its row tile or
+    of its token tile.  A stream of one tile is visited once a row tile
+    up to the last that holds a live row, whatever the rows' order."""
+    P = tok.shape[0]
+    V = P // tr + min(P, T // tt) - 1
+    row = jnp.arange(P, dtype=jnp.int32)
+    if T == tt:
+        visits = jnp.max(jnp.where(tok < T, row // tr + 1, 0))
+        return visits[None], jnp.zeros((V,), jnp.int32), row[:V]
+    tile = tok // tt
+    starts = (tok < T) & ((row % tr == 0) | (tile != jnp.roll(tile, 1)))
+    upto = jnp.cumsum(starts, dtype=jnp.int32)
+    at = jnp.minimum(jnp.searchsorted(
+        upto, jnp.arange(1, V + 1, dtype=jnp.int32)), P - 1).astype(jnp.int32)
+    return (upto[-1:], jnp.minimum(jnp.take(tile, at), T // tt - 1),
+            at // tr)
+
+
+def _combine_kernel(visits_ref, ttile_ref, rtile_ref, tok_ref, w_ref, rows_ref,
+                    y_ref, o_ref):
+    """One (column tile, visit): the row tile's rows that fall in the
+    token tile, weighted, added to their tokens' rows of it."""
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(1)
+    t, tt = ttile_ref[v], o_ref.shape[0]
+    token = t * tt + jax.lax.broadcasted_iota(jnp.int32, (tt, 1), 0)
+    onehot = (token == tok_ref[...]).astype(jnp.bfloat16)      # (tt, tr)
+    # a row past the pairs (`_combine_plain`) comes with the weight 0:
+    # the select keeps what it holds out, a product would not
+    w = w_ref[...]                                             # (tr, 1)
+    rest, add = jnp.where(w != 0.0, rows_ref[...] * w, 0.0), None
+    for _ in range(3):
+        part = rest.astype(jnp.bfloat16)
+        rest = rest - part.astype(jnp.float32)
+        part = jnp.dot(onehot, part, preferred_element_type=jnp.float32)
+        add = part if add is None else add + part
+    first = (v == 0) | (ttile_ref[jnp.maximum(v - 1, 0)] != t)
+
+    @pl.when(first)
+    def _():
+        o_ref[...] = y_ref[...] + add
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        o_ref[...] += add
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _combine_kernel_call(y, o, w, tok, interpret=False):
+    """`_combine_plain`'s sum by the walk of the stream's token tiles:
+    ``y`` is written in place (aliased; jitted for the reason
+    `_grouped_kernel_call` is)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (T, M), P = y.shape, o.shape[0]
+    tt, tr, tn = _combine_tiles(T, P, M)
+    w = jnp.where(tok < T, w, 0.0)
+    if T > tt:
+        # a stream of several tiles: the rows by token, so that a
+        # tile's rows follow one another (a permutation: every index is
+        # in bounds, and the gather is told so)
+        tok, w, order = jax.lax.sort(
+            (tok, w, jnp.arange(P, dtype=jnp.int32)), num_keys=1,
+            is_stable=True)
+        o = o.at[order].get(mode="promise_in_bounds")
+    # a stream of one tile (a decode step's) takes the rows as they lie
+    walk = _token_walk(tok, T, tt, tr)
+    # the stream's tile in and out and the row tile, each twice (the
+    # pipeline's double buffer), the row tile weighted, its three parts
+    # and the sum
+    need = 4 * tt * tn * 4 + 2 * tr * tn * 4 + tr * tn * 14 + 2 * tt * tn * 4
+    kw = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        **({} if need <= _VMEM_DEFAULT
+           else {"vmem_limit_bytes": need + need // 4}))}
+
+    def spec(block, index):
+        """A block at ``index(column tile, the visit's token tile, the
+        visit's row tile)``."""
+        return pl.BlockSpec(block, lambda j, v, visits, ttile, rtile: index(
+            j, ttile[v], rtile[v]))
+
+    stream = spec((tt, tn), lambda j, t, r: (t, j))
+    return pl.pallas_call(
+        _combine_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(M // tn, walk[0][0]),
+            in_specs=[spec((1, tr), lambda j, t, r: (0, r)),
+                      spec((tr, 1), lambda j, t, r: (r, 0)),
+                      spec((tr, tn), lambda j, t, r: (r, j)), stream],
+            out_specs=stream),
+        out_shape=jax.ShapeDtypeStruct((T, M), jnp.float32),
+        input_output_aliases={6: 0},
+        name="moe_combine", interpret=interpret, **kw,
+    )(*walk, tok.reshape(1, P), w.reshape(P, 1), o, y)
 
 
 def swiglu_ffn(x, gate, up, down):

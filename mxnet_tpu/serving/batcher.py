@@ -249,7 +249,8 @@ class ContinuousBatcher:
         "decode_row_steps_live", "decode_cache_write_kernel_share",
         "decode_cache_write_live_share", "decode_attn_kernel_share",
         "decode_attn_window_read_pct", "prefill_attn_kernel_share",
-        "moe_grouped_kernel_share") + telemetry.MEMORY_FIELDS
+        "moe_grouped_kernel_share", "moe_combine_kernel_share") \
+        + telemetry.MEMORY_FIELDS
 
     def _finish(self, group, outs, timings, t_batch, t_finish):
         t_done = time.time()

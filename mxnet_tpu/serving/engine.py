@@ -307,7 +307,8 @@ class ServingEngine:
       `ops/pallas_attention.py`), ``state_updates`` (likewise the rows
       whose state `ops/ssm.py` moved on, by path), ``grouped_products``
       (likewise the held experts' calls, by the path `ops/moe.py` took
-      for their grouped products) and
+      for their grouped products and by the one their passes took into
+      the stream) and
       ``signature`` (what a reloaded model must share beyond shapes).
 
     ``serve_group(prompts, max_new_tokens)`` is the whole request path:
@@ -913,14 +914,19 @@ class ServingEngine:
         # of the held experts' calls in the group's two programs, the
         # share whose grouped products went through the kernel of
         # ops/moe.py (a hit expert's weights read once, where they lie)
-        # and not through lax.ragged_dot
+        # and not through lax.ragged_dot, and the share whose passes
+        # reached the stream by its walk of the token tiles and not by
+        # XLA's scatter-add
         products = collections.Counter()
         for s in {1, int(S)}:
             products.update(getattr(self._program, "grouped_products",
                                     {}).get(s, {}))
         if products:
-            timings["moe_grouped_kernel_share"] = \
-                products["kernel"] / sum(products.values())
+            timings["moe_grouped_kernel_share"] = products["kernel"] / (
+                products["kernel"] + products["plain"])
+            timings["moe_combine_kernel_share"] = \
+                products["combine_kernel"] / (
+                    products["combine_kernel"] + products["combine_plain"])
         counters = getattr(self._program, "counters", None)
         if counters is not None:
             # what the family counted in its donated carry: one small

@@ -798,8 +798,12 @@ def test_command_a_plus_programs_compile_for_a_v5e_at_the_published_widths(
     alias = text[text.index("input_output_alias="):].split("\n")[0]
     for i in range(6):
         assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
-    # the four layers' held experts by the grouped product's kernel
-    assert dict(program.grouped_products[S]) == {"kernel": 4}
+    # the four layers' held experts by the grouped product's kernel,
+    # their passes into the stream by the walk of its token tiles
+    assert dict(program.grouped_products[S]) == {"kernel": 4,
+                                                 "combine_kernel": 4}
+    assert not [line for line in text.split("\n")
+                if "scatter" in line and "serve.moe.experts" in line]
     assert "ragged" not in text
     ring_layer = B * z.kv_heads * z.head_dim * z.window * 2
     mem = compiled.memory_analysis()
@@ -1024,6 +1028,43 @@ def test_the_grouped_kernel_compiles_for_a_v5e(one_chip, family, phase):
         <= P * (2 * F * 4 + F * 2) + (1 << 20)
 
 
+# (tokens of the stream, rows of the buffer, M) a decode step and a
+# prefill call hand a pass's way out in each expert family's cell
+WAYS_OUT = {
+    "mimo": ((64, 256, 4096), (4096, 1024, 4096)),
+    "keye": ((16, 128, 2048), (262144, 65536, 2048)),
+    "kimi": ((8, 64, 7168), (16384, 4096, 7168)),
+    "cmda": ((8, 64, 4096), (16384, 4096, 4096)),
+    "granite": ((128, 256, 4096), (4096, 1024, 4096)),
+    "smoke": ((8, 16, 256), (1024, 256, 256)),
+}
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("family", list(WAYS_OUT))
+def test_the_way_out_kernel_compiles_for_a_v5e(one_chip, family, phase):
+    """A pass's way out (`ops/moe.py::_combine_kernel_call`) at each
+    expert family's stream and buffer compiles for a described v5e
+    through Mosaic with the stream written into its donated argument, no
+    scatter left, and no temporary but the rows sorted by token and
+    their weights as a column (a lane tile wide in the chip's layout)."""
+    from mxnet_tpu.ops import moe
+
+    T, P, M = WAYS_OUT[family][phase == "prefill"]
+    assert moe._combine_fits(T, P, M)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(moe._combine_kernel_call, donate_argnums=(0,)).lower(
+        sds((T, M)), sds((P, M)), sds((P,)), sds((P,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "scatter" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == T * M * 4
+    assert mem.temp_size_in_bytes <= P * (M + 128) * 4 + (4 << 20)
+
+
 def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
         one_chip, monkeypatch):
     """Granite 4.0-H's decode step
@@ -1089,7 +1130,8 @@ def test_granites_decode_step_compiles_for_a_v5e_at_the_published_widths(
     # a pass's own count; not 10 x 18 groups), no
     # ragged_dot left, and neither a stack of experts nor a layer's
     # slice of one (113 MB the smaller) copied or sliced out
-    assert dict(program.grouped_products[1]) == {"kernel": 10}
+    assert dict(program.grouped_products[1]) == {"kernel": 10,
+                                                 "combine_kernel": 10}
     n = z.experts_held[1]
     calls = _grouped_calls(traced.jaxpr.jaxpr)
     assert calls == [(2, 2 + n - 1, n), (1, 2 + n - 1, n)] * 10, calls
@@ -1149,7 +1191,8 @@ def test_mimos_decode_step_compiles_for_a_v5e_at_the_published_widths(
     for i in range(len(cache)):
         assert f"{{{i}}}: ({len(weights) + i}, {{}}" in alias, alias
     n, F, M = z.experts_held[1], kwargs["expert_hidden"], kwargs["units"]
-    assert dict(program.grouped_products[1]) == {"kernel": 6}
+    assert dict(program.grouped_products[1]) == {"kernel": 6,
+                                                 "combine_kernel": 6}
     assert _grouped_calls(traced.jaxpr.jaxpr) \
         == [(4, 2 + n - 1, n), (2, 2 + n - 1, n)] * 6
     assert "ragged" not in text

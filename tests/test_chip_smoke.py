@@ -219,9 +219,10 @@ def test_moe_grouped_phase(monkeypatch):
     """The grouped product's phase, interpreted at tiny tiles: both
     products of a pass equal ``lax.ragged_dot``'s over layer 1 of the
     stack, a group over three tiles, empty groups and an empty tail
-    among the cases; no time is taken off the chip.  The chip's cases
-    are the five expert families' widths and buffers, each taken by the
-    kernel."""
+    among the cases, and a pass's way out by the walk of the stream's
+    token tiles equals the scatter-add's; no time is taken off the chip.
+    The chip's cases are the five expert families' widths, buffers and
+    streams, each taken by the kernels."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import moe
@@ -233,11 +234,22 @@ def test_moe_grouped_phase(monkeypatch):
     for _, P, M, F, n, sizes in full.cases:
         assert moe._fits(P, M, F, n, jnp.bfloat16)
         assert len(sizes) == n and 0 < sum(sizes) <= P
+    assert [c[0] for c in full.ways_out] == [c[0] for c in full.cases]
+    for (_, T, P, M, live, experts, row), case in zip(full.ways_out,
+                                                      full.cases):
+        assert moe._combine_fits(T, P, M)
+        assert (P, M) == (case[1], case[2]) or case[0] == "keye.prefill"
+        assert 0 < experts <= live <= P and T % row == 0
     monkeypatch.setattr(moe, "_ROWS", 16)
+    monkeypatch.setattr(moe, "_TOKENS", 8)
     out = chip_smoke.phase_moe_grouped(chip_smoke.GroupedSize(cases=(
         ("a", 64, 128, 128, 5, (0, 40, 0, 3, 7)),
-        ("b", 32, 256, 128, 3, (32, 0, 0))), reps=2), "cpu")
-    assert set(out) == {"a", "b"} and set(out["a"]) == {"err"}
+        ("b", 32, 256, 128, 3, (32, 0, 0))), reps=2, ways_out=(
+        ("c", 64, 32, 128, 21, 3, 16), ("d", 8, 16, 128, 5, 2, 1))), "cpu")
+    assert set(out) == {"a", "b", "c.way_out", "d.way_out"}
+    assert set(out["a"]) == {"err"}
+    assert set(out["c.way_out"]) == {"err", "tiles"}
+    assert 0 < out["c.way_out"]["tiles"] <= 8
 
 
 def test_serve_cmda_phase():
